@@ -31,7 +31,10 @@ from __future__ import annotations
 
 import os
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU by design: the meshes here are forced host devices, whatever
+# accelerator the machine has (set, not defaulted — a parent may hold
+# the chip).
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "xla_force_host_platform_device_count" not in os.environ.get(
     "XLA_FLAGS", ""
 ):
@@ -264,7 +267,10 @@ def measure_chipmover(smoke: bool = False) -> dict:
 
 
 def measure_fleet_mesh(smoke: bool = False) -> dict:
-    out = {}
+    out = {
+        "platform": f"{jax.default_backend()} ({jax.device_count()} "
+        f"forced host devices, by design)",
+    }
     out.update(measure_reshard(smoke))
     out.update(measure_serve_2mesh(smoke))
     out.update(measure_chipmover(smoke))

@@ -21,10 +21,8 @@ generalizes that into a first-class matrix over the serving decoder:
   ``exported`` (StableHLO artifacts through
   tpudl.export.decode.export_serving_decoder -> from_artifacts; paged
   cells export the page-pool contract and from_artifacts recovers the
-  geometry from avals) — exported cells auto-skip when jax.export is
-  unavailable (tpudl.export.export.EXPORT_AVAILABLE), mirroring the
-  test tier's conftest guard; prefix/spec cells skip the exported
-  column loudly (they need live chunk/draft programs).
+  geometry from avals); prefix/spec cells skip the exported column
+  loudly (they need live chunk/draft programs).
 
 Every cell runs ``assert_serving_parity`` against the f32 reference
 model at a per-cell tolerance: exact token equality for f32 cells,
@@ -106,8 +104,8 @@ LORA8_ALPHA = 4.0
 
 
 class CellUnrunnable(RuntimeError):
-    """A cell this ENVIRONMENT cannot run (no jax.export, paged KV has
-    no exported-artifact session). Deliberately distinct from plain
+    """A cell the exported backend cannot run (prefix/spec and adapter
+    cells need live programs). Deliberately distinct from plain
     RuntimeError so run_grid's skip path can never absorb a genuine
     cell failure (jaxlib's XlaRuntimeError subclasses RuntimeError —
     a broken cell must fail the benchmark, not report as a skip)."""
@@ -244,8 +242,8 @@ def build_cell_session(
     session_kwargs: dict,
 ):
     """One cell's ServeSession: live-jitted or round-tripped through
-    the StableHLO artifact pair. Raises CellUnrunnable for the exported
-    backend when jax.export is unavailable (callers skip the cell)."""
+    the StableHLO artifact pair. Raises CellUnrunnable for an exported
+    prefix/spec cell (callers skip the cell)."""
     from tpudl.serve import ServeSession
 
     if backend == "compiled":
@@ -255,9 +253,6 @@ def build_cell_session(
         )
     if backend != "exported":
         raise ValueError(f"unknown backend {backend!r}")
-    from tpudl.export.export import EXPORT_AVAILABLE
-    if not EXPORT_AVAILABLE:
-        raise CellUnrunnable("jax.export unavailable")
     if session_kwargs.get("prefix_share") or session_kwargs.get("spec_k"):
         # Sharing needs the live chunked suffix-prefill program and
         # speculation the live draft+verify pair — neither is part of
@@ -541,8 +536,8 @@ def run_grid(
                     sim_bw_gbps=sim_bw_gbps, seed=seed,
                 )
             except CellUnrunnable as e:
-                # Environment-limited cells (no jax.export, paged
-                # artifact gap) skip loudly, never silently pass.
+                # Cells the exported contract does not carry skip
+                # loudly, never silently pass.
                 # Anything else — including XlaRuntimeError, a
                 # RuntimeError subclass — propagates and FAILS the
                 # benchmark.

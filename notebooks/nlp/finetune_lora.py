@@ -36,7 +36,7 @@ from tpudl.models.lora import (
 )
 from tpudl.models.registry import build_model
 from tpudl.parallel.sharding import TP_TRANSFORMER_RULES
-from tpudl.runtime import MeshSpec, apply_platform_env, make_mesh
+from tpudl.runtime import MeshSpec, make_mesh
 from tpudl.train import (
     MetricLogger,
     TrainState,
@@ -45,8 +45,6 @@ from tpudl.train import (
     make_classification_train_step,
 )
 from tpudl.train.optim import make_optimizer
-
-apply_platform_env()
 
 
 def main():

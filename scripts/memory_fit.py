@@ -156,6 +156,8 @@ def report(config_name: str, n_devices: int, hbm_gb: float) -> dict:
     out = {
         "config": cfg.name,
         "model": cfg.model,
+        "platform": "cpu (forced host devices, by design: a bytes "
+        "model of shardings, nothing runs on an accelerator)",
         "devices": n_devices,
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
         "global_batch": cfg.global_batch_size,
@@ -193,6 +195,7 @@ def main(argv=None) -> int:
         bb = out["bytes_per_device"]
         print(f"{out['config']} ({out['model']}) on {out['devices']} devices, "
               f"mesh {out['mesh']}")
+        print(f"  platform: {out['platform']}")
         print(f"  params: {out['params_total'] / 1e9:.2f}B total, "
               f"{out['params_trainable'] / 1e6:.1f}M trainable (LoRA+head)")
         for k in ("params", "opt_moments", "activations_upper_bound",

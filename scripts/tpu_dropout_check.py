@@ -1,13 +1,20 @@
-"""Real-TPU statistical checks for the in-kernel (PRNG-backed) dropout
-paths — the half of tpudl.ops.fused_attention / tpudl.ops.softmax_dropout
-that pallas interpret mode cannot emulate (no PRNG), so the CPU test tier
-(tests/test_fused_attention.py) cannot cover it.
+"""Real-TPU checks for what the CPU test tier cannot cover.
+
+1. Statistical checks of the in-kernel (PRNG-backed) dropout paths —
+   the half of tpudl.ops.fused_attention / tpudl.ops.softmax_dropout /
+   flash_attention that pallas interpret mode cannot emulate (no PRNG).
+2. Compiled-kernel parity against the XLA composites at BERT-base and
+   Llama-1B widths for the epilogue, cross-entropy, segmented-LoRA and
+   masked fused-attention kernels: interpret mode checks their
+   arithmetic, tests/test_tpu_compile.py that they compile, this that
+   the compiled kernel computes the same thing.
 
 Run on a machine with a TPU: python scripts/tpu_dropout_check.py
-Prints PASS/FAIL per check; exits nonzero on failure; prints SKIP when
-no TPU backend is present (so CI without a chip stays green).
+Prints PASS/FAIL per check; exits nonzero on failure, and without a TPU
+(a pass that checked nothing is not a pass).
 """
 
+import itertools
 import pathlib
 import sys
 
@@ -21,16 +28,137 @@ from tpudl.ops.fused_attention import fused_attention
 from tpudl.ops.softmax_dropout import softmax_dropout
 
 
+def _max_rel(a, b) -> float:
+    """max|a - b| over max|b| in f32 (b the reference)."""
+    a = jnp.asarray(a, jnp.float32)
+    b = jnp.asarray(b, jnp.float32)
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-9))
+
+
+def compiled_parity(check) -> None:
+    """Compiled Pallas kernels vs their XLA composites, forward and
+    backward, at the widths the models run them at. bf16 cases compare
+    at bf16 resolution (2^-8 relative, a few ulps of headroom); f32
+    cases at the f32 matmul/reduction floor."""
+    from tpudl.ops.cross_entropy import (
+        softmax_cross_entropy,
+        softmax_cross_entropy_ref,
+    )
+    from tpudl.ops.mlp_fused import (
+        bias_gelu,
+        bias_gelu_ref,
+        swiglu,
+        swiglu_ref,
+    )
+    from tpudl.ops.norms import (
+        layer_norm,
+        layer_norm_ref,
+        rms_norm,
+        rms_norm_ref,
+    )
+    from tpudl.ops.segmented_lora import segmented_lora, segmented_lora_ref
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    ks = (jax.random.fold_in(jax.random.key(5), i) for i in itertools.count())
+
+    def rnd(shape, dtype=bf16, scale=1.0):
+        return (jax.random.normal(next(ks), shape, f32) * scale).astype(dtype)
+
+    def pair(name, fused_fn, ref_fn, args, tol, argnums):
+        """Forward and gradient parity of one kernel against its
+        composite on the same inputs."""
+        coef = rnd(jax.eval_shape(ref_fn, *args).shape, f32)
+        rel = _max_rel(fused_fn(*args), ref_fn(*args))
+        check(f"{name} fwd (rel {rel:.2e})", rel < tol)
+        loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(f32) * coef)
+        gf = jax.grad(loss(fused_fn), argnums=argnums)(*args)
+        gr = jax.grad(loss(ref_fn), argnums=argnums)(*args)
+        for i, (a, b) in enumerate(zip(gf, gr)):
+            rel = _max_rel(a, b)
+            check(f"{name} grad[{i}] (rel {rel:.2e})", rel < tol)
+
+    tol = 2e-2  # bf16 outputs/cotangents: a few 2^-8 ulps
+    # BERT-base rows: 4096 x 768 / 3072 (a slice of b256 s128's 32768).
+    x, r = rnd((4096, 768)), rnd((4096, 768))
+    scale, bias = 1.0 + rnd((768,), f32, 0.1), rnd((768,), f32, 0.1)
+    pair("layer_norm+residual [4096,768]",
+         lambda x, r, s, b: layer_norm(x, s, b, r, return_sum=False,
+                                       impl="fused"),
+         lambda x, r, s, b: layer_norm_ref(x, s, b, r)[0],
+         (x, r, scale, bias), tol, (0, 1, 2, 3))
+    pair("bias_gelu [4096,3072]",
+         lambda x, b: bias_gelu(x, b, impl="fused"), bias_gelu_ref,
+         (rnd((4096, 3072)), rnd((3072,), f32, 0.5)), tol, (0, 1))
+    # Llama-1B rows: decode (8) and prefill (4096) x 2048 / 8192.
+    scale = 1.0 + rnd((2048,), f32, 0.1)
+    for rows in (8, 4096):
+        pair(f"rms_norm+residual [{rows},2048]",
+             lambda x, r, s: rms_norm(x, s, r, return_sum=False,
+                                      impl="fused"),
+             lambda x, r, s: rms_norm_ref(x, s, r)[0],
+             (rnd((rows, 2048)), rnd((rows, 2048)), scale), tol, (0, 1, 2))
+        pair(f"swiglu [{rows},8192]",
+             lambda g, u: swiglu(g, u, impl="fused"), swiglu_ref,
+             (rnd((rows, 8192)), rnd((rows, 8192))), tol, (0, 1))
+    for shape, dtype in (((256, 2), f32), ((512, 128256), bf16)):
+        labels = jax.random.randint(next(ks), shape[:1], 0, shape[1])
+        pair(f"cross_entropy {list(shape)} {jnp.dtype(dtype).name}",
+             lambda z: softmax_cross_entropy(z, labels, impl="fused"),
+             lambda z: softmax_cross_entropy_ref(z, labels),
+             (rnd(shape, dtype, 3.0),), tol if dtype == bf16 else 1e-4,
+             (0,))
+
+    # Segmented LoRA, decode: 8 slots x rank<=8 over 64-page pools; the
+    # reference einsums run at full f32 precision (the kernel's sums
+    # are f32 on the VPU).
+    table = jax.random.randint(next(ks), (8, 8), 0, 64).astype(jnp.int32)
+    table = table.at[3].set(0).at[:, 6:].set(0)  # empty slot, short ranks
+    slot_scale = jnp.abs(rnd((8,), f32)).at[3].set(0.0)
+    for quant in (False, True):
+        if quant:
+            pools = {
+                "a": jax.random.randint(next(ks), (64, 2048), -127, 128)
+                .astype(jnp.int8).at[0].set(0),
+                "b": jax.random.randint(next(ks), (64, 8192), -127, 128)
+                .astype(jnp.int8).at[0].set(0),
+                "a_scale": jnp.abs(rnd((64,), f32, 0.01)),
+                "b_scale": jnp.abs(rnd((64,), f32, 0.01)),
+            }
+        else:
+            pools = {"a": rnd((64, 2048), f32, 0.05).at[0].set(0.0),
+                     "b": rnd((64, 8192), f32, 0.05).at[0].set(0.0)}
+        xs = rnd((8, 2048))
+        got = segmented_lora(xs, pools, table, slot_scale, impl="fused")
+        with jax.default_matmul_precision("highest"):
+            want = segmented_lora_ref(xs, pools, table, slot_scale)
+        name = f"segmented_lora decode [8,2048]->8192 {'int8' if quant else 'f32'}"
+        rel = _max_rel(got, want)
+        check(f"{name} (rel {rel:.2e})", rel < tol)
+        check(f"{name} empty slot is zero", bool(jnp.all(got[3] == 0)))
+
+    # Fused attention with a kv-validity mask at its longest sequence.
+    q, k, v = (rnd((4, 512, 12, 64)) for _ in range(3))
+    mask = (jnp.arange(512)[None, :] < jnp.asarray([512, 400, 77, 1])[:, None])
+    mask = mask.astype(jnp.int32)
+    pair("fused_attention mask [4,512,12,64]",
+         lambda q, k, v: fused_attention(q, k, v, mask=mask),
+         lambda q, k, v: attend(q, k, v, mask),
+         (q, k, v), tol, (0, 1, 2))
+
+
 def main() -> int:
     if not is_tpu_backend():
-        print("SKIP: no TPU backend")
-        return 0
+        print("tpu_dropout_check needs a TPU: "
+              f"found {jax.default_backend()!r}", file=sys.stderr)
+        return 2
     failures = 0
 
     def check(name, ok):
         nonlocal failures
         print(f"{'PASS' if ok else 'FAIL'}: {name}")
         failures += 0 if ok else 1
+
+    compiled_parity(check)
 
     B, S, H, D = 4, 128, 8, 64
     ks = jax.random.split(jax.random.key(0), 3)

@@ -3,8 +3,8 @@
 load-bearing: the round-3 gap was configs[2]/[3] hardcoded out of reach).
 
 Each test launches the real workload script as a subprocess on the fake
-8-device CPU mesh (TPUDL_PLATFORM=cpu + host-device-count XLA flag — the
-notebooks' apply_platform_env hook), at toy step counts. Big models
+8-device CPU mesh (JAX_PLATFORMS=cpu + the host-device-count XLA flag),
+at toy step counts. Big models
 override to tiny shapes via the SAME CLI the full run uses; the config's
 mesh / strategy / schema / accumulation path is what's exercised.
 """
@@ -20,7 +20,6 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 
 ENV = {
     **os.environ,
-    "TPUDL_PLATFORM": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
     "JAX_PLATFORMS": "cpu",
 }

@@ -22,10 +22,6 @@ from tpudl.export.decode import (
 from tpudl.models.generate import generate
 from tpudl.models.llama import LLAMA_TINY, LlamaForCausalLM
 
-# Every test serializes/deserializes StableHLO; on a jax build without
-# jax.export the conftest guard skips the module instead of erroring.
-pytestmark = pytest.mark.needs_jax_export
-
 CFG = LLAMA_TINY(dtype=jnp.float32, max_seq_len=64)
 B, S, NEW = 2, 8, 12
 

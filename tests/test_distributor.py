@@ -20,6 +20,41 @@ def test_unpicklable_fn_error():
         d.run(lambda x: x, 1)
 
 
+def test_local_spawn_refuses_tpu_platform():
+    """A chip belongs to one process: N local workers on platform "tpu"
+    would each claim every chip. Refused before anything is spawned."""
+    d = TpuDistributor(num_processes=2, platform="tpu")
+    with pytest.raises(ValueError, match="ONE process drives all local chips"):
+        d.run(dist_helpers.report_topology)
+
+
+def test_spawned_worker_platform_is_set_not_inherited(monkeypatch, tmp_path):
+    """The worker's JAX_PLATFORMS is the distributor's platform even
+    when the parent's environment names another (the worker would go
+    after a chip its parent may hold)."""
+    import subprocess
+
+    seen = {}
+
+    class _Done:
+        returncode = 0
+
+        def poll(self):
+            return 0
+
+    def fake_popen(cmd, env=None, **kwargs):
+        seen.setdefault("platforms", []).append(env["JAX_PLATFORMS"])
+        assert "TPUDL_PLATFORM" not in env
+        return _Done()
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(subprocess, "Popen", fake_popen)
+    d = TpuDistributor(num_processes=2, platform="cpu", timeout_s=5.0)
+    with pytest.raises(RuntimeError):  # no worker ran: no results
+        d._spawn_in(str(tmp_path), "localhost:1", b"", None)
+    assert seen["platforms"] == ["cpu", "cpu"]
+
+
 @pytest.mark.slow
 def test_spawn_two_processes_topology():
     d = TpuDistributor(num_processes=2, platform="cpu", devices_per_process=2)

@@ -13,10 +13,6 @@ from tpudl.export import (
     save_params,
 )
 
-# Everything here round-trips StableHLO blobs; on a jax build without
-# jax.export the conftest guard skips the module instead of erroring.
-pytestmark = pytest.mark.needs_jax_export
-
 
 def _fn(x, w):
     return jnp.tanh(x @ w)

@@ -135,9 +135,8 @@ def test_lm_shaped_leading_dims(rng_np):
 
 
 def _sub_jaxprs(params):
-    """Sub-jaxprs hiding in an eqn's params (custom_vjp/pjit bodies) —
-    hand-rolled so it works across jax versions."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    """Sub-jaxprs hiding in an eqn's params (custom_vjp/pjit bodies)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for val in params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)

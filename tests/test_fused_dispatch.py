@@ -673,19 +673,42 @@ def test_overlap_env_knob(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_compile_cache_second_compile_records_hit(tmp_path, monkeypatch):
-    """With TPUDL_COMPILE_CACHE set, a second compile_step of the same
-    signature is served from the persistent cache and the obs stream
-    records the hit."""
+@pytest.fixture
+def compile_cache_config():
+    """conftest turns the persistent cache off for the hermetic run; a
+    cache test turns it on for itself and leaves the session as it
+    found it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = (
+        "jax_enable_compilation_cache",
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    saved = {name: getattr(jax.config, name) for name in names}
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()  # un-latch: later tests stay uncached
+
+
+def test_compile_cache_second_compile_records_hit(
+    tmp_path, monkeypatch, compile_cache_config
+):
+    """With JAX_COMPILATION_CACHE_DIR set, tpudl sets no other
+    directory; a second compile_step of the same signature is served
+    from the persistent cache there and the obs stream records the
+    hit."""
     from tpudl.runtime import compile_cache
 
-    monkeypatch.setenv("TPUDL_COMPILE_CACHE", str(tmp_path / "cache"))
-    defaults = {
-        "jax_compilation_cache_dir": None,
-        "jax_persistent_cache_min_compile_time_secs": 1.0,
-        "jax_persistent_cache_min_entry_size_bytes": 0,
-    }
-    assert compile_cache.enable_compile_cache()
+    cache_dir = str(tmp_path / "cache")
+    # What JAX does with the variable at start-up (it was imported long
+    # before this test could set it).
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
+    assert compile_cache.enable_compile_cache() == cache_dir
     try:
         rec = obs_spans.enable(str(tmp_path / "obs"))
         mesh = make_mesh(MeshSpec(dp=-1))
@@ -709,20 +732,47 @@ def test_compile_cache_second_compile_records_hit(tmp_path, monkeypatch):
             if r.get("kind") == "event" and r["name"] == "compile_cache_hit"
         ]
         assert events, "cache hit must land in the span stream"
+        assert os.listdir(cache_dir), "entries land where the variable says"
     finally:
         obs_spans.disable()
-        for k, v in defaults.items():
-            jax.config.update(k, v)
-        try:
-            from jax._src import compilation_cache as _jax_cc
-
-            _jax_cc.reset_cache()  # un-latch: later tests stay uncached
-        except Exception:
-            pass
 
 
-def test_enable_compile_cache_noop_without_knob(monkeypatch):
+def test_compile_cache_default_is_one_fixed_directory(
+    monkeypatch, compile_cache_config
+):
+    """Without the variable the cache is one fixed, git-ignored path
+    inside the package — the same on every call, whatever the process
+    or the time."""
+    import pathlib
+
+    import tpudl
     from tpudl.runtime import compile_cache
 
-    monkeypatch.delenv("TPUDL_COMPILE_CACHE", raising=False)
-    assert compile_cache.enable_compile_cache() is False
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    expected = str(
+        pathlib.Path(tpudl.__file__).resolve().parent / ".compile_cache"
+    )
+    assert compile_cache.enable_compile_cache() == expected
+    assert compile_cache.enable_compile_cache() == expected
+    assert jax.config.jax_compilation_cache_dir == expected
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    ignored = (
+        pathlib.Path(tpudl.__file__).resolve().parents[1] / ".gitignore"
+    ).read_text().split()
+    assert "tpudl/.compile_cache/" in ignored
+
+
+def test_device_peak_flops_unknown_device_is_an_error():
+    """A utilization over the wrong peak looks like a result: a
+    device_kind the table does not know raises; the CPU is an explicit
+    entry, not the default."""
+    from tpudl.train.metrics import PEAK_FLOPS, device_peak_flops
+
+    class _Device:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    assert device_peak_flops(_Device("TPU v5 lite")) == 197e12
+    assert device_peak_flops() == PEAK_FLOPS["cpu"]  # this run's backend
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        device_peak_flops(_Device("TPU v9 imaginary"))

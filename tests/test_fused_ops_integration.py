@@ -238,49 +238,6 @@ def test_llama_fused_auto_is_bitwise_reference_off_tpu():
     assert (z_ref == z_auto).all()
 
 
-@pytest.mark.tpu
-def test_fused_kernels_compile_on_tpu():
-    """Compiled (non-interpret) Pallas lowering sanity on real hardware
-    — the CPU tier covers numerics in interpret mode; this covers the
-    Mosaic compile path. Auto-skipped off-TPU by conftest."""
-    from tpudl.ops.cross_entropy import (
-        softmax_cross_entropy,
-        softmax_cross_entropy_ref,
-    )
-    from tpudl.ops.mlp_fused import bias_gelu, bias_gelu_ref
-    from tpudl.ops.norms import layer_norm, layer_norm_ref
-
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(64, 768)), jnp.bfloat16)
-    r = jnp.asarray(rng.normal(size=(64, 768)), jnp.bfloat16)
-    scale = jnp.ones((768,))
-    bias = jnp.zeros((768,))
-    y, s = layer_norm(x, scale, bias, r, impl="fused", interpret=False)
-    yr, _ = layer_norm_ref(x, scale, bias, r)
-    np.testing.assert_allclose(
-        np.asarray(y, np.float32), np.asarray(yr, np.float32),
-        rtol=0.05, atol=0.05,
-    )
-    np.testing.assert_allclose(
-        np.asarray(
-            bias_gelu(x, bias, impl="fused", interpret=False), np.float32
-        ),
-        np.asarray(bias_gelu_ref(x, bias), np.float32),
-        rtol=0.05, atol=0.02,
-    )
-    logits = jnp.asarray(rng.normal(size=(32, 1000)) * 3, jnp.float32)
-    labels = jnp.asarray(rng.integers(0, 1000, size=(32,)), jnp.int32)
-    np.testing.assert_allclose(
-        np.asarray(
-            softmax_cross_entropy(
-                logits, labels, impl="fused", interpret=False
-            )
-        ),
-        np.asarray(softmax_cross_entropy_ref(logits, labels)),
-        rtol=1e-4, atol=1e-4,
-    )
-
-
 def test_bert_param_tree_identical_across_modes():
     """Checkpoints/HF imports interchange between fused and composite:
     identical param paths, shapes, dtypes."""

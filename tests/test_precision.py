@@ -238,7 +238,7 @@ def test_bf16_matmuls_actually_run_bf16(runs, batches):
 
 def test_cast_params_rule_classes(runs):
     """bf16 cast rules: kernels/embeddings go bf16, norm scales and
-    biases stay f32 — the same keep taxonomy as the quantizer."""
+    biases stay f32 — the same keep classes as the quantizer."""
     pol = precision_mod.policy("bf16")
     casted = pol.cast_params(runs["legacy"]["state0"].params)
     flat = jax.tree_util.tree_flatten_with_path(casted)[0]

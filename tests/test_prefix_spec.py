@@ -534,7 +534,6 @@ def test_spec_with_prefix_share_composed(model_and_params):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.needs_jax_export
 def test_from_artifacts_paged_parity(model_and_params):
     """The paged-KV contract round-trips through StableHLO: geometry
     (page size, pool size, slots, quantization) recovered from avals
@@ -562,7 +561,6 @@ def test_from_artifacts_paged_parity(model_and_params):
         ServeSession.from_artifacts(pre, dec, params, paged=False)
 
 
-@pytest.mark.needs_jax_export
 def test_from_artifacts_paged_clamps_model_bound(model_and_params):
     """A page size that does not divide the model's compiled bound
     rounds the page span past the model's position space; the artifact

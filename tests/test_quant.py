@@ -324,7 +324,6 @@ def test_quantized_weights_compose_with_int8_kv(llama_and_params):
     )
 
 
-@pytest.mark.needs_jax_export
 def test_exported_quantized_decoder_parity(llama_and_params):
     """The quantized decoder exports through the existing StableHLO
     path (quantized leaves are plain in_tree dicts) and the

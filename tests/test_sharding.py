@@ -42,16 +42,24 @@ def test_spec_for_path_tp_rules_order():
 
 
 def test_tree_shardings_clamps_indivisible(mesh8):
-    # fsdp axis is size 2: largest dim sharded; indivisible dims -> replicated
+    # fsdp axis is size 2: largest dim sharded; an entry its own dim
+    # cannot take moves to a dim that can, and only a leaf with no such
+    # dim replicates.
     tree = {
         "a": {"kernel": jnp.zeros((8, 6))},
-        "b": {"kernel": jnp.zeros((4, 7))},  # largest dim 7 not divisible by 2
+        "b": {"kernel": jnp.zeros((4, 7))},  # largest dim 7: moves to dim 0
         "c": {"bias": jnp.zeros((6,))},
+        "d": {"kernel": jnp.zeros((3, 7))},  # nothing divisible by 2
+        # BERT's 30,522 rows have no factor 4; odd here, for fsdp=2:
+        # hidden takes the axis.
+        "word": {"embedding": jnp.zeros((30521, 768))},
     }
     sh = tree_shardings(mesh8, tree, FSDP_RULES)
     assert sh["a"]["kernel"].spec == P("fsdp", None)
-    assert sh["b"]["kernel"].spec == P(None, None)
+    assert sh["b"]["kernel"].spec == P("fsdp", None)
     assert sh["c"]["bias"].spec == P()
+    assert sh["d"]["kernel"].spec == P(None, None)
+    assert sh["word"]["embedding"].spec == P(None, "fsdp")
 
 
 def test_tree_shardings_puts_arrays(mesh8):

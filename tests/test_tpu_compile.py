@@ -1,0 +1,278 @@
+"""The main path's Pallas kernels compile for the chip — asked of the
+TPU's own compiler, with no chip attached.
+
+libtpu compiles for a DESCRIBED topology (``v5e:2x2``), so each case
+lowers a kernel at the width the models run it at (BERT-base b256 s128:
+768/3072; Llama-3.2-1B: 2048/8192, vocab 128,256, decode rows 8 and
+prefill rows 4096) and hands it to the compiler the chip uses. This is
+what interpret mode cannot show: a block the tiling refuses, a call that
+outgrows VMEM, a primitive with no Mosaic lowering. A compile that
+passes is a compile, not a chip run — ``chip_smoke.py`` is the chip run.
+
+Plus the CPU rehearsal of ``chip_smoke.py``: its phases at a tiny size,
+and its device check refusing a CPU.
+"""
+
+import functools
+import importlib.util
+import os
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+@functools.lru_cache(maxsize=1)
+def _v5e_device():
+    """One device of a described v5e host, or None where this
+    installation cannot describe it (no libtpu, or another process
+    holds it)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception:  # no TPU compiler here: nothing to ask
+        return None
+    return topo.devices[0]
+
+
+_s = jax.ShapeDtypeStruct
+bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def _grad(fn, argnums):
+    def loss(*args):
+        return sum(
+            jnp.sum(leaf.astype(jnp.float32))
+            for leaf in jax.tree.leaves(fn(*args))
+        )
+
+    return jax.grad(loss, argnums=argnums)
+
+
+def _layer_norm_residual():
+    from tpudl.ops.norms import layer_norm
+
+    x, p = _s((256, 128, 768), bf16), _s((768,), f32)
+    fn = lambda x, r, s, b: layer_norm(  # noqa: E731
+        x, s, b, r, return_sum=False, impl="fused", interpret=False
+    )
+    return _grad(fn, (0, 1, 2, 3)), (x, x, p, p)
+
+
+def _cross_entropy(shape, dtype):
+    from tpudl.ops.cross_entropy import softmax_cross_entropy
+
+    fn = lambda z, y: softmax_cross_entropy(  # noqa: E731
+        z, y, impl="fused", interpret=False
+    )
+    return _grad(fn, (0,)), (_s(shape, dtype), _s(shape[:-1], i32))
+
+
+def _bias_gelu():
+    from tpudl.ops.mlp_fused import bias_gelu
+
+    fn = lambda x, b: bias_gelu(x, b, impl="fused", interpret=False)  # noqa: E731
+    return _grad(fn, (0, 1)), (_s((256, 128, 3072), bf16), _s((3072,), f32))
+
+
+def _fused_attention():
+    from tpudl.ops.fused_attention import fused_attention
+
+    qkv = _s((256, 128, 12, 64), bf16)
+    fn = lambda q, k, v, m: fused_attention(  # noqa: E731
+        q, k, v, mask=m, interpret=False
+    )
+    return _grad(fn, (0, 1, 2)), (qkv, qkv, qkv, _s((256, 128), i32))
+
+
+def _softmax_dropout():
+    from tpudl.ops.softmax_dropout import softmax_dropout
+
+    def fn(z, m, key):
+        return softmax_dropout(
+            z, mask=m, dropout_rate=0.1, dropout_rng=key, interpret=False
+        )
+
+    return _grad(fn, (0,)), (
+        _s((256, 12, 128, 128), bf16), _s((256, 128), i32),
+        _s((), jax.random.key(0).dtype),
+    )
+
+
+def _rms_norm(rows, backward):
+    from tpudl.ops.norms import rms_norm
+
+    x = _s((*rows, 2048), bf16)
+    fn = lambda x, r, s: rms_norm(  # noqa: E731
+        x, s, r, impl="fused", interpret=False
+    )
+    return (_grad(fn, (0, 1, 2)) if backward else fn), (
+        x, x, _s((2048,), f32)
+    )
+
+
+def _swiglu(rows, backward):
+    from tpudl.ops.mlp_fused import swiglu
+
+    x = _s((*rows, 8192), bf16)
+    fn = lambda g, u: swiglu(g, u, impl="fused", interpret=False)  # noqa: E731
+    return (_grad(fn, (0, 1)) if backward else fn), (x, x)
+
+
+def _segmented_lora(pool_dtype):
+    from tpudl.ops.segmented_lora import segmented_lora
+
+    pools = {"a": _s((64, 2048), pool_dtype), "b": _s((64, 8192), pool_dtype)}
+    if pool_dtype == jnp.int8:
+        pools["a_scale"] = pools["b_scale"] = _s((64,), f32)
+    fn = lambda x, pools, t, s: segmented_lora(  # noqa: E731
+        x, pools, t, s, impl="fused", interpret=False
+    )
+    return fn, (
+        _s((8, 2048), bf16), pools, _s((8, 8), i32), _s((8,), f32),
+    )
+
+
+def _flash_attention():
+    from tpudl.ops.flash_attention import flash_attention
+
+    qkv = _s((2, 2048, 8, 64), bf16)
+    fn = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, interpret=False
+    )
+    return _grad(fn, (0, 1, 2)), (qkv, qkv, qkv)
+
+
+CASES = {
+    # BERT-base, b256 s128
+    "bert/layer_norm+residual": _layer_norm_residual,
+    "bert/cross_entropy": lambda: _cross_entropy((256, 2), f32),
+    "bert/bias_gelu": _bias_gelu,
+    "bert/fused_attention": _fused_attention,
+    "bert/softmax_dropout": _softmax_dropout,
+    # Llama-3.2-1B
+    "llama/rms_norm-decode": lambda: _rms_norm((8, 1), False),
+    "llama/rms_norm-prefill": lambda: _rms_norm((1, 4096), True),
+    "llama/swiglu-decode": lambda: _swiglu((8, 1), False),
+    "llama/swiglu-prefill": lambda: _swiglu((1, 4096), True),
+    "llama/cross_entropy": lambda: _cross_entropy((4, 512, 128256), bf16),
+    "llama/segmented_lora-f32": lambda: _segmented_lora(f32),
+    "llama/segmented_lora-int8": lambda: _segmented_lora(jnp.int8),
+    "llama/flash_attention": _flash_attention,
+}
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache without one (the next run would warn): off around
+    these, whatever the session set."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, no_compile_cache):
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    fn, specs = CASES[name]()
+    on_chip = SingleDeviceSharding(device)
+    args = jax.tree.map(
+        lambda spec: _s(spec.shape, spec.dtype, sharding=on_chip), specs
+    )
+    compiled = jax.jit(fn).lower(*args).compile()
+    # The kernel is IN the program: neither interpreted nor swapped
+    # for the composite.
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py on the CPU: the phases at a tiny size, through a path
+# only the tests take — the script's own device check is not weakened.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py"
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+TINY_SERVE = dict(
+    size="llama-tiny", dtype=jnp.float32, prompt_len=8, max_seq_len=64,
+    num_slots=4, max_new=9,
+)
+TINY_TRAIN = dict(model="bert-tiny", seq_len=16)
+
+
+def test_chip_smoke_refuses_cpu(chip_smoke, capsys):
+    with pytest.raises(SystemExit) as exit_info:
+        chip_smoke.main([])
+    assert exit_info.value.code not in (0, None)
+    assert capsys.readouterr().out == ""  # no phase ran, no result line
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_chip_smoke_serve_phase_tiny(chip_smoke, paged):
+    line = chip_smoke.serve_phase(
+        0, paged=paged, requests_per_wave=4, **TINY_SERVE
+    )
+    # f32 on one backend: every request agrees token for token.
+    assert line["requests"] == 8 and line["requests_equal_generate"] == 8
+    assert line["recompiles_after_warmup"] == 0
+
+
+def test_chip_smoke_train_phase_tiny(chip_smoke):
+    line = chip_smoke.train_phase(
+        0, batch=32, warmup=2, steps=22,
+        optim={"learning_rate": 3e-3, "warmup_steps": 0}, **TINY_TRAIN,
+    )
+    assert line["steps"] == 24 and line["last_loss"] < line["first_loss"]
+    assert line["recompiles_after_warmup"] == 0
+
+
+def test_chip_smoke_mesh_phases_tiny(chip_smoke):
+    """``--chips 4`` on four of the forced host devices."""
+    serve = chip_smoke.mesh_serve_phase(0, n_requests=4, **TINY_SERVE)
+    assert serve["requests_equal_one_device"] == 4
+    assert serve["requests_equal_generate"] == 4
+    assert len(set(serve["param_bytes_per_device"])) == 1
+    train = chip_smoke.mesh_train_phase(0, batch=8, **TINY_TRAIN)
+    whole = train["one_device"]["state_bytes_per_device"][0]
+    assert max(train["fsdp4"]["state_bytes_per_device"]) < whole / 3
+    assert train["fsdp4"]["max_abs_loss_diff"] <= chip_smoke.MESH_LOSS_TOL
+
+
+def test_chip_smoke_phase_failure_is_nonzero(chip_smoke, monkeypatch):
+    """A phase that raises ends the script: nothing catches it and
+    nothing prints ``ok``."""
+    monkeypatch.setattr(
+        chip_smoke, "require_tpu",
+        lambda chips: {"platform": "tpu", "kind": "test", "count": 1},
+    )
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("phase failed")
+
+    monkeypatch.setattr(chip_smoke, "serve_phase", boom)
+    with pytest.raises(RuntimeError, match="phase failed"):
+        chip_smoke.main([])

@@ -44,6 +44,7 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _TRANSFER_KINDS = ("h2d", "d2h", "d2d")
 
 _compiles = 0
+_compile_seconds = 0.0
 _compiles_mu = threading.Lock()
 _listener_installed = False
 _install_mu = threading.Lock()
@@ -54,10 +55,11 @@ class DispatchHygieneError(AssertionError):
 
 
 def _on_duration_event(event: str, duration: float, **kwargs) -> None:
-    global _compiles
+    global _compiles, _compile_seconds
     if event == _COMPILE_EVENT:
         with _compiles_mu:
             _compiles += 1
+            _compile_seconds += duration
 
 
 def _ensure_listener() -> None:
@@ -81,6 +83,14 @@ def compile_count() -> int:
     _ensure_listener()
     with _compiles_mu:
         return _compiles
+
+
+def compile_seconds() -> float:
+    """Seconds spent in backend compiles since the listener installed
+    (monotonic, like :func:`compile_count`)."""
+    _ensure_listener()
+    with _compiles_mu:
+        return _compile_seconds
 
 
 class RecompileWatcher:

@@ -131,9 +131,6 @@ _declare("TPUDL_OVERLAP_BUCKET_MB", "float", None,
          "disables bucketing; unset = auto (4 MiB buckets on "
          "multi-shard meshes).",
          "tpudl.parallel.overlap")
-_declare("TPUDL_COMPILE_CACHE", "path", None,
-         "Persistent XLA compile-cache directory; unset = off.",
-         "tpudl.runtime.compile_cache")
 _declare("TPUDL_NORM_BLOCK_ROWS", "int", None,
          "Row-block override for the fused norm/MLP-epilogue Pallas "
          "kernels (benchmarks/fused_epilogue.py --sweep-blocks prints "
@@ -357,9 +354,6 @@ _declare("TPUDL_NUM_PROCESSES", "int", None,
          "tpudl.runtime.distributor", internal=True)
 _declare("TPUDL_PROCESS_ID", "int", 0,
          "This worker's rank (also tags span streams).",
-         "tpudl.runtime.distributor", internal=True)
-_declare("TPUDL_PLATFORM", "str", None,
-         "Backend platform override for spawned workers (cpu/tpu).",
          "tpudl.runtime.distributor", internal=True)
 
 

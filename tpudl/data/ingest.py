@@ -39,7 +39,7 @@ from tpudl.obs import counters as obs_counters
 from tpudl.obs import spans as obs_spans
 
 #: Obs span category for ingest chunks (outside the goodput step/compile
-#: taxonomy on purpose — ingest is a materialize-once cost, reported in
+#: categories on purpose — ingest is a materialize-once cost, reported in
 #: the breakdown table's extra rows, not against training goodput).
 _INGEST_CAT = "ingest"
 

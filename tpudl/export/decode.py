@@ -186,7 +186,7 @@ def generate_with_exported(
     sync, so it runs after the first token (catching the
     finished-at-token-1 batch for free) and then once per
     ``eos_check_every`` tokens — NOT per token, which would serialize
-    the otherwise-async decode dispatches on relay-attached devices.
+    the otherwise-async decode dispatches.
     """
     b, s = input_ids.shape
     if eos_check_every < 1:

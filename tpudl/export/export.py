@@ -15,31 +15,7 @@ import os
 from typing import Any, Callable, Optional, Sequence, Union
 
 import jax
-
-# jax.export is the one dependency of this module that moves between
-# jax releases; import-gate it so environments without it can still
-# import the package (tests/conftest.py auto-skips export-path tests
-# and benchmarks/parity_grid.py skips its exported-backend cells off
-# EXPORT_AVAILABLE instead of erroring at collection/import).
-try:
-    from jax import export as jax_export
-
-    EXPORT_AVAILABLE = True
-    _EXPORT_IMPORT_ERROR: Optional[BaseException] = None
-except Exception as _e:  # pragma: no cover - version-dependent
-    jax_export = None
-    EXPORT_AVAILABLE = False
-    _EXPORT_IMPORT_ERROR = _e
-
-
-def _require_export() -> None:
-    if not EXPORT_AVAILABLE:
-        raise RuntimeError(
-            f"jax.export is unavailable in this jax build "
-            f"({type(_EXPORT_IMPORT_ERROR).__name__}: "
-            f"{_EXPORT_IMPORT_ERROR}) — StableHLO export/deserialize "
-            f"paths cannot run"
-        )
+from jax import export as jax_export
 
 
 def export_stablehlo(
@@ -54,7 +30,6 @@ def export_stablehlo(
     artifact — the single-artifact-many-backends property the reference gets
     from ONNX.
     """
-    _require_export()
     jitted = jax.jit(fn)
     if platforms:
         exported = jax_export.export(jitted, platforms=tuple(platforms))(*args)
@@ -73,7 +48,6 @@ def load_exported_obj(blob_or_path: Union[bytes, str]) -> "jax_export.Exported":
     ``.in_tree`` (how a serving runtime recovers the compiled shapes —
     slot count, prompt window, cache bound — from the artifact alone;
     see tpudl.serve.api.ServeSession.from_artifacts)."""
-    _require_export()
     if isinstance(blob_or_path, str):
         with open(blob_or_path, "rb") as f:
             blob = f.read()

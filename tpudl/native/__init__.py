@@ -8,13 +8,16 @@ which falls back to a numpy implementation equal to f32 rounding when no
 C++ toolchain is available — the native layer accelerates, never
 changes, training.
 
-Build: `make -C tpudl/native`, or `load_library()` builds lazily with g++
-on first use (cached as libtpudl_data.so next to the sources).
+Build: `load_library()` builds with g++ on first use, next to the
+sources, under a name that carries the hash of ``augment.cpp`` — so the
+library loaded is always the one built from the source as it stands,
+whatever a copy of the tree did to file times (`*.so` is git-ignored).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,13 +26,21 @@ from typing import Optional
 
 _log = logging.getLogger("tpudl.native")
 _dir = os.path.dirname(os.path.abspath(__file__))
-_so_path = os.path.join(_dir, "libtpudl_data.so")
+_src_path = os.path.join(_dir, "augment.cpp")
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None | bool" = None  # None = untried, False = failed
 
 
-def _build() -> bool:
-    src = os.path.join(_dir, "augment.cpp")
+def _so_path() -> str:
+    with open(_src_path, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_dir, f"libtpudl_data.{digest}.so")
+
+
+def _build(so_path: str) -> bool:
+    # Build beside the target and rename: a second process loading at
+    # the same moment sees the whole library or none.
+    tmp_path = f"{so_path}.{os.getpid()}.tmp"
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O3",
@@ -37,13 +48,14 @@ def _build() -> bool:
         "-fopenmp",
         "-shared",
         "-o",
-        _so_path,
-        src,
+        tmp_path,
+        _src_path,
     ]
     try:
         subprocess.run(
             cmd, check=True, capture_output=True, text=True, timeout=120
         )
+        os.replace(tmp_path, so_path)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         detail = getattr(e, "stderr", "") or str(e)
@@ -52,25 +64,23 @@ def _build() -> bool:
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    """The native kernel library, building it if needed. None when neither
-    a prebuilt .so nor a working compiler is available (callers fall back
-    to numpy)."""
+    """The native kernel library, building it if needed. None when no
+    library built from the current source exists and no compiler works
+    (callers fall back to numpy; ``BatchAugmenter.backend`` says which
+    ran)."""
     global _lib
     with _lock:
         if _lib is None:
-            src = os.path.join(_dir, "augment.cpp")
-            stale = os.path.exists(_so_path) and os.path.getmtime(
-                _so_path
-            ) < os.path.getmtime(src)
-            if (not os.path.exists(_so_path) or stale) and not _build():
+            so_path = _so_path()
+            if not os.path.exists(so_path) and not _build(so_path):
                 _lib = False
             else:
                 try:
-                    lib = ctypes.CDLL(_so_path)
+                    lib = ctypes.CDLL(so_path)
                     _configure(lib)
                     _lib = lib
                 except OSError as e:
-                    _log.warning("failed to load %s: %s", _so_path, e)
+                    _log.warning("failed to load %s: %s", so_path, e)
                     _lib = False
         return _lib or None
 

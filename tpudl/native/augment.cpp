@@ -12,7 +12,7 @@
 // never change training.
 //
 // Built by tpudl/native/__init__.py with `g++ -O3 -fopenmp -shared
-// -fPIC` (see Makefile); loaded via ctypes.
+// -fPIC`; loaded via ctypes.
 
 #include <cstdint>
 

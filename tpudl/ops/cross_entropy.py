@@ -39,7 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE
 from tpudl.ops.norms import resolve_impl
-from tpudl.ops.pallas_utils import COMPILER_PARAMS, round_up
+from tpudl.ops.pallas_utils import round_up
 
 
 #: Override for the vocab-block cap below (None = the 1024 default).
@@ -176,7 +176,7 @@ def _xent_fwd_call(logits, labels, smoothing, interpret):
             has_pad=v_pad != v,
         ),
         grid=grid,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         in_specs=[
@@ -212,7 +212,7 @@ def _xent_bwd_call(logits, labels, lse, g, smoothing, interpret):
             has_pad=v_pad != v,
         ),
         grid=(b_pad // bb, v_pad // bv),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         in_specs=[

@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE
-from tpudl.ops.pallas_utils import COMPILER_PARAMS
 
 #: Default tile sizes; VPU/MXU-aligned (multiples of the f32 (8,128) tile).
 #: Swept on TPU v5 lite at seq 4096 (2026-07-30): large kv tiles keep the
@@ -227,7 +226,7 @@ def _fwd(q, k, v, kvmask, seed, causal, scale, block_q, block_k, interpret,
             causal_offset=skv - sq, has_kvmask=has_kvmask, rate=rate,
         ),
         grid=grid,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_DIM_SEMANTICS
         ),
         in_specs=[
@@ -426,7 +425,7 @@ def _bwd_core(causal, scale, block_q, block_k, interpret, has_mask, rate,
     sq, skv = g.shape[1], kvmask.shape[1]
     bq, bk = _block_sizes(sq, skv, block_q, block_k)
     has_kvmask = bool(has_mask) or skv_p != skv
-    dim_sem = COMPILER_PARAMS(dimension_semantics=_DIM_SEMANTICS)
+    dim_sem = pltpu.CompilerParams(dimension_semantics=_DIM_SEMANTICS)
 
     do = jnp.pad(
         g.astype(qt.dtype).transpose(0, 2, 1, 3),

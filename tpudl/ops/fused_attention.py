@@ -53,9 +53,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE
 from tpudl.ops.pallas_utils import (
-    COMPILER_PARAMS,
     flat_cell_id,
     keep_mask,
+    kv_valid,
     round_up as _round_up,
     seed_cell,
 )
@@ -82,7 +82,7 @@ def _kernel_body(
 
     seq = s.shape[0]
     if has_kvmask:
-        s = jnp.where((kvm_ref[0, 0, :] > 0.0)[None, :], s, MASK_VALUE)
+        s = jnp.where(kv_valid(kvm_ref, s.shape), s, MASK_VALUE)
     if causal:
         q_ids = jax.lax.broadcasted_iota(jnp.int32, (seq, seq), 0)
         kv_ids = jax.lax.broadcasted_iota(jnp.int32, (seq, seq), 1)
@@ -221,7 +221,7 @@ def _fused_fwd(q, k, v, kvmask, seed, causal, scale, rate, group, interpret,
             head_dim=d, group=group, has_kvmask=has_kvmask,
         ),
         grid=grid,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         in_specs=[seed_spec, row, row, row, kvm_spec],
@@ -251,7 +251,7 @@ def _fused_bwd(causal, scale, rate, group, interpret, has_mask, res, g_out):
             head_dim=d, group=group, has_kvmask=has_kvmask,
         ),
         grid=grid,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         in_specs=[seed_spec, row, row, row, kvm_spec, row],

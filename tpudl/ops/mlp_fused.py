@@ -35,21 +35,49 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.norms import resolve_impl, _grid_setup
-from tpudl.ops.pallas_utils import COMPILER_PARAMS
 
 _INV_SQRT2 = 1.0 / math.sqrt(2.0)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 
 
+#: Rational approximation of erf on [-4, 4] (numerator odd in x,
+#: denominator even; highest power first) — the f32 form XLA itself
+#: expands ``erf`` to, so the kernel agrees with ``jax.lax.erf`` to
+#: ~5e-7. Spelled out because Pallas TPU has no lowering for the erf
+#: primitive; multiply, add and divide all lower.
+_ERF_ALPHA = (
+    -2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+    -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+    -1.60960333262415e-02,
+)
+_ERF_BETA = (
+    -1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+    -7.37332916720468e-03, -1.42647390514189e-02,
+)
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    return x * _horner(x2, _ERF_ALPHA) / _horner(x2, _ERF_BETA)
+
+
 def _gelu_exact(u):
     """Exact (erf) GeLU in f32 — matches jax.nn.gelu(approximate=False)."""
-    return u * 0.5 * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    return u * 0.5 * (1.0 + _erf(u * _INV_SQRT2))
 
 
 def _gelu_grad(u):
     """d/du gelu_exact(u) = Phi(u) + u * phi(u)."""
     phi = jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
-    return 0.5 * (1.0 + jax.lax.erf(u * _INV_SQRT2)) + u * phi
+    return 0.5 * (1.0 + _erf(u * _INV_SQRT2)) + u * phi
 
 
 # ---------------------------------------------------------------------------
@@ -82,8 +110,11 @@ def _bg_bwd_kernel(x_ref, b_ref, g_ref, dx_ref, db_ref, db_scr):
 def _bg_call(x2, bias, g2, interpret):
     """Shared pallas_call builder: forward when g2 is None, else backward."""
     n, f = x2.shape
+    # The backward evaluates both erf polynomials and the gaussian on
+    # the block at once: about twice the forward's live f32 values.
     xp, extras, bn, n_pad, f_pad = _grid_setup(
-        x2, [g2] if g2 is not None else []
+        x2, [g2] if g2 is not None else [], outputs=1,
+        f32_temps=3 if g2 is None else 6,
     )
     bp = jnp.pad(bias.astype(jnp.float32), (0, f_pad - f))[None, :]
     row = pl.BlockSpec((bn, f_pad), lambda i: (i, 0),
@@ -94,7 +125,7 @@ def _bg_call(x2, bias, g2, interpret):
         y = pl.pallas_call(
             _bg_fwd_kernel,
             grid=(n_pad // bn,),
-            compiler_params=COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
             ),
             in_specs=[row, par],
@@ -108,7 +139,7 @@ def _bg_call(x2, bias, g2, interpret):
     dx, db = pl.pallas_call(
         _bg_bwd_kernel,
         grid=(n_pad // bn,),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         in_specs=[row, par, row],
@@ -188,11 +219,12 @@ def _sw_bwd_kernel(g_ref, u_ref, go_ref, dg_ref, du_ref):
 def _sw_call(g2, u2, go2, interpret):
     n, f = g2.shape
     gp, extras, bn, n_pad, f_pad = _grid_setup(
-        g2, [u2] + ([go2] if go2 is not None else [])
+        g2, [u2] + ([go2] if go2 is not None else []),
+        outputs=1 if go2 is None else 2, f32_temps=3,
     )
     row = pl.BlockSpec((bn, f_pad), lambda i: (i, 0),
                        memory_space=pltpu.VMEM)
-    sem = COMPILER_PARAMS(dimension_semantics=("parallel",))
+    sem = pltpu.CompilerParams(dimension_semantics=("parallel",))
     if go2 is None:
         y = pl.pallas_call(
             _sw_fwd_kernel,

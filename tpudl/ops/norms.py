@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpudl.ops.pallas_utils import COMPILER_PARAMS, round_up
+from tpudl.ops.pallas_utils import round_up
 
 
 def resolve_impl(impl: str, interpret: Optional[bool]):
@@ -84,9 +84,21 @@ def fused_ops_impl(flag) -> str:
 BLOCK_ROWS_OVERRIDE: Optional[int] = None
 
 
-def _block_rows(n: int, h_pad: int, itemsize: int) -> int:
+#: What a row-blocked call may hold in VMEM: the compiler's default
+#: scoped limit on v5e is 16 MiB; the remainder is headroom for the
+#: parameter rows, statistics columns and reduction scratch.
+_VMEM_BUDGET = 14 << 20
+
+
+def _block_rows(n: int, h_pad: int, itemsize: int, row_blocks: int,
+                f32_temps: int) -> int:
     """Row-block height: sublane-aligned (16 covers bf16's min tile),
-    capped so one (rows, h_pad) block stays ~1 MB."""
+    capped so one (rows, h_pad) block stays ~1 MB AND so everything the
+    call holds fits ``_VMEM_BUDGET`` — ``row_blocks`` row-shaped
+    inputs/outputs, each double-buffered by the pipeline, plus
+    ``f32_temps`` full-block f32 values live in the kernel body. The
+    second cap is what binds at wide rows (the SwiGLU backward at 8,192
+    columns holds five streams: the 1 MB rule alone asks for 17 MB)."""
     override = BLOCK_ROWS_OVERRIDE
     if override is None:
         from tpudl.analysis.registry import env_int
@@ -99,15 +111,20 @@ def _block_rows(n: int, h_pad: int, itemsize: int) -> int:
             )
         return min(round_up(override, 16), round_up(n, 16))
     cap = max(16, ((1 << 20) // max(h_pad * itemsize, 1)) // 16 * 16)
-    return min(256, cap, round_up(n, 16))
+    per_row = h_pad * (2 * row_blocks * itemsize + 4 * f32_temps)
+    fit = max(16, (_VMEM_BUDGET // per_row) // 16 * 16)
+    return min(256, cap, fit, round_up(n, 16))
 
 
-def _grid_setup(x2, others):
+def _grid_setup(x2, others, *, outputs: int, f32_temps: int):
     """Pad [N, H] operands to (N_pad, H_pad) tile multiples; returns the
-    padded arrays plus (bn, n_pad, h_pad)."""
+    padded arrays plus (bn, n_pad, h_pad). ``outputs`` counts the call's
+    row-shaped outputs and ``f32_temps`` its live f32 temporaries — with
+    the inputs, what ``_block_rows`` sizes the block against."""
     n, h = x2.shape
     h_pad = round_up(h, 128)
-    bn = _block_rows(n, h_pad, x2.dtype.itemsize)
+    bn = _block_rows(n, h_pad, x2.dtype.itemsize,
+                     1 + len(others) + outputs, f32_temps)
     n_pad = round_up(n, bn)
     def pad(a):
         return jnp.pad(a, ((0, n_pad - a.shape[0]), (0, h_pad - a.shape[1])))
@@ -171,7 +188,8 @@ def _norm_fwd(x2, scale, bias, res2, *, kind, eps, interpret,
     has_res = res2 is not None
     emit_sum = has_res and emit_sum
     xp, extras, bn, n_pad, h_pad = _grid_setup(
-        x2, [res2] if has_res else []
+        x2, [res2] if has_res else [], outputs=1 + int(emit_sum),
+        f32_temps=2,
     )
     grid = (n_pad // bn,)
     row = pl.BlockSpec((bn, h_pad), lambda i: (i, 0),
@@ -200,7 +218,7 @@ def _norm_fwd(x2, scale, bias, res2, *, kind, eps, interpret,
         functools.partial(_norm_fwd_kernel, kind=kind, has_res=has_res,
                           emit_sum=emit_sum, eps=eps, h=float(h)),
         grid=grid,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         in_specs=in_specs,
@@ -289,7 +307,9 @@ def _norm_bwd(x2, scale, res2, mean, rstd, g2, gs2, *, kind, interpret):
     has_res = res2 is not None
     has_gs = gs2 is not None
     extras = ([res2] if has_res else []) + [g2] + ([gs2] if has_gs else [])
-    xp, extras, bn, n_pad, h_pad = _grid_setup(x2, extras)
+    xp, extras, bn, n_pad, h_pad = _grid_setup(
+        x2, extras, outputs=1, f32_temps=2
+    )
     it = iter(extras)
     rp = next(it) if has_res else None
     gp = next(it)
@@ -336,7 +356,7 @@ def _norm_bwd(x2, scale, res2, mean, rstd, g2, gs2, *, kind, interpret):
         functools.partial(_norm_bwd_kernel, kind=kind, has_res=has_res,
                           has_gs=has_gs, h=float(h)),
         grid=(n_pad // bn,),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         in_specs=in_specs,

@@ -18,11 +18,14 @@ def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-#: jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (~0.5);
-#: resolve whichever this jax ships so the kernels run on both.
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+def kv_valid(kvm_ref, shape):
+    """Boolean ``shape`` = [Sq, Skv] tile from a (1, 1, Skv) f32
+    kv-validity block (> 0 = attend). The f32 row is broadcast first
+    and compared after: a [1, Skv] PREDICATE broadcast along sublanes
+    costs Mosaic minutes of compile and, at Skv = 512, the scoped VMEM
+    (the compiler refused the kernel); the f32 sublane broadcast is
+    free."""
+    return jnp.broadcast_to(kvm_ref[0, 0:1, :], shape) > 0.0
 
 
 def seed_cell(seed_ref, cell) -> None:

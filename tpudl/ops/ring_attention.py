@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpudl.ops.attention import MASK_VALUE
-from tpudl.runtime.mesh import AXIS_SEQ, BATCH_AXES, AXIS_TENSOR, shard_map
+from tpudl.runtime.mesh import AXIS_SEQ, BATCH_AXES, AXIS_TENSOR
 
 
 def _ring_local(q, k, v, kvm, key_data=None, *, axis_name, scale, causal,
@@ -322,7 +322,7 @@ def ring_attention(
             # key_data is positional after kvm in the body signature.
             inner = body
             body = lambda q_, k_, v_, kd_: inner(q_, k_, v_, None, kd_)  # noqa: E731
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),

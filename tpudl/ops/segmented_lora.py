@@ -51,7 +51,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.norms import resolve_impl
-from tpudl.ops.pallas_utils import COMPILER_PARAMS, round_up
+from tpudl.ops.pallas_utils import round_up
+
+#: Rows of an int8 array in one (32, 128) TPU tile.
+_INT8_ROWS = 32
 
 
 def _as_3d(x):
@@ -98,23 +101,40 @@ def _seg_lora_kernel(
     """One slot: gather its pages and accumulate ``pages`` rank-1
     updates in f32. The page loop is a static unroll (r_max is small —
     it is the rank budget, not the batch); page 0 rows are all-zero by
-    the pool contract, so short ranks and empty slots fall out free."""
+    the pool contract, so short ranks and empty slots fall out free.
+    The table and the scales sit whole in SMEM; the slot indexes them."""
     if quantized:
         asc_ref, bsc_ref, out_ref = rest
     else:
         (out_ref,) = rest
+    slot = pl.program_id(0)
     x = x_ref[0].astype(jnp.float32)  # [S_pad, H_pad]
     acc = jnp.zeros(out_ref.shape[1:], jnp.float32)  # [S_pad, O_pad]
     for j in range(pages):
-        page = t_ref[0, j]
-        a_row = a_ref[page, :].astype(jnp.float32)  # [H_pad]
-        b_row = b_ref[page, :].astype(jnp.float32)  # [O_pad]
+        page = t_ref[slot, j]
+        a_row = _pool_row(a_ref, page, quantized)  # [1, H_pad]
+        b_row = _pool_row(b_ref, page, quantized)  # [1, O_pad]
         if quantized:
-            a_row = a_row * asc_ref[page, 0]
-            b_row = b_row * bsc_ref[page, 0]
-        coef = jnp.sum(x * a_row[None, :], axis=-1, keepdims=True)
-        acc = acc + coef * b_row[None, :]
-    out_ref[0] = (acc * sc_ref[0, 0]).astype(out_ref.dtype)
+            a_row = a_row * asc_ref[page]
+            b_row = b_row * bsc_ref[page]
+        coef = jnp.sum(x * a_row, axis=-1, keepdims=True)
+        acc = acc + coef * b_row
+    out_ref[0] = (acc * sc_ref[slot]).astype(out_ref.dtype)
+
+
+def _pool_row(pool_ref, page, quantized: bool):
+    """Row ``page`` of a VMEM-resident pool as f32 ``[1, W]``. A 32-bit
+    pool reads the row at its dynamic sublane offset. An int8 pool
+    packs 32 rows to a tile and Mosaic takes no dynamic offset inside
+    one, so it loads the aligned 32-row tile and selects the row."""
+    if not quantized:
+        return pool_ref[pl.ds(page, 1), :].astype(jnp.float32)
+    base = pl.multiple_of((page // _INT8_ROWS) * _INT8_ROWS, _INT8_ROWS)
+    tile = pool_ref[pl.ds(base, _INT8_ROWS), :].astype(jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    return jnp.sum(
+        jnp.where(rows == page - base, tile, 0.0), axis=0, keepdims=True
+    )
 
 
 def _pad_rows(arr, rows: int, cols: Optional[int] = None):
@@ -142,7 +162,7 @@ def segmented_lora_fused(x, pools, table, scale, interpret: bool):
     o_pad = round_up(o, 128)
     s_pad = round_up(s, 8)
     # int8 pools tile at (32, 128); f32 at (8, 128).
-    np_pad = round_up(np_rows, 32 if quantized else 8)
+    np_pad = round_up(np_rows, _INT8_ROWS if quantized else 8)
 
     xp = jnp.pad(x3, ((0, 0), (0, s_pad - s), (0, h_pad - h)))
     ap = _pad_rows(pools["a"], np_pad, h_pad)
@@ -157,29 +177,24 @@ def segmented_lora_fused(x, pools, table, scale, interpret: bool):
     pool_b_spec = pl.BlockSpec(
         (np_pad, o_pad), lambda i: (0, 0), memory_space=pltpu.VMEM
     )
-    t_spec = pl.BlockSpec(
-        (1, pages), lambda i: (i, 0), memory_space=pltpu.SMEM
-    )
-    sc_spec = pl.BlockSpec(
-        (1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM
-    )
-    in_specs = [x_spec, pool_a_spec, pool_b_spec, t_spec, sc_spec]
-    args = [xp, ap, bp, table, scale[:, None]]
+    # Scalars ride whole in SMEM (no block shape): a (1, pages) block of
+    # the table is neither (8, 128)-aligned nor the whole array, which
+    # the TPU lowering refuses.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [x_spec, pool_a_spec, pool_b_spec, smem, smem]
+    args = [xp, ap, bp, table, scale]
     if quantized:
-        page_sc_spec = pl.BlockSpec(
-            (np_pad, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
-        )
-        in_specs += [page_sc_spec, page_sc_spec]
+        in_specs += [smem, smem]
         args += [
-            _pad_rows(pools["a_scale"][:, None], np_pad),
-            _pad_rows(pools["b_scale"][:, None], np_pad),
+            _pad_rows(pools["a_scale"], np_pad),
+            _pad_rows(pools["b_scale"], np_pad),
         ]
     out = pl.pallas_call(
         functools.partial(
             _seg_lora_kernel, pages=pages, quantized=quantized
         ),
         grid=(b_dim,),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         in_specs=in_specs,

@@ -40,9 +40,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE
 from tpudl.ops.pallas_utils import (
-    COMPILER_PARAMS,
     flat_cell_id,
     keep_mask as _keep_mask_impl,
+    kv_valid,
     round_up as _round_up,
     seed_cell,
 )
@@ -61,7 +61,7 @@ def _masked_softmax(s, kvm_ref, *, causal, q_off, block_q, has_kvmask):
     rows, skv = s.shape
     masked = has_kvmask or causal
     if has_kvmask:
-        s = jnp.where((kvm_ref[0, 0, :] > 0.0)[None, :], s, MASK_VALUE)
+        s = jnp.where(kv_valid(kvm_ref, s.shape), s, MASK_VALUE)
     if causal:
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, skv), 0)
         q_ids = q_off + jax.lax.rem(row_ids, block_q)
@@ -141,7 +141,7 @@ def _specs(b, h, sq_p, skv_p, block_q, group):
                        memory_space=pltpu.VMEM)
     seed = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid = (b, h // group, sq_p // block_q)
-    sem = COMPILER_PARAMS(
+    sem = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel")
     )
     return grid, seed, tile, kvm, sem

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudl.runtime.mesh import AXIS_SEQ, BATCH_AXES, AXIS_TENSOR, shard_map
+from tpudl.runtime.mesh import AXIS_SEQ, BATCH_AXES, AXIS_TENSOR
 
 
 def _ulysses_local(q, k, v, kvm=None, key_data=None, *, axis_name, causal,
@@ -223,7 +223,7 @@ def ulysses_attention(
             # wrap the ONE bound partial rather than rebuilding it.
             inner = body
             body = lambda q_, k_, v_, kd_: inner(q_, k_, v_, None, kd_)  # noqa: E731
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),

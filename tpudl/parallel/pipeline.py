@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudl.runtime.mesh import AXIS_PIPE, shard_map
+from tpudl.runtime.mesh import AXIS_PIPE
 
 def stage_param_spec(ndim: int, axis_name: str = AXIS_PIPE) -> P:
     """PartitionSpec for one stacked stage param: leading (stage) dim over
@@ -380,7 +380,7 @@ def pipeline(
             lambda p: stage_param_spec(p.ndim, axis_name), stacked_params
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _pipeline_local,
             stage_fn=stage_fn,
@@ -629,7 +629,7 @@ def pipeline_1f1b(
     data_specs = jax.tree.map(lambda a: P(*([None] * a.ndim)), xm)
     tgt_specs = jax.tree.map(lambda a: P(*([None] * a.ndim)), tm)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _1f1b_local,
             stage_fn=stage_fn,
@@ -825,7 +825,7 @@ def pipeline_interleaved(
         lambda p: stage_param_spec(p.ndim, axis_name), stacked_params
     )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _pipeline_local_interleaved,
             stage_fn=stage_fn,

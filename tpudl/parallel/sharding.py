@@ -49,21 +49,50 @@ def spec_for_path(
     return spec(shape) if callable(spec) else spec
 
 
-def _clamp_entries(mesh: Mesh, spec: PartitionSpec, shape) -> PartitionSpec:
+def _axes_size(mesh: Mesh, entry) -> int:
+    names = entry if isinstance(entry, tuple) else (entry,)
+    size = 1
+    for n in names:
+        size *= mesh.shape[n]
+    return size
+
+
+def _clamp_entries(
+    mesh: Mesh, spec: PartitionSpec, shape, relocate: bool = False
+) -> PartitionSpec:
     """Truncate a spec to the array rank and unshard any dimension whose size
     the named mesh axes don't divide — keeps one rule list usable across
-    full-size and tiny-test configurations."""
+    full-size and tiny-test configurations.
+
+    ``relocate`` (parameter placement): an entry its own dimension
+    cannot take moves to the largest unsharded dimension the axes DO
+    divide, and is dropped only when there is none. BERT's 30,522-row
+    word embedding has no factor 4: under fsdp=4 it shards along the
+    hidden dimension instead of sitting whole on every device."""
     entries = list(spec)[: len(shape)]
-    fixed = []
-    for dim, entry in enumerate(entries):
-        if entry is None:
-            fixed.append(None)
-            continue
-        names = entry if isinstance(entry, tuple) else (entry,)
-        size = 1
-        for n in names:
-            size *= mesh.shape[n]
-        fixed.append(entry if shape[dim] % size == 0 else None)
+    spec_rank = len(entries)
+    entries += [None] * (len(shape) - spec_rank)
+    fixed = [
+        entry
+        if entry is None or shape[dim] % _axes_size(mesh, entry) == 0
+        else None
+        for dim, entry in enumerate(entries)
+    ]
+    if relocate:
+        for dim, entry in enumerate(entries):
+            if entry is None or fixed[dim] is not None:
+                continue
+            size = _axes_size(mesh, entry)
+            homes = [
+                d for d in range(len(shape))
+                if entries[d] is None and fixed[d] is None
+                and shape[d] % size == 0
+            ]
+            if homes and size > 1:
+                fixed[max(homes, key=lambda d: shape[d])] = entry
+    # Same length as the rule's own spec unless an entry moved past it.
+    while len(fixed) > spec_rank and fixed[-1] is None:
+        fixed.pop()
     return P(*fixed)
 
 
@@ -76,7 +105,9 @@ def tree_shardings(
     def one(path, leaf):
         shape = getattr(leaf, "shape", ())
         spec = spec_for_path(_path_str(path), rules, shape)
-        return NamedSharding(mesh, _clamp_entries(mesh, spec, shape))
+        return NamedSharding(
+            mesh, _clamp_entries(mesh, spec, shape, relocate=True)
+        )
 
     return jax.tree_util.tree_map_with_path(one, tree)
 

@@ -11,14 +11,13 @@ from tpudl.runtime.mesh import (  # noqa: F401
     AXIS_TENSOR,
     MESH_AXES,
     MeshSpec,
-    apply_platform_env,
     batch_partition_spec,
     make_mesh,
     window_partition_spec,
 )
 from tpudl.runtime.rng import use_hardware_rng  # noqa: F401
 
-# Honor TPUDL_COMPILE_CACHE at import — before the first jit compiles —
-# so every entrypoint that touches the runtime gets the persistent
-# cache without its own plumbing. No-op when the knob is unset.
+# Place the persistent compile cache at import — before the first jit
+# compiles — so every entrypoint that touches the runtime gets it
+# without its own plumbing (JAX_COMPILATION_CACHE_DIR moves it).
 enable_compile_cache()

@@ -1,7 +1,8 @@
 """Subprocess entry point for TpuDistributor local spawn.
 
-Reads TPUDL_* env (coordinator, process count/id, platform), brings up
-jax.distributed against the coordinator, runs the pickled payload, and
+Reads TPUDL_* env (coordinator, process count/id; the distributor also
+sets JAX_PLATFORMS for it), brings up jax.distributed against the
+coordinator, runs the pickled payload, and
 writes ("ok", result) or ("error", traceback) to the result path.
 """
 
@@ -20,11 +21,9 @@ def main() -> int:
     coord = env_require("TPUDL_COORDINATOR")
     nproc = env_int("TPUDL_NUM_PROCESSES", required=True)
     pid = env_int("TPUDL_PROCESS_ID", required=True)
-    platform = env_str("TPUDL_PLATFORM", "cpu")
 
     import jax
 
-    jax.config.update("jax_platforms", platform)
     jax.distributed.initialize(coord, num_processes=nproc, process_id=pid)
 
     # Observability: the distributor points TPUDL_OBS_DIR at its
@@ -52,7 +51,7 @@ def main() -> int:
     if rec is not None:
         rec.record(
             "worker_run", "worker", t0, rec.clock() - t0,
-            {"ok": code == 0, "platform": platform},
+            {"ok": code == 0, "platform": jax.default_backend()},
         )
 
     tmp = result_path + ".tmp"

@@ -26,18 +26,25 @@ PEAK_FLOPS = {
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
-    "cpu": 1e12,  # nominal; MFU on CPU backend is not meaningful
+    "cpu": 1e12,  # nominal, for the CPU tests; not a device metric
 }
 
 
 def device_peak_flops(device: Optional[jax.Device] = None) -> float:
+    """Peak FLOP/s of ``device`` (default: the first) from PEAK_FLOPS.
+    A ``device_kind`` the table does not know is an error, never a
+    default: a utilization over the wrong peak looks like a result."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu")
+    kind = str(device.device_kind)
     for name, peak in PEAK_FLOPS.items():
-        if name.lower() in str(kind).lower():
+        if name.lower() in kind.lower():
             return peak
-    return PEAK_FLOPS["cpu"]
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {kind!r}: add it to "
+        f"tpudl.train.metrics.PEAK_FLOPS with its source "
+        f"(known: {sorted(PEAK_FLOPS)})"
+    )
 
 
 def compiled_flops(lowered_or_compiled) -> Optional[float]:

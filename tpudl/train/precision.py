@@ -53,7 +53,7 @@ from tpudl.rules import Rules
 
 #: Default cast rules: matmul weights and embedding tables compute in
 #: the policy dtype; everything else (norm scales, biases, scalars —
-#: the precision-load-bearing leaves, same taxonomy as the quantizer's
+#: the precision-load-bearing leaves, same classes as the quantizer's
 #: keep classes) stays f32. The catch-all keeps the uncovered->raise
 #: engine contract satisfied explicitly.
 DEFAULT_CAST_RULES: Rules = (
