@@ -87,11 +87,14 @@ def test_request_trace_legs_recorded(model_and_params, tmp_path):
     assert sorted(p["request_id"] for p in prefills) == [
         f"r{i}" for i in range(5)
     ]
-    decodes = [
+    # (decode_step's children share its category; a count over the
+    # category takes the spans with no parent of it.)
+    decodes = obs_spans.without_same_category_children(
         r for r in records
         if r.get("kind") == "span" and r.get("cat") == "serve_decode"
-    ]
-    assert decodes and all("rids" in d for d in decodes)
+    )
+    assert decodes and all(d["name"] == "decode_step" for d in decodes)
+    assert all("rids" in d for d in decodes)
     assert all(len(d["rids"]) == d["busy"] for d in decodes)
     # Completion events close each trace with the measured aggregates.
     completes = {

@@ -315,18 +315,18 @@ class CheckpointManager:
         if self._impl is not None:
             return self._impl.save(step, state, rng=rng, data_state=data_state)
         rec = obs_spans.active_recorder()
-        t0 = rec.clock() if rec is not None else None
+        span = None
+        if rec is not None:
+            span = rec.begin(
+                "checkpoint_save", obs_spans.CAT_CHECKPOINT, step=step
+            )
         saved = self._mgr.save(
             step, args=ocp.args.StandardSave(_state_payload(state))
         )
         if saved:
             self._write_sidecar(step, rng, data_state)
-        if rec is not None:
-            dur = rec.clock() - t0
-            rec.record(
-                "checkpoint_save", obs_spans.CAT_CHECKPOINT, t0, dur,
-                {"step": step},
-            )
+        if span is not None:
+            dur = span.end()["dur"]
             reg = obs_counters.registry()
             reg.histogram("checkpoint_time_s").observe(dur)
             if saved:

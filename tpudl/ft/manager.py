@@ -195,7 +195,12 @@ class AsyncCheckpointManager:
         if self._store.is_committed(step):
             return False
         rec = obs_spans.active_recorder()
-        t0 = rec.clock() if rec is not None else None
+        span = None
+        if rec is not None:
+            span = rec.begin(
+                "checkpoint_save", obs_spans.CAT_CHECKPOINT, step=step,
+                **{"async": True},
+            )
         leaves = flatten_with_keys(state_payload(state))
         extra_meta: dict = {}
         if rng is not None:
@@ -213,15 +218,10 @@ class AsyncCheckpointManager:
             step, host_leaves, extra_meta=extra_meta,
             delay_hook=chaos.io_delay_hook(),
         )
-        if rec is not None:
-            dur = rec.clock() - t0
+        if span is not None:
             # One span covers the whole stall; back-pressure rides as
-            # an attribute (a nested same-category span would be
-            # double-counted by the goodput sums).
-            rec.record(
-                "checkpoint_save", obs_spans.CAT_CHECKPOINT, t0, dur,
-                {"step": step, "async": True, "backpressure_s": waited},
-            )
+            # an attribute.
+            dur = span.end(backpressure_s=waited)["dur"]
             reg = obs_counters.registry()
             reg.histogram("checkpoint_stall_s").observe(dur)
             if waited > 0:

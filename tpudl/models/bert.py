@@ -320,6 +320,9 @@ class BertLayer(nn.Module):
         attn_out = attn_cls(cfg, name="attention")(
             hidden, attn_mask, train
         )
+        # `norm` and `ffn` (like `attention`, `embeddings`, `classifier`,
+        # which the module names give) are scope components a profiler
+        # trace groups device time by; HLO metadata only.
         if cfg.fused_ops:
             # Fused-epilogue path (tpudl.ops.norms / mlp_fused): the
             # residual add rides inside the LayerNorm kernel, and BERT's
@@ -330,39 +333,48 @@ class BertLayer(nn.Module):
             from tpudl.ops.norms import fused_ops_impl
 
             impl = fused_ops_impl(cfg.fused_ops)
-            hidden = FusedLayerNorm(
-                cfg.layer_norm_eps, impl, name="attention_norm"
-            )(attn_out, hidden, return_sum=False).astype(cfg.dtype)
-            inter = FusedBiasGeluDense(
-                cfg, cfg.intermediate_size, impl, name="intermediate"
-            )(hidden)
-            out = _dense(cfg, cfg.hidden_size, "output", quantize=True)(
-                inter
-            )
-            out = Dropout(cfg.hidden_dropout, exact=cfg.dropout_exact)(
-                out, deterministic=not train
-            )
-            hidden = FusedLayerNorm(
-                cfg.layer_norm_eps, impl, name="output_norm"
-            )(out, hidden, return_sum=False).astype(cfg.dtype)
+            with jax.named_scope("norm"):
+                hidden = FusedLayerNorm(
+                    cfg.layer_norm_eps, impl, name="attention_norm"
+                )(attn_out, hidden, return_sum=False).astype(cfg.dtype)
+            with jax.named_scope("ffn"):
+                inter = FusedBiasGeluDense(
+                    cfg, cfg.intermediate_size, impl, name="intermediate"
+                )(hidden)
+                out = _dense(
+                    cfg, cfg.hidden_size, "output", quantize=True
+                )(inter)
+                out = Dropout(cfg.hidden_dropout, exact=cfg.dropout_exact)(
+                    out, deterministic=not train
+                )
+            with jax.named_scope("norm"):
+                hidden = FusedLayerNorm(
+                    cfg.layer_norm_eps, impl, name="output_norm"
+                )(out, hidden, return_sum=False).astype(cfg.dtype)
         else:
-            hidden = nn.LayerNorm(
-                epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                name="attention_norm"
-            )(hidden + attn_out).astype(cfg.dtype)
+            with jax.named_scope("norm"):
+                hidden = nn.LayerNorm(
+                    epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
+                    name="attention_norm"
+                )(hidden + attn_out).astype(cfg.dtype)
 
-            inter = _dense(
-                cfg, cfg.intermediate_size, "intermediate", quantize=True
-            )(hidden)
-            inter = nn.gelu(inter, approximate=False)
-            out = _dense(cfg, cfg.hidden_size, "output", quantize=True)(
-                inter
-            )
-            out = Dropout(cfg.hidden_dropout, exact=cfg.dropout_exact)(out, deterministic=not train)
-            hidden = nn.LayerNorm(
-                epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
-                name="output_norm"
-            )(hidden + out).astype(cfg.dtype)
+            with jax.named_scope("ffn"):
+                inter = _dense(
+                    cfg, cfg.intermediate_size, "intermediate",
+                    quantize=True,
+                )(hidden)
+                inter = nn.gelu(inter, approximate=False)
+                out = _dense(
+                    cfg, cfg.hidden_size, "output", quantize=True
+                )(inter)
+                out = Dropout(cfg.hidden_dropout, exact=cfg.dropout_exact)(
+                    out, deterministic=not train
+                )
+            with jax.named_scope("norm"):
+                hidden = nn.LayerNorm(
+                    epsilon=cfg.layer_norm_eps, dtype=jnp.float32,
+                    name="output_norm"
+                )(hidden + out).astype(cfg.dtype)
         hidden = constrain(hidden, ("dp", "fsdp"), "sp", "tp")
         return hidden
 

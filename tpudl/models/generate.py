@@ -24,13 +24,26 @@ import jax
 import jax.numpy as jnp
 
 
+def named(fn, name: str):
+    """``fn`` under another name: ``jax.jit`` calls the compiled
+    program ``jit_<name>``, which is how a profiler trace tells a
+    draft model's programs from the target's."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+# The contract functions below carry names of their own (``tpudl_prefill``,
+# ``tpudl_decode``, ...) for the same reason: the trace's ``XLA Modules``
+# line then reads ``jit_tpudl_decode``, not ``jit_fn``.
+
+
 def prefill_fn(model):
     """THE functional prefill contract (cache as explicit pytree I/O):
     (params, input_ids, attention_mask) -> (last_logits, cache). One
     definition serves both the live loop below and the serving export
     (tpudl.export.decode) — they cannot diverge."""
 
-    def fn(params, input_ids, attention_mask):
+    def tpudl_prefill(params, input_ids, attention_mask):
         positions = jnp.maximum(
             jnp.cumsum(attention_mask, axis=-1) - 1, 0
         ).astype(jnp.int32)
@@ -44,14 +57,14 @@ def prefill_fn(model):
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_prefill
 
 
 def decode_fn(model):
     """THE functional single-token decode contract:
     (params, cache, token, position) -> (logits, new_cache)."""
 
-    def fn(params, cache, token, position):
+    def tpudl_decode(params, cache, token, position):
         logits, mutated = model.apply(
             {"params": params, "cache": cache},
             token[:, None],
@@ -62,7 +75,7 @@ def decode_fn(model):
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_decode
 
 
 def paged_decode_fn(model, page_size: int, quantized: bool):
@@ -78,7 +91,7 @@ def paged_decode_fn(model, page_size: int, quantized: bool):
     (tpudl.serve.cache.PagedKVCache owns the pools and addressing)."""
     from tpudl.models.paged import PagedView
 
-    def fn(params, cache, token, position, page_table, start, lens):
+    def tpudl_decode(params, cache, token, position, page_table, start, lens):
         view = PagedView(
             page_table=page_table, start=start, lens=lens,
             page_size=page_size, quantized=quantized,
@@ -94,7 +107,7 @@ def paged_decode_fn(model, page_size: int, quantized: bool):
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_decode
 
 
 def lora_prefill_fn(model, impl: str = "auto"):
@@ -108,7 +121,7 @@ def lora_prefill_fn(model, impl: str = "auto"):
     tpudl.ops dispatch seam for the segmented kernel (static)."""
     from tpudl.models.lora import AdapterView
 
-    def fn(params, input_ids, attention_mask, apools, atable, ascale):
+    def tpudl_prefill(params, input_ids, attention_mask, apools, atable, ascale):
         positions = jnp.maximum(
             jnp.cumsum(attention_mask, axis=-1) - 1, 0
         ).astype(jnp.int32)
@@ -125,7 +138,7 @@ def lora_prefill_fn(model, impl: str = "auto"):
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_prefill
 
 
 def lora_paged_decode_fn(
@@ -142,7 +155,7 @@ def lora_paged_decode_fn(
     from tpudl.models.lora import AdapterView
     from tpudl.models.paged import PagedView
 
-    def fn(
+    def tpudl_decode(
         params, cache, token, position, page_table, start, lens,
         apools, atable, ascale,
     ):
@@ -164,7 +177,7 @@ def lora_paged_decode_fn(
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_decode
 
 
 def chunk_prefill_fn(model):
@@ -180,7 +193,7 @@ def chunk_prefill_fn(model):
     index in the unpadded prompt), keeping RoPE phases identical to a
     cold full prefill."""
 
-    def fn(params, cache, tokens, positions):
+    def tpudl_chunk_prefill(params, cache, tokens, positions):
         logits, mutated = model.apply(
             {"params": params, "cache": cache},
             tokens,
@@ -191,7 +204,7 @@ def chunk_prefill_fn(model):
         )
         return logits[:, -1, :], mutated["cache"]
 
-    return fn
+    return tpudl_chunk_prefill
 
 
 def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
@@ -207,7 +220,7 @@ def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
     are masked and overwritten by the next window)."""
     from tpudl.models.paged import PagedView
 
-    def fn(params, cache, tokens, positions, page_table, start, lens):
+    def tpudl_verify(params, cache, tokens, positions, page_table, start, lens):
         view = PagedView(
             page_table=page_table, start=start, lens=lens,
             page_size=page_size, quantized=quantized,
@@ -223,7 +236,7 @@ def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
         )
         return logits, mutated["cache"]
 
-    return fn
+    return tpudl_verify
 
 
 @functools.partial(jax.jit, static_argnums=(0,))

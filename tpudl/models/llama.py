@@ -206,9 +206,13 @@ class RMSNorm(nn.Module):
         from tpudl.ops.norms import rms_norm
 
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return rms_norm(
-            x, scale, residual, eps=self.eps, impl=self.impl
-        )
+        # `norm`, `mlp`, `embeddings` (like `attention`, `lm_head`, which
+        # the module names give) are scope components a profiler trace
+        # groups device time by; HLO metadata only.
+        with jax.named_scope("norm"):
+            return rms_norm(
+                x, scale, residual, eps=self.eps, impl=self.impl
+            )
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -439,29 +443,30 @@ class LlamaBlock(nn.Module):
         x, hidden = RMSNorm(
             cfg.rms_norm_eps, impl, name="post_attention_norm"
         )(attn, residual=hidden)
-        if cfg.moe_experts > 0:
-            from tpudl.ops.moe import MoEMlp
+        with jax.named_scope("mlp"):
+            if cfg.moe_experts > 0:
+                from tpudl.ops.moe import MoEMlp
 
-            down = MoEMlp(
-                num_experts=cfg.moe_experts,
-                intermediate_size=cfg.intermediate_size,
-                k=cfg.moe_k,
-                capacity_factor=cfg.moe_capacity_factor,
-                gated=True,
-                act=nn.silu,
-                dtype=cfg.dtype,
-                name="moe",
-            )(x)
-        else:
-            from tpudl.ops.mlp_fused import swiglu
+                down = MoEMlp(
+                    num_experts=cfg.moe_experts,
+                    intermediate_size=cfg.intermediate_size,
+                    k=cfg.moe_k,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    gated=True,
+                    act=nn.silu,
+                    dtype=cfg.dtype,
+                    name="moe",
+                )(x)
+            else:
+                from tpudl.ops.mlp_fused import swiglu
 
-            gate = _proj(cfg, cfg.intermediate_size, "gate_proj")(x)
-            gate = gate + adapter_delta(adapters, "gate_proj", x)
-            up = _proj(cfg, cfg.intermediate_size, "up_proj")(x)
-            up = up + adapter_delta(adapters, "up_proj", x)
-            act = swiglu(gate, up, impl=impl)
-            down = _proj(cfg, cfg.hidden_size, "down_proj")(act)
-            down = down + adapter_delta(adapters, "down_proj", act)
+                gate = _proj(cfg, cfg.intermediate_size, "gate_proj")(x)
+                gate = gate + adapter_delta(adapters, "gate_proj", x)
+                up = _proj(cfg, cfg.intermediate_size, "up_proj")(x)
+                up = up + adapter_delta(adapters, "up_proj", x)
+                act = swiglu(gate, up, impl=impl)
+                down = _proj(cfg, cfg.hidden_size, "down_proj")(act)
+                down = down + adapter_delta(adapters, "down_proj", act)
         hidden = hidden + down
         return constrain(hidden, ("dp", "fsdp"), "sp", "tp")
 
@@ -489,12 +494,13 @@ class LlamaModel(nn.Module):
             positions = jnp.maximum(
                 jnp.cumsum(attention_mask, axis=-1) - 1, 0
             ).astype(jnp.int32)
-        x = nn.Embed(
-            cfg.vocab_size,
-            cfg.hidden_size,
-            embedding_init=nn.initializers.normal(0.02),
-            name="embed_tokens",
-        )(input_ids).astype(cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = nn.Embed(
+                cfg.vocab_size,
+                cfg.hidden_size,
+                embedding_init=nn.initializers.normal(0.02),
+                name="embed_tokens",
+            )(input_ids).astype(cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", "tp")
         block = LlamaBlock
         if cfg.remat and not decode:

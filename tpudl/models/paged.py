@@ -129,19 +129,25 @@ def paged_write(
     s = value.shape[1]
     ps = view.page_size
     p = view.page_table.shape[1]
-    pos = view.lens[:, None] + jnp.arange(s, dtype=view.lens.dtype)[None, :]
-    pidx = pos // ps
-    page = jnp.take_along_axis(
-        view.page_table, jnp.minimum(pidx, p - 1), axis=1
-    )
-    page = jnp.where(pidx < p, page, 0)
-    off = pos % ps
-    if view.quantized:
-        q, sc = quantize_kv(value)
-        pages = pages.at[page, off].set(q)
-        scales = scales.at[page, off].set(sc)
-    else:
-        pages = pages.at[page, off].set(value.astype(pages.dtype))
+    # The scope names these operations in a profiler trace (HLO
+    # metadata only; tpudl.obs.spans has the host's half).
+    with jax.named_scope("kv_scatter"):
+        pos = (
+            view.lens[:, None]
+            + jnp.arange(s, dtype=view.lens.dtype)[None, :]
+        )
+        pidx = pos // ps
+        page = jnp.take_along_axis(
+            view.page_table, jnp.minimum(pidx, p - 1), axis=1
+        )
+        page = jnp.where(pidx < p, page, 0)
+        off = pos % ps
+        if view.quantized:
+            q, sc = quantize_kv(value)
+            pages = pages.at[page, off].set(q)
+            scales = scales.at[page, off].set(sc)
+        else:
+            pages = pages.at[page, off].set(value.astype(pages.dtype))
     return pages, scales
 
 
@@ -158,13 +164,16 @@ def paged_gather(
     gather for int8 pools. Unmapped logical pages resolve to the trash
     page — finite garbage the attention mask excludes."""
     np_, ps = pages.shape[0], view.page_size
-    flat_idx = flat_page_row_index(view.page_table, ps)
-    flat_pages = pages.reshape(np_ * ps, *pages.shape[2:])
-    out = flat_pages[flat_idx]  # [B, L, Hkv, D]
-    if view.quantized:
-        flat_scales = scales.reshape(np_ * ps, scales.shape[2])
-        out = out.astype(jnp.float32) * flat_scales[flat_idx][..., None]
-    return out.astype(compute_dtype)
+    with jax.named_scope("kv_gather"):
+        flat_idx = flat_page_row_index(view.page_table, ps)
+        flat_pages = pages.reshape(np_ * ps, *pages.shape[2:])
+        out = flat_pages[flat_idx]  # [B, L, Hkv, D]
+        if view.quantized:
+            flat_scales = scales.reshape(np_ * ps, scales.shape[2])
+            out = (
+                out.astype(jnp.float32) * flat_scales[flat_idx][..., None]
+            )
+        return out.astype(compute_dtype)
 
 
 def paged_attend_mask(view: PagedView, chunk: int = 1) -> jax.Array:

@@ -15,13 +15,16 @@ Three observability layers exist in tpudl, deliberately split:
   productive and host-3 was the straggler". Answers "where does the
   WALL-CLOCK go" — the question neither of the other two can.
 
-The two trace views compose: ``SpanRecorder.export_chrome_trace``
-writes the host spans as Chrome trace-event JSON that loads in
-Perfetto NEXT TO the XLA device trace, one timeline.
+The two trace views compose inside the profiler's trace: while
+recording is on, every span that is begun is also open as a
+``jax.profiler.TraceAnnotation("tpudl.<name>", span_id=<id>)``, so a
+trace taken by anyone holds the program's phases on its own clock
+beside the device's operations (``tpudl.obs.spans``; set
+``TPUDL_OBS_DIR`` and ``TPUDL_PROFILE_DIR`` together).
 
-Zero hard dependencies (stdlib only), thread-safe, and free when
-disabled: every instrumentation site guards on
-``spans.active_recorder() is None``. Enable by setting
+Zero hard dependencies (stdlib only; JAX is touched only if already
+imported), thread-safe, and free when disabled: every instrumentation
+site guards on ``spans.active_recorder() is None``. Enable by setting
 ``TPUDL_OBS_DIR=/path`` (the profiler-hook idiom) or calling
 ``tpudl.obs.enable(path)``; report with
 ``python -m tpudl.obs.report /path``.

@@ -11,9 +11,13 @@ anything unaccounted).
 Input is the span-record stream tpudl.obs.spans produces. Within one
 process the instrumented categories are sequential by construction
 (fit's loop waits on data, then steps; the synchronous part of a
-checkpoint save happens between steps), so seconds per category sum
-without overlap bookkeeping; ``idle`` is clamped at zero to stay robust
-if a custom instrumentation site violates that.
+checkpoint save happens between steps), and where spans nest (the serve
+engine's ``engine_step`` around ``prefill``, ``seat``, ``decode_step``
+and ``emit``; ``decode_step`` around its dispatch and read-back) each
+span counts its SELF time — its duration less its children's, by the
+records' ``id``/``parent`` — so every second is counted once, under the
+innermost span that held it. ``idle`` is clamped at zero to stay robust
+if a custom instrumentation site overlaps spans without nesting them.
 
 Multi-process runs classify per (host, process) and aggregate by
 summing: total goodput = all productive seconds / all wall seconds, so
@@ -24,6 +28,8 @@ from __future__ import annotations
 from typing import Dict, Iterable, Optional, Tuple
 
 from tpudl.obs.spans import (
+    self_seconds,
+    without_same_category_children,
     CAT_CHECKPOINT,
     CAT_CKPT_BG,
     CAT_COMPILE,
@@ -97,7 +103,7 @@ def classify(
     other = 0.0
     steps = 0
     lo, hi = None, None
-    for s in spans:
+    for s, own in self_seconds(spans):
         ts, dur = float(s["ts"]), float(s["dur"])
         lo = ts if lo is None else min(lo, ts)
         hi = ts + dur if hi is None else max(hi, ts + dur)
@@ -105,15 +111,16 @@ def classify(
         if cat in _WINDOW_ONLY_CATS:
             continue
         if cat in per_cat:
-            per_cat[cat] += dur
-            if cat == CAT_STEP:
-                # A fused dispatch_window span covers K train steps in
-                # one record (its "window" attr); count them all so
-                # goodput-per-step stays comparable across dispatch
-                # modes.
-                steps += int(s.get("window", 1) or 1)
+            per_cat[cat] += own
         else:
-            other += dur
+            other += own
+    for s in without_same_category_children(
+        s for s in spans if s.get("cat") == CAT_STEP
+    ):
+        # A fused dispatch_window span covers K train steps in one
+        # record (its "window" attr); count them all so goodput-per-step
+        # stays comparable across dispatch modes.
+        steps += int(s.get("window", 1) or 1)
     if window is not None:
         lo, hi = window
     wall = (hi - lo) if (lo is not None and hi is not None) else 0.0

@@ -32,7 +32,10 @@ queue-wait / prefill / first-decode-chunk, with the total checked
 against the measured TTFT + generation time.
 
 ``--chrome-trace`` additionally re-exports the loaded records as
-Chrome trace-event JSON for Perfetto, next to the XLA device trace."""
+Chrome trace-event JSON for Perfetto: the host spans alone, on each
+process's monotonic clock (the one timeline with the device's
+operations is the profiler's trace, which holds every span as a
+``tpudl.*`` annotation — ``tpudl.obs.spans``)."""
 
 from __future__ import annotations
 
@@ -54,6 +57,7 @@ from tpudl.obs.spans import (
     CAT_STEP,
     chrome_trace_events,
     read_jsonl,
+    without_same_category_children,
 )
 
 #: Table row order: the lifecycle order of one step; the overlapped
@@ -111,7 +115,11 @@ def build_report(
     straggler_factor: float = 1.2,
 ) -> dict:
     """Span records -> report dict (see module docstring for contents)."""
-    spans = [r for r in records if r.get("kind") == "span"]
+    # A phase split into children of its own category (decode_step into
+    # its dispatch and read-back) is one row entry, not three.
+    spans = without_same_category_children(
+        r for r in records if r.get("kind") == "span"
+    )
     by_cat: Dict[str, List[float]] = {}
     for s in spans:
         by_cat.setdefault(s.get("cat", "other"), []).append(float(s["dur"]))
@@ -300,9 +308,13 @@ def build_request_timeline(records: Iterable[dict], request_id) -> dict:
             elif name == "request_failover":
                 failovers.append(r)
         elif kind == "span":
-            if _match(r.get("request_id")):
+            # Only the two legs themselves: a `seat` span names its
+            # request too, and lies inside the same engine step.
+            if r.get("name") == "prefill" and _match(r.get("request_id")):
                 prefills.append(r)
-            elif any(_match(x) for x in (r.get("rids") or ())):
+            elif r.get("name") == "decode_step" and any(
+                _match(x) for x in (r.get("rids") or ())
+            ):
                 decode_chunks.append(r)
     if (
         queued is None and not prefills and complete is None

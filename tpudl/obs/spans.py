@@ -7,13 +7,26 @@ around the runtime's blocking calls. Records are plain dicts with a
 monotonic timestamp, duration, category, and host/process tags, exported
 two ways:
 
-- **JSONL** (one record per line, streamed as recorded) — the greppable
+- **JSONL** (one record per line, written in blocks) — the greppable
   artifact ``python -m tpudl.obs.report`` aggregates into goodput and
   straggler tables;
-- **Chrome trace-event JSON** (``export_chrome_trace``) — loads in
-  Perfetto/chrome://tracing NEXT TO the XLA device trace
-  ``jax.profiler.trace`` writes, so host spans and device ops line up in
-  one timeline view.
+- **Chrome trace-event JSON** (``export_chrome_trace``) — the host spans
+  alone, on the host's monotonic clock, for Perfetto/chrome://tracing.
+
+**One timeline.** While a recorder is active, every span that is begun
+(``begin``/``end`` or the ``span`` context manager) is also open as a
+``jax.profiler.TraceAnnotation("tpudl.<name>", span_id=<id>)``. A
+profiler trace taken by anyone (``TPUDL_PROFILE_DIR``, a benchmark
+harness) then holds the program's phases on the trace's own clock,
+beside the device's operations, and ``span_id`` joins each annotation
+to its JSONL record and that record's attributes. The annotation is
+made only if ``jax`` is already imported, so the module stays
+importable in data workers.
+
+Every span carries an ``id`` (unique in the process) and ``parent``
+(the ``id`` of the span open on the same thread when it began, else
+None), so self time can be reckoned and a child is never counted twice
+(``without_same_category_children``, ``tpudl.obs.goodput``).
 
 Design constraints, all load-bearing:
 
@@ -25,23 +38,31 @@ Design constraints, all load-bearing:
   byte-deterministic exports;
 - **disabled is free** — ``active_recorder()`` returns None unless
   ``enable()`` was called or TPUDL_OBS_DIR is set; instrumentation
-  sites guard on that None, so a disabled run adds one env lookup per
-  fit() call and nothing per step.
+  sites guard on that None. The environment is looked up once per
+  process while off (``disable()`` allows one more look), so a disabled
+  call is one global read;
+- **off the hot path** — records are kept in memory and written in
+  blocks of ``BLOCK_RECORDS``: on ``close``/``disable``, when
+  ``records`` is read, and when the buffer is full. A killed worker
+  loses at most its last block, which ``read_jsonl`` tolerates as a
+  torn tail.
 
 Activation mirrors the profiler hook: set ``TPUDL_OBS_DIR=/path`` (or
-call ``enable(path)``) and every instrumented layer streams into
+call ``enable(path)``) and every instrumented layer writes into
 ``spans-<host>-p<process>-<pid>.jsonl`` under it.
 """
 
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import os
 import socket
+import sys
 import threading
 import time
-from typing import Callable, Iterable, Optional
+from typing import Callable, Iterable, List, Optional
 
 from tpudl.analysis.registry import env_int, env_str
 
@@ -72,12 +93,40 @@ CAT_CKPT_BG = "ckpt_bg"
 CAT_ENCLOSING = "worker"
 
 
-class _Span:
-    """Context manager recording one span on exit. Created by
-    ``SpanRecorder.span`` — never when recording is disabled (the
-    module-level ``span()`` returns a shared no-op instead)."""
+#: Records a file-backed recorder holds before it writes them out.
+BLOCK_RECORDS = 1024
+#: Prefix of the profiler annotations that mirror the spans.
+ANNOTATION_PREFIX = "tpudl."
 
-    __slots__ = ("_rec", "_name", "_cat", "_attrs", "_t0")
+# Span ids are unique in the process, whichever recorder hands them out
+# (itertools.count.__next__ is atomic under the GIL).
+_span_ids = itertools.count(1)
+_annotation_cls = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if ``jax`` is already imported,
+    else None: this module never imports JAX itself."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            _annotation_cls = jax.profiler.TraceAnnotation
+        except AttributeError:  # jax is still being imported
+            return None
+    return _annotation_cls
+
+
+class _Span:
+    """One open span: begun by ``SpanRecorder.begin`` (or on entering
+    ``SpanRecorder.span``), recorded by ``end``. Never created when
+    recording is disabled (the module-level ``span()`` returns a shared
+    no-op instead)."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_attrs", "_annotation",
+                 "id", "parent", "t0")
 
     def __init__(self, rec: "SpanRecorder", name: str, cat: str, attrs: dict):
         self._rec = rec
@@ -85,15 +134,53 @@ class _Span:
         self._cat = cat
         self._attrs = attrs
 
-    def __enter__(self) -> "_Span":
-        self._t0 = self._rec.clock()
+    def _begin(self, ts: Optional[float] = None) -> "_Span":
+        stack = self._rec._open_spans()
+        self.id = next(_span_ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        cls = _trace_annotation()
+        self._annotation = None
+        if cls is not None:
+            self._annotation = cls(
+                ANNOTATION_PREFIX + self._name, span_id=self.id
+            )
+            self._annotation.__enter__()
+        # Read last, so that the span's extent is the work's and the
+        # annotation encloses it.
+        self.t0 = self._rec.clock() if ts is None else ts
         return self
 
-    def __exit__(self, *exc) -> None:
-        self._rec.record(
-            self._name, self._cat, self._t0,
-            self._rec.clock() - self._t0, self._attrs,
+    def end(self, ts: Optional[float] = None, **attrs) -> dict:
+        """Close the span and record it; ``attrs`` are those known only
+        now, ``ts`` the end if the caller has read the clock already.
+        Spans begun after this one on the same thread and never ended
+        (an exception unwound past them) are dropped unrecorded.
+        Returns the record."""
+        rec = self._rec
+        dur = (rec.clock() if ts is None else ts) - self.t0
+        self.cancel()
+        if attrs:
+            self._attrs = {**self._attrs, **attrs}
+        return rec._span_record(
+            self._name, self._cat, self.t0, dur, self._attrs,
+            self.id, self.parent,
         )
+
+    def cancel(self) -> None:
+        """Close the span without a record: what it waited for did not
+        happen (a pull that returned nothing, a wait of no length)."""
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        stack = self._rec._open_spans()
+        if self.id in stack:
+            del stack[stack.index(self.id):]
+
+    def __enter__(self) -> "_Span":
+        return self._begin()
+
+    def __exit__(self, *exc) -> None:
+        self.end()
 
 
 class _NullSpan:
@@ -113,13 +200,13 @@ _NULL_SPAN = _NullSpan()
 
 
 class SpanRecorder:
-    """Thread-safe span/event sink with streaming JSONL and in-memory
-    record lists.
+    """Thread-safe span/event sink: records are kept in memory and, with
+    a ``path``, written to JSONL in blocks.
 
     Every record is a flat dict:
 
-    - spans:    ``{"kind": "span", "name", "cat", "ts", "dur", "host",
-      "process", "pid", "tid", ...attrs}``
+    - spans:    ``{"kind": "span", "name", "cat", "ts", "dur", "id",
+      "parent", "host", "process", "pid", "tid", ...attrs}``
     - events:   ``{"kind": "event", "name", "cat", "ts", ...tags}``
     - counters: ``{"kind": "counters", "ts", "data": {...}}`` (a
       tpudl.obs.counters snapshot riding the same stream)
@@ -146,7 +233,9 @@ class SpanRecorder:
             else env_int("TPUDL_PROCESS_ID", 0)
         )
         self._lock = threading.Lock()
+        # With a file: the block not yet written. Without: every record.
         self._records: list = []
+        self._open = threading.local()
         self._file = None
         if path:
             os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -158,15 +247,44 @@ class SpanRecorder:
         """Context manager: ``with rec.span("save", "checkpoint"): ...``"""
         return _Span(self, name, cat, attrs)
 
+    def begin(self, name: str, cat: str = CAT_STEP,
+              ts: Optional[float] = None, **attrs) -> _Span:
+        """Open a span now (at ``ts`` on this recorder's clock, if the
+        caller has read it already); ``.end(**attrs)`` on what is
+        returned records it. The form the hot loops use behind their
+        ``rec is not None`` guard: spans begun in between, on the same
+        thread, become its children."""
+        return _Span(self, name, cat, attrs)._begin(ts)
+
+    def _open_spans(self) -> List[int]:
+        """Ids of the spans open on the calling thread, outermost
+        first."""
+        try:
+            return self._open.stack
+        except AttributeError:
+            self._open.stack = []
+            return self._open.stack
+
     def record(
         self, name: str, cat: str, ts: float, dur: float,
         attrs: Optional[dict] = None,
     ) -> dict:
-        """Append one completed span (the explicit form the hot loops use
-        so the disabled branch stays allocation-free)."""
+        """Append one span after the fact (no profiler annotation: the
+        time has passed). Its parent is the span open on this thread
+        now."""
+        stack = self._open_spans()
+        return self._span_record(
+            name, cat, ts, dur, attrs, next(_span_ids),
+            stack[-1] if stack else None,
+        )
+
+    def _span_record(
+        self, name: str, cat: str, ts: float, dur: float,
+        attrs: Optional[dict], span_id: int, parent: Optional[int],
+    ) -> dict:
         rec = {
             "kind": "span", "name": name, "cat": cat,
-            "ts": ts, "dur": dur,
+            "ts": ts, "dur": dur, "id": span_id, "parent": parent,
             "host": self.host, "process": self.process,
             "pid": os.getpid(), "tid": threading.get_ident(),
         }
@@ -210,15 +328,26 @@ class SpanRecorder:
         self._emit(record)
 
     def _emit(self, rec: dict) -> None:
-        # Streamed OR buffered, never both: a file-backed recorder keeps
-        # nothing in memory (a million-step run must not grow the host
-        # RSS by its own telemetry); `records` re-reads the file.
+        # A file-backed recorder holds one block at most (a million-step
+        # run must not grow the host RSS by its own telemetry) and
+        # serialises it off the per-record path; `records` re-reads the
+        # file.
         with self._lock:
-            if self._file is not None:
-                self._file.write(json.dumps(rec) + "\n")
-                self._file.flush()
-            else:
-                self._records.append(rec)
+            self._records.append(rec)
+            if (
+                self._file is not None
+                and len(self._records) >= BLOCK_RECORDS
+            ):
+                self._write_block()
+
+    def _write_block(self) -> None:
+        """Write the held block out (lock held, file open)."""
+        if self._records:
+            self._file.write(
+                "".join(json.dumps(r) + "\n" for r in self._records)
+            )
+            self._file.flush()
+            self._records = []
 
     # -- export --------------------------------------------------------
 
@@ -226,6 +355,8 @@ class SpanRecorder:
     def records(self) -> list:
         with self._lock:
             if self.path is not None:
+                if self._file is not None:
+                    self._write_block()
                 if not os.path.exists(self.path):
                     return []
                 return read_jsonl(self.path)
@@ -247,6 +378,7 @@ class SpanRecorder:
     def close(self) -> None:
         with self._lock:
             if self._file is not None:
+                self._write_block()
                 self._file.close()
                 self._file = None
 
@@ -265,7 +397,9 @@ def chrome_trace_events(records: Iterable[dict]) -> list:
     distributor parent and its rank-0 worker share the first two but
     have unrelated monotonic clocks — gets its own trace pid with a
     process_name metadata row, so a merged multi-host file renders one
-    lane per worker next to the XLA device lanes."""
+    lane per worker. Timestamps are each process's monotonic clock:
+    the file does not line up with a profiler trace (the ``tpudl.*``
+    annotations inside that trace do)."""
     out = []
     proc_ids: dict = {}
     seen_labels: dict = {}
@@ -285,8 +419,8 @@ def chrome_trace_events(records: Iterable[dict]) -> list:
         if rec.get("kind") == "span":
             args = {
                 k: v for k, v in rec.items()
-                if k not in ("kind", "name", "cat", "ts", "dur",
-                             "host", "process", "pid", "tid")
+                if k not in ("kind", "name", "cat", "ts", "dur", "id",
+                             "parent", "host", "process", "pid", "tid")
             }
             out.append({
                 "ph": "X", "name": rec["name"], "cat": rec["cat"],
@@ -324,6 +458,51 @@ def read_jsonl(path: str) -> list:
     return records
 
 
+def _span_key(record: dict, span_id) -> tuple:
+    """A span id is unique in its process only: records merged from
+    several (the distributor's ingest) are told apart by who recorded
+    them."""
+    return (record.get("host"), record.get("process"), record.get("pid"),
+            span_id)
+
+
+def without_same_category_children(spans: Iterable[dict]) -> list:
+    """The spans with no parent of their own category: a phase that is
+    split into children (``decode_step`` into ``decode.dispatch`` and
+    ``decode.readback``) counts once in a sum, a count or a histogram
+    over its category. Records without ids (older files) all pass."""
+    spans = list(spans)
+    cat_of = {
+        _span_key(s, s["id"]): s.get("cat")
+        for s in spans if s.get("id") is not None
+    }
+    return [
+        s for s in spans
+        if s.get("parent") is None
+        or cat_of.get(_span_key(s, s["parent"])) != s.get("cat")
+    ]
+
+
+def self_seconds(spans: Iterable[dict]) -> list:
+    """``(span, seconds)`` pairs: each span's duration less that of its
+    direct children, so that the pairs sum to the time the outermost
+    spans cover and every second is counted once, under the innermost
+    span that held it."""
+    spans = list(spans)
+    children: dict = {}
+    for s in spans:
+        if s.get("parent") is not None:
+            key = _span_key(s, s["parent"])
+            children[key] = children.get(key, 0.0) + float(s["dur"])
+    out = []
+    for s in spans:
+        own = float(s["dur"])
+        if s.get("id") is not None:
+            own -= children.get(_span_key(s, s["id"]), 0.0)
+        out.append((s, max(0.0, own)))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Module-level active recorder (the switch every instrumentation site
 # consults).
@@ -331,6 +510,9 @@ def read_jsonl(path: str) -> list:
 
 _active: Optional[SpanRecorder] = None
 _atexit_registered = False
+# TPUDL_OBS_DIR has been looked up and was not set: while this holds,
+# ``active_recorder()`` is one global read.
+_env_is_off = False
 
 
 def default_span_path(directory: str) -> str:
@@ -367,8 +549,11 @@ def enable(
 
 
 def disable() -> None:
-    """Deactivate and flush the active recorder (no-op when inactive)."""
-    global _active
+    """Deactivate the active recorder and write out what it holds (no-op
+    when inactive). The next ``active_recorder()`` looks the
+    environment up once more."""
+    global _active, _env_is_off
+    _env_is_off = False
     if _active is not None:
         _active.close()
         _active = None
@@ -377,12 +562,17 @@ def disable() -> None:
 def active_recorder() -> Optional[SpanRecorder]:
     """The active recorder, auto-enabling from TPUDL_OBS_DIR on first
     call (mirrors fit()'s TPUDL_PROFILE_DIR idiom) — None when disabled,
-    which is the branch every hot path takes for free."""
+    which is the branch every hot path takes for free: the environment
+    is read once per process while off, not once per call."""
+    global _env_is_off
     if _active is not None:
         return _active
+    if _env_is_off:
+        return None
     obs_dir = env_str("TPUDL_OBS_DIR")
     if obs_dir:
         return enable(obs_dir)
+    _env_is_off = True
     return None
 
 

@@ -59,7 +59,7 @@ def dot_product_attention(
     weights = jax.nn.softmax(logits, axis=-1)
     weights = weights.astype(v.dtype)
     if dropout_rate > 0.0 and dropout_rng is not None:
-        from tpudl.ops.dropout import dropout_keep_mask, quantized_rate
+        from tpudl.ops.dropout import apply_keep_mask, dropout_keep_mask
 
         # Low-width-bits mask (tpudl.ops.dropout): 4x less random-bit
         # traffic than bernoulli — 14.5 ms/step on the headline BERT
@@ -69,9 +69,8 @@ def dot_product_attention(
         keep = dropout_keep_mask(
             dropout_rng, weights.shape, dropout_rate, exact=dropout_exact
         )
-        eff = quantized_rate(dropout_rate, dropout_exact)
-        weights = jnp.where(keep, weights / (1.0 - eff), 0.0).astype(
-            v.dtype
+        weights = apply_keep_mask(
+            keep, weights, dropout_rate, dropout_exact
         )
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 

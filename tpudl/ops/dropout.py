@@ -50,15 +50,29 @@ def dropout_keep_mask(
     round(rate * 256) / 256; ``exact=True`` uses jax.random.bernoulli
     (f32-uniform compare, 4x the bit traffic).
     """
-    if exact:
-        return jax.random.bernoulli(rng, 1.0 - rate, shape)
-    if rate >= 1.0:
-        return jnp.zeros(shape, bool)  # flax.nn.Dropout(1.0) semantics
-    threshold = int(round(rate * 256.0))
-    if threshold <= 0:
-        return jnp.ones(shape, bool)
-    bits = jax.random.bits(rng, shape, jnp.uint8)
-    return bits >= jnp.uint8(min(threshold, 255))
+    # The scope names mask generation in a profiler trace (HLO metadata
+    # only); ``apply_keep_mask`` puts the application under it too.
+    with jax.named_scope("dropout"):
+        if exact:
+            return jax.random.bernoulli(rng, 1.0 - rate, shape)
+        if rate >= 1.0:
+            return jnp.zeros(shape, bool)  # flax.nn.Dropout(1.0) semantics
+        threshold = int(round(rate * 256.0))
+        if threshold <= 0:
+            return jnp.ones(shape, bool)
+        bits = jax.random.bits(rng, shape, jnp.uint8)
+        return bits >= jnp.uint8(min(threshold, 255))
+
+
+def apply_keep_mask(
+    keep: jax.Array, x: jax.Array, rate: float, exact: bool = False
+) -> jax.Array:
+    """Inverted-dropout application of a keep-mask: kept elements are
+    scaled by the EFFECTIVE keep probability, so E[output] == input on
+    the quantized path too."""
+    eff = quantized_rate(rate, exact)
+    with jax.named_scope("dropout"):
+        return jnp.where(keep, x / (1.0 - eff), 0.0).astype(x.dtype)
 
 
 def dropout(
@@ -72,8 +86,7 @@ def dropout(
     if rate <= 0.0:
         return x
     keep = dropout_keep_mask(rng, x.shape, rate, exact=exact)
-    eff = quantized_rate(rate, exact)
-    return jnp.where(keep, x / (1.0 - eff), 0.0).astype(x.dtype)
+    return apply_keep_mask(keep, x, rate, exact)
 
 
 class Dropout(nn.Module):

@@ -334,6 +334,7 @@ class ServeSession:
             decode_fn,
             lora_paged_decode_fn,
             lora_prefill_fn,
+            named,
             paged_chunk_decode_fn,
             paged_decode_fn,
             prefill_fn,
@@ -478,9 +479,14 @@ class ServeSession:
                     num_pages=num_pages,
                 )
                 speculator = Speculator(
-                    jax.jit(prefill_fn(draft_model)),
-                    jax.jit(paged_decode_fn(
-                        draft_model, draft_cache.page_size, False
+                    jax.jit(named(
+                        prefill_fn(draft_model), "tpudl_draft_prefill"
+                    )),
+                    jax.jit(named(
+                        paged_decode_fn(
+                            draft_model, draft_cache.page_size, False
+                        ),
+                        "tpudl_draft_decode",
                     )),
                     draft_params,
                     draft_cache,

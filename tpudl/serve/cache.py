@@ -666,6 +666,7 @@ class PagedKVCache:
         )
         self.start = np.zeros((self.num_slots,), np.int32)
         self.lens = np.zeros((self.num_slots,), np.int32)
+        self._reset_occupancy()
         self._seat_jit = {}
         # Prefix sharing (radix mode): seating is LEFT-ALIGNED (token i
         # of every prompt lives at logical position i, start == 0), so
@@ -740,6 +741,7 @@ class PagedKVCache:
         )
         obj.start = np.zeros((obj.num_slots,), np.int32)
         obj.lens = np.zeros((obj.num_slots,), np.int32)
+        obj._reset_occupancy()
         obj._seat_jit = {}
         obj.prefix_share = False
         obj.radix = None
@@ -748,6 +750,31 @@ class PagedKVCache:
         obj._seat_shared_fn = None
         obj._gather_rows_fn = None
         return obj
+
+    # -- occupancy counters --------------------------------------------
+
+    def _reset_occupancy(self) -> None:
+        """Reserved against in use, kept at O(1) where they change:
+        ``pages_reserved`` is the pages the seated slots' table rows
+        map (shared prefix pages count once per slot that maps them),
+        ``tokens_live`` the sum over seated slots of ``lens - start``,
+        the positions a decode step has to read."""
+        import numpy as np
+
+        self.pages_reserved = 0
+        self.tokens_live = 0
+        self._slot_pages = np.zeros((self.num_slots,), np.int64)
+
+    def _seated(self, slot: int, pages: int) -> None:
+        """``slot`` was just seated on ``pages`` pages, with its
+        ``start`` and ``lens`` set."""
+        self._slot_pages[slot] = pages
+        self.pages_reserved += pages
+        self.tokens_live += int(self.lens[slot]) - int(self.start[slot])
+
+    def pages_of(self, slot: int) -> int:
+        """Pages ``slot``'s table row maps (0 when it is not seated)."""
+        return int(self._slot_pages[slot])
 
     # -- capacity ------------------------------------------------------
 
@@ -849,6 +876,7 @@ class PagedKVCache:
         self.page_table[slot, : len(pages)] = pages
         self.start[slot] = pad
         self.lens[slot] = prompt_len
+        self._seated(slot, len(pages))
         prompt_pages = self.pages_needed(prompt_len)
         fn = self._seat_jit.get(prompt_pages)
         if fn is None:
@@ -868,7 +896,7 @@ class PagedKVCache:
         ps, quantized = self.page_size, self.quantized
         span = prompt_pages * ps
 
-        def seat(pool_tree, row_tree, page_ids):
+        def tpudl_seat(pool_tree, row_tree, page_ids):
             def one(pool: dict, row: dict) -> dict:
                 out = dict(pool)
                 for kv, name, sname in (
@@ -901,9 +929,10 @@ class PagedKVCache:
                         )
                 return out
 
-            return _zip_attn_caches(pool_tree, row_tree, one)
+            with jax.named_scope("kv_scatter"):
+                return _zip_attn_caches(pool_tree, row_tree, one)
 
-        return seat
+        return tpudl_seat
 
     # -- prefix-sharing (radix) seating ---------------------------------
 
@@ -987,6 +1016,7 @@ class PagedKVCache:
         self.page_table[slot, m:m + len(new_pages)] = new_pages
         self.start[slot] = 0
         self.lens[slot] = ids_len
+        self._seated(slot, m + len(new_pages))
         # Scatter ONLY the unshared pages [m, prompt_pages); matched
         # pages keep their (identical) bytes untouched and page ids
         # outside that range aim at the trash page.
@@ -1023,7 +1053,7 @@ class PagedKVCache:
         pages = self.pages_per_slot
         span = pages * ps
 
-        def seat(pool_tree, row_tree, page_ids, row_offset):
+        def tpudl_seat_shared(pool_tree, row_tree, page_ids, row_offset):
             def one(pool: dict, row: dict) -> dict:
                 out = dict(pool)
                 for kv, name, sname in (
@@ -1048,9 +1078,10 @@ class PagedKVCache:
                         )
                 return out
 
-            return _zip_attn_caches(pool_tree, row_tree, one)
+            with jax.named_scope("kv_scatter"):
+                return _zip_attn_caches(pool_tree, row_tree, one)
 
-        return seat
+        return tpudl_seat_shared
 
     def gather_prefix_rows(self, matched_pages, matched_tokens: int):
         """Materialize a leased prefix into a batch-1 DENSE row cache
@@ -1077,7 +1108,7 @@ class PagedKVCache:
         span = self.pages_per_slot * ps
         row_template = self._row_template
 
-        def gather(pool_tree, page_ids, m_tok):
+        def tpudl_gather_rows(pool_tree, page_ids, m_tok):
             from tpudl.models.paged import flat_page_row_index
 
             def one(pool: dict, tmpl: dict) -> dict:
@@ -1110,9 +1141,10 @@ class PagedKVCache:
                 out["index"] = jnp.asarray(m_tok, tmpl["index"].dtype)
                 return out
 
-            return _zip_attn_caches(pool_tree, row_template, one)
+            with jax.named_scope("kv_gather"):
+                return _zip_attn_caches(pool_tree, row_template, one)
 
-        return gather
+        return tpudl_gather_rows
 
     def free(self, slot: int) -> None:
         """Return the slot's PRIVATE pages to the pool, release its
@@ -1127,6 +1159,9 @@ class PagedKVCache:
         pages = self._reserved.pop(slot, None)
         if pages:
             self._free.extend(pages)
+        self.pages_reserved -= int(self._slot_pages[slot])
+        self._slot_pages[slot] = 0
+        self.tokens_live -= int(self.lens[slot]) - int(self.start[slot])
         self.page_table[slot, :] = 0
         self.start[slot] = 0
         self.lens[slot] = 0
@@ -1297,6 +1332,7 @@ class PagedKVCache:
         self.page_table[slot, m:m + len(new_pages)] = new_pages
         self.start[slot] = start
         self.lens[slot] = lens
+        self._seated(slot, m + len(new_pages))
         # Matched pages (and reserved-but-unwritten ones past ``used``)
         # aim at the trash page in the scatter's page_ids — their bytes
         # are either already identical (matched) or garbage-until-
@@ -1381,6 +1417,7 @@ class PagedKVCache:
         per-slot window advance."""
         for slot in slots:
             self.lens[slot] += steps
+        self.tokens_live += steps * len(slots)
 
     def set_len(self, slot: int, length: int) -> None:
         """Pin one slot's logical length — the speculative ROLLBACK
@@ -1388,6 +1425,7 @@ class PagedKVCache:
         so its page writes are masked garbage the next window
         overwrites. Per-slot bookkeeping only (no shared write index
         since the paged layout landed)."""
+        self.tokens_live += int(length) - int(self.lens[slot])
         self.lens[slot] = int(length)
 
     # -- accounting ----------------------------------------------------

@@ -78,35 +78,55 @@ from tpudl.serve.cache import (
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue, _Entry
 
 #: Span categories (their own rows in the obs report breakdown table).
+#: One ``engine_step`` span encloses a step's ``prefill``, ``seat``,
+#: ``decode_step`` and ``emit``; ``decode_step`` encloses
+#: ``decode.dispatch`` and ``decode.readback``, of its own category so
+#: that a sum over ``serve_decode`` counts each step once.
+CAT_SERVE_ENGINE = "serve_engine"
 CAT_SERVE_PREFILL = "serve_prefill"
+CAT_SERVE_SEAT = "serve_seat"
 CAT_SERVE_DECODE = "serve_decode"
+CAT_SERVE_EMIT = "serve_emit"
 
 
-@jax.jit
-def _select_greedy(logits):
+# Selection programs under names of their own (a trace's ``XLA
+# Modules`` line reads ``jit_tpudl_select``), their operations under
+# the ``select`` scope.
+
+
+def tpudl_select(logits):
     """Argmax-only selection: the fast path when no active slot samples
     (temperature 0 is the default) — skips the per-slot key derivation
     and the O(slots x vocab) categorical draw `_select_tokens` would
     compute just to discard. Same f32 argmax, bit-identical tokens."""
-    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    with jax.named_scope("select"):
+        return jnp.argmax(
+            logits.astype(jnp.float32), axis=-1
+        ).astype(jnp.int32)
 
 
-@jax.jit
-def _select_tokens(logits, temps, seeds, steps):
+def tpudl_select_sampled(logits, temps, seeds, steps):
     """Per-slot next-token selection on [B, V] logits: greedy argmax
     where ``temps[i] == 0``, else categorical over temperature-scaled
     logits keyed by ``fold_in(key(seeds[i]), steps[i])`` — the stream
     that makes sampling per-request deterministic regardless of which
     slot or neighbors the request has. f32 selection math like
     tpudl.models.generate._select_impl."""
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    keys = jax.vmap(
-        lambda s, t: jax.random.fold_in(jax.random.key(s), t)
-    )(seeds, steps)
-    scaled = logits / jnp.where(temps > 0, temps, 1.0)[:, None]
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
+    with jax.named_scope("select"):
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        keys = jax.vmap(
+            lambda s, t: jax.random.fold_in(jax.random.key(s), t)
+        )(seeds, steps)
+        scaled = logits / jnp.where(temps > 0, temps, 1.0)[:, None]
+        sampled = jax.vmap(jax.random.categorical)(
+            keys, scaled
+        ).astype(jnp.int32)
+        return jnp.where(temps > 0, sampled, greedy)
+
+
+_select_greedy = jax.jit(tpudl_select)
+_select_tokens = jax.jit(tpudl_select_sampled)
 
 
 def first_token(logits, request) -> int:
@@ -297,6 +317,11 @@ class Engine:
                 )
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
         self.results: Dict[Any, Result] = {}
+        # The recorder of the step under way (tpudl.obs.spans), looked
+        # up once in ``step`` and read by what it calls; None outside a
+        # step and whenever recording is off.
+        self._rec = None
+        self._seats = 0  # prompts seated in the step under way
         # Streaming feed: called with (request_id, token) the moment a
         # token is selected (prefill's first token included) — BEFORE
         # the finish check, so a consumer sees eos arrive as a token
@@ -461,8 +486,17 @@ class Engine:
         cost drops from O(prompt window) to O(unshared suffix)."""
         req = entry.request
         ids = np.asarray(req.input_ids, np.int32)
-        rec = active_recorder()
+        rec = self._rec
         t0 = self.clock()
+        span = None
+        if rec is not None:
+            # request_id on the prefill span is the trace link between
+            # the queued event and this request's decode chunks.
+            span = rec.begin(
+                "prefill", CAT_SERVE_PREFILL, t0, slot=slot,
+                request_id=req.request_id,
+                queue_wait_s=t0 - entry.submitted_at,
+            )
         lease = None
         hit = 0
         tenant_pinned = False
@@ -523,15 +557,10 @@ class Engine:
                 self.adapter_pool.release(req.tenant)
             raise
         now = self.clock()
-        if rec is not None:
-            # request_id on the prefill span is the trace link between
-            # the queued event and this request's decode chunks;
+        if span is not None:
             # prefix_hit_tokens names how much of the prompt the radix
             # cache paid for (report.py --request's TTFT attribution).
-            rec.record("prefill", CAT_SERVE_PREFILL, t0, now - t0,
-                       {"slot": slot, "request_id": req.request_id,
-                        "queue_wait_s": t0 - entry.submitted_at,
-                        "prefix_hit_tokens": hit})
+            span.end(now, prefix_hit_tokens=hit)
         if hit:
             registry().counter("serve_prefix_hit_tokens").inc(hit)
         self.num_prefills += 1
@@ -573,6 +602,12 @@ class Engine:
             # anyway so the invariant "a bound slot holds a pin" has
             # one owner.
             self.adapter_pool.acquire(tenant)
+        span = None
+        if self._rec is not None:
+            span = self._rec.begin(
+                "seat", CAT_SERVE_SEAT, self.clock(),
+                request_id=req.request_id, slot=slot,
+            )
         try:
             if self.prefix_share:
                 ids = np.asarray(req.input_ids, np.int32)
@@ -604,6 +639,12 @@ class Engine:
             if self.adapter_pool is not None:
                 self.adapter_pool.release(tenant)
             raise
+        if span is not None:
+            span.end(
+                self.clock(),
+                pages=self.cache.pages_of(slot) if self.paged else 0,
+            )
+            self._seats += 1
         if self.adapter_pool is not None:
             # The seat pin transfers to the slot; free_slot drops it.
             self.adapter_pool.bind_slot(slot, tenant)
@@ -740,9 +781,20 @@ class Engine:
             # caches carried their own write indices (discarded by
             # insert); pin the shared index past the prompt region.
             self.cache.set_write_index(self.prompt_len)
-        registry().gauge("serve_slots_busy").set(
+        self._publish_occupancy()
+
+    def _publish_occupancy(self) -> None:
+        """Slots in use and, paged, the cache's reserved-against-live
+        counters, as gauges."""
+        reg = registry()
+        reg.gauge("serve_slots_busy").set(
             sum(s is not None for s in self._slots)
         )
+        if self.paged:
+            reg.gauge("serve_kv_pages_reserved").set(
+                self.cache.pages_reserved
+            )
+            reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
 
     def _fits(self, request) -> bool:
         """Can this request be seated RIGHT NOW? Dense: its worst case
@@ -1065,9 +1117,7 @@ class Engine:
         s.migrations = int(meta.get("migrations", 0)) + 1
         self._slots[slot] = s
         registry().counter("serve_migrations_installed").inc()
-        registry().gauge("serve_slots_busy").set(
-            sum(x is not None for x in self._slots)
-        )
+        self._publish_occupancy()
         rec = active_recorder()
         if rec is not None:
             rec.event(
@@ -1262,8 +1312,12 @@ class Engine:
             temps[i] = s.request.temperature
             seeds[i] = s.request.seed
             steps[i] = s.steps
-        rec = active_recorder()
+        rec = self._rec
         t0 = self.clock()
+        span = dispatch = None
+        if rec is not None:
+            span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
+            dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
         if self.adapter_pool is not None:
             logits, self.cache.cache = self.decode_call(
                 self.params, self.cache.cache, tokens, positions,
@@ -1279,15 +1333,25 @@ class Engine:
             logits, self.cache.cache = self.decode_call(
                 self.params, self.cache.cache, tokens, positions
             )
+        if temps.any():
+            sel = _select_tokens(logits, temps, seeds, steps)
+        else:
+            sel = _select_greedy(logits)
+        readback = None
+        if dispatch is not None:
+            # Every dispatch of the step has returned; what is left of
+            # decode_step is the wait for the device and the copy back.
+            t = self.clock()
+            dispatch.end(t)
+            readback = rec.begin("decode.readback", CAT_SERVE_DECODE, t)
         # Explicit readback (jax.device_get, not an implicit
         # np.asarray): the per-step token sync is the ONE intended
         # d2h in the decode steady state, and the dispatch-hygiene
         # audit (tpudl.analysis.assert_no_host_transfers) disallows
         # implicit transfers — intent made visible is the contract.
-        if temps.any():
-            sel = jax.device_get(_select_tokens(logits, temps, seeds, steps))
-        else:
-            sel = jax.device_get(_select_greedy(logits))
+        sel = jax.device_get(sel)
+        if readback is not None:
+            readback.end(self.clock())
         if self.paged:
             # Each ACTIVE slot's logical length advanced by one (idle
             # slots stay pinned on the trash page).
@@ -1297,14 +1361,22 @@ class Engine:
         else:
             self.cache.advance_write_index()  # host mirror of in-graph +1
         now = self.clock()
-        if rec is not None:
+        emit = None
+        if span is not None:
             # "rids" names every request this decode chunk advanced —
             # the per-request trace's decode leg (report.py --request
-            # selects the chunks containing its id).
-            rec.record("decode_step", CAT_SERVE_DECODE, t0, now - t0,
-                       {"busy": int(sum(s is not None for s in self._slots)),
-                        "rids": [s.request.request_id
-                                 for s in self._slots if s is not None]})
+            # selects the chunks containing its id). Paged: the pages
+            # the seated slots hold and the positions the step read
+            # (lens already counts the token just written).
+            busy = int(sum(s is not None for s in self._slots))
+            attrs = {"busy": busy,
+                     "rids": [s.request.request_id
+                              for s in self._slots if s is not None]}
+            if self.paged:
+                attrs["pages_reserved"] = self.cache.pages_reserved
+                attrs["tokens_live"] = self.cache.tokens_live
+            span.end(now, **attrs)
+            emit = rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
         registry().counter("serve_decode_steps").inc()
         for i, s in enumerate(self._slots):
@@ -1326,6 +1398,11 @@ class Engine:
             if self.on_token is not None:
                 self.on_token(s.request.request_id, tok)
             self._maybe_finish(i, tok)
+        if emit is not None:
+            emit.end(
+                self.clock(),
+                finished=busy - sum(s is not None for s in self._slots),
+            )
 
     def _spec_step(self) -> None:
         """One speculative window: k draft dispatches propose, ONE
@@ -1357,8 +1434,11 @@ class Engine:
             seeds[i] = s.request.seed
             token_index[i] = s.steps
         rids = [self._slots[i].request.request_id for i in active]
-        rec = active_recorder()
+        rec = self._rec
         t0 = self.clock()
+        span = dispatch = None
+        if rec is not None:
+            span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
         proposals, q_probs = spec.propose(
             tokens0, positions0, active, temps, seeds, token_index
         )
@@ -1368,16 +1448,29 @@ class Engine:
                                axis=1)
         pos_chunk = positions0[:, None] + np.arange(k, dtype=np.int32)[None, :]
         lens_before = {i: int(self.cache.lens[i]) for i in active}
+        if span is not None:
+            dispatch = rec.begin(
+                "decode.dispatch", CAT_SERVE_DECODE, self.clock()
+            )
         logits, self.cache.cache = self.verify_call(
             self.params, self.cache.cache, chunk, pos_chunk,
             *self.cache.dispatch_args(),
         )
         sampling = any(temps[i] > 0 for i in active)
+        verdict = logits if sampling else _select_greedy(logits)
+        readback = None
+        if dispatch is not None:
+            t = self.clock()
+            dispatch.end(t)
+            readback = rec.begin("decode.readback", CAT_SERVE_DECODE, t)
+        verdict = jax.device_get(verdict)
+        if readback is not None:
+            readback.end(self.clock())
         if sampling:
-            host_logits = np.asarray(jax.device_get(logits), np.float32)
+            host_logits = np.asarray(verdict, np.float32)
             target_choice = host_logits.argmax(axis=-1).astype(np.int32)
         else:
-            target_choice = jax.device_get(_select_greedy(logits))
+            target_choice = verdict
         now = self.clock()
         total_emitted = 0
         total_accepted = 0
@@ -1427,20 +1520,21 @@ class Engine:
                 self._maybe_finish(i, int(tok))
                 if self._slots[i] is None:
                     break
-        if rec is not None:
+        if span is not None:
             # accepted/proposed on every speculative decode chunk: the
             # per-step attribution report.py --request renders (where
             # did TPOT go — draft quality is readable off the ratio).
             # slot_accepted/slot_emitted align with rids so a single
             # request's trace sums ITS OWN numbers, not the batch's.
-            rec.record("decode_step", CAT_SERVE_DECODE, t0, now - t0,
-                       {"busy": len(active), "rids": rids,
-                        "proposed": k * len(active),
-                        "proposed_per_slot": k,
-                        "accepted": total_accepted,
-                        "emitted": total_emitted,
-                        "slot_accepted": slot_accepted,
-                        "slot_emitted": slot_emitted})
+            # The extent ends at ``now``, before the acceptance loop,
+            # as it always has.
+            span.end(now, busy=len(active), rids=rids,
+                     proposed=k * len(active), proposed_per_slot=k,
+                     accepted=total_accepted, emitted=total_emitted,
+                     slot_accepted=slot_accepted,
+                     slot_emitted=slot_emitted,
+                     pages_reserved=self.cache.pages_reserved,
+                     tokens_live=self.cache.tokens_live)
         self.num_decode_steps += 1
         reg = registry()
         reg.counter("serve_decode_steps").inc()
@@ -1457,26 +1551,48 @@ class Engine:
         """Seat what fits, run one decode step (speculative window when
         a speculator is attached). False when fully drained (no active
         slots and nothing seatable queued)."""
-        for hook in self.chaos_hooks:
-            # Fault injection (tpudl.serve.chaos): a kill hook raises
-            # (crashing the replica driver thread exactly like a real
-            # engine fault), a freeze hook sleeps here holding the
-            # whole loop (the stale-heartbeat path).
-            hook(self.num_decode_steps)
-        self._fill_slots()
-        if not self._active():
-            # Nothing seated: the queue is empty or held only expired
-            # entries (shed during the fill's pop).
-            self._record_shed(self.queue.drain_expired(), "shed_timeout")
-            return False
-        if self.speculator is not None:
-            self._spec_step()
-        else:
-            self._decode_step()
-        return True
+        # One look for the recorder a step; what the step calls reads
+        # ``self._rec``. With none, nothing below reads a clock or
+        # allocates for tracing.
+        rec = self._rec = active_recorder()
+        span = None
+        if rec is not None:
+            span = rec.begin("engine_step", CAT_SERVE_ENGINE, self.clock())
+            self._seats = 0
+        try:
+            for hook in self.chaos_hooks:
+                # Fault injection (tpudl.serve.chaos): a kill hook
+                # raises (crashing the replica driver thread exactly
+                # like a real engine fault), a freeze hook sleeps here
+                # holding the whole loop (the stale-heartbeat path).
+                hook(self.num_decode_steps)
+            self._fill_slots()
+            if not self._active():
+                # Nothing seated: the queue is empty or held only
+                # expired entries (shed during the fill's pop).
+                self._record_shed(
+                    self.queue.drain_expired(), "shed_timeout"
+                )
+                return False
+            if self.speculator is not None:
+                self._spec_step()
+            else:
+                self._decode_step()
+            return True
+        finally:
+            if span is not None:
+                # Closing it also drops what an exception left open
+                # beneath it. Its self time (queue pop, fit checks,
+                # shedding, the step's host arrays) is its duration
+                # less its children's.
+                self._rec = None
+                span.end(
+                    self.clock(), seats=self._seats,
+                    busy=sum(s is not None for s in self._slots),
+                )
 
     def run_until_drained(self) -> Dict[Any, Result]:
         while self.step():
             pass
-        registry().gauge("serve_slots_busy").set(0)
+        self._publish_occupancy()
         return self.results

@@ -307,9 +307,11 @@ def make_classification_train_step(
                 # mean — the bf16/fp8 forward never degrades the loss
                 # arithmetic itself.
                 outputs = outputs.astype(policy.reduce_dtype)
-            loss = cross_entropy_loss(
-                outputs, batch[label_key], label_smoothing, impl=loss_impl
-            )
+            with jax.named_scope("loss"):
+                loss = cross_entropy_loss(
+                    outputs, batch[label_key], label_smoothing,
+                    impl=loss_impl,
+                )
             aux = None
             if moe_aux_weight > 0.0:
                 aux = _sown_aux(mutated)
@@ -358,7 +360,8 @@ def make_classification_train_step(
         advance — all traced (one compiled program; a skipped step is
         a select, not a cond)."""
         prec = state.precision or {}
-        applied = state.apply_gradients(grads=grads)
+        with jax.named_scope("optimizer"):
+            applied = state.apply_gradients(grads=grads)
         if new_stats is not None:
             applied = applied.replace(batch_stats=new_stats)
         if policy.loss_scale is not None:
@@ -456,7 +459,11 @@ def make_classification_train_step(
             return _finish_policy_step(
                 state, grads, metrics, new_stats, prec_aux
             )
-        new_state = state.apply_gradients(grads=grads)
+        # Scopes name the step's device operations in a profiler trace
+        # (``loss`` above; a ``make_optimizer`` chain puts its clip
+        # under ``optimizer/grad_clip``).
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads=grads)
         if new_stats is not None:
             new_state = new_state.replace(batch_stats=new_stats)
         return new_state, metrics
@@ -648,16 +655,25 @@ def compile_step(
     batch_sh = NamedSharding(mesh, batch_partition_spec())
     repl = NamedSharding(mesh, PartitionSpec())
 
+    # Programs under names of their own: a trace's ``XLA Modules`` line
+    # then reads ``jit_tpudl_train_step``, whatever the step function
+    # was called.
     if has_rng:
+        def tpudl_train_step(state, batch, rng):
+            return step_fn(state, batch, rng)
+
         jitted = jax.jit(
-            step_fn,
+            tpudl_train_step,
             in_shardings=(state_sh, batch_sh, repl),
             out_shardings=(state_sh, repl),
             donate_argnums=(0,) if donate_state else (),
         )
     else:
+        def tpudl_eval_step(state, batch):
+            return step_fn(state, batch)
+
         jitted = jax.jit(
-            step_fn,
+            tpudl_eval_step,
             in_shardings=(state_sh, batch_sh),
             out_shardings=repl,
             donate_argnums=(0,) if donate_state else (),
@@ -668,7 +684,7 @@ def compile_step(
     if steps_per_dispatch > 1:
         window_sh = NamedSharding(mesh, window_partition_spec())
 
-        def _window_fn(state, window, rng):
+        def tpudl_window_step(state, window, rng):
             # One compiled program for K steps: the scan body IS the
             # single-step function (one copy of the layer graph in the
             # executable), the state threads through the carry with the
@@ -683,7 +699,7 @@ def compile_step(
             return jax.lax.scan(body, state, window)
 
         jitted_window = jax.jit(
-            _window_fn,
+            tpudl_window_step,
             in_shardings=(state_sh, window_sh, repl),
             out_shardings=(state_sh, repl),
             donate_argnums=(0,) if donate_state else (),
@@ -850,14 +866,18 @@ def _obs_pull(rec, it, attrs):
     arm shared by fit() and evaluate() (their uninstrumented fast paths
     stay inline so the disabled mode allocates nothing per step).
     Returns ``(batch, wait_seconds)`` or ``None`` on exhaustion."""
-    t0 = rec.clock()
+    span = rec.begin("data_wait", obs_spans.CAT_DATA_WAIT, **attrs)
     try:
         batch = next(it)
     except StopIteration:
+        span.cancel()
         return None
-    dur = rec.clock() - t0
-    rec.record("data_wait", obs_spans.CAT_DATA_WAIT, t0, dur, attrs)
-    return batch, dur
+    return batch, span.end()["dur"]
+
+
+def _to_host_arrays(metrics: dict) -> dict:
+    """The same for a fused window's [K]-stacked metrics."""
+    return {k: np.asarray(v) for k, v in metrics.items()}
 
 
 def _to_host_metrics(metrics: dict) -> dict:
@@ -1010,7 +1030,6 @@ def fit(
         h_data = reg.histogram("data_wait_s")
         h_compile = reg.histogram("compile_time_s")
         h_mwait = reg.histogram("metric_wait_s") if fetcher else None
-        clock = rec.clock
 
     # Live telemetry (tpudl.obs.exporter): with TPUDL_OBS_PORT set the
     # process serves /metrics | /healthz | /snapshot while fit runs;
@@ -1092,19 +1111,35 @@ def fit(
             if log_every and step_no % log_every == 0:
                 _log_line(step_no, hm)
 
+    def _read_back(step_no, read, m):
+        """The synchronous ``log_every`` path's blocking read-back of
+        one dispatch's metrics. The dispatch returned at once, so this
+        wait holds the device's whole step: goodput shows a
+        ``log_every=1`` run as waiting on its metrics."""
+        if rec is None:
+            return read(m)
+        span = rec.begin(
+            "metric_wait", obs_spans.CAT_METRIC_WAIT, step=step_no
+        )
+        host = read(m)
+        span.end()
+        return host
+
     def _submit(first_step, m, count):
         """Queue one dispatch's device metrics on the async fetcher and
         drain whatever finished — never blocking except on the bounded
         window (recorded as metric_wait)."""
         if rec is not None:
-            t0 = clock()
+            span = rec.begin(
+                "metric_wait", obs_spans.CAT_METRIC_WAIT,
+                step=first_step + count - 1,
+            )
             waited = fetcher.submit(first_step, m, count)
             if waited > 0:
-                rec.record(
-                    "metric_wait", obs_spans.CAT_METRIC_WAIT, t0, waited,
-                    {"step": first_step + count - 1},
-                )
+                span.end(span.t0 + waited)
                 h_mwait.observe(waited)
+            else:
+                span.cancel()
         else:
             fetcher.submit(first_step, m, count)
         _deliver(fetcher.ready())
@@ -1145,7 +1180,12 @@ def fit(
                 and not pending
                 and (num_steps is None or num_steps - i >= K)
             ):
-                t0 = clock() if rec is not None else 0.0
+                span = None
+                if rec is not None:
+                    span = rec.begin(
+                        "data_wait", obs_spans.CAT_DATA_WAIT,
+                        step=i, window=K,
+                    )
                 if use_pf_window:
                     window = it.pull_window()
                     if window is None:
@@ -1166,13 +1206,11 @@ def fit(
                 # still blocked on the device queue (the ragged-tail
                 # single arriving) and that time is input starvation,
                 # not idle.
-                if rec is not None and (
-                    window is not None or pending or use_pf_window
-                ):
-                    dur = clock() - t0
-                    rec.record("data_wait", obs_spans.CAT_DATA_WAIT, t0,
-                               dur, {"step": i, "window": K})
-                    h_data.observe(dur)
+                if span is not None:
+                    if window is not None or pending or use_pf_window:
+                        h_data.observe(span.end()["dur"])
+                    else:
+                        span.cancel()
 
             if window is not None:
                 # Window-granularity profiling: start before the first
@@ -1198,22 +1236,25 @@ def fit(
                         compiled_step, "_tpudl_window_compile_pending",
                         False,
                     )
-                    t0 = clock()
+                    # ONE span covers K steps (its "window" attr is
+                    # how goodput counts them).
+                    span = (
+                        rec.begin("compile_step", obs_spans.CAT_COMPILE,
+                                  step=i, window=K)
+                        if is_compile else
+                        rec.begin("dispatch_window", obs_spans.CAT_STEP,
+                                  step=i, window=K)
+                    )
                     state, metrics = window_step(state, window, rng)
-                    t1 = clock()
+                    dur = span.end()["dur"]
                     if is_compile:
-                        rec.record("compile_step", obs_spans.CAT_COMPILE,
-                                   t0, t1 - t0, {"step": i, "window": K})
-                        h_compile.observe(t1 - t0)
+                        h_compile.observe(dur)
                     else:
-                        # ONE span covers K steps (its "window" attr is
-                        # how goodput counts them); the per-step
-                        # histogram gets K observations of the
-                        # amortized time so its count stays per-step.
-                        rec.record("dispatch_window", obs_spans.CAT_STEP,
-                                   t0, t1 - t0, {"step": i, "window": K})
+                        # The per-step histogram gets K observations of
+                        # the amortized time so its count stays
+                        # per-step.
                         for _ in range(K):
-                            h_step.observe((t1 - t0) / K)
+                            h_step.observe(dur / K)
                 metrics_count = K
                 dispatches += 1
                 heartbeat.beat(step=i + K)
@@ -1243,10 +1284,9 @@ def fit(
                     for s in range(first, i + 1):
                         if s % log_every == 0:
                             if host_all is None:
-                                host_all = {
-                                    k: np.asarray(v)
-                                    for k, v in metrics.items()
-                                }
+                                host_all = _read_back(
+                                    i, _to_host_arrays, metrics
+                                )
                             _log_line(s, {
                                 k: float(a[s - first])
                                 for k, a in host_all.items()
@@ -1285,17 +1325,15 @@ def fit(
                 is_compile = getattr(
                     compiled_step, "_tpudl_compile_pending", False
                 )
-                t0 = clock()
+                span = (
+                    rec.begin("compile_step", obs_spans.CAT_COMPILE, step=i)
+                    if is_compile else
+                    rec.begin("train_step", obs_spans.CAT_STEP, step=i)
+                )
                 state, metrics = compiled_step(state, batch, rng)
-                t1 = clock()
-                if is_compile:
-                    rec.record("compile_step", obs_spans.CAT_COMPILE,
-                               t0, t1 - t0, {"step": i})
-                    h_compile.observe(t1 - t0)
-                else:
-                    rec.record("train_step", obs_spans.CAT_STEP,
-                               t0, t1 - t0, {"step": i})
-                    h_step.observe(t1 - t0)
+                (h_compile if is_compile else h_step).observe(
+                    span.end()["dur"]
+                )
             metrics_count = 1
             dispatches += 1
             heartbeat.beat(step=i + 1)
@@ -1317,7 +1355,9 @@ def fit(
             if fetcher is not None:
                 _submit(i + 1, metrics, 1)
             elif log_every and (i + 1) % log_every == 0:
-                _log_line(i + 1, _to_host_metrics(metrics))
+                _log_line(
+                    i + 1, _read_back(i + 1, _to_host_metrics, metrics)
+                )
             i += 1
     finally:
         # Orderly exit (or unwind) is "finished", not "hung": a stopped
@@ -1338,15 +1378,12 @@ def fit(
             propagating = _sys.exc_info()[0] is not None
             try:
                 if rec is not None:
-                    t0 = clock()
+                    span = rec.begin(
+                        "metric_wait", obs_spans.CAT_METRIC_WAIT,
+                        flush=True,
+                    )
                     _deliver(fetcher.flush())
-                    dur = clock() - t0
-                    if dur > 0:
-                        rec.record(
-                            "metric_wait", obs_spans.CAT_METRIC_WAIT,
-                            t0, dur, {"flush": True},
-                        )
-                        h_mwait.observe(dur)
+                    h_mwait.observe(span.end()["dur"])
                 else:
                     _deliver(fetcher.flush())
             except BaseException:
@@ -1452,17 +1489,16 @@ def evaluate(
             is_compile = getattr(
                 compiled_eval_step, "_tpudl_compile_pending", False
             )
-            t0 = rec.clock()
-            metrics = compiled_eval_step(state, batch)
-            t1 = rec.clock()
             # CAT_EVAL, not CAT_STEP: eval steps have their own duration
             # scale — mixing them into the train-step distribution would
             # skew the report's outlier and straggler statistics.
-            rec.record(
+            span = rec.begin(
                 "eval_step",
                 obs_spans.CAT_COMPILE if is_compile else obs_spans.CAT_EVAL,
-                t0, t1 - t0, {"step": i, "phase": "eval"},
+                step=i, phase="eval",
             )
+            metrics = compiled_eval_step(state, batch)
+            span.end()
         n_examples += weight
         for k, v in metrics.items():
             totals[k] = totals.get(k, 0.0) + v * weight

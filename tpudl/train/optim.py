@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -25,6 +26,18 @@ def make_schedule(cfg: OptimConfig) -> optax.Schedule:
     return sched
 
 
+def scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update under ``jax.named_scope(name)``: a
+    profiler trace then says which device operations are its (HLO
+    metadata only, nothing at run time)."""
+
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
+
+
 def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
     sched = make_schedule(cfg)
     if cfg.name == "sgd":
@@ -41,5 +54,8 @@ def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
             mu_dtype=jnp.dtype(cfg.mu_dtype),
         )
     if cfg.grad_clip_norm:
-        tx = optax.chain(optax.clip_by_global_norm(cfg.grad_clip_norm), tx)
+        tx = optax.chain(
+            scoped("grad_clip", optax.clip_by_global_norm(cfg.grad_clip_norm)),
+            tx,
+        )
     return tx
