@@ -47,6 +47,14 @@ _s = jax.ShapeDtypeStruct
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
+def _placed(tree, sharding):
+    """The shapes of ``tree``, placed: there is no device to hold an
+    array, so the compiler is handed shapes."""
+    return jax.tree.map(
+        lambda a: _s(a.shape, a.dtype, sharding=sharding), tree
+    )
+
+
 def _grad(fn, argnums):
     def loss(*args):
         return sum(
@@ -191,14 +199,94 @@ def test_kernel_compiles_for_v5e(name, no_compile_cache):
     if device is None:
         pytest.skip("this installation cannot describe a v5e topology")
     fn, specs = CASES[name]()
-    on_chip = SingleDeviceSharding(device)
-    args = jax.tree.map(
-        lambda spec: _s(spec.shape, spec.dtype, sharding=on_chip), specs
-    )
+    args = _placed(specs, SingleDeviceSharding(device))
     compiled = jax.jit(fn).lower(*args).compile()
     # The kernel is IN the program: neither interpreted nor swapped
     # for the composite.
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# The page pool changes hands: the session's own pool programs, at the
+# benchmark's pool size, write in place on the chip.
+# ---------------------------------------------------------------------------
+
+#: Mistral-7B-v0.3's widths and the serving cells' session
+#: (perfbench/configs/mistral-7b-v0.3-l16.json), two layers deep: what
+#: is asked is asked of each layer alike.
+POOL_SLOTS, POOL_WINDOW, POOL_SEQ, POOL_PAGE = 48, 512, 1024, 16
+POOL_SHAPE = (POOL_SLOTS * POOL_SEQ // POOL_PAGE + 1, POOL_PAGE, 8, 128)
+
+
+@pytest.fixture(scope="module")
+def pool_session():
+    """A session over shapes alone (nothing is run), its own pool as
+    small as a pool may be: the programs are lowered at POOL_SHAPE."""
+    from tpudl.models.generate import prefill_fn
+    from tpudl.models.llama import LlamaConfig, LlamaForCausalLM
+    from tpudl.serve import ServeSession
+
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=32768, hidden_size=4096, num_layers=2, num_heads=32,
+        num_kv_heads=8, intermediate_size=14336, max_seq_len=POOL_SEQ,
+        rope_theta=1e6, dtype=bf16,
+    ))
+    ids = _s((1, POOL_WINDOW), i32)
+    params = jax.eval_shape(model.init, jax.random.key(0), ids)["params"]
+    session = ServeSession.from_model(
+        model, params, prompt_len=POOL_WINDOW, num_slots=POOL_SLOTS,
+        paged=True, page_size=POOL_PAGE,
+        num_pages=POOL_SEQ // POOL_PAGE + 1,
+    )
+    _, row = jax.eval_shape(prefill_fn(model), params, ids, ids)
+    return session, params, row
+
+
+def _pool_program(name, session, params, row, on_chip):
+    cache = session.engine.cache
+    pool = jax.tree.map(
+        lambda leaf: _s(POOL_SHAPE, leaf.dtype, sharding=on_chip),
+        cache.cache,
+    )
+    vec = _s((POOL_SLOTS,), i32, sharding=on_chip)
+    if name == "decode":
+        table = _s((POOL_SLOTS, POOL_SEQ // POOL_PAGE), i32,
+                   sharding=on_chip)
+        return session.engine.decode_call.lower(
+            _placed(params, on_chip), pool, vec, vec, table, vec, vec
+        )
+    pages = POOL_WINDOW // POOL_PAGE
+    return cache._seat_program(pages).lower(
+        pool, _placed(row, on_chip), _s((pages,), i32, sharding=on_chip)
+    )
+
+
+@pytest.mark.parametrize("name", ["decode", "seat"])
+def test_pool_program_writes_in_place_on_v5e(
+    name, pool_session, no_compile_cache
+):
+    """What ISSUE 25 removed stays removed: the chip's compiler aliases
+    every pool leaf to its successor and makes no pool-shaped copy (the
+    parent's decode held 32 a step at 16 layers, its seat 33)."""
+    import math
+    import re
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    session, params, row = pool_session
+    compiled = _pool_program(
+        name, session, params, row, SingleDeviceSharding(device)
+    ).compile()
+    leaves = jax.tree.leaves(session.engine.cache.cache)
+    pool_bytes = len(leaves) * math.prod(POOL_SHAPE) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    shape = ",".join(map(str, POOL_SHAPE))
+    copies = re.findall(
+        rf"= bf16\[{shape}\][^ ]* copy(?:-start|-done)?\(",
+        compiled.as_text(),
+    )
+    assert not copies
 
 
 # ---------------------------------------------------------------------------

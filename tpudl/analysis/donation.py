@@ -5,9 +5,12 @@ PR 5's donation test proved the fused train step donates its state
 that check lived inside one test. This generalizes it: hand
 ``audit_donation`` any compiled callable plus its args, name which
 positional args the program is supposed to donate, and get back the
-outputs plus a report — so serving decode (donates its KV cache),
-the fused K-step window, and future compiled paths all audit with the
-same ten lines.
+outputs plus a report — so the fused train step and its K-step
+window, and every serving program that takes the paged KV pool and
+returns it (decode, speculative verify, the seat programs, the
+migration scatter: the ownership rule of
+``tpudl.serve.cache.PagedKVCache``, audited in
+tests/test_serve_donation.py), all audit with the same ten lines.
 
 Donation failing SILENTLY is the point: XLA falls back to copying when
 a donated buffer cannot be aliased (layout mismatch, an extra

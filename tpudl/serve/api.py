@@ -406,8 +406,11 @@ class ServeSession:
                 kv_dtype=kv_dtype,
                 prefix_share=bool(prefix_share),
             )
+            # Every program that takes the pool and returns its
+            # successor donates it (PagedKVCache: the ownership rule).
             decode = jax.jit(
-                paged_decode_fn(model, cache.page_size, cache.quantized)
+                paged_decode_fn(model, cache.page_size, cache.quantized),
+                donate_argnums=(1,),
             )
             if adapters is not None:
                 from tpudl.serve.lora import AdapterPool
@@ -445,10 +448,13 @@ class ServeSession:
                 for tenant, tree in adapters.items():
                     pool.register(tenant, tree, alpha=adapter_alpha)
                 kwargs["adapter_pool"] = pool
-                decode = jax.jit(lora_paged_decode_fn(
-                    model, cache.page_size, cache.quantized,
-                    impl=adapter_impl,
-                ))
+                decode = jax.jit(
+                    lora_paged_decode_fn(
+                        model, cache.page_size, cache.quantized,
+                        impl=adapter_impl,
+                    ),
+                    donate_argnums=(1,),
+                )
             if prefix_share:
                 chunk_prefill = jax.jit(chunk_prefill_fn(model))
             if spec_k:
@@ -482,12 +488,15 @@ class ServeSession:
                     jax.jit(named(
                         prefill_fn(draft_model), "tpudl_draft_prefill"
                     )),
-                    jax.jit(named(
-                        paged_decode_fn(
-                            draft_model, draft_cache.page_size, False
+                    jax.jit(
+                        named(
+                            paged_decode_fn(
+                                draft_model, draft_cache.page_size, False
+                            ),
+                            "tpudl_draft_decode",
                         ),
-                        "tpudl_draft_decode",
-                    )),
+                        donate_argnums=(1,),
+                    ),
                     draft_params,
                     draft_cache,
                     k=spec_k,
@@ -495,9 +504,12 @@ class ServeSession:
                         draft_params
                     )["total_bytes"],
                 )
-                verify = jax.jit(paged_chunk_decode_fn(
-                    model, cache.page_size, cache.quantized
-                ))
+                verify = jax.jit(
+                    paged_chunk_decode_fn(
+                        model, cache.page_size, cache.quantized
+                    ),
+                    donate_argnums=(1,),
+                )
         elif page_size is not None or kv_dtype is not None or (
             num_pages is not None
         ):
@@ -604,8 +616,13 @@ class ServeSession:
             )
         else:
             _, cache_template, token_aval, _ = dec_args
+        # An artifact's call donates nothing by itself: the pool rule
+        # (PagedKVCache) is applied where the artifact is loaded.
+        decode = (
+            jax.jit(dec.call, donate_argnums=(1,)) if is_paged else dec.call
+        )
         session = cls(
-            pre.call, dec.call, params, cache_template, prompt_len,
+            pre.call, decode, params, cache_template, prompt_len,
             cache=cache, **kwargs,
         )
         if session.num_slots != int(token_aval.shape[0]):

@@ -29,6 +29,7 @@ batch-independent, so a refill is bit-invisible to the other slots
 
 from __future__ import annotations
 
+import functools
 import json
 import struct
 import threading
@@ -37,6 +38,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from tpudl.obs import registry
 
 
 def _is_valid_leaf(leaf) -> bool:
@@ -568,6 +571,19 @@ class PagedKVCache:
     dispatch as small traced inputs — seating and freeing never
     recompile anything.
 
+    **The pool changes hands, it is not copied.** ``self.cache`` is the
+    ONE live pool tree. Every compiled program that takes it and
+    returns its successor (decode, verify, the seat programs, the
+    migration scatter) donates it, so the scatter happens in place and
+    no second pool is ever allocated; a tree that went into such a
+    program is dead afterwards (``Array has been deleted``). So nothing
+    keeps a pool tree: programs that write go through ``decode`` or the
+    seat / import methods here, programs that only read (the gathers)
+    take ``self.cache`` at the moment they are dispatched. The counter
+    ``serve_kv_pool_copies`` counts the dispatches whose pool survived,
+    which is XLA (or a program jitted without ``donate_argnums``)
+    copying after all; it reads 0.
+
     ``prefix_share=True`` adds the RADIX layer (``RadixPrefixTree``):
     seating goes LEFT-ALIGNED through ``seat_shared`` — token ``i`` at
     logical position ``i``, so identical token prefixes are
@@ -878,19 +894,24 @@ class PagedKVCache:
         self.lens[slot] = prompt_len
         self._seated(slot, len(pages))
         prompt_pages = self.pages_needed(prompt_len)
-        fn = self._seat_jit.get(prompt_pages)
-        if fn is None:
-            fn = jax.jit(self._make_seat_fn(prompt_pages))
-            self._seat_jit[prompt_pages] = fn
-        self.cache = fn(
-            self.cache, row_cache,
+        self._replace_pool(
+            self._seat_program(prompt_pages), row_cache,
             jnp.asarray(pages[:prompt_pages], jnp.int32),
         )
 
+    def _seat_program(self, prompt_pages: int):
+        """The jitted, pool-donating scatter for prompts of
+        ``prompt_pages`` pages: one program per distinct count (in
+        practice one — the session's prompt window is fixed)."""
+        fn = self._seat_jit.get(prompt_pages)
+        if fn is None:
+            fn = self._seat_jit[prompt_pages] = jax.jit(
+                self._make_seat_fn(prompt_pages), donate_argnums=(0,)
+            )
+        return fn
+
     def _make_seat_fn(self, prompt_pages: int):
-        """Build the jitted scatter: dense prefill row -> page pool.
-        One program per distinct prompt page count (in practice one —
-        the session's prompt window is fixed)."""
+        """The scatter itself: dense prefill row -> page pool."""
         from tpudl.models.paged import quantize_kv
 
         ps, quantized = self.page_size, self.quantized
@@ -1023,9 +1044,11 @@ class PagedKVCache:
         page_ids = np.zeros((self.pages_per_slot,), np.int32)
         page_ids[m:prompt_pages] = new_pages[: prompt_pages - m]
         if self._seat_shared_fn is None:
-            self._seat_shared_fn = jax.jit(self._make_seat_shared_fn())
-        self.cache = self._seat_shared_fn(
-            self.cache, row_cache, jnp.asarray(page_ids),
+            self._seat_shared_fn = jax.jit(
+                self._make_seat_shared_fn(), donate_argnums=(0,)
+            )
+        self._replace_pool(
+            self._seat_shared_fn, row_cache, jnp.asarray(page_ids),
             jnp.int32(row_offset),
         )
         # The prompt's full pages enter the tree (tree-owned: they go
@@ -1339,8 +1362,8 @@ class PagedKVCache:
         # written (reserve), exactly like seat_shared's skip contract.
         page_ids = np.zeros((self.pages_per_slot,), np.int32)
         page_ids[m:used] = self.page_table[slot, m:used]
-        self.cache = _migration_scatter(
-            self.cache, rows, jnp.asarray(page_ids)
+        self._replace_pool(
+            _migration_scatter, rows, jnp.asarray(page_ids)
         )
         tree_pages = 0
         node = None
@@ -1409,6 +1432,33 @@ class PagedKVCache:
             jnp.asarray(self.start),
             jnp.asarray(self.lens),
         )
+
+    def decode(self, program, params, tokens, positions, *extra):
+        """Dispatch one program of the paged decode contract
+        (``paged_decode_fn``, ``paged_chunk_decode_fn``, the LoRA
+        decode with its adapter arguments in ``extra``) on the pool,
+        keep the pool it returns and hand back the logits."""
+        pool = self.cache
+        logits, self.cache = program(
+            params, pool, tokens, positions, *self.dispatch_args(), *extra
+        )
+        self._handed_over(pool)
+        return logits
+
+    def _replace_pool(self, program, *args) -> None:
+        """``program(pool, *args) -> pool``: a seat or an import."""
+        pool = self.cache
+        self.cache = program(pool, *args)
+        self._handed_over(pool)
+
+    @staticmethod
+    def _handed_over(pool) -> None:
+        """``pool`` went into a program that returned its successor.
+        One leaf tells whether it was donated (no device call): a leaf
+        that is still alive was copied."""
+        copies = registry().counter("serve_kv_pool_copies")
+        if not jax.tree.leaves(pool)[0].is_deleted():
+            copies.inc()
 
     def advance(self, slots, steps: int = 1) -> None:
         """Advance the logical length of each ACTIVE slot after a
@@ -1602,7 +1652,7 @@ def _migration_gather(cache, page_ids):
     return _map_pools(cache, one)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _migration_scatter(cache, rows, page_ids):
     """Write a full-span row pytree into the pools at ``page_ids``
     (entries pinned to 0 land in the trash page — how matched-prefix

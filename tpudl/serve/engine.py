@@ -1318,16 +1318,15 @@ class Engine:
         if rec is not None:
             span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
             dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
-        if self.adapter_pool is not None:
-            logits, self.cache.cache = self.decode_call(
-                self.params, self.cache.cache, tokens, positions,
-                *self.cache.dispatch_args(),
-                *self.adapter_pool.dispatch_args(),
+        if self.paged:
+            # Tenant adapters ride the paged contract as three more
+            # traced inputs (adapters imply a paged cache).
+            adapters = (
+                self.adapter_pool.dispatch_args()
+                if self.adapter_pool is not None else ()
             )
-        elif self.paged:
-            logits, self.cache.cache = self.decode_call(
-                self.params, self.cache.cache, tokens, positions,
-                *self.cache.dispatch_args(),
+            logits = self.cache.decode(
+                self.decode_call, self.params, tokens, positions, *adapters
             )
         else:
             logits, self.cache.cache = self.decode_call(
@@ -1452,9 +1451,8 @@ class Engine:
             dispatch = rec.begin(
                 "decode.dispatch", CAT_SERVE_DECODE, self.clock()
             )
-        logits, self.cache.cache = self.verify_call(
-            self.params, self.cache.cache, chunk, pos_chunk,
-            *self.cache.dispatch_args(),
+        logits = self.cache.decode(
+            self.verify_call, self.params, chunk, pos_chunk
         )
         sampling = any(temps[i] > 0 for i in active)
         verdict = logits if sampling else _select_greedy(logits)

@@ -259,9 +259,8 @@ class Speculator:
         cur_pos = np.asarray(positions0, np.int32).copy()
         lens_before = {i: int(self.cache.lens[i]) for i in active}
         for j in range(k):
-            logits, self.cache.cache = self.decode_call(
-                self.params, self.cache.cache, cur_tok, cur_pos,
-                *self.cache.dispatch_args(),
+            logits = self.cache.decode(
+                self.decode_call, self.params, cur_tok, cur_pos
             )
             if sampling:
                 host = np.asarray(jax.device_get(logits), np.float32)
