@@ -542,11 +542,20 @@ def test_frozen_replica_goes_stale_then_recovers(programs):
     migration pull, so resubmission covers it), and when the freeze
     ends the replica publishes again and scrapes ready."""
     sessions = [_session(programs, slow_s=0.01) for _ in range(2)]
+    for session in sessions:
+        # Each session compiles its own seat program at its first seat;
+        # behind the router that compile alone can outlast the 0.15 s
+        # staleness bound, r0's work fails over before it reaches the
+        # step that freezes, and nothing is frozen at all.
+        session.serve([Request("warm", [1, 2, 3], max_new_tokens=2)])
     replicas = [
         Replica("r0", sessions[0], stale_after_s=0.15),
         Replica("r1", sessions[1]),
     ]
-    sessions[0].engine.chaos_hooks.append(chaos.step_freezer(3, 0.6))
+    # The freeze has to outlast the collect below (0.48-0.63 s here, on
+    # this tree and on its parent alike): at 0.6 s the replica had
+    # sometimes recovered before the assertion looked.
+    sessions[0].engine.chaos_hooks.append(chaos.step_freezer(3, 2.0))
     requests = [
         Request(f"q{i}", [3 + i, 5, 7], max_new_tokens=16)
         for i in range(4)

@@ -289,6 +289,73 @@ def test_pool_program_writes_in_place_on_v5e(
     assert not copies
 
 
+# The latent (MLA) pool of ISSUE 26 at its cell's size: sarvam-105b's
+# widths (perfbench/configs/sarvam-105b-l5-e32.json), one dense and one
+# expert layer deep. The decode and seat programs compile for the chip,
+# take ONE donated pool a layer and fit its memory. What this does NOT
+# hold: the chip lays a headless [NP, 16, 576] pool out page-index-minor
+# (576 is 4.5 lanes of 128), so these programs re-lay the pool on the
+# way in and out (PERF.md sections 6 and 7); the test counts no copies
+# until a layout is chosen for it.
+LATENT_SLOTS, LATENT_WINDOW, LATENT_SEQ = 128, 512, 1280
+LATENT_SHAPE = (LATENT_SLOTS * LATENT_SEQ // POOL_PAGE + 1, POOL_PAGE, 576)
+
+
+@pytest.mark.parametrize("name", ["decode", "seat"])
+def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
+    from tpudl.models.generate import prefill_fn
+    from tpudl.models.llama import LlamaConfig, LlamaForCausalLM, RopeScaling
+    from tpudl.serve import ServeSession
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    on_chip = SingleDeviceSharding(device)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=65536, hidden_size=4096, num_layers=2, num_heads=64,
+        num_kv_heads=64, intermediate_size=16384, max_seq_len=LATENT_SEQ,
+        rope_theta=10000.0, rms_norm_eps=1e-6, dtype=bf16, attention="mla",
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128,
+        rope_scaling=RopeScaling(40.0, 4096, mscale=1.0, mscale_all_dim=1.0),
+        num_experts=128, experts_per_token=8, moe_intermediate_size=2048,
+        num_shared_experts=1, routed_scaling_factor=2.5, first_k_dense=1,
+        experts_held=(0, 32),
+    ))
+    ids = _s((1, LATENT_WINDOW), i32)
+    params = jax.eval_shape(model.init, jax.random.key(0), ids)["params"]
+    session = ServeSession.from_model(
+        model, params, prompt_len=LATENT_WINDOW, num_slots=LATENT_SLOTS,
+        paged=True, page_size=POOL_PAGE,
+        num_pages=LATENT_SEQ // POOL_PAGE + 1,
+    )
+    cache = session.engine.cache
+    leaves = jax.tree.leaves(cache.cache)
+    assert len(leaves) == 2  # ONE pool a layer
+    pool = jax.tree.map(
+        lambda leaf: _s(LATENT_SHAPE, leaf.dtype, sharding=on_chip),
+        cache.cache,
+    )
+    vec = _s((LATENT_SLOTS,), i32, sharding=on_chip)
+    if name == "decode":
+        table = _s((LATENT_SLOTS, LATENT_SEQ // POOL_PAGE), i32,
+                   sharding=on_chip)
+        lowered = session.engine.decode_call.lower(
+            _placed(params, on_chip), pool, vec, vec, table, vec, vec
+        )
+    else:
+        _, row, _ = jax.eval_shape(prefill_fn(model), params, ids, ids)
+        pages = LATENT_WINDOW // POOL_PAGE
+        lowered = cache._seat_program(pages).lower(
+            pool, _placed(row, on_chip), _s((pages,), i32, sharding=on_chip)
+        )
+    memory = lowered.compile().memory_analysis()
+    # Both pools are donated, and the weights of two layers, the pools
+    # and the step's temporaries fit the chip.
+    assert memory.alias_size_in_bytes >= 2 * 10241 * 16 * 576 * 2
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
 # ---------------------------------------------------------------------------
 # chip_smoke.py on the CPU: the phases at a tiny size, through a path
 # only the tests take — the script's own device check is not weakened.

@@ -18,6 +18,7 @@ what it would alone (tests/test_generate.py).
 from __future__ import annotations
 
 import functools
+import re
 from typing import Optional
 
 import jax
@@ -32,6 +33,37 @@ def named(fn, name: str):
     return fn
 
 
+#: The collection a routed-expert layer sows its token counts into
+#: (tpudl.ops.moe.DroplessMoE).
+MOE_STATS = "moe_stats"
+
+
+def _apply_cached(model, variables, *args, **kwargs):
+    """``model.apply`` with the cache mutable: ``(output, cache,
+    *counts)``. A model with routed experts adds ONE int32 array
+    ``[expert layers, experts held]``, the real tokens each held
+    expert got in this call, layers in order; other models add
+    nothing, and their contracts return the pair they always did."""
+    out, mutated = model.apply(
+        variables, *args, mutable=["cache", MOE_STATS], **kwargs
+    )
+    stats = jax.tree_util.tree_flatten_with_path(
+        mutated.get(MOE_STATS, {})
+    )[0]
+    if not stats:
+        return out, mutated["cache"]
+
+    def layer(path) -> int:
+        return int(re.search(
+            r"layer_(\d+)", jax.tree_util.keystr(path)
+        ).group(1))
+
+    counts = jnp.stack(
+        [leaf for _, leaf in sorted(stats, key=lambda pl: layer(pl[0]))]
+    )
+    return out, mutated["cache"], counts
+
+
 # The contract functions below carry names of their own (``tpudl_prefill``,
 # ``tpudl_decode``, ...) for the same reason: the trace's ``XLA Modules``
 # line then reads ``jit_tpudl_decode``, not ``jit_fn``.
@@ -41,21 +73,24 @@ def prefill_fn(model):
     """THE functional prefill contract (cache as explicit pytree I/O):
     (params, input_ids, attention_mask) -> (last_logits, cache). One
     definition serves both the live loop below and the serving export
-    (tpudl.export.decode) — they cannot diverge."""
+    (tpudl.export.decode) — they cannot diverge. A model with routed
+    experts returns a third value, its tokens per held expert
+    (``_apply_cached``); so do the paged decode, chunked prefill and
+    verify contracts below."""
 
     def tpudl_prefill(params, input_ids, attention_mask):
         positions = jnp.maximum(
             jnp.cumsum(attention_mask, axis=-1) - 1, 0
         ).astype(jnp.int32)
-        logits, mutated = model.apply(
+        logits, *rest = _apply_cached(
+            model,
             {"params": params},
             input_ids,
             attention_mask,
             decode=True,
             positions=positions,
-            mutable=["cache"],
         )
-        return logits[:, -1, :], mutated["cache"]
+        return (logits[:, -1, :], *rest)
 
     return tpudl_prefill
 
@@ -96,16 +131,16 @@ def paged_decode_fn(model, page_size: int, quantized: bool):
             page_table=page_table, start=start, lens=lens,
             page_size=page_size, quantized=quantized,
         )
-        logits, mutated = model.apply(
+        logits, *rest = _apply_cached(
+            model,
             {"params": params, "cache": cache},
             token[:, None],
             jnp.ones_like(token)[:, None],
             decode=True,
             positions=position[:, None],
             paged=view,
-            mutable=["cache"],
         )
-        return logits[:, -1, :], mutated["cache"]
+        return (logits[:, -1, :], *rest)
 
     return tpudl_decode
 
@@ -194,15 +229,15 @@ def chunk_prefill_fn(model):
     cold full prefill."""
 
     def tpudl_chunk_prefill(params, cache, tokens, positions):
-        logits, mutated = model.apply(
+        logits, *rest = _apply_cached(
+            model,
             {"params": params, "cache": cache},
             tokens,
             jnp.ones_like(tokens),
             decode=True,
             positions=positions,
-            mutable=["cache"],
         )
-        return logits[:, -1, :], mutated["cache"]
+        return (logits[:, -1, :], *rest)
 
     return tpudl_chunk_prefill
 
@@ -225,16 +260,15 @@ def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
             page_table=page_table, start=start, lens=lens,
             page_size=page_size, quantized=quantized,
         )
-        logits, mutated = model.apply(
+        return _apply_cached(
+            model,
             {"params": params, "cache": cache},
             tokens,
             jnp.ones_like(tokens),
             decode=True,
             positions=positions,
             paged=view,
-            mutable=["cache"],
         )
-        return logits, mutated["cache"]
 
     return tpudl_verify
 

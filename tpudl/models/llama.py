@@ -17,7 +17,8 @@ from __future__ import annotations
 import dataclasses
 import re
 from functools import partial
-from typing import Any, Optional
+import math
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -26,6 +27,57 @@ import jax.numpy as jnp
 from tpudl.models.lora import LoRADense
 from tpudl.ops.attention import attend
 from tpudl.parallel.sharding import constrain
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN as the ``deepseek_yarn`` configs publish it: per-frequency
+    blend of the plain and the ``factor``-times-stretched inverse
+    frequencies, by a linear ramp between the correction dimensions of
+    ``beta_fast`` and ``beta_slow`` rotations over the original
+    context. ``attention_scale`` is what the blend asks of the softmax
+    (``mscale ** 2``); cos and sin stay unscaled where ``mscale ==
+    mscale_all_dim``, which is how the published configs set them."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return self._mscale(self.factor, self.mscale) / self._mscale(
+            self.factor, self.mscale_all_dim
+        )
+
+    @property
+    def attention_scale(self) -> float:
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def inv_freq(self, dim: int, theta: float) -> jax.Array:
+        """[dim / 2] float32 inverse frequencies."""
+        exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+        plain = 1.0 / theta ** exponent
+
+        def correction_dim(rotations: float) -> float:
+            return dim * math.log(
+                self.original_max_position / (rotations * 2 * math.pi)
+            ) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(dim // 2, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3),
+            0.0, 1.0,
+        )
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,6 +103,24 @@ class LlamaConfig:
             raise ValueError(
                 f"lora_rank must be >= 0 (0 = adapters off), got "
                 f"{self.lora_rank}"
+            )
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(
+                f"attention must be 'gqa' or 'mla', got {self.attention!r}"
+            )
+        if self.attention == "mla" and not (
+            self.kv_lora_rank > 0 and self.qk_nope_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+        ):
+            raise ValueError(
+                "attention='mla' needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim"
+            )
+        if self.num_experts > 0 and self.moe_experts > 0:
+            raise ValueError(
+                "num_experts (dropless serving experts) and moe_experts "
+                "(the capacity-dropping training layer) are two layers: "
+                "set one"
             )
     # Fused-epilogue kernel tier (tpudl.ops.norms / mlp_fused): False
     # (default) = composite RMSNorm/SwiGLU, bit-identical to before the
@@ -85,10 +155,46 @@ class LlamaConfig:
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
+    # What each layer is made of. ``attention``: "gqa" (the block
+    # above) or "mla", latent attention: keys and values are
+    # up-projections of ONE normed latent of ``kv_lora_rank`` values a
+    # position plus one roped key of ``qk_rope_head_dim`` shared by all
+    # heads, and that pair is all the cache keeps (LatentAttention).
+    attention: str = "gqa"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    # Dropless routed experts (tpudl.ops.moe.DroplessMoE) in every
+    # layer from ``first_k_dense`` on; the layers before keep the dense
+    # SwiGLU of ``intermediate_size``. ``num_experts`` is the router's
+    # width; ``experts_held = (first, count)`` names the experts whose
+    # weights this program holds (None: all), as one share of an
+    # expert-parallel deployment does.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    def mlp_kind(self, layer: int) -> str:
+        """"moe" (dropless routed experts) or "dense" for ``layer``."""
+        if self.num_experts > 0 and layer >= self.first_k_dense:
+            return "moe"
+        return "dense"
+
+    @property
+    def expert_layers(self) -> int:
+        return sum(
+            self.mlp_kind(i) == "moe" for i in range(self.num_layers)
+        )
 
 
 LLAMA_TINY = partial(
@@ -215,15 +321,24 @@ class RMSNorm(nn.Module):
             )
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding on [B, S, H, D] (rotate-half convention)."""
+def rope(
+    x: jax.Array, positions: jax.Array, theta: float,
+    scaling: Optional[RopeScaling] = None,
+) -> jax.Array:
+    """Rotary embedding on [B, S, H, D] (rotate-half convention);
+    ``scaling`` swaps the plain frequencies for YaRN's."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    )  # [d/2]
+    if scaling is None:
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        )  # [d/2]
+        amp = 1.0
+    else:
+        inv_freq = scaling.inv_freq(d, theta)
+        amp = scaling.cos_sin_scale
     angles = positions[:, :, None].astype(jnp.float32) * inv_freq  # [B,S,d/2]
-    cos = jnp.cos(angles)[:, :, None, :]  # [B,S,1,d/2]
-    sin = jnp.sin(angles)[:, :, None, :]
+    cos = amp * jnp.cos(angles)[:, :, None, :]  # [B,S,1,d/2]
+    sin = amp * jnp.sin(angles)[:, :, None, :]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     x32_1, x32_2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
@@ -285,8 +400,8 @@ class LlamaAttention(nn.Module):
         q = q.reshape(B, S, cfg.num_heads, hd)
         k = k.reshape(B, S, cfg.num_kv_heads, hd)
         v = v.reshape(B, S, cfg.num_kv_heads, hd)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
         if decode and paged is not None:
             # Paged decode (tpudl.models.paged): KV lives in page pools
@@ -415,8 +530,172 @@ class LlamaAttention(nn.Module):
         return out + adapter_delta(adapters, "o_proj", ctx)
 
 
+class LatentAttention(nn.Module):
+    """Latent (MLA) attention. A position is cached as ONE row
+    ``[c | k_r]``: the RMS-normed latent ``c`` (``kv_lora_rank``) and
+    the roped key ``k_r`` (``qk_rope_head_dim``) that every head
+    shares, written after the norm and after RoPE. No value pool and no
+    head axis: the cache declares the single leaf ``kv`` (dense rows)
+    / ``pages_kv`` (page pool), and tpudl.serve.cache builds, seats,
+    gathers and migrates whatever leaves a layer declares.
+
+    Two forms of the same attention, which must agree. Prefill and
+    training up-project: ``[k_nope_h | v_h] = c W_kv_b`` and attend
+    with ``qk_nope_head_dim + qk_rope_head_dim``-wide keys. Paged
+    decode absorbs ``W_kv_b`` into the query and the output and attends
+    over the gathered rows as one shared head: ``score = (W_kv_b^K
+    q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(
+        self, hidden, positions, kv_mask=None, decode: bool = False,
+        paged=None, adapters=None,
+    ):
+        cfg = self.cfg
+        if adapters is not None:
+            raise ValueError(
+                "per-tenant adapters are not wired to latent attention"
+            )
+        B, S, _ = hidden.shape
+        H, r = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        scale = (dn + dr) ** -0.5
+        if cfg.rope_scaling is not None:
+            scale *= cfg.rope_scaling.attention_scale
+        q = _proj(cfg, H * (dn + dr), "q_proj")(hidden).reshape(B, S, H, dn + dr)
+        q_nope = q[..., :dn]
+        q_rope = rope(q[..., dn:], positions, cfg.rope_theta, cfg.rope_scaling)
+        with jax.named_scope("kv_down"):
+            down = nn.Dense(
+                r + dr, use_bias=False, dtype=cfg.dtype,
+                kernel_init=nn.initializers.normal(0.02), name="kv_a_proj",
+            )(hidden)
+            c = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down[..., :r])
+            k_r = rope(
+                down[..., None, r:], positions, cfg.rope_theta,
+                cfg.rope_scaling,
+            )[:, :, 0]
+            latent = jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)
+        kv_b = self.param(
+            "kv_b_proj", nn.initializers.normal(0.02), (r, H * (dn + dv))
+        ).astype(cfg.dtype).reshape(r, H, dn + dv)
+
+        if decode and paged is not None:
+            from tpudl.models.paged import (
+                paged_attend_mask,
+                paged_gather,
+                paged_write,
+            )
+
+            pool = self.variable("cache", "pages_kv", _paged_cache_missing)
+            sc = None
+            if paged.quantized:
+                sc = self.variable("cache", "scale_kv", _paged_cache_missing)
+            pool.value, new_sc = paged_write(
+                pool.value, sc.value if sc is not None else None, latent,
+                paged,
+            )
+            if sc is not None:
+                sc.value = new_sc
+            rows = paged_gather(
+                pool.value, sc.value if sc is not None else None, paged,
+                latent.dtype,
+            )
+            ctx = _mla_absorbed(
+                q_nope, q_rope, rows, kv_b, dn,
+                paged_attend_mask(paged, chunk=S), scale,
+            )
+        elif decode:
+            # The dense row cache of a prefill (and of the chunked
+            # suffix prefill, which is handed the prefix's rows). A
+            # cache made HERE starts empty, so the chunk is all there
+            # is to attend to; one that was handed in is attended whole.
+            fresh = not self.has_variable("cache", "kv")
+            ckv = self.variable(
+                "cache", "kv", jnp.zeros, (B, cfg.max_seq_len, r + dr),
+                latent.dtype,
+            )
+            cvalid = self.variable(
+                "cache", "valid", jnp.zeros, (B, cfg.max_seq_len), jnp.bool_
+            )
+            idx = self.variable(
+                "cache", "index", lambda: jnp.zeros((), jnp.int32)
+            )
+            start = idx.value
+            chunk_valid = (
+                jnp.ones((B, S), jnp.bool_) if kv_mask is None
+                else kv_mask.astype(jnp.bool_)
+            )
+            ckv.value = jax.lax.dynamic_update_slice(
+                ckv.value, latent, (0, start, 0)
+            )
+            cvalid.value = jax.lax.dynamic_update_slice(
+                cvalid.value, chunk_valid, (0, start)
+            )
+            idx.value = start + S
+            q_slot = jnp.arange(S)[None, None, :, None]
+            if fresh:
+                rows, valid = latent, chunk_valid
+            else:
+                rows, valid = ckv.value, cvalid.value
+                q_slot = q_slot + start
+            kv_slot = jnp.arange(rows.shape[1])[None, None, None, :]
+            mask = (kv_slot <= q_slot) & valid[:, None, None, :]
+            ctx = _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale)
+        else:
+            slot = jnp.arange(S)
+            mask = (slot[None, :] <= slot[:, None])[None, None]
+            if kv_mask is not None:
+                mask = mask & kv_mask.astype(jnp.bool_)[:, None, None, :]
+            ctx = _mla_up_projected(
+                q_nope, q_rope, latent, kv_b, dn, mask, scale
+            )
+        return _proj(cfg, cfg.hidden_size, "o_proj")(
+            ctx.reshape(B, S, H * dv)
+        )
+
+
+def _masked_softmax(logits, mask, dtype):
+    from tpudl.ops.attention import MASK_VALUE
+
+    logits = jnp.where(mask, logits.astype(jnp.float32), MASK_VALUE)
+    return jax.nn.softmax(logits, axis=-1).astype(dtype)
+
+
+def _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale):
+    """Keys and values up-projected from the cached rows. q_nope:
+    [B, S, H, dn]; q_rope: [B, S, H, dr]; rows: [B, T, r + dr]; kv_b:
+    [r, H, dn + dv]; mask: [B, 1, S, T] (True = attend). -> [B, S, H,
+    dv]."""
+    r = kv_b.shape[0]
+    with jax.named_scope("mla_core"):
+        up = jnp.einsum("btr,rhd->bthd", rows[..., :r], kv_b)
+        logits = jnp.einsum("bshd,bthd->bhst", q_nope, up[..., :dn])
+        logits = logits + jnp.einsum("bshd,btd->bhst", q_rope, rows[..., r:])
+        weights = _masked_softmax(logits * scale, mask, rows.dtype)
+        return jnp.einsum("bhst,bthd->bshd", weights, up[..., dn:])
+
+
+def _mla_absorbed(q_nope, q_rope, rows, kv_b, dn, mask, scale):
+    """The same attention with ``kv_b`` absorbed into the query and the
+    output: the rows are attended as they are cached, one head of
+    ``r + dr`` shared by all query heads."""
+    r = kv_b.shape[0]
+    with jax.named_scope("mla_core"):
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, kv_b[..., :dn])
+        query = jnp.concatenate([q_lat, q_rope], axis=-1)
+        logits = jnp.einsum("bshc,btc->bhst", query, rows)
+        weights = _masked_softmax(logits * scale, mask, rows.dtype)
+        u = jnp.einsum("bhst,btr->bshr", weights, rows[..., :r])
+        return jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
+    #: "dense" or "moe" (``LlamaConfig.mlp_kind`` of this layer).
+    mlp: str = "dense"
 
     @nn.compact
     def __call__(
@@ -429,7 +708,8 @@ class LlamaBlock(nn.Module):
         from tpudl.ops.norms import fused_ops_impl
 
         impl = fused_ops_impl(cfg.fused_ops)
-        attn = LlamaAttention(cfg, name="attention")(
+        attention = LatentAttention if cfg.attention == "mla" else LlamaAttention
+        attn = attention(cfg, name="attention")(
             RMSNorm(cfg.rms_norm_eps, impl, name="input_norm")(hidden),
             positions,
             kv_mask,
@@ -444,7 +724,38 @@ class LlamaBlock(nn.Module):
             cfg.rms_norm_eps, impl, name="post_attention_norm"
         )(attn, residual=hidden)
         with jax.named_scope("mlp"):
-            if cfg.moe_experts > 0:
+            if self.mlp == "moe":
+                from tpudl.ops.moe import DroplessMoE
+
+                if adapters is not None:
+                    raise ValueError(
+                        "per-tenant adapters are not wired to routed experts"
+                    )
+                # Tokens that are real: a prompt's, not its padding's;
+                # a seated slot's, not an idle slot's ride-along (whose
+                # table row maps the trash page).
+                if paged is not None:
+                    real = jnp.broadcast_to(
+                        (paged.page_table[:, :1] != 0), x.shape[:2]
+                    )
+                elif kv_mask is not None:
+                    real = kv_mask.astype(jnp.bool_)
+                else:
+                    real = jnp.ones(x.shape[:2], jnp.bool_)
+                down = DroplessMoE(
+                    num_experts=cfg.num_experts,
+                    experts_per_token=cfg.experts_per_token,
+                    intermediate_size=cfg.moe_intermediate_size,
+                    shared_intermediate_size=(
+                        cfg.num_shared_experts * cfg.moe_intermediate_size
+                    ),
+                    routed_scaling_factor=cfg.routed_scaling_factor,
+                    experts_held=cfg.experts_held,
+                    dtype=cfg.dtype,
+                    weight_dtype=cfg.weight_dtype,
+                    name="moe",
+                )(x, real)
+            elif cfg.moe_experts > 0:
                 from tpudl.ops.moe import MoEMlp
 
                 down = MoEMlp(
@@ -508,7 +819,7 @@ class LlamaModel(nn.Module):
             # are decode-only (serving), and decode skips remat.
             block = nn.remat(LlamaBlock, static_argnums=(4, 5))
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"layer_{i}")(
+            x = block(cfg, cfg.mlp_kind(i), name=f"layer_{i}")(
                 x, positions, kv_mask, decode, paged,
                 adapters.for_layer(f"layer_{i}")
                 if adapters is not None

@@ -77,7 +77,8 @@ class PagedView:
 def quantize_kv(x: jax.Array):
     """Symmetric int8 quantization over the head_dim axis.
 
-    ``x`` [..., Hkv, D] -> (q int8 [..., Hkv, D], scale f32 [..., Hkv]);
+    ``x`` [..., Hkv, D] -> (q int8 [..., Hkv, D], scale f32 [..., Hkv])
+    (a headless row [..., C] -> one scale a row);
     ``q * scale`` reconstructs x to ~0.4% of the per-head max — the
     granularity that keeps greedy decode token-stable at tiny scales
     while costing 4/D extra bytes per element (scale rows ride in the
@@ -116,7 +117,9 @@ def paged_write(
     ``pages`` [NP, ps, Hkv, D] (int8 or compute dtype), ``scales``
     [NP, ps, Hkv] f32 (quantized pools only), ``value`` [B, S, Hkv, D]
     (the freshly projected + RoPE'd k or v; [B, Hkv, D] is accepted as
-    the S=1 single-token form). Token j of slot b lands at physical
+    the S=1 single-token form). A pool with no head axis ([NP, ps, C],
+    scales [NP, ps]: a latent cache's one row a position) takes
+    ``value`` [B, S, C] the same way. Token j of slot b lands at physical
     ``(page_table[b, (lens[b]+j) // ps], (lens[b]+j) % ps)`` — the
     speculative-verify dispatch writes its whole k-token window this
     way; idle slots (lens pinned at 0 on a trash-mapped row) write into
@@ -124,7 +127,7 @@ def paged_write(
     capacity (a verify window overshooting a nearly-full slot) redirect
     to the trash page instead of clamping onto the slot's last page —
     a clamped write would corrupt KEPT rows of the same slot."""
-    if value.ndim == 3:
+    if value.ndim == pages.ndim - 1:
         value = value[:, None]
     s = value.shape[1]
     ps = view.page_size
@@ -159,20 +162,27 @@ def paged_gather(
 ) -> jax.Array:
     """Materialize every slot's logical KV view from the pool.
 
-    Returns [B, L, Hkv, D] in ``compute_dtype`` where L = pages_per_slot
-    x page_size; dequantization (``q * scale``) is fused into this
+    Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis)
+    in ``compute_dtype`` where L = pages_per_slot x page_size;
+    dequantization (``q * scale``) is fused into this
     gather for int8 pools. Unmapped logical pages resolve to the trash
     page — finite garbage the attention mask excludes."""
-    np_, ps = pages.shape[0], view.page_size
     with jax.named_scope("kv_gather"):
-        flat_idx = flat_page_row_index(view.page_table, ps)
-        flat_pages = pages.reshape(np_ * ps, *pages.shape[2:])
-        out = flat_pages[flat_idx]  # [B, L, Hkv, D]
+        # Whole pages, not rows: a slot's table row names its pages and
+        # a page's positions lie together, so one slice a page (16
+        # rows) is fetched where a row index would fetch 16. The
+        # logical view [B, P * ps, ...] is the same either way
+        # (``flat_page_row_index`` stays the address arithmetic of the
+        # prefix and migration gathers, which cut rows out of it).
+        out = pages[view.page_table]  # [B, P, ps, ...]
         if view.quantized:
-            flat_scales = scales.reshape(np_ * ps, scales.shape[2])
             out = (
-                out.astype(jnp.float32) * flat_scales[flat_idx][..., None]
+                out.astype(jnp.float32)
+                * scales[view.page_table][..., None]
             )
+        out = out.reshape(
+            out.shape[0], out.shape[1] * out.shape[2], *out.shape[3:]
+        )
         return out.astype(compute_dtype)
 
 

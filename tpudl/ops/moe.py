@@ -162,3 +162,147 @@ class MoEMlp(nn.Module):
         out = constrain(out, "ep", ("dp", "fsdp"), None, None)
         y = jnp.einsum("gsec,egcm->gsm", combine.astype(self.dtype), out)
         return y
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed experts, one share of an expert-parallel deployment
+# ---------------------------------------------------------------------------
+
+
+class _ExpertKernel(nn.Module):
+    """The stacked kernel ``[experts, in, out]`` of one projection of
+    the experts held, under the path ``<name>/kernel`` that a dense
+    projection has, so that tpudl.quant's rules address it. Like
+    ``QuantDense`` it serves what the tree holds: a plain kernel, or
+    the ``{"qvalues", "qscale"}`` pair of a quantized one (one scale
+    per output channel, applied after the contraction)."""
+
+    shape: tuple
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        from tpudl.quant.quantize import is_quantized
+
+        stored = (
+            self.get_variable("params", "kernel")
+            if self.has_variable("params", "kernel") else None
+        )
+        if is_quantized(stored):
+            return stored["qvalues"].astype(self.dtype), stored["qscale"]
+        kernel = self.param(
+            "kernel", nn.initializers.normal(0.02), self.shape
+        )
+        return kernel.astype(self.dtype), None
+
+
+class DroplessMoE(nn.Module):
+    """Routed SwiGLU experts with no capacity and no dropped token, as
+    serving needs them (a dropped token changes the served logits), for
+    the experts THIS program holds.
+
+    The router keeps its published width: ``s = sigmoid(x W_r)`` over
+    all ``num_experts`` in float32, ``T = top_k(s + b)`` with the
+    selection bias ``b`` (it decides the choice and never the weight),
+    ``g_i = routed_scaling_factor * s_i / sum_{j in T} s_j``. The layer
+    is told ``experts_held = (first, count)`` and computes
+
+        y = sum_{i in T, first <= i < first + count} g_i E_i(x) + E_shared(x)
+
+    What the other experts would add is left out: in a deployment it
+    arrives from the chips that hold them, and no code here stands in
+    for them. The shared expert is computed once, by every share.
+
+    Dispatch is a ``[tokens, held]`` matrix of gates and the experts
+    run as one grouped matmul over the experts held (``emh``): every
+    held expert's weights pass the matrix unit once a call, which is
+    what a call costs while an expert sees fewer tokens than the unit
+    has rows; the gates fold into the down-projection's contraction,
+    so no ``[held, tokens, hidden]`` tensor is made.
+
+    ``real`` ([B, S] bool) marks the tokens that count (not padding,
+    not an idle slot); the int32 ``[held]`` count of real tokens per
+    held expert is sown as ``moe_stats/tokens_per_expert``."""
+
+    num_experts: int
+    experts_per_token: int
+    intermediate_size: int
+    shared_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: Any = None
+    dtype: Any = jnp.bfloat16
+    weight_dtype: Any = None
+
+    def _dense(self, features: int, name: str):
+        kwargs = dict(
+            use_bias=False, dtype=self.dtype,
+            kernel_init=nn.initializers.normal(0.02), name=name,
+        )
+        if self.weight_dtype is not None:
+            from tpudl.quant.dense import QuantDense
+
+            return QuantDense(features, **kwargs)
+        return nn.Dense(features, **kwargs)
+
+    @nn.compact
+    def __call__(self, x: jax.Array, real: jax.Array) -> jax.Array:
+        b, s, m = x.shape
+        first, count = self.experts_held or (0, self.num_experts)
+        if not (0 <= first and 0 < count <= self.num_experts - first):
+            raise ValueError(
+                f"experts_held {(first, count)} outside the router's "
+                f"{self.num_experts} experts"
+            )
+        h = self.intermediate_size
+        tokens = x.reshape(b * s, m)
+        with jax.named_scope("moe"):
+            with jax.named_scope("router"):
+                scores = jax.nn.sigmoid(nn.Dense(
+                    self.num_experts, use_bias=False, dtype=jnp.float32,
+                    kernel_init=nn.initializers.normal(0.02), name="router",
+                )(tokens.astype(jnp.float32)))
+                bias = self.param(
+                    "router_bias", nn.initializers.zeros, (self.num_experts,)
+                )
+                _, chosen = jax.lax.top_k(
+                    scores + bias.astype(jnp.float32), self.experts_per_token
+                )  # [T, k]
+                picked = jnp.take_along_axis(scores, chosen, axis=-1)
+                gates = self.routed_scaling_factor * picked / jnp.sum(
+                    picked, axis=-1, keepdims=True
+                )
+                # [T, k, held]: which held expert each choice names.
+                hit = (chosen - first)[..., None] == jnp.arange(count)
+                combine = jnp.sum(hit * gates[..., None], axis=1)  # [T, held]
+                counts = jnp.sum(
+                    hit.any(axis=1) & real.reshape(-1, 1), axis=0,
+                    dtype=jnp.int32,
+                )
+                self.sow("moe_stats", "tokens_per_expert", counts)
+            with jax.named_scope("experts"):
+                wg, sg = _ExpertKernel((count, m, h), self.dtype, name="gate_proj")()
+                wu, su = _ExpertKernel((count, m, h), self.dtype, name="up_proj")()
+                wd, sd = _ExpertKernel((count, h, m), self.dtype, name="down_proj")()
+                gate = jnp.einsum("tm,emh->eth", tokens, wg)
+                up = jnp.einsum("tm,emh->eth", tokens, wu)
+                if sg is not None:
+                    gate = gate * sg.astype(gate.dtype)
+                    up = up * su.astype(up.dtype)
+                act = nn.silu(gate) * up
+                act = act * combine.T[..., None].astype(act.dtype)
+                routed = jnp.einsum(
+                    "eth,ehm->tm", act, wd,
+                    preferred_element_type=jnp.float32,
+                )
+                if sd is not None:
+                    routed = routed * sd
+                routed = routed.astype(self.dtype)
+            y = routed
+            if self.shared_intermediate_size:
+                with jax.named_scope("shared_expert"):
+                    f = self.shared_intermediate_size
+                    sh = nn.silu(
+                        self._dense(f, "shared_gate_proj")(tokens)
+                    ) * self._dense(f, "shared_up_proj")(tokens)
+                    y = y + self._dense(m, "shared_down_proj")(sh)
+        return y.reshape(b, s, m)

@@ -167,8 +167,10 @@ def _find_pool(tree) -> Optional[dict]:
     artifact-geometry probe ``from_artifacts`` reads shapes off)."""
     from collections.abc import Mapping
 
+    from tpudl.serve.cache import _is_pool
+
     if isinstance(tree, Mapping):
-        if "pages_k" in tree:
+        if _is_pool(tree):
             return dict(tree)
         for value in tree.values():
             found = _find_pool(value)
@@ -384,9 +386,37 @@ class ServeSession:
                     "spec_k cannot compose with per-tenant adapters "
                     "yet (the draft path has no adapter view)"
                 )
+        cfg = getattr(model, "cfg", None)
+        if getattr(cfg, "attention", "gqa") == "mla" or (
+            getattr(cfg, "num_experts", 0) > 0
+        ):
+            # What a latent (MLA) cache or routed experts are wired to:
+            # the paged pool (one leaf a layer), its int8 store, prefix
+            # sharing and migration. The rest says so here, in a
+            # sentence, instead of failing on a shape further down.
+            if not paged:
+                raise ValueError(
+                    "a model with latent attention or routed experts is "
+                    "served from the paged pool: pass paged=True (the "
+                    "dense slot cache has no absorbed decode and reports "
+                    "no tokens per expert)"
+                )
+            if adapters is not None:
+                raise ValueError(
+                    "per-tenant adapters are not wired to latent "
+                    "attention or routed experts: the adapter pool "
+                    "addresses the q/k/v/o and dense MLP projections of "
+                    "the grouped-query block"
+                )
+            if spec_k:
+                raise ValueError(
+                    "spec_k is not wired to latent attention or routed "
+                    "experts: the verify step does not report the "
+                    "tokens per expert of its window"
+                )
         pf = prefill_fn(model)
         ids = jax.ShapeDtypeStruct((num_slots, prompt_len), jnp.int32)
-        _, cache_template = jax.eval_shape(pf, params, ids, ids)
+        _, cache_template, *_ = jax.eval_shape(pf, params, ids, ids)
         chunk_prefill = None
         speculator = None
         verify = None
@@ -587,7 +617,7 @@ class ServeSession:
             if pool is None:
                 raise ValueError(
                     "paged decode artifact carries no page-pool cache "
-                    "(no pages_k leaf in its cache avals)"
+                    "(no pages_<name> leaf in its cache avals)"
                 )
             # The model's compiled sequence bound lives in the PREFILL
             # artifact's dense row-cache outputs ([1, max_seq_len]
@@ -605,13 +635,16 @@ class ServeSession:
                 ),
                 None,
             )
+            pages = next(
+                v for k, v in pool.items() if k.startswith("pages_")
+            )
             cache = PagedKVCache.from_pool_template(
                 cache_template,
                 num_slots=int(token_aval.shape[0]),
                 pages_per_slot=int(table_aval.shape[1]),
-                page_size=int(pool["pages_k"].shape[1]),
-                quantized="scale_k" in pool,
-                num_pages=int(pool["pages_k"].shape[0]),
+                page_size=int(pages.shape[1]),
+                quantized=any(k.startswith("scale_") for k in pool),
+                num_pages=int(pages.shape[0]),
                 model_seq_len=model_bound,
             )
         else:

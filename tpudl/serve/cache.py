@@ -202,14 +202,39 @@ class SlotCache:
 # ---------------------------------------------------------------------------
 
 
+#: The leaves of a dense decode cache that are not rows: which
+#: positions are real, and where the next chunk is written.
+_ROW_META = frozenset({"valid", "index"})
+
+
 def _is_attn_cache(node) -> bool:
-    """A per-layer dense decode cache dict: the four leaves
-    LlamaAttention's decode branch declares."""
+    """A per-layer dense decode cache dict, as an attention layer's
+    decode branch declares it: ``valid``, ``index`` and the layer's ROW
+    leaves ``[B, max_seq, ...]`` — ``k`` and ``v`` for grouped-query
+    attention (``[.., Hkv, D]``), the one ``kv`` for latent attention
+    (``[.., C]``, no head axis). The pool follows what is declared: a
+    row leaf ``name`` is pooled as ``pages_<name>`` (+ ``scale_<name>``
+    for int8 pools)."""
     from collections.abc import Mapping
 
-    return isinstance(node, Mapping) and set(node) >= {
-        "k", "v", "valid", "index"
-    }
+    return (
+        isinstance(node, Mapping) and set(node) > _ROW_META
+        and all(hasattr(v, "shape") for v in node.values())
+    )
+
+
+def _row_names(attn) -> list:
+    """The row leaves a layer declares, by name."""
+    return sorted(set(attn) - _ROW_META)
+
+
+def _is_pool(node) -> bool:
+    """A per-layer page-pool dict (``pages_<name>`` leaves)."""
+    from collections.abc import Mapping
+
+    return isinstance(node, Mapping) and any(
+        str(k).startswith("pages_") for k in node
+    )
 
 
 def _map_attn_caches(tree, fn):
@@ -232,7 +257,7 @@ def _zip_attn_caches(a, b, fn):
     prefill row cache into the matching layer's page pool)."""
     from collections.abc import Mapping
 
-    if isinstance(a, Mapping) and ("pages_k" in a or _is_attn_cache(a)):
+    if _is_pool(a) or _is_attn_cache(a):
         return fn(a, b)
     if isinstance(a, Mapping):
         return {k: _zip_attn_caches(v, b[k], fn) for k, v in a.items()}
@@ -548,7 +573,12 @@ class PagedKVCache:
     KV lives in per-layer page pools ``[num_pages, page_size, Hkv, D]``
     (int8 with ``[num_pages, page_size, Hkv]`` f32 dequant scales when
     ``kv_dtype="int8"``); a slot owns the pages its HOST-side page
-    table row maps. Three consequences the engine builds on:
+    table row maps. WHICH pools a layer has is the layer's to declare
+    (``_is_attn_cache``): a grouped-query layer declares ``k`` and
+    ``v``, a latent (MLA) layer ONE headless leaf ``kv`` and so one
+    pool ``pages_kv [num_pages, page_size, C]``; seating, the prefix
+    gather, migration and ``nbytes`` walk the declared leaves. Three
+    consequences the engine builds on:
 
     - **No shared write index**: each slot carries its own length, so
       the dense cache's horizon rollover (reset-the-world when the
@@ -651,25 +681,21 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
 
         def to_pool(attn: dict) -> dict:
-            k, v = attn["k"], attn["v"]
-            hkv, hd = int(k.shape[2]), int(k.shape[3])
-            store = jnp.int8 if self.quantized else k.dtype
-            pool = {
-                "pages_k": jnp.zeros(
-                    (self.num_pages, self.page_size, hkv, hd), store
-                ),
-                "pages_v": jnp.zeros(
-                    (self.num_pages, self.page_size, hkv, hd),
-                    jnp.int8 if self.quantized else v.dtype,
-                ),
-            }
-            if self.quantized:
-                pool["scale_k"] = jnp.zeros(
-                    (self.num_pages, self.page_size, hkv), jnp.float32
+            page = (self.num_pages, self.page_size)
+            pool = {}
+            for name in _row_names(attn):
+                row = attn[name]
+                tail = tuple(int(d) for d in row.shape[2:])
+                pool[f"pages_{name}"] = jnp.zeros(
+                    page + tail, jnp.int8 if self.quantized else row.dtype
                 )
-                pool["scale_v"] = jnp.zeros(
-                    (self.num_pages, self.page_size, hkv), jnp.float32
-                )
+                if self.quantized:
+                    # One dequant scale per stored vector (the last
+                    # axis): per head for [.., Hkv, D], per row for a
+                    # headless [.., C].
+                    pool[f"scale_{name}"] = jnp.zeros(
+                        page + tail[:-1], jnp.float32
+                    )
             return pool
 
         self.cache = _map_attn_caches(template, to_pool)
@@ -684,6 +710,7 @@ class PagedKVCache:
         self.lens = np.zeros((self.num_slots,), np.int32)
         self._reset_occupancy()
         self._seat_jit = {}
+        self.program_extras: list = []
         # Prefix sharing (radix mode): seating is LEFT-ALIGNED (token i
         # of every prompt lives at logical position i, start == 0), so
         # identical token prefixes land on identical page-aligned
@@ -759,6 +786,7 @@ class PagedKVCache:
         obj.lens = np.zeros((obj.num_slots,), np.int32)
         obj._reset_occupancy()
         obj._seat_jit = {}
+        obj.program_extras = []
         obj.prefix_share = False
         obj.radix = None
         obj._leases = {}
@@ -920,10 +948,8 @@ class PagedKVCache:
         def tpudl_seat(pool_tree, row_tree, page_ids):
             def one(pool: dict, row: dict) -> dict:
                 out = dict(pool)
-                for kv, name, sname in (
-                    ("k", "pages_k", "scale_k"),
-                    ("v", "pages_v", "scale_v"),
-                ):
+                for kv in _row_names(row):
+                    name, sname = f"pages_{kv}", f"scale_{kv}"
                     rowvals = row[kv]
                     take = min(span, rowvals.shape[1])
                     blocks = rowvals[0, :take]
@@ -1079,10 +1105,8 @@ class PagedKVCache:
         def tpudl_seat_shared(pool_tree, row_tree, page_ids, row_offset):
             def one(pool: dict, row: dict) -> dict:
                 out = dict(pool)
-                for kv, name, sname in (
-                    ("k", "pages_k", "scale_k"),
-                    ("v", "pages_v", "scale_v"),
-                ):
+                for kv in _row_names(row):
+                    name, sname = f"pages_{kv}", f"scale_{kv}"
                     rowvals = row[kv][0]
                     padded = jnp.pad(
                         rowvals,
@@ -1135,20 +1159,18 @@ class PagedKVCache:
             from tpudl.models.paged import flat_page_row_index
 
             def one(pool: dict, tmpl: dict) -> dict:
-                seq = int(tmpl["k"].shape[1])
+                seq = int(tmpl["valid"].shape[1])
                 flat_idx = flat_page_row_index(page_ids, ps)
                 out = {}
-                for kv, name, sname in (
-                    ("k", "pages_k", "scale_k"),
-                    ("v", "pages_v", "scale_v"),
-                ):
+                for kv in _row_names(tmpl):
+                    name, sname = f"pages_{kv}", f"scale_{kv}"
                     pool_arr = pool[name]
                     flat = pool_arr.reshape(
                         pool_arr.shape[0] * ps, *pool_arr.shape[2:]
                     )
                     rows = flat[flat_idx]
                     if quantized:
-                        sc = pool[sname].reshape(-1, pool[sname].shape[2])
+                        sc = pool[sname].reshape(-1, *pool[sname].shape[2:])
                         rows = rows.astype(jnp.float32) * (
                             sc[flat_idx][..., None]
                         )
@@ -1437,9 +1459,12 @@ class PagedKVCache:
         """Dispatch one program of the paged decode contract
         (``paged_decode_fn``, ``paged_chunk_decode_fn``, the LoRA
         decode with its adapter arguments in ``extra``) on the pool,
-        keep the pool it returns and hand back the logits."""
+        keep the pool it returns and hand back the logits. What the
+        program returned beside the two (a model with routed experts:
+        its tokens per held expert, still on the device) is kept as
+        ``program_extras`` until the next dispatch."""
         pool = self.cache
-        logits, self.cache = program(
+        logits, self.cache, *self.program_extras = program(
             params, pool, tokens, positions, *self.dispatch_args(), *extra
         )
         self._handed_over(pool)
@@ -1622,7 +1647,7 @@ def _map_pools(tree, fn):
     ``_map_attn_caches`` (which matches dense k/v/valid/index dicts)."""
     from collections.abc import Mapping
 
-    if isinstance(tree, Mapping) and "pages_k" in tree:
+    if _is_pool(tree):
         return fn(tree)
     if isinstance(tree, Mapping):
         return {k: _map_pools(v, fn) for k, v in tree.items()}
@@ -1641,7 +1666,7 @@ def _migration_gather(cache, page_ids):
     from tpudl.models.paged import flat_page_row_index
 
     def one(pool: dict) -> dict:
-        ps = pool["pages_k"].shape[1]
+        ps = next(iter(pool.values())).shape[1]
         flat_idx = flat_page_row_index(page_ids, ps)
         out = {}
         for name, arr in pool.items():
@@ -1661,7 +1686,7 @@ def _migration_scatter(cache, rows, page_ids):
     shared-compilation property."""
 
     def one(pool: dict, r: dict) -> dict:
-        ps = pool["pages_k"].shape[1]
+        ps = next(iter(pool.values())).shape[1]
         out = dict(pool)
         for name, vals in r.items():
             paged = vals.reshape(page_ids.shape[0], ps, *vals.shape[1:])

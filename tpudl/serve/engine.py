@@ -129,11 +129,13 @@ _select_greedy = jax.jit(tpudl_select)
 _select_tokens = jax.jit(tpudl_select_sampled)
 
 
-def first_token(logits, request) -> int:
+def first_token(logits, request, also=None):
     """Select a request's FIRST token from its batch-1 prefill logits
     (step 0 of its per-request sampling stream) — shared by the
     engine's local seat path and the router's dedicated prefill
-    workers, so disaggregated serving draws identical tokens."""
+    workers, so disaggregated serving draws identical tokens. ``also``
+    (device arrays the same program returned) is read back in the same
+    transfer: ``(token, also_on_host)``."""
     if request.temperature > 0:
         sel = _select_tokens(
             logits,
@@ -143,7 +145,37 @@ def first_token(logits, request) -> int:
         )
     else:
         sel = _select_greedy(logits)
-    return int(jax.device_get(sel)[0])
+    if also is None:
+        return int(jax.device_get(sel)[0])
+    sel, also = jax.device_get((sel, also))
+    return int(sel[0]), also
+
+
+def record_expert_load(counts) -> dict:
+    """``counts``: int [expert layers, experts held] on the host, the
+    real tokens each held expert got in one prefill or decode step
+    (tpudl.ops.moe.DroplessMoE). Counted into the registry and returned
+    as the step's span attributes: ``moe_assignments`` (their sum),
+    ``moe_experts_touched`` (held experts, over the layers, that got a
+    token: those whose weights the step had to read) and
+    ``moe_load_max_over_mean`` (the busiest held expert's tokens over
+    the mean, the worse layer's; 1.0 is even)."""
+    counts = np.asarray(counts)
+    total = int(counts.sum())
+    reg = registry()
+    reg.counter("serve_moe_assignments").inc(total)
+    per_expert = reg.histogram("serve_moe_tokens_per_expert")
+    for n in counts.ravel().tolist():
+        per_expert.observe(n)
+    means = counts.mean(axis=1)
+    skew = [
+        float(row.max() / mean) for row, mean in zip(counts, means) if mean > 0
+    ]
+    return {
+        "moe_assignments": total,
+        "moe_experts_touched": int((counts > 0).sum()),
+        "moe_load_max_over_mean": max(skew) if skew else 0.0,
+    }
 
 
 class _Prefilled:
@@ -524,7 +556,7 @@ class Engine:
                 positions = np.arange(
                     hit, ids.shape[0], dtype=np.int32
                 )[None, :]
-                logits, row_cache = self.chunk_prefill_call(
+                logits, row_cache, *counts = self.chunk_prefill_call(
                     self.params, rows, suffix, positions
                 )
                 row_offset = 0  # chunk rows are already left-aligned
@@ -545,11 +577,19 @@ class Engine:
                         arow[None, :],
                         np.float32([ascale]),
                     )
+                    counts = []
                 else:
-                    logits, row_cache = self.prefill_call(
+                    # A model with routed experts returns its tokens
+                    # per held expert beside the logits and the row.
+                    logits, row_cache, *counts = self.prefill_call(
                         self.params, padded, mask
                     )
-            first = first_token(logits, req)
+            load = {}
+            if counts:
+                first, counts = first_token(logits, req, also=counts)
+                load = record_expert_load(counts[0])
+            else:
+                first = first_token(logits, req)
         except BaseException:
             if lease is not None:
                 self.cache.release_lease(lease[1])
@@ -560,7 +600,7 @@ class Engine:
         if span is not None:
             # prefix_hit_tokens names how much of the prompt the radix
             # cache paid for (report.py --request's TTFT attribution).
-            span.end(now, prefix_hit_tokens=hit)
+            span.end(now, prefix_hit_tokens=hit, **load)
         if hit:
             registry().counter("serve_prefix_hit_tokens").inc(hit)
         self.num_prefills += 1
@@ -1348,9 +1388,13 @@ class Engine:
         # d2h in the decode steady state, and the dispatch-hygiene
         # audit (tpudl.analysis.assert_no_host_transfers) disallows
         # implicit transfers — intent made visible is the contract.
-        sel = jax.device_get(sel)
+        # (A model with routed experts: its tokens per held expert ride
+        # the same transfer.)
+        counts = self.cache.program_extras if self.paged else ()
+        sel, counts = jax.device_get((sel, counts))
         if readback is not None:
             readback.end(self.clock())
+        load = record_expert_load(counts[0]) if counts else {}
         if self.paged:
             # Each ACTIVE slot's logical length advanced by one (idle
             # slots stay pinned on the trash page).
@@ -1374,7 +1418,7 @@ class Engine:
             if self.paged:
                 attrs["pages_reserved"] = self.cache.pages_reserved
                 attrs["tokens_live"] = self.cache.tokens_live
-            span.end(now, **attrs)
+            span.end(now, **attrs, **load)
             emit = rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
         registry().counter("serve_decode_steps").inc()

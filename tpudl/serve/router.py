@@ -679,7 +679,7 @@ class PrefillWorker:
                      np.ones(ids.shape[0], np.int32)]
                 )[None, :]
                 t0 = self.clock()
-                logits, row_cache = self.prefill_call(
+                logits, row_cache, *_ = self.prefill_call(
                     self.params, padded, mask
                 )
                 first = first_token(logits, req)

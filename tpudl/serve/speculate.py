@@ -183,7 +183,7 @@ class Speculator:
         mask = np.concatenate(
             [np.zeros(pad, np.int32), np.ones(ids.shape[0], np.int32)]
         )[None, :]
-        _, row_cache = self.prefill_call(self.params, padded, mask)
+        _, row_cache, *_ = self.prefill_call(self.params, padded, mask)
         self.cache.seat(
             row_cache, slot, pad, prompt_len, reserve_tokens,
         )
