@@ -159,6 +159,33 @@ def _flash_attention():
     return _grad(fn, (0, 1, 2)), (qkv, qkv, qkv)
 
 
+#: Mistral-7B-v0.3's widths and the serving cells' session
+#: (perfbench/configs/mistral-7b-v0.3-l16.json), two layers deep: what
+#: is asked is asked of each layer alike.
+POOL_SLOTS, POOL_WINDOW, POOL_SEQ, POOL_PAGE = 48, 512, 1024, 16
+POOL_SHAPE = (POOL_SLOTS * POOL_SEQ // POOL_PAGE + 1, POOL_PAGE, 8, 128)
+
+
+def _paged_attention(chunk):
+    """Mistral-7B's decode attention over the serving cells' pool: 48
+    slots x 64 pages of 16 positions, 8 KV heads of 128; ``chunk`` 1 is
+    the decode step, 3 a speculative-verify window."""
+    from tpudl.models.paged import PagedView
+    from tpudl.ops.paged_attention import paged_attention
+
+    def fn(q, pages_k, pages_v, table, start, lens):
+        view = PagedView(table, start, lens, POOL_PAGE, False)
+        return paged_attention(
+            q, pages_k, pages_v, view, impl="fused", interpret=False
+        )
+
+    pool, vec = _s(POOL_SHAPE, bf16), _s((POOL_SLOTS,), i32)
+    return fn, (
+        _s((POOL_SLOTS, chunk, 32, 128), bf16), pool, pool,
+        _s((POOL_SLOTS, POOL_SEQ // POOL_PAGE), i32), vec, vec,
+    )
+
+
 CASES = {
     # BERT-base, b256 s128
     "bert/layer_norm+residual": _layer_norm_residual,
@@ -175,6 +202,9 @@ CASES = {
     "llama/segmented_lora-f32": lambda: _segmented_lora(f32),
     "llama/segmented_lora-int8": lambda: _segmented_lora(jnp.int8),
     "llama/flash_attention": _flash_attention,
+    # Mistral-7B-v0.3, the serving cells' pool
+    "mistral/paged_attention-decode": lambda: _paged_attention(1),
+    "mistral/paged_attention-verify": lambda: _paged_attention(3),
 }
 
 
@@ -211,15 +241,8 @@ def test_kernel_compiles_for_v5e(name, no_compile_cache):
 # benchmark's pool size, write in place on the chip.
 # ---------------------------------------------------------------------------
 
-#: Mistral-7B-v0.3's widths and the serving cells' session
-#: (perfbench/configs/mistral-7b-v0.3-l16.json), two layers deep: what
-#: is asked is asked of each layer alike.
-POOL_SLOTS, POOL_WINDOW, POOL_SEQ, POOL_PAGE = 48, 512, 1024, 16
-POOL_SHAPE = (POOL_SLOTS * POOL_SEQ // POOL_PAGE + 1, POOL_PAGE, 8, 128)
 
-
-@pytest.fixture(scope="module")
-def pool_session():
+def _pool_session():
     """A session over shapes alone (nothing is run), its own pool as
     small as a pool may be: the programs are lowered at POOL_SHAPE."""
     from tpudl.models.generate import prefill_fn
@@ -240,6 +263,9 @@ def pool_session():
     )
     _, row = jax.eval_shape(prefill_fn(model), params, ids, ids)
     return session, params, row
+
+
+pool_session = pytest.fixture(scope="module")(_pool_session)
 
 
 def _pool_program(name, session, params, row, on_chip):
@@ -287,6 +313,45 @@ def test_pool_program_writes_in_place_on_v5e(
         compiled.as_text(),
     )
     assert not copies
+
+
+def test_decode_program_reads_the_pool_in_place_on_v5e(
+    monkeypatch, no_compile_cache
+):
+    """ISSUE 27: on the chip the session's decode program attends
+    through the kernel (the sandbox's backend is the CPU, so the one
+    question ``is_tpu_backend`` is answered for it here, in the test),
+    still takes the pool donated with no copy of its shape, and has no
+    operation left under the scope ``kv_gather``."""
+    import math
+    import re
+
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    session, params, row = _pool_session()
+    compiled = _pool_program(
+        "decode", session, params, row, SingleDeviceSharding(device)
+    ).compile()
+    took = session.engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True, True)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "paged_attention" in text and "kv_gather" not in text
+    leaves = jax.tree.leaves(session.engine.cache.cache)
+    pool_bytes = len(leaves) * math.prod(POOL_SHAPE) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    shape = ",".join(map(str, POOL_SHAPE))
+    assert not re.findall(
+        rf"= bf16\[{shape}\][^ ]* copy(?:-start|-done)?\(", text
+    )
+    # Nothing of a slot's whole logical view is made any more.
+    assert f"bf16[{POOL_SLOTS},{POOL_SEQ}," not in text
 
 
 # The latent (MLA) pool of ISSUE 26 at its cell's size: sarvam-105b's

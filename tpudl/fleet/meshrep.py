@@ -35,7 +35,6 @@ from __future__ import annotations
 from typing import Any, Optional, Sequence
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec
 
 from tpudl.parallel.sharding import TP_TRANSFORMER_RULES, tree_shardings
 from tpudl.runtime.mesh import MeshSpec, make_mesh
@@ -81,45 +80,19 @@ def build_mesh_session(
     make jit compile the serving programs for the mesh's device
     assignment; the cache template and speculative draft build from
     the sharded tree and follow by propagation, the paged pools are
-    committed to the mesh here (``_commit_pool``). The returned
-    session carries the mesh as ``session.mesh``."""
+    committed to the mesh before any program is built for them
+    (``PagedKVCache.commit``, through ``from_model``'s ``mesh``). The
+    returned session carries the mesh as ``session.mesh``."""
     if mesh is None:
         mesh = serving_mesh(devices, tp=tp)
     if rules is None:
         rules = SERVE_MESH_RULES
     sharded = jax.device_put(params, tree_shardings(mesh, params, rules))
     session = ServeSession.from_model(
-        model, sharded, prompt_len, **from_model_kwargs
+        model, sharded, prompt_len, mesh=mesh, **from_model_kwargs
     )
     session.mesh = mesh
-    for cache in (
-        session.engine.cache,
-        getattr(session.engine.speculator, "cache", None),
-    ):
-        if getattr(cache, "paged", False):
-            _commit_pool(cache, mesh)
     return session
-
-
-def _commit_pool(cache, mesh) -> None:
-    """Commit a ``PagedKVCache``'s pool to ``mesh`` before the first
-    program sees it, KV heads over ``tp`` where they divide (the split
-    the column-parallel k/v projections write in). A pool left on one
-    device would come back from its first seat in the compiler's own
-    sharding: one whole copy, and every donating program compiled
-    twice. Where ``tp`` does not divide the KV heads the pool starts
-    replicated and that first seat does re-shard it: one counted copy
-    (``serve_kv_pool_copies``), in place from then on."""
-
-    def place(leaf):
-        # pages_k/v [pages, page, Hkv, D]; scale_k/v [pages, page, Hkv]
-        if leaf.shape[2] % mesh.shape["tp"] == 0:
-            return NamedSharding(mesh, PartitionSpec(None, None, "tp"))
-        return NamedSharding(mesh, PartitionSpec())
-
-    cache.cache = jax.device_put(
-        cache.cache, jax.tree.map(place, cache.cache)
-    )
 
 
 class MeshReplica(Replica):

@@ -113,23 +113,30 @@ def decode_fn(model):
     return tpudl_decode
 
 
-def paged_decode_fn(model, page_size: int, quantized: bool):
+def paged_decode_fn(
+    model, page_size: int, quantized: bool, sharded: bool = False
+):
     """THE paged single-token decode contract (tpudl.models.paged):
     ``(params, cache, token, position, page_table, start, lens) ->
     (logits, new_cache)`` where ``cache`` holds per-layer page pools
     (``pages_k``/``pages_v`` + ``scale_k``/``scale_v`` when int8) and
     the three small int32 arrays are the HOST-owned addressing state —
     page table [B, P], first attendable logical position [B], and the
-    logical write position [B]. ``page_size``/``quantized`` are static
-    (baked into the compiled program); placement changes never
+    logical write position [B]. ``page_size``/``quantized``/``sharded``
+    (the pool was committed to a mesh: ``PagedKVCache.sharded``) are
+    static (baked into the compiled program); placement changes never
     recompile. Built for the serve engine's paged mode
-    (tpudl.serve.cache.PagedKVCache owns the pools and addressing)."""
+    (tpudl.serve.cache.PagedKVCache owns the pools and addressing).
+
+    Once traced, the program says of itself which of its attention
+    layers read the pool in place (tpudl.ops.paged_attention):
+    ``attention_in_place``, one bool a layer, None before."""
     from tpudl.models.paged import PagedView
 
     def tpudl_decode(params, cache, token, position, page_table, start, lens):
         view = PagedView(
             page_table=page_table, start=start, lens=lens,
-            page_size=page_size, quantized=quantized,
+            page_size=page_size, quantized=quantized, sharded=sharded,
         )
         logits, *rest = _apply_cached(
             model,
@@ -140,8 +147,10 @@ def paged_decode_fn(model, page_size: int, quantized: bool):
             positions=position[:, None],
             paged=view,
         )
+        tpudl_decode.attention_in_place = tuple(view.took)
         return (logits[:, -1, :], *rest)
 
+    tpudl_decode.attention_in_place = None
     return tpudl_decode
 
 
@@ -177,7 +186,8 @@ def lora_prefill_fn(model, impl: str = "auto"):
 
 
 def lora_paged_decode_fn(
-    model, page_size: int, quantized: bool, impl: str = "auto"
+    model, page_size: int, quantized: bool, impl: str = "auto",
+    sharded: bool = False,
 ):
     """THE multi-tenant paged decode contract: ``paged_decode_fn``'s
     seven arguments plus ``(adapter_pools, adapter_table [B, r_max],
@@ -196,7 +206,7 @@ def lora_paged_decode_fn(
     ):
         view = PagedView(
             page_table=page_table, start=start, lens=lens,
-            page_size=page_size, quantized=quantized,
+            page_size=page_size, quantized=quantized, sharded=sharded,
         )
         logits, mutated = model.apply(
             {"params": params, "cache": cache},
@@ -210,8 +220,10 @@ def lora_paged_decode_fn(
             ),
             mutable=["cache"],
         )
+        tpudl_decode.attention_in_place = tuple(view.took)
         return logits[:, -1, :], mutated["cache"]
 
+    tpudl_decode.attention_in_place = None
     return tpudl_decode
 
 
@@ -242,7 +254,9 @@ def chunk_prefill_fn(model):
     return tpudl_chunk_prefill
 
 
-def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
+def paged_chunk_decode_fn(
+    model, page_size: int, quantized: bool, sharded: bool = False
+):
     """THE speculative-verify contract: ``(params, cache, tokens
     [B, C], positions [B, C], page_table, start, lens) -> (logits
     [B, C, V], new_cache)``. One slot-batched dispatch writes each
@@ -258,9 +272,9 @@ def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
     def tpudl_verify(params, cache, tokens, positions, page_table, start, lens):
         view = PagedView(
             page_table=page_table, start=start, lens=lens,
-            page_size=page_size, quantized=quantized,
+            page_size=page_size, quantized=quantized, sharded=sharded,
         )
-        return _apply_cached(
+        out = _apply_cached(
             model,
             {"params": params, "cache": cache},
             tokens,
@@ -269,7 +283,10 @@ def paged_chunk_decode_fn(model, page_size: int, quantized: bool):
             positions=positions,
             paged=view,
         )
+        tpudl_verify.attention_in_place = tuple(view.took)
+        return out
 
+    tpudl_verify.attention_in_place = None
     return tpudl_verify
 
 

@@ -407,18 +407,23 @@ class LlamaAttention(nn.Module):
             # Paged decode (tpudl.models.paged): KV lives in page pools
             # addressed by the host-provided page table instead of the
             # dense [B, max_seq] rows below — each slot has its OWN
-            # length (no shared write index, so no horizon rollover)
-            # and pools may store int8 with per-(page, row, head)
-            # dequant scales fused into the gather. Token chunks of any
-            # length step together (S=1 is the plain decode step; S=k
-            # is the speculative-verify window, causal within itself
-            # via the chunked mask); prefill stays dense batch-1 (its
-            # row cache is scattered into pages by PagedKVCache.seat).
-            from tpudl.models.paged import (
-                paged_attend_mask,
-                paged_gather,
-                paged_write,
-            )
+            # length (no shared write index, so no horizon rollover).
+            # This step's rows are scattered into the donated pool
+            # first (paged_write), then attention reads the pool: in
+            # place where it can (tpudl.ops.paged_attention: a kernel
+            # that visits only the pages a slot's live positions lie
+            # on), and through the dense gather of every slot's whole
+            # table where it cannot — int8 pools (per-(page, row, head)
+            # dequant scales fused into the gather), a pool committed
+            # to a mesh, any CPU run. The program chooses by what it
+            # can observe and records the choice (PagedView.took).
+            # Token chunks of any length step together (S=1 is the
+            # plain decode step; S=k is the speculative-verify window,
+            # causal within itself: query j attends up to lens + j);
+            # prefill stays dense batch-1 (its row cache is scattered
+            # into pages by PagedKVCache.seat).
+            from tpudl.models.paged import paged_write
+            from tpudl.ops.paged_attention import paged_attention
 
             pk = self.variable("cache", "pages_k", _paged_cache_missing)
             pv = self.variable("cache", "pages_v", _paged_cache_missing)
@@ -435,14 +440,10 @@ class LlamaAttention(nn.Module):
             pk.value, pv.value = new_k, new_v
             if paged.quantized:
                 sk.value, sv.value = new_sk, new_sv
-            kf = paged_gather(
-                pk.value, sk.value if sk is not None else None, paged, k.dtype
-            )
-            vf = paged_gather(
-                pv.value, sv.value if sv is not None else None, paged, v.dtype
-            )
-            ctx = _gqa_decode_attention(
-                q, kf, vf, paged_attend_mask(paged, chunk=S)
+            ctx = paged_attention(
+                q, pk.value, pv.value, paged,
+                scale_k=sk.value if sk is not None else None,
+                scale_v=sv.value if sv is not None else None,
             )
             ctx = ctx.reshape(B, S, cfg.num_heads * hd)
             out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
@@ -590,6 +591,7 @@ class LatentAttention(nn.Module):
             )
 
             pool = self.variable("cache", "pages_kv", _paged_cache_missing)
+            paged.took.append(False)  # a headless pool: the gather
             sc = None
             if paged.quantized:
                 sc = self.variable("cache", "scale_kv", _paged_cache_missing)

@@ -18,6 +18,18 @@ engine's horizon rollovers. The paged layout replaces both:
   kv-head) — ~4x the resident tokens per byte vs f32 pools — applied
   inside the decode gather (one fused multiply on the gathered view).
 
+A decode step first writes its rows into the pool (``paged_write``, a
+scatter into the donated pool), then attends. There are two ways to
+read, and the program chooses by what it can observe
+(tpudl.ops.paged_attention): a k / v pool pair on one device of a TPU
+is read **in place** — a kernel brings only the pages that cover a
+slot's live positions from HBM by the page table and keeps a running
+softmax over them, so nothing of shape ``[B, P * ps, ...]`` exists;
+everything else (int8 pools, a latent layer's headless pool, a pool
+committed to a mesh, any CPU run) **gathers**: ``paged_gather`` makes
+every slot's whole logical view dense and attention runs under
+``paged_attend_mask``. Both mean the same thing.
+
 Masking: slot ``b`` attends logical positions ``[start[b], lens[b]]``
 (``start`` = its left-pad count, ``lens`` = where this step's token was
 just written). Physical page ids play no role in masking — the page
@@ -57,9 +69,15 @@ class PagedView:
     physical pool page (0 = the trash page for unmapped entries);
     ``start`` ([B] int32) is slot b's first attendable logical position
     (its left-pad count); ``lens`` ([B] int32) is the logical position
-    this step's token is written at. ``page_size`` and ``quantized``
-    are STATIC (baked into the compiled program); the arrays are traced
-    inputs, so the host mutates placement freely between dispatches.
+    this step's token is written at. ``page_size``, ``quantized`` and
+    ``sharded`` are STATIC (baked into the compiled program); the
+    arrays are traced inputs, so the host mutates placement freely
+    between dispatches. ``sharded`` says the pool was committed to a
+    mesh (``PagedKVCache.commit``): a fact of the cache, not a setting.
+    ``took`` is the program's record of itself, written while it is
+    traced: one entry a paged attention layer, True where the layer
+    reads the pool in place (tpudl.ops.paged_attention) and False
+    where it gathers.
     """
 
     page_table: jax.Array
@@ -67,6 +85,8 @@ class PagedView:
     lens: jax.Array
     page_size: int
     quantized: bool
+    sharded: bool = False
+    took: list = dataclasses.field(default_factory=list)
 
     @property
     def logical_len(self) -> int:
@@ -160,7 +180,9 @@ def paged_gather(
     view: PagedView,
     compute_dtype,
 ) -> jax.Array:
-    """Materialize every slot's logical KV view from the pool.
+    """Materialize every slot's logical KV view from the pool (the
+    gather path; tpudl.ops.paged_attention reads a k / v pool in place
+    where it can, and then this is not called).
 
     Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis)
     in ``compute_dtype`` where L = pages_per_slot x page_size;

@@ -22,6 +22,10 @@ kernel tier, and expert-parallel MoE.
 - segmented_lora.py   — heterogeneous-adapter batched LoRA delta over
                         page pools (gather-from-pool in-kernel, f32
                         accumulation; the multi-tenant serving matmul);
+- paged_attention.py  — decode attention over the paged k / v pool,
+                        read in place: live pages fetched by the page
+                        table (scalar prefetch + DMA), running softmax;
+                        the dense gather stays as its reference;
 - fp8_dot.py          — fp8 TRAINING matmul (e4m3 fwd / e5m2 grad) with
                         delayed scaling: per-tensor amax-history rings
                         as traced state, saturate-don't-NaN casts,

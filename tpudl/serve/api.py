@@ -266,6 +266,7 @@ class ServeSession:
         adapter_dtype: Optional[str] = None,
         adapter_alpha: float = 16.0,
         adapter_impl: str = "auto",
+        mesh=None,
         **kwargs,
     ) -> "ServeSession":
         """Live-model session: jit the prefill/decode contracts (batch 1
@@ -300,7 +301,10 @@ class ServeSession:
         the decode gather — ~4x the resident slots per byte.
         ``page_size`` (``TPUDL_SERVE_PAGE_SIZE``, default 16) and
         ``num_pages`` (default: capacity parity with the dense cache)
-        size the pool.
+        size the pool. ``mesh`` is the mesh ``params`` were committed
+        to, if any (tpudl.fleet.meshrep.build_mesh_session passes it):
+        the paged pools are committed to it before any program is
+        built for them.
 
         ``adapters={tenant: lora_tree}`` turns on MULTI-TENANT adapter
         serving (tpudl.serve.lora): the base model stays resident once
@@ -436,10 +440,15 @@ class ServeSession:
                 kv_dtype=kv_dtype,
                 prefix_share=bool(prefix_share),
             )
+            if mesh is not None:
+                cache.commit(mesh)
             # Every program that takes the pool and returns its
             # successor donates it (PagedKVCache: the ownership rule).
+            # What the cache knows of its pool (page size, int8 rows,
+            # committed to a mesh) is static in the programs.
+            pool_facts = (cache.page_size, cache.quantized)
             decode = jax.jit(
-                paged_decode_fn(model, cache.page_size, cache.quantized),
+                paged_decode_fn(model, *pool_facts, sharded=cache.sharded),
                 donate_argnums=(1,),
             )
             if adapters is not None:
@@ -480,8 +489,8 @@ class ServeSession:
                 kwargs["adapter_pool"] = pool
                 decode = jax.jit(
                     lora_paged_decode_fn(
-                        model, cache.page_size, cache.quantized,
-                        impl=adapter_impl,
+                        model, *pool_facts, impl=adapter_impl,
+                        sharded=cache.sharded,
                     ),
                     donate_argnums=(1,),
                 )
@@ -514,6 +523,8 @@ class ServeSession:
                     page_size=cache.page_size,
                     num_pages=num_pages,
                 )
+                if mesh is not None:
+                    draft_cache.commit(mesh)
                 speculator = Speculator(
                     jax.jit(named(
                         prefill_fn(draft_model), "tpudl_draft_prefill"
@@ -521,7 +532,8 @@ class ServeSession:
                     jax.jit(
                         named(
                             paged_decode_fn(
-                                draft_model, draft_cache.page_size, False
+                                draft_model, draft_cache.page_size, False,
+                                sharded=draft_cache.sharded,
                             ),
                             "tpudl_draft_decode",
                         ),
@@ -536,7 +548,7 @@ class ServeSession:
                 )
                 verify = jax.jit(
                     paged_chunk_decode_fn(
-                        model, cache.page_size, cache.quantized
+                        model, *pool_facts, sharded=cache.sharded
                     ),
                     donate_argnums=(1,),
                 )

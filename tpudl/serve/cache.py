@@ -711,6 +711,8 @@ class PagedKVCache:
         self._reset_occupancy()
         self._seat_jit = {}
         self.program_extras: list = []
+        self.sharded = False
+        self.in_place_layers = 0
         # Prefix sharing (radix mode): seating is LEFT-ALIGNED (token i
         # of every prompt lives at logical position i, start == 0), so
         # identical token prefixes land on identical page-aligned
@@ -787,6 +789,8 @@ class PagedKVCache:
         obj._reset_occupancy()
         obj._seat_jit = {}
         obj.program_extras = []
+        obj.sharded = False
+        obj.in_place_layers = 0
         obj.prefix_share = False
         obj.radix = None
         obj._leases = {}
@@ -1468,7 +1472,58 @@ class PagedKVCache:
             params, pool, tokens, positions, *self.dispatch_args(), *extra
         )
         self._handed_over(pool)
+        # What the program noted of itself while it was traced: the
+        # attention layers that read the pool in place (an exported
+        # artifact keeps no such note and counts as the gather).
+        took = getattr(
+            getattr(program, "__wrapped__", program),
+            "attention_in_place", None,
+        )
+        self.in_place_layers = sum(took or ())
         return logits
+
+    def pages_live(self, chunk: int = 1) -> int:
+        """Pages a dispatch of ``chunk`` tokens a slot visits where
+        attention reads the pool in place, summed over the slots: those
+        that cover logical positions ``[start, lens + chunk - 1]``; an
+        idle slot (lens 0 on the trash page) counts one. Counted from
+        the host's ``start`` / ``lens`` as the dispatch sees them, so
+        before ``advance``."""
+        import numpy as np
+
+        last = np.minimum(
+            (self.lens + chunk - 1) // self.page_size,
+            self.pages_per_slot - 1,
+        )
+        first = self.start // self.page_size
+        return int(np.maximum(last - first, 0).sum()) + self.num_slots
+
+    def commit(self, mesh) -> None:
+        """Commit the pool to ``mesh`` before the first program sees
+        it, KV heads over ``tp`` where they divide (the split the
+        column-parallel k/v projections write in). A pool left on one
+        device would come back from its first seat in the compiler's
+        own sharding: one whole copy, and every donating program
+        compiled twice. Where ``tp`` does not divide the KV heads the
+        pool starts replicated and that first seat does re-shard it:
+        one counted copy (``serve_kv_pool_copies``), in place from then
+        on. The cache then knows its pool lives on a mesh (``sharded``)
+        and the decode programs built for it are told
+        (``PagedView.sharded``): under GSPMD a kernel that reads the
+        pool in place would have it gathered whole to every chip, so
+        those programs keep the dense gather."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        def place(leaf):
+            # pages_k/v [pages, page, Hkv, D]; scale_k/v [pages, page, Hkv]
+            if leaf.shape[2] % mesh.shape["tp"] == 0:
+                return NamedSharding(mesh, PartitionSpec(None, None, "tp"))
+            return NamedSharding(mesh, PartitionSpec())
+
+        self.cache = jax.device_put(
+            self.cache, jax.tree.map(place, self.cache)
+        )
+        self.sharded = True
 
     def _replace_pool(self, program, *args) -> None:
         """``program(pool, *args) -> pool``: a seat or an import."""

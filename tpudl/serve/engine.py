@@ -825,7 +825,7 @@ class Engine:
 
     def _publish_occupancy(self) -> None:
         """Slots in use and, paged, the cache's reserved-against-live
-        counters, as gauges."""
+        counters and the path its decode program took, as gauges."""
         reg = registry()
         reg.gauge("serve_slots_busy").set(
             sum(s is not None for s in self._slots)
@@ -835,6 +835,23 @@ class Engine:
                 self.cache.pages_reserved
             )
             reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
+            # Layers of the decode program whose attention reads the
+            # pool in place (0 until its first dispatch traced it).
+            reg.gauge("serve_paged_attention_in_place").set(
+                self.cache.in_place_layers
+            )
+
+    def _paged_attrs(self, pages_live: int) -> dict:
+        """What a ``decode_step`` span says of the paged cache: the
+        pages the seated slots hold, the positions the step read,
+        whether its attention read the pool in place (the program's
+        own note, ``PagedKVCache.in_place_layers``) and the pages such
+        a step visits, counted on the host at dispatch."""
+        cache = self.cache
+        return {"pages_reserved": cache.pages_reserved,
+                "tokens_live": cache.tokens_live,
+                "kv_in_place": int(cache.in_place_layers > 0),
+                "pages_live": pages_live}
 
     def _fits(self, request) -> bool:
         """Can this request be seated RIGHT NOW? Dense: its worst case
@@ -1358,6 +1375,7 @@ class Engine:
         if rec is not None:
             span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
             dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
+        pages_live = 0
         if self.paged:
             # Tenant adapters ride the paged contract as three more
             # traced inputs (adapters imply a paged cache).
@@ -1365,6 +1383,8 @@ class Engine:
                 self.adapter_pool.dispatch_args()
                 if self.adapter_pool is not None else ()
             )
+            if span is not None:
+                pages_live = self.cache.pages_live()
             logits = self.cache.decode(
                 self.decode_call, self.params, tokens, positions, *adapters
             )
@@ -1410,14 +1430,15 @@ class Engine:
             # the per-request trace's decode leg (report.py --request
             # selects the chunks containing its id). Paged: the pages
             # the seated slots hold and the positions the step read
-            # (lens already counts the token just written).
+            # (lens already counts the token just written); whether
+            # attention read the pool in place, and the pages it then
+            # visits (``_paged_attrs``).
             busy = int(sum(s is not None for s in self._slots))
             attrs = {"busy": busy,
                      "rids": [s.request.request_id
                               for s in self._slots if s is not None]}
             if self.paged:
-                attrs["pages_reserved"] = self.cache.pages_reserved
-                attrs["tokens_live"] = self.cache.tokens_live
+                attrs.update(self._paged_attrs(pages_live))
             span.end(now, **attrs, **load)
             emit = rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
@@ -1495,6 +1516,7 @@ class Engine:
             dispatch = rec.begin(
                 "decode.dispatch", CAT_SERVE_DECODE, self.clock()
             )
+        pages_live = self.cache.pages_live(k) if span is not None else 0
         logits = self.cache.decode(
             self.verify_call, self.params, chunk, pos_chunk
         )
@@ -1575,8 +1597,7 @@ class Engine:
                      accepted=total_accepted, emitted=total_emitted,
                      slot_accepted=slot_accepted,
                      slot_emitted=slot_emitted,
-                     pages_reserved=self.cache.pages_reserved,
-                     tokens_live=self.cache.tokens_live)
+                     **self._paged_attrs(pages_live))
         self.num_decode_steps += 1
         reg = registry()
         reg.counter("serve_decode_steps").inc()
