@@ -262,19 +262,14 @@ def build_cell_session(
         )
     from tpudl.export.decode import export_serving_decoder
 
-    if session_kwargs.get("paged"):
-        # The paged decode contract round-trips through StableHLO: the
-        # page pools are the cache avals, the host addressing arrays
-        # ride as extra inputs, and from_artifacts recovers the whole
-        # geometry from shapes (ROADMAP item 6's exported-paged cell).
-        pre, dec = export_serving_decoder(
-            model_v, params_v, num_slots=num_slots,
-            prompt_len=PROMPT_LEN, paged=True,
-            kv_dtype=session_kwargs.get("kv_dtype"),
-        )
-        return ServeSession.from_artifacts(pre, dec, params_v, paged=True)
+    # The paged decode contract round-trips through StableHLO: the
+    # page pools are the cache avals, the host addressing arrays
+    # ride as extra inputs, and from_artifacts recovers the whole
+    # geometry from shapes (ROADMAP item 6's exported-paged cell).
     pre, dec = export_serving_decoder(
-        model_v, params_v, num_slots=num_slots, prompt_len=PROMPT_LEN
+        model_v, params_v, num_slots=num_slots,
+        prompt_len=PROMPT_LEN,
+        kv_dtype=session_kwargs.get("kv_dtype"),
     )
     return ServeSession.from_artifacts(pre, dec, params_v)
 

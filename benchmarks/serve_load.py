@@ -140,7 +140,6 @@ def _with_sim_latency(call, sim_step_s: float):
 def build_programs(
     num_slots: int = 4,
     max_seq_len: int = MAX_SEQ_LEN,
-    paged: bool = False,
     page_size: int = 16,
     kv_dtype=None,
 ):
@@ -151,11 +150,7 @@ def build_programs(
     import jax
     import jax.numpy as jnp
 
-    from tpudl.models.generate import (
-        decode_fn,
-        paged_decode_fn,
-        prefill_fn,
-    )
+    from tpudl.models.generate import paged_decode_fn, prefill_fn
     from tpudl.models.llama import LLAMA_TINY, LlamaForCausalLM
 
     cfg = LLAMA_TINY(dtype=jnp.float32, max_seq_len=max_seq_len)
@@ -166,15 +161,10 @@ def build_programs(
     pf = prefill_fn(model)
     ids = jax.ShapeDtypeStruct((num_slots, PROMPT_LEN), jnp.int32)
     _, template = jax.eval_shape(pf, params, ids, ids)
-    if paged:
-        decode = jax.jit(
-            paged_decode_fn(model, page_size, kv_dtype == "int8")
-        )
-    else:
-        decode = jax.jit(decode_fn(model))
+    decode = jax.jit(paged_decode_fn(model, page_size, kv_dtype == "int8"))
     return {
         "model": model, "params": params, "prefill": jax.jit(pf),
-        "decode": decode, "template": template, "paged": paged,
+        "decode": decode, "template": template,
         "page_size": page_size, "kv_dtype": kv_dtype,
         "num_slots": num_slots,
     }
@@ -190,17 +180,14 @@ def session_from_programs(
     from tpudl.serve import ServeSession
     from tpudl.serve.cache import PagedKVCache
 
-    cache = None
-    if programs["paged"]:
-        cache = PagedKVCache(
-            programs["template"],
-            page_size=programs["page_size"],
-            kv_dtype=programs["kv_dtype"],
-        )
+    cache = PagedKVCache(
+        programs["template"],
+        page_size=programs["page_size"],
+        kv_dtype=programs["kv_dtype"],
+    )
     session = ServeSession(
         programs["prefill"], programs["decode"], programs["params"],
-        programs["template"], PROMPT_LEN, cache=cache, clock=clock,
-        **kwargs,
+        cache, PROMPT_LEN, clock=clock, **kwargs,
     )
     session.engine.prefill_call = _with_sim_latency(
         session.engine.prefill_call, sim_step_s
@@ -299,7 +286,6 @@ def run_closed_loop(
     if warmup:
         warmup_session(session)
     steps0 = session.engine.num_decode_steps
-    rolls0 = session.engine.num_rollovers
     t0 = clock()
     with RecompileWatcher(label="serve steady state") as recompiles:
         with assert_no_host_transfers(
@@ -313,7 +299,6 @@ def run_closed_loop(
         wall_s=round(elapsed, 4),
         tokens_per_sec=round(stats["tokens"] / elapsed, 2),
         decode_steps=session.engine.num_decode_steps - steps0,
-        rollovers=session.engine.num_rollovers - rolls0,
         steady_state_recompiles=recompiles.count,
     )
     return stats
@@ -331,7 +316,6 @@ def run_open_loop(
     shed — exactly the regime the closed loop can't show."""
     warmup_session(session)
     steps0 = session.engine.num_decode_steps
-    rolls0 = session.engine.num_rollovers
     rng = np.random.default_rng(seed)
     gaps = rng.exponential(1.0 / offered_rate, size=len(requests))
     arrivals = np.cumsum(gaps)
@@ -357,7 +341,6 @@ def run_open_loop(
         wall_s=round(elapsed, 4),
         tokens_per_sec=round(stats["tokens"] / elapsed, 2),
         decode_steps=session.engine.num_decode_steps - steps0,
-        rollovers=session.engine.num_rollovers - rolls0,
     )
     return stats
 
@@ -395,7 +378,6 @@ def run_replica_sweep(
     n_requests: int = 64,
     num_slots: int = 4,
     sim_step_ms: float = 30.0,
-    paged: bool = True,
     kv_dtype=None,
     seed: int = 0,
     assert_scaling: Optional[float] = 1.7,
@@ -407,9 +389,7 @@ def run_replica_sweep(
     does not serialize what the replicas parallelize"."""
     from tpudl.serve import Replica, Router
 
-    programs = build_programs(
-        num_slots, paged=paged, kv_dtype=kv_dtype
-    )
+    programs = build_programs(num_slots, kv_dtype=kv_dtype)
     # Compile + warm every program shape OUTSIDE the timed windows.
     warm = session_from_programs(programs)
     warmup_session(warm)
@@ -448,7 +428,6 @@ def run_replica_sweep(
         "sim_step_ms": sim_step_ms,
         "num_slots": num_slots,
         "n_requests": n_requests,
-        "paged": paged,
         "kv_dtype": kv_dtype,
         "sweep": sweep,
     }
@@ -490,7 +469,7 @@ def run_router_overload(
     from tpudl.obs.slo import Objective, SloMonitor
     from tpudl.serve import Replica, Router
 
-    programs = build_programs(num_slots, paged=True)
+    programs = build_programs(num_slots)
     warm = session_from_programs(programs)
     warmup_session(warm)
     replicas = []
@@ -599,7 +578,7 @@ def run_autoscale_recovery(
     from tpudl.obs.slo import Objective, SloMonitor
     from tpudl.serve import AutoscaleConfig, Autoscaler, Replica, Router
 
-    programs = build_programs(num_slots, paged=True)
+    programs = build_programs(num_slots)
     warm = session_from_programs(programs)
     warmup_session(warm)
     monitors: List = []
@@ -1642,7 +1621,7 @@ def run_chaos(
     from tpudl.serve import Replica, Router, chaos
 
     sim_step_s = 1e-3 * sim_step_ms
-    programs = build_programs(num_slots, paged=True)
+    programs = build_programs(num_slots)
     warm = session_from_programs(programs)
     warmup_session(warm)
     _warm_migration(programs)
@@ -1797,8 +1776,9 @@ def kv_capacity_report(
     page_size: int = 16,
     check: bool = True,
 ) -> dict:
-    """Resident-slots-per-byte: the dense f32 fixed-slot cache vs the
-    paged cache (f32 and int8 pools) at identical logical capacity.
+    """Resident-slots-per-byte: dense f32 rows (the template's own
+    shapes, what ``generate()`` allocates for that batch) vs the paged
+    cache (f32 and int8 pools) at identical logical capacity.
     The int8 pool must hold >= 1.8x the slots per byte (it measures
     ~3.5x: 4x from the dtype minus per-row scales and page-table
     overhead) — the KV-residency lever behind the whole paging tier."""
@@ -1807,7 +1787,7 @@ def kv_capacity_report(
 
     from tpudl.models.generate import prefill_fn
     from tpudl.models.llama import LLAMA_TINY, LlamaForCausalLM
-    from tpudl.serve.cache import PagedKVCache, SlotCache
+    from tpudl.serve.cache import PagedKVCache
 
     cfg = LLAMA_TINY(dtype=jnp.float32, max_seq_len=max_seq_len)
     model = LlamaForCausalLM(cfg)
@@ -1820,19 +1800,22 @@ def kv_capacity_report(
     _, template = jax.eval_shape(
         prefill_fn(model), params, ids, ids
     )
-    dense = SlotCache(template)
+    dense_bytes = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(template)
+    )
     paged_f32 = PagedKVCache(template, page_size=page_size)
     paged_int8 = PagedKVCache(template, page_size=page_size, kv_dtype="int8")
     out = {
         "num_slots": num_slots,
         "max_seq_len": max_seq_len,
         "page_size": page_size,
-        "dense_f32_bytes": dense.nbytes,
+        "dense_f32_bytes": dense_bytes,
         "paged_f32_bytes": paged_f32.nbytes,
         "paged_int8_bytes": paged_int8.nbytes,
         # Same resident slots each, so slots-per-byte ratios are just
         # byte ratios.
-        "int8_slots_per_byte_x": round(dense.nbytes / paged_int8.nbytes, 3),
+        "int8_slots_per_byte_x": round(dense_bytes / paged_int8.nbytes, 3),
         "serve_kv_slots_per_gb": round(
             num_slots / (paged_int8.nbytes / 2**30), 1
         ),

@@ -1,18 +1,18 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that tpudl still starts on the chip.
 
-    python chip_smoke.py [--seed N]     # one TPU chip, three phases
+    python chip_smoke.py [--seed N]     # one TPU chip, two phases
     python chip_smoke.py --chips 4      # four chips: the sharded paths only
 
 Drives both main paths once, at full width, through the entry points a
 user calls, with weights, data and prompts made from ``--seed``:
 
-- ``serve`` / ``serve_paged``: ``ServeSession.from_model`` on
-  Llama-3.2-1B shape (2048 wide, 16 layers, 32/8 heads, vocab 128,256,
-  bf16 parameters), default cache and ``paged=True``: two waves of eight
-  ragged requests; every request completes with the token count it
-  asked for and greedy tokens equal ``generate()`` on the same prompts;
-  the second wave compiles nothing.
+- ``serve``: ``ServeSession.from_model`` on Llama-3.2-1B shape (2048
+  wide, 16 layers, 32/8 heads, vocab 128,256, bf16 parameters), the
+  paged pool at its defaults: two waves of eight ragged requests; every
+  request completes with the token count it asked for and greedy tokens
+  equal ``generate()`` on the same prompts; the second wave compiles
+  nothing.
 - ``train``: ``notebooks/nlp/train_sst2.py``'s path for
   ``--config sst2_bert_base --batch 256`` (BERT-base, seq 128):
   ``build_model``, ``create_train_state``, ``make_mesh``,
@@ -407,7 +407,6 @@ def check_served(model, params, requests, results, prompt_len: int,
 def serve_phase(
     seed: int,
     *,
-    paged: bool,
     size: str = "llama3-1b",
     dtype=None,
     prompt_len: int = 128,
@@ -423,10 +422,10 @@ def serve_phase(
     from tpudl.serve import ServeSession
 
     dtype = jnp.bfloat16 if dtype is None else dtype
-    with Probe("serve_paged" if paged else "serve") as probe:
+    with Probe("serve") as probe:
         model, params = build_llama(seed, size, max_seq_len, dtype)
         session = ServeSession.from_model(
-            model, params, prompt_len, num_slots=num_slots, paged=paged
+            model, params, prompt_len, num_slots=num_slots
         )
         rng = np.random.default_rng(seed)
         vocab = model.cfg.vocab_size
@@ -456,7 +455,7 @@ def serve_phase(
     if watch.count:
         raise AssertionError(f"{watch.count} compilation(s) after warm-up")
     return probe.line(
-        model=size, paged=paged, num_slots=num_slots,
+        model=size, num_slots=num_slots,
         prompt_len=prompt_len, requests=2 * requests_per_wave,
         tokens=first["tokens"] + second["tokens"],
         requests_equal_generate=first["requests_equal_generate"]
@@ -594,14 +593,14 @@ def mesh_serve_phase(
         )
         session = ServeSession.from_model(
             model, jax.device_put(params, devices[0]), prompt_len,
-            num_slots=num_slots, paged=True,
+            num_slots=num_slots,
         )
         want = session.serve(requests)
         del session
         replica = MeshReplica(
             "tp", model=model, params=params, prompt_len=prompt_len,
             devices=devices, tp=chips,
-            session_kwargs={"num_slots": num_slots, "paged": True},
+            session_kwargs={"num_slots": num_slots},
         )
         sharded = replica.session.engine.params
         param_bytes = _tree_bytes_per_device(sharded, devices)
@@ -646,8 +645,7 @@ def main(argv=None) -> int:
     device = require_tpu(args.chips)
     if args.chips == 1:
         phases = [
-            lambda: serve_phase(args.seed, paged=False),
-            lambda: serve_phase(args.seed, paged=True),
+            lambda: serve_phase(args.seed),
             lambda: train_phase(args.seed),
         ]
     else:

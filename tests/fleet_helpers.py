@@ -41,7 +41,7 @@ def build_session():
         jax.random.key(0), jnp.zeros((1, PROMPT_LEN), jnp.int32)
     )["params"]
     return ServeSession.from_model(
-        model, params, PROMPT_LEN, num_slots=2, paged=True,
+        model, params, PROMPT_LEN, num_slots=2,
         page_size=PAGE,
     )
 

@@ -364,10 +364,10 @@ def test_env_accessors_semantics(monkeypatch):
     with pytest.raises(ValueError, match=">= 1"):
         reg.env_int("TPUDL_SERVE_SLOTS", 4, min_value=1)
     for truthy in ("1", "true", "YES", "on"):
-        monkeypatch.setenv("TPUDL_SERVE_PAGED", truthy)
-        assert reg.env_flag("TPUDL_SERVE_PAGED")
-    monkeypatch.setenv("TPUDL_SERVE_PAGED", "0")
-    assert not reg.env_flag("TPUDL_SERVE_PAGED")
+        monkeypatch.setenv("TPUDL_SERVE_PREFIX_SHARE", truthy)
+        assert reg.env_flag("TPUDL_SERVE_PREFIX_SHARE")
+    monkeypatch.setenv("TPUDL_SERVE_PREFIX_SHARE", "0")
+    assert not reg.env_flag("TPUDL_SERVE_PREFIX_SHARE")
     monkeypatch.setenv("TPUDL_FT_GRACE_S", "2.5")
     assert reg.env_float("TPUDL_FT_GRACE_S", 15.0) == 2.5
 

@@ -105,7 +105,7 @@ def _psession(programs, **kw):
     cache = PagedKVCache(programs["template"], page_size=PAGE)
     return ServeSession(
         programs["prefill"], programs["decode"], programs["params"],
-        programs["template"], PROMPT_LEN, cache=cache, **kw,
+        cache, PROMPT_LEN, **kw,
     )
 
 
@@ -570,7 +570,7 @@ def test_draft_cache_migrates_with_the_request(model_and_params, programs):
 
     def spec_session():
         return ServeSession.from_model(
-            model, params, PROMPT_LEN, num_slots=2, paged=True,
+            model, params, PROMPT_LEN, num_slots=2,
             page_size=PAGE, spec_k=3,
         )
 
@@ -632,7 +632,7 @@ def test_draftless_payload_refused_by_speculating_engine(
         plain_src.engine.step()
     payload = plain_src.engine.export_request("nd0")
     spec_dst = ServeSession.from_model(
-        model, params, PROMPT_LEN, num_slots=2, paged=True,
+        model, params, PROMPT_LEN, num_slots=2,
         page_size=PAGE, spec_k=3,
     )
     with pytest.raises(MigrationCompatError, match="draft"):

@@ -64,7 +64,6 @@ def served():
 
 def _session(model, params, **kw):
     kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", PAGE)
     return ServeSession.from_model(model, params, WINDOW, **kw)
 
@@ -218,7 +217,7 @@ def test_a_request_migrates_with_its_latent_rows(served):
 
 
 @pytest.mark.parametrize("options, sentence", [
-    ({"paged": False}, "served from the paged pool"),
+    ({"paged": False}, "dense slot cache was removed"),
     ({"adapters": {"t": {}}}, "adapters are not wired to latent"),
     ({"spec_k": 2}, "spec_k is not wired to latent"),
 ], ids=["dense_cache", "tenant_adapters", "speculation"])

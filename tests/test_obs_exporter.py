@@ -437,7 +437,7 @@ def test_dropped_engine_is_collectable_and_health_degrades():
     import gc
 
     from tpudl.obs.slo import Objective, SloMonitor
-    from tpudl.serve.cache import SlotCache
+    from tpudl.serve.cache import PagedKVCache
     from tpudl.serve.engine import Engine
     from tpudl.serve.queue import AdmissionQueue
 
@@ -454,7 +454,7 @@ def test_dropped_engine_is_collectable_and_health_degrades():
     mon = SloMonitor([Objective("o", "serve_ttft_ms", threshold=1.0)])
     engine = Engine(
         prefill_call=lambda *a: None, decode_call=lambda *a: None,
-        params=None, cache=SlotCache(template),
+        params=None, cache=PagedKVCache(template, page_size=4),
         queue=AdmissionQueue(capacity=4), prompt_len=4,
     )
     engine.attach_slo(mon)

@@ -69,7 +69,6 @@ def _requests(n, tag="r", seed=0, new=(3, 8)):
 
 
 def _session(model, params, **kw):
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 4)
     return ServeSession.from_model(
         model, params, prompt_len=PROMPT_LEN, num_slots=2, **kw
@@ -497,20 +496,6 @@ def test_spec_step_has_the_same_two_children(model_and_params, tmp_path):
             "decode.dispatch", "decode.readback"
         ]
         assert all(_inside(k, d) for k in kids)
-
-
-def test_dense_engine_records_the_same_phases(model_and_params, tmp_path):
-    model, params = model_and_params
-    obs.enable(str(tmp_path))
-    session = _session(model, params, paged=False, page_size=None)
-    session.serve(_requests(3))
-    records = obs_spans.active_recorder().records
-    names = {s["name"] for s in _spans(records)}
-    assert {"engine_step", "prefill", "seat", "decode_step",
-            "decode.dispatch", "decode.readback", "emit"} <= names
-    assert all("pages_reserved" not in s
-               for s in _spans(records, "decode_step"))
-    assert all(s["pages"] == 0 for s in _spans(records, "seat"))
 
 
 def test_without_a_recorder_the_engine_records_nothing(

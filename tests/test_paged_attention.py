@@ -153,7 +153,6 @@ def on_a_tpu(monkeypatch):
 
 def _session(model, params, **kw):
     kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", PAGE)
     return ServeSession.from_model(model, params, WINDOW, **kw)
 
@@ -189,7 +188,7 @@ def _mesh_session(model, params):
 
     return build_mesh_session(
         model, params, WINDOW, devices=jax.devices()[:2], tp=2,
-        num_slots=SLOTS, paged=True, page_size=PAGE,
+        num_slots=SLOTS, page_size=PAGE,
     )
 
 

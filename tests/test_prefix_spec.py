@@ -49,7 +49,6 @@ def model_and_params():
 def _session(model, params, **kw):
     kw.setdefault("prompt_len", PROMPT_LEN)
     kw.setdefault("num_slots", 2)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", PAGE)
     return ServeSession.from_model(model, params, **kw)
 
@@ -332,13 +331,22 @@ def test_prefix_eviction_under_pool_pressure():
     assert session.engine.cache.radix.stats()["evictions"] > 0
 
 
-def test_prefix_share_requires_paged(model_and_params):
+def test_prefix_share_needs_no_other_flag(model_and_params):
+    """``prefix_share=True`` alone, pool at its defaults (page size 16):
+    the session shares pages and holds exact parity."""
     model, params = model_and_params
-    with pytest.raises(ValueError, match="require paged"):
-        ServeSession.from_model(
-            model, params, prompt_len=PROMPT_LEN, num_slots=2,
-            prefix_share=True,
-        )
+    session = ServeSession.from_model(
+        model, params, prompt_len=PROMPT_LEN, num_slots=2,
+        prefix_share=True,
+    )
+    assert session.engine.prefix_share
+    assert session.engine.cache.page_size == 16
+    prompt = np.random.default_rng(41).integers(1, 512, size=16).tolist()
+    assert_serving_parity(
+        session, model, params,
+        [Request(f"d{i}", prompt, max_new_tokens=3 + i) for i in range(3)],
+    )
+    assert session.engine.cache.radix.stats()["cached_pages"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -477,12 +485,16 @@ def test_spec_companion_draft_different_architecture(model_and_params):
         )
 
 
-def test_spec_requires_paged(model_and_params):
+def test_spec_needs_no_other_flag(model_and_params):
+    """``spec_k`` alone, pool at its defaults: a speculating session
+    whose draft pool has the target's page size."""
     model, params = model_and_params
-    with pytest.raises(ValueError, match="require paged"):
-        ServeSession.from_model(
-            model, params, prompt_len=PROMPT_LEN, num_slots=2, spec_k=3,
-        )
+    session = ServeSession.from_model(
+        model, params, prompt_len=PROMPT_LEN, num_slots=2, spec_k=3,
+    )
+    spec = session.engine.speculator
+    assert spec is not None and spec.k == 3
+    assert spec.cache.page_size == session.engine.cache.page_size == 16
 
 
 def test_acceptance_rules_unit():
@@ -543,11 +555,11 @@ def test_from_artifacts_paged_parity(model_and_params):
 
     pre, dec = export_serving_decoder(
         model, params, num_slots=2, prompt_len=PROMPT_LEN,
-        paged=True, page_size=PAGE, kv_dtype="int8",
+        page_size=PAGE, kv_dtype="int8",
     )
-    session = ServeSession.from_artifacts(pre, dec, params, paged=True)
+    session = ServeSession.from_artifacts(pre, dec, params)
     cache = session.engine.cache
-    assert cache.paged and cache.quantized and cache.page_size == PAGE
+    assert cache.quantized and cache.page_size == PAGE
     assert session.num_slots == 2
     rng = np.random.default_rng(17)
     reqs = [
@@ -556,9 +568,18 @@ def test_from_artifacts_paged_parity(model_and_params):
         for i in range(3)
     ]
     assert_serving_parity(session, model, params, reqs, atol=0.05)
-    # Expectation mismatch is a loud error, not a silent fallback.
-    with pytest.raises(ValueError, match="paged"):
-        ServeSession.from_artifacts(pre, dec, params, paged=False)
+
+
+def test_from_artifacts_refuses_the_dense_decode_pair(model_and_params):
+    """The pair ``export_decoder`` writes for offline generation has the
+    4-argument dense decode: a session over it is a loud error that
+    names the contract it wants, not a second serving path."""
+    model, params = model_and_params
+    from tpudl.export.decode import export_decoder
+
+    pre, dec = export_decoder(model, params, 1, PROMPT_LEN)
+    with pytest.raises(ValueError, match="7 of the paged decode contract"):
+        ServeSession.from_artifacts(pre, dec, params)
 
 
 def test_from_artifacts_paged_clamps_model_bound(model_and_params):
@@ -571,7 +592,7 @@ def test_from_artifacts_paged_clamps_model_bound(model_and_params):
 
     pre, dec = export_serving_decoder(
         model, params, num_slots=2, prompt_len=PROMPT_LEN,
-        paged=True, page_size=28,  # 4 * 28 = 112 > the model's 96
+        page_size=28,  # 4 * 28 = 112 > the model's 96
     )
     session = ServeSession.from_artifacts(pre, dec, params)
     assert session.max_seq_len == CFG.max_seq_len == 96

@@ -317,7 +317,7 @@ def test_quantized_weights_compose_with_int8_kv(llama_and_params):
     model, params = llama_and_params
     session = ServeSession.from_model(
         model, params, prompt_len=PROMPT_LEN, num_slots=SLOTS,
-        weight_dtype="int8", paged=True, kv_dtype="int8",
+        weight_dtype="int8", kv_dtype="int8",
     )
     assert_serving_parity(
         session, model, params, _requests(6, seed=1), atol=KV8_ATOL

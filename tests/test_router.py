@@ -149,7 +149,9 @@ def test_router_failover_on_503_mid_stream(model_and_params):
     )
     r1 = Replica("r1", sessions[1])
     requests = _greedy_requests(4, seed=3, max_new_lo=12, max_new_hi=18)
-    with Router([r0, r1], scrape_interval_s=0.0) as router:
+    # migrate=False: the from-scratch path (seated work on the pool
+    # would migrate; tests/test_serve_chaos.py has that path).
+    with Router([r0, r1], scrape_interval_s=0.0, migrate=False) as router:
         for req in requests:
             router.submit(req)
         assert any(
@@ -220,7 +222,7 @@ def test_router_disaggregated_prefill_parity(model_and_params):
     dispatch — and the outputs still match solo generate()."""
     model, params = model_and_params
     replicas = [
-        Replica(f"r{i}", _session(model, params, paged=True))
+        Replica(f"r{i}", _session(model, params))
         for i in range(2)
     ]
     worker = PrefillWorker.from_model("p0", model, params, PROMPT_LEN)

@@ -1,8 +1,8 @@
 """Continuous-batching serving engine (tpudl.serve).
 
 The correctness bar mirrors test_generate's: every request served
-through the slot engine — whatever its neighbors, seat time, refills,
-or horizon rollovers — must produce token-for-token what ``generate()``
+through the slot engine — whatever its neighbors, seat time or
+refills — must produce token-for-token what ``generate()``
 produces for that request alone, through both the live model and the
 deserialized StableHLO artifact pair. On top of that: admission
 rejects the unservable, deadlines shed the late, and continuous
@@ -24,7 +24,6 @@ from tpudl.serve import (
     PagedKVCache,
     Request,
     ServeSession,
-    SlotCache,
     assert_serving_parity,
 )
 
@@ -121,8 +120,8 @@ def test_refill_on_exact_step_neighbor_emits_eos(model_and_params):
     assert results["A"].finish_reason == "eos"
     assert results["A"].tokens[-1] == eos and len(results["A"].tokens) <= 20
     # C was refilled mid-stream: the engine never drained between A and
-    # C (a drain would show as a rollover or an idle gap; prefills == 3
-    # with decode steps bounded by B's runtime shows overlap).
+    # C (a drain would show as an idle gap; prefills == 3 with decode
+    # steps bounded by B's runtime shows overlap).
     assert session.engine.num_prefills == 3
     assert session.engine.num_decode_steps < (20 + 24 + 8 - 3)
     for req in requests:
@@ -235,41 +234,36 @@ def test_artifact_vs_live_parity(model_and_params, tmp_path):
         )
 
 
-def test_horizon_rollover_preserves_parity(model_and_params):
-    """More queued decode work than one cache horizon holds: the engine
-    rolls the cache over between waves and every request still matches
-    its solo generation."""
-    model = LlamaForCausalLM(LLAMA_TINY(dtype=jnp.float32, max_seq_len=32))
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1, PROMPT_LEN), jnp.int32)
-    )["params"]
-    session = ServeSession.from_model(
-        model, params, prompt_len=PROMPT_LEN, num_slots=2
+def test_default_session_serves_from_the_paged_pool(model_and_params):
+    """No flag, no environment: the one serving cache is the page pool
+    at its defaults (page size 16, every slot can hold max_seq_len),
+    and health() reports the pool's facts."""
+    model, params = model_and_params
+    session = _session(model, params)
+    cache = session.engine.cache
+    assert isinstance(cache, PagedKVCache)
+    assert cache.page_size == 16 and not cache.quantized
+    assert cache.num_pages == SLOTS * -(-CFG.max_seq_len // 16) + 1
+    health = session.engine.health()
+    assert health["free_pages"] == cache.num_pages - 1
+    assert health["page_size"] == 16
+    assert "write_index" not in health and "paged" not in health
+
+
+@pytest.mark.parametrize("paged", [None, True])
+def test_from_model_paged_keyword_selects_nothing(model_and_params, paged):
+    """``paged`` survives as a keyword the benchmark's configurations
+    pass: None and True build the default session, False names the
+    cache that was removed."""
+    model, params = model_and_params
+    default = _session(model, params).engine.cache
+    cache = _session(model, params, paged=paged).engine.cache
+    assert type(cache) is type(default)
+    assert (cache.page_size, cache.num_pages, cache.quantized) == (
+        default.page_size, default.num_pages, default.quantized,
     )
-    rng = np.random.default_rng(5)
-    requests = [
-        Request(f"r{i}", rng.integers(1, 500, size=5).tolist(),
-                max_new_tokens=20)
-        for i in range(5)
-    ]
-    results = session.serve(requests)
-    assert session.engine.num_rollovers >= 1
-    # The host-mirrored write index stayed in lockstep with the
-    # device-side scalar through seats, decode steps, and resets.
-    device_index = next(
-        int(leaf)
-        for leaf in jax.tree.leaves(session.engine.cache.cache)
-        if leaf.ndim == 0
-    )
-    assert device_index == session.engine.cache.write_index
-    for req in requests:
-        want = np.asarray(
-            generate(model, params, jnp.asarray(req.input_ids)[None, :],
-                     max_new_tokens=20)
-        )[0]
-        np.testing.assert_array_equal(
-            np.asarray(results[req.request_id].tokens), want
-        )
+    with pytest.raises(ValueError, match="dense slot cache was removed"):
+        _session(model, params, paged=False)
 
 
 def test_sampling_is_batch_composition_independent(model_and_params):
@@ -391,41 +385,20 @@ def test_admission_queue_deadlines_and_capacity():
         AdmissionQueue(capacity=0)
 
 
-def test_slot_cache_bookkeeping():
-    template = {
-        "layer": {
-            "k": jax.ShapeDtypeStruct((3, 16, 2, 4), jnp.float32),
-            "valid": jax.ShapeDtypeStruct((3, 16), jnp.bool_),
-            "index": jax.ShapeDtypeStruct((), jnp.int32),
-        }
-    }
-    cache = SlotCache(template)
-    assert (cache.num_slots, cache.max_seq_len) == (3, 16)
-    assert cache.write_index == 0 and cache.remaining_horizon == 16
-    row = {
-        "layer": {
-            "k": jnp.ones((1, 16, 2, 4), jnp.float32),
-            "valid": jnp.asarray([[True] * 5 + [False] * 11]),
-            "index": jnp.int32(5),
-        }
-    }
-    cache.insert(row, 1)
-    assert cache.write_index == 0  # row's own index never leaks in
-    np.testing.assert_array_equal(cache.valid_counts(), [0, 5, 0])
-    cache.set_write_index(5)
-    assert cache.write_index == 5 and cache.remaining_horizon == 11
-    cache.free(1)
-    np.testing.assert_array_equal(cache.valid_counts(), [0, 0, 0])
-    assert cache.write_index == 5  # free touches validity only
-    cache.advance_write_index()  # host mirror of one decode dispatch
-    assert cache.write_index == 6 and cache.remaining_horizon == 10
-    cache.reset()
-    assert cache.write_index == 0
-    assert cache.nbytes > 0
-    with pytest.raises(IndexError):
-        cache.insert(row, 3)
-    with pytest.raises(ValueError, match="validity"):
-        SlotCache({"k": jax.ShapeDtypeStruct((3, 16), jnp.float32)})
+def test_engine_refuses_a_cache_that_is_not_the_pool(model_and_params):
+    """The engine drives the pool through PagedKVCache's methods; a
+    bare cache pytree (what the removed dense path held) is a
+    TypeError at construction, not an AttributeError mid-step."""
+    from tpudl.models.generate import prefill_fn
+    from tpudl.serve import Engine
+
+    model, params = model_and_params
+    ids = jax.ShapeDtypeStruct((SLOTS, PROMPT_LEN), jnp.int32)
+    _, template = jax.eval_shape(prefill_fn(model), params, ids, ids)
+    with pytest.raises(TypeError, match="PagedKVCache"):
+        Engine(None, None, params, template, AdmissionQueue(4), PROMPT_LEN)
+    with pytest.raises(TypeError, match="PagedKVCache"):
+        ServeSession(None, None, params, template, PROMPT_LEN)
 
 
 def test_admission_queue_starvation_promotion():
@@ -575,10 +548,6 @@ def test_cache_bytes_accounting_matches_buffers():
     pools report int8 + scale bytes, not the dense dtype, and the
     host-side page-table/start/len addressing is counted."""
     template = _paged_template()
-    dense = SlotCache(template)
-    assert dense.nbytes == sum(
-        leaf.nbytes for leaf in jax.tree.leaves(dense.cache)
-    )
     f32 = PagedKVCache(template, page_size=8)
     q8 = PagedKVCache(template, page_size=8, kv_dtype="int8")
     for paged in (f32, q8):
@@ -600,18 +569,17 @@ def test_cache_bytes_accounting_matches_buffers():
 
 
 def test_paged_rollover_free_long_generation():
-    """The workload that forces the dense cache to roll over (see
-    test_horizon_rollover_preserves_parity: 5 x 20-token requests
-    through 2 slots of a 32-position model — cumulative decode writes
-    cross the shared horizon several times) runs rollover-FREE on the
-    paged cache, with identical tokens: slots recycle piecewise, no
-    shared write index exists."""
+    """5 x 20-token requests through 2 slots of a 32-position model:
+    cumulative decode writes cross the model's sequence bound several
+    times (what a cache with one shared write index would have to
+    reset for). The default session serves them with ``generate()``'s
+    tokens: slots recycle piecewise, every page comes back."""
     model = LlamaForCausalLM(LLAMA_TINY(dtype=jnp.float32, max_seq_len=32))
     params = model.init(
         jax.random.key(0), jnp.zeros((1, PROMPT_LEN), jnp.int32)
     )["params"]
     session = ServeSession.from_model(
-        model, params, prompt_len=PROMPT_LEN, num_slots=2, paged=True,
+        model, params, prompt_len=PROMPT_LEN, num_slots=2
     )
     rng = np.random.default_rng(5)
     requests = [
@@ -620,9 +588,8 @@ def test_paged_rollover_free_long_generation():
         for i in range(5)
     ]
     total_decode_tokens = sum(r.max_new_tokens for r in requests)
-    assert total_decode_tokens > 32  # crosses what was the horizon
+    assert total_decode_tokens > 32
     results = session.serve(requests)
-    assert session.engine.num_rollovers == 0
     assert session.engine.cache.free_pages == session.engine.cache.num_pages - 1
     for req in requests:
         want = np.asarray(
@@ -644,7 +611,7 @@ def test_int8_kv_decode_parity_at_tolerance(model_and_params):
     model, params = model_and_params
     session = ServeSession.from_model(
         model, params, prompt_len=PROMPT_LEN, num_slots=SLOTS,
-        paged=True, kv_dtype="int8",
+        kv_dtype="int8",
     )
     assert session.engine.cache.quantized
     assert (
@@ -654,7 +621,6 @@ def test_int8_kv_decode_parity_at_tolerance(model_and_params):
     assert_serving_parity(
         session, model, params, _ragged_requests(8, seed=1), atol=0.05
     )
-    assert session.engine.num_rollovers == 0
 
 
 def test_streaming_matches_collect(model_and_params):
@@ -763,7 +729,7 @@ def test_paged_page_size_not_dividing_model_bound(model_and_params):
     last page instead of raising at trace time — which previously
     struck AFTER pages were reserved, stranding the slot."""
     model, params = model_and_params
-    session = _session(model, params, paged=True, page_size=100)
+    session = _session(model, params, page_size=100)
     engine = session.engine
     assert engine.cache.max_seq_len == CFG.max_seq_len  # clamped, not 100
     assert engine.max_seq_len == CFG.max_seq_len

@@ -88,7 +88,7 @@ def _session(programs, slow_s: float = 0.0, **kw):
     cache = PagedKVCache(programs["template"], page_size=PAGE)
     session = ServeSession(
         programs["prefill"], programs["decode"], programs["params"],
-        programs["template"], PROMPT_LEN, cache=cache, **kw,
+        cache, PROMPT_LEN, **kw,
     )
     if slow_s:
         orig = session.engine.decode_call
@@ -171,7 +171,7 @@ def test_migration_int8_pages_ship_as_int8(programs):
         )
         return ServeSession(
             programs["prefill"], dec8, params,
-            programs["template"], PROMPT_LEN, cache=cache,
+            cache, PROMPT_LEN,
         )
 
     req = Request("r0", [3, 5, 7, 11, 2], max_new_tokens=16)
@@ -323,7 +323,7 @@ def test_migration_prefix_reference_first(programs):
     def mk_share():
         return ServeSession.from_model(
             model, params, prompt_len=3 * PAGE, num_slots=2,
-            paged=True, page_size=PAGE, prefix_share=True,
+            page_size=PAGE, prefix_share=True,
         )
 
     shared = list(range(2, 2 + PAGE))  # one full page
@@ -699,7 +699,7 @@ def test_pad_aligned_payload_ignores_prepinned_lease(programs):
     model, params = programs["model"], programs["params"]
     share = ServeSession.from_model(
         model, params, prompt_len=2 * PAGE, num_slots=2,
-        paged=True, page_size=PAGE, prefix_share=True,
+        page_size=PAGE, prefix_share=True,
     )
     prompt = list(range(2, 2 + PAGE)) + [31, 37]
     # Warm the share target's tree with the same leading page.
@@ -708,7 +708,7 @@ def test_pad_aligned_payload_ignores_prepinned_lease(programs):
     # Pad-aligned source: plain paged session (seat() path, start > 0).
     src = ServeSession.from_model(
         model, params, prompt_len=2 * PAGE, num_slots=2,
-        paged=True, page_size=PAGE,
+        page_size=PAGE,
     )
     req = Request("r0", prompt, max_new_tokens=10)
     src.submit(req)

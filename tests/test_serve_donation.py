@@ -50,7 +50,6 @@ def model_and_params():
 def _session(model, params, **kw):
     kw.setdefault("prompt_len", PROMPT_LEN)
     kw.setdefault("num_slots", SLOTS)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", PAGE)
     return ServeSession.from_model(model, params, **kw)
 
@@ -209,7 +208,7 @@ def _artifact_session(model, params, kv_dtype=None):
     from tpudl.export.decode import export_serving_decoder
 
     pre, dec = export_serving_decoder(
-        model, params, SLOTS, PROMPT_LEN, paged=True, page_size=PAGE,
+        model, params, SLOTS, PROMPT_LEN, page_size=PAGE,
         kv_dtype=kv_dtype,
     )
     return ServeSession.from_artifacts(pre, dec, params)
@@ -220,7 +219,7 @@ def _mesh_session(model, params, kv_dtype=None):
 
     return build_mesh_session(
         model, params, PROMPT_LEN, devices=jax.devices()[:2], tp=2,
-        num_slots=SLOTS, paged=True, page_size=PAGE, kv_dtype=kv_dtype,
+        num_slots=SLOTS, page_size=PAGE, kv_dtype=kv_dtype,
     )
 
 
@@ -349,7 +348,7 @@ def test_donation_changes_no_token(model_and_params, kv_dtype, seed):
     copying = ServeSession(
         jax.jit(prefill_fn(model)),
         jax.jit(paged_decode_fn(model, PAGE, kv_dtype == "int8")),
-        params, template, PROMPT_LEN, cache=cache,
+        params, cache, PROMPT_LEN,
     )
     registry().reset()
     want = copying.serve(_requests(5, seed=seed))

@@ -427,7 +427,7 @@ def test_tenant_admission_validation(base, adapters):
     with pytest.raises(ValueError, match="unknown tenant"):
         session.submit(Request("x", [1, 2], 2, tenant="nobody"))
     plain = ServeSession.from_model(
-        model, params, prompt_len=PROMPT_LEN, num_slots=2, paged=True
+        model, params, prompt_len=PROMPT_LEN, num_slots=2
     )
     with pytest.raises(ValueError, match="serves no adapters"):
         plain.submit(Request("y", [1, 2], 2, tenant="t0"))
@@ -498,7 +498,7 @@ def test_migration_refused_without_target_pool(base, adapters):
         adapters={"t0": adapters["t0"]},
     )
     dst = ServeSession.from_model(
-        model, params, prompt_len=PROMPT_LEN, num_slots=2, paged=True
+        model, params, prompt_len=PROMPT_LEN, num_slots=2
     )
     req = Request("m2", [4, 5, 6], max_new_tokens=8, tenant="t0")
     src.submit(req)
@@ -558,7 +558,7 @@ def test_router_places_tenant_only_on_serving_replica(base, adapters):
 
     model, params = base
     s_plain = ServeSession.from_model(
-        model, params, prompt_len=PROMPT_LEN, num_slots=2, paged=True
+        model, params, prompt_len=PROMPT_LEN, num_slots=2
     )
     s_lora = ServeSession.from_model(
         model, params, prompt_len=PROMPT_LEN, num_slots=2,

@@ -258,7 +258,7 @@ def _pool_session():
     params = jax.eval_shape(model.init, jax.random.key(0), ids)["params"]
     session = ServeSession.from_model(
         model, params, prompt_len=POOL_WINDOW, num_slots=POOL_SLOTS,
-        paged=True, page_size=POOL_PAGE,
+        page_size=POOL_PAGE,
         num_pages=POOL_SEQ // POOL_PAGE + 1,
     )
     _, row = jax.eval_shape(prefill_fn(model), params, ids, ids)
@@ -391,7 +391,7 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
     params = jax.eval_shape(model.init, jax.random.key(0), ids)["params"]
     session = ServeSession.from_model(
         model, params, prompt_len=LATENT_WINDOW, num_slots=LATENT_SLOTS,
-        paged=True, page_size=POOL_PAGE,
+        page_size=POOL_PAGE,
         num_pages=LATENT_SEQ // POOL_PAGE + 1,
     )
     cache = session.engine.cache
@@ -422,8 +422,10 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
 
 
 # ---------------------------------------------------------------------------
-# chip_smoke.py on the CPU: the phases at a tiny size, through a path
-# only the tests take — the script's own device check is not weakened.
+# chip_smoke.py on the CPU: the phases at a tiny size (one serve phase,
+# on the page pool; one train phase; the two mesh phases), through a
+# path only the tests take — the script's own device check is not
+# weakened.
 # ---------------------------------------------------------------------------
 
 
@@ -451,11 +453,9 @@ def test_chip_smoke_refuses_cpu(chip_smoke, capsys):
     assert capsys.readouterr().out == ""  # no phase ran, no result line
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_chip_smoke_serve_phase_tiny(chip_smoke, paged):
-    line = chip_smoke.serve_phase(
-        0, paged=paged, requests_per_wave=4, **TINY_SERVE
-    )
+def test_chip_smoke_serve_phase_tiny(chip_smoke):
+    line = chip_smoke.serve_phase(0, requests_per_wave=4, **TINY_SERVE)
+    assert line["phase"] == "serve"
     # f32 on one backend: every request agrees token for token.
     assert line["requests"] == 8 and line["requests_equal_generate"] == 8
     assert line["recompiles_after_warmup"] == 0

@@ -171,9 +171,6 @@ _declare("TPUDL_SERVE_SLOTS", "int", 4,
 _declare("TPUDL_SERVE_QUEUE_DEPTH", "int", 256,
          "Admission queue capacity; overflow sheds shed_capacity.",
          "tpudl.serve.api")
-_declare("TPUDL_SERVE_PAGED", "flag", False,
-         "Swap the dense fixed-slot KV cache for the paged pool.",
-         "tpudl.serve.api")
 _declare("TPUDL_SERVE_PAGE_SIZE", "int", 16,
          "Paged KV page size in tokens.",
          "tpudl.serve.api")
@@ -187,7 +184,7 @@ _declare("TPUDL_SERVE_WEIGHT_DTYPE", "str", None,
          "tpudl.serve.api")
 _declare("TPUDL_SERVE_PREFIX_SHARE", "flag", False,
          "Radix prefix-sharing KV: COW page sharing + chunked suffix "
-         "prefill (requires paged).",
+         "prefill.",
          "tpudl.serve.api")
 _declare("TPUDL_SERVE_SPEC_K", "int", None,
          "Speculative-decoding window (draft proposes k tokens per "

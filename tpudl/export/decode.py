@@ -10,9 +10,13 @@ survives serialization), and a deserialized-artifact generation loop
 reproduces live ``generate()`` token for token
 (tests/test_decode_export.py).
 
-Artifacts:
+Artifacts (``export_decoder``, the offline generation pair):
 - prefill: (params, input_ids, attention_mask) -> (last_logits, cache)
 - decode:  (params, cache, token, position) -> (logits, new_cache)
+
+``export_serving_decoder`` writes the pair tpudl.serve runs: a batch-1
+prefill and the slot-batched PAGED decode (page pools in and out, page
+table / start / lens as inputs).
 
 Both can carry multi-platform lowering (cpu + tpu) like the rest of
 tpudl.export — one artifact, either backend, the property the reference
@@ -46,36 +50,22 @@ def export_decoder(
     prompt_len: int,
     path_prefix: Optional[str] = None,
     platforms: Optional[Sequence[str]] = None,
-    decode_batch_size: Optional[int] = None,
 ) -> Tuple[bytes, bytes]:
     """Export (prefill, decode) StableHLO artifacts for fixed
     ``batch_size``/``prompt_len`` shapes (static shapes are the serving
     contract — the KV cache is bounded by model.cfg.max_seq_len).
 
-    ``decode_batch_size`` lets the decode program carry a different
-    batch than the prefill (the continuous-batching engine prefills one
-    request at a time into a slot-batched decode — see
-    ``export_serving_decoder``); default: same as ``batch_size``.
-
     With ``path_prefix``, writes ``{prefix}.prefill.stablehlo`` and
     ``{prefix}.decode.stablehlo``.
     """
-    if decode_batch_size is None:
-        decode_batch_size = batch_size
     ids = jnp.zeros((batch_size, prompt_len), jnp.int32)
     mask = jnp.ones((batch_size, prompt_len), jnp.int32)
     pf = prefill_fn(model)
-    # A real (abstractly-traced) cache example for the decode export, at
-    # the decode program's own batch.
-    _, cache = jax.eval_shape(
-        pf,
-        params,
-        jnp.zeros((decode_batch_size, prompt_len), jnp.int32),
-        jnp.ones((decode_batch_size, prompt_len), jnp.int32),
-    )
+    # A real (abstractly-traced) cache example for the decode export.
+    _, cache = jax.eval_shape(pf, params, ids, mask)
     cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache)
-    token = jnp.zeros((decode_batch_size,), jnp.int32)
-    position = jnp.full((decode_batch_size,), prompt_len, jnp.int32)
+    token = jnp.zeros((batch_size,), jnp.int32)
+    position = jnp.full((batch_size,), prompt_len, jnp.int32)
 
     prefill_blob = export_stablehlo(
         pf,
@@ -99,7 +89,6 @@ def export_serving_decoder(
     prompt_len: int,
     path_prefix: Optional[str] = None,
     platforms: Optional[Sequence[str]] = None,
-    paged: bool = False,
     page_size: int = 16,
     kv_dtype: Optional[str] = None,
     num_pages: Optional[int] = None,
@@ -110,7 +99,7 @@ def export_serving_decoder(
     ``ServeSession.from_artifacts`` recovers every shape it needs from
     these blobs — no side-channel metadata.
 
-    ``paged=True`` exports the PAGED decode contract instead
+    The decode is the PAGED contract
     (tpudl.models.generate.paged_decode_fn): the cache input is the
     page-pool pytree and three host-owned addressing arrays (page
     table, start, lens) ride as extra traced inputs — seating/freeing
@@ -118,12 +107,6 @@ def export_serving_decoder(
     the live path. ``page_size``/``kv_dtype``/``num_pages`` fix the
     exported pool geometry (a PagedKVCache at the same settings);
     ``from_artifacts`` reads it all back from the avals."""
-    if not paged:
-        return export_decoder(
-            model, params, 1, prompt_len,
-            path_prefix=path_prefix, platforms=platforms,
-            decode_batch_size=num_slots,
-        )
     from tpudl.models.generate import paged_decode_fn
     from tpudl.serve.cache import PagedKVCache
 

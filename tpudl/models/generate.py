@@ -96,7 +96,7 @@ def prefill_fn(model):
 
 
 def decode_fn(model):
-    """THE functional single-token decode contract:
+    """``generate()``'s decode step (dense rows; the engine's is paged):
     (params, cache, token, position) -> (logits, new_cache)."""
 
     def tpudl_decode(params, cache, token, position):
@@ -125,7 +125,7 @@ def paged_decode_fn(
     logical write position [B]. ``page_size``/``quantized``/``sharded``
     (the pool was committed to a mesh: ``PagedKVCache.sharded``) are
     static (baked into the compiled program); placement changes never
-    recompile. Built for the serve engine's paged mode
+    recompile. Built for the serve engine
     (tpudl.serve.cache.PagedKVCache owns the pools and addressing).
 
     Once traced, the program says of itself which of its attention

@@ -407,7 +407,7 @@ class LlamaAttention(nn.Module):
             # Paged decode (tpudl.models.paged): KV lives in page pools
             # addressed by the host-provided page table instead of the
             # dense [B, max_seq] rows below — each slot has its OWN
-            # length (no shared write index, so no horizon rollover).
+            # length (no shared write index).
             # This step's rows are scattered into the donated pool
             # first (paged_write), then attention reads the pool: in
             # place where it can (tpudl.ops.paged_attention: a kernel

@@ -3,8 +3,10 @@
 The dense decode cache (tpudl.models.llama.LlamaAttention decode mode)
 allocates ``[num_slots, max_seq_len, Hkv, D]`` per layer whether a slot
 holds a 14-token short request or a 256-token horizon-filler, and every
-slot shares ONE device-side write index — the source of the serve
-engine's horizon rollovers. The paged layout replaces both:
+slot shares ONE device-side write index, which is right for
+``generate()``'s batch in lockstep and wrong for a server whose
+requests come and go. The paged layout, the serve engine's, replaces
+both:
 
 - KV lives in a pool of fixed-size **pages** ``[num_pages, page_size,
   Hkv, D]`` per layer; a slot owns whichever pages its **page table**
@@ -12,8 +14,7 @@ engine's horizon rollovers. The paged layout replaces both:
   id). Memory scales with what requests actually reserve, not with
   ``num_slots x max_seq_len``.
 - Each slot carries its OWN length (``lens[slot]``) — decode writes
-  row ``b`` at its own logical position, so no horizon is shared and
-  rollovers cease to exist.
+  row ``b`` at its own logical position, so no horizon is shared.
 - Pages optionally store **int8** with a dequant scale per (page, row,
   kv-head) — ~4x the resident tokens per byte vs f32 pools — applied
   inside the decode gather (one fused multiply on the gathered view).

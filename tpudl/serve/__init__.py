@@ -4,9 +4,8 @@ decode path, scaled out by a multi-replica router.
 The reference repo's substance is export -> session -> infer on single
 inputs (reference notebooks/cv/onnx_experiments.py); this package is
 what sits between that and "serve heavy traffic": a bounded admission
-queue (tpudl.serve.queue), KV cache managers — the dense fixed-slot
-layout and its paged + optionally int8-quantized successor
-(tpudl.serve.cache) — a continuous-batching engine multiplexing many
+queue (tpudl.serve.queue), the paged, optionally int8-quantized KV
+cache (tpudl.serve.cache), a continuous-batching engine multiplexing many
 requests onto the compiled XLA programs (tpudl.serve.engine), a
 synchronous Request/Result front end with token streaming that serves
 either a live model or a deserialized StableHLO artifact
@@ -37,7 +36,6 @@ from tpudl.serve.cache import (  # noqa: F401
     MigrationCorruptError,
     PagedKVCache,
     RadixPrefixTree,
-    SlotCache,
 )
 from tpudl.serve.engine import Engine  # noqa: F401
 from tpudl.serve.lora import (  # noqa: F401

@@ -24,9 +24,9 @@ deadline produces a ``Result`` with finish_reason ``shed_capacity`` /
 Knobs: ``TPUDL_SERVE_SLOTS`` (default slot count for ``from_model``,
 artifact sessions carry theirs in the decode program's batch dim),
 ``TPUDL_SERVE_QUEUE_DEPTH`` (admission queue capacity),
-``TPUDL_SERVE_PAGED`` / ``TPUDL_SERVE_PAGE_SIZE`` /
-``TPUDL_SERVE_KV_DTYPE`` (paged KV layout + optional int8 storage for
-``from_model`` — see tpudl.serve.cache.PagedKVCache),
+``TPUDL_SERVE_PAGE_SIZE`` / ``TPUDL_SERVE_KV_DTYPE`` (page size and
+optional int8 storage of ``from_model``'s KV pool — see
+tpudl.serve.cache.PagedKVCache),
 ``TPUDL_SERVE_PREFIX_SHARE`` (radix prefix-sharing KV — COW page
 sharing + chunked suffix prefill), ``TPUDL_SERVE_SPEC_K``
 (speculative decoding window; 0/unset = off — see
@@ -53,7 +53,7 @@ from tpudl.analysis.registry import env_flag, env_int, env_str
 from tpudl.obs import registry
 from tpudl.obs import requestlog
 from tpudl.obs.spans import active_recorder
-from tpudl.serve.cache import SlotCache
+from tpudl.serve.cache import PagedKVCache, _is_pool, _is_valid_leaf
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue
 
 
@@ -167,8 +167,6 @@ def _find_pool(tree) -> Optional[dict]:
     artifact-geometry probe ``from_artifacts`` reads shapes off)."""
     from collections.abc import Mapping
 
-    from tpudl.serve.cache import _is_pool
-
     if isinstance(tree, Mapping):
         if _is_pool(tree):
             return dict(tree)
@@ -191,13 +189,12 @@ class ServeSession:
         prefill_call: Callable,
         decode_call: Callable,
         params: Any,
-        cache_template: Any,
+        cache: PagedKVCache,
         prompt_len: int,
         queue_capacity: Optional[int] = None,
         clock: Callable[[], float] = time.monotonic,
         continuous: bool = True,
         slo=None,
-        cache=None,
         chunk_prefill_call: Optional[Callable] = None,
         speculator=None,
         verify_call: Optional[Callable] = None,
@@ -212,8 +209,6 @@ class ServeSession:
         # exposes /metrics, /healthz (engine slots/queue + SLO burn
         # state), and /snapshot while it runs.
         obs_exporter.maybe_start_from_env()
-        if cache is None:
-            cache = SlotCache(cache_template)
         self.queue = AdmissionQueue(
             capacity=queue_capacity
             if queue_capacity is not None
@@ -274,8 +269,8 @@ class ServeSession:
         template by abstract evaluation — nothing compiles until the
         first request.
 
-        ``prefix_share=True`` (or ``TPUDL_SERVE_PREFIX_SHARE=1``;
-        requires ``paged``) turns on the radix prefix cache: seating
+        ``prefix_share=True`` (or ``TPUDL_SERVE_PREFIX_SHARE=1``) turns
+        on the radix prefix cache: seating
         walks a tree of page-granular token-block hashes, maps every
         matched full page into the new slot's table copy-on-write for
         free, and prefills only the unshared suffix through the
@@ -283,7 +278,7 @@ class ServeSession:
         once per replica, then TTFT is O(unshared suffix) and resident
         capacity multiplies on top of int8 KV.
 
-        ``spec_k=K`` (or ``TPUDL_SERVE_SPEC_K``; requires ``paged``)
+        ``spec_k=K`` (or ``TPUDL_SERVE_SPEC_K``)
         turns on speculative decoding: a DRAFT path proposes K tokens
         per slot (default: a quantized self-draft built by
         ``tpudl.quant`` at ``draft_weight_dtype``; pass
@@ -293,18 +288,17 @@ class ServeSession:
         (tpudl.serve.speculate), gated by ``assert_serving_parity``'s
         teacher-forced margin mode.
 
-        ``paged=True`` (or ``TPUDL_SERVE_PAGED=1``) swaps the dense
-        fixed-slot cache for the paged layout (per-slot page tables, no
-        shared write horizon, so no rollovers); ``kv_dtype="int8"`` (or
-        ``TPUDL_SERVE_KV_DTYPE=int8``) additionally stores pages
-        quantized with per-(page, row, head) dequant scales fused into
-        the decode gather — ~4x the resident slots per byte.
+        The KV cache is the paged pool (tpudl.serve.cache.PagedKVCache).
+        ``kv_dtype="int8"`` (or ``TPUDL_SERVE_KV_DTYPE=int8``) stores
+        pages quantized with per-(page, row, head) dequant scales fused
+        into the decode gather — ~4x the resident slots per byte.
         ``page_size`` (``TPUDL_SERVE_PAGE_SIZE``, default 16) and
-        ``num_pages`` (default: capacity parity with the dense cache)
+        ``num_pages`` (default: every slot can hold ``max_seq_len``)
         size the pool. ``mesh`` is the mesh ``params`` were committed
         to, if any (tpudl.fleet.meshrep.build_mesh_session passes it):
-        the paged pools are committed to it before any program is
-        built for them.
+        the pools are committed to it before any program is built for
+        them. ``paged`` selects nothing: it is accepted because the
+        benchmark's configurations still pass ``"paged": true``.
 
         ``adapters={tenant: lora_tree}`` turns on MULTI-TENANT adapter
         serving (tpudl.serve.lora): the base model stays resident once
@@ -314,7 +308,7 @@ class ServeSession:
         applies every slot's own adapter through ONE segmented-matmul
         dispatch per projection site (tpudl.ops.segmented_lora).
         ``Request.tenant`` picks the adapter (None = plain base).
-        Requires ``paged`` (auto-enabled); composes with
+        Composes with
         ``weight_dtype`` — the old lora/quantization mutual exclusion
         is lifted, since adapters ride OUTSIDE the base projections.
         ``adapter_rank_max`` (``TPUDL_SERVE_LORA_RANK``; default = the
@@ -337,7 +331,6 @@ class ServeSession:
         model, same as the quantized-KV tier."""
         from tpudl.models.generate import (
             chunk_prefill_fn,
-            decode_fn,
             lora_paged_decode_fn,
             lora_prefill_fn,
             named,
@@ -346,6 +339,11 @@ class ServeSession:
             prefill_fn,
         )
 
+        if paged is not None and not paged:
+            raise ValueError(
+                "paged=False: the dense slot cache was removed, every "
+                "session serves from the paged pool (PagedKVCache)"
+            )
         if weight_dtype is None:
             weight_dtype = env_str("TPUDL_SERVE_WEIGHT_DTYPE")
         if weight_dtype is not None:
@@ -359,8 +357,6 @@ class ServeSession:
         )
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-        if paged is None:
-            paged = env_flag("TPUDL_SERVE_PAGED")
         if prefix_share is None:
             prefix_share = env_flag("TPUDL_SERVE_PREFIX_SHARE")
         if spec_k is None:
@@ -373,10 +369,6 @@ class ServeSession:
                     "adapters={} registers no tenants — pass None to "
                     "serve the plain base model"
                 )
-            # Adapter serving rides the paged substrate (same
-            # host-owned-table contract); a dense request for it is a
-            # config error, not a silent downgrade.
-            paged = True
             if prefix_share:
                 raise ValueError(
                     "prefix_share cannot compose with per-tenant "
@@ -398,13 +390,6 @@ class ServeSession:
             # the paged pool (one leaf a layer), its int8 store, prefix
             # sharing and migration. The rest says so here, in a
             # sentence, instead of failing on a shape further down.
-            if not paged:
-                raise ValueError(
-                    "a model with latent attention or routed experts is "
-                    "served from the paged pool: pass paged=True (the "
-                    "dense slot cache has no absorbed decode and reports "
-                    "no tokens per expert)"
-                )
             if adapters is not None:
                 raise ValueError(
                     "per-tenant adapters are not wired to latent "
@@ -421,160 +406,137 @@ class ServeSession:
         pf = prefill_fn(model)
         ids = jax.ShapeDtypeStruct((num_slots, prompt_len), jnp.int32)
         _, cache_template, *_ = jax.eval_shape(pf, params, ids, ids)
-        chunk_prefill = None
         speculator = None
         verify = None
-        if paged:
-            from tpudl.serve.cache import PagedKVCache
+        if kv_dtype is None:
+            kv_dtype = env_str("TPUDL_SERVE_KV_DTYPE")
+        cache = PagedKVCache(
+            cache_template,
+            page_size=(
+                page_size
+                if page_size is not None
+                else _env_int("TPUDL_SERVE_PAGE_SIZE", 16)
+            ),
+            num_pages=num_pages,
+            kv_dtype=kv_dtype,
+            prefix_share=bool(prefix_share),
+        )
+        if mesh is not None:
+            cache.commit(mesh)
+        # Every program that takes the pool and returns its successor
+        # donates it (PagedKVCache: the ownership rule). What the cache
+        # knows of its pool (page size, int8 rows, committed to a mesh)
+        # is static in the programs.
+        pool_facts = (cache.page_size, cache.quantized)
+        decode = jax.jit(
+            paged_decode_fn(model, *pool_facts, sharded=cache.sharded),
+            donate_argnums=(1,),
+        )
+        if adapters is not None:
+            from tpudl.serve.lora import AdapterPool
 
-            if kv_dtype is None:
-                kv_dtype = env_str("TPUDL_SERVE_KV_DTYPE")
-            cache = PagedKVCache(
-                cache_template,
-                page_size=(
-                    page_size
-                    if page_size is not None
-                    else _env_int("TPUDL_SERVE_PAGE_SIZE", 16)
-                ),
-                num_pages=num_pages,
-                kv_dtype=kv_dtype,
-                prefix_share=bool(prefix_share),
+            if adapter_rank_max is None:
+                adapter_rank_max = env_int("TPUDL_SERVE_LORA_RANK")
+            if adapter_pages is None:
+                adapter_pages = env_int("TPUDL_SERVE_LORA_PAGES")
+            if adapter_dtype is None:
+                adapter_dtype = env_str("TPUDL_SERVE_LORA_DTYPE")
+            if adapter_rank_max is None:
+                # Default rank budget: the largest registered adapter
+                # (probed off the trees before the pool exists — ranks
+                # validate again at register).
+                from tpudl.models.lora import as_flat_adapters
+
+                ranks = [
+                    int(jnp.shape(f["lora_a"])[-1])
+                    for tree in adapters.values()
+                    for f in as_flat_adapters(tree).values()
+                ]
+                if not ranks:
+                    raise ValueError(
+                        "no lora_a/lora_b leaves in any adapter tree"
+                    )
+                adapter_rank_max = max(ranks)
+            pool = AdapterPool(
+                model.cfg,
+                r_max=adapter_rank_max,
+                num_slots=num_slots,
+                num_pages=adapter_pages,
+                dtype=adapter_dtype,
             )
-            if mesh is not None:
-                cache.commit(mesh)
-            # Every program that takes the pool and returns its
-            # successor donates it (PagedKVCache: the ownership rule).
-            # What the cache knows of its pool (page size, int8 rows,
-            # committed to a mesh) is static in the programs.
-            pool_facts = (cache.page_size, cache.quantized)
+            for tenant, tree in adapters.items():
+                pool.register(tenant, tree, alpha=adapter_alpha)
+            kwargs["adapter_pool"] = pool
             decode = jax.jit(
-                paged_decode_fn(model, *pool_facts, sharded=cache.sharded),
+                lora_paged_decode_fn(
+                    model, *pool_facts, impl=adapter_impl,
+                    sharded=cache.sharded,
+                ),
                 donate_argnums=(1,),
             )
-            if adapters is not None:
-                from tpudl.serve.lora import AdapterPool
+        chunk_prefill = (
+            jax.jit(chunk_prefill_fn(model)) if prefix_share else None
+        )
+        if spec_k:
+            from tpudl.quant import quantize_model, weight_bytes_report
+            from tpudl.serve.speculate import Speculator
 
-                if adapter_rank_max is None:
-                    adapter_rank_max = env_int("TPUDL_SERVE_LORA_RANK")
-                if adapter_pages is None:
-                    adapter_pages = env_int("TPUDL_SERVE_LORA_PAGES")
-                if adapter_dtype is None:
-                    adapter_dtype = env_str("TPUDL_SERVE_LORA_DTYPE")
-                if adapter_rank_max is None:
-                    # Default rank budget: the largest registered
-                    # adapter (probed off the trees before the pool
-                    # exists — ranks validate again at register).
-                    from tpudl.models.lora import as_flat_adapters
-
-                    ranks = [
-                        int(jnp.shape(f["lora_a"])[-1])
-                        for tree in adapters.values()
-                        for f in as_flat_adapters(tree).values()
-                    ]
-                    if not ranks:
-                        raise ValueError(
-                            "no lora_a/lora_b leaves in any adapter "
-                            "tree"
-                        )
-                    adapter_rank_max = max(ranks)
-                pool = AdapterPool(
-                    model.cfg,
-                    r_max=adapter_rank_max,
-                    num_slots=num_slots,
-                    num_pages=adapter_pages,
-                    dtype=adapter_dtype,
+            if draft_model is None:
+                # Quantized SELF-draft: same architecture, low-precision
+                # weights — agrees with the target on almost every
+                # greedy token at a fraction of the bytes/dispatch.
+                draft_model, draft_params = quantize_model(
+                    model, params, draft_weight_dtype
                 )
-                for tenant, tree in adapters.items():
-                    pool.register(tenant, tree, alpha=adapter_alpha)
-                kwargs["adapter_pool"] = pool
-                decode = jax.jit(
-                    lora_paged_decode_fn(
-                        model, *pool_facts, impl=adapter_impl,
-                        sharded=cache.sharded,
-                    ),
-                    donate_argnums=(1,),
-                )
-            if prefix_share:
-                chunk_prefill = jax.jit(chunk_prefill_fn(model))
-            if spec_k:
-                from tpudl.quant import quantize_model, weight_bytes_report
-                from tpudl.serve.speculate import Speculator
-
-                if draft_model is None:
-                    # Quantized SELF-draft: same architecture, low-
-                    # precision weights — agrees with the target on
-                    # almost every greedy token at a fraction of the
-                    # bytes/dispatch.
-                    draft_model, draft_params = quantize_model(
-                        model, params, draft_weight_dtype
-                    )
-                elif draft_params is None:
-                    raise ValueError(
-                        "draft_model needs draft_params"
-                    )
-                # The draft's OWN cache template: a companion model's
-                # KV geometry (layers, kv-heads, head-dim) need not
-                # match the target's — only the tokenizer must.
-                _, draft_template = jax.eval_shape(
-                    prefill_fn(draft_model), draft_params, ids, ids
-                )
-                draft_cache = PagedKVCache(
-                    draft_template,
-                    page_size=cache.page_size,
-                    num_pages=num_pages,
-                )
-                if mesh is not None:
-                    draft_cache.commit(mesh)
-                speculator = Speculator(
-                    jax.jit(named(
-                        prefill_fn(draft_model), "tpudl_draft_prefill"
-                    )),
-                    jax.jit(
-                        named(
-                            paged_decode_fn(
-                                draft_model, draft_cache.page_size, False,
-                                sharded=draft_cache.sharded,
-                            ),
-                            "tpudl_draft_decode",
+            elif draft_params is None:
+                raise ValueError("draft_model needs draft_params")
+            # The draft's OWN cache template: a companion model's KV
+            # geometry (layers, kv-heads, head-dim) need not match the
+            # target's — only the tokenizer must.
+            _, draft_template = jax.eval_shape(
+                prefill_fn(draft_model), draft_params, ids, ids
+            )
+            draft_cache = PagedKVCache(
+                draft_template,
+                page_size=cache.page_size,
+                num_pages=num_pages,
+            )
+            if mesh is not None:
+                draft_cache.commit(mesh)
+            speculator = Speculator(
+                jax.jit(named(
+                    prefill_fn(draft_model), "tpudl_draft_prefill"
+                )),
+                jax.jit(
+                    named(
+                        paged_decode_fn(
+                            draft_model, draft_cache.page_size, False,
+                            sharded=draft_cache.sharded,
                         ),
-                        donate_argnums=(1,),
-                    ),
-                    draft_params,
-                    draft_cache,
-                    k=spec_k,
-                    weight_bytes=weight_bytes_report(
-                        draft_params
-                    )["total_bytes"],
-                )
-                verify = jax.jit(
-                    paged_chunk_decode_fn(
-                        model, *pool_facts, sharded=cache.sharded
+                        "tpudl_draft_decode",
                     ),
                     donate_argnums=(1,),
-                )
-        elif page_size is not None or kv_dtype is not None or (
-            num_pages is not None
-        ):
-            raise ValueError(
-                "page_size/kv_dtype/num_pages require paged=True"
+                ),
+                draft_params,
+                draft_cache,
+                k=spec_k,
+                weight_bytes=weight_bytes_report(
+                    draft_params
+                )["total_bytes"],
             )
-        elif prefix_share or spec_k:
-            raise ValueError(
-                "prefix_share/spec_k require paged=True (per-slot page "
-                "tables are what make COW sharing and window rollback "
-                "possible)"
+            verify = jax.jit(
+                paged_chunk_decode_fn(
+                    model, *pool_facts, sharded=cache.sharded
+                ),
+                donate_argnums=(1,),
             )
-        else:
-            cache = None
-            decode = jax.jit(decode_fn(model))
         prefill_call = (
             jax.jit(lora_prefill_fn(model, impl=adapter_impl))
             if adapters is not None
             else jax.jit(pf)
         )
         return cls(
-            prefill_call, decode, params,
-            cache_template, prompt_len, cache=cache,
+            prefill_call, decode, params, cache, prompt_len,
             chunk_prefill_call=chunk_prefill, speculator=speculator,
             verify_call=verify, **kwargs,
         )
@@ -585,21 +547,17 @@ class ServeSession:
         prefill_blob_or_path,
         decode_blob_or_path,
         params,
-        paged: Optional[bool] = None,
         **kwargs,
     ) -> "ServeSession":
-        """Artifact session: every engine shape is recovered from the
-        deserialized programs — slot count and cache bound from the
-        decode input avals, prompt window from the prefill's.
-
-        A PAGED decode artifact (exported with
-        ``export_serving_decoder(..., paged=True)``) is auto-detected
-        by its extra addressing inputs; page size, pool size, per-slot
-        page span, and int8 quantization are all recovered from the
+        """Artifact session over the pair ``export_serving_decoder``
+        writes: every engine shape is recovered from the deserialized
+        programs — slot count from the decode input avals, prompt
+        window and sequence bound from the prefill's; page size, pool
+        size, per-slot page span and int8 quantization from the
         pool/page-table avals, so the paged-KV contract round-trips
-        through StableHLO with no side-channel metadata. ``paged``
-        (optional) asserts the expectation — a mismatch raises instead
-        of serving the wrong layout."""
+        through StableHLO with no side-channel metadata. A decode
+        artifact of another contract (the dense pair ``export_decoder``
+        writes for offline generation) raises."""
         from tpudl.export.export import load_exported_obj
 
         pre = load_exported_obj(prefill_blob_or_path)
@@ -607,11 +565,13 @@ class ServeSession:
         (pre_args, _) = jax.tree.unflatten(pre.in_tree, pre.in_avals)
         (dec_args, _) = jax.tree.unflatten(dec.in_tree, dec.in_avals)
         _, ids_aval, _ = pre_args
-        is_paged = len(dec_args) == 7
-        if paged is not None and bool(paged) != is_paged:
+        if len(dec_args) != 7:
             raise ValueError(
-                f"decode artifact is {'paged' if is_paged else 'dense'} "
-                f"but paged={paged} was requested"
+                f"decode artifact takes {len(dec_args)} arguments, not "
+                f"the 7 of the paged decode contract (params, pools, "
+                f"token, position, page_table, start, lens) — a session "
+                f"serves from the paged pool; export with "
+                f"tpudl.export.decode.export_serving_decoder"
             )
         if ids_aval.shape[0] != 1:
             raise ValueError(
@@ -620,61 +580,43 @@ class ServeSession:
                 f"export with tpudl.export.decode.export_serving_decoder"
             )
         prompt_len = int(ids_aval.shape[1])
-        cache = None
-        if is_paged:
-            from tpudl.serve.cache import PagedKVCache
-
-            _, cache_template, token_aval, _, table_aval, _, _ = dec_args
-            pool = _find_pool(cache_template)
-            if pool is None:
-                raise ValueError(
-                    "paged decode artifact carries no page-pool cache "
-                    "(no pages_<name> leaf in its cache avals)"
-                )
-            # The model's compiled sequence bound lives in the PREFILL
-            # artifact's dense row-cache outputs ([1, max_seq_len]
-            # validity rows): when page_size does not divide it, the
-            # page span rounds past the model's position space and the
-            # cache must clamp admission exactly like the live path.
-            _, pre_cache = jax.tree.unflatten(pre.out_tree, pre.out_avals)
-            from tpudl.serve.cache import _is_valid_leaf
-
-            model_bound = next(
-                (
-                    int(leaf.shape[1])
-                    for leaf in jax.tree.leaves(pre_cache)
-                    if _is_valid_leaf(leaf)
-                ),
-                None,
+        _, pools, token_aval, _, table_aval, _, _ = dec_args
+        pool = _find_pool(pools)
+        if pool is None:
+            raise ValueError(
+                "paged decode artifact carries no page-pool cache "
+                "(no pages_<name> leaf in its cache avals)"
             )
-            pages = next(
-                v for k, v in pool.items() if k.startswith("pages_")
-            )
-            cache = PagedKVCache.from_pool_template(
-                cache_template,
-                num_slots=int(token_aval.shape[0]),
-                pages_per_slot=int(table_aval.shape[1]),
-                page_size=int(pages.shape[1]),
-                quantized=any(k.startswith("scale_") for k in pool),
-                num_pages=int(pages.shape[0]),
-                model_seq_len=model_bound,
-            )
-        else:
-            _, cache_template, token_aval, _ = dec_args
+        # The model's compiled sequence bound lives in the PREFILL
+        # artifact's dense row-cache outputs ([1, max_seq_len]
+        # validity rows): when page_size does not divide it, the
+        # page span rounds past the model's position space and the
+        # cache must clamp admission exactly like the live path.
+        _, pre_cache = jax.tree.unflatten(pre.out_tree, pre.out_avals)
+        model_bound = next(
+            (
+                int(leaf.shape[1])
+                for leaf in jax.tree.leaves(pre_cache)
+                if _is_valid_leaf(leaf)
+            ),
+            None,
+        )
+        pages = next(v for k, v in pool.items() if k.startswith("pages_"))
+        cache = PagedKVCache.from_pool_template(
+            pools,
+            num_slots=int(token_aval.shape[0]),
+            pages_per_slot=int(table_aval.shape[1]),
+            page_size=int(pages.shape[1]),
+            quantized=any(k.startswith("scale_") for k in pool),
+            num_pages=int(pages.shape[0]),
+            model_seq_len=model_bound,
+        )
         # An artifact's call donates nothing by itself: the pool rule
         # (PagedKVCache) is applied where the artifact is loaded.
-        decode = (
-            jax.jit(dec.call, donate_argnums=(1,)) if is_paged else dec.call
+        return cls(
+            pre.call, jax.jit(dec.call, donate_argnums=(1,)), params,
+            cache, prompt_len, **kwargs,
         )
-        session = cls(
-            pre.call, decode, params, cache_template, prompt_len,
-            cache=cache, **kwargs,
-        )
-        if session.num_slots != int(token_aval.shape[0]):
-            raise ValueError(
-                "decode artifact's cache and token batch dims disagree"
-            )
-        return session
 
     # -- introspection -------------------------------------------------
 
@@ -879,7 +821,7 @@ def assert_serving_parity(
     to and including eos; generate pads with eos after).
 
     ``atol=None`` (exact mode) demands token-for-token equality — the
-    f32 dense/paged contract. ``atol`` set is the QUANTIZED-cache
+    f32 contract. ``atol`` set is the QUANTIZED-cache
     contract ("parity at tolerance"): an int8 KV cache perturbs logits
     by a bounded dequantization error, so greedy argmax may flip — but
     ONLY at a genuine near-tie. The check walks the tokens and, at the

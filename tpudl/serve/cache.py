@@ -1,30 +1,14 @@
-"""KV-slot manager: the static-shape cache pytree behind the engine.
+"""The serving KV cache: per-layer page pools behind the engine.
 
-The engine's decode program is compiled ONCE for a fixed-slot cache
-(``[num_slots, max_seq_len, ...]`` per layer, the shape
-tpudl.models.llama.LlamaAttention builds in decode mode). Continuous
-batching never reshapes it — requests come and go by mutating WHICH
-rows mean something:
-
-- ``insert(row_cache, slot)`` scatters a batch-1 prefill's cache row
-  into an occupied batch (k/v/valid rows replaced wholesale, so the
-  slot's previous tenant vanishes atomically);
-- ``free(slot)`` zeroes the slot's validity row (its k/v bytes remain
-  but are unreachable — the attention mask is ``slot-order causal AND
-  valid``, the contract that makes a stale row harmless);
-- ``reset()`` returns the whole pytree to zeros, restoring the full
-  write horizon (the engine's rollover when the shared write index
-  nears ``max_seq_len``).
-
-Why insertion into an OCCUPIED cache is sound: LlamaAttention masks by
-slot write-order and validity, never by position (positions only drive
-RoPE phases, and those are baked into the cached keys at prefill). A
-new request's prompt lives at slots ``[0, prompt_len)`` — always below
-the shared write index — with everything above invalid, so the next
-decode query sees exactly its own prompt and nothing of the previous
-tenant. Neighbor rows are untouched: every per-row op in the model is
-batch-independent, so a refill is bit-invisible to the other slots
-(asserted by tests/test_serve.py).
+The engine's decode program is compiled ONCE for fixed-shape pools
+(``[num_pages, page_size, ...]`` per layer). Continuous batching never
+reshapes them — requests come and go by mutating WHICH pages a slot's
+host-side page table row maps (``PagedKVCache``): a seat scatters a
+batch-1 prefill's dense cache rows (the shape
+tpudl.models.llama.LlamaAttention builds in decode mode) into the
+slot's pages, a free points the row back at the trash page. Every
+per-row op in the model is batch-independent, so a refill is
+bit-invisible to the other slots (asserted by tests/test_serve.py).
 """
 
 from __future__ import annotations
@@ -45,156 +29,6 @@ from tpudl.obs import registry
 def _is_valid_leaf(leaf) -> bool:
     """The per-slot validity buffer: [num_slots, max_seq_len] bool."""
     return leaf.ndim == 2 and leaf.dtype == jnp.bool_
-
-
-@jax.jit
-def _insert_row(cache, row_cache, slot):
-    """Scatter a batch-1 cache row into ``slot`` of the batch cache.
-
-    Scalar leaves (the shared write index) keep the BATCH cache's value
-    — the row cache's index is its own prompt length and must not
-    rewind the live batch. ``slot`` is traced, so one compiled program
-    serves every slot.
-    """
-
-    def one(c, r):
-        if c.ndim == 0:
-            return c
-        return jax.lax.dynamic_update_slice(
-            c, r.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1)
-        )
-
-    return jax.tree.map(one, cache, row_cache)
-
-
-@jax.jit
-def _free_slot(cache, slot):
-    """Invalidate one slot: its validity row goes all-False. k/v bytes
-    stay (masked — see module docstring); scalar index leaves stay."""
-
-    def one(c):
-        if _is_valid_leaf(c):
-            row = jnp.zeros((1, c.shape[1]), c.dtype)
-            return jax.lax.dynamic_update_slice(c, row, (slot, 0))
-        return c
-
-    return jax.tree.map(one, cache)
-
-
-class SlotCache:
-    """Owns the engine's cache pytree and the slot bookkeeping on it.
-
-    ``paged = False``: this is the dense fixed-slot layout; see
-    ``PagedKVCache`` below for the paged + quantized successor.
-
-    ``template`` is a cache pytree of arrays or ShapeDtypeStructs with
-    leading dim ``num_slots`` (from ``jax.eval_shape`` of the prefill
-    contract at the slot-batched shape, or from a deserialized decode
-    artifact's input avals). The concrete cache starts zeroed —
-    all-invalid, which decode tolerates (an all-masked row softmaxes to
-    uniform weights over finite mask values; its output is discarded).
-    """
-
-    #: Marks the dense engine path (Engine branches on this).
-    paged = False
-
-    def __init__(self, template: Any):
-        self.cache = jax.tree.map(
-            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), template
-        )
-        valid_leaves = [
-            leaf for leaf in jax.tree.leaves(self.cache) if _is_valid_leaf(leaf)
-        ]
-        if not valid_leaves:
-            raise ValueError(
-                "cache template has no [num_slots, max_seq_len] bool "
-                "validity leaf — not a tpudl decode cache (expected the "
-                "pytree prefill_fn returns)"
-            )
-        self.num_slots = int(valid_leaves[0].shape[0])
-        self.max_seq_len = int(valid_leaves[0].shape[1])
-        self._write_index = 0
-
-    # -- slot mutation -------------------------------------------------
-
-    def insert(self, row_cache: Any, slot: int) -> None:
-        if not 0 <= slot < self.num_slots:
-            raise IndexError(f"slot {slot} out of range [0, {self.num_slots})")
-        self.cache = _insert_row(self.cache, row_cache, jnp.int32(slot))
-
-    def free(self, slot: int) -> None:
-        if not 0 <= slot < self.num_slots:
-            raise IndexError(f"slot {slot} out of range [0, {self.num_slots})")
-        self.cache = _free_slot(self.cache, jnp.int32(slot))
-
-    def reset(self) -> None:
-        """All slots empty, write index 0: the full horizon is back."""
-        self.cache = jax.tree.map(
-            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), self.cache
-        )
-        self._write_index = 0
-
-    # -- the shared write index ----------------------------------------
-
-    @property
-    def write_index(self) -> int:
-        """The decode programs' next write slot (shared across rows —
-        every decode step writes all rows at this index and advances it
-        by one; see LlamaAttention's scalar cache index).
-
-        This is a HOST MIRROR of the device-side scalar, maintained by
-        ``reset``/``set_write_index``/``advance_write_index`` — the
-        value is fully host-determined, so the engine's per-step horizon
-        checks never pay a device readback (a blocking host sync per
-        decode step). It is correct as long
-        as every decode dispatch on ``self.cache`` is followed by one
-        ``advance_write_index()``, which Engine._decode_step does."""
-        return self._write_index
-
-    def set_write_index(self, index: int) -> None:
-        """Pin every layer's scalar write index (after filling a fresh
-        cache from batch-1 prefills, whose own indices were discarded by
-        ``insert``)."""
-        self.cache = jax.tree.map(
-            lambda leaf: jnp.asarray(index, leaf.dtype)
-            if leaf.ndim == 0
-            else leaf,
-            self.cache,
-        )
-        self._write_index = int(index)
-
-    def advance_write_index(self, steps: int = 1) -> None:
-        """Advance the host mirror after ``steps`` decode dispatches
-        (the device-side scalar advanced itself inside the program)."""
-        self._write_index += steps
-
-    @property
-    def remaining_horizon(self) -> int:
-        """Decode steps left before the cache is full. The engine
-        admits a request into a slot only if its max_new_tokens fits —
-        running past the horizon would silently CLAMP cache writes onto
-        the last slot (corrupted tokens, no error)."""
-        return self.max_seq_len - self.write_index
-
-    # -- accounting ----------------------------------------------------
-
-    @property
-    def nbytes(self) -> int:
-        """Resident bytes of the cache pytree (the number behind the
-        ``serve_cache_bytes`` gauge)."""
-        return int(
-            sum(leaf.nbytes for leaf in jax.tree.leaves(self.cache))
-        )
-
-    def valid_counts(self):
-        """Per-slot count of valid (attendable) cache positions — one
-        host readback of a [num_slots] reduction."""
-        for leaf in jax.tree.leaves(self.cache):
-            if _is_valid_leaf(leaf):
-                import numpy as np
-
-                return np.asarray(jnp.sum(leaf, axis=-1))
-        raise AssertionError("unreachable: ctor checked a valid leaf")
 
 
 # ---------------------------------------------------------------------------
@@ -568,7 +402,7 @@ class RadixPrefixTree:
 
 
 class PagedKVCache:
-    """Paged + optionally int8-quantized successor to ``SlotCache``.
+    """The serving KV cache: paged, optionally int8-quantized.
 
     KV lives in per-layer page pools ``[num_pages, page_size, Hkv, D]``
     (int8 with ``[num_pages, page_size, Hkv]`` f32 dequant scales when
@@ -581,8 +415,7 @@ class PagedKVCache:
     consequences the engine builds on:
 
     - **No shared write index**: each slot carries its own length, so
-      the dense cache's horizon rollover (reset-the-world when the
-      shared index nears ``max_seq_len``) does not exist here.
+      a long generation in one slot never costs another its cache.
     - **Reservation-based admission**: ``seat`` reserves every page a
       request could need (``ceil((prompt_len + max_new_tokens) /
       page_size)``) up front, so a seated request can NEVER strand
@@ -590,8 +423,7 @@ class PagedKVCache:
       predicate.
     - **Physical page 0 is the trash page**: freed/idle slots' table
       rows point at it, so their ride-along decode writes land where no
-      live slot ever reads — the paged analog of "stale rows are
-      masked".
+      live slot ever reads.
 
     ``template`` is the SAME dense cache template ``ServeSession``
     already derives (eval_shape of the prefill contract); the pools are
@@ -622,9 +454,6 @@ class PagedKVCache:
     leaf-first under pressure), and ``gather_prefix_rows`` turns a
     cached prefix back into dense rows for the chunked suffix prefill.
     """
-
-    #: Marks the paged engine path (Engine branches on this).
-    paged = True
 
     def __init__(
         self,
@@ -670,8 +499,8 @@ class PagedKVCache:
             )
         self.pages_per_slot = -(-cap // self.page_size)
         if num_pages is None:
-            # Capacity parity with the dense cache by default (+1 trash
-            # page); overcommit or shrink via explicit num_pages.
+            # Capacity parity by default: every slot can hold its whole
+            # span (+1 trash page); overcommit or shrink via num_pages.
             num_pages = self.num_slots * self.pages_per_slot + 1
         if num_pages < 2 + self.pages_per_slot - 1:
             raise ValueError(
@@ -1553,8 +1382,7 @@ class PagedKVCache:
         """Pin one slot's logical length — the speculative ROLLBACK
         primitive: a rejected proposal tail simply never advances lens,
         so its page writes are masked garbage the next window
-        overwrites. Per-slot bookkeeping only (no shared write index
-        since the paged layout landed)."""
+        overwrites. Per-slot bookkeeping only."""
         self.tokens_live += int(length) - int(self.lens[slot])
         self.lens[slot] = int(length)
 

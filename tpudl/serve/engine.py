@@ -2,10 +2,11 @@
 
 The whole engine is host orchestration around exactly two XLA
 executables — the batch-1 prefill and the slot-batched single-token
-decode that tpudl.models.generate defines and tpudl.export.decode
+paged decode that tpudl.models.generate defines and tpudl.export.decode
 serializes (``(params, ids, mask) -> (logits, cache)`` and
-``(params, cache, token, position) -> (logits, cache)``). Requests are
-multiplexed onto them through a fixed-slot cache:
+``(params, pools, token, position, page_table, start, lens) ->
+(logits, pools)``). Requests are multiplexed onto them through a
+fixed-slot cache:
 
     queue ──pop──▶ prefill(batch=1) ──insert──▶ slot i of the cache
                                                     │
@@ -20,27 +21,17 @@ trick: a ragged batch never waits for its longest row
 engine into the run-to-completion static-batch baseline the load
 benchmark compares against).
 
-Why mid-stream insertion is correct: see tpudl.serve.cache (slot-order
-+ validity masking makes the new row see only its own prompt, and every
-per-row op is batch-independent, so neighbors are bit-unaffected).
+Why mid-stream insertion is correct: the new row's pages hold only its
+own prompt, and every per-row op is batch-independent, so neighbors are
+bit-unaffected (see tpudl.serve.cache).
 
-The one resource all slots share — in DENSE mode — is the cache WRITE
-INDEX: the compiled decode writes every row at the same slot and
-advances it by one per step (LlamaAttention's scalar index), so the
-horizon ``max_seq_len - write_index`` shrinks monotonically for
-everyone. The engine therefore (a) only seats a request whose
-max_new_tokens fits the remaining horizon, and (b) when the batch
-drains with work still queued, RESETS the cache to recover the full
-horizon (a "rollover").
-
-In PAGED mode (``cache.paged`` — a tpudl.serve.cache.PagedKVCache over
-tpudl.models.paged pools) there is no shared index: each slot carries
-its own length and decode writes through a host-owned page table, so
-rollovers cease to exist and admission is ``fits_tokens`` (are enough
-free pages left to reserve the request's worst case up front). The
-decode contract grows three small traced inputs
-(``paged_decode_fn``: page table + start + lens); everything else —
-mid-stream seating, selection, sampling, telemetry — is identical.
+The cache is a tpudl.serve.cache.PagedKVCache over tpudl.models.paged
+pools: each slot carries its own length and decode writes through a
+host-owned page table (three small traced inputs of the decode
+contract, ``paged_decode_fn``: page table + start + lens), so slots
+share no write position and a finished slot's pages recycle piecewise.
+Admission is ``fits_tokens``: are enough free pages left to reserve the
+request's worst case up front.
 
 Two hooks the multi-replica router (tpudl.serve.router) builds on:
 ``on_token`` (called per (request_id, token) as it is selected — the
@@ -73,7 +64,7 @@ from tpudl.serve.api import Request, Result
 from tpudl.serve.cache import (
     MigrationCompatError,
     MigrationCorruptError,
-    SlotCache,
+    PagedKVCache,
 )
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue, _Entry
 
@@ -271,7 +262,7 @@ class Engine:
         prefill_call: Callable,
         decode_call: Callable,
         params: Any,
-        cache: SlotCache,
+        cache: PagedKVCache,
         queue: AdmissionQueue,
         prompt_len: int,
         clock: Callable[[], float] = time.monotonic,
@@ -281,6 +272,11 @@ class Engine:
         verify_call: Optional[Callable] = None,
         adapter_pool=None,
     ):
+        if not isinstance(cache, PagedKVCache):
+            raise TypeError(
+                f"Engine serves from a PagedKVCache, got "
+                f"{type(cache).__name__}"
+            )
         if prompt_len < 1 or prompt_len >= cache.max_seq_len:
             raise ValueError(
                 f"prompt_len must be in [1, max_seq_len) = "
@@ -296,16 +292,13 @@ class Engine:
         self.max_seq_len = cache.max_seq_len
         self.clock = clock
         self.continuous = continuous
-        self.paged = bool(getattr(cache, "paged", False))
         # Prefix sharing (radix mode, tpudl.serve.cache): seat walks
         # the radix tree, maps matched full pages for free, and — with
         # the chunked prefill program — prefills only the unshared
         # suffix (the TTFT lever for shared system prompts). Without
         # the chunk program (artifact sessions) sharing still
         # deduplicates pages; only the compute skip is lost.
-        self.prefix_share = self.paged and bool(
-            getattr(cache, "prefix_share", False)
-        )
+        self.prefix_share = bool(cache.prefix_share)
         self.chunk_prefill_call = chunk_prefill_call
         # Multi-tenant LoRA serving (tpudl.serve.lora.AdapterPool):
         # when present, the prefill/decode programs are the lora_*
@@ -314,12 +307,6 @@ class Engine:
         # tenant's adapter pages for the slot's lifetime.
         self.adapter_pool = adapter_pool
         if adapter_pool is not None:
-            if not self.paged:
-                raise ValueError(
-                    "multi-tenant adapters require a paged cache (the "
-                    "adapter pool rides the same host-owned-table "
-                    "contract)"
-                )
             if self.prefix_share:
                 raise ValueError(
                     "adapter serving cannot share KV prefixes across "
@@ -337,11 +324,6 @@ class Engine:
         self.speculator = speculator
         self.verify_call = verify_call
         if speculator is not None:
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding requires a paged cache "
-                    "(per-slot lens is what makes rollback free)"
-                )
             if verify_call is None:
                 raise ValueError(
                     "speculator needs verify_call (the k-token paged "
@@ -382,7 +364,6 @@ class Engine:
         # comparison uses (wall time rides on them 1:1 at fixed slots).
         self.num_decode_steps = 0
         self.num_prefills = 0
-        self.num_rollovers = 0
         # SLO hook (attach_slo): while any subscribed objective burns,
         # admission sheds the queue instead of seating doomed work.
         self._slo = None
@@ -393,7 +374,7 @@ class Engine:
         # Live health: slots/queue state on /healthz while this engine
         # is the process's serving engine (latest instance wins). The
         # source holds a WEAK reference — a registered bound method
-        # would pin the engine and its whole SlotCache KV pytree
+        # would pin the engine and its whole KV pool
         # (potentially GBs) for the process lifetime, and keep serving
         # a dead engine's state as live readiness data.
         import weakref
@@ -432,20 +413,16 @@ class Engine:
             "prefills": self.num_prefills,
             "max_seq_len": self.max_seq_len,
             "slo_burning": sorted(self._slo_burning),
-            "paged": self.paged,
+            "free_pages": self.cache.free_pages,
+            "page_size": self.cache.page_size,
+            "kv_quantized": self.cache.quantized,
         }
-        if self.paged:
-            out["free_pages"] = self.cache.free_pages
-            out["page_size"] = self.cache.page_size
-            out["kv_quantized"] = self.cache.quantized
-            if self.prefix_share:
-                out["prefix_cache"] = self.cache.radix.stats()
-            if self.speculator is not None:
-                out["spec_k"] = self.speculator.k
-            if self.adapter_pool is not None:
-                out["adapters"] = self.adapter_pool.stats()
-        else:
-            out["write_index"] = self.cache.write_index
+        if self.prefix_share:
+            out["prefix_cache"] = self.cache.radix.stats()
+        if self.speculator is not None:
+            out["spec_k"] = self.speculator.k
+        if self.adapter_pool is not None:
+            out["adapters"] = self.adapter_pool.stats()
         return out
 
     def attach_slo(self, monitor) -> None:
@@ -629,8 +606,8 @@ class Engine:
                  tenant_pinned: bool = False, prefix_hit: int = 0,
                  adapter_reloads: int = 0,
                  ) -> None:
-        """Shared seat tail: cache insertion (dense scatter, paged
-        reservation+scatter, or radix-shared left-aligned seat),
+        """Shared seat tail: cache insertion (page reservation+scatter,
+        or radix-shared left-aligned seat),
         latency accounting, draft-cache seating, adapter binding, slot
         activation."""
         req = entry.request
@@ -664,13 +641,11 @@ class Engine:
                         if row_offset is None else row_offset
                     ),
                 )
-            elif self.paged:
+            else:
                 self.cache.seat(
                     row_cache, slot, self.prompt_len - ids_len,
                     self.prompt_len, self.prompt_len + req.max_new_tokens,
                 )
-            else:
-                self.cache.insert(row_cache, slot)
         except BaseException:
             # A failed seat must not strand the tenant pin: the slot
             # was never bound, so free_slot will never run for it —
@@ -680,10 +655,7 @@ class Engine:
                 self.adapter_pool.release(tenant)
             raise
         if span is not None:
-            span.end(
-                self.clock(),
-                pages=self.cache.pages_of(slot) if self.paged else 0,
-            )
+            span.end(self.clock(), pages=self.cache.pages_of(slot))
             self._seats += 1
         if self.adapter_pool is not None:
             # The seat pin transfers to the slot; free_slot drops it.
@@ -727,18 +699,6 @@ class Engine:
                 self._record_shed(self.queue.drain_all(), "shed_slo")
         if not self.continuous and self._active():
             return
-        if (
-            not self.paged
-            and not self._active()
-            and (len(self.queue) or self.prefill_inbox)
-        ):
-            # Batch drained with work queued: recover the full write
-            # horizon before seating the next wave (dense only — paged
-            # slots recycle piecewise, there is no horizon to recover).
-            if self.cache.write_index > self.prompt_len:
-                self.cache.reset()
-                self.num_rollovers += 1
-                registry().counter("serve_rollovers").inc()
         # Migrated-in requests seat FIRST: they are mid-stream — their
         # prefill AND some decode are already paid, and every queued
         # token of delay widens the client's visible stall (the
@@ -812,34 +772,22 @@ class Engine:
             if entry is None:
                 break
             self._seat(entry, slot)
-        if (
-            not self.paged
-            and self._active()
-            and self.cache.write_index < self.prompt_len
-        ):
-            # Fresh cache just seated its first wave: the batch-1 row
-            # caches carried their own write indices (discarded by
-            # insert); pin the shared index past the prompt region.
-            self.cache.set_write_index(self.prompt_len)
         self._publish_occupancy()
 
     def _publish_occupancy(self) -> None:
-        """Slots in use and, paged, the cache's reserved-against-live
-        counters and the path its decode program took, as gauges."""
+        """Slots in use, the cache's reserved-against-live counters and
+        the path its decode program took, as gauges."""
         reg = registry()
         reg.gauge("serve_slots_busy").set(
             sum(s is not None for s in self._slots)
         )
-        if self.paged:
-            reg.gauge("serve_kv_pages_reserved").set(
-                self.cache.pages_reserved
-            )
-            reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
-            # Layers of the decode program whose attention reads the
-            # pool in place (0 until its first dispatch traced it).
-            reg.gauge("serve_paged_attention_in_place").set(
-                self.cache.in_place_layers
-            )
+        reg.gauge("serve_kv_pages_reserved").set(self.cache.pages_reserved)
+        reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
+        # Layers of the decode program whose attention reads the pool
+        # in place (0 until its first dispatch traced it).
+        reg.gauge("serve_paged_attention_in_place").set(
+            self.cache.in_place_layers
+        )
 
     def _paged_attrs(self, pages_live: int) -> dict:
         """What a ``decode_step`` span says of the paged cache: the
@@ -854,10 +802,9 @@ class Engine:
                 "pages_live": pages_live}
 
     def _fits(self, request) -> bool:
-        """Can this request be seated RIGHT NOW? Dense: its worst case
-        fits the remaining shared write horizon. Paged: its worst case
-        fits the per-slot logical bound and enough pool pages are free
-        to reserve it up front (so it can never strand mid-decode).
+        """Can this request be seated RIGHT NOW? Its worst case fits
+        the per-slot logical bound and enough pool pages are free to
+        reserve it up front (so it can never strand mid-decode).
         Radix mode counts only the UNSHARED pages (matched prefix
         pages seat for free — sharing multiplies admission capacity on
         top of int8's byte multiplier), and left-aligned seating
@@ -886,16 +833,13 @@ class Engine:
             return need <= self.max_seq_len and self.cache.fits_request(
                 request.input_ids, need
             )
-        if self.paged:
-            need = self.prompt_len + request.max_new_tokens
-            return need <= self.max_seq_len and self.cache.fits_tokens(need)
-        base = max(self.cache.write_index, self.prompt_len)
-        return base + request.max_new_tokens <= self.max_seq_len
+        need = self.prompt_len + request.max_new_tokens
+        return need <= self.max_seq_len and self.cache.fits_tokens(need)
 
     def _fits_ever(self, request) -> bool:
         """Could this request be seated in an EMPTY cache? False means
         waiting can never help (the worst case exceeds the compiled
-        seq-len bound, or the paged pool is too small outright)."""
+        seq-len bound, or the pool is too small outright)."""
         need = (
             len(request.input_ids) + request.max_new_tokens
             if self.prefix_share
@@ -915,12 +859,10 @@ class Engine:
                 > self.speculator.cache.num_pages - 1
             ):
                 return False
-        if self.paged:
-            # Page 0 is the trash page; an empty pool frees the rest
-            # (radix mode: refcount-0 cached pages evict on demand, so
-            # the whole pool minus the trash page is reachable).
-            return self.cache.pages_needed(need) <= self.cache.num_pages - 1
-        return True
+        # Page 0 is the trash page; an empty pool frees the rest
+        # (radix mode: refcount-0 cached pages evict on demand, so
+        # the whole pool minus the trash page is reachable).
+        return self.cache.pages_needed(need) <= self.cache.num_pages - 1
 
     # -- page-granular migration ---------------------------------------
 
@@ -935,18 +877,14 @@ class Engine:
         (``Speculator.export_slot``), so draft and target cross the
         wire in lens-lockstep and the first post-failover propose
         window runs as if the request never moved. Returns ``None``
-        when the request is not seated here or the cache is dense
-        (migration is a paged-substrate feature: pages are
-        position-independent, dense rows are not) — the caller's cue
-        to fall back to a from-scratch resubmission.
+        when the request is not seated here — the caller's cue to fall
+        back to a from-scratch resubmission.
 
         ``skip_prefix_tokens`` omits that many leading logical rows
         from the payload (the router probed AND LEASED them in the
         target's radix tree — prefix by reference, not by bytes).
         Commit-or-invisible: the slot is freed only after the payload
         exists in full."""
-        if not self.paged:
-            return None
         slot = next(
             (
                 i
@@ -1071,11 +1009,6 @@ class Engine:
         from tpudl.serve.cache import parse_migration
 
         try:
-            if not self.paged:
-                raise ValueError(
-                    "migration requires a paged cache (dense rows are "
-                    "not position-independent)"
-                )
             meta = (
                 payload
                 if isinstance(payload, dict) and "_arrays" in payload
@@ -1096,8 +1029,7 @@ class Engine:
                 submitted_at=meta["submitted_at"],
             )
         except BaseException:
-            if self.paged:
-                self.cache.release_lease(lease[1] if lease else None)
+            self.cache.release_lease(lease[1] if lease else None)
             raise
         if entry.deadline is not None and self.clock() > entry.deadline:
             # The migration transfer ate the remaining budget: shed at
@@ -1195,7 +1127,7 @@ class Engine:
         count, so the terminal record bills the RIGHT tenant instead of
         ``_base`` (a corrupt transfer has no meta — those fields fall
         back to unknown)."""
-        if lease is not None and self.paged:
+        if lease is not None:
             self.cache.release_lease(lease[1])
         self.results[rid] = Result(
             request_id=rid, tokens=[],
@@ -1310,13 +1242,11 @@ class Engine:
         # Terminal durable-log record: slot occupancy x KV footprint,
         # computed BEFORE the free below releases the pages.
         active_s = max(0.0, s.t_last - s.t_seated)
-        kv_page_s = kv_byte_s = 0.0
-        if self.paged:
-            pages = -(-int(self.cache.lens[slot]) // self.cache.page_size)
-            kv_page_s = pages * active_s
-            kv_byte_s = kv_page_s * (
-                self.cache.nbytes / max(1, self.cache.num_pages)
-            )
+        pages = -(-int(self.cache.lens[slot]) // self.cache.page_size)
+        kv_page_s = pages * active_s
+        kv_byte_s = kv_page_s * (
+            self.cache.nbytes / max(1, self.cache.num_pages)
+        )
         # Sample capture (schema v2, opt-in): token ids ride ONLY on
         # completed results from this site — sheds/failures never carry
         # user content into the durable log.
@@ -1350,11 +1280,7 @@ class Engine:
     def _decode_step(self) -> None:
         """One slot-batched decode dispatch + selection + host readback;
         idle slots ride along with zeros and their output is discarded
-        (paged: idle rows write into the trash page)."""
-        assert self.paged or self.cache.write_index < self.max_seq_len, (
-            "decode past the cache horizon would silently clamp writes "
-            "(admission fit checks should make this unreachable)"
-        )
+        (idle rows write into the trash page)."""
         b = self.num_slots
         tokens = np.zeros(b, np.int32)
         positions = np.zeros(b, np.int32)
@@ -1375,23 +1301,16 @@ class Engine:
         if rec is not None:
             span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
             dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
-        pages_live = 0
-        if self.paged:
-            # Tenant adapters ride the paged contract as three more
-            # traced inputs (adapters imply a paged cache).
-            adapters = (
-                self.adapter_pool.dispatch_args()
-                if self.adapter_pool is not None else ()
-            )
-            if span is not None:
-                pages_live = self.cache.pages_live()
-            logits = self.cache.decode(
-                self.decode_call, self.params, tokens, positions, *adapters
-            )
-        else:
-            logits, self.cache.cache = self.decode_call(
-                self.params, self.cache.cache, tokens, positions
-            )
+        # Tenant adapters ride the paged contract as three more
+        # traced inputs.
+        adapters = (
+            self.adapter_pool.dispatch_args()
+            if self.adapter_pool is not None else ()
+        )
+        pages_live = self.cache.pages_live() if span is not None else 0
+        logits = self.cache.decode(
+            self.decode_call, self.params, tokens, positions, *adapters
+        )
         if temps.any():
             sel = _select_tokens(logits, temps, seeds, steps)
         else:
@@ -1410,36 +1329,30 @@ class Engine:
         # implicit transfers — intent made visible is the contract.
         # (A model with routed experts: its tokens per held expert ride
         # the same transfer.)
-        counts = self.cache.program_extras if self.paged else ()
-        sel, counts = jax.device_get((sel, counts))
+        sel, counts = jax.device_get((sel, self.cache.program_extras))
         if readback is not None:
             readback.end(self.clock())
         load = record_expert_load(counts[0]) if counts else {}
-        if self.paged:
-            # Each ACTIVE slot's logical length advanced by one (idle
-            # slots stay pinned on the trash page).
-            self.cache.advance(
-                [i for i, s in enumerate(self._slots) if s is not None]
-            )
-        else:
-            self.cache.advance_write_index()  # host mirror of in-graph +1
+        # Each ACTIVE slot's logical length advanced by one (idle
+        # slots stay pinned on the trash page).
+        self.cache.advance(
+            [i for i, s in enumerate(self._slots) if s is not None]
+        )
         now = self.clock()
         emit = None
         if span is not None:
             # "rids" names every request this decode chunk advanced —
             # the per-request trace's decode leg (report.py --request
-            # selects the chunks containing its id). Paged: the pages
-            # the seated slots hold and the positions the step read
-            # (lens already counts the token just written); whether
-            # attention read the pool in place, and the pages it then
-            # visits (``_paged_attrs``).
+            # selects the chunks containing its id). The pages the
+            # seated slots hold and the positions the step read (lens
+            # already counts the token just written); whether attention
+            # read the pool in place, and the pages it then visits
+            # (``_paged_attrs``).
             busy = int(sum(s is not None for s in self._slots))
-            attrs = {"busy": busy,
-                     "rids": [s.request.request_id
-                              for s in self._slots if s is not None]}
-            if self.paged:
-                attrs.update(self._paged_attrs(pages_live))
-            span.end(now, **attrs, **load)
+            span.end(now, busy=busy,
+                     rids=[s.request.request_id
+                           for s in self._slots if s is not None],
+                     **self._paged_attrs(pages_live), **load)
             emit = rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
         registry().counter("serve_decode_steps").inc()

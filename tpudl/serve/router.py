@@ -210,7 +210,7 @@ class Replica:
         outstanding leaves this replica. Waiting work (inbox, admission
         queue, disaggregation inbox) returns as Requests — nothing is
         seated, nothing to export; seated slots export page-granular
-        payloads (skipping dense/speculating engines, which the caller
+        payloads (skipping speculating engines, which the caller
         resubmits instead); already-queued migrate-inbox payloads
         forward as-is, their local leases released."""
         engine = self.session.engine
@@ -984,7 +984,7 @@ class Router:
         request: Optional[Request] = None,
     ) -> Optional[Replica]:
         """Least-loaded ready survivor whose cache can SEAT the
-        payload (paged, same KV quantization) — chosen BEFORE the
+        payload (same KV quantization) — chosen BEFORE the
         export so the reference-prefix probe pins pages on the replica
         the payload will actually reach. ``tentative`` carries the
         token load of payloads already directed at each survivor in
@@ -998,10 +998,7 @@ class Router:
                 if r.name != exclude
                 and self._ready.get(r.name)
                 and r.name not in self._draining
-                and getattr(r.session.engine.cache, "paged", False)
-                and bool(
-                    getattr(r.session.engine.cache, "quantized", False)
-                ) == quantized
+                and bool(r.session.engine.cache.quantized) == quantized
                 # Tenant requests only resume where the adapter can be
                 # re-pinned (install would refuse anyway; filtering
                 # here avoids exporting a payload no survivor seats).
@@ -1027,8 +1024,7 @@ class Router:
         leaves ``replica``. Seated decode state migrates (export ->
         crc-guarded payload -> survivor's migrate inbox, resuming
         mid-stream); waiting work and anything the replica could not
-        export (crashed/frozen thread, dense cache, speculating
-        engine) resubmits from scratch — counted against the
+        export (crashed/frozen thread, speculating engine) resubmits from scratch — counted against the
         per-request failover cap when ``count_resubmits`` (unplanned
         failover) and uncounted on planned drains. The caller already
         took the replica out of placement (unready or draining)."""
@@ -1140,8 +1136,8 @@ class Router:
                 and not returned
             ):
                 # Planned drain and the request never left the replica
-                # (seated but unexportable — dense cache, speculating
-                # engine — or the command went unanswered): leave it
+                # (seated but unexportable — speculating engine —
+                # or the command went unanswered): leave it
                 # assigned; the caller's wait loop delivers it in place
                 # rather than restarting mid-stream work.
                 continue
@@ -1566,8 +1562,8 @@ class Router:
         the surviving replicas (page-granular KV export, resumed
         mid-stream — zero re-prefill), making drain latency
         ~O(payload transfer) instead of O(longest generation); waiting
-        work resubmits. Work that cannot migrate (no survivors, dense
-        cache, speculating engine, a thread that stopped answering) is
+        work resubmits. Work that cannot migrate (no survivors,
+        speculating engine, a thread that stopped answering) is
         WAITED out exactly as before — a drain never drops in-flight
         work either way. ``drain=False`` stops the replica immediately
         and fails its outstanding work over to the survivors (the
