@@ -9,6 +9,12 @@ frequencies and softmax scale, the expert layer with every token sent to
 one expert, the shares of a layer adding up to the whole, the one-pool
 layer seated, freed and seated again with no pool copy, and each
 session option that is not wired refusing with its sentence.
+
+The pool's held shape (ISSUE 29, ``tpudl.models.paged.page_fold``): the
+rule as a table, and every path that touches the pool at a second tiny
+size whose row the rule FOLDS (64 wide on pages of 4: two positions a
+held row of 128 lanes), held to the same reference and to the pool
+held as declared.
 """
 
 import dataclasses
@@ -25,6 +31,13 @@ from tpudl.models.llama import (
     RopeScaling,
     _mla_absorbed,
     _mla_up_projected,
+)
+from tpudl.models.paged import (
+    PagedView,
+    page_fold,
+    paged_attend_mask,
+    paged_gather,
+    paged_write,
 )
 from tpudl.obs import registry
 from tpudl.obs import spans as obs_spans
@@ -50,16 +63,41 @@ CONFIG = {
 MAX_SEQ, WINDOW, PAGE, SLOTS = 64, 16, 4, 3
 LATENT = CONFIG["kv_lora_rank"] + CONFIG["qk_rope_head_dim"]
 SETTINGS = ref.settings(CONFIG)
+#: The same model with a row of 48 + 16 = 64: no whole number of lanes,
+#: and two of them are (CONFIG's 24 would need 16 on a page of 4), so
+#: the pool is held folded, ``[NP, 2, 128]``.
+FOLDED = dict(CONFIG, kv_lora_rank=48, qk_rope_head_dim=16)
+
+
+def _build(config):
+    key = ref.seed_key(2**31 + 26)
+    settings = ref.settings(config)
+    model = LlamaForCausalLM(model_config(config, MAX_SEQ, jnp.float32))
+    params = to_flax(ref.all_weights(key, settings, jnp.float32), settings)
+    return model, params, key
 
 
 @pytest.fixture(scope="module")
 def served():
     """(model, params, key): float32, the reference's weights laid over
     the program's tree."""
-    key = ref.seed_key(2**31 + 26)
-    model = LlamaForCausalLM(model_config(CONFIG, MAX_SEQ, jnp.float32))
-    params = to_flax(ref.all_weights(key, SETTINGS, jnp.float32), SETTINGS)
-    return model, params, key
+    return _build(CONFIG)
+
+
+@pytest.fixture(scope="module")
+def folded():
+    """``served`` at the size whose pool is held folded."""
+    return _build(FOLDED)
+
+
+@pytest.fixture(params=["declared", "folded"])
+def either(request):
+    """(model, params, key, config) of both sizes in turn: the pool
+    held as its layer declares it, and held folded."""
+    name, config = {
+        "declared": ("served", CONFIG), "folded": ("folded", FOLDED),
+    }[request.param]
+    return (*request.getfixturevalue(name), config)
 
 
 def _session(model, params, **kw):
@@ -79,7 +117,7 @@ def _requests(shared=0, seed=0):
     ]
 
 
-def _margins(key, reqs, got, dtype=jnp.float32):
+def _margins(key, reqs, got, config=CONFIG, dtype=jnp.float32):
     """The reference's margin at every served token, teacher-forced."""
     width = WINDOW + max(r.max_new_tokens for r in reqs)
     t_max = max(r.max_new_tokens for r in reqs)
@@ -95,7 +133,7 @@ def _margins(key, reqs, got, dtype=jnp.float32):
         chosen[row, :len(tokens)] = tokens
         valid[row, :len(tokens)] = True
     out = np.asarray(ref.margins(
-        key, CONFIG, dtype, jnp.asarray(ids), jnp.asarray(picks),
+        key, config, dtype, jnp.asarray(ids), jnp.asarray(picks),
         jnp.asarray(chosen)))
     return out[valid]
 
@@ -109,19 +147,19 @@ def _margins(key, reqs, got, dtype=jnp.float32):
     ({"kv_dtype": "int8"}, 0.05),
     ({"kv_dtype": "int8", "prefix_share": True}, 0.05),
 ], ids=["plain", "prefix_share", "int8_latents", "int8_prefix_share"])
-def test_served_tokens_are_the_references(served, options, limit):
+def test_served_tokens_are_the_references(either, options, limit):
     """Prefill, then decode through the paged latent pool, more
     requests than slots and every length different: each served token
     is the reference's best to within ``limit`` logits (float32
     rounding; a quantisation step of the cached rows for int8)."""
-    model, params, key = served
+    model, params, key, config = either
     copies = registry().counter("serve_kv_pool_copies").value
     sess = _session(model, params, **options)
     reqs = _requests(shared=8 if options.get("prefix_share") else 0)
     got = sess.serve(reqs)
     assert all(got[r.request_id].finish_reason == "length" for r in reqs)
     assert all(len(got[r.request_id].tokens) == r.max_new_tokens for r in reqs)
-    gaps = _margins(key, reqs, got)
+    gaps = _margins(key, reqs, got, config)
     assert gaps.max() <= limit, gaps.max()
     assert registry().counter("serve_kv_pool_copies").value == copies
     if options.get("prefix_share"):
@@ -156,30 +194,38 @@ def test_int8_weights_part_from_the_reference_more_than_float32(served):
     assert control.mean() > 10 * sound.mean() and control.mean() > 1e-5
 
 
-def test_the_pool_is_one_leaf_a_layer_with_no_head_axis(served):
-    model, params, _ = served
+def test_the_pool_is_one_leaf_a_layer_with_no_head_axis(either):
+    """One leaf a layer, held in the shape the rule gives its row (24
+    wide: as declared; 64 wide: two positions a held row), the same
+    bytes either way; an int8 pool keeps a row a position, its scales
+    beside it."""
+    model, params, _, config = either
+    width = config["kv_lora_rank"] + config["qk_rope_head_dim"]
+    fold = page_fold(PAGE, (width,), jnp.float32)
+    assert fold == {24: 1, 64: 2}[width]
     cache = _session(model, params).engine.cache
     layers = cache.cache["model"]
     assert sorted(layers) == ["layer_0", "layer_1", "layer_2"]
     for layer in layers.values():
         assert list(layer["attention"]) == ["pages_kv"]
         assert layer["attention"]["pages_kv"].shape == (
-            cache.num_pages, PAGE, LATENT)
-    pools = 3 * cache.num_pages * PAGE * LATENT * 4
+            cache.num_pages, PAGE // fold, fold * width)
+    pools = 3 * cache.num_pages * PAGE * width * 4
     host = (cache.page_table.nbytes + cache.start.nbytes + cache.lens.nbytes)
     assert cache.nbytes == pools + host
     quantized = _session(model, params, kv_dtype="int8").engine.cache
     pool = quantized.cache["model"]["layer_1"]["attention"]
     assert sorted(pool) == ["pages_kv", "scale_kv"]
     assert pool["pages_kv"].dtype == jnp.int8
+    assert pool["pages_kv"].shape == (quantized.num_pages, PAGE, width)
     assert pool["scale_kv"].shape == (quantized.num_pages, PAGE)
 
 
-def test_seat_free_and_seat_again_copy_no_pool(served):
+def test_seat_free_and_seat_again_copy_no_pool(either):
     """The donation rule holds for the one-pool layer: a pool tree kept
     from before a seat is dead after it, the counter stays where it
     was, and a freed slot's pages seat the next prompt."""
-    model, params, _ = served
+    model, params, _, _ = either
     sess = _session(model, params)
     cache, engine = sess.engine.cache, sess.engine
     copies = registry().counter("serve_kv_pool_copies").value
@@ -198,10 +244,10 @@ def test_seat_free_and_seat_again_copy_no_pool(served):
     assert registry().counter("serve_kv_pool_copies").value == copies
 
 
-def test_a_request_migrates_with_its_latent_rows(served):
+def test_a_request_migrates_with_its_latent_rows(either):
     """Export mid-stream, install on another engine: the continuation is
     the uninterrupted one and the target pays no prefill."""
-    model, params, _ = served
+    model, params, _, _ = either
     req = Request("m0", [3, 5, 7, 11, 2], max_new_tokens=14)
     want = _session(model, params).serve([req])["m0"].tokens
     src, dst = _session(model, params), _session(model, params)
@@ -214,6 +260,161 @@ def test_a_request_migrates_with_its_latent_rows(served):
         pass
     assert list(dst.engine.results["m0"].tokens) == list(want)
     assert dst.engine.num_prefills == 0
+
+
+# -- the pool's held shape (ISSUE 29) ----------------------------------------
+
+
+@pytest.mark.parametrize("page, tail, dtype, fold", [
+    (16, (8, 128), jnp.bfloat16, 1),   # a head axis (Mistral): as today
+    (16, (2, 64), jnp.bfloat16, 1),    # a head axis, whatever its width
+    (16, (512,), jnp.bfloat16, 1),     # whole lanes already
+    (16, (576,), jnp.bfloat16, 2),     # the latent row: [NP, 8, 1152]
+    (16, (576,), jnp.int8, 1),         # int8 keeps a row a position
+    (4, (24,), jnp.float32, 1),        # 24 x 16 = 384: 16 is no part of 4
+    (4, (64,), jnp.float32, 2),
+    (16, (64,), jnp.float32, 2),       # 16 / 2 = 8 rows: one whole tile
+    (32, (96,), jnp.bfloat16, 4),      # 4, 8, 16, 32 fit; 32 / 4 = 8 rows
+    (16, (32,), jnp.float32, 4),       # no fold leaves whole tiles: least
+    (16, (100,), jnp.bfloat16, 1),     # needs 32 positions
+], ids=str)
+def test_the_rule_of_the_held_shape(page, tail, dtype, fold):
+    assert page_fold(page, tail, dtype) == fold
+    assert page % fold == 0
+    if fold > 1:
+        assert (fold * tail[0]) % 128 == 0
+
+
+def _view(table, start, lens):
+    return PagedView(jnp.asarray(table, jnp.int32),
+                     jnp.asarray(start, jnp.int32),
+                     jnp.asarray(lens, jnp.int32), PAGE, False)
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_folded_write_gather_and_attention_are_the_declared_pools(chunk):
+    """The same steps on a pool held folded ``[NP, 2, 128]`` and on one
+    held as declared ``[NP, 4, 64]``: after every write the folded pool
+    is the declared one's bytes, and the attention over its held rows is
+    the attention over the logical rows. Slot 0 writes odd positions
+    from an odd left pad, slot 1 even ones across a page boundary,
+    slot 2 is idle on the trash page, slot 3 writes up to and past the
+    table's capacity (the overshoot lands on the trash page)."""
+    rng = np.random.default_rng(29 + chunk)
+    heads, r, dn, dr, dv, pages = 4, 48, 16, 16, 16, 3
+    width, num_pages = r + dr, 1 + 4 * pages
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    kv_b = f(r, heads, dn + dv) * 0.3
+    declared = f(num_pages, PAGE, width)
+    held = declared.reshape(num_pages, PAGE // 2, 2 * width)
+    table = np.zeros((4, pages), np.int32)
+    for slot in (0, 1, 3):
+        table[slot] = 1 + slot * pages + np.arange(pages)
+    start = np.array([3, 0, 0, 1])
+    lens = np.array([5, 2, 0, pages * PAGE - 2 * chunk])
+    for _ in range(3):
+        view = _view(table, start, lens)
+        value = f(4, chunk, width)
+        declared, _ = paged_write(declared, None, value, view)
+        held, _ = paged_write(held, None, value, view)
+        np.testing.assert_array_equal(
+            np.asarray(held).reshape(declared.shape)[1:],
+            np.asarray(declared)[1:])
+        rows = paged_gather(held, None, view, jnp.float32)
+        assert rows.shape == (4, pages * PAGE // 2, 2 * width)
+        q_nope, q_rope = f(4, chunk, heads, dn), f(4, chunk, heads, dr)
+        got = _mla_absorbed(
+            q_nope, q_rope, rows, kv_b, dn,
+            paged_attend_mask(view, chunk, fold=2), 0.2)
+        want = _mla_absorbed(
+            q_nope, q_rope, paged_gather(declared, None, view, jnp.float32),
+            kv_b, dn, paged_attend_mask(view, chunk), 0.2)
+        live = [0, 1, 3]  # an idle slot attends nothing: any row will do
+        np.testing.assert_allclose(
+            np.asarray(got)[live], np.asarray(want)[live], atol=2e-6)
+        lens = lens + chunk * np.array([1, 1, 0, 1])
+    # The last chunks of slot 3 ran past its three pages.
+    assert lens[3] > pages * PAGE
+
+
+def test_folded_rows_come_back_bit_for_bit(folded):
+    """Logical rows in, logical rows out, whatever shape the pool holds
+    them in: a prefill's rows seated left-aligned and gathered back for
+    a suffix prefill; a request exported and installed, its rows read
+    on the target; and a slot seated, decoded, freed and seated again."""
+    from tpudl.serve.cache import _migration_gather, parse_migration
+
+    model, params, _ = folded
+    sess = _session(model, params, prefix_share=True)
+    cache, engine = sess.engine.cache, sess.engine
+    assert cache.cache["model"]["layer_0"]["attention"]["pages_kv"].shape[1:] == (
+        PAGE // 2, 2 * 64)
+    ids = np.arange(1, WINDOW + 1, dtype=np.int32)[None]
+    _, row, _ = engine.prefill_call(params, ids, np.ones_like(ids))
+    want = {name: np.asarray(layer["attention"]["kv"][0, :WINDOW])
+            for name, layer in row["model"].items()}
+    cache.seat_shared(row, slot=0, input_ids=ids[0],
+                      reserve_tokens=WINDOW + 8)
+    matched, lease = cache.match_and_lease(ids[0][:WINDOW - 3])
+    assert len(matched) == (WINDOW - 3) // PAGE
+    back = cache.gather_prefix_rows(matched, len(matched) * PAGE)
+    cache.release_lease(lease)
+    for name, rows in want.items():
+        got = np.asarray(back["model"][name]["attention"]["kv"][0])
+        n = len(matched) * PAGE
+        np.testing.assert_array_equal(got[:n], rows[:n])
+    # Migration: the payload carries logical rows [T, 64].
+    meta = {"request": {"input_ids": ids[0].tolist()},
+            "reserve_tokens": WINDOW + 8}
+    parsed = parse_migration(cache.export_request(0, meta))
+    for path, arr in parsed["_arrays"].items():
+        layer = path.split("'")[3]
+        np.testing.assert_array_equal(arr, want[layer])
+    other = _session(model, params).engine.cache
+    other.import_request(cache.export_request(0, meta), slot=2)
+    landed = _migration_gather(
+        other.cache, jnp.asarray(other.page_table[2]), PAGE)
+    for name, rows in want.items():
+        got = np.asarray(landed["model"][name]["attention"]["pages_kv"])
+        np.testing.assert_array_equal(got[:WINDOW], rows)
+    # Seat, decode, free, seat again: the second tenant of the pages
+    # is served as the first was.
+    plain = _session(model, params, num_slots=1)
+    req = lambda rid: Request(rid, [3, 5, 7, 11, 2], max_new_tokens=7)  # noqa: E731
+    first = plain.serve([req("a")])["a"].tokens
+    assert plain.engine.cache.free_pages == plain.engine.cache.num_pages - 1
+    assert list(plain.serve([req("b")])["b"].tokens) == list(first)
+
+
+def test_an_exported_folded_pool_is_read_back_at_its_page_size(tmp_path):
+    """``from_artifacts`` recovers the page size from shapes alone: a
+    folded pool leaf has ``page_size / f`` rows to a page, and the
+    prefill artifact's dense row says how wide one position is."""
+    from tpudl.export.decode import export_serving_decoder
+
+    # Latent attention over dense layers: an artifact session takes the
+    # two-value contracts, which a model with routed experts has not.
+    model = LlamaForCausalLM(dataclasses.replace(
+        model_config(FOLDED, MAX_SEQ, jnp.float32), num_layers=2,
+        num_experts=0, experts_per_token=0, num_shared_experts=0,
+        first_k_dense=0, experts_held=None,
+    ))
+    ids = jnp.ones((1, WINDOW), jnp.int32)
+    params = model.init(jax.random.key(29), ids)["params"]
+    prefix = str(tmp_path / "latent")
+    export_serving_decoder(
+        model, params, num_slots=SLOTS, prompt_len=WINDOW,
+        path_prefix=prefix, page_size=PAGE,
+    )
+    art = ServeSession.from_artifacts(
+        f"{prefix}.prefill.stablehlo", f"{prefix}.decode.stablehlo", params)
+    cache = art.engine.cache
+    assert cache.page_size == PAGE and cache.folds == (2, 2)
+    assert cache.max_seq_len == MAX_SEQ
+    live = _session(model, params).serve(_requests())
+    served = art.serve(_requests())
+    assert {k: v.tokens for k, v in served.items()} == {
+        k: v.tokens for k, v in live.items()}
 
 
 @pytest.mark.parametrize("options, sentence", [

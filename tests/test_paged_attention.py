@@ -157,13 +157,13 @@ def _session(model, params, **kw):
     return ServeSession.from_model(model, params, WINDOW, **kw)
 
 
-def _latent_session():
+def _latent_session(rank=16, rope=8):
     from perfbench.families.mla_moe_serve import model_config, to_flax
     from perfbench.reference import mla_moe as ref
 
     config = {
-        "hidden_size": 64, "num_attention_heads": 4, "kv_lora_rank": 16,
-        "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "hidden_size": 64, "num_attention_heads": 4, "kv_lora_rank": rank,
+        "qk_nope_head_dim": 16, "qk_rope_head_dim": rope, "v_head_dim": 16,
         "intermediate_size": 128, "moe_intermediate_size": 32,
         "num_hidden_layers": 2, "first_k_dense_replace": 1,
         "num_experts": 4, "num_experts_per_tok": 2,
@@ -274,6 +274,10 @@ def test_session_serves_the_same_tokens_in_place(decoder, monkeypatch, tmp_path)
     steps = [r for r in records
              if r.get("kind") == "span" and r.get("name") == "decode_step"]
     assert steps and all(s["kv_in_place"] == 1 for s in steps)
+    # A k / v pool is held as declared: no layer folded.
+    assert session.engine.cache.folds == (1, 1, 1, 1)
+    assert registry().gauge("serve_kv_pool_folded_layers").value == 0
+    assert all(s["kv_fold"] == 1 for s in steps)
     # An idle slot costs a page; a busy one the pages its live
     # positions lie on, never its whole table.
     pages_a_slot = SEQ // PAGE
@@ -282,6 +286,33 @@ def test_session_serves_the_same_tokens_in_place(decoder, monkeypatch, tmp_path)
         + (SLOTS - s["busy"]) for s in steps
     )
     assert any(s["pages_live"] > SLOTS for s in steps)
+
+
+def test_a_latent_session_says_how_its_pool_is_held(tmp_path):
+    """The headless pool of a latent layer whose row is no whole number
+    of lanes (48 + 16 = 64 wide, pages of 8) is held folded: one leaf
+    a layer, two positions a held row, on the gauge and on every
+    ``decode_step`` span; a row of 24 finds no fold and says 1."""
+    obs.enable(str(tmp_path / "obs"))
+    try:
+        session = _latent_session(rank=48, rope=16)
+        session.serve(_requests())
+        records = obs_spans.active_recorder().records
+    finally:
+        obs.disable()
+    cache = session.engine.cache
+    assert cache.folds == (2, 2)
+    assert jax.tree.leaves(cache.cache)[0].shape == (
+        cache.num_pages, PAGE // 2, 2 * 64)
+    assert registry().gauge("serve_kv_pool_folded_layers").value == 2
+    steps = [r for r in records
+             if r.get("kind") == "span" and r.get("name") == "decode_step"]
+    assert steps and all(
+        s["kv_fold"] == 2 and s["kv_in_place"] == 0 for s in steps)
+    plain = _latent_session()
+    plain.serve(_requests(2))
+    assert plain.engine.cache.folds == (1, 1)
+    assert registry().gauge("serve_kv_pool_folded_layers").value == 0
 
 
 def test_pages_live_counts_what_the_kernel_visits():

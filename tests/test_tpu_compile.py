@@ -357,17 +357,24 @@ def test_decode_program_reads_the_pool_in_place_on_v5e(
 # The latent (MLA) pool of ISSUE 26 at its cell's size: sarvam-105b's
 # widths (perfbench/configs/sarvam-105b-l5-e32.json), one dense and one
 # expert layer deep. The decode and seat programs compile for the chip,
-# take ONE donated pool a layer and fit its memory. What this does NOT
-# hold: the chip lays a headless [NP, 16, 576] pool out page-index-minor
-# (576 is 4.5 lanes of 128), so these programs re-lay the pool on the
-# way in and out (PERF.md sections 6 and 7); the test counts no copies
-# until a layout is chosen for it.
+# take ONE donated pool a layer and fit its memory. Since ISSUE 29 the
+# pool is HELD in the shape the chip lays out major-to-minor with a page
+# contiguous (tpudl.models.paged.page_fold: a row of 576 is 4.5 lanes of
+# 128, and [NP, 16, 576] would be laid page-index-minor and re-laid on
+# the way in and out of every program; two positions a held row,
+# [NP, 8, 1152], is not). So the test holds what the k / v pool's test
+# holds: each leaf enters and leaves major-to-minor, is aliased whole,
+# and no copy of its shape is in the compiled text.
 LATENT_SLOTS, LATENT_WINDOW, LATENT_SEQ = 128, 512, 1280
-LATENT_SHAPE = (LATENT_SLOTS * LATENT_SEQ // POOL_PAGE + 1, POOL_PAGE, 576)
+LATENT_SHAPE = (
+    LATENT_SLOTS * LATENT_SEQ // POOL_PAGE + 1, POOL_PAGE // 2, 2 * 576
+)
 
 
 @pytest.mark.parametrize("name", ["decode", "seat"])
 def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
+    import re
+
     from tpudl.models.generate import prefill_fn
     from tpudl.models.llama import LlamaConfig, LlamaForCausalLM, RopeScaling
     from tpudl.serve import ServeSession
@@ -397,6 +404,7 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
     cache = session.engine.cache
     leaves = jax.tree.leaves(cache.cache)
     assert len(leaves) == 2  # ONE pool a layer
+    assert all(leaf.shape[1:] == LATENT_SHAPE[1:] for leaf in leaves)
     pool = jax.tree.map(
         lambda leaf: _s(LATENT_SHAPE, leaf.dtype, sharding=on_chip),
         cache.cache,
@@ -414,11 +422,21 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
         lowered = cache._seat_program(pages).lower(
             pool, _placed(row, on_chip), _s((pages,), i32, sharding=on_chip)
         )
-    memory = lowered.compile().memory_analysis()
-    # Both pools are donated, and the weights of two layers, the pools
-    # and the step's temporaries fit the chip.
-    assert memory.alias_size_in_bytes >= 2 * 10241 * 16 * 576 * 2
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    # Both pools are donated whole (1,152 bytes a position a layer, as
+    # declared), and the weights of two layers, the pools and the
+    # step's temporaries fit the chip.
+    assert memory.alias_size_in_bytes == 2 * 10241 * 16 * 576 * 2
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    text = compiled.as_text()
+    shape = ",".join(map(str, LATENT_SHAPE))
+    # Two leaves in, two out, each laid major-to-minor at the boundary.
+    boundary = re.search(r"entry_computation_layout=\{(.*)", text).group(1)
+    assert re.findall(rf"bf16\[{shape}\]\{{([\d,]+)", boundary) == ["2,1,0"] * 4
+    assert not re.findall(
+        rf"= bf16\[{shape}\][^ ]* copy(?:-start|-done)?\(", text
+    )
 
 
 # ---------------------------------------------------------------------------

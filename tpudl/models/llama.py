@@ -545,7 +545,10 @@ class LatentAttention(nn.Module):
     with ``qk_nope_head_dim + qk_rope_head_dim``-wide keys. Paged
     decode absorbs ``W_kv_b`` into the query and the output and attends
     over the gathered rows as one shared head: ``score = (W_kv_b^K
-    q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``."""
+    q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``. The pool
+    may be held folded, several positions to a held row
+    (tpudl.models.paged.page_fold); the write and ``_mla_absorbed``
+    address it as it is held."""
 
     cfg: LlamaConfig
 
@@ -585,6 +588,7 @@ class LatentAttention(nn.Module):
 
         if decode and paged is not None:
             from tpudl.models.paged import (
+                held_fold,
                 paged_attend_mask,
                 paged_gather,
                 paged_write,
@@ -592,6 +596,9 @@ class LatentAttention(nn.Module):
 
             pool = self.variable("cache", "pages_kv", _paged_cache_missing)
             paged.took.append(False)  # a headless pool: the gather
+            # The pool may be held folded (tpudl.models.paged.page_fold):
+            # the write and the attention address it as it is held.
+            fold = held_fold(pool.value, paged.page_size)
             sc = None
             if paged.quantized:
                 sc = self.variable("cache", "scale_kv", _paged_cache_missing)
@@ -607,7 +614,7 @@ class LatentAttention(nn.Module):
             )
             ctx = _mla_absorbed(
                 q_nope, q_rope, rows, kv_b, dn,
-                paged_attend_mask(paged, chunk=S), scale,
+                paged_attend_mask(paged, chunk=S, fold=fold), scale,
             )
         elif decode:
             # The dense row cache of a prefill (and of the chunked
@@ -683,15 +690,63 @@ def _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale):
 def _mla_absorbed(q_nope, q_rope, rows, kv_b, dn, mask, scale):
     """The same attention with ``kv_b`` absorbed into the query and the
     output: the rows are attended as they are cached, one head of
-    ``r + dr`` shared by all query heads."""
+    ``r + dr`` shared by all query heads.
+
+    ``rows`` may come FOLDED, as a folded pool holds them
+    (tpudl.models.paged.page_fold): [B, T / f, f * C], logical position
+    ``f * i + g`` in lanes ``g * C ...`` of held row ``i``, with
+    ``mask`` [B, f, S, T / f] in the same order. The ``f`` lane blocks
+    are attended as ``f`` groups of positions under one softmax (which
+    does not care in which order its positions come). Cutting block
+    ``g`` out of a view that size at lane ``g * C`` would copy it on
+    the chip, so each group reads a window of WHOLE 128-value lanes
+    around its block, which the matrix unit takes as it lies: the
+    query is padded with zeros to the window and the values' block is
+    cut out of the small result."""
+    from tpudl.models.paged import LANES
+    from tpudl.ops.attention import MASK_VALUE
+
     r = kv_b.shape[0]
     with jax.named_scope("mla_core"):
         q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, kv_b[..., :dn])
         query = jnp.concatenate([q_lat, q_rope], axis=-1)
-        logits = jnp.einsum("bshc,btc->bhst", query, rows)
-        weights = _masked_softmax(logits * scale, mask, rows.dtype)
-        u = jnp.einsum("bhst,btr->bshr", weights, rows[..., :r])
-        return jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
+        C, width = query.shape[-1], rows.shape[-1]
+
+        def window(first, size):
+            """Whole lanes around ``[first, first + size)`` of a row."""
+            return first // LANES * LANES, min(
+                -(-(first + size) // LANES) * LANES, width
+            )
+
+        logits = []
+        for g in range(width // C):
+            lo, hi = window(g * C, C)
+            padded = jnp.pad(
+                query, [(0, 0)] * 3 + [(g * C - lo, hi - (g + 1) * C)]
+            )
+            group = jnp.einsum("bshc,btc->bhst", padded, rows[..., lo:hi])
+            logits.append(jnp.where(
+                mask[:, g, None], group.astype(jnp.float32) * scale,
+                MASK_VALUE,
+            ))
+        # One softmax over every group's positions (jax.nn.softmax,
+        # written out over the list).
+        top = logits[0].max(-1, keepdims=True)
+        for x in logits[1:]:
+            top = jnp.maximum(top, x.max(-1, keepdims=True))
+        weights = [jnp.exp(x - jax.lax.stop_gradient(top)) for x in logits]
+        total = sum(w.sum(-1, keepdims=True) for w in weights)
+        u = 0.0
+        for g, w in enumerate(weights):
+            lo, hi = window(g * C, r)
+            part = jnp.einsum(
+                "bhst,btc->bshc", (w / total).astype(rows.dtype),
+                rows[..., lo:hi], preferred_element_type=jnp.float32,
+            )
+            u = u + part[..., g * C - lo:g * C - lo + r]
+        return jnp.einsum(
+            "bshr,rhd->bshd", u.astype(rows.dtype), kv_b[..., dn:]
+        )
 
 
 class LlamaBlock(nn.Module):

@@ -19,6 +19,18 @@ both:
   kv-head) — ~4x the resident tokens per byte vs f32 pools — applied
   inside the decode gather (one fused multiply on the gathered view).
 
+A pool leaf is HELD in the shape the chip lays out major-to-minor
+with a page contiguous (``page_fold``, the one place that knows): a
+row with a head axis, or whose width is a whole number of 128-value
+lanes, is held as ``[num_pages, page_size, ...]``; a headless row of
+another width C (a latent layer's 576) is held FOLDED,
+``[num_pages, page_size / f, f * C]``: position ``t`` of a page lies
+in row ``t // f``, lanes ``(t % f) * C ...``. The bytes are the same
+row-major bytes either way; what changes is that the chip's default
+layout of the held shape has the page index outermost, so no program
+re-lays the pool on the way in or out. Every program addresses the
+leaf in its held shape and reads the fold off it (``held_fold``).
+
 A decode step first writes its rows into the pool (``paged_write``, a
 scatter into the donated pool), then attends. There are two ways to
 read, and the program chooses by what it can observe
@@ -28,8 +40,8 @@ slot's live positions from HBM by the page table and keeps a running
 softmax over them, so nothing of shape ``[B, P * ps, ...]`` exists;
 everything else (int8 pools, a latent layer's headless pool, a pool
 committed to a mesh, any CPU run) **gathers**: ``paged_gather`` makes
-every slot's whole logical view dense and attention runs under
-``paged_attend_mask``. Both mean the same thing.
+every slot's whole logical view dense (in the held row form) and
+attention runs under ``paged_attend_mask``. Both mean the same thing.
 
 Masking: slot ``b`` attends logical positions ``[start[b], lens[b]]``
 (``start`` = its left-pad count, ``lens`` = where this step's token was
@@ -112,19 +124,55 @@ def quantize_kv(x: jax.Array):
     return q, scale
 
 
-def flat_page_row_index(page_table, page_size: int):
-    """Flat row index into a pool reshaped to ``[NP * page_size, ...]``:
-    logical position ``j`` of each table row maps to physical row
-    ``table[..., j // ps] * ps + j % ps``. Accepts ``[P]`` (one slot's
-    page ids — the radix gather and KV-migration paths) or ``[B, P]``
-    (the batched decode gather); the trailing axis flattens to
-    ``P * page_size`` either way. The ONE definition of page-table
-    address arithmetic shared by every pool gather."""
-    idx = (
-        page_table[..., :, None] * page_size
-        + jnp.arange(page_size, dtype=page_table.dtype)[None, :]
-    )
-    return idx.reshape(*page_table.shape[:-1], -1)
+#: The chip's tile: 128 values along the minor axis (lanes), 8 rows
+#: along the one before it (sublanes).
+LANES = 128
+SUBLANES = 8
+
+
+def page_fold(page_size: int, tail, dtype) -> int:
+    """How many positions of a page share one row of the HELD pool
+    leaf: the ONE rule of the pool's shape, from what the cache can
+    observe when it builds a leaf (the page size, the row's trailing
+    shape ``tail``, the stored dtype).
+
+    The chip's default layout of a buffer follows its SHAPE: a leaf
+    ``[NP, ps, C]`` whose ``C`` is not a whole number of 128-value
+    lanes is laid page-index-minor, and every program that takes it
+    re-lays it on the way in and out (two pool-sized copies a layer a
+    program). So such a row is folded ``f`` positions to a held row,
+    ``[NP, ps / f, f * C]``, with the smallest ``f`` that divides the
+    page and makes ``f * C`` whole lanes; one that leaves ``ps / f`` a
+    whole number of 8-row tiles is taken first (then a slot's gathered
+    pages merge into one view without a copy). 1 = held as declared:
+    a row with a head axis (``[.., Hkv, D]``), a width that is whole
+    lanes already, a width no such ``f`` exists for, and an int8 pool,
+    whose dequant scales ride beside it one a position."""
+    tail = tuple(int(d) for d in tail)
+    if len(tail) != 1 or jnp.dtype(dtype) == jnp.int8:
+        return 1
+    width = tail[0]
+    if width % LANES == 0:
+        return 1
+    folds = [
+        f for f in range(2, page_size + 1)
+        if page_size % f == 0 and (f * width) % LANES == 0
+    ]
+    whole_tiles = [f for f in folds if (page_size // f) % SUBLANES == 0]
+    return min(whole_tiles or folds or [1])
+
+
+def held_fold(pages, page_size: int) -> int:
+    """The fold a pool leaf is held in, read off its shape."""
+    return page_size // int(pages.shape[1])
+
+
+def row_tail(pages, page_size: int) -> tuple:
+    """The trailing shape of ONE logical position of a pool leaf
+    ``[NP, *held page]``, whatever shape the page is held in."""
+    fold = held_fold(pages, page_size)
+    tail = tuple(int(d) for d in pages.shape[2:])
+    return tail if fold == 1 else (tail[0] // fold,)
 
 
 def paged_write(
@@ -140,7 +188,10 @@ def paged_write(
     (the freshly projected + RoPE'd k or v; [B, Hkv, D] is accepted as
     the S=1 single-token form). A pool with no head axis ([NP, ps, C],
     scales [NP, ps]: a latent cache's one row a position) takes
-    ``value`` [B, S, C] the same way. Token j of slot b lands at physical
+    ``value`` [B, S, C] the same way; held folded ([NP, ps / f, f * C],
+    ``page_fold``) the row lands in held row ``off // f``, lanes
+    ``(off % f) * C ...``: the pool is never reshaped.
+    Token j of slot b lands at physical
     ``(page_table[b, (lens[b]+j) // ps], (lens[b]+j) % ps)`` — the
     speculative-verify dispatch writes its whole k-token window this
     way; idle slots (lens pinned at 0 on a trash-mapped row) write into
@@ -166,12 +217,28 @@ def paged_write(
         )
         page = jnp.where(pidx < p, page, 0)
         off = pos % ps
+        fold = held_fold(pages, ps)
         if view.quantized:
             q, sc = quantize_kv(value)
             pages = pages.at[page, off].set(q)
             scales = scales.at[page, off].set(sc)
-        else:
+        elif fold == 1:
             pages = pages.at[page, off].set(value.astype(pages.dtype))
+        else:
+            # Held folded: the row goes into lane block ``off % fold``
+            # of held row ``off // fold``. A scatter whose window starts
+            # at a lane offset is taken apart into one update at a time
+            # by the chip's compiler, so whole held rows are read,
+            # merged and written back (both native). One position a
+            # slot at a time: two positions of a chunk may share a
+            # held row, two slots never do (but on the trash page).
+            width = value.shape[-1]
+            block = jnp.arange(fold * width) // width
+            for j in range(s):
+                at = (page[:, j], off[:, j] // fold)
+                mine = block[None, :] == (off[:, j] % fold)[:, None]
+                new = jnp.tile(value[:, j].astype(pages.dtype), (1, fold))
+                pages = pages.at[at].set(jnp.where(mine, new, pages[at]))
     return pages, scales
 
 
@@ -186,17 +253,22 @@ def paged_gather(
     where it can, and then this is not called).
 
     Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis)
-    in ``compute_dtype`` where L = pages_per_slot x page_size;
-    dequantization (``q * scale``) is fused into this
-    gather for int8 pools. Unmapped logical pages resolve to the trash
-    page — finite garbage the attention mask excludes."""
+    in ``compute_dtype`` where L = pages_per_slot x page_size; from a
+    pool held folded, the HELD rows [B, L / f, f * C] (logical position
+    ``t`` in row ``t // f``, lanes ``(t % f) * C ...``), because
+    splitting the lanes of a view this size is a copy of it on the
+    chip: the attention reads them as they lie
+    (``paged_attend_mask(fold=f)``). Dequantization (``q * scale``) is
+    fused into this gather for int8 pools. Unmapped logical pages
+    resolve to the trash page — finite garbage the attention mask
+    excludes."""
     with jax.named_scope("kv_gather"):
         # Whole pages, not rows: a slot's table row names its pages and
         # a page's positions lie together, so one slice a page (16
         # rows) is fetched where a row index would fetch 16. The
-        # logical view [B, P * ps, ...] is the same either way
-        # (``flat_page_row_index`` stays the address arithmetic of the
-        # prefix and migration gathers, which cut rows out of it).
+        # logical view [B, P * ps, ...] is the same either way (the
+        # prefix and migration gathers cut a slot's pages the same
+        # way).
         out = pages[view.page_table]  # [B, P, ps, ...]
         if view.quantized:
             out = (
@@ -209,16 +281,21 @@ def paged_gather(
         return out.astype(compute_dtype)
 
 
-def paged_attend_mask(view: PagedView, chunk: int = 1) -> jax.Array:
+def paged_attend_mask(
+    view: PagedView, chunk: int = 1, fold: int = 1
+) -> jax.Array:
     """[B, 1, S, L] bool — query j of the chunk attends logical
     positions in [start, lens + j] inclusive (lens + j = where query
     j's own token was just written), so a multi-token verify chunk is
-    causal within itself exactly like sequential single-token steps."""
-    pos = jnp.arange(view.logical_len)
+    causal within itself exactly like sequential single-token steps.
+    ``fold`` > 1 gives the same mask in the order a folded pool's held
+    rows come in, [B, fold, S, L / fold]: entry ``[b, g, j, i]`` is
+    logical position ``fold * i + g``."""
+    pos = jnp.arange(view.logical_len).reshape(-1, fold).T
     upper = view.lens[:, None] + jnp.arange(
         chunk, dtype=view.lens.dtype
     )[None, :]
-    mask = (pos[None, None, :] >= view.start[:, None, None]) & (
-        pos[None, None, :] <= upper[:, :, None]
+    lower = view.start[:, None, None, None]
+    return (pos[None, :, None, :] >= lower) & (
+        pos[None, :, None, :] <= upper[:, None, :, None]
     )
-    return mask[:, None, :, :]

@@ -53,7 +53,12 @@ from tpudl.analysis.registry import env_flag, env_int, env_str
 from tpudl.obs import registry
 from tpudl.obs import requestlog
 from tpudl.obs.spans import active_recorder
-from tpudl.serve.cache import PagedKVCache, _is_pool, _is_valid_leaf
+from tpudl.serve.cache import (
+    PagedKVCache,
+    _is_attn_cache,
+    _is_pool,
+    _is_valid_leaf,
+)
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue
 
 
@@ -162,16 +167,18 @@ def validate_request(request: Request, prompt_len: int, max_seq_len: int) -> Non
         )
 
 
-def _find_pool(tree) -> Optional[dict]:
-    """First per-layer page-pool dict in a paged cache pytree (the
-    artifact-geometry probe ``from_artifacts`` reads shapes off)."""
+def _find_layer(tree, is_layer=_is_pool) -> Optional[dict]:
+    """First per-layer cache dict in a cache pytree: a page pool of
+    the decode artifact, or (``_is_attn_cache``) the dense rows of the
+    prefill artifact's same layer: the artifact-geometry probe
+    ``from_artifacts`` reads shapes off."""
     from collections.abc import Mapping
 
     if isinstance(tree, Mapping):
-        if _is_pool(tree):
+        if is_layer(tree):
             return dict(tree)
         for value in tree.values():
-            found = _find_pool(value)
+            found = _find_layer(value, is_layer)
             if found is not None:
                 return found
     return None
@@ -581,7 +588,7 @@ class ServeSession:
             )
         prompt_len = int(ids_aval.shape[1])
         _, pools, token_aval, _, table_aval, _, _ = dec_args
-        pool = _find_pool(pools)
+        pool = _find_layer(pools)
         if pool is None:
             raise ValueError(
                 "paged decode artifact carries no page-pool cache "
@@ -601,12 +608,19 @@ class ServeSession:
             ),
             None,
         )
-        pages = next(v for k, v in pool.items() if k.startswith("pages_"))
+        name, pages = next(
+            (k, v) for k, v in pool.items() if k.startswith("pages_")
+        )
+        # A pool held folded (tpudl.models.paged.page_fold) has
+        # page_size / f rows of f positions to a page: the prefill's
+        # dense row of the same leaf says how wide ONE position is.
+        row = _find_layer(pre_cache, _is_attn_cache)[name[len("pages_"):]]
+        fold = 1 if pages.ndim != 3 else int(pages.shape[2] // row.shape[2])
         cache = PagedKVCache.from_pool_template(
             pools,
             num_slots=int(token_aval.shape[0]),
             pages_per_slot=int(table_aval.shape[1]),
-            page_size=int(pages.shape[1]),
+            page_size=int(pages.shape[1]) * fold,
             quantized=any(k.startswith("scale_") for k in pool),
             num_pages=int(pages.shape[0]),
             model_seq_len=model_bound,

@@ -1,8 +1,9 @@
 """The serving KV cache: per-layer page pools behind the engine.
 
 The engine's decode program is compiled ONCE for fixed-shape pools
-(``[num_pages, page_size, ...]`` per layer). Continuous batching never
-reshapes them — requests come and go by mutating WHICH pages a slot's
+(``[num_pages, page_size, ...]`` per layer, or the same bytes held
+folded: ``PagedKVCache``). Continuous batching never reshapes them —
+requests come and go by mutating WHICH pages a slot's
 host-side page table row maps (``PagedKVCache``): a seat scatters a
 batch-1 prefill's dense cache rows (the shape
 tpudl.models.llama.LlamaAttention builds in decode mode) into the
@@ -410,9 +411,27 @@ class PagedKVCache:
     table row maps. WHICH pools a layer has is the layer's to declare
     (``_is_attn_cache``): a grouped-query layer declares ``k`` and
     ``v``, a latent (MLA) layer ONE headless leaf ``kv`` and so one
-    pool ``pages_kv [num_pages, page_size, C]``; seating, the prefix
-    gather, migration and ``nbytes`` walk the declared leaves. Three
-    consequences the engine builds on:
+    pool ``pages_kv``; seating, the prefix gather, migration and
+    ``nbytes`` walk the declared leaves.
+
+    The SHAPE a leaf is held in is the cache's to choose, by one rule
+    (tpudl.models.paged.page_fold, from the page size, the row's
+    trailing shape and the stored dtype): the shape whose default
+    layout on the chip is major-to-minor with a page contiguous, so
+    that no program re-lays the pool on the way in or out. A row with
+    a head axis, or one that is whole 128-value lanes wide, is held as
+    declared (``[num_pages, page_size, Hkv, D]``); a headless row of
+    another width C (the latent 576) is held FOLDED, ``f`` positions
+    to a held row: ``[num_pages, page_size / f, f * C]``, position
+    ``t`` of a page in row ``t // f``, lanes ``(t % f) * C ...``
+    (576 on pages of 16: ``[num_pages, 8, 1152]``, a page one
+    contiguous 18 KB run). The bytes, ``nbytes`` and ``page_size`` are
+    what was declared; ``folds`` says what was chosen. The decode
+    program addresses the held shape (``paged_write``, ``paged_gather``
+    and the latent attention); the seats, the prefix gather and
+    migration keep LOGICAL rows ``[T, C]`` on the outside and reshape
+    a slot's worth of pages, never a pool. Three consequences the
+    engine builds on:
 
     - **No shared write index**: each slot carries its own length, so
       a long generation in one slot never costs another its cache.
@@ -510,13 +529,22 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
 
         def to_pool(attn: dict) -> dict:
+            from tpudl.models.paged import page_fold
+
             page = (self.num_pages, self.page_size)
             pool = {}
             for name in _row_names(attn):
                 row = attn[name]
                 tail = tuple(int(d) for d in row.shape[2:])
+                dtype = jnp.int8 if self.quantized else row.dtype
+                # Held in the shape the chip lays out with a page
+                # contiguous: a headless row that is not whole lanes
+                # wide is folded, ``fold`` positions to a held row.
+                fold = page_fold(self.page_size, tail, dtype)
                 pool[f"pages_{name}"] = jnp.zeros(
-                    page + tail, jnp.int8 if self.quantized else row.dtype
+                    (self.num_pages, self.page_size // fold)
+                    + tail[:-1] + (fold * tail[-1],),
+                    dtype,
                 )
                 if self.quantized:
                     # One dequant scale per stored vector (the last
@@ -627,6 +655,22 @@ class PagedKVCache:
         obj._seat_shared_fn = None
         obj._gather_rows_fn = None
         return obj
+
+    # -- the held shape --------------------------------------------------
+
+    @functools.cached_property
+    def folds(self) -> tuple:
+        """The fold each pool leaf is held in
+        (tpudl.models.paged.page_fold), read off the leaves once (a
+        pool keeps its shape): 1 where a page is held as its layer
+        declares it."""
+        from tpudl.models.paged import held_fold
+
+        flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
+        return tuple(
+            held_fold(leaf, self.page_size) for path, leaf in flat
+            if str(getattr(path[-1], "key", "")).startswith("pages_")
+        )
 
     # -- occupancy counters --------------------------------------------
 
@@ -796,17 +840,10 @@ class PagedKVCache:
                             blocks,
                             [(0, span - take)] + [(0, 0)] * (blocks.ndim - 1),
                         )
-                    blocks = blocks.reshape(
-                        prompt_pages, ps, *rowvals.shape[2:]
-                    )
                     if quantized:
-                        q, s = quantize_kv(blocks)
-                        out[name] = out[name].at[page_ids].set(q)
-                        out[sname] = out[sname].at[page_ids].set(s)
-                    else:
-                        out[name] = out[name].at[page_ids].set(
-                            blocks.astype(out[name].dtype)
-                        )
+                        blocks, s = quantize_kv(blocks)
+                        out[sname] = _set_pages(out[sname], page_ids, s)
+                    out[name] = _set_pages(out[name], page_ids, blocks)
                 return out
 
             with jax.named_scope("kv_scatter"):
@@ -932,8 +969,7 @@ class PagedKVCache:
         from tpudl.models.paged import quantize_kv
 
         ps, quantized = self.page_size, self.quantized
-        pages = self.pages_per_slot
-        span = pages * ps
+        span = self.pages_per_slot * ps
 
         def tpudl_seat_shared(pool_tree, row_tree, page_ids, row_offset):
             def one(pool: dict, row: dict) -> dict:
@@ -947,15 +983,11 @@ class PagedKVCache:
                     )
                     blocks = jax.lax.dynamic_slice_in_dim(
                         padded, row_offset, span, axis=0
-                    ).reshape(pages, ps, *rowvals.shape[1:])
+                    )
                     if quantized:
-                        q, s = quantize_kv(blocks)
-                        out[name] = out[name].at[page_ids].set(q)
-                        out[sname] = out[sname].at[page_ids].set(s)
-                    else:
-                        out[name] = out[name].at[page_ids].set(
-                            blocks.astype(out[name].dtype)
-                        )
+                        blocks, s = quantize_kv(blocks)
+                        out[sname] = _set_pages(out[sname], page_ids, s)
+                    out[name] = _set_pages(out[name], page_ids, blocks)
                 return out
 
             with jax.named_scope("kv_scatter"):
@@ -989,23 +1021,15 @@ class PagedKVCache:
         row_template = self._row_template
 
         def tpudl_gather_rows(pool_tree, page_ids, m_tok):
-            from tpudl.models.paged import flat_page_row_index
-
             def one(pool: dict, tmpl: dict) -> dict:
                 seq = int(tmpl["valid"].shape[1])
-                flat_idx = flat_page_row_index(page_ids, ps)
                 out = {}
                 for kv in _row_names(tmpl):
                     name, sname = f"pages_{kv}", f"scale_{kv}"
-                    pool_arr = pool[name]
-                    flat = pool_arr.reshape(
-                        pool_arr.shape[0] * ps, *pool_arr.shape[2:]
-                    )
-                    rows = flat[flat_idx]
+                    rows = _slot_rows(pool[name], page_ids, ps)
                     if quantized:
-                        sc = pool[sname].reshape(-1, *pool[sname].shape[2:])
                         rows = rows.astype(jnp.float32) * (
-                            sc[flat_idx][..., None]
+                            _slot_rows(pool[sname], page_ids, ps)[..., None]
                         )
                     if span >= seq:
                         rows = rows[:seq]
@@ -1098,7 +1122,9 @@ class PagedKVCache:
                 "canonical token->position mapping)"
             )
         page_ids = jnp.asarray(self.page_table[slot], jnp.int32)
-        host = jax.device_get(_migration_gather(self.cache, page_ids))
+        host = jax.device_get(
+            _migration_gather(self.cache, page_ids, self.page_size)
+        )
         flat, _ = jax.tree_util.tree_flatten_with_path(host)
         leaves = [
             (jax.tree_util.keystr(path), np.asarray(arr)[skip:lens])
@@ -1250,8 +1276,12 @@ class PagedKVCache:
         arrays = meta["_arrays"]
 
         def make_rows(pool: dict) -> dict:
+            from tpudl.models.paged import row_tail
+
             return {
-                name: np.zeros((span,) + tuple(arr.shape[2:]), arr.dtype)
+                name: np.zeros(
+                    (span,) + row_tail(arr, self.page_size), arr.dtype
+                )
                 for name, arr in pool.items()
             }
 
@@ -1537,8 +1567,31 @@ def _map_pools(tree, fn):
     return tree
 
 
-@jax.jit
-def _migration_gather(cache, page_ids):
+def _slot_rows(leaf, page_ids, page_size: int):
+    """One slot's logical rows ``[P * page_size, *tail]`` out of a pool
+    leaf by its page ids: whole pages are fetched as the leaf holds
+    them, and the small result takes its logical row form (a held page
+    is its rows' row-major bytes: a reshape, of a slot's worth of
+    pages, never of a pool)."""
+    from tpudl.models.paged import row_tail
+
+    return leaf[page_ids].reshape(
+        page_ids.shape[0] * page_size, *row_tail(leaf, page_size)
+    )
+
+
+def _set_pages(leaf, page_ids, rows):
+    """Write logical rows ``[P * page_size, *tail]`` into a pool leaf
+    as whole pages at ``page_ids``, in the shape the leaf holds a page
+    in (a held page is its rows' row-major bytes: a reshape of the
+    small side)."""
+    return leaf.at[page_ids].set(
+        rows.reshape(page_ids.shape[0], *leaf.shape[1:]).astype(leaf.dtype)
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _migration_gather(cache, page_ids, page_size):
     """Materialize one slot's logical rows from every pool leaf in
     STORED dtype — no dequantization, so int8 pages and their scale
     rows round-trip bit-exact through a migration. Module-level jit on
@@ -1546,16 +1599,11 @@ def _migration_gather(cache, page_ids):
     fleet) shares ONE compiled program, so migrating never recompiles
     per replica."""
 
-    from tpudl.models.paged import flat_page_row_index
-
     def one(pool: dict) -> dict:
-        ps = next(iter(pool.values())).shape[1]
-        flat_idx = flat_page_row_index(page_ids, ps)
-        out = {}
-        for name, arr in pool.items():
-            flat = arr.reshape(arr.shape[0] * ps, *arr.shape[2:])
-            out[name] = flat[flat_idx]
-        return out
+        return {
+            name: _slot_rows(arr, page_ids, page_size)
+            for name, arr in pool.items()
+        }
 
     return _map_pools(cache, one)
 
@@ -1569,13 +1617,9 @@ def _migration_scatter(cache, rows, page_ids):
     shared-compilation property."""
 
     def one(pool: dict, r: dict) -> dict:
-        ps = next(iter(pool.values())).shape[1]
         out = dict(pool)
         for name, vals in r.items():
-            paged = vals.reshape(page_ids.shape[0], ps, *vals.shape[1:])
-            out[name] = out[name].at[page_ids].set(
-                paged.astype(out[name].dtype)
-            )
+            out[name] = _set_pages(out[name], page_ids, vals)
         return out
 
     return _zip_attn_caches(cache, rows, one)

@@ -788,17 +788,25 @@ class Engine:
         reg.gauge("serve_paged_attention_in_place").set(
             self.cache.in_place_layers
         )
+        # Pool leaves held folded (one a latent layer; a k / v pool
+        # never is).
+        reg.gauge("serve_kv_pool_folded_layers").set(
+            sum(fold > 1 for fold in self.cache.folds)
+        )
 
     def _paged_attrs(self, pages_live: int) -> dict:
         """What a ``decode_step`` span says of the paged cache: the
         pages the seated slots hold, the positions the step read,
         whether its attention read the pool in place (the program's
-        own note, ``PagedKVCache.in_place_layers``) and the pages such
-        a step visits, counted on the host at dispatch."""
+        own note, ``PagedKVCache.in_place_layers``), the positions a
+        held row of its pool folds together (``PagedKVCache.folds``; 1
+        = held as declared) and the pages an in-place step visits,
+        counted on the host at dispatch."""
         cache = self.cache
         return {"pages_reserved": cache.pages_reserved,
                 "tokens_live": cache.tokens_live,
                 "kv_in_place": int(cache.in_place_layers > 0),
+                "kv_fold": max(cache.folds),
                 "pages_live": pages_live}
 
     def _fits(self, request) -> bool:
