@@ -511,6 +511,12 @@ def test_drain_is_instant_and_drops_nothing(programs):
     step_s = 0.05
     max_new = 40
     sessions = [_session(programs, slow_s=step_s) for _ in range(2)]
+    for session in sessions:
+        # Each session compiles its own seat program at its first seat
+        # (test_frozen_replica_goes_stale_then_recovers): served once
+        # here, nothing of d1's compiles inside the timed drain while
+        # d0's requests are seated on it.
+        session.serve([Request("warm", [1, 2, 3], max_new_tokens=2)])
     replicas = [Replica(f"d{i}", s) for i, s in enumerate(sessions)]
     requests = [
         Request(f"w{i}", [3, 5, 7 + i], max_new_tokens=max_new)

@@ -439,6 +439,119 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
     )
 
 
+# Laguna-XS.2's five-layer stage of ISSUE 30 at its cell's size
+# (perfbench/configs/laguna-xs2-l5.json through its family's own
+# ``model_config``): two full-context layers of 48 heads over a table of
+# 320 pages a slot, three window layers of 64 heads over rings of 33.
+# What the k / v pool's test holds for Mistral holds here for BOTH
+# groups: every layer's attention is the in-place kernel, each pool leaf
+# is aliased whole, nothing of a pool's shape is copied and nothing of a
+# slot's whole logical view (table or ring) is made. The batch-1
+# prefill at a window of 4,096 attends in blocks and dispatches its
+# experts by sorted groups: its temporaries stay far under what one
+# dense pass would take ([64, 4096, 5120] float32 alone is 5.4 GB, two
+# [256, 4096, 512] expert tensors 2.1 GB).
+
+
+def _window_moe_session():
+    import json
+
+    from perfbench.families import window_moe_serve as family
+    from perfbench.reference import window_moe as ref
+    from tpudl.models.llama import LlamaForCausalLM
+    from tpudl.serve import ServeSession
+
+    with open(REPO / "perfbench/configs/laguna-xs2-l5.json") as f:
+        cfg = json.load(f)
+    sess = cfg["session"]
+    model = LlamaForCausalLM(
+        family.model_config(cfg, sess["max_seq_len"], bf16)
+    )
+    s = ref.settings(cfg)
+    key = jax.eval_shape(lambda: ref.seed_key(0))
+    params = jax.eval_shape(
+        lambda k: family.to_flax(ref.all_weights(k, s, bf16), s), key
+    )
+    session = ServeSession.from_model(
+        model, params, sess["prompt_window"], num_slots=sess["num_slots"],
+        page_size=sess["page_size"],
+        num_pages=sess["max_seq_len"] // sess["page_size"] + 1,
+    )
+    return sess, model, params, session
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_window_moe_program_compiles_for_v5e(
+    name, monkeypatch, no_compile_cache
+):
+    import math
+    import re
+
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+    from tpudl.models.generate import prefill_fn
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    on_chip = SingleDeviceSharding(device)
+    sess, model, params, session = _window_moe_session()
+    slots, page = sess["num_slots"], sess["page_size"]
+    if name == "prefill":
+        ids = _s((1, sess["prompt_window"]), i32, sharding=on_chip)
+        compiled = jax.jit(prefill_fn(model)).lower(
+            _placed(params, on_chip), ids, ids
+        ).compile()
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < 1.5e9
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+        return
+    cache = session.engine.cache
+    assert cache.ring_pages == 33
+    table_pages = sess["max_seq_len"] // page
+    shapes = {
+        False: (slots * table_pages + 1, page, 8, 128),
+        True: (slots * cache.ring_pages + 1, page, 8, 128),
+    }
+    pool = jax.tree.map(
+        lambda leaf: _s(
+            shapes[leaf.shape[0] == cache.num_ring_pages], leaf.dtype,
+            sharding=on_chip,
+        ),
+        cache.cache,
+    )
+    vec = _s((slots,), i32, sharding=on_chip)
+    tables = (
+        _s((slots, table_pages), i32, sharding=on_chip),
+        _s((slots, cache.ring_pages), i32, sharding=on_chip),
+    )
+    compiled = session.engine.decode_call.lower(
+        _placed(params, on_chip), pool, vec, vec, tables, vec, vec
+    ).compile()
+    took = session.engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True,) * 5
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert "paged_attention" in text and "kv_gather" not in text
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * 2 * (
+        2 * math.prod(shapes[False]) + 3 * math.prod(shapes[True])
+    )
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert 3.0e9 < pool_bytes < 3.2e9  # one table for all: 6.7 GB
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+    for shape in shapes.values():
+        dims = ",".join(map(str, shape))
+        assert not re.findall(
+            rf"= bf16\[{dims}\][^ ]* copy(?:-start|-done)?\(", text
+        )
+    # Nothing of a slot's whole logical view, table or ring.
+    assert f"bf16[{slots},{sess['max_seq_len']}," not in text
+    assert f"bf16[{slots},{cache.ring_pages * page}," not in text
+
+
 # ---------------------------------------------------------------------------
 # chip_smoke.py on the CPU: the phases at a tiny size (one serve phase,
 # on the page pool; one train phase; the two mesh phases), through a

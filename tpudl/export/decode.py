@@ -113,7 +113,7 @@ def export_serving_decoder(
     pf = prefill_fn(model)
     ids = jnp.zeros((1, prompt_len), jnp.int32)
     mask = jnp.ones((1, prompt_len), jnp.int32)
-    _, template = jax.eval_shape(
+    _, template, *_ = jax.eval_shape(
         pf,
         params,
         jnp.zeros((num_slots, prompt_len), jnp.int32),
@@ -123,6 +123,7 @@ def export_serving_decoder(
         template, page_size=page_size, num_pages=num_pages,
         kv_dtype=kv_dtype,
     )
+    cache._no_rings("the exported decode artifact")
     token = jnp.zeros((num_slots,), jnp.int32)
     position = jnp.full((num_slots,), prompt_len, jnp.int32)
     prefill_blob = export_stablehlo(

@@ -14,6 +14,7 @@ optimizer's job — see lora.lora_optimizer).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import re
 from functools import partial
@@ -37,7 +38,10 @@ class RopeScaling:
     ``beta_fast`` and ``beta_slow`` rotations over the original
     context. ``attention_scale`` is what the blend asks of the softmax
     (``mscale ** 2``); cos and sin stay unscaled where ``mscale ==
-    mscale_all_dim``, which is how the published configs set them."""
+    mscale_all_dim``, which is how the published configs set them.
+    A config of the plain ``yarn`` rope type states the one number
+    instead, ``attention_factor``: cos and sin are multiplied by it
+    and the softmax is left alone."""
 
     factor: float
     original_max_position: int
@@ -45,6 +49,7 @@ class RopeScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    attention_factor: Optional[float] = None
 
     @staticmethod
     def _mscale(factor: float, m: float) -> float:
@@ -52,12 +57,16 @@ class RopeScaling:
 
     @property
     def cos_sin_scale(self) -> float:
+        if self.attention_factor is not None:
+            return self.attention_factor
         return self._mscale(self.factor, self.mscale) / self._mscale(
             self.factor, self.mscale_all_dim
         )
 
     @property
     def attention_scale(self) -> float:
+        if self.attention_factor is not None:
+            return 1.0
         return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
     def inv_freq(self, dim: int, theta: float) -> jax.Array:
@@ -78,6 +87,23 @@ class RopeScaling:
             0.0, 1.0,
         )
         return plain / self.factor * ramp + plain * (1.0 - ramp)
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionSpec:
+    """One layer's grouped-query attention (``LlamaConfig.layer_spec``):
+    ``scope`` "full_attention" or "window_attention", its query heads,
+    its RoPE (``rotary_dim`` leading values of the head rotate, the
+    rest pass), ``window`` (0 = the whole context) and whether its
+    output is gated by head."""
+
+    scope: str
+    num_heads: int
+    rope_theta: float
+    rope_scaling: Optional[RopeScaling]
+    rotary_dim: int
+    window: int
+    gate: bool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +148,29 @@ class LlamaConfig:
                 "(the capacity-dropping training layer) are two layers: "
                 "set one"
             )
+        for name in ("layer_types", "num_heads_per_layer"):
+            per_layer = getattr(self, name)
+            if per_layer is not None and len(per_layer) != self.num_layers:
+                raise ValueError(
+                    f"{name} names {len(per_layer)} layers, num_layers "
+                    f"is {self.num_layers}"
+                )
+        if self.layer_types is not None:
+            kinds = set(self.layer_types)
+            if not kinds <= {"full_attention", "sliding_attention"}:
+                raise ValueError(
+                    f"layer_types must be 'full_attention' or "
+                    f"'sliding_attention', got {sorted(kinds)}"
+                )
+            if "sliding_attention" in kinds and self.sliding_window < 1:
+                raise ValueError(
+                    "a 'sliding_attention' layer needs sliding_window >= 1"
+                )
+            if self.attention == "mla":
+                raise ValueError(
+                    "layer_types are the grouped-query block's: latent "
+                    "attention keeps every layer alike"
+                )
     # Fused-epilogue kernel tier (tpudl.ops.norms / mlp_fused): False
     # (default) = composite RMSNorm/SwiGLU, bit-identical to before the
     # tier; True = Pallas fused RMSNorm(+residual) and SwiGLU on TPU,
@@ -179,10 +228,58 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     first_k_dense: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    # Layers that differ in their attention. ``layer_types`` names each
+    # layer's kind ("full_attention": the whole context;
+    # "sliding_attention": the last ``sliding_window`` positions, the
+    # query's own counted), ``num_heads_per_layer`` its query heads
+    # (``num_kv_heads`` is shared), ``head_size`` states the head's
+    # width where it is not ``hidden_size // num_heads``. RoPE by kind:
+    # a full layer rotates the first ``partial_rotary_factor`` of the
+    # head with ``rope_theta`` / ``rope_scaling``, a sliding layer the
+    # whole head, plainly, with ``sliding_rope_theta``.
+    # ``attention_gate``: one sigmoid gate a head, from the layer's
+    # normed input, on the attention's output before ``o_proj``. A
+    # configuration that sets none of these builds the uniform blocks
+    # above (``layer_spec``).
+    head_size: int = 0
+    layer_types: Optional[Tuple[str, ...]] = None
+    num_heads_per_layer: Optional[Tuple[int, ...]] = None
+    sliding_window: int = 0
+    sliding_rope_theta: float = 10_000.0
+    partial_rotary_factor: float = 1.0
+    attention_gate: bool = False
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    def layer_spec(self, layer: int) -> "AttentionSpec":
+        """What ``layer``'s grouped-query attention is made of."""
+        heads = (
+            self.num_heads_per_layer[layer]
+            if self.num_heads_per_layer is not None else self.num_heads
+        )
+        kind = (
+            self.layer_types[layer] if self.layer_types is not None
+            else "full_attention"
+        )
+        if kind == "sliding_attention":
+            return AttentionSpec(
+                "window_attention", heads, self.sliding_rope_theta, None,
+                self.head_dim, self.sliding_window, self.attention_gate,
+            )
+        return AttentionSpec(
+            "full_attention", heads, self.rope_theta, self.rope_scaling,
+            int(self.head_dim * self.partial_rotary_factor), 0,
+            self.attention_gate,
+        )
+
+    @property
+    def window_layers(self) -> int:
+        """Layers that keep a window of the context only."""
+        return sum(
+            self.layer_spec(i).window > 0 for i in range(self.num_layers)
+        )
 
     def mlp_kind(self, layer: int) -> str:
         """"moe" (dropless routed experts) or "dense" for ``layer``."""
@@ -324,10 +421,13 @@ class RMSNorm(nn.Module):
 def rope(
     x: jax.Array, positions: jax.Array, theta: float,
     scaling: Optional[RopeScaling] = None,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
     """Rotary embedding on [B, S, H, D] (rotate-half convention);
-    ``scaling`` swaps the plain frequencies for YaRN's."""
-    d = x.shape[-1]
+    ``scaling`` swaps the plain frequencies for YaRN's; ``rotary_dim``
+    rotates the head's first values only (the frequencies are those of
+    a head that wide) and passes the rest."""
+    d = x.shape[-1] if rotary_dim is None else rotary_dim
     if scaling is None:
         inv_freq = 1.0 / (
             theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -339,11 +439,13 @@ def rope(
     angles = positions[:, :, None].astype(jnp.float32) * inv_freq  # [B,S,d/2]
     cos = amp * jnp.cos(angles)[:, :, None, :]  # [B,S,1,d/2]
     sin = amp * jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:d]
     x32_1, x32_2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(
         [x32_1 * cos - x32_2 * sin, x32_2 * cos + x32_1 * sin], axis=-1
     )
+    if d < x.shape[-1]:
+        return jnp.concatenate([out.astype(x.dtype), x[..., d:]], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -374,34 +476,103 @@ def _paged_cache_missing():
     )
 
 
+#: A prefill that starts a cache attends its own chunk. Where the
+#: chunk's scores against the whole row cache ([B, H, S, max_seq_len]
+#: float32) would pass this many bytes, the chunk is attended in blocks
+#: of ``PREFILL_BLOCK`` queries instead, each against the keys it can
+#: see. Chosen from the static shapes of the program being traced; a
+#: 512-token window over a 1,024-position cache at 32 heads is 67 MB
+#: and keeps the one dense pass, a 4,096-token window at 64 heads would
+#: be 5.4 GB.
+PREFILL_SCORE_BYTES = 256 << 20
+PREFILL_BLOCK = 256
+
+
+def _blocked_attention(q, k, v, valid, window: int, block: int):
+    """Causal grouped-query attention of a chunk over itself, a block
+    of queries at a time, so that no [H, S, S] tensor exists. q: [B, S,
+    H, D]; k, v: [B, S, Hkv, D] in slot order; valid: [B, S] bool (a
+    left-padded prompt's real slots). A block of queries meets the
+    keys up to its own last slot: all of them on a layer that keeps the
+    context, and on a layer with a ``window`` only the band, from the
+    block start that holds its first query's oldest visible key."""
+    s = q.shape[1]
+    out = []
+    for at in range(0, s, block):
+        end = min(at + block, s)
+        low = max(at - (window - 1), 0) // block * block if window else 0
+        q_slot = jnp.arange(at, end)[:, None]
+        kv_slot = jnp.arange(low, end)[None, :]
+        mask = (kv_slot <= q_slot)[None] & valid[:, None, low:end]
+        if window:
+            mask = mask & (q_slot - kv_slot < window)[None]
+        out.append(_gqa_decode_attention(
+            q[:, at:end], k[:, low:end], v[:, low:end], mask[:, None]
+        ))
+    return jnp.concatenate(out, axis=1)
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
+    #: Which layer this is: ``cfg.layer_spec(layer)`` says what it is
+    #: made of where the layers differ.
+    layer: int = 0
 
     @nn.compact
     def __call__(
         self, hidden, positions, kv_mask=None, decode: bool = False,
         paged=None, adapters=None,
     ):
+        cfg = self.cfg
+        # Layers that differ say which kind they are in a trace.
+        scope = (
+            jax.named_scope(cfg.layer_spec(self.layer).scope)
+            if cfg.layer_types is not None else contextlib.nullcontext()
+        )
+        with scope:
+            return self._attend(
+                hidden, positions, kv_mask, decode, paged, adapters
+            )
+
+    def _attend(self, hidden, positions, kv_mask, decode, paged, adapters):
         from tpudl.models.lora import adapter_delta
 
         cfg = self.cfg
+        spec = cfg.layer_spec(self.layer)
         B, S, _ = hidden.shape
-        hd = cfg.head_dim
+        hd, H, window = cfg.head_dim, spec.num_heads, spec.window
         # Multi-tenant adapters (tpudl.models.lora.AdapterView): each
         # slot's per-tenant LoRA delta rides AFTER the shared base
         # projection — one segmented-kernel dispatch per site, base
         # weights (full-precision or quantized) resident exactly once.
-        q = _proj(cfg, cfg.num_heads * hd, "q_proj")(hidden)
+        q = _proj(cfg, H * hd, "q_proj")(hidden)
         q = q + adapter_delta(adapters, "q_proj", hidden)
         k = _proj(cfg, cfg.num_kv_heads * hd, "k_proj")(hidden)
         k = k + adapter_delta(adapters, "k_proj", hidden)
         v = _proj(cfg, cfg.num_kv_heads * hd, "v_proj")(hidden)
         v = v + adapter_delta(adapters, "v_proj", hidden)
-        q = q.reshape(B, S, cfg.num_heads, hd)
+        q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, cfg.num_kv_heads, hd)
         v = v.reshape(B, S, cfg.num_kv_heads, hd)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q = rope(q, positions, spec.rope_theta, spec.rope_scaling,
+                 spec.rotary_dim)
+        k = rope(k, positions, spec.rope_theta, spec.rope_scaling,
+                 spec.rotary_dim)
+
+        def project_out(ctx):
+            """[B, S, H, hd] -> the layer's output: the gate by head
+            where the layer has one, then ``o_proj``."""
+            if spec.gate:
+                with jax.named_scope("gate"):
+                    gate = jax.nn.sigmoid(nn.Dense(
+                        H, use_bias=False, dtype=cfg.dtype,
+                        kernel_init=nn.initializers.normal(0.02),
+                        name="g_proj",
+                    )(hidden).astype(jnp.float32))
+                    ctx = (ctx * gate[..., None]).astype(ctx.dtype)
+            ctx = ctx.reshape(B, S, H * hd)
+            out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
+            return out + adapter_delta(adapters, "o_proj", ctx)
 
         if decode and paged is not None:
             # Paged decode (tpudl.models.paged): KV lives in page pools
@@ -422,9 +593,20 @@ class LlamaAttention(nn.Module):
             # causal within itself: query j attends up to lens + j);
             # prefill stays dense batch-1 (its row cache is scattered
             # into pages by PagedKVCache.seat).
+            # A layer with a window keeps a RING of pages a slot in a
+            # pool of its own; write and read address it through the
+            # rotated view (PagedView.ring_view), as any other table.
             from tpudl.models.paged import paged_write
             from tpudl.ops.paged_attention import paged_attention
 
+            if window:
+                if S > 1:
+                    raise ValueError(
+                        "a window layer steps one token at a time: a "
+                        "chunk's queries would each need their own "
+                        "window over the ring"
+                    )
+                paged = paged.ring_view(window)
             pk = self.variable("cache", "pages_k", _paged_cache_missing)
             pv = self.variable("cache", "pages_v", _paged_cache_missing)
             sk = sv = None
@@ -445,9 +627,7 @@ class LlamaAttention(nn.Module):
                 scale_k=sk.value if sk is not None else None,
                 scale_v=sv.value if sv is not None else None,
             )
-            ctx = ctx.reshape(B, S, cfg.num_heads * hd)
-            out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
-            return out + adapter_delta(adapters, "o_proj", ctx)
+            return project_out(ctx)
 
         if decode:
             # KV cache (flax decode idiom): static [B, max_seq, Hkv, D]
@@ -455,6 +635,7 @@ class LlamaAttention(nn.Module):
             # autoregressive serving path (the reference repo's entire
             # substance is inference benchmarking; this is its decoder
             # analog). Shapes stay static so the step jits once.
+            fresh = not self.has_variable("cache", "k")
             ck = self.variable(
                 "cache", "k",
                 jnp.zeros, (B, cfg.max_seq_len, cfg.num_kv_heads, hd), k.dtype,
@@ -475,6 +656,13 @@ class LlamaAttention(nn.Module):
             idx = self.variable(
                 "cache", "index", lambda: jnp.zeros((), jnp.int32)
             )
+            if window:
+                # What the layer keeps, declared beside its rows: the
+                # page manager sizes this layer's pool as rings
+                # (tpudl.serve.cache: the leaf's LENGTH is the window).
+                self.variable(
+                    "cache", "window", jnp.zeros, (window,), jnp.int8
+                )
             start = idx.value
             ck.value = jax.lax.dynamic_update_slice(
                 ck.value, k, (0, start, 0, 0)
@@ -491,6 +679,14 @@ class LlamaAttention(nn.Module):
                 cvalid.value, chunk_valid, (0, start)
             )
             idx.value = start + S
+            if fresh and (
+                4 * B * H * S * cfg.max_seq_len > PREFILL_SCORE_BYTES
+            ):
+                # A long prompt into an empty cache: the chunk is all
+                # there is to attend to, and it is attended in blocks.
+                return project_out(_blocked_attention(
+                    q, k, v, chunk_valid, window, PREFILL_BLOCK
+                ))
             k, v = ck.value, cv.value
             # Attend to slots that are (a) causally prior in WRITE order —
             # slots fill in token order, so slot order IS causal order
@@ -499,20 +695,25 @@ class LlamaAttention(nn.Module):
             kv_slot = jnp.arange(cfg.max_seq_len)[None, None, None, :]
             q_slot = (start + jnp.arange(S))[None, None, :, None]
             mask = (kv_slot <= q_slot) & cvalid.value[:, None, None, :]
-        else:
-            mask = None
-
-        if decode:
+            if window:
+                mask = mask & (q_slot - kv_slot < window)
             # Grouped-query attention against the UNEXPANDED cache — never
             # materialize [B, max_seq, H, D] (the 4x KV blowup per decode
             # step that GQA exists to avoid).
-            ctx = _gqa_decode_attention(q, k, v, mask)
-            ctx = ctx.reshape(B, S, cfg.num_heads * hd)
-            out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
-            return out + adapter_delta(adapters, "o_proj", ctx)
+            return project_out(_gqa_decode_attention(q, k, v, mask))
 
-        if cfg.num_kv_heads != cfg.num_heads:  # GQA: expand kv heads
-            reps = cfg.num_heads // cfg.num_kv_heads
+        if window:
+            # Training / scoring with a window: the band under an
+            # explicit mask, in blocks.
+            valid = (
+                jnp.ones((B, S), jnp.bool_) if kv_mask is None
+                else kv_mask.astype(jnp.bool_)
+            )
+            return project_out(_blocked_attention(
+                q, k, v, valid, window, PREFILL_BLOCK
+            ))
+        if cfg.num_kv_heads != H:  # GQA: expand kv heads
+            reps = H // cfg.num_kv_heads
             k = jnp.repeat(k, reps, axis=2)
             v = jnp.repeat(v, reps, axis=2)
         q = constrain(q, ("dp", "fsdp"), "sp", "tp", None)
@@ -526,9 +727,7 @@ class LlamaAttention(nn.Module):
             q, k, v, mask=kv_mask, causal=True,
             implementation=cfg.attention_impl,
         )
-        ctx = ctx.reshape(B, S, cfg.num_heads * hd)
-        out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
-        return out + adapter_delta(adapters, "o_proj", ctx)
+        return project_out(ctx)
 
 
 class LatentAttention(nn.Module):
@@ -753,6 +952,8 @@ class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     #: "dense" or "moe" (``LlamaConfig.mlp_kind`` of this layer).
     mlp: str = "dense"
+    #: The layer's index, for ``LlamaConfig.layer_spec``.
+    layer: int = 0
 
     @nn.compact
     def __call__(
@@ -765,8 +966,11 @@ class LlamaBlock(nn.Module):
         from tpudl.ops.norms import fused_ops_impl
 
         impl = fused_ops_impl(cfg.fused_ops)
-        attention = LatentAttention if cfg.attention == "mla" else LlamaAttention
-        attn = attention(cfg, name="attention")(
+        if cfg.attention == "mla":
+            attention = LatentAttention(cfg, name="attention")
+        else:
+            attention = LlamaAttention(cfg, self.layer, name="attention")
+        attn = attention(
             RMSNorm(cfg.rms_norm_eps, impl, name="input_norm")(hidden),
             positions,
             kv_mask,
@@ -876,7 +1080,7 @@ class LlamaModel(nn.Module):
             # are decode-only (serving), and decode skips remat.
             block = nn.remat(LlamaBlock, static_argnums=(4, 5))
         for i in range(cfg.num_layers):
-            x = block(cfg, cfg.mlp_kind(i), name=f"layer_{i}")(
+            x = block(cfg, cfg.mlp_kind(i), i, name=f"layer_{i}")(
                 x, positions, kv_mask, decode, paged,
                 adapters.for_layer(f"layer_{i}")
                 if adapters is not None
@@ -896,11 +1100,15 @@ class LlamaForCausalLM(nn.Module):
     @nn.compact
     def __call__(
         self, input_ids, attention_mask=None, decode=False, positions=None,
-        paged=None, adapters=None,
+        paged=None, adapters=None, last_only: bool = False,
     ):
         x = LlamaModel(self.cfg, name="model")(
             input_ids, attention_mask, decode, positions, paged, adapters
         )
+        if last_only:
+            # A caller that reads the last position's logits alone (a
+            # long prefill) says so: the head then runs on one row.
+            x = x[:, -1:]
         logits = nn.Dense(
             self.cfg.vocab_size,
             use_bias=False,

@@ -91,6 +91,12 @@ class PagedView:
     traced: one entry a paged attention layer, True where the layer
     reads the pool in place (tpudl.ops.paged_attention) and False
     where it gathers.
+
+    A cache with WINDOW layers (``PagedKVCache.window``) hands
+    ``page_table`` over as the pair ``(page_table, ring_table)``: the
+    second, [B, R] int32, maps each slot's ring of ``R = window /
+    page_size + 1`` pages in the window layers' own, smaller pools. A
+    window layer addresses its pool through ``ring_view``.
     """
 
     page_table: jax.Array
@@ -100,11 +106,65 @@ class PagedView:
     quantized: bool
     sharded: bool = False
     took: list = dataclasses.field(default_factory=list)
+    ring_table: Optional[jax.Array] = None
+
+    def __post_init__(self):
+        if isinstance(self.page_table, (tuple, list)):
+            self.page_table, self.ring_table = self.page_table
+        self._rings: dict = {}
 
     @property
     def logical_len(self) -> int:
         """Positions addressable per slot: pages_per_slot x page_size."""
         return int(self.page_table.shape[1]) * self.page_size
+
+    def ring_view(self, window: int) -> "PagedView":
+        """The view a layer that keeps only the last ``window``
+        positions addresses its ring by: an ordinary view over a table
+        of R pages, so the write, the gather, the mask and the
+        in-place kernel serve it unchanged.
+
+        Logical position ``t`` of a slot lies on ring page ``(t //
+        page_size) mod R``. A step at position ``lens`` attends
+        ``[first, lens]`` with ``first = max(start, lens - window +
+        1)``, at most R consecutive logical pages from ``first //
+        page_size`` on; the ring table is ROTATED so that this page
+        comes first, and ``start`` / ``lens`` are taken relative to it
+        (RoPE was applied before the cache, so attention needs no
+        absolute position). The page just behind the window is the one
+        the next page's first write lands on: its old rows lie past the
+        relative ``lens`` until they are overwritten, and are masked
+        like any unwritten row. Derived on the device from the three
+        small inputs (a [B, R] gather), once a program."""
+        view = self._rings.get(window)
+        if view is None:
+            if self.ring_table is None:
+                raise ValueError(
+                    "a window layer needs the cache's ring table: build "
+                    "the pools with tpudl.serve.cache.PagedKVCache from "
+                    "this model's own prefill template"
+                )
+            ps, ring = self.page_size, int(self.ring_table.shape[1])
+            if ring * ps < window + ps - 1:
+                raise ValueError(
+                    f"a ring of {ring} pages of {ps} cannot hold a "
+                    f"window of {window}"
+                )
+            first = jnp.maximum(self.start, self.lens - (window - 1))
+            page0 = first // ps
+            turn = (
+                page0[:, None]
+                + jnp.arange(ring, dtype=page0.dtype)[None, :]
+            ) % ring
+            view = self._rings[window] = PagedView(
+                page_table=jnp.take_along_axis(
+                    self.ring_table, turn, axis=1
+                ),
+                start=first - page0 * ps, lens=self.lens - page0 * ps,
+                page_size=ps, quantized=self.quantized,
+                sharded=self.sharded, took=self.took,
+            )
+        return view
 
 
 def quantize_kv(x: jax.Array):

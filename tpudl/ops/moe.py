@@ -196,6 +196,12 @@ class _ExpertKernel(nn.Module):
         return kernel.astype(self.dtype), None
 
 
+#: Rows of a traced program past which the experts are dispatched by
+#: sorted groups (``DroplessMoE``): about four passes of the weights
+#: through the dense form on a v5e.
+SORTED_DISPATCH_ROWS = 1024
+
+
 class DroplessMoE(nn.Module):
     """Routed SwiGLU experts with no capacity and no dropped token, as
     serving needs them (a dropped token changes the served logits), for
@@ -213,12 +219,31 @@ class DroplessMoE(nn.Module):
     arrives from the chips that hold them, and no code here stands in
     for them. The shared expert is computed once, by every share.
 
-    Dispatch is a ``[tokens, held]`` matrix of gates and the experts
-    run as one grouped matmul over the experts held (``emh``): every
-    held expert's weights pass the matrix unit once a call, which is
-    what a call costs while an expert sees fewer tokens than the unit
-    has rows; the gates fold into the down-projection's contraction,
-    so no ``[held, tokens, hidden]`` tensor is made.
+    Two dispatch forms compute the same sum over the same parameter
+    tree, and the layer takes one by the STATIC row count of the
+    program it is traced in (``dispatch="auto"``; no knob reaches it):
+
+    - ``"dense"``: a ``[tokens, held]`` matrix of gates and the experts
+      run as one grouped matmul over the experts held (``emh``): every
+      held expert's weights pass the matrix unit once a call, which is
+      what a call costs while an expert sees fewer tokens than the unit
+      has rows; the gates fold into the down-projection's contraction,
+      so no ``[held, tokens, hidden]`` tensor is made. Its operations
+      grow with rows x held experts.
+    - ``"sorted"``: the ``tokens x experts_per_token`` assignments are
+      sorted by expert, the rows gathered in that order, and the three
+      projections run as ragged grouped matmuls over the sorted groups
+      (``jax.lax.ragged_dot``): each row meets only the experts it
+      chose. A choice of an expert held elsewhere sorts behind the
+      last group and is left out. The results go back to token order
+      and are summed by token in float32.
+
+    The dense form does ``rows`` operations for every byte of weights
+    it streams, so it costs one pass of the weights up to about 240
+    rows on a v5e (197 TFLOP/s over 819 GB/s); past
+    ``SORTED_DISPATCH_ROWS``, a few such passes, the sorted form is
+    taken. The counters ``serve_moe_dispatch_dense`` /
+    ``serve_moe_dispatch_sorted`` count the layers traced each way.
 
     ``real`` ([B, S] bool) marks the tokens that count (not padding,
     not an idle slot); the int32 ``[held]`` count of real tokens per
@@ -232,6 +257,10 @@ class DroplessMoE(nn.Module):
     experts_held: Any = None
     dtype: Any = jnp.bfloat16
     weight_dtype: Any = None
+    #: "auto" (by the traced program's rows), or one form by name: the
+    #: seam tests and measurements hold the two forms against each
+    #: other through.
+    dispatch: str = "auto"
 
     def _dense(self, features: int, name: str):
         kwargs = dict(
@@ -271,29 +300,74 @@ class DroplessMoE(nn.Module):
                 gates = self.routed_scaling_factor * picked / jnp.sum(
                     picked, axis=-1, keepdims=True
                 )
-                # [T, k, held]: which held expert each choice names.
-                hit = (chosen - first)[..., None] == jnp.arange(count)
-                combine = jnp.sum(hit * gates[..., None], axis=1)  # [T, held]
-                counts = jnp.sum(
-                    hit.any(axis=1) & real.reshape(-1, 1), axis=0,
-                    dtype=jnp.int32,
-                )
+                dispatch = self.dispatch
+                if dispatch == "auto":
+                    dispatch = (
+                        "sorted" if b * s > SORTED_DISPATCH_ROWS else "dense"
+                    )
+                from tpudl.obs import registry
+
+                registry().counter(f"serve_moe_dispatch_{dispatch}").inc()
+                if dispatch == "dense":
+                    # [T, k, held]: which held expert each choice names.
+                    hit = (chosen - first)[..., None] == jnp.arange(count)
+                    combine = jnp.sum(hit * gates[..., None], axis=1)  # [T, held]
+                    counts = jnp.sum(
+                        hit.any(axis=1) & real.reshape(-1, 1), axis=0,
+                        dtype=jnp.int32,
+                    )
+                else:
+                    # No [T, k, held] tensor at these row counts: the
+                    # held choices of the real tokens, counted by expert.
+                    local = chosen - first
+                    held = (local >= 0) & (local < count)
+                    counts = jnp.zeros((count + 1,), jnp.int32).at[
+                        jnp.where(held & real.reshape(-1, 1), local, count)
+                    ].add(1)[:count]
                 self.sow("moe_stats", "tokens_per_expert", counts)
             with jax.named_scope("experts"):
                 wg, sg = _ExpertKernel((count, m, h), self.dtype, name="gate_proj")()
                 wu, su = _ExpertKernel((count, m, h), self.dtype, name="up_proj")()
                 wd, sd = _ExpertKernel((count, h, m), self.dtype, name="down_proj")()
-                gate = jnp.einsum("tm,emh->eth", tokens, wg)
-                up = jnp.einsum("tm,emh->eth", tokens, wu)
+                if dispatch == "dense":
+                    gate = jnp.einsum("tm,emh->eth", tokens, wg)
+                    up = jnp.einsum("tm,emh->eth", tokens, wu)
+                    weight = combine.T[..., None]  # [held, T, 1]
+                else:
+                    # Assignment a = token a // k, choice a % k. Sorted
+                    # by the held expert it names; those held elsewhere
+                    # go behind the last group, which no group covers.
+                    k = self.experts_per_token
+                    key = jnp.where(held, local, count).reshape(-1)
+                    order = jnp.argsort(key)
+                    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(
+                        1
+                    )[:count]
+                    rows = tokens[order // k]  # [T * k, m]
+                    gate = jax.lax.ragged_dot(rows, wg, sizes)
+                    up = jax.lax.ragged_dot(rows, wu, sizes)
+                    weight = gates.reshape(-1)[order, None]  # [T * k, 1]
                 if sg is not None:
                     gate = gate * sg.astype(gate.dtype)
                     up = up * su.astype(up.dtype)
                 act = nn.silu(gate) * up
-                act = act * combine.T[..., None].astype(act.dtype)
-                routed = jnp.einsum(
-                    "eth,ehm->tm", act, wd,
-                    preferred_element_type=jnp.float32,
-                )
+                act = act * weight.astype(act.dtype)
+                if dispatch == "dense":
+                    routed = jnp.einsum(
+                        "eth,ehm->tm", act, wd,
+                        preferred_element_type=jnp.float32,
+                    )
+                else:
+                    out = jax.lax.ragged_dot(
+                        act, wd, sizes, preferred_element_type=jnp.float32
+                    )
+                    # Rows past the groups are no expert's: left out.
+                    out = jnp.where(
+                        (key[order] < count)[:, None], out, 0.0
+                    )
+                    routed = out[jnp.argsort(order)].reshape(
+                        b * s, k, m
+                    ).sum(axis=1)
                 if sd is not None:
                     routed = routed * sd
                 routed = routed.astype(self.dtype)

@@ -410,6 +410,22 @@ class ServeSession:
                     "experts: the verify step does not report the "
                     "tokens per expert of its window"
                 )
+        if getattr(cfg, "window_layers", 0):
+            # Window layers keep rings of pages under a table of their
+            # own (PagedKVCache): what walks ONE table over every
+            # layer, or steps several tokens a slot, says so here.
+            for what, asked in (
+                ("prefix_share", prefix_share), ("spec_k", spec_k),
+                ("per-tenant adapters", adapters is not None),
+                ("a mesh-committed pool", mesh is not None),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{what} is not wired to window layers: a "
+                        f"sliding-attention layer's cache is a ring of "
+                        f"its last {cfg.sliding_window} positions a "
+                        f"slot, stepped one token at a time on one chip"
+                    )
         pf = prefill_fn(model)
         ids = jax.ShapeDtypeStruct((num_slots, prompt_len), jnp.int32)
         _, cache_template, *_ = jax.eval_shape(pf, params, ids, ids)

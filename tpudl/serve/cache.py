@@ -38,8 +38,11 @@ def _is_valid_leaf(leaf) -> bool:
 
 
 #: The leaves of a dense decode cache that are not rows: which
-#: positions are real, and where the next chunk is written.
-_ROW_META = frozenset({"valid", "index"})
+#: positions are real, and where the next chunk is written; and, on a
+#: layer that keeps a window of the context only, ``window``, whose
+#: LENGTH is the window (a template keeps shapes, not values).
+_ROW_BOOKKEEPING = frozenset({"valid", "index"})
+_ROW_META = _ROW_BOOKKEEPING | {"window"}
 
 
 def _is_attn_cache(node) -> bool:
@@ -53,9 +56,15 @@ def _is_attn_cache(node) -> bool:
     from collections.abc import Mapping
 
     return (
-        isinstance(node, Mapping) and set(node) > _ROW_META
+        isinstance(node, Mapping) and set(node) > _ROW_BOOKKEEPING
         and all(hasattr(v, "shape") for v in node.values())
     )
+
+
+def _window_of(attn) -> int:
+    """The window a layer's dense cache declares (0: the whole
+    context)."""
+    return int(attn["window"].shape[0]) if "window" in attn else 0
 
 
 def _row_names(attn) -> list:
@@ -465,6 +474,25 @@ class PagedKVCache:
     which is XLA (or a program jitted without ``donate_argnums``)
     copying after all; it reads 0.
 
+    **Two groups of layers under one manager.** A layer that declares
+    a ``window`` (its dense cache's ``window`` leaf: sliding-window
+    attention) needs the last ``window`` positions only, so its pool
+    is a pool of RINGS: ``ring_pages = ceil(window / page_size) + 1``
+    pages a slot, ``num_slots * ring_pages + 1`` in all, whatever the
+    context, against ``pages_per_slot`` a slot for a layer that keeps
+    the context. Position ``t`` lies on ring page ``(t // page_size)
+    mod ring_pages``. One manager holds both: ``seat`` reserves the
+    full-context pages and takes a ring, scatters the whole prompt
+    into the first and the prompt's last ``ring_pages`` pages into the
+    second; ``free`` returns both; ``start`` / ``lens`` are shared;
+    the decode program gets ``(page_table, ring_table)`` and a window
+    layer addresses its ring through
+    tpudl.models.paged.PagedView.ring_view. ``pages_reserved`` /
+    ``tokens_live`` count the full-context group,
+    ``pages_reserved_window`` / ``tokens_live_window`` the rings. What
+    is not wired to rings says so: prefix sharing, migration and a
+    mesh-committed pool raise with a sentence.
+
     ``prefix_share=True`` adds the RADIX layer (``RadixPrefixTree``):
     seating goes LEFT-ALIGNED through ``seat_shared`` — token ``i`` at
     logical position ``i``, so identical token prefixes are
@@ -527,11 +555,34 @@ class PagedKVCache:
                 f"(pages_per_slot={self.pages_per_slot} + trash page)"
             )
         self.num_pages = int(num_pages)
+        # The window group: layers whose dense cache declares a window.
+        windows = set()
+        _map_attn_caches(
+            template, lambda attn: windows.add(_window_of(attn)) or attn
+        )
+        windows.discard(0)
+        if len(windows) > 1:
+            raise ValueError(
+                f"one page manager keeps one ring size: the layers "
+                f"declare windows {sorted(windows)}"
+            )
+        self.window = windows.pop() if windows else 0
+        if self.window and prefix_share:
+            raise ValueError(
+                "prefix sharing is not wired to window layers: a ring "
+                "holds a slot's last positions, which no other slot's "
+                "prefix can map"
+            )
+        self.ring_pages = (
+            -(-self.window // self.page_size) + 1 if self.window else 0
+        )
+        self.num_ring_pages = self.num_slots * self.ring_pages + 1
 
         def to_pool(attn: dict) -> dict:
             from tpudl.models.paged import page_fold
 
-            page = (self.num_pages, self.page_size)
+            pages = self.num_ring_pages if _window_of(attn) else self.num_pages
+            page = (pages, self.page_size)
             pool = {}
             for name in _row_names(attn):
                 row = attn[name]
@@ -542,7 +593,7 @@ class PagedKVCache:
                 # wide is folded, ``fold`` positions to a held row.
                 fold = page_fold(self.page_size, tail, dtype)
                 pool[f"pages_{name}"] = jnp.zeros(
-                    (self.num_pages, self.page_size // fold)
+                    (pages, self.page_size // fold)
                     + tail[:-1] + (fold * tail[-1],),
                     dtype,
                 )
@@ -562,6 +613,14 @@ class PagedKVCache:
         self._reserved: dict = {}
         self.page_table = np.zeros(
             (self.num_slots, self.pages_per_slot), np.int32
+        )
+        # The rings' own addressing (page 0 of a ring pool is its trash
+        # page): a slot takes ``ring_pages`` pages at seat and returns
+        # them at free.
+        self._free_ring: list = list(range(1, self.num_ring_pages))
+        self._rings: dict = {}
+        self.ring_table = np.zeros(
+            (self.num_slots, self.ring_pages), np.int32
         )
         self.start = np.zeros((self.num_slots,), np.int32)
         self.lens = np.zeros((self.num_slots,), np.int32)
@@ -641,6 +700,11 @@ class PagedKVCache:
         obj.page_table = np.zeros(
             (obj.num_slots, obj.pages_per_slot), np.int32
         )
+        # An exported artifact has one table: no window group.
+        obj.window = obj.ring_pages = 0
+        obj.num_ring_pages = 1
+        obj._free_ring, obj._rings = [], {}
+        obj.ring_table = np.zeros((obj.num_slots, 0), np.int32)
         obj.start = np.zeros((obj.num_slots,), np.int32)
         obj.lens = np.zeros((obj.num_slots,), np.int32)
         obj._reset_occupancy()
@@ -684,7 +748,19 @@ class PagedKVCache:
 
         self.pages_reserved = 0
         self.tokens_live = 0
+        self.pages_reserved_window = 0
         self._slot_pages = np.zeros((self.num_slots,), np.int64)
+
+    @property
+    def tokens_live_window(self) -> int:
+        """Positions a decode step reads in ONE window layer: the sum
+        over seated slots of ``min(lens - start, window)`` (an idle
+        slot's ``lens`` and ``start`` are 0)."""
+        import numpy as np
+
+        if not self.window:
+            return 0
+        return int(np.minimum(self.lens - self.start, self.window).sum())
 
     def _seated(self, slot: int, pages: int) -> None:
         """``slot`` was just seated on ``pages`` pages, with its
@@ -728,7 +804,10 @@ class PagedKVCache:
         logical positions be seated right now? Reservation up front
         means yes here == never strands mid-decode. Radix sessions use
         ``fits_request`` instead — it credits the cached prefix."""
-        return self.pages_needed(tokens) <= self.available_pages
+        return (
+            self.pages_needed(tokens) <= self.available_pages
+            and self.ring_pages <= len(self._free_ring)
+        )
 
     def fits_request(self, input_ids, tokens: int) -> bool:
         """Radix-mode admission: matched prefix pages map for free, so
@@ -786,10 +865,12 @@ class PagedKVCache:
                 f"per-slot bound {self.max_seq_len}"
             )
         n = self.pages_needed(reserve_tokens)
-        if n > len(self._free):
+        if n > len(self._free) or self.ring_pages > len(self._free_ring):
             raise RuntimeError(
-                f"page pool exhausted: need {n} pages, {len(self._free)} "
-                f"free (admission should have checked fits_tokens)"
+                f"page pool exhausted: need {n} pages and a ring of "
+                f"{self.ring_pages}, {len(self._free)} and "
+                f"{len(self._free_ring)} free (admission should have "
+                f"checked fits_tokens)"
             )
         pages = [self._free.pop() for _ in range(n)]
         self._reserved[slot] = pages
@@ -799,10 +880,27 @@ class PagedKVCache:
         self.lens[slot] = prompt_len
         self._seated(slot, len(pages))
         prompt_pages = self.pages_needed(prompt_len)
+        page_ids = jnp.asarray(pages[:prompt_pages], jnp.int32)
+        if self.window:
+            # The prompt's last pages go where the ring keeps them:
+            # logical page j on ring page j mod ring_pages.
+            ring = [self._free_ring.pop() for _ in range(self.ring_pages)]
+            self._rings[slot] = ring
+            self.ring_table[slot] = ring
+            self.pages_reserved_window += self.ring_pages
+            kept = range(self._first_kept_page(prompt_pages), prompt_pages)
+            page_ids = (page_ids, jnp.asarray(
+                [ring[j % self.ring_pages] for j in kept], jnp.int32
+            ))
         self._replace_pool(
-            self._seat_program(prompt_pages), row_cache,
-            jnp.asarray(pages[:prompt_pages], jnp.int32),
+            self._seat_program(prompt_pages), row_cache, page_ids
         )
+
+    def _first_kept_page(self, prompt_pages: int) -> int:
+        """The first of a prompt's pages that a ring keeps: its last
+        ``ring_pages``, which hold the last ``window`` positions
+        wherever the prompt ends in its page."""
+        return max(prompt_pages - self.ring_pages, 0)
 
     def _seat_program(self, prompt_pages: int):
         """The jitted, pool-donating scatter for prompts of
@@ -821,15 +919,25 @@ class PagedKVCache:
 
         ps, quantized = self.page_size, self.quantized
         span = prompt_pages * ps
+        # Where a window layer's kept pages start in the row.
+        kept_from = self._first_kept_page(prompt_pages) * ps
 
         def tpudl_seat(pool_tree, row_tree, page_ids):
+            # With a window group: (the table's pages, the ring's).
+            table_ids, ring_ids = (
+                page_ids if isinstance(page_ids, tuple) else (page_ids, None)
+            )
+
             def one(pool: dict, row: dict) -> dict:
                 out = dict(pool)
+                ids, first = table_ids, 0
+                if _window_of(row):
+                    ids, first = ring_ids, kept_from
                 for kv in _row_names(row):
                     name, sname = f"pages_{kv}", f"scale_{kv}"
                     rowvals = row[kv]
                     take = min(span, rowvals.shape[1])
-                    blocks = rowvals[0, :take]
+                    blocks = rowvals[0, first:take]
                     if take < span:
                         # page_size doesn't divide the model bound: the
                         # last prompt page extends past the dense row.
@@ -842,8 +950,8 @@ class PagedKVCache:
                         )
                     if quantized:
                         blocks, s = quantize_kv(blocks)
-                        out[sname] = _set_pages(out[sname], page_ids, s)
-                    out[name] = _set_pages(out[name], page_ids, blocks)
+                        out[sname] = _set_pages(out[sname], ids, s)
+                    out[name] = _set_pages(out[name], ids, blocks)
                 return out
 
             with jax.named_scope("kv_scatter"):
@@ -1061,6 +1169,11 @@ class PagedKVCache:
         pages = self._reserved.pop(slot, None)
         if pages:
             self._free.extend(pages)
+        ring = self._rings.pop(slot, None)
+        if ring:
+            self._free_ring.extend(ring)
+            self.pages_reserved_window -= len(ring)
+            self.ring_table[slot, :] = 0
         self.pages_reserved -= int(self._slot_pages[slot])
         self._slot_pages[slot] = 0
         self.tokens_live -= int(self.lens[slot]) - int(self.start[slot])
@@ -1107,6 +1220,7 @@ class PagedKVCache:
         tpudl.ft.store applied to a transfer."""
         import numpy as np
 
+        self._no_rings("migration")
         if slot not in self._reserved and slot not in self._leases:
             raise ValueError(f"slot {slot} is not seated")
         lens = int(self.lens[slot])
@@ -1168,6 +1282,7 @@ class PagedKVCache:
         (the engine rebuilds its slot state from it)."""
         import numpy as np
 
+        self._no_rings("migration")
         meta = payload if isinstance(payload, dict) else parse_migration(payload)
         matched_pages: list = []
         deepest = None
@@ -1307,16 +1422,26 @@ class PagedKVCache:
             filled.append(buf)
         return jax.tree_util.tree_unflatten(treedef, filled)
 
+    def _no_rings(self, what: str) -> None:
+        """``what`` walks ONE table over every layer's pool."""
+        if self.window:
+            raise ValueError(
+                f"{what} is not wired to window layers: their pools are "
+                f"rings of {self.ring_pages} pages a slot under a table "
+                f"of their own"
+            )
+
     # -- per-dispatch addressing ---------------------------------------
 
     def dispatch_args(self):
         """The three small traced inputs each paged decode dispatch
-        takes: (page_table [B, P], start [B], lens [B]) as int32."""
-        return (
-            jnp.asarray(self.page_table),
-            jnp.asarray(self.start),
-            jnp.asarray(self.lens),
-        )
+        takes: (page_table [B, P], start [B], lens [B]) as int32; with
+        a window group the first is the pair (page_table, ring_table
+        [B, ring_pages]) (tpudl.models.paged.PagedView)."""
+        table = jnp.asarray(self.page_table)
+        if self.window:
+            table = (table, jnp.asarray(self.ring_table))
+        return table, jnp.asarray(self.start), jnp.asarray(self.lens)
 
     def decode(self, program, params, tokens, positions, *extra):
         """Dispatch one program of the paged decode contract
@@ -1372,6 +1497,8 @@ class PagedKVCache:
         pool in place would have it gathered whole to every chip, so
         those programs keep the dense gather."""
         from jax.sharding import NamedSharding, PartitionSpec
+
+        self._no_rings("a pool committed to a mesh")
 
         def place(leaf):
             # pages_k/v [pages, page, Hkv, D]; scale_k/v [pages, page, Hkv]
@@ -1429,7 +1556,8 @@ class PagedKVCache:
             sum(leaf.nbytes for leaf in jax.tree.leaves(self.cache))
         )
         host = (
-            self.page_table.nbytes + self.start.nbytes + self.lens.nbytes
+            self.page_table.nbytes + self.ring_table.nbytes
+            + self.start.nbytes + self.lens.nbytes
         )
         return device + host
 

@@ -783,6 +783,14 @@ class Engine:
         )
         reg.gauge("serve_kv_pages_reserved").set(self.cache.pages_reserved)
         reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
+        if self.cache.window:
+            # The window layers' rings, beside the full-context group.
+            reg.gauge("serve_kv_pages_reserved_window").set(
+                self.cache.pages_reserved_window
+            )
+            reg.gauge("serve_kv_tokens_live_window").set(
+                self.cache.tokens_live_window
+            )
         # Layers of the decode program whose attention reads the pool
         # in place (0 until its first dispatch traced it).
         reg.gauge("serve_paged_attention_in_place").set(
@@ -803,11 +811,17 @@ class Engine:
         = held as declared) and the pages an in-place step visits,
         counted on the host at dispatch."""
         cache = self.cache
-        return {"pages_reserved": cache.pages_reserved,
-                "tokens_live": cache.tokens_live,
-                "kv_in_place": int(cache.in_place_layers > 0),
-                "kv_fold": max(cache.folds),
-                "pages_live": pages_live}
+        attrs = {"pages_reserved": cache.pages_reserved,
+                 "tokens_live": cache.tokens_live,
+                 "kv_in_place": int(cache.in_place_layers > 0),
+                 "kv_fold": max(cache.folds),
+                 "pages_live": pages_live}
+        if cache.window:
+            # The same two of the window layers' rings: pages held, and
+            # positions ONE such layer reads (at most its window a slot).
+            attrs["pages_reserved_window"] = cache.pages_reserved_window
+            attrs["tokens_live_window"] = cache.tokens_live_window
+        return attrs
 
     def _fits(self, request) -> bool:
         """Can this request be seated RIGHT NOW? Its worst case fits
