@@ -15,6 +15,11 @@ rule as a table, and every path that touches the pool at a second tiny
 size whose row the rule FOLDS (64 wide on pages of 4: two positions a
 held row of 128 lanes), held to the same reference and to the pool
 held as declared.
+
+Reading the held pool in place (ISSUE 31): a session of that second
+size on pages of 16 (a held page of 8 rows of 128 lanes) serves the
+same tokens through the latent kernel of ``tpudl.ops.paged_attention``
+as through the gather, and says which path it took.
 """
 
 import dataclasses
@@ -260,6 +265,61 @@ def test_a_request_migrates_with_its_latent_rows(either):
         pass
     assert list(dst.engine.results["m0"].tokens) == list(want)
     assert dst.engine.num_prefills == 0
+
+
+# -- the held pool read in place (ISSUE 31) ----------------------------------
+
+
+@pytest.mark.parametrize("path, layers", [("gather", 0), ("in_place", 3)])
+def test_a_session_serves_the_references_tokens_on_either_path(
+        folded, path, layers, monkeypatch, tmp_path):
+    """End to end through the engine on pages of 16 (held [NP, 8, 128]):
+    the decode program that reads the pool in place (what ``auto`` finds
+    on a TPU: only the kernel module's own question is answered so, and
+    the kernel runs in interpret mode) and the one that gathers serve
+    the reference's tokens, copy no pool, and say which path they took:
+    ``in_place_layers``, the gauge, ``kv_in_place`` and ``pages_live``
+    on every ``decode_step`` span."""
+    import tpudl.ops.paged_attention as pa
+
+    model, params, key = folded
+    if path == "in_place":
+        monkeypatch.setattr(pa, "is_tpu_backend", lambda: True)
+    copies = registry().counter("serve_kv_pool_copies").value
+    rec = obs_spans.enable(str(tmp_path))
+    try:
+        sess = _session(model, params, page_size=16)
+        reqs = _requests()
+        got = sess.serve(reqs)
+        steps = [r for r in rec.records
+                 if r.get("kind") == "span" and r.get("name") == "decode_step"]
+    finally:
+        obs_spans.disable()
+    assert _margins(key, reqs, got, FOLDED).max() <= 2e-4
+    assert registry().counter("serve_kv_pool_copies").value == copies
+    cache = sess.engine.cache
+    assert cache.folds == (2, 2, 2)
+    assert cache.in_place_layers == layers
+    assert registry().gauge("serve_paged_attention_in_place").value == layers
+    assert steps and all(
+        s["kv_in_place"] == int(layers > 0) and s["kv_fold"] == 2
+        for s in steps)
+    # An idle slot costs a page, a busy one the pages its live positions
+    # lie on: never its whole table of four.
+    assert all(SLOTS <= s["pages_live"] <= 4 * SLOTS for s in steps)
+    assert any(s["pages_live"] > SLOTS for s in steps)
+
+
+def test_both_paths_serve_the_same_tokens(folded, monkeypatch):
+    import tpudl.ops.paged_attention as pa
+
+    model, params, _ = folded
+    tokens = []
+    for on_a_tpu in (False, True):
+        monkeypatch.setattr(pa, "is_tpu_backend", lambda: on_a_tpu)
+        got = _session(model, params, page_size=16).serve(_requests(seed=3))
+        tokens.append({rid: list(r.tokens) for rid, r in got.items()})
+    assert tokens[0] == tokens[1]
 
 
 # -- the pool's held shape (ISSUE 29) ----------------------------------------

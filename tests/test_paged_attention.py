@@ -12,7 +12,15 @@ plain k / v pool takes the kernel on a TPU; an int8 pool, a headless
 does every CPU run. And one session end to end through the kernel,
 which serves the gather's tokens and says so in its spans and gauge.
 
-What only a chip shows (the compiled kernel, its times) is in
+The latent sibling (ISSUE 31) is held the same way to the path IT
+replaces, ``paged_gather`` + ``attend_latent_rows``: ONE headless pool
+held folded (two positions of 576 a held row) and held as a row of
+whole lanes, an idle slot, a slot full to 1,280, lengths at a page's
+and at a block's edge, left padding; and chosen the same way: one token
+a slot over an unquantized pool on one device of a TPU whose held row
+is whole lanes, the gather for everything else.
+
+What only a chip shows (the compiled kernels, their times) is in
 ``tests/test_tpu_compile.py`` (a compile for a described v5e) and in
 PERF.md (chip runs).
 """
@@ -157,7 +165,7 @@ def _session(model, params, **kw):
     return ServeSession.from_model(model, params, WINDOW, **kw)
 
 
-def _latent_session(rank=16, rope=8):
+def _latent_session(rank=16, rope=8, **kw):
     from perfbench.families.mla_moe_serve import model_config, to_flax
     from perfbench.reference import mla_moe as ref
 
@@ -180,7 +188,7 @@ def _latent_session(rank=16, rope=8):
     params = to_flax(
         ref.all_weights(ref.seed_key(27), settings, jnp.float32), settings
     )
-    return _session(model, params)
+    return _session(model, params, **kw)
 
 
 def _mesh_session(model, params):
@@ -196,7 +204,15 @@ PROGRAMS = {
     # name: (session, attention layers on the kernel on a TPU)
     "kv_pool": (lambda m, p: _session(m, p), 2),
     "int8_view": (lambda m, p: _session(m, p, kv_dtype="int8"), 0),
+    # A latent row of 24 finds no fold and is no whole number of lanes.
     "headless_pool": (lambda m, p: _latent_session(), 0),
+    # 48 + 16 = 64 wide on pages of 16: held [NP, 8, 128], the latent
+    # kernel's; the same pool stored int8 keeps a row a position.
+    "folded_latent_pool": (
+        lambda m, p: _latent_session(48, 16, page_size=16), 2),
+    "int8_latent_pool": (
+        lambda m, p: _latent_session(48, 16, page_size=16, kv_dtype="int8"),
+        0),
     "mesh_committed_pool": (_mesh_session, 0),
 }
 
@@ -335,3 +351,140 @@ def test_pages_live_counts_what_the_kernel_visits():
     # there is no such page.
     cache.lens[1] = 15
     assert cache.pages_live(3) == 1 + 3 + 6
+
+
+# ---------------------------------------------------------------------------
+# The headless pool of a latent layer (ISSUE 31): the sibling kernel.
+# ---------------------------------------------------------------------------
+
+#: 4 slots x 80 pages of 16 positions, the sarvam cell's table a slot
+#: (1,280 positions): a slot spans up to three blocks of
+#: ``LATENT_PAGES_PER_BLOCK`` = 32 pages (512 positions).
+LB, LP, LPS, LH = 4, 80, 16, 4
+LNP = LB * LP + 1
+#: name -> (row width C, rank, positions a held row).
+HELD = {"folded_576": (576, 512, 2), "whole_lanes_128": (128, 96, 1)}
+#: name -> (start [B], lens [B], slots idle on the trash page).
+LATENT_CASES = {
+    "ragged_with_an_idle_slot": ([0, 0, 0, 0], [40, 700, 0, 300], [2]),
+    "slot_full_to_1280": ([0, 5, 0, 100], [1279, 1279, 1, 1278], []),
+    "lens_at_a_pages_edge": ([0, 0, 0, 3], [15, 16, 17, 31], []),
+    "lens_across_a_blocks_edge": ([0, 0, 0, 500], [511, 512, 513, 1023], []),
+    "left_pad": ([384, 17, 255, 256], [600, 18, 256, 1000], []),
+}
+
+
+def _latent_inputs(held, case, dtype=jnp.float32, chunk=1, **facts):
+    """(query, pool as held, view): a permuted page table, an idle
+    slot's row on the trash page."""
+    width, _, fold = HELD[held]
+    start, lens, idle = LATENT_CASES[case]
+    rng = np.random.default_rng(31)
+    pool = jnp.asarray(
+        rng.normal(size=(LNP, LPS // fold, fold * width)), dtype)
+    query = jnp.asarray(rng.normal(size=(LB, chunk, LH, width)), dtype)
+    table = rng.permutation(np.arange(1, LNP)).reshape(LB, LP)
+    table[idle] = 0
+    view = PagedView(
+        jnp.asarray(table, jnp.int32), jnp.asarray(start, jnp.int32),
+        jnp.asarray(lens, jnp.int32), LPS,
+        facts.pop("quantized", False), **facts,
+    )
+    return query, pool, view
+
+
+def _latent(held, case, impl, **kw):
+    query, pool, view = _latent_inputs(held, case, **kw)
+    out = pa.paged_latent_attention(
+        query, pool, view, rank=HELD[held][1], scale=0.2, impl=impl)
+    return out, view
+
+
+@pytest.mark.parametrize("held", sorted(HELD))
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_kernel_matches_gather_path(case, held):
+    want, _ = _latent(held, case, "reference")
+    got, view = _latent(held, case, "fused")
+    assert view.took == [True]
+    assert got.shape == (LB, 1, LH, HELD[held][1])
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_latent_kernel_matches_gather_path_bf16():
+    """The cell's dtype: float32 logits and statistics, ``p . rows`` in
+    bfloat16 with float32 accumulation, one division at the end. (The
+    CPU has no bfloat16 matmul into float32: the gather path is asked
+    in float32 of the same bfloat16 values.)"""
+    query, pool, view = _latent_inputs(
+        "folded_576", "left_pad", jnp.bfloat16)
+    want = pa.paged_latent_attention(
+        query.astype(jnp.float32), pool.astype(jnp.float32), view,
+        rank=512, scale=0.2, impl="reference")
+    got = pa.paged_latent_attention(
+        query, pool, view, rank=512, scale=0.2, impl="fused")
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=0.03)
+
+
+#: name -> (what the program observes, the held pool it observes it
+#: on): everything the latent kernel leaves to the gather.
+GATHERED = {
+    "int8_pool": (dict(quantized=True), "folded_576"),
+    "pool_on_a_mesh": (dict(sharded=True), "folded_576"),
+    "chunk_of_three": (dict(chunk=3), "whole_lanes_128"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GATHERED))
+def test_latent_kernel_refuses_and_auto_gathers(name, on_a_tpu):
+    facts, held = GATHERED[name]
+    query, pool, view = _latent_inputs(held, "left_pad", **facts)
+    scales = None
+    if view.quantized:
+        pool = jnp.zeros((LNP, LPS, HELD[held][0]), jnp.int8)
+        scales = jnp.ones((LNP, LPS), jnp.float32)
+    rank = HELD[held][1]
+    assert not pa.latent_in_place_ok(query, pool, view)
+    with pytest.raises(ValueError, match="one unquantized headless pool"):
+        pa.paged_latent_attention(
+            query, pool, view, rank=rank, scale=0.2, impl="fused")
+    view.took.clear()
+    out = pa.paged_latent_attention(
+        query, pool, view, rank=rank, scale=0.2, scales=scales)
+    assert view.took == [False] and out.shape == (*query.shape[:3], rank)
+
+
+@pytest.mark.parametrize("page, width, row, ok", [
+    (16, 1152, 576, True),    # the cell's: [NP, 8, 1152]
+    (16, 128, 128, True),     # a row of whole lanes, held as declared
+    (16, 576, 576, False),    # held as declared: 4.5 lanes
+    (8, 128, 64, False),      # [NP, 4, 128]: half a tile a page
+    (16, 1152, 512, False),   # not the query's width
+], ids=str)
+def test_latent_kernel_takes_a_held_row_of_whole_lanes(page, width, row, ok):
+    fold = max(width // row, 1)
+    pool = jax.ShapeDtypeStruct((9, page // fold, width), jnp.bfloat16)
+    query = jax.ShapeDtypeStruct((2, 1, 4, row), jnp.bfloat16)
+    view = PagedView(None, None, None, page, False)
+    assert pa.latent_in_place_ok(query, pool, view) is ok
+
+
+def test_latent_auto_on_the_cpu_gathers():
+    query, pool, view = _latent_inputs("folded_576", "lens_at_a_pages_edge")
+    assert pa.latent_in_place_ok(query, pool, view)
+    jaxpr = jax.make_jaxpr(lambda q, p: pa.paged_latent_attention(
+        q, p, view, rank=512, scale=0.2))(query, pool)
+    assert view.took == [False] and "pallas_call" not in str(jaxpr)
+
+
+def test_latent_auto_on_a_tpu_takes_the_kernel_under_its_scope(on_a_tpu):
+    """The kernel's operations sit under ``mla_core``, the scope of the
+    dense absorbed attention: what reads that scope reads the same work
+    whatever implements it, and nothing is left under ``kv_gather``."""
+    query, pool, view = _latent_inputs("folded_576", "lens_at_a_pages_edge")
+    lowered = jax.jit(lambda q, p: pa.paged_latent_attention(
+        q, p, view, rank=512, scale=0.2)).lower(query, pool)
+    assert view.took == [True]
+    text = lowered.as_text(debug_info=True)
+    assert "mla_core" in text and "kv_gather" not in text

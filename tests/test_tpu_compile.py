@@ -371,18 +371,13 @@ LATENT_SHAPE = (
 )
 
 
-@pytest.mark.parametrize("name", ["decode", "seat"])
-def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
-    import re
-
+def _latent_program(name, on_chip):
+    """The session of the sarvam cell's shapes over shapes alone, and
+    its decode or seat program lowered at LATENT_SHAPE."""
     from tpudl.models.generate import prefill_fn
     from tpudl.models.llama import LlamaConfig, LlamaForCausalLM, RopeScaling
     from tpudl.serve import ServeSession
 
-    device = _v5e_device()
-    if device is None:
-        pytest.skip("this installation cannot describe a v5e topology")
-    on_chip = SingleDeviceSharding(device)
     model = LlamaForCausalLM(LlamaConfig(
         vocab_size=65536, hidden_size=4096, num_layers=2, num_heads=64,
         num_kv_heads=64, intermediate_size=16384, max_seq_len=LATENT_SEQ,
@@ -413,29 +408,78 @@ def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
     if name == "decode":
         table = _s((LATENT_SLOTS, LATENT_SEQ // POOL_PAGE), i32,
                    sharding=on_chip)
-        lowered = session.engine.decode_call.lower(
+        return session, session.engine.decode_call.lower(
             _placed(params, on_chip), pool, vec, vec, table, vec, vec
         )
-    else:
-        _, row, _ = jax.eval_shape(prefill_fn(model), params, ids, ids)
-        pages = LATENT_WINDOW // POOL_PAGE
-        lowered = cache._seat_program(pages).lower(
-            pool, _placed(row, on_chip), _s((pages,), i32, sharding=on_chip)
-        )
-    compiled = lowered.compile()
+    _, row, _ = jax.eval_shape(prefill_fn(model), params, ids, ids)
+    pages = LATENT_WINDOW // POOL_PAGE
+    return session, cache._seat_program(pages).lower(
+        pool, _placed(row, on_chip), _s((pages,), i32, sharding=on_chip)
+    )
+
+
+def _held_pool_stays_put(compiled):
+    """Both pools are donated whole (1,152 bytes a position a layer, as
+    declared), each enters and leaves laid major-to-minor, nothing of
+    their shape is copied, and the weights of two layers, the pools and
+    the step's temporaries fit the chip. -> the compiled text."""
+    import re
+
     memory = compiled.memory_analysis()
-    # Both pools are donated whole (1,152 bytes a position a layer, as
-    # declared), and the weights of two layers, the pools and the
-    # step's temporaries fit the chip.
     assert memory.alias_size_in_bytes == 2 * 10241 * 16 * 576 * 2
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
     text = compiled.as_text()
     shape = ",".join(map(str, LATENT_SHAPE))
-    # Two leaves in, two out, each laid major-to-minor at the boundary.
     boundary = re.search(r"entry_computation_layout=\{(.*)", text).group(1)
     assert re.findall(rf"bf16\[{shape}\]\{{([\d,]+)", boundary) == ["2,1,0"] * 4
     assert not re.findall(
         rf"= bf16\[{shape}\][^ ]* copy(?:-start|-done)?\(", text
+    )
+    return text
+
+
+@pytest.mark.parametrize("name", ["decode", "seat"])
+def test_latent_pool_program_compiles_for_v5e(name, no_compile_cache):
+    """The programs as the sandbox's backend chooses them: the decode
+    program through the gather (the path an int8 latent pool keeps on
+    the chip), the seat."""
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    session, lowered = _latent_program(name, SingleDeviceSharding(device))
+    _held_pool_stays_put(lowered.compile())
+    if name == "decode":
+        took = session.engine.decode_call.__wrapped__.attention_in_place
+        assert took == (False, False)
+
+
+def test_latent_decode_program_reads_the_pool_in_place_on_v5e(
+    monkeypatch, no_compile_cache
+):
+    """ISSUE 31: on the chip the latent decode program attends through
+    the latent kernel (``is_tpu_backend`` is answered for it here, as
+    for the k / v pool above): one kernel call a layer takes the HELD
+    pool as it lies (no copy of its shape, still donated whole),
+    nothing is left under ``kv_gather`` and nothing of a slot's whole
+    view ([128, 640, 1152] held rows, or 1,280 positions) is made."""
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    session, lowered = _latent_program("decode", SingleDeviceSharding(device))
+    text = _held_pool_stays_put(lowered.compile())
+    took = session.engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True, True)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "latent_paged_attention" in text and "kv_gather" not in text
+    held_view = LATENT_SEQ // 2
+    assert f"[{LATENT_SLOTS},{held_view}," not in text
+    assert f"[{LATENT_SLOTS},{LATENT_SEQ // POOL_PAGE}," not in text.replace(
+        f"s32[{LATENT_SLOTS},{LATENT_SEQ // POOL_PAGE}]", ""
     )
 
 

@@ -743,11 +743,14 @@ class LatentAttention(nn.Module):
     training up-project: ``[k_nope_h | v_h] = c W_kv_b`` and attend
     with ``qk_nope_head_dim + qk_rope_head_dim``-wide keys. Paged
     decode absorbs ``W_kv_b`` into the query and the output and attends
-    over the gathered rows as one shared head: ``score = (W_kv_b^K
-    q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``. The pool
-    may be held folded, several positions to a held row
-    (tpudl.models.paged.page_fold); the write and ``_mla_absorbed``
-    address it as it is held."""
+    the cached rows as they are, one head shared by all query heads:
+    ``score = (W_kv_b^K q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c)
+    W_kv_b^V``. The rows are read where they lie, only the pages of a
+    slot's live positions, by the latent kernel of
+    tpudl.ops.paged_attention where the program observes that it can;
+    elsewhere every slot's whole table is gathered dense first. The
+    pool may be held folded (tpudl.models.paged.page_fold): the write,
+    the kernel and the gather path address it as it is held."""
 
     cfg: LlamaConfig
 
@@ -786,35 +789,30 @@ class LatentAttention(nn.Module):
         ).astype(cfg.dtype).reshape(r, H, dn + dv)
 
         if decode and paged is not None:
-            from tpudl.models.paged import (
-                held_fold,
-                paged_attend_mask,
-                paged_gather,
-                paged_write,
-            )
+            from tpudl.models.paged import paged_write
+            from tpudl.ops.paged_attention import paged_latent_attention
 
             pool = self.variable("cache", "pages_kv", _paged_cache_missing)
-            paged.took.append(False)  # a headless pool: the gather
-            # The pool may be held folded (tpudl.models.paged.page_fold):
-            # the write and the attention address it as it is held.
-            fold = held_fold(pool.value, paged.page_size)
             sc = None
             if paged.quantized:
                 sc = self.variable("cache", "scale_kv", _paged_cache_missing)
-            pool.value, new_sc = paged_write(
-                pool.value, sc.value if sc is not None else None, latent,
-                paged,
-            )
+            # This step's row is scattered into the donated pool first.
+            scales = sc.value if sc is not None else None
+            pool.value, scales = paged_write(pool.value, scales, latent, paged)
             if sc is not None:
-                sc.value = new_sc
-            rows = paged_gather(
-                pool.value, sc.value if sc is not None else None, paged,
-                latent.dtype,
+                sc.value = scales
+            # Then the absorbed attention reads the pool: in place where
+            # it can, through the dense gather where it cannot (an int8
+            # pool, a pool on a mesh, several tokens a slot, a CPU run).
+            # The program chooses by what it observes and records the
+            # choice (PagedView.took).
+            with jax.named_scope("mla_core"):
+                query = _absorbed_query(q_nope, q_rope, kv_b, dn)
+            u = paged_latent_attention(
+                query, pool.value, paged, rank=r, scale=scale, scales=scales
             )
-            ctx = _mla_absorbed(
-                q_nope, q_rope, rows, kv_b, dn,
-                paged_attend_mask(paged, chunk=S, fold=fold), scale,
-            )
+            with jax.named_scope("mla_core"):
+                ctx = jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
         elif decode:
             # The dense row cache of a prefill (and of the chunked
             # suffix prefill, which is handed the prefix's rows). A
@@ -886,66 +884,68 @@ def _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale):
         return jnp.einsum("bhst,bthd->bshd", weights, up[..., dn:])
 
 
-def _mla_absorbed(q_nope, q_rope, rows, kv_b, dn, mask, scale):
-    """The same attention with ``kv_b`` absorbed into the query and the
-    output: the rows are attended as they are cached, one head of
-    ``r + dr`` shared by all query heads.
+def _absorbed_query(q_nope, q_rope, kv_b, dn):
+    """``[q_nope W_kv_b^K | q_rope]``: [B, S, H, r + dr], the query that
+    meets a cached row ``[c | k_r]`` as it is."""
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, kv_b[..., :dn])
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+def attend_latent_rows(query, rows, mask, scale, r):
+    """Dense attention of the absorbed ``query`` [B, S, H, C] over the
+    cached rows, one head shared by all query heads: a row's score is
+    ``query . row``, its value the row's first ``r``. -> [B, S, H, r].
 
     ``rows`` may come FOLDED, as a folded pool holds them
     (tpudl.models.paged.page_fold): [B, T / f, f * C], logical position
     ``f * i + g`` in lanes ``g * C ...`` of held row ``i``, with
     ``mask`` [B, f, S, T / f] in the same order. The ``f`` lane blocks
-    are attended as ``f`` groups of positions under one softmax (which
-    does not care in which order its positions come). Cutting block
-    ``g`` out of a view that size at lane ``g * C`` would copy it on
-    the chip, so each group reads a window of WHOLE 128-value lanes
-    around its block, which the matrix unit takes as it lies: the
-    query is padded with zeros to the window and the values' block is
-    cut out of the small result."""
-    from tpudl.models.paged import LANES
+    are attended as ``f`` groups of positions under one softmax.
+    Cutting block ``g`` out of a view that size at lane ``g * C`` would
+    copy it on the chip, so each group reads a window of WHOLE lanes
+    around its block: the query is padded with zeros to the window and
+    the values' block is cut out of the small result."""
+    from tpudl.models.paged import lane_window
     from tpudl.ops.attention import MASK_VALUE
 
-    r = kv_b.shape[0]
-    with jax.named_scope("mla_core"):
-        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, kv_b[..., :dn])
-        query = jnp.concatenate([q_lat, q_rope], axis=-1)
-        C, width = query.shape[-1], rows.shape[-1]
-
-        def window(first, size):
-            """Whole lanes around ``[first, first + size)`` of a row."""
-            return first // LANES * LANES, min(
-                -(-(first + size) // LANES) * LANES, width
-            )
-
-        logits = []
-        for g in range(width // C):
-            lo, hi = window(g * C, C)
-            padded = jnp.pad(
-                query, [(0, 0)] * 3 + [(g * C - lo, hi - (g + 1) * C)]
-            )
-            group = jnp.einsum("bshc,btc->bhst", padded, rows[..., lo:hi])
-            logits.append(jnp.where(
-                mask[:, g, None], group.astype(jnp.float32) * scale,
-                MASK_VALUE,
-            ))
-        # One softmax over every group's positions (jax.nn.softmax,
-        # written out over the list).
-        top = logits[0].max(-1, keepdims=True)
-        for x in logits[1:]:
-            top = jnp.maximum(top, x.max(-1, keepdims=True))
-        weights = [jnp.exp(x - jax.lax.stop_gradient(top)) for x in logits]
-        total = sum(w.sum(-1, keepdims=True) for w in weights)
-        u = 0.0
-        for g, w in enumerate(weights):
-            lo, hi = window(g * C, r)
-            part = jnp.einsum(
-                "bhst,btc->bshc", (w / total).astype(rows.dtype),
-                rows[..., lo:hi], preferred_element_type=jnp.float32,
-            )
-            u = u + part[..., g * C - lo:g * C - lo + r]
-        return jnp.einsum(
-            "bshr,rhd->bshd", u.astype(rows.dtype), kv_b[..., dn:]
+    C, width = query.shape[-1], rows.shape[-1]
+    logits = []
+    for g in range(width // C):
+        lo, hi = lane_window(g * C, C, width)
+        padded = jnp.pad(
+            query, [(0, 0)] * 3 + [(g * C - lo, hi - (g + 1) * C)]
         )
+        group = jnp.einsum("bshc,btc->bhst", padded, rows[..., lo:hi])
+        logits.append(jnp.where(
+            mask[:, g, None], group.astype(jnp.float32) * scale,
+            MASK_VALUE,
+        ))
+    # One softmax over every group's positions (jax.nn.softmax,
+    # written out over the list).
+    top = logits[0].max(-1, keepdims=True)
+    for x in logits[1:]:
+        top = jnp.maximum(top, x.max(-1, keepdims=True))
+    weights = [jnp.exp(x - jax.lax.stop_gradient(top)) for x in logits]
+    total = sum(w.sum(-1, keepdims=True) for w in weights)
+    u = 0.0
+    for g, w in enumerate(weights):
+        lo, hi = lane_window(g * C, r, width)
+        part = jnp.einsum(
+            "bhst,btc->bshc", (w / total).astype(rows.dtype),
+            rows[..., lo:hi], preferred_element_type=jnp.float32,
+        )
+        u = u + part[..., g * C - lo:g * C - lo + r]
+    return u.astype(rows.dtype)
+
+
+def _mla_absorbed(q_nope, q_rope, rows, kv_b, dn, mask, scale):
+    """The same attention with ``kv_b`` absorbed into the query and the
+    output, the rows attended as they are cached: what paged decode
+    computes (the kernel in place of ``attend_latent_rows`` on a TPU)."""
+    with jax.named_scope("mla_core"):
+        query = _absorbed_query(q_nope, q_rope, kv_b, dn)
+        u = attend_latent_rows(query, rows, mask, scale, kv_b.shape[0])
+        return jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
 
 
 class LlamaBlock(nn.Module):

@@ -34,14 +34,16 @@ leaf in its held shape and reads the fold off it (``held_fold``).
 A decode step first writes its rows into the pool (``paged_write``, a
 scatter into the donated pool), then attends. There are two ways to
 read, and the program chooses by what it can observe
-(tpudl.ops.paged_attention): a k / v pool pair on one device of a TPU
-is read **in place** — a kernel brings only the pages that cover a
-slot's live positions from HBM by the page table and keeps a running
+(tpudl.ops.paged_attention): an unquantized pool on one device of a TPU
+(a k / v pool pair, or a latent layer's one headless pool as it is
+held) is read **in place** — a kernel brings only the pages that cover
+a slot's live positions from HBM by the page table and keeps a running
 softmax over them, so nothing of shape ``[B, P * ps, ...]`` exists;
-everything else (int8 pools, a latent layer's headless pool, a pool
-committed to a mesh, any CPU run) **gathers**: ``paged_gather`` makes
-every slot's whole logical view dense (in the held row form) and
-attention runs under ``paged_attend_mask``. Both mean the same thing.
+everything else (int8 pools, a pool committed to a mesh, a latent
+layer's chunk of several tokens, any CPU run) **gathers**:
+``paged_gather`` makes every slot's whole logical view dense (in the
+held row form) and attention runs under ``paged_attend_mask``. Both
+mean the same thing.
 
 Masking: slot ``b`` attends logical positions ``[start[b], lens[b]]``
 (``start`` = its left-pad count, ``lens`` = where this step's token was
@@ -190,6 +192,15 @@ LANES = 128
 SUBLANES = 8
 
 
+def lane_window(first: int, size: int, width: int):
+    """Whole lanes around ``[first, first + size)`` of a row ``width``
+    wide: what the matrix unit takes as it lies, where a cut at
+    ``first`` would be a copy."""
+    return first // LANES * LANES, min(
+        -(-(first + size) // LANES) * LANES, width
+    )
+
+
 def page_fold(page_size: int, tail, dtype) -> int:
     """How many positions of a page share one row of the HELD pool
     leaf: the ONE rule of the pool's shape, from what the cache can
@@ -309,8 +320,8 @@ def paged_gather(
     compute_dtype,
 ) -> jax.Array:
     """Materialize every slot's logical KV view from the pool (the
-    gather path; tpudl.ops.paged_attention reads a k / v pool in place
-    where it can, and then this is not called).
+    gather path; tpudl.ops.paged_attention reads a pool in place where
+    it can, and then this is not called).
 
     Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis)
     in ``compute_dtype`` where L = pages_per_slot x page_size; from a

@@ -1,46 +1,46 @@
 """Paged decode attention that reads the live pages where they lie.
 
-The paged decode branch (tpudl.models.llama.LlamaAttention) used to
-make a dense logical view ``[B, P * ps, Hkv, D]`` of the k and the v
-pool twice a layer (tpudl.models.paged.paged_gather) and attend it
-under a mask: every slot's whole table, whatever was live. With 5-13 %
-of the gathered positions live that was 40 % of a decode step's device
-time (PERF.md, PR 26). This kernel visits, for slot ``b``, only the
-pages that cover its logical positions ``[start[b], lens[b] + S - 1]``:
+The paged decode branches (tpudl.models.llama: LlamaAttention over a
+k / v pool pair, LatentAttention over ONE headless pool) used to make a
+dense view of every slot's whole table (tpudl.models.paged.paged_gather)
+and attend it under a mask, whatever was live: 40 % of a Mistral decode
+step's device time (PERF.md, PR 26), 22 % of the latent cell's (PR 30).
+The two kernels here visit, for slot ``b``, only the pages that cover
+its logical positions ``[start[b], lens[b] + S - 1]``:
 
 - the page table, ``start`` and ``lens`` are scalar-prefetched into
-  SMEM; the two pools stay in HBM (``memory_space=pl.ANY``) and are
-  read in place, one ``make_async_copy`` a live page into a
-  double-buffered VMEM block of ``PAGES_PER_BLOCK`` pages, the next
-  block (of this slot, or the first of the next) in flight while this
-  one is attended;
+  SMEM; the pools stay in HBM (``memory_space=pl.ANY``) and are read in
+  place, one ``make_async_copy`` a live page into a double-buffered VMEM
+  block of pages, the next block (of this slot, or the first of the
+  next) in flight while this one is attended;
 - a running (flash-style) softmax over the blocks: logits and
   statistics in float32, ``p . v`` in the pool's dtype with float32
   accumulation, the division once at the end;
 - nothing of shape ``[B, P * ps, ...]`` is made; an idle slot (lens 0
   on the trash page) costs one page.
 
-The pool keeps its shape ``[NP, ps, Hkv, D]``: a page is ``ps * Hkv``
-contiguous rows of ``D`` lanes, so the kernel takes the pool as
-``[NP, ps * Hkv, D]`` (a bitcast for XLA, no copy) and a block as a
-matrix ``[PAGES_PER_BLOCK * ps * Hkv, D]`` whose row ``t * Hkv + h`` is
-head ``h`` of position ``t``. All ``S * H`` query rows of a slot meet
-every row of the block in ONE matmul, and a query row keeps only the
-columns of its own KV head (and of its own positions) under the mask:
-that costs the matrix unit nothing it would not pay anyway (the keys
-are its stationary operand either way) and needs no strided read of a
-head out of the page.
+The k / v kernel (``_kernel``, PR 27) takes a pool ``[NP, ps, Hkv, D]``
+as ``[NP, ps * Hkv, D]`` (a bitcast for XLA) and a block as a matrix
+whose row ``t * Hkv + h`` is head ``h`` of position ``t``: all ``S * H``
+query rows of a slot meet every row of the block in ONE matmul, and a
+query row keeps the columns of its own KV head and positions under the
+mask (no strided read of a head out of the page). The latent kernel
+(``_latent_kernel``, PR 31; below, with its own notes) takes the one
+pool as it is HELD, ``[NP, ps / f, f * C]`` (``page_fold``): a row is
+key and value at once and all heads share it.
 
-Dispatch seam (tpudl.ops.norms.resolve_impl's rule): ``"reference"`` is
-the gather and ``_gqa_decode_attention``, left as they were;
-``"fused"`` is this kernel (compiled on the TPU, interpret mode
+Dispatch seam (tpudl.ops.norms.resolve_impl's rule), the same for both:
+``"reference"`` is the gather and the dense attention, left as they
+were; ``"fused"`` is the kernel (compiled on the TPU, interpret mode
 elsewhere: the CPU test mode); ``"auto"`` is what the model calls, and
-chooses by what the program can observe (``in_place_ok``): the kernel
-on a TPU for a k / v pool pair with a head axis, not quantized, on one
-device, ``head_dim`` a multiple of 128; the gather for everything else
-(int8 pools, whose dequantisation is fused into the gather; a pool
-committed to a mesh, which GSPMD would gather whole to every chip for
-a custom call; any CPU run). There is no knob. Inference only.
+chooses by what the program can observe (``in_place_ok``,
+``latent_in_place_ok``): the kernel on a TPU for an unquantized pool on
+one device whose rows are whole lanes (and, latent, one token a slot);
+the gather for everything else (int8 pools, whose dequantisation is
+fused into the gather; a pool committed to a mesh, which GSPMD would
+gather whole to every chip for a custom call; any CPU run).
+
+There is no knob. Inference only.
 """
 
 from __future__ import annotations
@@ -299,3 +299,318 @@ def paged_attention(
     if fused:
         return paged_attention_fused(q, pages_k, pages_v, view, interpret)
     return paged_attention_ref(q, pages_k, pages_v, view, scale_k, scale_v)
+
+
+# ---------------------------------------------------------------------------
+# The headless pool of a latent (MLA) layer, read the same way. A sibling
+# with its own ``pallas_call``: ONE pool serves as keys and as values, it
+# has no head axis, and it may be held folded. The k / v kernel above is
+# left as it is, line for line.
+# ---------------------------------------------------------------------------
+
+#: Pages fetched into one VMEM block of the latent kernel. A latent page
+#: is 18 KB and all of a slot's heads share it: a block costs its
+#: matmuls' fixed part (a microsecond or two), not its bytes, so most
+#: slots of the sarvam cell (~19 live pages) take ONE block. Timed on
+#: the chip against 8, 16 and 64 on that pattern (PERF.md, PR 31).
+LATENT_PAGES_PER_BLOCK = 32
+#: The scope the latent kernel's operations sit under (inside the
+#: layer's ``attention``): the one ``_mla_absorbed`` names, so whatever
+#: implements the absorbed attention is read as the same work.
+LATENT_SCOPE = "mla_core"
+
+
+def latent_in_place_ok(query, pages, view) -> bool:
+    """``in_place_ok`` for a headless pool, from what the program can
+    observe at trace time. ``query`` [B, S, H, C] is the absorbed query
+    (``[q_nope W_kv_b^K | q_rope]``), ``pages`` the pool as it is HELD,
+    [NP, ps / f, f * C] (tpudl.models.paged.page_fold): one query a
+    slot, a held row of whole lanes that is ``f`` query widths, and a
+    page of whole 8-row tiles, so that no copy lands on part of one."""
+    from tpudl.models.paged import LANES, SUBLANES, held_fold
+
+    if view.quantized or view.sharded or pages.ndim != 3:
+        return False
+    _, held, width = pages.shape
+    return (
+        query.shape[1] == 1
+        and width % LANES == 0
+        and held % SUBLANES == 0
+        and view.page_size % held == 0
+        and width == held_fold(pages, view.page_size) * query.shape[-1]
+    )
+
+
+def paged_latent_attention_ref(query, pages, view, rank, scale, scales=None):
+    """The gather path: every slot's whole logical view made dense out
+    of the pool (held rows, dequantised where the pool is int8), then
+    the absorbed attention under the mask."""
+    from tpudl.models.llama import attend_latent_rows
+    from tpudl.models.paged import held_fold, paged_attend_mask, paged_gather
+
+    rows = paged_gather(pages, scales, view, query.dtype)
+    mask = paged_attend_mask(
+        view, chunk=query.shape[1], fold=held_fold(pages, view.page_size)
+    )
+    with jax.named_scope(LATENT_SCOPE):
+        return attend_latent_rows(query, rows, mask, scale, rank)
+
+
+def _latent_kernel(
+    table_ref, start_ref, lens_ref,  # scalar prefetch
+    q_ref, kv_hbm,
+    o_ref,
+    buf, sem, turn,
+    *, page_size: int, rank: int, ppb: int, scale: float,
+):
+    """One slot a grid step (the query and the output are too large to
+    sit in VMEM whole; the pipeline brings a slot's), an inner loop over
+    the slot's blocks of pages; the first block of the next slot is in
+    flight while this slot's last is attended.
+
+    ``kv_hbm`` [NP, ps / f, f * C] as held; ``buf`` [2, ppb, ps / f,
+    f * C]: a page is a LEADING index of the buffer, so a copy is one
+    whole page onto whole tiles whatever the dtype packs into a tile.
+    ``q_ref`` [1, Hp, C padded to whole lanes]: the heads' queries, put
+    ``f`` times into one matrix [f * Hp, f * C] once a slot, rows
+    ``g * Hp ...`` holding them in lanes ``g * C ...`` and zeros
+    elsewhere (a rotation inside a window of whole lanes), so ONE
+    matmul against a block's held rows scores lane block ``g`` (logical
+    positions ``f * i + g``) in rows ``g * Hp ...``: all ``f`` groups
+    under one softmax. The value of a position is the first ``rank``
+    values of its row: group ``g`` accumulates ``p_g . rows`` over a
+    window of whole lanes around its block, cut once at the end.
+    ``o_ref`` [1, Hp, rank]; ``sem`` one DMA semaphore a buffer;
+    ``turn`` (SMEM) the buffer the next block lands in."""
+    from tpudl.models.paged import lane_window
+
+    b = pl.program_id(0)
+    num_slots = pl.num_programs(0)
+    pages = table_ref.shape[1]
+    _, _, held, width = buf.shape
+    fold = page_size // held
+    c = width // fold
+    hp = o_ref.shape[1]
+    cols = ppb * held
+    windows = [lane_window(g * c, rank, width) for g in range(fold)]
+
+    def span(slot):
+        """First and last logical page ``slot`` attends."""
+        lo = start_ref[slot] // page_size
+        hi = jnp.minimum(lens_ref[slot] // page_size, pages - 1)
+        return lo, jnp.maximum(hi, lo)
+
+    def each_page(slot, j, which, act):
+        """``act`` on the copies of block ``j`` of ``slot`` into buffer
+        ``which``: one a live page. Start and wait walk the same
+        pages (a loop, not unrolled copies: set-up time is judged)."""
+        lo, hi = span(slot)
+        first = lo + j * ppb
+
+        def page(i, _):
+            act(pltpu.make_async_copy(
+                kv_hbm.at[table_ref[slot, first + i]], buf.at[which, i],
+                sem.at[which],
+            ))
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(hi - first + 1, ppb), page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # A page that was not fetched holds what the buffer held
+        # before: masked as a key, and as a value it meets a weight of
+        # exactly 0, which only a finite value survives.
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        turn[0] = 0
+        each_page(0, 0, 0, lambda copy: copy.start())
+
+    def stacked_query():
+        padded = q_ref[0]
+        lanes = padded.shape[1]
+        zeros = lambda n: jnp.zeros((hp, n), padded.dtype)  # noqa: E731
+        groups = []
+        for g in range(fold):
+            begin, end = lane_window(g * c, c, width)
+            part = padded
+            if end - begin > lanes:
+                part = jnp.concatenate(
+                    [part, zeros(end - begin - lanes)], axis=1
+                )
+            if g * c > begin:
+                # The lanes that come round are zeros. (Rotated as
+                # 32-bit values: the chip rotates no packed ones.)
+                part = pltpu.roll(
+                    part.astype(jnp.float32), g * c - begin, 1
+                ).astype(padded.dtype)
+            groups.append(jnp.concatenate([
+                x for x in (zeros(begin), part, zeros(width - end))
+                if x.shape[1]
+            ], axis=1))
+        return jnp.concatenate(groups, axis=0)
+
+    lo, hi = span(b)
+    blocks = (hi - lo) // ppb + 1
+    q = stacked_query()
+    first = start_ref[b]
+    upper = jnp.minimum(lens_ref[b], pages * page_size - 1)
+    # Row g * Hp + h is head h on lane block g; column i is held row i
+    # of the block: logical position f * i + g of it.
+    row = jax.lax.broadcasted_iota(jnp.int32, (fold * hp, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (fold * hp, cols), 1)
+    offset = fold * col + row // hp
+
+    def block_body(j, carry):
+        m, l, accs, which = carry
+        last = j + 1 >= blocks
+        nb = jnp.where(last, b + 1, b)
+        nj = jnp.where(last, 0, j + 1)
+
+        @pl.when(nb < num_slots)
+        def _():
+            each_page(nb, nj, 1 - which, lambda copy: copy.start())
+
+        each_page(b, j, which, lambda copy: copy.wait())
+        rows = buf[which].reshape(cols, width)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        pos = (lo + j * ppb) * page_size + offset
+        s = jnp.where((pos >= first) & (pos <= upper), s, MASK_VALUE)
+        top = jnp.max(s, axis=-1, keepdims=True)
+        m_new = m
+        for g in range(fold):
+            m_new = jnp.maximum(m_new, top[g * hp:(g + 1) * hp])
+        p = jnp.exp(s - jnp.concatenate([m_new] * fold, axis=0))
+        alpha = jnp.exp(m - m_new)
+        total = jnp.sum(p, axis=-1, keepdims=True)
+        l = alpha * l + sum(
+            total[g * hp:(g + 1) * hp] for g in range(fold)
+        )
+        accs = tuple(
+            alpha * acc + jnp.dot(
+                p[g * hp:(g + 1) * hp].astype(rows.dtype),
+                rows[:, begin:end], preferred_element_type=jnp.float32,
+            )
+            for g, (acc, (begin, end)) in enumerate(zip(accs, windows))
+        )
+        return m_new, l, accs, 1 - which
+
+    m0 = jnp.full((hp, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((hp, 1), jnp.float32)
+    accs0 = tuple(
+        jnp.zeros((hp, end - begin), jnp.float32) for begin, end in windows
+    )
+    _, l, accs, which = jax.lax.fori_loop(
+        0, blocks, block_body, (m0, l0, accs0, turn[0])
+    )
+    turn[0] = which
+    u = sum(
+        acc[:, g * c - begin:g * c - begin + rank]
+        for g, (acc, (begin, _)) in enumerate(zip(accs, windows))
+    )
+    o_ref[0] = (u / l).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("page_size", "rank", "scale", "interpret")
+)
+def _fused_latent(
+    query, pages, page_table, start, lens,
+    *, page_size: int, rank: int, scale: float, interpret: bool,
+):
+    """The latent kernel's call, jitted on its own like ``_fused``: the
+    layers of a decode program share one lowering, and the scope is
+    named inside so the shared function carries it."""
+    from tpudl.models.paged import LANES
+
+    b, _, h, c = query.shape
+    _, held, width = pages.shape
+    hp = round_up(h, 16)
+    ppb = min(LATENT_PAGES_PER_BLOCK, int(page_table.shape[1]))
+    with jax.named_scope(LATENT_SCOPE):
+        q = jnp.pad(
+            query.reshape(b, h, c).astype(pages.dtype),
+            ((0, 0), (0, hp - h), (0, round_up(c, LANES) - c)),
+        )
+        out = pl.pallas_call(
+            functools.partial(
+                _latent_kernel, page_size=page_size, rank=rank, ppb=ppb,
+                scale=scale,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(b,),
+                in_specs=[
+                    pl.BlockSpec(
+                        (1, hp, q.shape[-1]), lambda i, *_: (i, 0, 0)
+                    ),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, hp, rank), lambda i, *_: (i, 0, 0)
+                ),
+                scratch_shapes=[
+                    pltpu.VMEM((2, ppb, held, width), pages.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SMEM((1,), jnp.int32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, hp, rank), query.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+            name="latent_paged_attention",
+        )(
+            page_table.astype(jnp.int32),
+            start.astype(jnp.int32),
+            lens.astype(jnp.int32),
+            q,
+            pages,
+        )
+        return out[:, :h].reshape(b, 1, h, rank)
+
+
+def paged_latent_attention(
+    query, pages, view, *, rank: int, scale: float,
+    scales=None,
+    impl: str = "auto", interpret: Optional[bool] = None,
+):
+    """Absorbed latent attention of ``query`` [B, S, H, C] over each
+    slot's logical positions ``[start, lens + j]`` of the ONE headless
+    pool ``pages`` (as held), ``paged_write`` having put this step's
+    rows there: the score of a position is ``query . row`` over all
+    ``C`` values, its value the row's first ``rank``. Returns
+    [B, S, H, rank] in ``query.dtype``: ``u``, which the caller
+    projects through ``W_kv_b^V``. ``view`` records which path the layer
+    took. ``"auto"`` takes the kernel on a TPU where
+    ``latent_in_place_ok``; int8 pools (``scales``), a pool on a mesh,
+    a chunk of more than one token and every CPU run gather."""
+    if impl == "auto":
+        impl = (
+            "fused"
+            if is_tpu_backend() and latent_in_place_ok(query, pages, view)
+            else "reference"
+        )
+    fused, interpret = resolve_impl(impl, interpret)
+    view.took.append(fused)
+    if not fused:
+        return paged_latent_attention_ref(
+            query, pages, view, rank, scale, scales
+        )
+    if not latent_in_place_ok(query, pages, view):
+        raise ValueError(
+            "the latent paged-attention kernel reads one unquantized "
+            "headless pool [NP, ps / f, f * C] on one device, a held "
+            "row of whole lanes and a page of whole 8-row tiles, one "
+            f"query a slot; got query {query.shape}, pool {pages.shape} "
+            f"{pages.dtype}, page size {view.page_size}, "
+            f"quantized={view.quantized}, sharded={view.sharded}"
+        )
+    return _fused_latent(
+        query, pages, view.page_table, view.start, view.lens,
+        page_size=view.page_size, rank=rank, scale=float(scale),
+        interpret=interpret,
+    )
