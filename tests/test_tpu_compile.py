@@ -596,6 +596,178 @@ def test_window_moe_program_compiles_for_v5e(
     assert f"bf16[{slots},{cache.ring_pages * page}," not in text
 
 
+# LongCat-Flash-Chat's four double layers of ISSUE 33 at its cell's size
+# (perfbench/configs/longcat-flash-l4-e16.json through its family's own
+# ``model_config``): EIGHT latent pools (two a layer) of the sarvam
+# cell's held shape under one table, 192 slots of 1,536 positions. The
+# decode program attends all eight in place through the latent kernel,
+# takes every pool donated and copies none; 10.35 GB of weights, 2.72 GB
+# of pools and the step's temporaries fit the chip. The batch-1 prefill
+# at a window of 512 fits beside them.
+
+
+def _shortcut_moe_session():
+    import json
+
+    from perfbench.families import shortcut_moe_serve as family
+    from perfbench.reference import shortcut_moe as ref
+    from tpudl.models.llama import LlamaForCausalLM
+    from tpudl.serve import ServeSession
+
+    with open(REPO / "perfbench/configs/longcat-flash-l4-e16.json") as f:
+        cfg = json.load(f)
+    sess = cfg["session"]
+    model = LlamaForCausalLM(
+        family.model_config(cfg, sess["max_seq_len"], bf16)
+    )
+    s = ref.settings(cfg)
+    key = jax.eval_shape(lambda: ref.seed_key(0))
+    params = jax.eval_shape(
+        lambda k: family.to_flax(ref.all_weights(k, s, bf16), s), key
+    )
+    session = ServeSession.from_model(
+        model, params, sess["prompt_window"], num_slots=sess["num_slots"],
+        page_size=sess["page_size"],
+        num_pages=sess["max_seq_len"] // sess["page_size"] + 1,
+    )
+    return sess, model, params, session
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_shortcut_moe_program_compiles_for_v5e(
+    name, monkeypatch, no_compile_cache
+):
+    import math
+    import re
+
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+    from tpudl.models.generate import prefill_fn
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    on_chip = SingleDeviceSharding(device)
+    sess, model, params, session = _shortcut_moe_session()
+    slots, page = sess["num_slots"], sess["page_size"]
+    weights = sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(params)
+    )
+    assert 10.3e9 < weights < 10.4e9
+    if name == "prefill":
+        ids = _s((1, sess["prompt_window"]), i32, sharding=on_chip)
+        compiled = jax.jit(prefill_fn(model)).lower(
+            _placed(params, on_chip), ids, ids
+        ).compile()
+        memory = compiled.memory_analysis()
+        # Beside the pools (2.72 GB) on a chip of 16 GB.
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.5e9
+        return
+    cache = session.engine.cache
+    table_pages = sess["max_seq_len"] // page
+    shape = (slots * table_pages + 1, page // 2, 2 * 576)
+    leaves = jax.tree.leaves(cache.cache)
+    assert len(leaves) == 8 and cache.folds == (2,) * 8
+    assert all(leaf.shape[1:] == shape[1:] for leaf in leaves)
+    pool = jax.tree.map(
+        lambda leaf: _s(shape, leaf.dtype, sharding=on_chip), cache.cache
+    )
+    vec = _s((slots,), i32, sharding=on_chip)
+    table = _s((slots, table_pages), i32, sharding=on_chip)
+    compiled = session.engine.decode_call.lower(
+        _placed(params, on_chip), pool, vec, vec, table, vec, vec
+    ).compile()
+    took = session.engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True,) * 8
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    assert "latent_paged_attention" in text and "kv_gather" not in text
+    memory = compiled.memory_analysis()
+    pool_bytes = 8 * math.prod(shape) * 2
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert 2.7e9 < pool_bytes < 2.75e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    dims = ",".join(map(str, shape))
+    assert not re.findall(
+        rf"= bf16\[{dims}\][^ ]* copy(?:-start|-done)?\(", text
+    )
+    # Nothing of a slot's whole logical view (held rows or positions).
+    assert f"[{slots},{sess['max_seq_len'] // 2}," not in text
+    # An identity choice reaches no expert matmul: the grouped matmuls
+    # are over the 16 experts held, whatever the router's width.
+    assert "bf16[16,192,2048]" in text and "bf16[768," not in text
+
+
+# A configuration that sets none of ISSUE 33's keys (the block's kind,
+# the low-rank query and its scales, the router's scoring, identity
+# experts) builds the programs it built before: the lowered text of a
+# grouped-query and of a latent + sigmoid-routed decoder's prefill and
+# paged decode, counted at the parent commit (PR 31) and held here. A
+# later PR that changes one of these programs on purpose counts again.
+_PLAIN = dict(
+    vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+    intermediate_size=128, max_seq_len=64, rope_theta=1e4, dtype=bf16,
+)
+OLD_PROGRAMS = {
+    "gqa": (dict(num_kv_heads=2), {"decode": (742, 19), "prefill": (589, 19)}),
+    "mla_moe": (
+        dict(
+            num_kv_heads=4, attention="mla", kv_lora_rank=48,
+            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+            num_experts=8, experts_per_token=2, moe_intermediate_size=32,
+            num_shared_experts=1, routed_scaling_factor=2.5, first_k_dense=1,
+            experts_held=(2, 4),
+        ),
+        {"decode": (1090, 29), "prefill": (767, 25)},
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(OLD_PROGRAMS))
+def test_a_configuration_without_the_new_keys_lowers_the_old_programs(name):
+    import re
+
+    from tpudl.models.generate import prefill_fn
+    from tpudl.models.llama import LlamaConfig, LlamaForCausalLM, RopeScaling
+    from tpudl.serve import ServeSession
+
+    keys, want = OLD_PROGRAMS[name]
+    if name == "mla_moe":
+        keys = dict(keys, rope_scaling=RopeScaling(
+            40.0, 16, mscale=1.0, mscale_all_dim=1.0))
+    model = LlamaForCausalLM(LlamaConfig(**_PLAIN, **keys))
+    ids = _s((1, 16), i32)
+    params = jax.eval_shape(model.init, jax.random.key(0), ids)["params"]
+    session = ServeSession.from_model(
+        model, params, 16, num_slots=4, page_size=16
+    )
+    cache = session.engine.cache
+    vec = _s((4,), i32)
+    lowered = {
+        "decode": session.engine.decode_call.lower(
+            params, cache.cache, vec, vec, *cache.dispatch_args()
+        ),
+        "prefill": jax.jit(prefill_fn(model)).lower(params, ids, ids),
+    }
+    got = {
+        program: (
+            len(re.findall(r"stablehlo\.\w+", text)),
+            text.count("stablehlo.dot_general"),
+        )
+        for program, text in (
+            (k, v.as_text()) for k, v in lowered.items()
+        )
+    }
+    assert got == want
+    # None of the new block's parts is in a tree that did not ask.
+    names = "".join(jax.tree_util.keystr(p) for p, _ in
+                    jax.tree_util.tree_flatten_with_path(params)[0])
+    assert "q_a_proj" not in names and "attention_0" not in names
+
+
 # ---------------------------------------------------------------------------
 # chip_smoke.py on the CPU: the phases at a tiny size (one serve phase,
 # on the page pool; one train phase; the two mesh phases), through a

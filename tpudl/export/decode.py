@@ -124,6 +124,13 @@ def export_serving_decoder(
         kv_dtype=kv_dtype,
     )
     cache._no_rings("the exported decode artifact")
+    if getattr(getattr(model, "cfg", None), "num_experts", 0) > 0:
+        raise ValueError(
+            "the exported decode artifact is not wired to routed "
+            "experts: an artifact session takes the two-value prefill "
+            "and decode contracts, and a model with routed experts "
+            "returns its tokens per held expert beside them"
+        )
     token = jnp.zeros((num_slots,), jnp.int32)
     position = jnp.full((num_slots,), prompt_len, jnp.int32)
     prefill_blob = export_stablehlo(

@@ -40,28 +40,28 @@ MOE_STATS = "moe_stats"
 
 def _apply_cached(model, variables, *args, **kwargs):
     """``model.apply`` with the cache mutable: ``(output, cache,
-    *counts)``. A model with routed experts adds ONE int32 array
-    ``[expert layers, experts held]``, the real tokens each held
-    expert got in this call, layers in order; other models add
-    nothing, and their contracts return the pair they always did."""
+    *stats)``. A model with routed experts adds one int32 array
+    ``[expert layers, ...]`` a statistic its layers sow, layers in
+    order (tpudl.ops.moe.MOE_STAT_NAMES: the real tokens each held
+    expert got in this call, ...); other models add nothing."""
     out, mutated = model.apply(
         variables, *args, mutable=["cache", MOE_STATS], **kwargs
     )
+    from tpudl.ops.moe import MOE_STAT_NAMES
     stats = jax.tree_util.tree_flatten_with_path(
         mutated.get(MOE_STATS, {})
     )[0]
-    if not stats:
-        return out, mutated["cache"]
 
-    def layer(path) -> int:
-        return int(re.search(
-            r"layer_(\d+)", jax.tree_util.keystr(path)
-        ).group(1))
+    def layer(path_leaf) -> int:
+        path = jax.tree_util.keystr(path_leaf[0])
+        return int(re.search(r"layer_(\d+)", path).group(1))
 
-    counts = jnp.stack(
-        [leaf for _, leaf in sorted(stats, key=lambda pl: layer(pl[0]))]
-    )
-    return out, mutated["cache"], counts
+    by_name = {name: [
+        leaf for path, leaf in sorted(stats, key=layer)
+        if path[-2].key == name
+    ] for name in MOE_STAT_NAMES}
+    stacks = [jnp.stack(leaves) for leaves in by_name.values() if leaves]
+    return (out, mutated["cache"], *stacks)
 
 
 # The contract functions below carry names of their own (``tpudl_prefill``,
@@ -74,9 +74,9 @@ def prefill_fn(model):
     (params, input_ids, attention_mask) -> (last_logits, cache). One
     definition serves both the live loop below and the serving export
     (tpudl.export.decode) — they cannot diverge. A model with routed
-    experts returns a third value, its tokens per held expert
-    (``_apply_cached``); so do the paged decode, chunked prefill and
-    verify contracts below."""
+    experts returns a third value, its tokens per held expert (and,
+    with identity experts, a fourth: ``_apply_cached``); so do the
+    paged decode, chunked prefill and verify contracts below."""
 
     def tpudl_prefill(params, input_ids, attention_mask):
         positions = jnp.maximum(

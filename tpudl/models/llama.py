@@ -171,36 +171,22 @@ class LlamaConfig:
                     "layer_types are the grouped-query block's: latent "
                     "attention keeps every layer alike"
                 )
-    # Fused-epilogue kernel tier (tpudl.ops.norms / mlp_fused): False
-    # (default) = composite RMSNorm/SwiGLU, bit-identical to before the
-    # tier; True = Pallas fused RMSNorm(+residual) and SwiGLU on TPU,
-    # composite off-TPU; "force" = Pallas everywhere (interpret mode
-    # off-TPU — the CPU parity-test mode). Same param tree either way.
-    # These ops run per serve decode step, so the fused path cuts decode
-    # TPOT alongside training step time.
+        _check_block(self)
+    # Fused-epilogue kernel tier (tpudl.ops.norms / mlp_fused): False =
+    # composite RMSNorm/SwiGLU; True = Pallas fused on TPU, composite
+    # off it; "force" = Pallas everywhere. Same param tree either way.
     fused_ops: Any = False
-    # Low-precision weight tier (tpudl.quant): None (default) = plain
-    # nn.Dense projections, bit-identical to before the tier; "int8" /
-    # "fp8_e4m3" = attention+MLP projections become QuantDense, which
-    # serves the quantize_tree output (kernels carried as
-    # (qvalues, qscale) pairs, dequant fused into the contraction) and
-    # runs full-precision kernels through the exact nn.Dense math —
-    # same param-tree structure either way, so checkpoints round-trip.
-    # Norms/embeddings/lm_head always stay full precision. Serving
-    # entry: ServeSession.from_model(weight_dtype=...).
+    # Low-precision weight tier (tpudl.quant): None = plain nn.Dense;
+    # "int8" / "fp8_e4m3" = the projections become QuantDense and serve
+    # the quantize_tree output (same tree structure; norms, embeddings
+    # and lm_head stay full). ServeSession.from_model(weight_dtype=...).
     weight_dtype: Optional[str] = None
-    # fp8 TRAINING tier (tpudl.ops.fp8_dot + the tpudl.train.precision
-    # "fp8" policy): True routes the SAME rule-class projection sites
-    # the quantizer addresses (LLAMA_QUANT_PATTERNS — the seven
-    # per-block projections) through Fp8Dense (e4m3 fwd / e5m2 grad,
-    # delayed scaling; f32 master params, nn.Dense-identical tree).
-    # "force"/"fused"/"reference" pin the fp8_dot impl seam. Mutually
-    # exclusive with weight_dtype; COMPOSES with lora_rank (fp8 base
-    # matmul + full-precision rank-r adapters — the flywheel refresh's
-    # cheapest training cell).
+    # fp8 TRAINING tier (tpudl.ops.fp8_dot, the "fp8" precision
+    # policy): the sites LLAMA_QUANT_PATTERNS address go through
+    # Fp8Dense; a string pins the fp8_dot impl. Exclusive with
+    # weight_dtype, composes with lora_rank.
     fp8_train: Any = False
-    # MoE (tpudl.ops.moe): >0 swaps the dense SwiGLU MLP for an
-    # expert-parallel gated MoE in every block.
+    # MoE (tpudl.ops.moe): >0 swaps every block's dense MLP for MoEMlp.
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
@@ -248,6 +234,20 @@ class LlamaConfig:
     sliding_rope_theta: float = 10_000.0
     partial_rotary_factor: float = 1.0
     attention_gate: bool = False
+    # ``block``: "llama" (attention -> MLP, once a layer) or "shortcut"
+    # (ShortcutBlock: two latent attentions and two dense FFNs around
+    # one expert branch). ``q_lora_rank`` > 0: the latent query is
+    # low-rank (q_a_proj, RMSNorm, q_b_proj); ``mla_scale_q`` / ``_kv``
+    # are constants on the normed low-rank query and the normed latent.
+    # ``router_scoring`` ("sigmoid" | "softmax"), ``router_renormalize``
+    # and ``zero_experts`` (identity experts) are DroplessMoE's.
+    block: str = "llama"
+    q_lora_rank: int = 0
+    mla_scale_q: float = 1.0
+    mla_scale_kv: float = 1.0
+    router_scoring: str = "sigmoid"
+    router_renormalize: bool = True
+    zero_experts: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -732,25 +732,25 @@ class LlamaAttention(nn.Module):
 
 class LatentAttention(nn.Module):
     """Latent (MLA) attention. A position is cached as ONE row
-    ``[c | k_r]``: the RMS-normed latent ``c`` (``kv_lora_rank``) and
-    the roped key ``k_r`` (``qk_rope_head_dim``) that every head
-    shares, written after the norm and after RoPE. No value pool and no
-    head axis: the cache declares the single leaf ``kv`` (dense rows)
-    / ``pages_kv`` (page pool), and tpudl.serve.cache builds, seats,
-    gathers and migrates whatever leaves a layer declares.
+    ``[c | k_r]``: the RMS-normed latent ``c`` (``kv_lora_rank``, times
+    the constant ``mla_scale_kv``: the scale is IN the row) and the
+    roped key ``k_r`` (``qk_rope_head_dim``) every head shares. No
+    value pool, no head axis: the cache declares the one leaf ``kv``
+    (dense rows) / ``pages_kv`` (page pool); tpudl.serve.cache builds,
+    seats, gathers and migrates whatever leaves a layer declares. The
+    query is one matrix ``q_proj``, or with ``q_lora_rank`` low-rank:
+    ``q = W_qb (RMSNorm(W_qa x) * mla_scale_q)`` (``_latent_query``).
 
     Two forms of the same attention, which must agree. Prefill and
-    training up-project: ``[k_nope_h | v_h] = c W_kv_b`` and attend
-    with ``qk_nope_head_dim + qk_rope_head_dim``-wide keys. Paged
-    decode absorbs ``W_kv_b`` into the query and the output and attends
-    the cached rows as they are, one head shared by all query heads:
-    ``score = (W_kv_b^K q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c)
-    W_kv_b^V``. The rows are read where they lie, only the pages of a
-    slot's live positions, by the latent kernel of
-    tpudl.ops.paged_attention where the program observes that it can;
-    elsewhere every slot's whole table is gathered dense first. The
-    pool may be held folded (tpudl.models.paged.page_fold): the write,
-    the kernel and the gather path address it as it is held."""
+    training up-project: ``[k_nope_h | v_h] = c W_kv_b``, keys
+    ``qk_nope_head_dim + qk_rope_head_dim`` wide. Paged decode absorbs
+    ``W_kv_b`` into the query and the output and attends the cached
+    rows as they are, one head for all query heads: ``score =
+    (W_kv_b^K q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``;
+    in place through the latent kernel of tpudl.ops.paged_attention
+    where the program observes that it can, else over every slot's
+    table gathered dense. The pool may be held folded
+    (tpudl.models.paged.page_fold): all three address it as held."""
 
     cfg: LlamaConfig
 
@@ -770,7 +770,7 @@ class LatentAttention(nn.Module):
         scale = (dn + dr) ** -0.5
         if cfg.rope_scaling is not None:
             scale *= cfg.rope_scaling.attention_scale
-        q = _proj(cfg, H * (dn + dr), "q_proj")(hidden).reshape(B, S, H, dn + dr)
+        q = _latent_query(cfg, hidden).reshape(B, S, H, dn + dr)
         q_nope = q[..., :dn]
         q_rope = rope(q[..., dn:], positions, cfg.rope_theta, cfg.rope_scaling)
         with jax.named_scope("kv_down"):
@@ -778,7 +778,7 @@ class LatentAttention(nn.Module):
                 r + dr, use_bias=False, dtype=cfg.dtype,
                 kernel_init=nn.initializers.normal(0.02), name="kv_a_proj",
             )(hidden)
-            c = RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down[..., :r])
+            c = _latent_norm(cfg, down[..., :r])
             k_r = rope(
                 down[..., None, r:], positions, cfg.rope_theta,
                 cfg.rope_scaling,
@@ -1074,11 +1074,11 @@ class LlamaModel(nn.Module):
                 name="embed_tokens",
             )(input_ids).astype(cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", "tp")
-        block = LlamaBlock
+        block = _BLOCKS[cfg.block]
         if cfg.remat and not decode:
             # adapters never reach the remat path: multi-tenant views
             # are decode-only (serving), and decode skips remat.
-            block = nn.remat(LlamaBlock, static_argnums=(4, 5))
+            block = nn.remat(block, static_argnums=(4, 5))
         for i in range(cfg.num_layers):
             x = block(cfg, cfg.mlp_kind(i), i, name=f"layer_{i}")(
                 x, positions, kv_mask, decode, paged,
@@ -1141,6 +1141,187 @@ class LlamaForSequenceClassification(nn.Module):
             name="classifier",
         )(pooled)
         return logits.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# The shortcut-connected double layer, and what the latent attention and
+# the configuration take for it. Kept BELOW the decoder stack: the
+# compiled programs of the configurations in the benchmark hold the
+# line numbers of the attention call chain above (PERF.md section 7).
+# ---------------------------------------------------------------------------
+
+
+def _check_block(cfg: LlamaConfig) -> None:
+    """``LlamaConfig.__post_init__``'s checks of the block's kind, the
+    low-rank query and the router."""
+    if cfg.block not in _BLOCKS:
+        raise ValueError(
+            f"block must be one of {sorted(_BLOCKS)}, got {cfg.block!r}"
+        )
+    if cfg.router_scoring not in ("sigmoid", "softmax"):
+        raise ValueError(
+            f"router_scoring must be 'sigmoid' or 'softmax', got "
+            f"{cfg.router_scoring!r}"
+        )
+    if cfg.q_lora_rank < 0 or cfg.zero_experts < 0:
+        raise ValueError(
+            f"q_lora_rank and zero_experts must be >= 0, got "
+            f"{cfg.q_lora_rank} and {cfg.zero_experts}"
+        )
+    if cfg.zero_experts and not cfg.num_experts:
+        raise ValueError(
+            "zero_experts are ids past num_experts of a routed-expert "
+            "layer's router: set num_experts"
+        )
+    if cfg.block == "shortcut" and not (
+        cfg.attention == "mla" and cfg.num_experts > 0
+        and cfg.first_k_dense == 0
+    ):
+        raise ValueError(
+            "block='shortcut' is two latent attentions and two dense "
+            "FFNs around one expert branch in EVERY layer: it needs "
+            "attention='mla', num_experts > 0 and first_k_dense == 0"
+        )
+
+
+def _scaled(scale: float, x):
+    """``x`` times a configuration's constant; untouched (the program
+    it had) where the constant is 1."""
+    return x if scale == 1.0 else (x * scale).astype(x.dtype)
+
+
+def _latent_query(cfg: LlamaConfig, hidden):
+    """``LatentAttention``'s query, [B, S, H * (dn + dr)]: one matrix
+    ``q_proj``, or with ``q_lora_rank`` the low-rank ``W_qb
+    (RMSNorm(W_qa x) * mla_scale_q)``."""
+    width = cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if not cfg.q_lora_rank:
+        return _proj(cfg, width, "q_proj")(hidden)
+    with jax.named_scope("q_down"):
+        low = RMSNorm(cfg.rms_norm_eps, name="q_norm")(
+            _proj(cfg, cfg.q_lora_rank, "q_a_proj")(hidden)
+        )
+    return _proj(cfg, width, "q_b_proj")(_scaled(cfg.mla_scale_q, low))
+
+
+def _latent_norm(cfg: LlamaConfig, down):
+    """``LatentAttention``'s cached latent: ``RMSNorm(c') *
+    mla_scale_kv``."""
+    return _scaled(
+        cfg.mla_scale_kv, RMSNorm(cfg.rms_norm_eps, name="kv_norm")(down)
+    )
+
+
+def real_tokens(x, kv_mask, paged):
+    """[B, S] bool: the tokens that are real: a prompt's, not its
+    padding's; a seated slot's, not an idle slot's ride-along (whose
+    table row maps the trash page). ``LlamaBlock`` keeps the same three
+    lines inline, and its own dense MLP beside ``_DenseFFN``: its line
+    numbers are held (the note above)."""
+    if paged is not None:
+        return jnp.broadcast_to(paged.page_table[:, :1] != 0, x.shape[:2])
+    if kv_mask is not None:
+        return kv_mask.astype(jnp.bool_)
+    return jnp.ones(x.shape[:2], jnp.bool_)
+
+
+class _DenseFFN(nn.Module):
+    """A dense SwiGLU of ``intermediate_size`` under the projection
+    names the quantizer's rules address."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from tpudl.ops.mlp_fused import swiglu
+        from tpudl.ops.norms import fused_ops_impl
+
+        cfg = self.cfg
+        act = swiglu(
+            _proj(cfg, cfg.intermediate_size, "gate_proj")(x),
+            _proj(cfg, cfg.intermediate_size, "up_proj")(x),
+            impl=fused_ops_impl(cfg.fused_ops),
+        )
+        return _proj(cfg, cfg.hidden_size, "down_proj")(act)
+
+
+class ShortcutBlock(nn.Module):
+    """The shortcut-connected double layer (``LlamaConfig.block ==
+    "shortcut"``): two sublayers ``i`` of latent attention ``A_i`` and
+    dense SwiGLU FFN ``F_i``, each with its own norms, and ONE routed
+    expert branch that reads the first sublayer's normed
+    post-attention stream and joins the residual at the END of the
+    second:
+
+        a0 = h  + A_0(norm_in0(h))
+        x0 = norm_post0(a0)
+        m  = MoE(x0)                          # read here ...
+        b0 = a0 + F_0(x0)
+        a1 = b0 + A_1(norm_in1(b0))
+        h' = a1 + F_1(norm_post1(a1)) + m     # ... added here
+
+    so the experts (and in a deployment their exchange) run beside the
+    first dense FFN and the whole second attention. A layer declares
+    TWO latent row leaves (``attention_0/kv``, ``attention_1/kv``):
+    the page manager pools whatever a layer declares. Scopes:
+    ``attention`` (> ``mla_core``, ``kv_down``), ``mlp`` >
+    ``dense_ffn`` | ``moe`` (> ``router``, ``experts``,
+    ``zero_experts``)."""
+
+    cfg: LlamaConfig
+    #: ``LlamaBlock``'s fields: every layer of this kind routes.
+    mlp: str = "moe"
+    layer: int = 0
+
+    @nn.compact
+    def __call__(
+        self, hidden, positions, kv_mask=None, decode: bool = False,
+        paged=None, adapters=None,
+    ):
+        from tpudl.ops.moe import DroplessMoE
+        from tpudl.ops.norms import fused_ops_impl
+
+        cfg = self.cfg
+        if adapters is not None:
+            raise ValueError(
+                "per-tenant adapters are not wired to the shortcut "
+                "double layer"
+            )
+        impl = fused_ops_impl(cfg.fused_ops)
+        for i in (0, 1):
+            normed = RMSNorm(
+                cfg.rms_norm_eps, impl, name=f"input_norm_{i}"
+            )(hidden)
+            with jax.named_scope("attention"):
+                attn = LatentAttention(cfg, name=f"attention_{i}")(
+                    normed, positions, kv_mask, decode, paged
+                )
+            x, hidden = RMSNorm(
+                cfg.rms_norm_eps, impl, name=f"post_attention_norm_{i}"
+            )(attn, residual=hidden)
+            with jax.named_scope("mlp"):
+                if i == 0:
+                    shortcut = DroplessMoE(
+                        num_experts=cfg.num_experts,
+                        experts_per_token=cfg.experts_per_token,
+                        intermediate_size=cfg.moe_intermediate_size,
+                        routed_scaling_factor=cfg.routed_scaling_factor,
+                        experts_held=cfg.experts_held,
+                        dtype=cfg.dtype,
+                        weight_dtype=cfg.weight_dtype,
+                        scoring=cfg.router_scoring,
+                        renormalize=cfg.router_renormalize,
+                        zero_experts=cfg.zero_experts,
+                        name="moe",
+                    )(x, real_tokens(x, kv_mask, paged))
+                with jax.named_scope("dense_ffn"):
+                    hidden = hidden + _DenseFFN(cfg, name=f"mlp_{i}")(x)
+        hidden = hidden + shortcut
+        return constrain(hidden, ("dp", "fsdp"), "sp", "tp")
+
+
+#: ``LlamaConfig.block`` -> the module a layer is.
+_BLOCKS = {"llama": LlamaBlock, "shortcut": ShortcutBlock}
 
 
 # ---------------------------------------------------------------------------

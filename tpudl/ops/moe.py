@@ -196,6 +196,16 @@ class _ExpertKernel(nn.Module):
         return kernel.astype(self.dtype), None
 
 
+#: The statistics a ``DroplessMoE`` sows into ``moe_stats``, in the
+#: order the serving contracts return them (tpudl.models.generate).
+MOE_STAT_NAMES = ("tokens_per_expert", "real_experts_a_token")
+
+#: How a router turns its outputs into scores.
+_SCORING = {
+    "sigmoid": jax.nn.sigmoid,
+    "softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+}
+
 #: Rows of a traced program past which the experts are dispatched by
 #: sorted groups (``DroplessMoE``): about four passes of the weights
 #: through the dense form on a v5e.
@@ -207,17 +217,31 @@ class DroplessMoE(nn.Module):
     serving needs them (a dropped token changes the served logits), for
     the experts THIS program holds.
 
-    The router keeps its published width: ``s = sigmoid(x W_r)`` over
-    all ``num_experts`` in float32, ``T = top_k(s + b)`` with the
-    selection bias ``b`` (it decides the choice and never the weight),
-    ``g_i = routed_scaling_factor * s_i / sum_{j in T} s_j``. The layer
+    The router keeps its published width and scores in float32. The
+    selection bias ``b`` decides the choice and never the weight. Two
+    routers, by ``scoring`` and ``renormalize``, ``f`` the
+    ``routed_scaling_factor``:
+
+        sigmoid, renormalised         softmax, not renormalised
+        s = sigmoid(x W_r)            p = softmax(x W_r)
+        T = top_k(s + b)              T = top_k(p + b)
+        g_i = f s_i / sum_{j in T} s_j      g_i = f p_i
+
+    (the two settings are independent; these are the pairs the served
+    configurations publish). ``W_r`` has ``num_experts +
+    zero_experts`` outputs. The ids past ``num_experts`` are IDENTITY
+    experts: they return their input, hold no weights, reach no
+    dispatch and no matmul, and in a deployment no exchange. The layer
     is told ``experts_held = (first, count)`` and computes
 
-        y = sum_{i in T, first <= i < first + count} g_i E_i(x) + E_shared(x)
+        y = sum_{i in T, first <= i < first + count} g_i E_i(x)
+            + (sum_{i in T, i >= num_experts} g_i) x  +  E_shared(x)
 
     What the other experts would add is left out: in a deployment it
     arrives from the chips that hold them, and no code here stands in
-    for them. The shared expert is computed once, by every share.
+    for them. The identity term (scope ``zero_experts``) and the shared
+    expert are computed once, by every share for its own tokens; a
+    token uses between 0 and ``experts_per_token`` real experts.
 
     Two dispatch forms compute the same sum over the same parameter
     tree, and the layer takes one by the STATIC row count of the
@@ -229,14 +253,14 @@ class DroplessMoE(nn.Module):
       what a call costs while an expert sees fewer tokens than the unit
       has rows; the gates fold into the down-projection's contraction,
       so no ``[held, tokens, hidden]`` tensor is made. Its operations
-      grow with rows x held experts.
+      grow with rows x held experts. An identity id has no column.
     - ``"sorted"``: the ``tokens x experts_per_token`` assignments are
       sorted by expert, the rows gathered in that order, and the three
       projections run as ragged grouped matmuls over the sorted groups
       (``jax.lax.ragged_dot``): each row meets only the experts it
-      chose. A choice of an expert held elsewhere sorts behind the
-      last group and is left out. The results go back to token order
-      and are summed by token in float32.
+      chose. A choice of an expert held elsewhere, or of an identity
+      expert, sorts behind the last group and is left out. The results
+      go back to token order and are summed by token in float32.
 
     The dense form does ``rows`` operations for every byte of weights
     it streams, so it costs one pass of the weights up to about 240
@@ -247,7 +271,11 @@ class DroplessMoE(nn.Module):
 
     ``real`` ([B, S] bool) marks the tokens that count (not padding,
     not an idle slot); the int32 ``[held]`` count of real tokens per
-    held expert is sown as ``moe_stats/tokens_per_expert``."""
+    held expert is sown as ``moe_stats/tokens_per_expert``, and where
+    the layer has identity experts the int32 ``[experts_per_token +
+    1]`` count of real tokens by how many REAL experts (held or not)
+    they chose as ``moe_stats/real_experts_a_token``
+    (``MOE_STAT_NAMES``)."""
 
     num_experts: int
     experts_per_token: int
@@ -257,6 +285,12 @@ class DroplessMoE(nn.Module):
     experts_held: Any = None
     dtype: Any = jnp.bfloat16
     weight_dtype: Any = None
+    #: "sigmoid" or "softmax" over the router's outputs.
+    scoring: str = "sigmoid"
+    #: Whether the chosen scores are divided by their sum.
+    renormalize: bool = True
+    #: Identity experts: router ids ``num_experts ...``.
+    zero_experts: int = 0
     #: "auto" (by the traced program's rows), or one form by name: the
     #: seam tests and measurements hold the two forms against each
     #: other through.
@@ -282,24 +316,30 @@ class DroplessMoE(nn.Module):
                 f"experts_held {(first, count)} outside the router's "
                 f"{self.num_experts} experts"
             )
+        if self.scoring not in _SCORING:
+            raise ValueError(
+                f"scoring must be one of {sorted(_SCORING)}, got "
+                f"{self.scoring!r}"
+            )
         h = self.intermediate_size
+        width = self.num_experts + self.zero_experts
         tokens = x.reshape(b * s, m)
         with jax.named_scope("moe"):
             with jax.named_scope("router"):
-                scores = jax.nn.sigmoid(nn.Dense(
-                    self.num_experts, use_bias=False, dtype=jnp.float32,
+                scores = _SCORING[self.scoring](nn.Dense(
+                    width, use_bias=False, dtype=jnp.float32,
                     kernel_init=nn.initializers.normal(0.02), name="router",
                 )(tokens.astype(jnp.float32)))
                 bias = self.param(
-                    "router_bias", nn.initializers.zeros, (self.num_experts,)
+                    "router_bias", nn.initializers.zeros, (width,)
                 )
                 _, chosen = jax.lax.top_k(
                     scores + bias.astype(jnp.float32), self.experts_per_token
                 )  # [T, k]
                 picked = jnp.take_along_axis(scores, chosen, axis=-1)
-                gates = self.routed_scaling_factor * picked / jnp.sum(
-                    picked, axis=-1, keepdims=True
-                )
+                gates = self.routed_scaling_factor * picked
+                if self.renormalize:
+                    gates = gates / jnp.sum(picked, axis=-1, keepdims=True)
                 dispatch = self.dispatch
                 if dispatch == "auto":
                     dispatch = (
@@ -325,6 +365,22 @@ class DroplessMoE(nn.Module):
                         jnp.where(held & real.reshape(-1, 1), local, count)
                     ].add(1)[:count]
                 self.sow("moe_stats", "tokens_per_expert", counts)
+                if self.zero_experts:
+                    # Identity choices name no weights: their gates are
+                    # summed a token, and the tokens counted by how
+                    # many real experts they chose.
+                    zero = chosen >= self.num_experts
+                    zero_gate = jnp.sum(jnp.where(zero, gates, 0.0), axis=-1)
+                    chose = (self.experts_per_token - zero.sum(axis=-1))[
+                        :, None
+                    ] == jnp.arange(self.experts_per_token + 1)
+                    self.sow(
+                        "moe_stats", "real_experts_a_token",
+                        jnp.sum(
+                            chose & real.reshape(-1, 1), axis=0,
+                            dtype=jnp.int32,
+                        ),
+                    )
             with jax.named_scope("experts"):
                 wg, sg = _ExpertKernel((count, m, h), self.dtype, name="gate_proj")()
                 wu, su = _ExpertKernel((count, m, h), self.dtype, name="up_proj")()
@@ -372,6 +428,11 @@ class DroplessMoE(nn.Module):
                     routed = routed * sd
                 routed = routed.astype(self.dtype)
             y = routed
+            if self.zero_experts:
+                with jax.named_scope("zero_experts"):
+                    y = y + (
+                        zero_gate[:, None] * tokens.astype(jnp.float32)
+                    ).astype(self.dtype)
             if self.shared_intermediate_size:
                 with jax.named_scope("shared_expert"):
                     f = self.shared_intermediate_size

@@ -54,14 +54,17 @@ SCALE_EPS = 1e-12
 Rule = Tuple[str, Optional[str]]
 Rules = Sequence[Rule]
 
-#: Which Llama leaves quantize: the seven per-block projections —
-#: embeddings, norms, lm_head, the classifier, and any LoRA adapters
-#: stay full precision (the rule-class contract tests/test_quant.py
-#: pins). Patterns are dtype-free; ``default_quant_rules`` pairs them
-#: with the requested storage dtype and appends the keep-all fallback.
+#: Which Llama leaves quantize: the seven per-block projections (the
+#: low-rank latent query's pair ``q_a_proj`` / ``q_b_proj`` where a
+#: configuration has one in place of ``q_proj``) — embeddings, norms,
+#: lm_head, the classifier, and any LoRA adapters stay full precision
+#: (the rule-class contract tests/test_quant.py pins). Patterns are
+#: dtype-free; ``default_quant_rules`` pairs them with the requested
+#: storage dtype and appends the keep-all fallback.
 LLAMA_QUANT_PATTERNS = (
     r"(q|k|v|o)_proj/kernel$",
     r"(gate|up|down)_proj/kernel$",
+    r"q_(a|b)_proj/kernel$",
 )
 
 #: Which BERT leaves quantize: encoder attention + MLP projections.

@@ -390,6 +390,24 @@ class ServeSession:
                     "yet (the draft path has no adapter view)"
                 )
         cfg = getattr(model, "cfg", None)
+        if getattr(cfg, "block", "llama") == "shortcut":
+            # The shortcut double layer (two latent attentions and two
+            # dense FFNs around one expert branch) is served from the
+            # paged pool, two leaves a layer, with its int8 store.
+            if adapters is not None:
+                raise ValueError(
+                    "per-tenant adapters are not wired to the shortcut "
+                    "double layer: the adapter pool addresses ONE "
+                    "attention and one dense MLP a layer, and this "
+                    "layer has two of each around an expert branch"
+                )
+            if spec_k:
+                raise ValueError(
+                    "spec_k is not wired to the shortcut double layer: "
+                    "the verify step does not report its window's "
+                    "tokens per expert nor its choices of identity "
+                    "experts"
+                )
         if getattr(cfg, "attention", "gqa") == "mla" or (
             getattr(cfg, "num_experts", 0) > 0
         ):

@@ -142,7 +142,7 @@ def first_token(logits, request, also=None):
     return int(sel[0]), also
 
 
-def record_expert_load(counts) -> dict:
+def record_expert_load(counts, chose=None) -> dict:
     """``counts``: int [expert layers, experts held] on the host, the
     real tokens each held expert got in one prefill or decode step
     (tpudl.ops.moe.DroplessMoE). Counted into the registry and returned
@@ -150,7 +150,18 @@ def record_expert_load(counts) -> dict:
     ``moe_experts_touched`` (held experts, over the layers, that got a
     token: those whose weights the step had to read) and
     ``moe_load_max_over_mean`` (the busiest held expert's tokens over
-    the mean, the worse layer's; 1.0 is even)."""
+    the mean, the worse layer's; 1.0 is even).
+
+    ``chose`` (a model with identity experts): int [expert layers,
+    experts_per_token + 1], the real tokens by how many REAL experts
+    they chose. From it ``moe_real_assignments`` (choices that name a
+    real expert, held here or elsewhere), ``moe_zero_assignments``
+    (choices that name an identity expert and cost nothing) and
+    ``moe_real_experts_a_token`` (the tokens by real experts chosen,
+    the layers summed); counters of the first two names under
+    ``serve_``, and each layer's mean real experts a token into the
+    histogram ``serve_moe_real_experts_a_token`` (one observation a
+    layer, not one a token: this runs between decode steps)."""
     counts = np.asarray(counts)
     total = int(counts.sum())
     reg = registry()
@@ -162,11 +173,29 @@ def record_expert_load(counts) -> dict:
     skew = [
         float(row.max() / mean) for row, mean in zip(counts, means) if mean > 0
     ]
-    return {
+    attrs = {
         "moe_assignments": total,
         "moe_experts_touched": int((counts > 0).sum()),
         "moe_load_max_over_mean": max(skew) if skew else 0.0,
     }
+    if chose is not None:
+        chose = np.asarray(chose)
+        k = chose.shape[1] - 1
+        by_layer = chose @ np.arange(k + 1)  # real choices a layer
+        tokens = chose.sum(axis=1)
+        real = int(by_layer.sum())
+        zero = int(tokens.sum()) * k - real
+        reg.counter("serve_moe_real_assignments").inc(real)
+        reg.counter("serve_moe_zero_assignments").inc(zero)
+        a_token = reg.histogram("serve_moe_real_experts_a_token")
+        for n, of in zip(by_layer.tolist(), tokens.tolist()):
+            if of:
+                a_token.observe(n / of)
+        attrs.update(
+            moe_real_assignments=real, moe_zero_assignments=zero,
+            moe_real_experts_a_token=chose.sum(axis=0).tolist(),
+        )
+    return attrs
 
 
 class _Prefilled:
@@ -564,7 +593,7 @@ class Engine:
             load = {}
             if counts:
                 first, counts = first_token(logits, req, also=counts)
-                load = record_expert_load(counts[0])
+                load = record_expert_load(*counts)
             else:
                 first = first_token(logits, req)
         except BaseException:
@@ -1354,7 +1383,7 @@ class Engine:
         sel, counts = jax.device_get((sel, self.cache.program_extras))
         if readback is not None:
             readback.end(self.clock())
-        load = record_expert_load(counts[0]) if counts else {}
+        load = record_expert_load(*counts) if counts else {}
         # Each ACTIVE slot's logical length advanced by one (idle
         # slots stay pinned on the trash page).
         self.cache.advance(
