@@ -775,6 +775,7 @@ def test_never_fitting_prefill_inbox_head_sheds(model_and_params):
             _Entry(priority=0, seq=0, request=req, deadline=None,
                    submitted_at=t),
             row_cache, first_token(logits, req), int(ids.shape[0]), t, t,
+            PROMPT_LEN,
         )
 
     huge = Request("huge", [1, 2, 3], max_new_tokens=CFG.max_seq_len)

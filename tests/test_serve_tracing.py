@@ -107,6 +107,51 @@ def test_request_trace_legs_recorded(model_and_params, tmp_path):
         assert completes[rid]["ttft_s"] == pytest.approx(res.ttft_s)
 
 
+@pytest.mark.parametrize("options, prompts, rows, tokens", [
+    # Padded to the one length of an 8-token window, whatever they hold.
+    ({}, [[5, 6, 7], [8, 9, 10, 11, 12, 13, 14, 15], [16]],
+     [8, 8, 8], [3, 8, 1]),
+    # A shared prefix: the first prompt runs whole, the next run the
+    # suffix the radix tree leaves them (pages of 2; the last token is
+    # always run), left-aligned with no padding.
+    ({"prefix_share": True, "page_size": 2},
+     [[5, 6, 7, 8, 9]] * 2 + [[5, 6, 7, 8, 20, 21]],
+     [8, 1, 2], [5, 1, 2]),
+])
+def test_prefill_spans_and_counters_say_rows_and_tokens(
+    model_and_params, tmp_path, options, prompts, rows, tokens
+):
+    """A ``prefill`` span says the length its program ran (``rows``)
+    and the prompt's tokens among them (``tokens``); the counters
+    ``serve_prefill_rows`` / ``serve_prefill_tokens`` sum them beside
+    ``serve_prefills``."""
+    model, params = model_and_params
+    obs.enable(str(tmp_path / "obs"))
+    session = ServeSession.from_model(
+        model, params, prompt_len=PROMPT_LEN, num_slots=1, **options
+    )
+    got = session.serve([
+        Request(f"p{i}", ids, max_new_tokens=2)
+        for i, ids in enumerate(prompts)
+    ])
+    records = obs_spans.active_recorder().records
+    obs.disable()
+    assert all(r.ok for r in got.values())
+    spans = {
+        r["request_id"]: r for r in records
+        if r.get("kind") == "span" and r.get("name") == "prefill"
+    }
+    assert [spans[f"p{i}"]["rows"] for i in range(3)] == rows
+    assert [spans[f"p{i}"]["tokens"] for i in range(3)] == tokens
+    assert [spans[f"p{i}"]["prefix_hit_tokens"] for i in range(3)] == [
+        len(ids) - n for ids, n in zip(prompts, tokens)
+    ]
+    reg = obs_counters.registry()
+    assert reg.counter("serve_prefills").value == 3
+    assert reg.counter("serve_prefill_rows").value == sum(rows)
+    assert reg.counter("serve_prefill_tokens").value == sum(tokens)
+
+
 def test_request_timeline_decomposition_sums(model_and_params, tmp_path):
     """The acceptance criterion: queue-wait + prefill + decode
     decomposition sums (within tolerance) to the measured

@@ -17,7 +17,10 @@ identical to live ``generate()`` (tests/test_serve.py asserts it;
 Admission errors (prompt longer than the compiled prompt window, or
 prompt window + max_new_tokens overflowing the KV-cache bound) raise at
 ``submit`` — a request that can NEVER be seated is a caller bug, not
-load. Overload is data, not an exception: a full queue or a missed
+load. The window is the LONGEST prompt a session takes, not what every
+prompt costs: ``from_model`` compiles its prefill at the window and at
+its half (``prefill_lengths``) and a prompt runs at the shortest length
+that holds it. Overload is data, not an exception: a full queue or a missed
 deadline produces a ``Result`` with finish_reason ``shed_capacity`` /
 ``shed_timeout``.
 
@@ -167,6 +170,41 @@ def validate_request(request: Request, prompt_len: int, max_seq_len: int) -> Non
         )
 
 
+#: Under about this many rows a batch-1 prefill costs one pass over the
+#: weights however few rows it has (one v5e chip: 197 TFLOP/s over 819
+#: GB/s is 240 operations a byte, and bfloat16 weights give one
+#: operation a byte a row): a shorter program would buy nothing and
+#: cost a compile.
+PREFILL_FLOOR_ROWS = 256
+
+
+def prefill_lengths(window: int) -> tuple:
+    """The lengths ``from_model`` compiles its batch-1 prefill at,
+    ascending: the prompt window and, where it is no lower than
+    ``PREFILL_FLOOR_ROWS``, its half (512 -> (256, 512); 4096 ->
+    (2048, 4096); 64 -> (64,)). One half and no more: every length is
+    a program to trace, load and warm at set-up, the first extra
+    length takes most of the padding away, and a quarter was measured
+    to cost set-up more than it gave (PERF.md, PR 34)."""
+    half, odd = divmod(int(window), 2)
+    if odd or half < PREFILL_FLOOR_ROWS:
+        return (int(window),)
+    return (half, int(window))
+
+
+def left_pad(input_ids, rows: int):
+    """A prompt as the batch-1 prefill takes it: ``(ids, mask)``, both
+    int32 ``[1, rows]``, the prompt at the right end under a mask of
+    ones (positions come from the mask's running sum)."""
+    ids = np.asarray(input_ids, np.int32)
+    pad = rows - ids.shape[0]
+    padded = np.concatenate([np.zeros(pad, np.int32), ids])[None, :]
+    mask = np.concatenate(
+        [np.zeros(pad, np.int32), np.ones(ids.shape[0], np.int32)]
+    )[None, :]
+    return padded, mask
+
+
 def _find_layer(tree, is_layer=_is_pool) -> Optional[dict]:
     """First per-layer cache dict in a cache pytree: a page pool of
     the decode artifact, or (``_is_attn_cache``) the dense rows of the
@@ -273,8 +311,14 @@ class ServeSession:
     ) -> "ServeSession":
         """Live-model session: jit the prefill/decode contracts (batch 1
         and batch ``num_slots`` respectively) and derive the cache
-        template by abstract evaluation — nothing compiles until the
-        first request.
+        template by abstract evaluation. ``prompt_len`` is the prompt
+        WINDOW, the longest prompt the session takes: a window of 512
+        or more is served by two prefill programs
+        (``prefill_lengths``: the window and its half), a prompt
+        runs at the shortest that holds it and reserves pages from that
+        length on, and every length's prefill and seat are compiled
+        (and run once, dry) before this returns. A shorter window has
+        one program and compiles nothing until the first request.
 
         ``prefix_share=True`` (or ``TPUDL_SERVE_PREFIX_SHARE=1``) turns
         on the radix prefix cache: seating
@@ -445,8 +489,23 @@ class ServeSession:
                         f"slot, stepped one token at a time on one chip"
                     )
         pf = prefill_fn(model)
+        prefill_call = jax.jit(pf)
         ids = jax.ShapeDtypeStruct((num_slots, prompt_len), jnp.int32)
-        _, cache_template, *_ = jax.eval_shape(pf, params, ids, ids)
+        # The cache template is one prefilled row at every slot. The
+        # row is traced through the jit, and at the batch-1 shape, that
+        # serves the window's prompts: the jit keeps the trace, so the
+        # model is traced once for its window and not twice (0.6 s of
+        # set-up for 16 layers). Rows and their validity carry the
+        # batch axis; the write index and a window layer's marker
+        # (``[window]``) have none.
+        row_ids = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
+        _, row, *_ = jax.eval_shape(prefill_call, params, row_ids, row_ids)
+        cache_template = jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                (num_slots, *leaf.shape[1:]), leaf.dtype
+            ) if leaf.ndim >= 2 else leaf,
+            row,
+        )
         speculator = None
         verify = None
         if kv_dtype is None:
@@ -571,16 +630,30 @@ class ServeSession:
                 ),
                 donate_argnums=(1,),
             )
-        prefill_call = (
-            jax.jit(lora_prefill_fn(model, impl=adapter_impl))
-            if adapters is not None
-            else jax.jit(pf)
-        )
-        return cls(
+        if adapters is not None:
+            prefill_call = jax.jit(lora_prefill_fn(model, impl=adapter_impl))
+        session = cls(
             prefill_call, decode, params, cache, prompt_len,
             chunk_prefill_call=chunk_prefill, speculator=speculator,
             verify_call=verify, **kwargs,
         )
+        lengths = prefill_lengths(prompt_len)
+        shapes_alone = any(
+            isinstance(leaf, jax.ShapeDtypeStruct)
+            for leaf in jax.tree.leaves(params)
+        )
+        if len(lengths) > 1 and adapters is None and not shapes_alone:
+            # A caller's warm-up reaches only the lengths its prompts
+            # pick, so the session makes every length's programs exist
+            # itself, here. One length stays where there is nothing
+            # more to make or nothing to run it with: a window too
+            # short to halve (its one program compiled by the first
+            # request, as before), the adapter prefill (three more
+            # traced inputs), and parameters that are shapes alone (a
+            # compile rehearsal for a described chip: it serves
+            # nothing and lowers the programs at the shapes it names).
+            session.engine.compile_prefill_lengths(lengths)
+        return session
 
     @classmethod
     def from_artifacts(

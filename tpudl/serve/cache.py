@@ -849,6 +849,8 @@ class PagedKVCache:
         (``[0, prompt_len)``, quantizing if int8) into the first pages.
         ``pad`` is the row's left-pad count — logical positions below
         it stay masked, exactly like dense validity."""
+        import numpy as np
+
         if self.prefix_share:
             raise ValueError(
                 "prefix-share caches seat left-aligned via seat_shared "
@@ -880,7 +882,10 @@ class PagedKVCache:
         self.lens[slot] = prompt_len
         self._seated(slot, len(pages))
         prompt_pages = self.pages_needed(prompt_len)
-        page_ids = jnp.asarray(pages[:prompt_pages], jnp.int32)
+        # Page ids go to the program as the host holds them: made an
+        # int32 array on the device, each new count of them would be a
+        # small program of its own, compiled at a length's first seat.
+        page_ids = np.asarray(pages[:prompt_pages], np.int32)
         if self.window:
             # The prompt's last pages go where the ring keeps them:
             # logical page j on ring page j mod ring_pages.
@@ -889,9 +894,24 @@ class PagedKVCache:
             self.ring_table[slot] = ring
             self.pages_reserved_window += self.ring_pages
             kept = range(self._first_kept_page(prompt_pages), prompt_pages)
-            page_ids = (page_ids, jnp.asarray(
-                [ring[j % self.ring_pages] for j in kept], jnp.int32
+            page_ids = (page_ids, np.asarray(
+                [ring[j % self.ring_pages] for j in kept], np.int32
             ))
+        self._replace_pool(
+            self._seat_program(prompt_pages), row_cache, page_ids
+        )
+
+    def compile_seat(self, row_cache: Any, prompt_len: int) -> None:
+        """Run the seat program for rows of ``prompt_len`` once, aimed
+        at the trash page: it is compiled and loaded, and no slot, page
+        list or counter changes."""
+        import numpy as np
+
+        prompt_pages = self.pages_needed(prompt_len)
+        page_ids = np.zeros((prompt_pages,), np.int32)
+        if self.window:
+            kept = prompt_pages - self._first_kept_page(prompt_pages)
+            page_ids = (page_ids, np.zeros((kept,), np.int32))
         self._replace_pool(
             self._seat_program(prompt_pages), row_cache, page_ids
         )
@@ -904,8 +924,8 @@ class PagedKVCache:
 
     def _seat_program(self, prompt_pages: int):
         """The jitted, pool-donating scatter for prompts of
-        ``prompt_pages`` pages: one program per distinct count (in
-        practice one — the session's prompt window is fixed)."""
+        ``prompt_pages`` pages: one program per distinct count (one
+        a prefill length of the session)."""
         fn = self._seat_jit.get(prompt_pages)
         if fn is None:
             fn = self._seat_jit[prompt_pages] = jax.jit(

@@ -60,7 +60,7 @@ import numpy as np
 from tpudl.obs import registry
 from tpudl.obs import requestlog
 from tpudl.obs.spans import active_recorder
-from tpudl.serve.api import Request, Result
+from tpudl.serve.api import Request, Result, left_pad
 from tpudl.serve.cache import (
     MigrationCompatError,
     MigrationCorruptError,
@@ -205,17 +205,19 @@ class _Prefilled:
 
     __slots__ = (
         "entry", "row_cache", "first_token", "prompt_ids_len",
-        "t_popped", "t_first",
+        "t_popped", "t_first", "rows",
     )
 
     def __init__(self, entry: _Entry, row_cache: Any, first_token: int,
-                 prompt_ids_len: int, t_popped: float, t_first: float):
+                 prompt_ids_len: int, t_popped: float, t_first: float,
+                 rows: int):
         self.entry = entry
         self.row_cache = row_cache
         self.first_token = first_token
         self.prompt_ids_len = prompt_ids_len
         self.t_popped = t_popped  # queue wait ended here (prefill start)
         self.t_first = t_first  # first token selected here (TTFT end)
+        self.rows = rows  # the length the prompt was padded to
 
 
 class _Slot:
@@ -317,6 +319,9 @@ class Engine:
         self.cache = cache
         self.queue = queue
         self.prompt_len = prompt_len
+        # The lengths the batch-1 prefill runs at, ascending: the
+        # window alone until compile_prefill_lengths adds shorter ones.
+        self.prefill_lengths = (prompt_len,)
         self.num_slots = cache.num_slots
         self.max_seq_len = cache.max_seq_len
         self.clock = clock
@@ -488,6 +493,36 @@ class Engine:
         if self._slo is not None:
             self._slo.observe(metric, value)
 
+    # -- prefill lengths -----------------------------------------------
+
+    def prefill_rows(self, tokens: int) -> int:
+        """The shortest compiled prefill length that holds a prompt of
+        ``tokens`` tokens (the window for one that admission should
+        have refused: the pad then fails as it always did)."""
+        return next(
+            (rows for rows in self.prefill_lengths if rows >= tokens),
+            self.prompt_len,
+        )
+
+    def compile_prefill_lengths(self, lengths) -> None:
+        """Serve prompts at these lengths (``tpudl.serve.api
+        .prefill_lengths``; the window stays the longest): every
+        length's batch-1 prefill and seat, and a speculator's draft
+        pair, are compiled and run once here, dry, so that no request
+        meets a program that does not exist yet. The dry seat aims at
+        the trash page and no slot is touched. A radix session seats
+        through ONE left-aligned program whatever the row's length, so
+        only its prefill is made."""
+        for rows in lengths:
+            _, row_cache, *_ = self.prefill_call(
+                self.params, *left_pad([0], rows)
+            )
+            if not self.prefix_share:
+                self.cache.compile_seat(row_cache, rows)
+            if self.speculator is not None:
+                self.speculator.compile_seat(rows)
+        self.prefill_lengths = tuple(sorted({*lengths, self.prompt_len}))
+
     # -- admission / seating -------------------------------------------
 
     def _record_shed(self, entries: List[_Entry], reason: str) -> None:
@@ -524,6 +559,7 @@ class Engine:
         cost drops from O(prompt window) to O(unshared suffix)."""
         req = entry.request
         ids = np.asarray(req.input_ids, np.int32)
+        n = int(ids.shape[0])
         rec = self._rec
         t0 = self.clock()
         span = None
@@ -539,7 +575,11 @@ class Engine:
         hit = 0
         tenant_pinned = False
         reloads0 = 0
-        row_offset = self.prompt_len - int(ids.shape[0])
+        # The prompt runs left-padded to the shortest compiled length
+        # that holds it; the row, its seat and its pages follow that
+        # length, not the window.
+        rows = ran = self.prefill_rows(n)
+        row_offset = rows - n
         try:
             if self.adapter_pool is not None:
                 # Pin the tenant's adapter pages BEFORE the prefill
@@ -554,28 +594,19 @@ class Engine:
                 # A fully-matched prompt still needs its LAST token's
                 # logits to select the first generated token, so the
                 # compute skip caps at ids_len - 1.
-                hit = min(len(lease[0]) * self.cache.page_size,
-                          int(ids.shape[0]) - 1)
+                hit = min(len(lease[0]) * self.cache.page_size, n - 1)
             if hit > 0 and self.chunk_prefill_call is not None:
-                rows = self.cache.gather_prefix_rows(lease[0], hit)
+                prefix = self.cache.gather_prefix_rows(lease[0], hit)
                 suffix = ids[hit:][None, :]
-                positions = np.arange(
-                    hit, ids.shape[0], dtype=np.int32
-                )[None, :]
+                positions = np.arange(hit, n, dtype=np.int32)[None, :]
                 logits, row_cache, *counts = self.chunk_prefill_call(
-                    self.params, rows, suffix, positions
+                    self.params, prefix, suffix, positions
                 )
                 row_offset = 0  # chunk rows are already left-aligned
+                ran = suffix.shape[1]
             else:
                 hit = 0  # no chunk program: full prefill, pages dedup only
-                pad = self.prompt_len - ids.shape[0]
-                padded = np.concatenate(
-                    [np.zeros(pad, np.int32), ids]
-                )[None, :]
-                mask = np.concatenate(
-                    [np.zeros(pad, np.int32),
-                     np.ones(ids.shape[0], np.int32)]
-                )[None, :]
+                padded, mask = left_pad(ids, rows)
                 if self.adapter_pool is not None:
                     logits, row_cache = self.prefill_call(
                         self.params, padded, mask,
@@ -606,13 +637,19 @@ class Engine:
         if span is not None:
             # prefix_hit_tokens names how much of the prompt the radix
             # cache paid for (report.py --request's TTFT attribution).
-            span.end(now, prefix_hit_tokens=hit, **load)
+            # rows: the length the program ran; tokens: the prompt's
+            # among them (what the padding and a shared prefix leave).
+            span.end(now, prefix_hit_tokens=hit, rows=ran, tokens=n - hit,
+                     **load)
+        reg = registry()
         if hit:
-            registry().counter("serve_prefix_hit_tokens").inc(hit)
+            reg.counter("serve_prefix_hit_tokens").inc(hit)
         self.num_prefills += 1
-        registry().counter("serve_prefills").inc()
-        self._install(entry, slot, row_cache, first, ids.shape[0], t0, now,
-                      lease=lease, row_offset=row_offset,
+        reg.counter("serve_prefills").inc()
+        reg.counter("serve_prefill_rows").inc(ran)
+        reg.counter("serve_prefill_tokens").inc(n - hit)
+        self._install(entry, slot, row_cache, first, n, t0, now,
+                      rows, lease=lease, row_offset=row_offset,
                       tenant_pinned=self.adapter_pool is not None,
                       prefix_hit=hit,
                       adapter_reloads=(
@@ -626,19 +663,20 @@ class Engine:
         no local batch-1 dispatch — this engine only decodes."""
         self._install(
             item.entry, slot, item.row_cache, item.first_token,
-            item.prompt_ids_len, item.t_popped, item.t_first,
+            item.prompt_ids_len, item.t_popped, item.t_first, item.rows,
         )
 
     def _install(self, entry: _Entry, slot: int, row_cache: Any,
                  first: int, ids_len: int, t_popped: float,
-                 t_first: float, lease=None, row_offset: Optional[int] = None,
+                 t_first: float, rows: int, lease=None,
+                 row_offset: Optional[int] = None,
                  tenant_pinned: bool = False, prefix_hit: int = 0,
                  adapter_reloads: int = 0,
                  ) -> None:
         """Shared seat tail: cache insertion (page reservation+scatter,
         or radix-shared left-aligned seat),
         latency accounting, draft-cache seating, adapter binding, slot
-        activation."""
+        activation. ``rows`` is the length the row was prefilled at."""
         req = entry.request
         tenant = getattr(req, "tenant", None)
         if self.adapter_pool is not None and not tenant_pinned:
@@ -666,14 +704,14 @@ class Engine:
                     row_cache, slot, ids, ids_len + req.max_new_tokens,
                     lease=lease,
                     row_offset=(
-                        self.prompt_len - ids_len
+                        rows - ids_len
                         if row_offset is None else row_offset
                     ),
                 )
             else:
                 self.cache.seat(
-                    row_cache, slot, self.prompt_len - ids_len,
-                    self.prompt_len, self.prompt_len + req.max_new_tokens,
+                    row_cache, slot, rows - ids_len,
+                    rows, rows + req.max_new_tokens,
                 )
         except BaseException:
             # A failed seat must not strand the tenant pin: the slot
@@ -692,7 +730,7 @@ class Engine:
         if self.speculator is not None:
             self.speculator.seat(
                 slot, np.asarray(req.input_ids, np.int32),
-                self.prompt_len, self.prompt_len + req.max_new_tokens,
+                rows, rows + req.max_new_tokens,
             )
         queue_wait_ms = 1e3 * (t_popped - entry.submitted_at)
         ttft_ms = 1e3 * (t_first - entry.submitted_at)
@@ -777,8 +815,9 @@ class Engine:
             )
             if slot is None:
                 break
-            if not self._fits(self.prefill_inbox[0].entry.request):
-                if self._fits_ever(self.prefill_inbox[0].entry.request):
+            head = self.prefill_inbox[0]
+            if not self._fits(head.entry.request, head.rows):
+                if self._fits_ever(head.entry.request, head.rows):
                     break  # fits once seated work frees capacity
                 # A never-fitting head (too big for even an EMPTY
                 # cache) would otherwise block every prefilled request
@@ -852,14 +891,16 @@ class Engine:
             attrs["tokens_live_window"] = cache.tokens_live_window
         return attrs
 
-    def _fits(self, request) -> bool:
+    def _fits(self, request, rows: Optional[int] = None) -> bool:
         """Can this request be seated RIGHT NOW? Its worst case fits
         the per-slot logical bound and enough pool pages are free to
         reserve it up front (so it can never strand mid-decode).
-        Radix mode counts only the UNSHARED pages (matched prefix
+        ``rows`` is the length its row was prefilled at, where another
+        worker has prefilled it; else the length its prompt will run
+        at. Radix mode counts only the UNSHARED pages (matched prefix
         pages seat for free — sharing multiplies admission capacity on
         top of int8's byte multiplier), and left-aligned seating
-        reserves from the real prompt length, not the padded window.
+        reserves from the real prompt length, not the padded row.
         A speculating engine additionally needs draft-cache room; an
         adapter-serving engine needs the tenant's pages securable
         (resident, or loadable by evicting lease-free adapters)."""
@@ -868,13 +909,15 @@ class Engine:
         ):
             if not self.adapter_pool.can_seat(request.tenant):
                 return False
+        if rows is None:
+            rows = self.prefill_rows(len(request.input_ids))
         if self.speculator is not None:
-            # Pad-aligned draft seating reserves the full prompt
-            # window. submit() already validates prompt_len + max_new
+            # Pad-aligned draft seating reserves the whole padded row.
+            # submit() already validates prompt_len + max_new
             # against the session bound, so the bound check here is
             # belt-and-suspenders for work pushed straight onto the
             # queue.
-            draft_need = self.prompt_len + request.max_new_tokens
+            draft_need = rows + request.max_new_tokens
             if draft_need > self.speculator.cache.max_seq_len or not (
                 self.speculator.cache.fits_tokens(draft_need)
             ):
@@ -884,17 +927,19 @@ class Engine:
             return need <= self.max_seq_len and self.cache.fits_request(
                 request.input_ids, need
             )
-        need = self.prompt_len + request.max_new_tokens
+        need = rows + request.max_new_tokens
         return need <= self.max_seq_len and self.cache.fits_tokens(need)
 
-    def _fits_ever(self, request) -> bool:
+    def _fits_ever(self, request, rows: Optional[int] = None) -> bool:
         """Could this request be seated in an EMPTY cache? False means
         waiting can never help (the worst case exceeds the compiled
         seq-len bound, or the pool is too small outright)."""
+        if rows is None:
+            rows = self.prefill_rows(len(request.input_ids))
         need = (
             len(request.input_ids) + request.max_new_tokens
             if self.prefix_share
-            else self.prompt_len + request.max_new_tokens
+            else rows + request.max_new_tokens
         )
         if need > self.max_seq_len:
             return False
@@ -904,7 +949,7 @@ class Engine:
             if not self.adapter_pool.can_ever_seat(request.tenant):
                 return False
         if self.speculator is not None:
-            draft_need = self.prompt_len + request.max_new_tokens
+            draft_need = rows + request.max_new_tokens
             if draft_need > self.speculator.cache.max_seq_len or (
                 self.speculator.cache.pages_needed(draft_need)
                 > self.speculator.cache.num_pages - 1

@@ -75,7 +75,13 @@ from tpudl.analysis.registry import env_int
 from tpudl.obs import metering, registry, requestlog
 from tpudl.obs.spans import active_recorder
 from tpudl.serve import chaos as serve_chaos
-from tpudl.serve.api import Request, Result, ServeSession, validate_request
+from tpudl.serve.api import (
+    Request,
+    Result,
+    ServeSession,
+    left_pad,
+    validate_request,
+)
 from tpudl.serve.queue import CAT_SERVE_REQUEST, _Entry
 
 
@@ -644,8 +650,6 @@ class PrefillWorker:
             self._thread = None
 
     def _loop(self) -> None:
-        import numpy as np
-
         from tpudl.serve.engine import (
             CAT_SERVE_PREFILL,
             _Prefilled,
@@ -669,15 +673,10 @@ class PrefillWorker:
                 continue
             try:
                 req = entry.request
-                ids = np.asarray(req.input_ids, np.int32)
-                pad = self.prompt_len - ids.shape[0]
-                padded = np.concatenate(
-                    [np.zeros(pad, np.int32), ids]
-                )[None, :]
-                mask = np.concatenate(
-                    [np.zeros(pad, np.int32),
-                     np.ones(ids.shape[0], np.int32)]
-                )[None, :]
+                # One length here, the window: the worker's program
+                # is whatever it was handed, and a decode replica seats
+                # a row of any of its own lengths.
+                padded, mask = left_pad(req.input_ids, self.prompt_len)
                 t0 = self.clock()
                 logits, row_cache, *_ = self.prefill_call(
                     self.params, padded, mask
@@ -691,13 +690,19 @@ class PrefillWorker:
                         {"worker": self.name,
                          "request_id": req.request_id,
                          "queue_wait_s": t0 - entry.submitted_at,
-                         "disaggregated": True},
+                         "disaggregated": True,
+                         "rows": self.prompt_len,
+                         "tokens": len(req.input_ids)},
                     )
                 self.num_prefills += 1
-                registry().counter("serve_prefills").inc()
-                registry().counter("serve_disaggregated_prefills").inc()
+                reg = registry()
+                reg.counter("serve_prefills").inc()
+                reg.counter("serve_prefill_rows").inc(self.prompt_len)
+                reg.counter("serve_prefill_tokens").inc(len(req.input_ids))
+                reg.counter("serve_disaggregated_prefills").inc()
                 item = _Prefilled(
-                    entry, row_cache, first, int(ids.shape[0]), t0, now
+                    entry, row_cache, first, len(req.input_ids), t0, now,
+                    self.prompt_len,
                 )
                 if self.place is None:
                     raise RuntimeError(

@@ -55,6 +55,8 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from tpudl.serve.api import left_pad
+
 
 def _philox(seed: int, token_index: int, salt: int) -> np.random.Generator:
     """Counter-keyed per-(request, position) randomness: deterministic
@@ -177,16 +179,21 @@ class Speculator:
         the engine's own seat) and seat its draft KV row — the draft's
         own view of the prompt (its KV differs from the target's, so
         sharing a cache is impossible by construction)."""
-        ids = np.asarray(input_ids, np.int32)
-        pad = prompt_len - ids.shape[0]
-        padded = np.concatenate([np.zeros(pad, np.int32), ids])[None, :]
-        mask = np.concatenate(
-            [np.zeros(pad, np.int32), np.ones(ids.shape[0], np.int32)]
-        )[None, :]
-        _, row_cache, *_ = self.prefill_call(self.params, padded, mask)
-        self.cache.seat(
-            row_cache, slot, pad, prompt_len, reserve_tokens,
+        _, row_cache, *_ = self.prefill_call(
+            self.params, *left_pad(input_ids, prompt_len)
         )
+        self.cache.seat(
+            row_cache, slot, prompt_len - len(input_ids), prompt_len,
+            reserve_tokens,
+        )
+
+    def compile_seat(self, prompt_len: int) -> None:
+        """The draft's prefill and seat at rows of ``prompt_len``,
+        compiled and run once, dry (``Engine.compile_prefill_lengths``)."""
+        _, row_cache, *_ = self.prefill_call(
+            self.params, *left_pad([0], prompt_len)
+        )
+        self.cache.compile_seat(row_cache, prompt_len)
 
     def free(self, slot: int) -> None:
         self.cache.free(slot)
