@@ -274,18 +274,34 @@ def test_spans_module_does_not_import_jax():
 # ---------------------------------------------------------------------------
 
 
-def _nested_records():
-    """Two engine steps on one clock: the first seats a request."""
+def _nested_records(parts=False):
+    """Two engine steps on one clock: the first seats a request. With
+    ``parts``, the same script of clock readings with the spans that
+    ISSUE 36 put around and inside the old ones."""
     rec = obs_spans.SpanRecorder(clock=FakeClock(), host="h", process=0)
     for seats in (1, 0):
         step = rec.begin("engine_step", "serve_engine", 100.0 * (2 - seats))
         t = step.t0
+        admit = (rec.begin("admit", "serve_engine", t + 0.5)
+                 if parts else None)
         if seats:
-            rec.begin("prefill", "serve_prefill", t + 1, request_id="a",
-                      queue_wait_s=0.5).end(t + 11)
+            p = rec.begin("prefill", "serve_prefill", t + 1, request_id="a",
+                          queue_wait_s=0.5)
+            if parts:
+                rec.begin("prefill.dispatch", "serve_prefill", t + 1).end(t + 4)
+                rec.begin("prefill.readback", "serve_prefill", t + 4).end(t + 11)
+            p.end(t + 11)
             rec.begin("seat", "serve_seat", t + 11, request_id="a").end(t + 13)
+        if parts:
+            admit.end(t + 15, popped=seats, shed=0, queue_depth=0)
+            rec.begin("decode_prepare", "serve_engine", t + 16).end(
+                t + 20, slots=2)
         d = rec.begin("decode_step", "serve_decode", t + 20)
-        rec.begin("decode.dispatch", "serve_decode", t + 20).end(t + 23)
+        dispatch = rec.begin("decode.dispatch", "serve_decode", t + 20)
+        if parts:
+            rec.begin("decode.address", "serve_decode", t + 20).end(
+                t + 21, bytes=64)
+        dispatch.end(t + 23)
         rec.begin("decode.readback", "serve_decode", t + 23).end(t + 29)
         d.end(t + 30, busy=1, rids=["a"])
         rec.begin("emit", "serve_emit", t + 30).end(t + 32)
@@ -293,8 +309,13 @@ def _nested_records():
     return rec.records
 
 
-def test_goodput_counts_each_second_once():
-    records = _nested_records()
+PARTS = pytest.mark.parametrize("parts", [False, True],
+                                ids=["old_spans", "with_parts"])
+
+
+@PARTS
+def test_goodput_counts_each_second_once(parts):
+    records = _nested_records(parts)
     cls = goodput.classify(records)
     # Two steps of 40 s; every second inside them is accounted once.
     assert cls["wall_s"] == pytest.approx(140.0)
@@ -308,8 +329,22 @@ def test_goodput_counts_each_second_once():
     assert by_cat["serve_prefill"] == pytest.approx(10.0)
     assert by_cat["serve_seat"] == pytest.approx(2.0)
     assert by_cat["serve_emit"] == pytest.approx(4.0)
-    # engine_step's self time: what it did outside its children.
+    # The engine's category: what a step did outside the other four
+    # (engine_step's self time, and with the parts admit's and the
+    # step's preparation).
     assert by_cat["serve_engine"] == pytest.approx(80.0 - 36.0)
+    if parts:
+        own = {}
+        for s, sec in obs_spans.self_seconds(_spans(records)):
+            own[s["name"]] = own.get(s["name"], 0.0) + sec
+        # admit: 14.5 s a step less the prefill and the seat it caused.
+        assert own["admit"] == pytest.approx(2 * 14.5 - 12.0)
+        assert own["decode_prepare"] == pytest.approx(8.0)
+        assert own["prefill"] == pytest.approx(0.0)
+        assert own["decode.dispatch"] == pytest.approx(2 * 2.0)
+        assert own["engine_step"] == pytest.approx(
+            44.0 - 17.0 - 8.0
+        )
 
 
 def test_goodput_train_step_with_a_child_of_its_category_is_one_step():
@@ -338,18 +373,61 @@ def test_records_without_ids_classify_as_before():
     assert obs_spans.without_same_category_children(old) == old
 
 
-def test_report_breakdown_counts_decode_step_once():
-    report = obs_report.build_report(_nested_records())
-    row = report["breakdown"]["serve_decode"]
-    assert row["count"] == 2
-    assert row["total_s"] == pytest.approx(20.0)
-    assert report["breakdown"]["serve_engine"]["count"] == 2
+@PARTS
+def test_report_breakdown_counts_decode_step_once(parts):
+    """The serve totals are what they were: a child of its parent's
+    category (admit and decode_prepare under engine_step, the halves of
+    a prefill, the addressing inside the dispatch) adds no row entry."""
+    report = obs_report.build_report(_nested_records(parts))
+    rows = report["breakdown"]
+    assert rows["serve_decode"]["count"] == 2
+    assert rows["serve_decode"]["total_s"] == pytest.approx(20.0)
+    assert rows["serve_engine"]["count"] == 2
+    assert rows["serve_engine"]["total_s"] == pytest.approx(80.0)
+    assert rows["serve_prefill"]["count"] == 1
+    assert rows["serve_prefill"]["total_s"] == pytest.approx(10.0)
+    assert rows["serve_seat"]["total_s"] == pytest.approx(2.0)
+    assert rows["serve_emit"]["total_s"] == pytest.approx(4.0)
 
 
-def test_request_timeline_takes_only_the_two_legs():
-    """`seat` names its request too, and the decode step's children
-    share its category: neither is a leg of the timeline."""
-    records = _nested_records() + [
+def test_report_lists_the_serve_phases_as_their_tree():
+    records = _nested_records(parts=True)
+    phases = obs_report.build_report(records)["serve_phases"]
+    assert list(phases) == [
+        "engine_step", "admit", "prefill", "prefill.dispatch",
+        "prefill.readback", "seat", "decode_prepare", "decode_step",
+        "decode.dispatch", "decode.address", "decode.readback", "emit",
+    ]
+    assert [phases[n]["depth"] for n in phases] == [
+        0, 1, 2, 3, 3, 2, 1, 1, 2, 3, 2, 1
+    ]
+    # Every second of the two steps is some phase's own.
+    assert sum(r["self_s"] for r in phases.values()) == pytest.approx(80.0)
+    admit = phases["admit"]
+    assert (admit["count"], admit["total_s"]) == (2, pytest.approx(29.0))
+    assert admit["self_s"] == pytest.approx(17.0)
+    assert (admit["queue_depth_mean"], admit["queue_depth_max"]) == (0.0, 0.0)
+    assert phases["decode.dispatch"]["self_s"] == pytest.approx(4.0)
+    text = obs_report.format_report(obs_report.build_report(records))
+    assert "serve phase" in text and "      decode.address" in text
+    assert "queue_depth mean 0.0 max 0" in text
+    # The old spans alone make the old rows; a run that served nothing
+    # has no such table.
+    old = obs_report.build_report(_nested_records())["serve_phases"]
+    assert list(old) == ["engine_step", "prefill", "seat", "decode_step",
+                         "decode.dispatch", "decode.readback", "emit"]
+    assert old["engine_step"]["self_s"] == pytest.approx(44.0)
+    assert obs_report.build_report([])["serve_phases"] == {}
+    assert "serve phase" not in obs_report.format_report(
+        obs_report.build_report([]))
+
+
+@PARTS
+def test_request_timeline_takes_only_the_two_legs(parts):
+    """`seat` names its request too, and the children of a prefill and
+    of a decode step share their category: none is a leg of the
+    timeline."""
+    records = _nested_records(parts) + [
         {"kind": "event", "name": "request_complete", "cat": "serve_request",
          "ts": 240.0, "request_id": "a", "finish_reason": "length",
          "ttft_s": 10.5, "tpot_s": 10.0, "queue_wait_s": 0.5,
@@ -390,11 +468,16 @@ def _inside(child, parent, eps=1e-9):
 
 
 @pytest.mark.parametrize("child,parent", [
-    ("prefill", "engine_step"),
-    ("seat", "engine_step"),
+    ("admit", "engine_step"),
+    ("prefill", "admit"),
+    ("prefill.dispatch", "prefill"),
+    ("prefill.readback", "prefill"),
+    ("seat", "admit"),
+    ("decode_prepare", "engine_step"),
     ("decode_step", "engine_step"),
     ("emit", "engine_step"),
     ("decode.dispatch", "decode_step"),
+    ("decode.address", "decode.dispatch"),
     ("decode.readback", "decode_step"),
 ])
 def test_engine_phase_nests_in_its_parent(served, child, parent):
@@ -422,8 +505,113 @@ def test_engine_step_is_top_level_and_counts_its_seats(served):
         )
         for a, b in zip(kids, kids[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
-    # The last step found nothing to do and has no children.
+    # The last step found nothing to do: it only looked for work.
     assert steps[-1]["busy"] == 0
+    assert [s["name"] for s in _spans(records)
+            if s["parent"] == steps[-1]["id"]] == ["admit"]
+
+
+def test_children_add_up_to_no_more_than_their_parent(served):
+    records, _, _ = served
+    total = {}
+    for s in _spans(records):
+        if s["parent"] is not None:
+            total[s["parent"]] = total.get(s["parent"], 0.0) + s["dur"]
+    assert total
+    for s in _spans(records):
+        assert total.get(s["id"], 0.0) <= s["dur"] + 1e-9, s["name"]
+
+
+def test_the_halves_of_a_prefill_tile_it(served):
+    records, _, _ = served
+    prefills = _spans(records, "prefill")
+    assert prefills
+    for p in prefills:
+        first, second = sorted(
+            (s for s in _spans(records) if s["parent"] == p["id"]),
+            key=lambda s: s["ts"],
+        )
+        assert (first["name"], second["name"]) == (
+            "prefill.dispatch", "prefill.readback"
+        )
+        assert first["cat"] == second["cat"] == p["cat"]
+        assert first["ts"] == p["ts"]
+        assert first["ts"] + first["dur"] == pytest.approx(second["ts"])
+        assert second["ts"] + second["dur"] == pytest.approx(
+            p["ts"] + p["dur"]
+        )
+
+
+def test_admit_prepare_and_address_carry_their_attributes(served):
+    records, results, session = served
+    admits = _spans(records, "admit")
+    steps = _spans(records, "engine_step")
+    assert len(admits) == len(steps)
+    assert all(a["cat"] == "serve_engine" for a in admits)
+    assert sum(a["popped"] for a in admits) == len(results)
+    assert all(a["shed"] == 0 for a in admits)
+    # Five requests on two slots: three wait after the first admission,
+    # none after the last.
+    assert admits[0]["queue_depth"] == 3
+    assert admits[-1]["queue_depth"] == 0
+    prepares = _spans(records, "decode_prepare")
+    assert len(prepares) == len(_spans(records, "decode_step"))
+    assert all(p["slots"] == 2 and p["cat"] == "serve_engine"
+               for p in prepares)
+    cache = session.engine.cache
+    sent = (cache.page_table.nbytes + cache.start.nbytes
+            + cache.lens.nbytes)
+    assert cache.addressing_nbytes == sent
+    assert all(a["bytes"] == sent for a in _spans(records, "decode.address"))
+
+
+def test_decode_prepare_ends_where_decode_step_begins(served):
+    """One clock reading ends the preparation and begins the step, as
+    one ends the step and begins the emit."""
+    records, _, _ = served
+    by_parent = {}
+    for s in _spans(records):
+        by_parent.setdefault(s["parent"], []).append(s)
+    for step in _spans(records, "engine_step"):
+        kids = sorted(by_parent.get(step["id"], []), key=lambda s: s["ts"])
+        names = [k["name"] for k in kids]
+        if "decode_step" not in names:
+            continue
+        assert names == ["admit", "decode_prepare", "decode_step", "emit"]
+        _, prepare, decode, emit = kids
+        assert prepare["ts"] + prepare["dur"] == pytest.approx(decode["ts"])
+        assert decode["ts"] + decode["dur"] == pytest.approx(emit["ts"])
+
+
+def test_engine_step_self_time_is_its_boundaries_alone(model_and_params,
+                                                       tmp_path):
+    """On a clock that moves one tick a reading, a span lasts as many
+    ticks as clock readings fall inside it. What ``engine_step`` keeps
+    for itself in a step that decodes is three ticks: one before
+    ``admit`` begins, one between ``admit`` and ``decode_prepare`` and
+    one after ``emit``; no clock is read, and so no phase of the step
+    runs unnamed, in its own time."""
+    model, params = model_and_params
+    rec = obs.enable(str(tmp_path))
+    session = _session(model, params)
+    session.engine.clock = FakeClock()
+    session.serve(_requests(4))
+    records = rec.records
+    obs.disable()
+    own = {
+        s["id"]: sec for s, sec in obs_spans.self_seconds(_spans(records))
+    }
+    steps = _spans(records, "engine_step")
+    decoded = {s["parent"] for s in _spans(records, "decode_step")}
+    assert decoded
+    for step in steps:
+        if step["id"] in decoded:
+            assert own[step["id"]] == pytest.approx(3.0)
+    # decode_step's own tail (the span's attributes, the cache's
+    # advance, the experts' counters) holds no clock reading either:
+    # the step's two children tile it but for the last tick.
+    for d in _spans(records, "decode_step"):
+        assert own[d["id"]] == pytest.approx(1.0)
 
 
 def test_dispatch_and_readback_tile_the_front_of_decode_step(served):
@@ -498,6 +686,33 @@ def test_spec_step_has_the_same_two_children(model_and_params, tmp_path):
         assert all(_inside(k, d) for k in kids)
 
 
+def test_spec_step_is_prepared_and_addressed_under_spans(model_and_params,
+                                                         tmp_path):
+    """A speculative window: its host arrays under ``decode_prepare``
+    (``slots``: the active ones), the VERIFY dispatch's addressing
+    under ``decode.address``; the draft's own dispatches make none."""
+    model, params = model_and_params
+    obs.enable(str(tmp_path))
+    session = _session(model, params, page_size=4, spec_k=2)
+    session.serve(_requests(3, new=(4, 7)))
+    records = obs_spans.active_recorder().records
+    by_id = {s["id"]: s for s in _spans(records)}
+    steps = _spans(records, "decode_step")
+    prepares = _spans(records, "decode_prepare")
+    assert len(prepares) == len(steps)
+    for p, d in zip(prepares, steps):
+        assert by_id[p["parent"]]["name"] == "engine_step"
+        assert p["parent"] == d["parent"]
+        assert p["slots"] == d["busy"]
+        assert p["ts"] + p["dur"] == pytest.approx(d["ts"])
+    addresses = _spans(records, "decode.address")
+    assert len(addresses) == len(steps)
+    for a in addresses:
+        parent = by_id[a["parent"]]
+        assert parent["name"] == "decode.dispatch"
+        assert _inside(a, parent) and a["bytes"] > 0
+
+
 def test_without_a_recorder_the_engine_records_nothing(
         model_and_params, tmp_path, monkeypatch):
     model, params = model_and_params
@@ -508,16 +723,32 @@ def test_without_a_recorder_the_engine_records_nothing(
         obs_spans.SpanRecorder, "begin",
         lambda self, *a, **kw: begun.append(a) or pytest.fail("recorded"),
     )
+    made = []
+    real_init = obs_spans._Span.__init__
+    monkeypatch.setattr(
+        obs_spans._Span, "__init__",
+        lambda self, *a, **kw: made.append(a) or real_init(self, *a, **kw),
+    )
     clock_reads = []
     session = _session(model, params)
-    real_clock = session.engine.clock
-    session.engine.clock = lambda: clock_reads.append(1) or real_clock()
+    engine = session.engine
+    real_clock = engine.clock
+    engine.clock = lambda: clock_reads.append(1) or real_clock()
+    sheds = []
+    real_shed = engine._record_shed
+    engine._record_shed = lambda *a: sheds.append(1) or real_shed(*a)
     results = session.serve(_requests(2, new=(4, 5)))
     assert all(r.ok for r in results.values())
-    assert begun == [] and obs_spans.active_recorder() is None
+    assert begun == [] and made == []
+    assert obs_spans.active_recorder() is None
     assert list(tmp_path.rglob("*.jsonl")) == []
-    assert session.engine._rec is None
+    assert engine._rec is None
     off = len(clock_reads)
+    # As many readings as before the spans of ISSUE 36: two a prefill
+    # (its start and its first token), two a decode step (its start
+    # and its tokens' time) and one a look for entries to shed.
+    assert off == (2 * engine.num_prefills + 2 * engine.num_decode_steps
+                   + len(sheds))
     # With a recorder the same requests read the clock more often: the
     # reads the spans need are made only then.
     clock_reads.clear()

@@ -80,15 +80,16 @@ def test_request_trace_legs_recorded(model_and_params, tmp_path):
     ]
     # Every prefill span carries its request_id; every decode chunk
     # names the requests it advanced.
-    prefills = [
+    # (A prefill's and a decode_step's children share their parent's
+    # category; a count over a category takes the spans with no parent
+    # of it.)
+    prefills = obs_spans.without_same_category_children(
         r for r in records
         if r.get("kind") == "span" and r.get("cat") == "serve_prefill"
-    ]
+    )
     assert sorted(p["request_id"] for p in prefills) == [
         f"r{i}" for i in range(5)
     ]
-    # (decode_step's children share its category; a count over the
-    # category takes the spans with no parent of it.)
     decodes = obs_spans.without_same_category_children(
         r for r in records
         if r.get("kind") == "span" and r.get("cat") == "serve_decode"
@@ -242,6 +243,44 @@ def test_shed_reason_breakdown_row(model_and_params, tmp_path, capsys):
     out = capsys.readouterr().out
     assert "serve requests" in out
     assert "shed_timeout" in out and "shed_capacity" in out
+
+
+def test_admit_spans_count_what_was_seated_shed_and_left(
+        model_and_params, tmp_path):
+    """``admit`` on the engine's own clock readings: what it seated,
+    what it shed and what it left waiting, and the prefill and the seat
+    it caused inside it."""
+    model, params = model_and_params
+    t = [0.0]
+    obs.enable(str(tmp_path / "obs"))
+    session = ServeSession.from_model(
+        model, params, prompt_len=PROMPT_LEN, num_slots=2,
+        clock=lambda: t[0],
+    )
+    session.submit(Request("late", [1, 2, 3], max_new_tokens=3,
+                           deadline_s=1.0))
+    t[0] = 5.0  # its deadline passes while it waits
+    for i in range(3):
+        session.submit(Request(f"ok{i}", [1, 2, 3], max_new_tokens=3))
+    session.engine.step()
+    session.collect()
+    records = obs_spans.active_recorder().records
+    obs.disable()
+    spans = [r for r in records if r.get("kind") == "span"]
+    admits = [s for s in spans if s["name"] == "admit"]
+    first = admits[0]
+    assert (first["popped"], first["shed"], first["queue_depth"]) == (2, 1, 1)
+    assert sum(a["popped"] for a in admits) == 3
+    assert sum(a["shed"] for a in admits) == 1
+    assert admits[-1]["queue_depth"] == 0
+    caused = [s["name"] for s in spans if s["parent"] == first["id"]]
+    assert caused == ["prefill", "seat", "prefill", "seat"]
+    # The report's serve totals count a step and a prefill once each.
+    rows = obs_report.build_report(records)["breakdown"]
+    assert rows["serve_engine"]["count"] == len(
+        [s for s in spans if s["name"] == "engine_step"])
+    assert rows["serve_prefill"]["count"] == 3
+    assert rows["serve_seat"]["count"] == 3
 
 
 def test_live_metrics_during_serve_session(model_and_params, tmp_path,

@@ -154,8 +154,7 @@ def test_served_tokens_are_the_references(
     assert registry().gauge("serve_kv_pool_folded_layers").value == 4
     assert registry().counter("serve_kv_pool_copies").value == copies
     assert steps and all(
-        s["kv_in_place"] == int(layers > 0) and s["kv_fold"] == 2
-        for s in steps)
+        s["kv_in_place"] == int(layers > 0) for s in steps)
     # ``tokens_live`` stays positions a slot: ONE pool's, not four.
     assert all(s["tokens_live"] <= SLOTS * MAX_SEQ for s in steps)
 

@@ -22,6 +22,12 @@ since every record carries host/process tags), then prints
 - a served-request outcome breakdown (completed vs each shed reason,
   with queue-wait/TTFT means per reason), when a serve run's
   ``request_complete`` events rode the stream;
+- the serve engine's phases as the tree its spans make (``engine_step``
+  > ``admit`` > ``prefill`` > ``prefill.dispatch`` /
+  ``prefill.readback``, ``seat``; ``decode_prepare``; ``decode_step`` >
+  ``decode.dispatch`` > ``decode.address``, ``decode.readback``;
+  ``emit``), each with its total and its SELF time (its duration less
+  its children's), and the queue depth ``admit`` left behind;
 - the last counters snapshot per process, if any rode the stream.
 
 ``--request <id>`` switches to per-request trace mode: the serve
@@ -57,6 +63,7 @@ from tpudl.obs.spans import (
     CAT_STEP,
     chrome_trace_events,
     read_jsonl,
+    self_seconds,
     without_same_category_children,
 )
 
@@ -95,6 +102,16 @@ def load_records(paths: Iterable[str]) -> List[dict]:
     for f in files:
         records.extend(read_jsonl(f))
     return records
+
+
+#: The serve engine's spans, a step's order, with their depth in the
+#: tree (tpudl.serve.engine).
+_SERVE_TREE = (
+    ("engine_step", 0), ("admit", 1), ("prefill", 2),
+    ("prefill.dispatch", 3), ("prefill.readback", 3), ("seat", 2),
+    ("decode_prepare", 1), ("decode_step", 1), ("decode.dispatch", 2),
+    ("decode.address", 3), ("decode.readback", 2), ("emit", 1),
+)
 
 
 def _dist(durs: List[float]) -> dict:
@@ -203,8 +220,49 @@ def build_report(
         "per_host": host_rows,
         "straggler_factor": straggler_factor,
         "serve_requests": serve_request_breakdown(records),
+        "serve_phases": serve_phase_breakdown(records),
         "counters": counters,
     }
+
+
+def serve_phase_breakdown(records: Iterable[dict]) -> dict:
+    """The serve engine's phases by span name, in the tree's order:
+    count, total seconds, SELF seconds (a span's duration less its
+    direct children's, so the rows' self times add up to the steps'
+    total) and mean ms. ``admit``'s row also says the queue depth it
+    left behind (mean and most): its self time is what admission costs
+    the host a step (SLO checks, slot scans, the queue's pop, gauges),
+    and a depth that grows while that stays flat is load the engine
+    cannot seat, not a slow admission. Empty without serve spans."""
+    names = {name for name, _ in _SERVE_TREE}
+    rows: Dict[str, dict] = {}
+    depths: List[float] = []
+    for s, own in self_seconds(
+        r for r in records if r.get("kind") == "span"
+    ):
+        if s["name"] not in names or not str(s.get("cat", "")).startswith(
+            "serve_"
+        ):
+            continue
+        row = rows.setdefault(
+            s["name"], {"count": 0, "total_s": 0.0, "self_s": 0.0}
+        )
+        row["count"] += 1
+        row["total_s"] += float(s["dur"])
+        row["self_s"] += own
+        if s["name"] == "admit" and "queue_depth" in s:
+            depths.append(float(s["queue_depth"]))
+    out = {}
+    for name, depth in _SERVE_TREE:
+        if name in rows:
+            row = rows[name]
+            row["depth"] = depth
+            row["mean_ms"] = 1e3 * row["total_s"] / row["count"]
+            out[name] = row
+    if depths and "admit" in out:
+        out["admit"]["queue_depth_mean"] = sum(depths) / len(depths)
+        out["admit"]["queue_depth_max"] = max(depths)
+    return out
 
 
 def serve_request_breakdown(records: Iterable[dict]) -> dict:
@@ -930,6 +988,25 @@ def format_report(report: dict) -> str:
             )
             lines.append(
                 f"{reason:16} {r['count']:6d} {r['tokens']:8d} {qw} {tt}"
+            )
+
+    if report.get("serve_phases"):
+        lines += [
+            "",
+            f"{'serve phase':24} {'count':>6} {'total_s':>8} "
+            f"{'self_s':>8} {'mean_ms':>9}",
+        ]
+        for name, r in report["serve_phases"].items():
+            depth = ""
+            if "queue_depth_mean" in r:
+                depth = (
+                    f"  queue_depth mean {r['queue_depth_mean']:.1f} "
+                    f"max {r['queue_depth_max']:g}"
+                )
+            lines.append(
+                f"{'  ' * r['depth'] + name:24} {r['count']:6d} "
+                f"{r['total_s']:8.2f} {r['self_s']:8.2f} "
+                f"{r['mean_ms']:9.2f}{depth}"
             )
 
     for key, snap in report["counters"].items():

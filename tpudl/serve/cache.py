@@ -1463,17 +1463,31 @@ class PagedKVCache:
             table = (table, jnp.asarray(self.ring_table))
         return table, jnp.asarray(self.start), jnp.asarray(self.lens)
 
-    def decode(self, program, params, tokens, positions, *extra):
+    @property
+    def addressing_nbytes(self) -> int:
+        """Host bytes ``dispatch_args`` hands to the device a dispatch
+        (the ring table has no columns without a window group)."""
+        return (
+            self.page_table.nbytes + self.ring_table.nbytes
+            + self.start.nbytes + self.lens.nbytes
+        )
+
+    def decode(self, program, params, tokens, positions, *extra,
+               addressing=None):
         """Dispatch one program of the paged decode contract
         (``paged_decode_fn``, ``paged_chunk_decode_fn``, the LoRA
         decode with its adapter arguments in ``extra``) on the pool,
         keep the pool it returns and hand back the logits. What the
         program returned beside the two (a model with routed experts:
         its tokens per held expert, still on the device) is kept as
-        ``program_extras`` until the next dispatch."""
+        ``program_extras`` until the next dispatch. ``addressing``:
+        this dispatch's ``dispatch_args()``, where the caller has made
+        them already (the engine does, under a span)."""
         pool = self.cache
+        if addressing is None:
+            addressing = self.dispatch_args()
         logits, self.cache, *self.program_extras = program(
-            params, pool, tokens, positions, *self.dispatch_args(), *extra
+            params, pool, tokens, positions, *addressing, *extra
         )
         self._handed_over(pool)
         # What the program noted of itself while it was traced: the
@@ -1575,11 +1589,7 @@ class PagedKVCache:
         device = int(
             sum(leaf.nbytes for leaf in jax.tree.leaves(self.cache))
         )
-        host = (
-            self.page_table.nbytes + self.ring_table.nbytes
-            + self.start.nbytes + self.lens.nbytes
-        )
-        return device + host
+        return device + self.addressing_nbytes
 
 
 # ---------------------------------------------------------------------------

@@ -69,10 +69,13 @@ from tpudl.serve.cache import (
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue, _Entry
 
 #: Span categories (their own rows in the obs report breakdown table).
-#: One ``engine_step`` span encloses a step's ``prefill``, ``seat``,
-#: ``decode_step`` and ``emit``; ``decode_step`` encloses
-#: ``decode.dispatch`` and ``decode.readback``, of its own category so
-#: that a sum over ``serve_decode`` counts each step once.
+#: One ``engine_step`` span encloses a step's ``admit`` (which encloses
+#: the ``prefill`` and ``seat`` spans it causes), ``decode_prepare``,
+#: ``decode_step`` and ``emit``. ``prefill`` encloses
+#: ``prefill.dispatch`` and ``prefill.readback``, ``decode_step``
+#: encloses ``decode.dispatch`` (around ``decode.address``) and
+#: ``decode.readback``: children of their parent's category, so that a
+#: sum over ``serve_prefill`` or ``serve_decode`` counts each once.
 CAT_SERVE_ENGINE = "serve_engine"
 CAT_SERVE_PREFILL = "serve_prefill"
 CAT_SERVE_SEAT = "serve_seat"
@@ -562,7 +565,7 @@ class Engine:
         n = int(ids.shape[0])
         rec = self._rec
         t0 = self.clock()
-        span = None
+        span = part = None
         if rec is not None:
             # request_id on the prefill span is the trace link between
             # the queued event and this request's decode chunks.
@@ -571,6 +574,9 @@ class Engine:
                 request_id=req.request_id,
                 queue_wait_s=t0 - entry.submitted_at,
             )
+            # Its two halves: everything up to the program's call
+            # returning, then the wait for the first token.
+            part = rec.begin("prefill.dispatch", CAT_SERVE_PREFILL, t0)
         lease = None
         hit = 0
         tenant_pinned = False
@@ -621,6 +627,10 @@ class Engine:
                     logits, row_cache, *counts = self.prefill_call(
                         self.params, padded, mask
                     )
+            if part is not None:
+                t = self.clock()
+                part.end(t)
+                part = rec.begin("prefill.readback", CAT_SERVE_PREFILL, t)
             load = {}
             if counts:
                 first, counts = first_token(logits, req, also=counts)
@@ -635,6 +645,7 @@ class Engine:
             raise
         now = self.clock()
         if span is not None:
+            part.end(now)
             # prefix_hit_tokens names how much of the prompt the radix
             # cache paid for (report.py --request's TTFT attribution).
             # rows: the length the program ran; tokens: the prompt's
@@ -752,9 +763,33 @@ class Engine:
         return any(s is not None for s in self._slots)
 
     def _fill_slots(self) -> None:
+        """A step's admission, under the ``admit`` span: parent of the
+        ``prefill`` and ``seat`` spans it causes, so that its SELF time
+        is what admission costs the host (SLO evaluation and shedding,
+        the inboxes, the free-slot scans, the queue's pop, a seat's
+        tail, the occupancy gauges). ``popped``: requests it seated,
+        ``shed``: entries it shed, ``queue_depth``: entries left
+        waiting (queue and inboxes, as ``health`` counts them)."""
+        rec = self._rec
+        span = None
+        if rec is not None:
+            span = rec.begin("admit", CAT_SERVE_ENGINE, self.clock())
+        popped, shed = self._admit()
+        if span is not None:
+            span.end(
+                self.clock(), popped=popped, shed=shed,
+                queue_depth=(
+                    len(self.queue) + len(self.prefill_inbox)
+                    + len(self.migrate_inbox)
+                ),
+            )
+
+    def _admit(self) -> tuple:
         """Seat queued work into empty slots. Static mode only refills
         once the WHOLE batch drained (the run-to-completion baseline);
-        continuous mode refills the moment a slot frees."""
+        continuous mode refills the moment a slot frees. Returns the
+        requests seated and the entries shed."""
+        popped = shed = 0
         if self._slo is not None:
             # Drive burn-state transitions from the engine's own thread
             # (the subscriber flips _slo_burning synchronously), then
@@ -763,9 +798,11 @@ class Engine:
             # client can retry elsewhere (the ROADMAP-2 router's cue).
             self._slo.evaluate()
             if self._slo_burning and len(self.queue):
-                self._record_shed(self.queue.drain_all(), "shed_slo")
+                burnt = self.queue.drain_all()
+                shed += len(burnt)
+                self._record_shed(burnt, "shed_slo")
         if not self.continuous and self._active():
-            return
+            return popped, shed
         # Migrated-in requests seat FIRST: they are mid-stream — their
         # prefill AND some decode are already paid, and every queued
         # token of delay widens the client's visible stall (the
@@ -801,6 +838,7 @@ class Engine:
             self.migrate_inbox.popleft()
             try:
                 self.install_migrated(meta, slot=slot, lease=item.lease)
+                popped += 1
             except (MigrationCorruptError, MigrationCompatError,
                     ValueError, RuntimeError) as e:
                 # install/import released the lease on their own
@@ -827,20 +865,25 @@ class Engine:
                 self._record_shed(
                     [self.prefill_inbox.popleft().entry], "shed_capacity"
                 )
+                shed += 1
                 continue
             self._seat_prefilled(self.prefill_inbox.popleft(), slot)
+            popped += 1
         while True:
             slot = next(
                 (i for i, s in enumerate(self._slots) if s is None), None
             )
             if slot is None:
                 break
-            entry, shed = self.queue.pop(fit=self._fits)
-            self._record_shed(shed, "shed_timeout")
+            entry, expired = self.queue.pop(fit=self._fits)
+            shed += len(expired)
+            self._record_shed(expired, "shed_timeout")
             if entry is None:
                 break
             self._seat(entry, slot)
+            popped += 1
         self._publish_occupancy()
+        return popped, shed
 
     def _publish_occupancy(self) -> None:
         """Slots in use, the cache's reserved-against-live counters and
@@ -874,15 +917,14 @@ class Engine:
         """What a ``decode_step`` span says of the paged cache: the
         pages the seated slots hold, the positions the step read,
         whether its attention read the pool in place (the program's
-        own note, ``PagedKVCache.in_place_layers``), the positions a
-        held row of its pool folds together (``PagedKVCache.folds``; 1
-        = held as declared) and the pages an in-place step visits,
-        counted on the host at dispatch."""
+        own note, ``PagedKVCache.in_place_layers``) and the pages an
+        in-place step visits, counted on the host before dispatch. (How
+        the pool is held is a constant of the session: the gauge
+        ``serve_kv_pool_folded_layers`` says it, not every step.)"""
         cache = self.cache
         attrs = {"pages_reserved": cache.pages_reserved,
                  "tokens_live": cache.tokens_live,
                  "kv_in_place": int(cache.in_place_layers > 0),
-                 "kv_fold": max(cache.folds),
                  "pages_live": pages_live}
         if cache.window:
             # The same two of the window layers' rings: pages held, and
@@ -1373,10 +1415,37 @@ class Engine:
             self.adapter_pool.free_slot(slot)
         self._slots[slot] = None
 
+    def _decode_on_pool(self, program, tokens, positions, *extra):
+        """``cache.decode`` of one program of the paged decode
+        contract, the step's addressing made first and under a span of
+        its own, ``decode.address`` (child of ``decode.dispatch``): the
+        host tables handed to the device every step, ``bytes`` of
+        them."""
+        rec = self._rec
+        span = None
+        if rec is not None:
+            span = rec.begin(
+                "decode.address", CAT_SERVE_DECODE, self.clock()
+            )
+        addressing = self.cache.dispatch_args()
+        if span is not None:
+            span.end(self.clock(), bytes=self.cache.addressing_nbytes)
+        return self.cache.decode(
+            program, self.params, tokens, positions, *extra,
+            addressing=addressing,
+        )
+
     def _decode_step(self) -> None:
         """One slot-batched decode dispatch + selection + host readback;
         idle slots ride along with zeros and their output is discarded
         (idle rows write into the trash page)."""
+        rec = self._rec
+        prepare = None
+        if rec is not None:
+            # The step's host arrays, a sibling before ``decode_step``.
+            prepare = rec.begin(
+                "decode_prepare", CAT_SERVE_ENGINE, self.clock()
+            )
         b = self.num_slots
         tokens = np.zeros(b, np.int32)
         positions = np.zeros(b, np.int32)
@@ -1391,21 +1460,22 @@ class Engine:
             temps[i] = s.request.temperature
             seeds[i] = s.request.seed
             steps[i] = s.steps
-        rec = self._rec
-        t0 = self.clock()
-        span = dispatch = None
-        if rec is not None:
-            span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
-            dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
         # Tenant adapters ride the paged contract as three more
         # traced inputs.
         adapters = (
             self.adapter_pool.dispatch_args()
             if self.adapter_pool is not None else ()
         )
-        pages_live = self.cache.pages_live() if span is not None else 0
-        logits = self.cache.decode(
-            self.decode_call, self.params, tokens, positions, *adapters
+        # Counted for the span alone, so outside what is read for time.
+        pages_live = self.cache.pages_live() if rec is not None else 0
+        t0 = self.clock()
+        span = dispatch = None
+        if rec is not None:
+            prepare.end(t0, slots=b)
+            span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
+            dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
+        logits = self._decode_on_pool(
+            self.decode_call, tokens, positions, *adapters
         )
         if temps.any():
             sel = _select_tokens(logits, temps, seeds, steps)
@@ -1434,7 +1504,6 @@ class Engine:
         self.cache.advance(
             [i for i, s in enumerate(self._slots) if s is not None]
         )
-        now = self.clock()
         emit = None
         if span is not None:
             # "rids" names every request this decode chunk advanced —
@@ -1443,12 +1512,18 @@ class Engine:
             # seated slots hold and the positions the step read (lens
             # already counts the token just written); whether attention
             # read the pool in place, and the pages it then visits
-            # (``_paged_attrs``).
+            # (``_paged_attrs``). Built before ``now`` is read, so that
+            # they are ``decode_step``'s own tail and not ``emit``'s.
             busy = int(sum(s is not None for s in self._slots))
-            span.end(now, busy=busy,
-                     rids=[s.request.request_id
-                           for s in self._slots if s is not None],
-                     **self._paged_attrs(pages_live), **load)
+            attrs = dict(
+                busy=busy,
+                rids=[s.request.request_id
+                      for s in self._slots if s is not None],
+                **self._paged_attrs(pages_live), **load,
+            )
+        now = self.clock()
+        if span is not None:
+            span.end(now, **attrs)
             emit = rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
         registry().counter("serve_decode_steps").inc()
@@ -1484,6 +1559,12 @@ class Engine:
         ``lens`` bookkeeping on both caches (tpudl.serve.speculate's
         lockstep contract: both saw the same window, both advance by
         the emitted count)."""
+        rec = self._rec
+        prepare = None
+        if rec is not None:
+            prepare = rec.begin(
+                "decode_prepare", CAT_SERVE_ENGINE, self.clock()
+            )
         from tpudl.serve.speculate import (
             greedy_accept,
             sample_accept,
@@ -1507,10 +1588,13 @@ class Engine:
             seeds[i] = s.request.seed
             token_index[i] = s.steps
         rids = [self._slots[i].request.request_id for i in active]
-        rec = self._rec
+        # The draft never moves the target's ``lens``: counted here,
+        # outside what is read for time.
+        pages_live = self.cache.pages_live(k) if rec is not None else 0
         t0 = self.clock()
         span = dispatch = None
         if rec is not None:
+            prepare.end(t0, slots=len(active))
             span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
         proposals, q_probs = spec.propose(
             tokens0, positions0, active, temps, seeds, token_index
@@ -1525,10 +1609,7 @@ class Engine:
             dispatch = rec.begin(
                 "decode.dispatch", CAT_SERVE_DECODE, self.clock()
             )
-        pages_live = self.cache.pages_live(k) if span is not None else 0
-        logits = self.cache.decode(
-            self.verify_call, self.params, chunk, pos_chunk
-        )
+        logits = self._decode_on_pool(self.verify_call, chunk, pos_chunk)
         sampling = any(temps[i] > 0 for i in active)
         verdict = logits if sampling else _select_greedy(logits)
         readback = None
@@ -1654,9 +1735,10 @@ class Engine:
         finally:
             if span is not None:
                 # Closing it also drops what an exception left open
-                # beneath it. Its self time (queue pop, fit checks,
-                # shedding, the step's host arrays) is its duration
-                # less its children's.
+                # beneath it. Its self time (the chaos hooks and a few
+                # lines: admission and the step's host arrays have
+                # spans of their own) is its duration less its
+                # children's.
                 self._rec = None
                 span.end(
                     self.clock(), seats=self._seats,
