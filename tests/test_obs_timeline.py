@@ -895,9 +895,11 @@ def test_occupancy_gauges_are_set_with_slots_busy(model_and_params):
     cache = session.engine.cache
     assert reg.gauge("serve_slots_busy").value == 2
     # Set where serve_slots_busy is: after the seats, before the step's
-    # decode advanced the lengths.
+    # decodes advanced the lengths: two dispatches, since both slots are
+    # seated (the step that landed and the step ahead, still in flight).
     assert reg.gauge("serve_kv_pages_reserved").value == cache.pages_reserved
-    assert reg.gauge("serve_kv_tokens_live").value == cache.tokens_live - 2
+    assert session.engine._in_flight is not None
+    assert reg.gauge("serve_kv_tokens_live").value == cache.tokens_live - 4
     assert "serve_kv_tokens_live" in obs_exporter.render_prometheus(
         reg.snapshot()
     )

@@ -1457,11 +1457,16 @@ class PagedKVCache:
         """The three small traced inputs each paged decode dispatch
         takes: (page_table [B, P], start [B], lens [B]) as int32; with
         a window group the first is the pair (page_table, ring_table
-        [B, ring_pages]) (tpudl.models.paged.PagedView)."""
-        table = jnp.asarray(self.page_table)
+        [B, ring_pages]) (tpudl.models.paged.PagedView). COPIES of
+        the host's tables: the engine moves ``lens`` and frees slots
+        while the dispatch that took them is still queued, and a device
+        array made of a host buffer may go on reading it (on the CPU it
+        is that buffer)."""
+        table = jnp.asarray(self.page_table.copy())
         if self.window:
-            table = (table, jnp.asarray(self.ring_table))
-        return table, jnp.asarray(self.start), jnp.asarray(self.lens)
+            table = (table, jnp.asarray(self.ring_table.copy()))
+        return (table, jnp.asarray(self.start.copy()),
+                jnp.asarray(self.lens.copy()))
 
     @property
     def addressing_nbytes(self) -> int:

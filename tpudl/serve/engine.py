@@ -123,6 +123,25 @@ _select_greedy = jax.jit(tpudl_select)
 _select_tokens = jax.jit(tpudl_select_sampled)
 
 
+def _selection_sharding(params):
+    """Where the selection leaves a step's tokens: on every device that
+    holds the parameters, whole, if the parameters are committed there
+    (a replica put on a device of its own, a mesh); None if they are
+    committed nowhere, and neither are then the tokens. Tokens that the
+    host hands a decode step are put the same way (``Engine._dispatch``),
+    so that a step that takes them from the device is the same program
+    to jit and compiles nothing."""
+    leaf = next(iter(jax.tree.leaves(params)), None)
+    if not getattr(leaf, "committed", False):
+        return None
+    sharding = leaf.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(
+            sharding.mesh, jax.sharding.PartitionSpec()
+        )
+    return sharding
+
+
 def first_token(logits, request, also=None):
     """Select a request's FIRST token from its batch-1 prefill logits
     (step 0 of its per-request sampling stream) — shared by the
@@ -230,16 +249,20 @@ class _Slot:
         "entry", "request", "tokens", "position", "steps",
         "t_seated", "t_first", "t_last", "gap_origin",
         "prefix_hit", "spec_proposed", "spec_accepted",
-        "adapter_reloads", "migrations",
+        "adapter_reloads", "migrations", "kv_base",
     )
 
     def __init__(self, entry: _Entry, first_token: int, prompt_len: int,
-                 seated: float, now: float):
+                 seated: float, now: float, kv_base: int = 0):
         self.entry = entry
         self.request: Request = entry.request
         self.tokens: List[int] = [first_token]
         self.position = prompt_len  # next absolute RoPE position
         self.steps = 1  # tokens drawn so far (the sampling fold_in index)
+        # Cache rows the slot held with its first token alone: with n
+        # tokens it holds ``kv_base + n - 1``, whatever the cache's own
+        # ``lens`` says while a step is in flight.
+        self.kv_base = kv_base
         self.t_seated = seated  # pop time: queue wait ends HERE
         self.t_first = now  # first token out: TTFT ends here (incl. prefill)
         self.t_last = now
@@ -256,6 +279,48 @@ class _Slot:
         self.spec_accepted = 0
         self.adapter_reloads = 0
         self.migrations = 0
+
+
+class _Plan:
+    """One decode step's inputs, made before its dispatch. ``rows[i]``
+    is the ``_Slot`` whose row ``i`` the step computes, None for a row
+    that rides idle. ``tokens``: the host's array, or with ``ahead``
+    the selection of the step before as it lies on the device (int32
+    [slots] either way, what ``decode_call`` takes)."""
+
+    __slots__ = ("rows", "ahead", "tokens", "positions", "temps", "seeds",
+                 "steps", "pages_live")
+
+    def __init__(self, num_slots: int, prev: Optional["_InFlight"]):
+        self.rows: List[Optional[_Slot]] = [None] * num_slots
+        self.ahead = prev is not None
+        self.tokens = (
+            prev.sel if self.ahead else np.zeros(num_slots, np.int32)
+        )
+        self.positions = np.zeros(num_slots, np.int32)
+        self.temps = np.zeros(num_slots, np.float32)
+        self.seeds = np.zeros(num_slots, np.uint32)
+        self.steps = np.zeros(num_slots, np.int32)
+        self.pages_live = 0
+
+
+class _InFlight:
+    """One decode step the device has been handed and the host has not
+    read back: the tokens it selects (``sel``, int32 [slots], on the
+    device) and what its program returned beside the logits
+    (``extras``), the slots whose rows it computes (``rows``, as
+    ``_Plan`` has them), whether its input tokens came from the device
+    (``ahead``), and what its ``decode_step`` span will say of it
+    (``attrs``, made at the dispatch; None where nothing records)."""
+
+    __slots__ = ("rows", "sel", "extras", "ahead", "attrs")
+
+    def __init__(self, rows, sel, extras, ahead: bool, attrs):
+        self.rows = rows
+        self.sel = sel
+        self.extras = extras
+        self.ahead = ahead
+        self.attrs = attrs
 
 
 class _Migrated:
@@ -289,7 +354,10 @@ class Engine:
     ``num_slots`` generation streams in flight, writes ``Result``s into
     ``self.results`` keyed by request_id. Synchronous: ``step()``
     advances the world by one decode step; ``run_until_drained()`` loops
-    it (the ServeSession front end drives either)."""
+    it (the ServeSession front end drives either). With every slot
+    seated the device is kept one step ahead of what ``step()`` has
+    read back (``_decode_step``): that step is ``_in_flight``, and
+    ``land()`` reads it back for whoever touches a slot from outside."""
 
     def __init__(
         self,
@@ -367,6 +435,10 @@ class Engine:
                     "chunk decode program)"
                 )
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
+        # The decode step the device holds and the host has not read
+        # back (``_decode_step``): at most one between two ``step``s.
+        self._in_flight: Optional[_InFlight] = None
+        self._token_sharding = _selection_sharding(params)
         self.results: Dict[Any, Result] = {}
         # The recorder of the step under way (tpudl.obs.spans), looked
         # up once in ``step`` and read by what it calls; None outside a
@@ -750,7 +822,8 @@ class Engine:
         reg.histogram("serve_ttft_ms").observe(ttft_ms)
         self._slo_observe("serve_queue_wait_ms", queue_wait_ms)
         self._slo_observe("serve_ttft_ms", ttft_ms)
-        s = _Slot(entry, first, ids_len, t_popped, t_first)
+        s = _Slot(entry, first, ids_len, t_popped, t_first,
+                  kv_base=int(self.cache.lens[slot]))
         s.prefix_hit = prefix_hit
         s.adapter_reloads = adapter_reloads
         self._slots[slot] = s
@@ -803,6 +876,14 @@ class Engine:
                 self._record_shed(burnt, "shed_slo")
         if not self.continuous and self._active():
             return popped, shed
+        if self._in_flight is not None:
+            # Nothing is seated behind a decode step in flight: its
+            # landing (this call's) frees the chip for the prefill and
+            # the next call seats, as a step that was never ahead would
+            # have. A prefill queued behind it would run no sooner, and
+            # the step's device time would pass under ``admit`` instead
+            # of under the ``decode_step`` that lands it.
+            return popped, shed
         # Migrated-in requests seat FIRST: they are mid-stream — their
         # prefill AND some decode are already paid, and every queued
         # token of delay widens the client's visible stall (the
@@ -837,7 +918,7 @@ class Engine:
                 continue
             self.migrate_inbox.popleft()
             try:
-                self.install_migrated(meta, slot=slot, lease=item.lease)
+                self._install_migrated(meta, slot=slot, lease=item.lease)
                 popped += 1
             except (MigrationCorruptError, MigrationCompatError,
                     ValueError, RuntimeError) as e:
@@ -1022,7 +1103,11 @@ class Engine:
         from the payload (the router probed AND LEASED them in the
         target's radix tree — prefix by reference, not by bytes).
         Commit-or-invisible: the slot is freed only after the payload
-        exists in full."""
+        exists in full. A decode step in flight is landed first, so the
+        payload holds every token the device has computed; a request
+        that step finished is not seated any more (None) and its Result
+        is in ``results``."""
+        self.land()
         slot = next(
             (
                 i
@@ -1143,7 +1228,14 @@ class Engine:
         never resumed. Raises ``MigrationCorruptError`` on a payload
         that fails the crc (resuming garbage is the one unforgivable
         outcome) and ``MigrationCompatError`` on a cache this engine
-        cannot seat it in. Returns the request_id."""
+        cannot seat it in. Returns the request_id. Lands a decode step
+        in flight first, as whoever touches a slot from outside
+        ``step`` does (admission, inside one, seats without)."""
+        self.land()
+        return self._install_migrated(payload, slot, lease)
+
+    def _install_migrated(self, payload, slot: Optional[int] = None,
+                          lease=None) -> Any:
         from tpudl.serve.cache import parse_migration
 
         try:
@@ -1231,6 +1323,7 @@ class Engine:
         s = _Slot(
             entry, int(meta["tokens"][0]), int(meta["prompt_ids_len"]),
             float(meta["t_seated"]), float(meta["t_first"]),
+            kv_base=int(self.cache.lens[slot]) - len(meta["tokens"]) + 1,
         )
         s.tokens = [int(t) for t in meta["tokens"]]
         s.position = int(meta["position"])
@@ -1378,9 +1471,10 @@ class Engine:
                 generation_s=s.t_last - s.t_first, num_tokens=n,
             )
         # Terminal durable-log record: slot occupancy x KV footprint,
-        # computed BEFORE the free below releases the pages.
+        # from the rows the request's own tokens hold (the cache's
+        # ``lens`` may count a step in flight, or nothing any more).
         active_s = max(0.0, s.t_last - s.t_seated)
-        pages = -(-int(self.cache.lens[slot]) // self.cache.page_size)
+        pages = -(-(s.kv_base + n - 1) // self.cache.page_size)
         kv_page_s = pages * active_s
         kv_byte_s = kv_page_s * (
             self.cache.nbytes / max(1, self.cache.num_pages)
@@ -1435,10 +1529,128 @@ class Engine:
             addressing=addressing,
         )
 
+    def _prepare(self, prev: Optional[_InFlight]) -> Optional[_Plan]:
+        """The host arrays of the next decode step. With nothing in
+        flight (``prev`` None) every seated slot's row, its input token
+        from the host. With ``prev`` in flight the step AHEAD of it,
+        which takes its tokens from the device, and only where the
+        engine can see that running ahead costs nobody anything: every
+        slot is seated (no arrival could be seated before that step
+        anyway), each by the request whose row ``prev`` computes (a
+        guard: admission seats nothing while a step is in flight, and
+        whoever seats from outside ``step`` lands it first; a slot
+        seated since would have its first token on the host and no row
+        in ``prev``), and nothing speculates (acceptance needs the
+        host). None where that does not hold, or no row would be left.
+
+        A slot whose last token is ``prev``'s to select (by length: the
+        host knows beforehand) rides the step ahead as an idle row: its
+        pages are handed back here, ``prev`` having been dispatched, so
+        that its table row maps the trash page as an idle slot's does
+        (whoever is given the pages next writes them in a program
+        ordered after ``prev`` on the device: the pool goes from program
+        to program). The slot itself stays seated until ``prev`` lands
+        its token. One that ends by ``eos_id`` is known one step late:
+        the step ahead then computes one row for nobody, inside the
+        slot's own reservation, and ``_land`` drops its token."""
+        ahead = prev is not None
+        if ahead and (self.speculator is not None or any(
+            s is None or s is not row
+            for s, row in zip(self._slots, prev.rows)
+        )):
+            return None
+        plan = _Plan(self.num_slots, prev)
+        ending = []
+        for i, s in enumerate(self._slots):
+            if s is None:
+                continue
+            if ahead and len(s.tokens) + 1 >= s.request.max_new_tokens:
+                ending.append(i)
+                continue
+            plan.rows[i] = s
+            if not ahead:
+                plan.tokens[i] = s.tokens[-1]
+            # ``position`` and ``steps`` move when a token lands: the
+            # step ahead is one past them.
+            plan.positions[i] = s.position + ahead
+            plan.temps[i] = s.request.temperature
+            plan.seeds[i] = s.request.seed
+            plan.steps[i] = s.steps + ahead
+        if ahead and not any(plan.rows):
+            return None
+        for i in ending:
+            self.cache.free(i)
+        # Counted for the span alone, so outside what is read for time.
+        if self._rec is not None:
+            plan.pages_live = self.cache.pages_live()
+        return plan
+
+    def _dispatch(self, plan: _Plan) -> _InFlight:
+        """Hand the device one decode step and its selection; nothing
+        is read back. The cache's ``lens`` advance HERE, so that the
+        next dispatch's addressing counts the row this one writes; idle
+        slots ride along and their output is discarded (idle rows write
+        into the trash page)."""
+        tokens = plan.tokens
+        if not plan.ahead and self._token_sharding is not None:
+            # Put where the selection would have left them.
+            tokens = jax.device_put(tokens, self._token_sharding)
+        # Tenant adapters ride the paged contract as three more
+        # traced inputs.
+        adapters = (
+            self.adapter_pool.dispatch_args()
+            if self.adapter_pool is not None else ()
+        )
+        logits = self._decode_on_pool(
+            self.decode_call, tokens, plan.positions, *adapters,
+        )
+        if plan.temps.any():
+            sel = _select_tokens(logits, plan.temps, plan.seeds, plan.steps)
+        else:
+            sel = _select_greedy(logits)
+        # Each ACTIVE slot's logical length advanced by one (idle
+        # slots stay pinned on the trash page).
+        rows = plan.rows
+        self.cache.advance([i for i, s in enumerate(rows) if s is not None])
+        attrs = None
+        if self._rec is not None:
+            # "rids" names every request this decode chunk advances —
+            # the per-request trace's decode leg (report.py --request
+            # selects the chunks containing its id). The pages the
+            # seated slots hold and the positions the step reads (lens
+            # already counts the token it writes); whether attention
+            # reads the pool in place, and the pages it then visits
+            # (``_paged_attrs``). ``ahead``: its input tokens came from
+            # the device.
+            attrs = dict(
+                busy=sum(s is not None for s in rows),
+                rids=[s.request.request_id for s in rows if s is not None],
+                ahead=int(plan.ahead),
+                **self._paged_attrs(plan.pages_live),
+            )
+        # What the program returned beside the logits is this step's
+        # until the next dispatch overwrites it: kept with the step.
+        return _InFlight(rows, sel, list(self.cache.program_extras),
+                         plan.ahead, attrs)
+
     def _decode_step(self) -> None:
-        """One slot-batched decode dispatch + selection + host readback;
-        idle slots ride along with zeros and their output is discarded
-        (idle rows write into the trash page)."""
+        """One LANDING: the oldest decode step the device holds is read
+        back and its tokens emitted. With nothing in flight that step
+        is dispatched here first, from the host's tokens; and where
+        ``_prepare`` finds that the engine may run ahead, the step
+        after it is dispatched BEFORE the read-back and stays in flight
+        until the next call lands it, so that the read-back's wait, the
+        emit, whoever drives the engine, the next admission and the
+        next step's arrays all pass under a busy device.
+
+        The spans are the ones a step always had: ``decode_prepare``
+        (the host arrays of the call's first dispatch; a call that only
+        lands has none to make), ``decode_step`` around
+        ``decode.dispatch`` (every dispatch the call makes: of the step
+        it lands, when none was in flight, and of the step ahead, whose
+        arrays are then made inside it) and ``decode.readback`` (the
+        wait for the step that lands), then ``emit``. ``decode_step``'s
+        attributes describe the step that lands."""
         rec = self._rec
         prepare = None
         if rec is not None:
@@ -1446,48 +1658,43 @@ class Engine:
             prepare = rec.begin(
                 "decode_prepare", CAT_SERVE_ENGINE, self.clock()
             )
-        b = self.num_slots
-        tokens = np.zeros(b, np.int32)
-        positions = np.zeros(b, np.int32)
-        temps = np.zeros(b, np.float32)
-        seeds = np.zeros(b, np.uint32)
-        steps = np.zeros(b, np.int32)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            tokens[i] = s.tokens[-1]
-            positions[i] = s.position
-            temps[i] = s.request.temperature
-            seeds[i] = s.request.seed
-            steps[i] = s.steps
-        # Tenant adapters ride the paged contract as three more
-        # traced inputs.
-        adapters = (
-            self.adapter_pool.dispatch_args()
-            if self.adapter_pool is not None else ()
-        )
-        # Counted for the span alone, so outside what is read for time.
-        pages_live = self.cache.pages_live() if rec is not None else 0
+        land, self._in_flight = self._in_flight, None
+        plan = self._prepare(land)
         t0 = self.clock()
-        span = dispatch = None
+        span = dispatch = readback = None
         if rec is not None:
-            prepare.end(t0, slots=b)
+            prepare.end(t0, slots=self.num_slots)
             span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
             dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
-        logits = self._decode_on_pool(
-            self.decode_call, tokens, positions, *adapters
-        )
-        if temps.any():
-            sel = _select_tokens(logits, temps, seeds, steps)
-        else:
-            sel = _select_greedy(logits)
-        readback = None
+        if land is None:
+            land = self._dispatch(plan)
+            plan = self._prepare(land)
+        if plan is not None:
+            self._in_flight = self._dispatch(plan)
         if dispatch is not None:
-            # Every dispatch of the step has returned; what is left of
+            # Every dispatch of the call has returned; what is left of
             # decode_step is the wait for the device and the copy back.
             t = self.clock()
             dispatch.end(t)
             readback = rec.begin("decode.readback", CAT_SERVE_DECODE, t)
+        self._land(land, span, readback)
+
+    def land(self) -> None:
+        """Read back and emit the decode step in flight, if there is
+        one. ``step`` lands it by itself; whoever touches a slot from
+        outside ``step`` (an export, an install, a drain) calls this
+        first, so that no token the device has computed is lost."""
+        step, self._in_flight = self._in_flight, None
+        if step is not None:
+            self._land(step)
+
+    def _land(self, step: _InFlight, span=None, readback=None) -> None:
+        """Read ``step``'s tokens back and emit them, in order: each
+        goes to the slot whose row computed it if that slot still holds
+        the same request (one that ended meanwhile, by ``eos_id`` a
+        step before, gets nothing, nor would whoever was seated there
+        since). ``span`` / ``readback``: the open ``decode_step`` and
+        ``decode.readback`` spans of a landing inside ``step``."""
         # Explicit readback (jax.device_get, not an implicit
         # np.asarray): the per-step token sync is the ONE intended
         # d2h in the decode steady state, and the dispatch-hygiene
@@ -1495,40 +1702,26 @@ class Engine:
         # implicit transfers — intent made visible is the contract.
         # (A model with routed experts: its tokens per held expert ride
         # the same transfer.)
-        sel, counts = jax.device_get((sel, self.cache.program_extras))
+        sel, counts = jax.device_get((step.sel, step.extras))
         if readback is not None:
             readback.end(self.clock())
         load = record_expert_load(*counts) if counts else {}
-        # Each ACTIVE slot's logical length advanced by one (idle
-        # slots stay pinned on the trash page).
-        self.cache.advance(
-            [i for i, s in enumerate(self._slots) if s is not None]
-        )
+        # Read before ``now``, so that they are ``decode_step``'s own
+        # tail and not ``emit``'s.
+        attrs = {**(step.attrs or {}), **load}
+        now = self.clock()
         emit = None
         if span is not None:
-            # "rids" names every request this decode chunk advanced —
-            # the per-request trace's decode leg (report.py --request
-            # selects the chunks containing its id). The pages the
-            # seated slots hold and the positions the step read (lens
-            # already counts the token just written); whether attention
-            # read the pool in place, and the pages it then visits
-            # (``_paged_attrs``). Built before ``now`` is read, so that
-            # they are ``decode_step``'s own tail and not ``emit``'s.
-            busy = int(sum(s is not None for s in self._slots))
-            attrs = dict(
-                busy=busy,
-                rids=[s.request.request_id
-                      for s in self._slots if s is not None],
-                **self._paged_attrs(pages_live), **load,
-            )
-        now = self.clock()
-        if span is not None:
             span.end(now, **attrs)
-            emit = rec.begin("emit", CAT_SERVE_EMIT, now)
+            emit = self._rec.begin("emit", CAT_SERVE_EMIT, now)
         self.num_decode_steps += 1
-        registry().counter("serve_decode_steps").inc()
-        for i, s in enumerate(self._slots):
-            if s is None:
+        reg = registry()
+        reg.counter("serve_decode_steps").inc()
+        if step.ahead:
+            reg.counter("serve_decode_steps_ahead").inc()
+        finished = 0
+        for i, s in enumerate(step.rows):
+            if s is None or self._slots[i] is not s:
                 continue
             s.position += 1
             s.steps += 1
@@ -1536,7 +1729,7 @@ class Engine:
                 # First token after a migration landed: the client's
                 # stream stalled from the SOURCE's last token until now
                 # — the failover token gap the bench banks.
-                registry().histogram(
+                reg.histogram(
                     "serve_failover_token_gap_ms"
                 ).observe(1e3 * (now - s.gap_origin))
                 s.gap_origin = None
@@ -1546,11 +1739,9 @@ class Engine:
             if self.on_token is not None:
                 self.on_token(s.request.request_id, tok)
             self._maybe_finish(i, tok)
+            finished += self._slots[i] is not s
         if emit is not None:
-            emit.end(
-                self.clock(),
-                finished=busy - sum(s is not None for s in self._slots),
-            )
+            emit.end(self.clock(), finished=finished)
 
     def _spec_step(self) -> None:
         """One speculative window: k draft dispatches propose, ONE
@@ -1701,9 +1892,10 @@ class Engine:
         reg.counter("spec_slot_steps").inc(len(active))
 
     def step(self) -> bool:
-        """Seat what fits, run one decode step (speculative window when
-        a speculator is attached). False when fully drained (no active
-        slots and nothing seatable queued)."""
+        """Seat what fits, land one decode step (``_decode_step``; a
+        speculative window when a speculator is attached). False when
+        fully drained (no active slots, no step in flight and nothing
+        seatable queued)."""
         # One look for the recorder a step; what the step calls reads
         # ``self._rec``. With none, nothing below reads a clock or
         # allocates for tracing.
@@ -1720,7 +1912,7 @@ class Engine:
                 # holding the whole loop (the stale-heartbeat path).
                 hook(self.num_decode_steps)
             self._fill_slots()
-            if not self._active():
+            if self._in_flight is None and not self._active():
                 # Nothing seated: the queue is empty or held only
                 # expired entries (shed during the fill's pop).
                 self._record_shed(

@@ -476,12 +476,13 @@ class AdapterPool:
     def dispatch_args(self):
         """The three extra traced inputs every multi-tenant dispatch
         carries: (pools pytree, slot table [B, r_max], slot scale
-        [B])."""
+        [B]); copies of the host's two, which a slot's binding moves
+        while a dispatch may still be queued."""
         with self._lock:
             return (
                 self.pools,
-                jnp.asarray(self.slot_table),
-                jnp.asarray(self.slot_scale),
+                jnp.asarray(self.slot_table.copy()),
+                jnp.asarray(self.slot_scale.copy()),
             )
 
     # -- accounting -----------------------------------------------------

@@ -252,6 +252,9 @@ class Replica:
                 box["requests"][item.rid] = Request(**meta["request"])
             else:
                 box["payloads"][item.rid] = item.payload
+        # The decode step in flight lands before the seats are listed: a
+        # request it finishes leaves as its Result, not as a payload.
+        engine.land()
         for rid in [
             s.request.request_id for s in engine._slots if s is not None
         ]:
