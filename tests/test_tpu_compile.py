@@ -701,6 +701,120 @@ def test_shortcut_moe_program_compiles_for_v5e(
     assert "bf16[16,192,2048]" in text and "bf16[768," not in text
 
 
+# Xing4.0-29B-A4B's stage 0 of ISSUE 38 at its cell's size
+# (perfbench/configs/xing4-29b-a4b-l6.json through its family's own
+# ``model_config``): a residual of four streams a token, six latent
+# pools of the sarvam cell's held shape, 64 slots of 4,352 positions
+# (272 pages a slot: nine blocks of the latent kernel), 32 heads, all 64
+# experts of five layers held. The decode program attends all six pools
+# in place, takes them donated and copies none; 9.60 GB of weights,
+# 1.93 GB of pools and a step's temporaries fit the chip. The batch-1
+# prefill at 2,048 rows (the half of the window: the ladder's shorter
+# length) attends in blocks and fits beside them.
+
+
+def _hyper_mla_moe_session():
+    import json
+
+    from perfbench.families import hyper_mla_moe_serve as family
+    from perfbench.reference import hyper_mla_moe as ref
+    from tpudl.models.llama import LlamaForCausalLM
+    from tpudl.serve import ServeSession
+
+    with open(REPO / "perfbench/configs/xing4-29b-a4b-l6.json") as f:
+        cfg = json.load(f)
+    sess = cfg["session"]
+    model = LlamaForCausalLM(
+        family.model_config(cfg, sess["max_seq_len"], bf16)
+    )
+    s = ref.settings(cfg)
+    key = jax.eval_shape(lambda: ref.seed_key(0))
+    params = jax.eval_shape(
+        lambda k: family.to_flax(ref.all_weights(k, s, bf16), s), key
+    )
+    session = ServeSession.from_model(
+        model, params, sess["prompt_window"], num_slots=sess["num_slots"],
+        page_size=sess["page_size"],
+        num_pages=sess["max_seq_len"] // sess["page_size"] + 1,
+    )
+    return sess, model, params, session
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_hyper_mla_moe_program_compiles_for_v5e(
+    name, monkeypatch, no_compile_cache
+):
+    import math
+    import re
+
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+    from tpudl.models.generate import prefill_fn
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    on_chip = SingleDeviceSharding(device)
+    sess, model, params, session = _hyper_mla_moe_session()
+    slots, page = sess["num_slots"], sess["page_size"]
+    weights = sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(params)
+    )
+    assert 9.59e9 < weights < 9.61e9
+    if name == "prefill":
+        ids = _s((1, 2048), i32, sharding=on_chip)
+        compiled = jax.jit(prefill_fn(model)).lower(
+            _placed(params, on_chip), ids, ids
+        ).compile()
+        memory = compiled.memory_analysis()
+        # Beside the pools (1.93 GB) on a chip of 16 GB.
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 11e9
+        text = compiled.as_text()
+        # No score matrix of the whole prompt, at any precision: a
+        # block of 256 queries meets the keys up to its own end.
+        assert ",2048,2048]" not in text
+        for keys in range(256, 2049, 256):
+            assert f"f32[1,32,1,256,{keys}]" in text
+        return
+    cache = session.engine.cache
+    table_pages = sess["max_seq_len"] // page
+    assert table_pages == 272
+    shape = (slots * table_pages + 1, page // 2, 2 * 576)
+    leaves = jax.tree.leaves(cache.cache)
+    assert len(leaves) == 6 and cache.folds == (2,) * 6
+    assert all(leaf.shape[1:] == shape[1:] for leaf in leaves)
+    pool = jax.tree.map(
+        lambda leaf: _s(shape, leaf.dtype, sharding=on_chip), cache.cache
+    )
+    vec = _s((slots,), i32, sharding=on_chip)
+    table = _s((slots, table_pages), i32, sharding=on_chip)
+    compiled = session.engine.decode_call.lower(
+        _placed(params, on_chip), pool, vec, vec, table, vec, vec
+    ).compile()
+    took = session.engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True,) * 6
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "latent_paged_attention" in text and "kv_gather" not in text
+    memory = compiled.memory_analysis()
+    pool_bytes = 6 * math.prod(shape) * 2
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert 1.92e9 < pool_bytes < 1.93e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+    dims = ",".join(map(str, shape))
+    assert not re.findall(
+        rf"= bf16\[{dims}\][^ ]* copy(?:-start|-done)?\(", text
+    )
+    # Nothing of a slot's whole logical view (held rows or positions).
+    assert f"[{slots},{sess['max_seq_len'] // 2}," not in text
+    # Every choice lands on a held expert: the grouped matmuls are over
+    # all 64, at the step's 64 rows in the dense form.
+    assert "bf16[64,64,1024]" in text
+
+
 # A configuration that sets none of ISSUE 33's keys (the block's kind,
 # the low-rank query and its scales, the router's scoring, identity
 # experts) builds the programs it built before: the lowered text of a

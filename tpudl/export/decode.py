@@ -131,6 +131,14 @@ def export_serving_decoder(
             "and decode contracts, and a model with routed experts "
             "returns its tokens per held expert beside them"
         )
+    if getattr(getattr(model, "cfg", None), "hyper_streams", 0):
+        raise ValueError(
+            "the exported decode artifact is not wired to a residual "
+            "stream of several vectors a token (hyper_streams): an "
+            "artifact session takes the two-value prefill and decode "
+            "contracts, and such a model returns its maps' statistic "
+            "beside them"
+        )
     token = jnp.zeros((num_slots,), jnp.int32)
     position = jnp.full((num_slots,), prompt_len, jnp.int32)
     prefill_blob = export_stablehlo(

@@ -1,11 +1,9 @@
 """Autoregressive decoding with a KV cache (Llama serving path).
 
-The reference repo's substance is inference benchmarking of an exported
-model (reference notebooks/cv/onnx_experiments.py:77-140 — build a
-session, run it, time it); this is the decoder-model analog: a jitted
-prefill + a jitted single-token decode step over static-shape KV caches
-(tpudl.models.llama.LlamaAttention decode mode), so the whole generation
-loop runs as two compiled XLA programs regardless of length.
+The decoder-model analog of the reference repo's inference benchmarking
+(reference notebooks/cv/onnx_experiments.py:77-140): a jitted prefill +
+a jitted single-token decode step over static-shape KV caches, so the
+generation loop runs as two compiled XLA programs whatever the length.
 
 Greedy (temperature=0), temperature, top-k, and top-p (nucleus)
 sampling. Ragged prompt batches are served LEFT-padded: the cache marks
@@ -24,6 +22,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from tpudl.models.hyper import HYPER_STAT_NAME
+
 
 def named(fn, name: str):
     """``fn`` under another name: ``jax.jit`` calls the compiled
@@ -33,34 +33,34 @@ def named(fn, name: str):
     return fn
 
 
-#: The collection a routed-expert layer sows its token counts into
-#: (tpudl.ops.moe.DroplessMoE).
+#: The collection a layer sows its serving statistics into
+#: (tpudl.ops.moe.DroplessMoE, tpudl.models.hyper.HyperConnection).
 MOE_STATS = "moe_stats"
 
 
 def _apply_cached(model, variables, *args, **kwargs):
     """``model.apply`` with the cache mutable: ``(output, cache,
-    *stats)``. A model with routed experts adds one int32 array
-    ``[expert layers, ...]`` a statistic its layers sow, layers in
-    order (tpudl.ops.moe.MOE_STAT_NAMES: the real tokens each held
-    expert got in this call, ...); other models add nothing."""
+    *stats)``: one array ``[layers, ...]`` a statistic the layers sow,
+    layers in order, at fixed places (tpudl.ops.moe.MOE_STAT_NAMES, then
+    tpudl.models.hyper.HYPER_STAT_NAME), None where a later one is sown
+    and this one is not; a model that sows none adds nothing."""
     out, mutated = model.apply(
         variables, *args, mutable=["cache", MOE_STATS], **kwargs
     )
     from tpudl.ops.moe import MOE_STAT_NAMES
-    stats = jax.tree_util.tree_flatten_with_path(
-        mutated.get(MOE_STATS, {})
-    )[0]
+    stats = jax.tree_util.tree_leaves_with_path(mutated.get(MOE_STATS, {}))
 
     def layer(path_leaf) -> int:
         path = jax.tree_util.keystr(path_leaf[0])
         return int(re.search(r"layer_(\d+)", path).group(1))
 
-    by_name = {name: [
+    stacks = [[
         leaf for path, leaf in sorted(stats, key=layer)
         if path[-2].key == name
-    ] for name in MOE_STAT_NAMES}
-    stacks = [jnp.stack(leaves) for leaves in by_name.values() if leaves]
+    ] for name in (*MOE_STAT_NAMES, HYPER_STAT_NAME)]
+    while stacks and not stacks[-1]:
+        stacks.pop()
+    stacks = [jnp.stack(leaves) if leaves else None for leaves in stacks]
     return (out, mutated["cache"], *stacks)
 
 

@@ -172,29 +172,26 @@ class LlamaConfig:
                     "attention keeps every layer alike"
                 )
         _check_block(self)
-    # Fused-epilogue kernel tier (tpudl.ops.norms / mlp_fused): False =
-    # composite RMSNorm/SwiGLU; True = Pallas fused on TPU, composite
-    # off it; "force" = Pallas everywhere. Same param tree either way.
+    # Fused-epilogue kernels (tpudl.ops.norms / mlp_fused): False =
+    # composite; True = Pallas on TPU; "force" = Pallas everywhere.
     fused_ops: Any = False
     # Low-precision weight tier (tpudl.quant): None = plain nn.Dense;
     # "int8" / "fp8_e4m3" = the projections become QuantDense and serve
-    # the quantize_tree output (same tree structure; norms, embeddings
-    # and lm_head stay full). ServeSession.from_model(weight_dtype=...).
+    # the quantize_tree output. ServeSession.from_model(weight_dtype=).
     weight_dtype: Optional[str] = None
-    # fp8 TRAINING tier (tpudl.ops.fp8_dot, the "fp8" precision
-    # policy): the sites LLAMA_QUANT_PATTERNS address go through
-    # Fp8Dense; a string pins the fp8_dot impl. Exclusive with
-    # weight_dtype, composes with lora_rank.
+    # fp8 TRAINING tier (tpudl.ops.fp8_dot): LLAMA_QUANT_PATTERNS' sites
+    # go through Fp8Dense; a string pins the fp8_dot impl. Exclusive
+    # with weight_dtype, composes with lora_rank.
     fp8_train: Any = False
     # MoE (tpudl.ops.moe): >0 swaps every block's dense MLP for MoEMlp.
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
     # What each layer is made of. ``attention``: "gqa" (the block
-    # above) or "mla", latent attention: keys and values are
-    # up-projections of ONE normed latent of ``kv_lora_rank`` values a
-    # position plus one roped key of ``qk_rope_head_dim`` shared by all
-    # heads, and that pair is all the cache keeps (LatentAttention).
+    # above) or "mla", latent attention: keys and values up-project ONE
+    # normed latent of ``kv_lora_rank`` values a position plus one roped
+    # key of ``qk_rope_head_dim`` shared by all heads, and that pair is
+    # all the cache keeps (LatentAttention).
     attention: str = "gqa"
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -203,10 +200,9 @@ class LlamaConfig:
     rope_scaling: Optional[RopeScaling] = None
     # Dropless routed experts (tpudl.ops.moe.DroplessMoE) in every
     # layer from ``first_k_dense`` on; the layers before keep the dense
-    # SwiGLU of ``intermediate_size``. ``num_experts`` is the router's
-    # width; ``experts_held = (first, count)`` names the experts whose
-    # weights this program holds (None: all), as one share of an
-    # expert-parallel deployment does.
+    # SwiGLU of ``intermediate_size``. ``num_experts``: the router's
+    # width; ``experts_held = (first, count)``: the experts whose
+    # weights this program holds (None: all), one expert-parallel share.
     num_experts: int = 0
     experts_per_token: int = 0
     moe_intermediate_size: int = 0
@@ -214,19 +210,16 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     first_k_dense: int = 0
     experts_held: Optional[Tuple[int, int]] = None
-    # Layers that differ in their attention. ``layer_types`` names each
+    # Layers that differ in their attention. ``layer_types``: each
     # layer's kind ("full_attention": the whole context;
     # "sliding_attention": the last ``sliding_window`` positions, the
     # query's own counted), ``num_heads_per_layer`` its query heads
-    # (``num_kv_heads`` is shared), ``head_size`` states the head's
-    # width where it is not ``hidden_size // num_heads``. RoPE by kind:
-    # a full layer rotates the first ``partial_rotary_factor`` of the
-    # head with ``rope_theta`` / ``rope_scaling``, a sliding layer the
-    # whole head, plainly, with ``sliding_rope_theta``.
-    # ``attention_gate``: one sigmoid gate a head, from the layer's
-    # normed input, on the attention's output before ``o_proj``. A
-    # configuration that sets none of these builds the uniform blocks
-    # above (``layer_spec``).
+    # (``num_kv_heads`` is shared), ``head_size`` the head's width where
+    # it is not ``hidden_size // num_heads``. A full layer rotates the
+    # first ``partial_rotary_factor`` of the head with ``rope_theta`` /
+    # ``rope_scaling``, a sliding layer the whole head, plainly, with
+    # ``sliding_rope_theta``. ``attention_gate``: a sigmoid gate a head,
+    # from the layer's normed input, before ``o_proj`` (``layer_spec``).
     head_size: int = 0
     layer_types: Optional[Tuple[str, ...]] = None
     num_heads_per_layer: Optional[Tuple[int, ...]] = None
@@ -234,13 +227,12 @@ class LlamaConfig:
     sliding_rope_theta: float = 10_000.0
     partial_rotary_factor: float = 1.0
     attention_gate: bool = False
-    # ``block``: "llama" (attention -> MLP, once a layer) or "shortcut"
-    # (ShortcutBlock: two latent attentions and two dense FFNs around
-    # one expert branch). ``q_lora_rank`` > 0: the latent query is
-    # low-rank (q_a_proj, RMSNorm, q_b_proj); ``mla_scale_q`` / ``_kv``
-    # are constants on the normed low-rank query and the normed latent.
-    # ``router_scoring`` ("sigmoid" | "softmax"), ``router_renormalize``
-    # and ``zero_experts`` (identity experts) are DroplessMoE's.
+    # ``block``: "llama" or "shortcut" (ShortcutBlock: two latent
+    # attentions and two dense FFNs around one expert branch).
+    # ``q_lora_rank`` > 0: the latent query is low-rank (q_a_proj,
+    # RMSNorm, q_b_proj); ``mla_scale_q`` / ``_kv``: constants on the
+    # normed low-rank query and latent. ``router_scoring``,
+    # ``router_renormalize``, ``zero_experts``: DroplessMoE's.
     block: str = "llama"
     q_lora_rank: int = 0
     mla_scale_q: float = 1.0
@@ -248,6 +240,14 @@ class LlamaConfig:
     router_scoring: str = "sigmoid"
     router_renormalize: bool = True
     zero_experts: int = 0
+    # ``hyper_streams`` n > 0: the residual is n vectors a token, mixed
+    # around every sublayer by manifold-constrained hyper-connections
+    # (HyperBlock, tpudl.models.hyper); the model's Sinkhorn iterations,
+    # their epsilon and the clamp before the exponential beside it.
+    hyper_streams: int = 0
+    hyper_sinkhorn_iters: int = 20
+    hyper_eps: float = 1e-6
+    hyper_clamp: float = 30.0
 
     @property
     def head_dim(self) -> int:
@@ -449,23 +449,23 @@ def rope(
     return out.astype(x.dtype)
 
 
-def _gqa_decode_attention(q, k, v, mask):
+def _gqa_decode_attention(q, k, v, mask, scale=None):
     """Decode-path attention with query heads grouped over shared KV
-    heads. q: [B, S, H, D]; k, v: [B, T, Hkv, D]; mask: [B, 1, S, T]
-    (True = attend). f32 logits/softmax like
-    tpudl.ops.attention.dot_product_attention."""
+    heads. q: [B, S, H, D]; k: [B, T, Hkv, D]; v: [B, T, Hkv, Dv]; mask:
+    [B, 1, S, T] (True = attend); ``scale``: the softmax's, D ** -0.5
+    unless given. f32 logits/softmax like ops.attention's."""
     from tpudl.ops.attention import MASK_VALUE
 
     b, s, h, d = q.shape
     hkv = k.shape[2]
     g = h // hkv
     qg = q.reshape(b, s, hkv, g, d)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * (d ** -0.5)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * (scale or d ** -0.5)
     logits = logits.astype(jnp.float32)
     logits = jnp.where(mask[:, :, None, :, :], logits, MASK_VALUE)
     weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     ctx = jnp.einsum("bhgqk,bkhd->bqhgd", weights, v)
-    return ctx.reshape(b, s, h, d)
+    return ctx.reshape(b, s, h, v.shape[-1])
 
 
 def _paged_cache_missing():
@@ -488,7 +488,7 @@ PREFILL_SCORE_BYTES = 256 << 20
 PREFILL_BLOCK = 256
 
 
-def _blocked_attention(q, k, v, valid, window: int, block: int):
+def _blocked_attention(q, k, v, valid, window: int, block: int, scale=None):
     """Causal grouped-query attention of a chunk over itself, a block
     of queries at a time, so that no [H, S, S] tensor exists. q: [B, S,
     H, D]; k, v: [B, S, Hkv, D] in slot order; valid: [B, S] bool (a
@@ -507,7 +507,7 @@ def _blocked_attention(q, k, v, valid, window: int, block: int):
         if window:
             mask = mask & (q_slot - kv_slot < window)[None]
         out.append(_gqa_decode_attention(
-            q[:, at:end], k[:, low:end], v[:, low:end], mask[:, None]
+            q[:, at:end], k[:, low:end], v[:, low:end], mask[:, None], scale
         ))
     return jnp.concatenate(out, axis=1)
 
@@ -849,15 +849,15 @@ class LatentAttention(nn.Module):
                 q_slot = q_slot + start
             kv_slot = jnp.arange(rows.shape[1])[None, None, None, :]
             mask = (kv_slot <= q_slot) & valid[:, None, None, :]
-            ctx = _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale)
+            ctx = _mla_prefill(
+                q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh)
         else:
             slot = jnp.arange(S)
             mask = (slot[None, :] <= slot[:, None])[None, None]
             if kv_mask is not None:
                 mask = mask & kv_mask.astype(jnp.bool_)[:, None, None, :]
             ctx = _mla_up_projected(
-                q_nope, q_rope, latent, kv_b, dn, mask, scale
-            )
+                q_nope, q_rope, latent, kv_b, dn, mask, scale)
         return _proj(cfg, cfg.hidden_size, "o_proj")(
             ctx.reshape(B, S, H * dv)
         )
@@ -1073,8 +1073,8 @@ class LlamaModel(nn.Module):
                 embedding_init=nn.initializers.normal(0.02),
                 name="embed_tokens",
             )(input_ids).astype(cfg.dtype)
-        x = constrain(x, ("dp", "fsdp"), "sp", "tp")
-        block = _BLOCKS[cfg.block]
+        x = _enter_stream(cfg, constrain(x, ("dp", "fsdp"), "sp", "tp"))
+        block = _block_of(cfg)
         if cfg.remat and not decode:
             # adapters never reach the remat path: multi-tenant views
             # are decode-only (serving), and decode skips remat.
@@ -1091,7 +1091,7 @@ class LlamaModel(nn.Module):
         return RMSNorm(
             cfg.rms_norm_eps, fused_ops_impl(cfg.fused_ops),
             name="final_norm"
-        )(x)
+        )(_leave_stream(cfg, x))
 
 
 class LlamaForCausalLM(nn.Module):
@@ -1152,8 +1152,8 @@ class LlamaForSequenceClassification(nn.Module):
 
 
 def _check_block(cfg: LlamaConfig) -> None:
-    """``LlamaConfig.__post_init__``'s checks of the block's kind, the
-    low-rank query and the router."""
+    """``__post_init__``: the block, the low-rank query, the router."""
+    _check_stream(cfg)
     if cfg.block not in _BLOCKS:
         raise ValueError(
             f"block must be one of {sorted(_BLOCKS)}, got {cfg.block!r}"
@@ -1320,8 +1320,166 @@ class ShortcutBlock(nn.Module):
         return constrain(hidden, ("dp", "fsdp"), "sp", "tp")
 
 
+class HyperBlock(nn.Module):
+    """``LlamaBlock``'s two sublayers around a residual stream of
+    ``cfg.hyper_streams`` vectors a token, [B, S, n, d]
+    (tpudl.models.hyper): each sublayer reads a learned mixture of the
+    streams through the layer's own norm (``input_norm`` /
+    ``post_attention_norm``), and its output is written back through a
+    doubly-stochastic n x n map and a learned fan-out:
+
+        u, h_post, H = maps(X);  X <- H X + h_post F(norm(u))^T
+
+    once for the attention, once for the dense SwiGLU or the expert
+    layer, each with maps of its own (``hyper_attention``,
+    ``hyper_mlp``). Nothing is added to anything else: there is no
+    residual sum for a norm to fold. Scopes: ``hyper`` (>
+    ``hyper_maps``, ``hyper_mix_in``, ``hyper_mix_out``) beside
+    ``attention`` and ``mlp``. A block of its own, so that
+    ``LlamaBlock`` keeps its program, and its lines, as they were."""
+
+    cfg: LlamaConfig
+    mlp: str = "dense"
+    layer: int = 0
+
+    @nn.compact
+    def __call__(
+        self, stream, positions, kv_mask=None, decode: bool = False,
+        paged=None, adapters=None,
+    ):
+        from tpudl.models.hyper import HyperConnection, mix_out
+        from tpudl.ops.norms import fused_ops_impl
+
+        cfg = self.cfg
+        if adapters is not None:
+            raise ValueError(
+                "per-tenant adapters are not wired to a stream of "
+                "several vectors a token (hyper_streams)"
+            )
+        impl = fused_ops_impl(cfg.fused_ops)
+        real = real_tokens(stream, kv_mask, paged)
+
+        def attention(x):
+            if cfg.attention == "mla":
+                module = LatentAttention(cfg, name="attention")
+            else:
+                module = LlamaAttention(cfg, self.layer, name="attention")
+            return module(x, positions, kv_mask, decode, paged)
+
+        def mlp(x):
+            with jax.named_scope("mlp"):
+                if self.mlp != "moe":
+                    return _DenseFFN(cfg, name="mlp")(x)
+                from tpudl.ops.moe import DroplessMoE
+
+                return DroplessMoE(
+                    num_experts=cfg.num_experts,
+                    experts_per_token=cfg.experts_per_token,
+                    intermediate_size=cfg.moe_intermediate_size,
+                    shared_intermediate_size=(
+                        cfg.num_shared_experts * cfg.moe_intermediate_size
+                    ),
+                    routed_scaling_factor=cfg.routed_scaling_factor,
+                    experts_held=cfg.experts_held,
+                    dtype=cfg.dtype,
+                    weight_dtype=cfg.weight_dtype,
+                    scoring=cfg.router_scoring,
+                    renormalize=cfg.router_renormalize,
+                    zero_experts=cfg.zero_experts,
+                    name="moe",
+                )(x, real)
+
+        for name, norm, sublayer in (
+            ("hyper_attention", "input_norm", attention),
+            ("hyper_mlp", "post_attention_norm", mlp),
+        ):
+            u, h_post, h_res = HyperConnection(
+                cfg.hyper_streams, cfg.hyper_sinkhorn_iters, cfg.hyper_eps,
+                cfg.hyper_clamp, cfg.rms_norm_eps, name=name,
+            )(stream, real)
+            y = sublayer(RMSNorm(cfg.rms_norm_eps, impl, name=norm)(u))
+            stream = mix_out(stream, h_res, h_post, y)
+        return constrain(stream, ("dp", "fsdp"), "sp", None, "tp")
+
+
 #: ``LlamaConfig.block`` -> the module a layer is.
 _BLOCKS = {"llama": LlamaBlock, "shortcut": ShortcutBlock}
+
+
+def _check_stream(cfg: LlamaConfig) -> None:
+    """``_check_block``'s checks of ``hyper_streams``: what a stream of
+    several vectors a token is, and what it is not wired to."""
+    if cfg.hyper_streams < 0 or cfg.hyper_streams == 1:
+        raise ValueError(
+            f"hyper_streams must be 0 (one residual vector a token) or "
+            f">= 2 (that many, mixed by hyper-connections), got "
+            f"{cfg.hyper_streams}"
+        )
+    if cfg.hyper_streams:
+        # What a stream of several vectors a token is not wired to.
+        if cfg.block == "shortcut":
+            raise ValueError(
+                "hyper_streams is not wired to block='shortcut': the "
+                "double layer's expert branch leaves the residual after "
+                "one attention and rejoins it after the second FFN, and "
+                "which sublayer's maps would read and write the stream "
+                "for it is not defined"
+            )
+        if cfg.lora_rank or cfg.moe_experts or cfg.fp8_train:
+            raise ValueError(
+                "hyper_streams is not wired to lora_rank, moe_experts "
+                "or fp8_train: HyperBlock builds the serving sublayers "
+                "(attention, dense SwiGLU, dropless experts) and none "
+                "of the training tiers"
+            )
+
+
+def _block_of(cfg: LlamaConfig):
+    """The module a layer of ``cfg`` is: by ``block``, and for a stream
+    of several vectors a token ``HyperBlock``."""
+    return HyperBlock if cfg.hyper_streams else _BLOCKS[cfg.block]
+
+
+def _enter_stream(cfg: LlamaConfig, x):
+    """The embedding as the blocks' residual: itself, or repeated into
+    ``hyper_streams`` streams, [B, S, n, d]."""
+    if not cfg.hyper_streams:
+        return x
+    return jnp.repeat(x[:, :, None], cfg.hyper_streams, axis=2)
+
+
+def _leave_stream(cfg: LlamaConfig, x):
+    """What the final norm reads: the residual, or the streams' sum
+    (float32, stored as the stream is)."""
+    if not cfg.hyper_streams:
+        return x
+    return jnp.sum(x, axis=2, dtype=jnp.float32).astype(x.dtype)
+
+
+def _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh):
+    """``_mla_up_projected`` for a chunk written into a row cache.
+    Where the chunk starts the cache (``fresh``: its own rows are all
+    there is to attend to; ``valid`` [B, S] marks the real ones) and
+    its scores [B, H, S, S] float32 would pass ``PREFILL_SCORE_BYTES``,
+    it is attended a block of ``PREFILL_BLOCK`` queries at a time by
+    the grouped-query routine, with one KV head a query head: keys
+    ``[k_nope_h | k_r]`` (the roped key repeated to every head),
+    values ``v_h``. Chosen from the static shapes of the program being
+    traced: 512 rows at 64 heads are 67 MB and keep the one pass."""
+    b, s, h, _ = q_nope.shape
+    if not fresh or 4 * b * h * s * s <= PREFILL_SCORE_BYTES:
+        return _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale)
+    r = kv_b.shape[0]
+    with jax.named_scope("mla_core"):
+        up = jnp.einsum("btr,rhd->bthd", rows[..., :r], kv_b)
+        k_rope = jnp.broadcast_to(
+            rows[:, :, None, r:], (b, s, h, rows.shape[-1] - r)
+        )
+        return _blocked_attention(
+            jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([up[..., :dn], k_rope.astype(up.dtype)], axis=-1),
+            up[..., dn:], valid, 0, PREFILL_BLOCK, scale,
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,13 @@ LLAMA_QUANT_PATTERNS = (
     r"q_(a|b)_proj/kernel$",
 )
 
+#: Which Llama leaves never quantize, whatever a later pattern says: a
+#: hyper-connection's maps (``hyper_attention/phi``, ``hyper_mlp/phi``;
+#: tpudl.models.hyper) decide how every value of the residual stream is
+#: mixed, in float32, as a router's matrix decides the choice of experts
+#: (``router/kernel``, which no pattern above names).
+LLAMA_KEEP_PATTERNS = (r"hyper_\w+/phi$",)
+
 #: Which BERT leaves quantize: encoder attention + MLP projections.
 #: The pooler/classifier head and embeddings keep full precision.
 BERT_QUANT_PATTERNS = (
@@ -212,8 +219,9 @@ def default_quant_rules(model_or_cfg: Any, weight_dtype: str) -> Rules:
     (``rope_theta``) or BERT (``type_vocab_size``)."""
     validate_weight_dtype(weight_dtype)
     cfg = getattr(model_or_cfg, "cfg", model_or_cfg)
+    keep = ()
     if hasattr(cfg, "rope_theta"):
-        patterns = LLAMA_QUANT_PATTERNS
+        patterns, keep = LLAMA_QUANT_PATTERNS, LLAMA_KEEP_PATTERNS
     elif hasattr(cfg, "type_vocab_size"):
         patterns = BERT_QUANT_PATTERNS
     else:
@@ -221,7 +229,10 @@ def default_quant_rules(model_or_cfg: Any, weight_dtype: str) -> Rules:
             f"no default quantization rules for {type(cfg).__name__}; "
             f"pass explicit rules to quantize_tree"
         )
-    return tuple((p, weight_dtype) for p in patterns) + ((r".*", None),)
+    return (
+        tuple((p, None) for p in keep)
+        + tuple((p, weight_dtype) for p in patterns) + ((r".*", None),)
+    )
 
 
 def quantize_model(

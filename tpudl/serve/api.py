@@ -434,6 +434,29 @@ class ServeSession:
                     "yet (the draft path has no adapter view)"
                 )
         cfg = getattr(model, "cfg", None)
+        streams = getattr(cfg, "hyper_streams", 0)
+        registry().gauge("serve_hyper_streams").set(streams)
+        if streams:
+            # A residual of several vectors a token (hyper-connections)
+            # is served from the paged pool, with its int8 store and
+            # prefix sharing where its attention has them. What was
+            # written for one vector a token says so here.
+            for what, asked, why in (
+                ("per-tenant adapters", adapters is not None,
+                 "the adapter pool addresses the projections of "
+                 "LlamaBlock, and HyperBlock takes no adapter view"),
+                ("spec_k", spec_k,
+                 "the verify step does not report its window's maps "
+                 "(hyper_res_offdiag, hyper_res_sum_error)"),
+                ("a mesh-committed session", mesh is not None,
+                 "no sharding rule places the maps' parameters, and the "
+                 "stream's constraint has run on no mesh"),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{what} is not wired to a residual stream of "
+                        f"{streams} vectors a token (hyper_streams): {why}"
+                    )
         if getattr(cfg, "block", "llama") == "shortcut":
             # The shortcut double layer (two latent attentions and two
             # dense FFNs around one expert branch) is served from the

@@ -164,11 +164,15 @@ def first_token(logits, request, also=None):
     return int(sel[0]), also
 
 
-def record_expert_load(counts, chose=None) -> dict:
-    """``counts``: int [expert layers, experts held] on the host, the
-    real tokens each held expert got in one prefill or decode step
-    (tpudl.ops.moe.DroplessMoE). Counted into the registry and returned
-    as the step's span attributes: ``moe_assignments`` (their sum),
+def record_expert_load(counts=None, chose=None, hyper=None) -> dict:
+    """The statistics a model's layers sowed in one prefill or decode
+    step, on the host, in the places the serving contracts return them
+    (tpudl.models.generate): counted into the registry and returned as
+    the step's span attributes. Each is None for a model without it.
+
+    ``counts``: int [expert layers, experts held], the real tokens each
+    held expert got (tpudl.ops.moe.DroplessMoE): ``moe_assignments``
+    (their sum),
     ``moe_experts_touched`` (held experts, over the layers, that got a
     token: those whose weights the step had to read) and
     ``moe_load_max_over_mean`` (the busiest held expert's tokens over
@@ -183,7 +187,28 @@ def record_expert_load(counts, chose=None) -> dict:
     the layers summed); counters of the first two names under
     ``serve_``, and each layer's mean real experts a token into the
     histogram ``serve_moe_real_experts_a_token`` (one observation a
-    layer, not one a token: this runs between decode steps)."""
+    layer, not one a token: this runs between decode steps).
+
+    ``hyper`` (a model whose residual is several streams a token):
+    float [2 x layers, 3], a sublayer's maps a row
+    (tpudl.models.hyper.HYPER_STAT_NAME). ``hyper_res_offdiag``: the
+    mean over the real tokens, sublayers and layers of the mass
+    ``H_res`` puts off its diagonal (0: streams kept apart; ``1 -
+    1/n``: fully mixed), also observed into the histogram
+    ``serve_hyper_res_offdiag``; ``hyper_res_sum_error``: the largest
+    ``|column sum - 1|`` the Sinkhorn iterations left."""
+    attrs = {}
+    if hyper is not None:
+        hyper = np.asarray(hyper, np.float64)
+        tokens = hyper[:, 1].sum()
+        off = float(hyper[:, 0].sum() / tokens) if tokens else 0.0
+        registry().histogram("serve_hyper_res_offdiag").observe(off)
+        attrs.update(
+            hyper_res_offdiag=off,
+            hyper_res_sum_error=float(hyper[:, 2].max()),
+        )
+    if counts is None:
+        return attrs
     counts = np.asarray(counts)
     total = int(counts.sum())
     reg = registry()
@@ -195,11 +220,11 @@ def record_expert_load(counts, chose=None) -> dict:
     skew = [
         float(row.max() / mean) for row, mean in zip(counts, means) if mean > 0
     ]
-    attrs = {
-        "moe_assignments": total,
-        "moe_experts_touched": int((counts > 0).sum()),
-        "moe_load_max_over_mean": max(skew) if skew else 0.0,
-    }
+    attrs.update(
+        moe_assignments=total,
+        moe_experts_touched=int((counts > 0).sum()),
+        moe_load_max_over_mean=max(skew) if skew else 0.0,
+    )
     if chose is not None:
         chose = np.asarray(chose)
         k = chose.shape[1] - 1
