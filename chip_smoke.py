@@ -419,6 +419,7 @@ def serve_phase(
     import numpy as np
 
     from tpudl.analysis.dispatch import RecompileWatcher
+    from tpudl.obs import registry
     from tpudl.serve import ServeSession
 
     dtype = jnp.bfloat16 if dtype is None else dtype
@@ -447,9 +448,14 @@ def serve_phase(
                 prompt_len, max_new,
             )
         engine = session.engine
+        gauges = registry().snapshot()["gauges"]
         counts = {
             "prefills": engine.num_prefills,
             "decode_steps": engine.num_decode_steps,
+            # Kernels the session holds turned (tpudl.serve.weights):
+            # 3 a layer on a chip, none on a CPU.
+            "weights_relaid_leaves": gauges["serve_weights_relaid_leaves"],
+            "weights_relaid_bytes": gauges["serve_weights_relaid_bytes"],
         }
     del session, engine, params
     if watch.count:

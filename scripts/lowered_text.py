@@ -26,7 +26,14 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 
-def main(root: str, out: str) -> int:
+def decoder_programs(root: str, param_dtype=None):
+    """``(name, kind, fn, args)`` for the prefill (at the prompt window)
+    and the paged decode program of every decoder configuration
+    ``BENCHMARK.json`` names, two layers each, their arguments shapes
+    placed on one device of a described v5e, the kernels taken.
+    ``args[0]`` is the parameters, in ``param_dtype`` or as
+    ``model.init`` declares them (float32: what the texts compared
+    across checkouts have always held)."""
     sys.path.insert(0, root)
     os.chdir(root)
     import jax
@@ -46,13 +53,11 @@ def main(root: str, out: str) -> int:
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    debug = bool(int(os.environ.get("DEBUG_INFO", "0")))
 
-    def on_chip(tree):
+    def on_chip(tree, dtype=None):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=chip), tree)
+            a.shape, dtype or a.dtype, sharding=chip), tree)
 
-    os.makedirs(out, exist_ok=True)
     with open("BENCHMARK.json") as f:
         files = [c["file"] for c in json.load(f)["configs"]]
     for file in files:
@@ -82,15 +87,13 @@ def main(root: str, out: str) -> int:
             model_cfg = family.model_config(
                 cfg, sess["max_seq_len"], jnp.bfloat16)
         model = LlamaForCausalLM(model_cfg)
-        params = on_chip(jax.eval_shape(lambda: model.init(
-            jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]))
+        params = jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"])
+        params = on_chip(params, param_dtype)
         prefill = prefill_fn(model)
         ids = jax.ShapeDtypeStruct(
             (1, sess["prompt_window"]), jnp.int32, sharding=chip)
-        text = jax.jit(prefill).lower(params, ids, ids).as_text(
-            debug_info=debug)
-        with open(os.path.join(out, f"{name}.prefill.txt"), "w") as f:
-            f.write(text)
+        yield name, "prefill", prefill, (params, ids, ids)
         slots = sess["num_slots"]
         ids = jax.ShapeDtypeStruct((slots, sess["prompt_window"]), jnp.int32)
         _, template, *_ = jax.eval_shape(prefill, params, ids, ids)
@@ -101,12 +104,21 @@ def main(root: str, out: str) -> int:
 
         pools, addressing = on_chip(jax.eval_shape(pools_and_addressing))
         vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
-        text = jax.jit(paged_decode_fn(model, sess["page_size"], False)).lower(
-            params, pools, vec, vec, *addressing
-        ).as_text(debug_info=debug)
-        with open(os.path.join(out, f"{name}.decode.txt"), "w") as f:
+        yield (name, "decode", paged_decode_fn(model, sess["page_size"], False),
+               (params, pools, vec, vec, *addressing))
+
+
+def main(root: str, out: str) -> int:
+    debug = bool(int(os.environ.get("DEBUG_INFO", "0")))
+    import jax
+
+    os.makedirs(out, exist_ok=True)
+    for name, kind, fn, args in decoder_programs(root):
+        text = jax.jit(fn).lower(*args).as_text(debug_info=debug)
+        with open(os.path.join(out, f"{name}.{kind}.txt"), "w") as f:
             f.write(text)
-        print(name, "lowered", flush=True)
+        if kind == "decode":
+            print(name, "lowered", flush=True)
     return 0
 
 

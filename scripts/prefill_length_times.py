@@ -8,10 +8,11 @@ Builds the cell's session as the benchmark does (``perfbench``'s family
 and configuration, weights from ``--seed``), then prints one JSON line
 a length: the session's own program, milliseconds a call (median of
 ``--repeats`` calls after a warm-up, each blocked on, a full prompt of
-that length), and the seconds a second ``jax.jit`` of the same contract
-takes to trace, to lower and to compile (with a warm compile cache that
-is the cache's read and the load onto the chip): the parts of
-``setup_s`` one more length adds. Refuses to run without a TPU: a time
+that length; and how many kernels and bytes the session holds turned,
+``tpudl.serve.weights``), and the seconds a second ``jax.jit`` of the
+same contract takes to trace, to lower and to compile (with a warm
+compile cache that is the cache's read and the load onto the chip): the
+parts of ``setup_s`` one more length adds. Refuses to run without a TPU: a time
 from a CPU is not a device time.
 """
 
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
     from perfbench.device import require_chips
     from perfbench.manifest import Manifest
     from tpudl.models.generate import named
+    from tpudl.obs import registry
     from tpudl.serve.api import left_pad
 
     device = jax.devices()[0]
@@ -58,11 +60,17 @@ def main(argv=None) -> int:
     system = family.build(cell["config"], require_chips(1), args.seed)
     built_s = time.perf_counter() - t
     engine = system.session.engine
+    # Whether the session holds kernels turned (tpudl.serve.weights).
+    gauges = registry().snapshot()["gauges"]
     rng = np.random.default_rng(args.seed)
     for rows in engine.prefill_lengths:
         ids, mask = left_pad(rng.integers(1, 1000, size=rows), rows)
         line = {"workload": args.workload, "rows": rows,
-                "device": device.device_kind, "build_s": built_s}
+                "device": device.device_kind, "build_s": built_s,
+                "weights_relaid_leaves": gauges.get(
+                    "serve_weights_relaid_leaves"),
+                "weights_relaid_bytes": gauges.get(
+                    "serve_weights_relaid_bytes")}
         jax.block_until_ready(engine.prefill_call(engine.params, ids, mask))
         times = []
         for _ in range(args.repeats):

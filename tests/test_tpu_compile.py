@@ -354,6 +354,78 @@ def test_decode_program_reads_the_pool_in_place_on_v5e(
     assert f"bf16[{POOL_SLOTS},{POOL_SEQ}," not in text
 
 
+# ISSUE 39: no serving program turns a weight over in every call. The
+# two-layer Mistral-width session is built by ``from_model`` over shapes
+# placed on the described chip, as a cell's is over arrays: its own tree
+# holds q_proj, k_proj and v_proj turned (tpudl.models.turned), and the
+# decode program and a 256-row prefill compiled from that tree have no
+# weight-shaped ``copy`` in their ENTRY; handed the weights as the
+# parent handed them (the declared tree) the same programs have three a
+# layer, which is how the test is known to see the thing. And the rule
+# that names those kernels is held to the compiler's own answer.
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_no_serving_program_turns_a_weight_over_on_v5e(
+    name, monkeypatch, no_compile_cache
+):
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+    from tpudl.models.llama import LlamaConfig, LlamaForCausalLM
+    from tpudl.models.turned import turned_nodes
+    from tpudl.serve import ServeSession
+    from tpudl.serve.weights import asked_layouts, weight_copies
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    on_chip = SingleDeviceSharding(device)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=32768, hidden_size=4096, num_layers=2, num_heads=32,
+        num_kv_heads=8, intermediate_size=14336, max_seq_len=POOL_SEQ,
+        rope_theta=1e6, dtype=bf16,
+    ))
+    declared = jax.tree.map(
+        lambda a: _s(a.shape, bf16, sharding=on_chip),
+        jax.eval_shape(
+            model.init, jax.random.key(0), _s((1, POOL_WINDOW), i32)
+        )["params"],
+    )
+    session = ServeSession.from_model(
+        model, declared, prompt_len=POOL_WINDOW, num_slots=POOL_SLOTS,
+        page_size=POOL_PAGE, num_pages=POOL_SEQ // POOL_PAGE + 1,
+    )
+    engine = session.engine
+    if name == "decode":
+        vec = _s((POOL_SLOTS,), i32, sharding=on_chip)
+        rest = (
+            _placed(engine.cache.cache, on_chip), vec, vec,
+            *_placed(engine.cache.dispatch_args(), on_chip),
+        )
+        program = engine.decode_call
+    else:
+        rest = (_s((1, POOL_WINDOW // 2), i32, sharding=on_chip),) * 2
+        program = engine.prefill_call
+    held = program.lower(engine.params, *rest).compile()
+    assert weight_copies(held.as_text(), declared) == []
+    given = program.lower(declared, *rest).compile()
+    assert sorted(weight_copies(given.as_text(), declared)) == (
+        4 * ["bf16[1024,4096] copy"] + 2 * ["bf16[4096,4096] copy"]
+    )
+    if name == "decode":
+        # Asked, the compiler wants exactly the kernels the session
+        # holds turned the other way round, and no other matrix.
+        turned = set(turned_nodes(engine.params))
+        asked = asked_layouts(
+            program.__wrapped__, declared, rest, donate_argnums=(1,)
+        )
+        assert {path for path, _, _ in asked} == turned
+        assert len(turned) == 6
+        assert {order for _, _, order in asked} == {(1, 0)}
+
+
 # The latent (MLA) pool of ISSUE 26 at its cell's size: sarvam-105b's
 # widths (perfbench/configs/sarvam-105b-l5-e32.json), one dense and one
 # expert layer deep. The decode and seat programs compile for the chip,
@@ -920,6 +992,8 @@ def test_chip_smoke_serve_phase_tiny(chip_smoke):
     # f32 on one backend: every request agrees token for token.
     assert line["requests"] == 8 and line["requests_equal_generate"] == 8
     assert line["recompiles_after_warmup"] == 0
+    # On a CPU a session holds its weights as given.
+    assert line["weights_relaid_leaves"] == line["weights_relaid_bytes"] == 0
 
 
 def test_chip_smoke_train_phase_tiny(chip_smoke):

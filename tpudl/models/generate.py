@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudl.models.hyper import HYPER_STAT_NAME
+from tpudl.models.turned import as_declared
 
 
 def named(fn, name: str):
@@ -43,9 +44,11 @@ def _apply_cached(model, variables, *args, **kwargs):
     *stats)``: one array ``[layers, ...]`` a statistic the layers sow,
     layers in order, at fixed places (tpudl.ops.moe.MOE_STAT_NAMES, then
     tpudl.models.hyper.HYPER_STAT_NAME), None where a later one is sown
-    and this one is not; a model that sows none adds nothing."""
+    and this one is not; a model that sows none adds nothing. The
+    parameters may hold kernels turned (tpudl.models.turned)."""
     out, mutated = model.apply(
-        variables, *args, mutable=["cache", MOE_STATS], **kwargs
+        {**variables, "params": as_declared(variables["params"])},
+        *args, mutable=["cache", MOE_STATS], **kwargs
     )
     from tpudl.ops.moe import MOE_STAT_NAMES
     stats = jax.tree_util.tree_leaves_with_path(mutated.get(MOE_STATS, {}))

@@ -63,6 +63,7 @@ from tpudl.serve.cache import (
     _is_valid_leaf,
 )
 from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue
+from tpudl.serve.weights import held
 
 
 @dataclasses.dataclass
@@ -320,6 +321,16 @@ class ServeSession:
         (and run once, dry) before this returns. A shorter window has
         one program and compiles nothing until the first request.
 
+        Parameters that lie whole on ONE TPU device are held as the
+        serving programs read them (tpudl.serve.weights): the session's
+        own tree keeps the head-split attention kernels turned
+        (``[out, in]``, tpudl.models.turned), once, so that no program
+        turns them over in every call; every other leaf is the
+        caller's array, and ``params`` itself is not touched. No knob:
+        a CPU run, host arrays, a mesh-committed or quantized tree and
+        an adapter session keep the tree as given (gauges
+        ``serve_weights_relaid_leaves`` / ``_bytes``).
+
         ``prefix_share=True`` (or ``TPUDL_SERVE_PREFIX_SHARE=1``) turns
         on the radix prefix cache: seating
         walks a tree of page-granular token-block hashes, maps every
@@ -511,6 +522,17 @@ class ServeSession:
                         f"its last {cfg.sliding_window} positions a "
                         f"slot, stepped one token at a time on one chip"
                     )
+        # The weights as the serving programs read them
+        # (tpudl.serve.weights): on one chip the session's own tree,
+        # ``serving``, holds the head-split attention kernels turned,
+        # and the contracts turn them back inside their programs. A
+        # quantized tree and the tree under the adapter programs are
+        # kept as given, as is whatever lies on no chip or on a mesh.
+        serving, leaves, nbytes = params, 0, 0
+        if adapters is None and getattr(cfg, "weight_dtype", None) is None:
+            serving, leaves, nbytes = held(params)
+        registry().gauge("serve_weights_relaid_leaves").set(leaves)
+        registry().gauge("serve_weights_relaid_bytes").set(nbytes)
         pf = prefill_fn(model)
         prefill_call = jax.jit(pf)
         ids = jax.ShapeDtypeStruct((num_slots, prompt_len), jnp.int32)
@@ -522,7 +544,7 @@ class ServeSession:
         # batch axis; the write index and a window layer's marker
         # (``[window]``) have none.
         row_ids = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
-        _, row, *_ = jax.eval_shape(prefill_call, params, row_ids, row_ids)
+        _, row, *_ = jax.eval_shape(prefill_call, serving, row_ids, row_ids)
         cache_template = jax.tree.map(
             lambda leaf: jax.ShapeDtypeStruct(
                 (num_slots, *leaf.shape[1:]), leaf.dtype
@@ -656,7 +678,7 @@ class ServeSession:
         if adapters is not None:
             prefill_call = jax.jit(lora_prefill_fn(model, impl=adapter_impl))
         session = cls(
-            prefill_call, decode, params, cache, prompt_len,
+            prefill_call, decode, serving, cache, prompt_len,
             chunk_prefill_call=chunk_prefill, speculator=speculator,
             verify_call=verify, **kwargs,
         )
