@@ -68,8 +68,8 @@ def decoder_programs(root: str, param_dtype=None):
         name = os.path.basename(file)[:-len(".json")]
         for key in ("num_hidden_layers", "num_layers"):
             # Two layers: every kind a configuration has but Laguna's,
-            # whose five ARE its kinds.
-            if key in cfg and "layer_types" not in cfg:
+            # whose five ARE its kinds (a list of one kind is no list).
+            if key in cfg and len(set(cfg.get("layer_types", ()))) < 2:
                 cfg[key] = max(2, cfg.get("first_k_dense_replace", 0) + 1)
         sess = cfg["session"]
         if cfg["family"] == "decoder_serve":

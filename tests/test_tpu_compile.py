@@ -887,6 +887,119 @@ def test_hyper_mla_moe_program_compiles_for_v5e(
     assert "bf16[64,64,1024]" in text
 
 
+# The looped decoder of ISSUE 40 at its cell's size: Ouro-2.6B's widths
+# and FULL depth (perfbench/configs/ouro-2.6b.json: 48 sandwich-normed
+# layers run 4 times, 16 query heads over 16 KV heads of 128, 16 slots
+# of 256 positions). Unrolled, the decode program has 192 layer bodies:
+# it takes the k/v kernel at ONE query head a KV head in every one of
+# them (192 calls), takes all 384 pools donated, turns no weight over,
+# and fits the chip beside the 5.34 GB of weights; the batch-1 prefill
+# at the window's 128 rows turns no weight over either. (Two of the
+# 384 pools are carried through VMEM and back by XLA's own choice,
+# PERF.md section 7: that is counted here, so that a change of it
+# shows.)
+
+
+def _loop_decoder_session(on_chip, layers=None):
+    import json
+
+    from perfbench.families import loop_decoder_serve as family
+    from tpudl.models.llama import LlamaForCausalLM
+    from tpudl.serve import ServeSession
+
+    with open(REPO / "perfbench/configs/ouro-2.6b.json") as f:
+        cfg = json.load(f)
+    cfg["num_hidden_layers"] = layers or cfg["num_hidden_layers"]
+    sess = cfg["session"]
+    model = LlamaForCausalLM(
+        family.model_config(cfg, sess["max_seq_len"], bf16)
+    )
+    declared = jax.tree.map(
+        # The exit gate and its bias are float32, as declared.
+        lambda a: _s(a.shape, a.dtype if a.shape[-1] == 1 else bf16,
+                     sharding=on_chip),
+        jax.eval_shape(
+            model.init, jax.random.key(0), _s((1, 8), i32)
+        )["params"],
+    )
+    session = ServeSession.from_model(
+        model, declared, sess["prompt_window"], num_slots=sess["num_slots"],
+        page_size=sess["page_size"],
+    )
+    return cfg, declared, session
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_loop_decoder_program_compiles_for_v5e(
+    name, monkeypatch, no_compile_cache
+):
+    import math
+    import re
+
+    import tpudl.ops.attention
+    import tpudl.ops.paged_attention
+    from tpudl.serve.weights import weight_copies
+
+    device = _v5e_device()
+    if device is None:
+        pytest.skip("this installation cannot describe a v5e topology")
+    for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
+        monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    on_chip = SingleDeviceSharding(device)
+    if name == "prefill":
+        # A quarter of the depth (12 layers x 4 passes: 48 bodies, a
+        # quarter of the compile): what it shows does not grow with
+        # depth but the weights, which are counted at full depth below.
+        cfg, declared, session = _loop_decoder_session(on_chip, layers=12)
+        sess, engine = cfg["session"], session.engine
+        ids = _s((1, sess["prompt_window"]), i32, sharding=on_chip)
+        compiled = engine.prefill_call.lower(engine.params, ids, ids).compile()
+        memory = compiled.memory_analysis()
+        # The program's temporaries (the row cache of 48 (pass, layer)
+        # pairs, 128 rows of activations) are under half a GB; at full
+        # depth the sandbox compile reads 1.63 GB (PERF.md section 5).
+        assert memory.temp_size_in_bytes < 0.5e9
+        assert weight_copies(compiled.as_text(), declared) == []
+        return
+    cfg, declared, session = _loop_decoder_session(on_chip)
+    sess, deployment = cfg["session"], cfg["deployment"]
+    engine, cache = session.engine, session.engine.cache
+    weights = sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(declared)
+    )
+    assert weights == deployment["weight_bytes"] == 5_335_953_412
+    passes, layers = cfg["total_ut_steps"], cfg["num_hidden_layers"]
+    leaves = jax.tree.leaves(cache.cache)
+    assert len(leaves) == 2 * passes * layers == 384
+    pool = (sess["num_slots"] * sess["max_seq_len"] // 16 + 1, 16, 16, 128)
+    assert all(leaf.shape == pool for leaf in leaves)
+    pool_bytes = 384 * math.prod(pool) * 2
+    assert pool_bytes == deployment["cache_bytes"] == 6_467_616_768
+    vec = _s((sess["num_slots"],), i32, sharding=on_chip)
+    compiled = engine.decode_call.lower(
+        engine.params, _placed(cache.cache, on_chip), vec, vec,
+        *_placed(cache.dispatch_args(), on_chip),
+    ).compile()
+    took = engine.decode_call.__wrapped__.attention_in_place
+    assert took == (True,) * (passes * layers) and len(took) == 192
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 192
+    assert "kv_gather" not in text
+    assert weight_copies(text, declared) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5e9
+    # XLA carries these through VMEM and back (a pool under 128 MiB is
+    # exposed to that choice, ROADMAP A9): 2 of 384, in and out.
+    dims = ",".join(map(str, pool))
+    carried = re.findall(rf"copy-start\(%cache__\w*pages_\w+\)", text)
+    moved = re.findall(rf"= \(bf16\[{dims}\][^ ]* copy-start\(", text)
+    assert len(moved) <= 4 and len(carried) <= 2
+    # No plain copy of a pool anywhere.
+    assert not re.findall(rf"= bf16\[{dims}\][^ ]* copy\(", text)
+
+
 # A configuration that sets none of ISSUE 33's keys (the block's kind,
 # the low-rank query and its scales, the router's scoring, identity
 # experts) builds the programs it built before: the lowered text of a

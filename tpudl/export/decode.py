@@ -139,6 +139,13 @@ def export_serving_decoder(
             "contracts, and such a model returns its maps' statistic "
             "beside them"
         )
+    if getattr(getattr(model, "cfg", None), "loop_passes", 1) > 1:
+        raise ValueError(
+            "the exported decode artifact is not wired to a stack run "
+            "several times a token (loop_passes): an artifact session "
+            "takes the two-value prefill and decode contracts, and such "
+            "a model returns its exit distribution beside them"
+        )
     token = jnp.zeros((num_slots,), jnp.int32)
     position = jnp.full((num_slots,), prompt_len, jnp.int32)
     prefill_blob = export_stablehlo(

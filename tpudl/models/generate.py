@@ -40,12 +40,12 @@ MOE_STATS = "moe_stats"
 
 
 def _apply_cached(model, variables, *args, **kwargs):
-    """``model.apply`` with the cache mutable: ``(output, cache,
-    *stats)``: one array ``[layers, ...]`` a statistic the layers sow,
-    layers in order, at fixed places (tpudl.ops.moe.MOE_STAT_NAMES, then
-    tpudl.models.hyper.HYPER_STAT_NAME), None where a later one is sown
-    and this one is not; a model that sows none adds nothing. The
-    parameters may hold kernels turned (tpudl.models.turned)."""
+    """``model.apply`` with the cache mutable: ``(output, cache, *stats)``:
+    one array ``[layers, ...]`` a statistic the layers sow, at fixed places
+    (MOE_STAT_NAMES, HYPER_STAT_NAME, then a looped stack's LOOP_STAT_NAME,
+    ``[1, passes + 1]``), None where a later one is sown and this one is
+    not; none sown adds nothing. Kernels may be held turned (models.turned)."""
+    from tpudl.models.llama import LOOP_STAT_NAME
     out, mutated = model.apply(
         {**variables, "params": as_declared(variables["params"])},
         *args, mutable=["cache", MOE_STATS], **kwargs
@@ -53,14 +53,14 @@ def _apply_cached(model, variables, *args, **kwargs):
     from tpudl.ops.moe import MOE_STAT_NAMES
     stats = jax.tree_util.tree_leaves_with_path(mutated.get(MOE_STATS, {}))
 
-    def layer(path_leaf) -> int:
-        path = jax.tree_util.keystr(path_leaf[0])
-        return int(re.search(r"layer_(\d+)", path).group(1))
+    def layer(path_leaf) -> int:  # -1: the stack's own statistic
+        found = re.search(r"layer_(\d+)", jax.tree_util.keystr(path_leaf[0]))
+        return int(found.group(1)) if found else -1
 
     stacks = [[
         leaf for path, leaf in sorted(stats, key=layer)
         if path[-2].key == name
-    ] for name in (*MOE_STAT_NAMES, HYPER_STAT_NAME)]
+    ] for name in (*MOE_STAT_NAMES, HYPER_STAT_NAME, LOOP_STAT_NAME)]
     while stacks and not stacks[-1]:
         stacks.pop()
     stacks = [jnp.stack(leaves) if leaves else None for leaves in stacks]

@@ -172,17 +172,17 @@ class LlamaConfig:
                     "attention keeps every layer alike"
                 )
         _check_block(self)
-    # Fused-epilogue kernels (tpudl.ops.norms / mlp_fused): False =
-    # composite; True = Pallas on TPU; "force" = Pallas everywhere.
+    # tpudl.ops.norms / mlp_fused: False = composite; True = Pallas on
+    # TPU; "force" = Pallas everywhere.
     fused_ops: Any = False
-    # Low-precision weight tier (tpudl.quant): None = plain nn.Dense;
-    # "int8" / "fp8_e4m3" = the projections become QuantDense and serve
-    # the quantize_tree output. ServeSession.from_model(weight_dtype=).
+    # tpudl.quant: "int8" / "fp8_e4m3" = the projections become
+    # QuantDense over the quantize_tree output (from_model(weight_dtype=)).
     weight_dtype: Optional[str] = None
-    # fp8 TRAINING tier (tpudl.ops.fp8_dot): LLAMA_QUANT_PATTERNS' sites
-    # go through Fp8Dense; a string pins the fp8_dot impl. Exclusive
-    # with weight_dtype, composes with lora_rank.
-    fp8_train: Any = False
+    fp8_train: Any = False  # tpudl.ops.fp8_dot; a string pins its impl
+    # ``loop_passes`` T > 1: the stack runs T times over the SAME weights
+    # (a cache a (pass, layer); final norm and exit gate after every pass).
+    loop_passes: int = 1
+    loop_exit_threshold: float = 1.0  # 1.0: every token runs every pass
     # MoE (tpudl.ops.moe): >0 swaps every block's dense MLP for MoEMlp.
     moe_experts: int = 0
     moe_k: int = 2
@@ -240,14 +240,14 @@ class LlamaConfig:
     router_scoring: str = "sigmoid"
     router_renormalize: bool = True
     zero_experts: int = 0
-    # ``hyper_streams`` n > 0: the residual is n vectors a token, mixed
-    # around every sublayer by manifold-constrained hyper-connections
-    # (HyperBlock, tpudl.models.hyper); the model's Sinkhorn iterations,
-    # their epsilon and the clamp before the exponential beside it.
+    # ``hyper_streams`` n > 0: n residual vectors a token, mixed around
+    # every sublayer by hyper-connections (HyperBlock). ``sandwich_norm``:
+    # a norm on each sublayer's OUTPUT too, before the add (SandwichBlock).
     hyper_streams: int = 0
     hyper_sinkhorn_iters: int = 20
     hyper_eps: float = 1e-6
     hyper_clamp: float = 30.0
+    sandwich_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -521,7 +521,7 @@ class LlamaAttention(nn.Module):
     @nn.compact
     def __call__(
         self, hidden, positions, kv_mask=None, decode: bool = False,
-        paged=None, adapters=None,
+        paged=None, adapters=None, loop_pass: int = 0,
     ):
         cfg = self.cfg
         # Layers that differ say which kind they are in a trace.
@@ -531,20 +531,20 @@ class LlamaAttention(nn.Module):
         )
         with scope:
             return self._attend(
-                hidden, positions, kv_mask, decode, paged, adapters
+                hidden, positions, kv_mask, decode, paged, adapters, loop_pass
             )
 
-    def _attend(self, hidden, positions, kv_mask, decode, paged, adapters):
+    def _attend(self, hidden, positions, kv_mask, decode, paged, adapters, t):
         from tpudl.models.lora import adapter_delta
 
         cfg = self.cfg
         spec = cfg.layer_spec(self.layer)
         B, S, _ = hidden.shape
         hd, H, window = cfg.head_dim, spec.num_heads, spec.window
-        # Multi-tenant adapters (tpudl.models.lora.AdapterView): each
-        # slot's per-tenant LoRA delta rides AFTER the shared base
-        # projection — one segmented-kernel dispatch per site, base
-        # weights (full-precision or quantized) resident exactly once.
+        # Pass ``t`` of a looped stack keeps cache leaves of its own.
+        of_pass, last_pass = _pass_leaves(cfg, t)
+        # Multi-tenant adapters (tpudl.models.lora.AdapterView): a slot's
+        # LoRA delta rides AFTER the shared base projection, one dispatch.
         q = _proj(cfg, H * hd, "q_proj")(hidden)
         q = q + adapter_delta(adapters, "q_proj", hidden)
         k = _proj(cfg, cfg.num_kv_heads * hd, "k_proj")(hidden)
@@ -607,12 +607,12 @@ class LlamaAttention(nn.Module):
                         "window over the ring"
                     )
                 paged = paged.ring_view(window)
-            pk = self.variable("cache", "pages_k", _paged_cache_missing)
-            pv = self.variable("cache", "pages_v", _paged_cache_missing)
+            pk = self.variable("cache", of_pass("pages_k"), _paged_cache_missing)
+            pv = self.variable("cache", of_pass("pages_v"), _paged_cache_missing)
             sk = sv = None
             if paged.quantized:
-                sk = self.variable("cache", "scale_k", _paged_cache_missing)
-                sv = self.variable("cache", "scale_v", _paged_cache_missing)
+                sk = self.variable("cache", of_pass("scale_k"), _paged_cache_missing)
+                sv = self.variable("cache", of_pass("scale_v"), _paged_cache_missing)
             new_k, new_sk = paged_write(
                 pk.value, sk.value if sk is not None else None, k, paged
             )
@@ -635,13 +635,13 @@ class LlamaAttention(nn.Module):
             # autoregressive serving path (the reference repo's entire
             # substance is inference benchmarking; this is its decoder
             # analog). Shapes stay static so the step jits once.
-            fresh = not self.has_variable("cache", "k")
+            fresh = not self.has_variable("cache", of_pass("k"))
             ck = self.variable(
-                "cache", "k",
+                "cache", of_pass("k"),
                 jnp.zeros, (B, cfg.max_seq_len, cfg.num_kv_heads, hd), k.dtype,
             )
             cv = self.variable(
-                "cache", "v",
+                "cache", of_pass("v"),
                 jnp.zeros, (B, cfg.max_seq_len, cfg.num_kv_heads, hd), v.dtype,
             )
             # Per-slot validity: padded prompt slots hold garbage k/v and
@@ -678,7 +678,7 @@ class LlamaAttention(nn.Module):
             cvalid.value = jax.lax.dynamic_update_slice(
                 cvalid.value, chunk_valid, (0, start)
             )
-            idx.value = start + S
+            idx.value = start + (S if last_pass else 0)
             if fresh and (
                 4 * B * H * S * cfg.max_seq_len > PREFILL_SCORE_BYTES
             ):
@@ -1075,9 +1075,9 @@ class LlamaModel(nn.Module):
             )(input_ids).astype(cfg.dtype)
         x = _enter_stream(cfg, constrain(x, ("dp", "fsdp"), "sp", "tp"))
         block = _block_of(cfg)
-        if cfg.remat and not decode:
-            # adapters never reach the remat path: multi-tenant views
-            # are decode-only (serving), and decode skips remat.
+        if cfg.loop_passes > 1:
+            return _loop(self, block, x, positions, kv_mask, decode, paged, adapters)
+        if cfg.remat and not decode:  # (adapter views are decode-only)
             block = nn.remat(block, static_argnums=(4, 5))
         for i in range(cfg.num_layers):
             x = block(cfg, cfg.mlp_kind(i), i, name=f"layer_{i}")(
@@ -1154,14 +1154,14 @@ class LlamaForSequenceClassification(nn.Module):
 def _check_block(cfg: LlamaConfig) -> None:
     """``__post_init__``: the block, the low-rank query, the router."""
     _check_stream(cfg)
+    _check_loop(cfg)
     if cfg.block not in _BLOCKS:
         raise ValueError(
             f"block must be one of {sorted(_BLOCKS)}, got {cfg.block!r}"
         )
     if cfg.router_scoring not in ("sigmoid", "softmax"):
         raise ValueError(
-            f"router_scoring must be 'sigmoid' or 'softmax', got "
-            f"{cfg.router_scoring!r}"
+            f"router_scoring must be 'sigmoid' or 'softmax', got {cfg.router_scoring!r}"
         )
     if cfg.q_lora_rank < 0 or cfg.zero_experts < 0:
         raise ValueError(
@@ -1435,8 +1435,11 @@ def _check_stream(cfg: LlamaConfig) -> None:
 
 
 def _block_of(cfg: LlamaConfig):
-    """The module a layer of ``cfg`` is: by ``block``, and for a stream
-    of several vectors a token ``HyperBlock``."""
+    """The module a layer of ``cfg`` is: by ``block``, for a stream of
+    several vectors a token ``HyperBlock``, with a norm on each
+    sublayer's output ``SandwichBlock``."""
+    if cfg.sandwich_norm:
+        return SandwichBlock
     return HyperBlock if cfg.hyper_streams else _BLOCKS[cfg.block]
 
 
@@ -1454,6 +1457,173 @@ def _leave_stream(cfg: LlamaConfig, x):
     if not cfg.hyper_streams:
         return x
     return jnp.sum(x, axis=2, dtype=jnp.float32).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A stack run several times over the same weights (a looped language
+# model, arXiv:2510.25741), and the sandwich-normed layer it is made of.
+# ---------------------------------------------------------------------------
+
+#: The statistic a looped stack sows into the serving statistics'
+#: collection (tpudl.models.generate), float32 [T + 1] over the call's
+#: real tokens: the exit distribution's mass at each pass, summed, and
+#: how many tokens they were.
+LOOP_STAT_NAME = "loop_exit"
+
+
+def _pass_leaves(cfg: LlamaConfig, t: int):
+    """``(name -> the cache leaf pass t keeps under it, whether t is the
+    last pass)``. One pass (every model but a looped one) keeps the
+    names as they are; pass ``t`` of several keeps ``<name>_pass<t>``,
+    so that a layer declares T row leaves of each kind beside ONE
+    ``valid`` and ONE ``index``, which only the last pass advances:
+    the page manager pools whatever leaves a layer declares."""
+    if cfg.loop_passes == 1:
+        return (lambda name: name), True
+    return (lambda name: f"{name}_pass{t}"), t == cfg.loop_passes - 1
+
+
+def _check_loop(cfg: LlamaConfig) -> None:
+    """``_check_block``'s checks of ``loop_passes`` and
+    ``sandwich_norm``: what they are, and what they are not wired to."""
+    if cfg.loop_passes < 1:
+        raise ValueError(
+            f"loop_passes must be >= 1 (1: every layer runs once a "
+            f"token), got {cfg.loop_passes}"
+        )
+    if cfg.loop_exit_threshold != 1.0:
+        raise ValueError(
+            f"loop_exit_threshold {cfg.loop_exit_threshold} < 1 lets a "
+            f"token leave the loop before the last pass: the later "
+            f"passes' cache rows of a token that left must still be "
+            f"filled for the tokens after it, and a step's rows would "
+            f"run different numbers of passes. Only 1.0 (every pass, the "
+            f"last one's logits) is served (ROADMAP B-mech)"
+        )
+    if cfg.loop_passes > 1 and not cfg.sandwich_norm:
+        raise ValueError(
+            "loop_passes > 1 needs sandwich_norm: the block that takes "
+            "the pass it runs in (a cache a (pass, layer)) is "
+            "SandwichBlock, and a pass that reads the last one's NORMED "
+            "state has only been defined with the norms on the "
+            "sublayers' outputs"
+        )
+    if cfg.sandwich_norm and (
+        cfg.attention != "gqa" or cfg.num_experts or cfg.hyper_streams
+        or cfg.block != "llama" or cfg.layer_types is not None
+        or cfg.lora_rank or cfg.moe_experts or cfg.fp8_train or cfg.remat
+    ):
+        raise ValueError(
+            "sandwich_norm is grouped-query attention and a dense SwiGLU "
+            "with a norm before and after each: it is not wired to "
+            "attention='mla', routed experts, hyper_streams, "
+            "block='shortcut', layer_types, lora_rank, moe_experts, "
+            "fp8_train or remat"
+        )
+
+
+class SandwichBlock(nn.Module):
+    """``LlamaBlock``'s two sublayers with a norm on each one's OUTPUT
+    too, before the residual add (four norms a layer):
+
+        x = x + norm_2(Attn(norm_1(x)));  x = x + norm_4(MLP(norm_3(x)))
+
+    ``input_norm``, ``input_norm_2``, ``post_attention_norm``,
+    ``post_attention_norm_2`` in that order; the dense SwiGLU under
+    ``mlp``. Nothing is folded into a norm: what is added is a norm's
+    OUTPUT. Takes the pass it runs in (``loop_pass``) for the
+    attention's cache leaves. A block of its own, so that ``LlamaBlock``
+    keeps its program, and its lines, as they were."""
+
+    cfg: LlamaConfig
+    mlp: str = "dense"
+    layer: int = 0
+
+    @nn.compact
+    def __call__(
+        self, hidden, positions, kv_mask=None, decode: bool = False,
+        paged=None, adapters=None, loop_pass: int = 0,
+    ):
+        from tpudl.ops.norms import fused_ops_impl
+
+        cfg = self.cfg
+        if adapters is not None:
+            raise ValueError(
+                "per-tenant adapters are not wired to a sandwich-normed "
+                "layer (sandwich_norm)"
+            )
+        impl = fused_ops_impl(cfg.fused_ops)
+
+        def norm(name, x):
+            return RMSNorm(cfg.rms_norm_eps, impl, name=name)(x)
+
+        attn = LlamaAttention(cfg, self.layer, name="attention")(
+            norm("input_norm", hidden), positions, kv_mask, decode, paged,
+            None, loop_pass,
+        )
+        hidden = hidden + norm("input_norm_2", attn)
+        with jax.named_scope("mlp"):
+            down = _DenseFFN(cfg, name="mlp")(
+                norm("post_attention_norm", hidden)
+            )
+        hidden = hidden + norm("post_attention_norm_2", down)
+        return constrain(hidden, ("dp", "fsdp"), "sp", "tp")
+
+
+def _loop(model, block, x, positions, kv_mask, decode, paged, adapters):
+    """``LlamaModel``'s stack for ``loop_passes`` T > 1, called inside
+    its ``__call__``: the SAME ``num_layers`` modules (one set of
+    weights) run T times, pass ``t`` on cache leaves of its own; the
+    final norm runs after EVERY pass and the next pass reads the normed
+    state; after each, the exit gate ``lambda_t = sigmoid(w . h_{t+1} +
+    b)`` (float32). The exit distribution
+
+        p_t = lambda_t prod_{s<t} (1 - lambda_s)   (t < T - 1)
+        p_{T-1} = prod_{s<T-1} (1 - lambda_s)
+
+    is sown summed over the real tokens (``LOOP_STAT_NAME``); what is
+    returned is the LAST pass's normed state (an exit threshold of 1).
+    Scopes: ``loop_pass_<t>`` around each pass of the stack and its
+    norm, ``exit_gate``."""
+    from tpudl.ops.norms import fused_ops_impl
+
+    cfg = model.cfg
+    if adapters is not None:
+        raise ValueError(
+            "per-tenant adapters are not wired to a looped stack "
+            "(loop_passes > 1)"
+        )
+    layers = [
+        block(cfg, cfg.mlp_kind(i), i, name=f"layer_{i}")
+        for i in range(cfg.num_layers)
+    ]
+    final_norm = RMSNorm(
+        cfg.rms_norm_eps, fused_ops_impl(cfg.fused_ops), name="final_norm"
+    )
+    gate = nn.Dense(
+        1, dtype=jnp.float32, param_dtype=jnp.float32,
+        kernel_init=nn.initializers.normal(0.02), name="early_exit_gate",
+    )
+    real = real_tokens(x, kv_mask, paged).astype(jnp.float32)
+    stay = jnp.ones(x.shape[:2], jnp.float32)
+    pdf = []
+    for t in range(cfg.loop_passes):
+        with jax.named_scope(f"loop_pass_{t}"):
+            for layer in layers:
+                x = layer(x, positions, kv_mask, decode, paged, None, t)
+            x = final_norm(x)
+        with jax.named_scope("exit_gate"):
+            if t == cfg.loop_passes - 1:
+                pdf.append(stay)
+            else:
+                leave = jax.nn.sigmoid(gate(x.astype(jnp.float32))[..., 0])
+                pdf.append(leave * stay)
+                stay = stay * (1.0 - leave)
+    with jax.named_scope("exit_gate"):
+        model.sow("moe_stats", LOOP_STAT_NAME, jnp.stack(
+            [jnp.sum(p * real) for p in pdf] + [jnp.sum(real)]
+        ))
+    return x
 
 
 def _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh):

@@ -71,8 +71,11 @@ LLAMA_QUANT_PATTERNS = (
 #: hyper-connection's maps (``hyper_attention/phi``, ``hyper_mlp/phi``;
 #: tpudl.models.hyper) decide how every value of the residual stream is
 #: mixed, in float32, as a router's matrix decides the choice of experts
-#: (``router/kernel``, which no pattern above names).
-LLAMA_KEEP_PATTERNS = (r"hyper_\w+/phi$",)
+#: (``router/kernel``, which no pattern above names); a looped stack's
+#: exit gate (``early_exit_gate``, float32: its sigmoid is the exit
+#: distribution). The sandwich form's four norms a layer are ``scale``
+#: leaves, which no pattern names.
+LLAMA_KEEP_PATTERNS = (r"hyper_\w+/phi$", r"early_exit_gate/")
 
 #: Which BERT leaves quantize: encoder attention + MLP projections.
 #: The pooler/classifier head and embeddings keep full precision.

@@ -468,6 +468,30 @@ class ServeSession:
                         f"{what} is not wired to a residual stream of "
                         f"{streams} vectors a token (hyper_streams): {why}"
                     )
+        passes = getattr(cfg, "loop_passes", 1)
+        registry().gauge("serve_loop_passes").set(passes)
+        if passes > 1:
+            # A stack run several times over the same weights is served
+            # from the paged pool, a k/v pool pair a (pass, layer) under
+            # the one table, with its int8 store, prefix sharing and
+            # migration. What was written for one pass a token says so.
+            for what, asked, why in (
+                ("per-tenant adapters", adapters is not None,
+                 "the adapter pool addresses the projections of "
+                 "LlamaBlock, and SandwichBlock takes no adapter view"),
+                ("spec_k", spec_k,
+                 "the verify step does not report its window's exit "
+                 "distribution (loop_exit_pdf), and a draft would have "
+                 "to run every pass to propose a token"),
+                ("a mesh-committed session", mesh is not None,
+                 "the exit gate has no sharding rule, and a looped "
+                 "stack has run on no mesh"),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{what} is not wired to a stack run {passes} "
+                        f"times a token (loop_passes): {why}"
+                    )
         if getattr(cfg, "block", "llama") == "shortcut":
             # The shortcut double layer (two latent attentions and two
             # dense FFNs around one expert branch) is served from the

@@ -164,7 +164,7 @@ def first_token(logits, request, also=None):
     return int(sel[0]), also
 
 
-def record_expert_load(counts=None, chose=None, hyper=None) -> dict:
+def record_expert_load(counts=None, chose=None, hyper=None, loop=None) -> dict:
     """The statistics a model's layers sowed in one prefill or decode
     step, on the host, in the places the serving contracts return them
     (tpudl.models.generate): counted into the registry and returned as
@@ -196,8 +196,24 @@ def record_expert_load(counts=None, chose=None, hyper=None) -> dict:
     ``H_res`` puts off its diagonal (0: streams kept apart; ``1 -
     1/n``: fully mixed), also observed into the histogram
     ``serve_hyper_res_offdiag``; ``hyper_res_sum_error``: the largest
-    ``|column sum - 1|`` the Sinkhorn iterations left."""
+    ``|column sum - 1|`` the Sinkhorn iterations left.
+
+    ``loop`` (a stack run several times over the same weights): float
+    [1, passes + 1], the exit distribution's mass at each pass summed
+    over the real tokens, and how many they were
+    (tpudl.models.llama.LOOP_STAT_NAME). ``loop_passes``: the passes
+    every token ran; ``loop_exit_pdf``: the mean exit distribution over
+    the real tokens, a number a pass; its last entry (the mass that no
+    earlier pass's gate let go) is observed into the histogram
+    ``serve_loop_exit_last_pass_mass``."""
     attrs = {}
+    if loop is not None:
+        loop = np.asarray(loop, np.float64)[0]
+        pdf = (loop[:-1] / loop[-1] if loop[-1] else loop[:-1]).tolist()
+        registry().histogram("serve_loop_exit_last_pass_mass").observe(
+            pdf[-1]
+        )
+        attrs.update(loop_passes=len(pdf), loop_exit_pdf=pdf)
     if hyper is not None:
         hyper = np.asarray(hyper, np.float64)
         tokens = hyper[:, 1].sum()
@@ -1018,6 +1034,9 @@ class Engine:
         reg.gauge("serve_kv_pool_folded_layers").set(
             sum(fold > 1 for fold in self.cache.folds)
         )
+        # Pool leaves under the one manager: a k and a v a layer, and
+        # as many again for every further pass of a looped stack.
+        reg.gauge("serve_kv_pools").set(len(self.cache.folds))
 
     def _paged_attrs(self, pages_live: int) -> dict:
         """What a ``decode_step`` span says of the paged cache: the
