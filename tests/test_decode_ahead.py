@@ -9,7 +9,9 @@ rule engages from the engine's own slots and from nothing else, a slot
 that ends while a step is ahead is handled (by length it rides as an
 idle row, by ``eos_id`` its wasted row reaches nobody), whoever touches
 a slot from outside ``step`` lands the step in flight first, and the
-spans stay the ones a step always had, one ``decode_step`` a landing.
+spans stay the ones a step always had, one ``decode_step`` a landing
+(opened before the call's admission where the step it lands was in
+flight: the seats queue behind that step, tests/test_seat_async.py).
 """
 
 import gc
@@ -264,13 +266,15 @@ def test_with_a_free_slot_nothing_runs_ahead(model_and_params, tmp_path):
     assert session.engine._in_flight is None
 
 
-def test_a_full_engine_runs_ahead_but_for_the_first_step_after_a_seat(
+def test_a_full_engine_runs_ahead_over_the_seats_it_makes(
     model_and_params, tmp_path
 ):
     """Driven a call at a time: the step a call lands was in flight
     (``ahead`` 1) or is the call's own (0); a call that finds a step in
-    flight seats nobody behind it; and a call leaves a step in flight
-    iff every slot was seated at its dispatches."""
+    flight seats behind it, and the step it dispatches next takes the
+    seat's first token from the device like its neighbours' tokens; a
+    call leaves a step in flight iff every slot was seated at its
+    dispatches."""
     model, params = model_and_params
     rec = obs.enable(str(tmp_path))
     session = _session(model, params)
@@ -285,6 +289,10 @@ def test_a_full_engine_runs_ahead_but_for_the_first_step_after_a_seat(
             break
         calls.append((was_in_flight, engine.num_prefills - prefills,
                       engine._in_flight is not None))
+        # Between two calls the device holds one decode step at most,
+        # and no first token.
+        assert len(engine._unread) <= 1
+        assert all(s is None or s.first is None for s in engine._slots)
     records = rec.records
     obs.disable()
     steps = _spans(records, "decode_step")
@@ -293,11 +301,12 @@ def test_a_full_engine_runs_ahead_but_for_the_first_step_after_a_seat(
                     if any(d["parent"] == s["id"] for d in steps)]
     assert len(steps) == len(emits) == len(engine_steps) == len(calls)
     seen = {0: 0, 1: 0}
+    behind = 0
     for (was_in_flight, seated, left_in_flight), step, emit, outer in zip(
         calls, steps, emits, engine_steps
     ):
         assert step["ahead"] == int(was_in_flight)
-        assert not (was_in_flight and seated)
+        behind += seated if was_in_flight else 0
         seen[step["ahead"]] += 1
         # Slots seated when the call dispatched: those still seated at
         # its end and those its landing finished.
@@ -309,9 +318,18 @@ def test_a_full_engine_runs_ahead_but_for_the_first_step_after_a_seat(
         )
         assert left_in_flight == (full and rows_left)
     assert seen[1] == _ahead() > 0
-    # Six requests on two slots: a seat follows each of the first four
-    # finishes, and the step dispatched after it takes host tokens.
-    assert seen[0] >= 1 + 4
+    # Six requests on two slots: four seats follow the first two, each
+    # behind the step that was ahead when its slot came free, and the
+    # step dispatched after it is ahead too.
+    waits = _spans(records, "prefill")
+    assert behind == sum(w["behind"] for w in waits) == 4
+    assert obs_counters.registry().counter(
+        "serve_prefills_behind_step"
+    ).value == behind
+    # Only the first step, and those after the queue ran dry and left a
+    # slot free, took their tokens from the host.
+    flags = [s["ahead"] for s in steps]
+    assert flags[0] == 0 and flags[1:] == sorted(flags[1:], reverse=True)
     assert obs_counters.registry().counter("serve_decode_steps").value == (
         len(steps)
     )
@@ -480,22 +498,43 @@ def test_on_the_fake_clock_a_landing_is_one_decode_step_with_its_family(
         if "decode_step" not in names:
             continue
         landed += 1
-        assert names == ["admit", "decode_prepare", "decode_step", "emit"]
-        _, prepare, decode, emit = kids[outer["id"]]
-        assert prepare["ts"] + prepare["dur"] == pytest.approx(decode["ts"])
+        decode = kids[outer["id"]][names.index("decode_step")]
+        emit = kids[outer["id"]][names.index("emit")]
+        inner = kids[decode["id"]]
+        inner_names = [k["name"] for k in inner]
+        if decode["ahead"]:
+            # The step was in flight when the call began: its span
+            # opens before the admission, whose dispatches queue
+            # behind it; the first tokens of those seats are waited
+            # for once the step has landed and its emit is done.
+            assert names[:2] == ["decode_step", "emit"]
+            assert set(names[2:]) <= {"prefill"}
+            assert inner_names == ["admit", "decode_prepare",
+                                   "decode.dispatch", "decode.readback"]
+            prepare = inner[1]
+            assert own[outer["id"]] == pytest.approx(2.0)
+            assert own[decode["id"]] == pytest.approx(3.0)
+        else:
+            # Nothing was in flight: the first tokens of the call's
+            # seats are waited for inside the step it dispatches.
+            assert names == ["admit", "decode_prepare", "decode_step", "emit"]
+            assert inner_names[0] == "decode.dispatch"
+            assert inner_names[-1] == "decode.readback"
+            assert set(inner_names[1:-1]) <= {"prefill"}
+            prepare = kids[outer["id"]][1]
+            assert prepare["ts"] + prepare["dur"] == pytest.approx(
+                decode["ts"]
+            )
+            # engine_step keeps its three boundary ticks, decode_step
+            # its last one: no phase of a call goes unnamed.
+            assert own[outer["id"]] == pytest.approx(3.0)
+            assert own[decode["id"]] == pytest.approx(1.0)
         assert decode["ts"] + decode["dur"] == pytest.approx(emit["ts"])
-        # engine_step keeps its three boundary ticks, decode_step its
-        # last one: no phase of a call that runs ahead goes unnamed.
-        assert own[outer["id"]] == pytest.approx(3.0)
-        assert own[decode["id"]] == pytest.approx(1.0)
-        dispatch, readback = kids[decode["id"]]
-        assert (dispatch["name"], readback["name"]) == (
-            "decode.dispatch", "decode.readback"
-        )
-        assert dispatch["ts"] == decode["ts"]
-        assert dispatch["ts"] + dispatch["dur"] == pytest.approx(
-            readback["ts"]
-        )
+        at = inner_names.index("decode.dispatch")
+        dispatch, readback = inner[at], inner[-1]
+        assert prepare["ts"] + prepare["dur"] == pytest.approx(dispatch["ts"])
+        for a, b in zip(inner[at:], inner[at + 1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
         # decode.dispatch holds the call's dispatches, one
         # decode.address each: the landed step's own if none was in
         # flight, and the step ahead's.

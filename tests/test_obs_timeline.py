@@ -394,12 +394,13 @@ def test_report_lists_the_serve_phases_as_their_tree():
     records = _nested_records(parts=True)
     phases = obs_report.build_report(records)["serve_phases"]
     assert list(phases) == [
-        "engine_step", "admit", "prefill", "prefill.dispatch",
-        "prefill.readback", "seat", "decode_prepare", "decode_step",
-        "decode.dispatch", "decode.address", "decode.readback", "emit",
+        "engine_step", "admit", "prefill.dispatch", "seat",
+        "decode_prepare", "decode_step", "decode.dispatch",
+        "decode.address", "decode.readback", "emit", "prefill",
+        "prefill.readback",
     ]
     assert [phases[n]["depth"] for n in phases] == [
-        0, 1, 2, 3, 3, 2, 1, 1, 2, 3, 2, 1
+        0, 1, 2, 2, 1, 1, 2, 3, 2, 1, 1, 2
     ]
     # Every second of the two steps is some phase's own.
     assert sum(r["self_s"] for r in phases.values()) == pytest.approx(80.0)
@@ -414,8 +415,9 @@ def test_report_lists_the_serve_phases_as_their_tree():
     # The old spans alone make the old rows; a run that served nothing
     # has no such table.
     old = obs_report.build_report(_nested_records())["serve_phases"]
-    assert list(old) == ["engine_step", "prefill", "seat", "decode_step",
-                         "decode.dispatch", "decode.readback", "emit"]
+    assert list(old) == ["engine_step", "seat", "decode_step",
+                         "decode.dispatch", "decode.readback", "emit",
+                         "prefill"]
     assert old["engine_step"]["self_s"] == pytest.approx(44.0)
     assert obs_report.build_report([])["serve_phases"] == {}
     assert "serve phase" not in obs_report.format_report(
@@ -467,28 +469,43 @@ def _inside(child, parent, eps=1e-9):
             <= parent["ts"] + parent["dur"] + eps)
 
 
-@pytest.mark.parametrize("child,parent", [
-    ("admit", "engine_step"),
-    ("prefill", "admit"),
-    ("prefill.dispatch", "prefill"),
-    ("prefill.readback", "prefill"),
-    ("seat", "admit"),
-    ("decode_prepare", "engine_step"),
-    ("decode_step", "engine_step"),
-    ("emit", "engine_step"),
-    ("decode.dispatch", "decode_step"),
-    ("decode.address", "decode.dispatch"),
-    ("decode.readback", "decode_step"),
+# A call that lands a decode step already in flight opens its
+# ``decode_step`` before it admits, so that what is left of that step on
+# the device lies inside the span: ``admit`` and ``decode_prepare`` are
+# then ITS children. A first token is waited for (``prefill``) inside the
+# ``decode_step`` of the call that seated it with nothing in flight, and
+# after ``emit``, under ``engine_step``, where it was seated behind a
+# step.
+@pytest.mark.parametrize("child,parents", [
+    ("admit", ("engine_step", "decode_step")),
+    ("prefill.dispatch", ("admit",)),
+    ("seat", ("admit",)),
+    ("prefill", ("decode_step", "engine_step")),
+    ("prefill.readback", ("prefill",)),
+    ("decode_prepare", ("engine_step", "decode_step")),
+    ("decode_step", ("engine_step",)),
+    ("emit", ("engine_step",)),
+    ("decode.dispatch", ("decode_step",)),
+    ("decode.address", ("decode.dispatch",)),
+    ("decode.readback", ("decode_step",)),
 ])
-def test_engine_phase_nests_in_its_parent(served, child, parent):
+def test_engine_phase_nests_in_its_parent(served, child, parents):
     records, _, _ = served
     by_id = {s["id"]: s for s in _spans(records)}
     children = _spans(records, child)
     assert children
+    seen = set()
     for c in children:
         p = by_id[c["parent"]]
-        assert p["name"] == parent
+        seen.add(p["name"])
         assert _inside(c, p)
+        if child in ("admit", "decode_prepare"):
+            # Inside ``decode_step`` where that step was in flight when
+            # the call began, and nowhere else.
+            assert (p["name"] == "decode_step") == bool(p.get("ahead"))
+        if child == "prefill":
+            assert (p["name"] == "engine_step") == bool(c["behind"])
+    assert seen == set(parents)
 
 
 def test_engine_step_is_top_level_and_counts_its_seats(served):
@@ -522,24 +539,24 @@ def test_children_add_up_to_no_more_than_their_parent(served):
         assert total.get(s["id"], 0.0) <= s["dur"] + 1e-9, s["name"]
 
 
-def test_the_halves_of_a_prefill_tile_it(served):
-    records, _, _ = served
+def test_the_wait_for_a_first_token_is_its_readback(served):
+    """``prefill.dispatch`` is host work of ``admit``; ``prefill`` is
+    the wait for the first token wherever the engine waits, and
+    ``prefill.readback`` tiles it but for its tail (the experts' load)."""
+    records, results, _ = served
     prefills = _spans(records, "prefill")
-    assert prefills
+    dispatches = _spans(records, "prefill.dispatch")
+    assert len(prefills) == len(dispatches) == len(results)
+    assert all(d["cat"] == "serve_engine" for d in dispatches)
     for p in prefills:
-        first, second = sorted(
-            (s for s in _spans(records) if s["parent"] == p["id"]),
-            key=lambda s: s["ts"],
-        )
-        assert (first["name"], second["name"]) == (
-            "prefill.dispatch", "prefill.readback"
-        )
-        assert first["cat"] == second["cat"] == p["cat"]
-        assert first["ts"] == p["ts"]
-        assert first["ts"] + first["dur"] == pytest.approx(second["ts"])
-        assert second["ts"] + second["dur"] == pytest.approx(
-            p["ts"] + p["dur"]
-        )
+        (readback,) = [s for s in _spans(records) if s["parent"] == p["id"]]
+        assert readback["name"] == "prefill.readback"
+        assert readback["cat"] == p["cat"] == "serve_prefill"
+        assert readback["ts"] == p["ts"]
+        assert readback["ts"] + readback["dur"] <= p["ts"] + p["dur"]
+    # Each wait begins after its own dispatch ended.
+    for d, p in zip(dispatches, prefills):
+        assert d["ts"] + d["dur"] <= p["ts"]
 
 
 def test_admit_prepare_and_address_carry_their_attributes(served):
@@ -565,32 +582,69 @@ def test_admit_prepare_and_address_carry_their_attributes(served):
     assert all(a["bytes"] == sent for a in _spans(records, "decode.address"))
 
 
-def test_decode_prepare_ends_where_decode_step_begins(served):
-    """One clock reading ends the preparation and begins the step, as
-    one ends the step and begins the emit."""
+def test_decode_prepare_ends_where_the_dispatch_begins(served):
+    """One clock reading ends the preparation and begins the dispatch
+    (and the step, where no step was in flight), as one ends the step
+    and begins the emit, and one ends the emit and begins the wait for
+    a first token seated behind the step."""
     records, _, _ = served
     by_parent = {}
     for s in _spans(records):
         by_parent.setdefault(s["parent"], []).append(s)
+    for group in by_parent.values():
+        group.sort(key=lambda s: s["ts"])
+    landed = {0: 0, 1: 0}
     for step in _spans(records, "engine_step"):
-        kids = sorted(by_parent.get(step["id"], []), key=lambda s: s["ts"])
+        kids = by_parent.get(step["id"], [])
         names = [k["name"] for k in kids]
         if "decode_step" not in names:
             continue
-        assert names == ["admit", "decode_prepare", "decode_step", "emit"]
-        _, prepare, decode, emit = kids
-        assert prepare["ts"] + prepare["dur"] == pytest.approx(decode["ts"])
+        decode = kids[names.index("decode_step")]
+        emit = kids[names.index("emit")]
+        inner = by_parent[decode["id"]]
+        inner_names = [k["name"] for k in inner]
+        landed[decode["ahead"]] += 1
+        if decode["ahead"]:
+            # The step was in flight: the span opens first.
+            assert names[:2] == ["decode_step", "emit"]
+            assert set(names[2:]) <= {"prefill"}
+            assert inner_names == ["admit", "decode_prepare",
+                                   "decode.dispatch", "decode.readback"]
+            prepare = inner[1]
+        else:
+            assert names == ["admit", "decode_prepare", "decode_step", "emit"]
+            assert inner_names[0] == "decode.dispatch"
+            assert inner_names[-1] == "decode.readback"
+            assert set(inner_names[1:-1]) <= {"prefill"}
+            prepare = kids[1]
+            assert prepare["ts"] + prepare["dur"] == pytest.approx(
+                decode["ts"]
+            )
+        dispatch = inner[inner_names.index("decode.dispatch")]
+        assert prepare["ts"] + prepare["dur"] == pytest.approx(dispatch["ts"])
         assert decode["ts"] + decode["dur"] == pytest.approx(emit["ts"])
+        # What is waited for inside or after the step tiles: no time
+        # between a dispatch's end, the waits and the read-back, nor
+        # between the emit's end and the waits after it.
+        for a, b in zip(inner[inner_names.index("decode.dispatch"):],
+                        inner[inner_names.index("decode.dispatch") + 1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
+        after = kids[names.index("emit"):]
+        for a, b in zip(after, after[1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
+    assert landed[0] and landed[1]
 
 
 def test_engine_step_self_time_is_its_boundaries_alone(model_and_params,
                                                        tmp_path):
     """On a clock that moves one tick a reading, a span lasts as many
     ticks as clock readings fall inside it. What ``engine_step`` keeps
-    for itself in a step that decodes is three ticks: one before
-    ``admit`` begins, one between ``admit`` and ``decode_prepare`` and
-    one after ``emit``; no clock is read, and so no phase of the step
-    runs unnamed, in its own time."""
+    for itself in a step that decodes: one tick before its first child
+    begins and one after its last ends, and, where nothing was in
+    flight, one between ``admit`` and ``decode_prepare``; no clock is
+    read, and so no phase of the step runs unnamed, in its own time.
+    ``decode_step`` keeps its last tick and, where it holds ``admit``
+    and ``decode_prepare``, one before the first and one between them."""
     model, params = model_and_params
     rec = obs.enable(str(tmp_path))
     session = _session(model, params)
@@ -601,30 +655,44 @@ def test_engine_step_self_time_is_its_boundaries_alone(model_and_params,
     own = {
         s["id"]: sec for s, sec in obs_spans.self_seconds(_spans(records))
     }
-    steps = _spans(records, "engine_step")
-    decoded = {s["parent"] for s in _spans(records, "decode_step")}
-    assert decoded
-    for step in steps:
+    decoded = {s["parent"]: s for s in _spans(records, "decode_step")}
+    assert {d["ahead"] for d in decoded.values()} == {0, 1}
+    for step in _spans(records, "engine_step"):
         if step["id"] in decoded:
-            assert own[step["id"]] == pytest.approx(3.0)
+            was_in_flight = decoded[step["id"]]["ahead"]
+            assert own[step["id"]] == pytest.approx(2.0 if was_in_flight
+                                                    else 3.0)
     # decode_step's own tail (the span's attributes, the cache's
     # advance, the experts' counters) holds no clock reading either:
-    # the step's two children tile it but for the last tick.
-    for d in _spans(records, "decode_step"):
-        assert own[d["id"]] == pytest.approx(1.0)
+    # its children tile it but for the last tick.
+    for d in decoded.values():
+        assert own[d["id"]] == pytest.approx(3.0 if d["ahead"] else 1.0)
+    # A wait for a first token keeps its last tick (its attributes).
+    for p in _spans(records, "prefill"):
+        assert own[p["id"]] == pytest.approx(1.0)
 
 
 def test_dispatch_and_readback_tile_the_front_of_decode_step(served):
+    """With the waits for the first tokens a call seated with nothing
+    in flight between them, and ``admit`` and ``decode_prepare`` before
+    them in a call that found a step in flight."""
     records, _, _ = served
     for d in _spans(records, "decode_step"):
         kids = sorted((s for s in _spans(records) if s["parent"] == d["id"]),
                       key=lambda s: s["ts"])
-        assert [k["name"] for k in kids] == [
-            "decode.dispatch", "decode.readback"
-        ]
-        assert kids[0]["ts"] == d["ts"]
-        assert kids[0]["ts"] + kids[0]["dur"] == pytest.approx(kids[1]["ts"])
-        assert all(k["cat"] == d["cat"] for k in kids)
+        names = [k["name"] for k in kids]
+        at = names.index("decode.dispatch")
+        assert names[:at] == (
+            ["admit", "decode_prepare"] if d["ahead"] else []
+        )
+        assert names[-1] == "decode.readback"
+        assert set(names[at + 1:-1]) <= {"prefill"}
+        if not d["ahead"]:
+            assert kids[0]["ts"] == d["ts"]
+        for a, b in zip(kids[at:], kids[at + 1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
+        assert all(k["cat"] == d["cat"] for k in kids
+                   if k["name"].startswith("decode."))
 
 
 def test_seat_and_emit_carry_their_attributes(served):
@@ -637,17 +705,19 @@ def test_seat_and_emit_carry_their_attributes(served):
         assert 0 <= s["slot"] < 2
         assert s["pages"] >= cache.pages_needed(PROMPT_LEN + len(res.tokens))
     emits = _spans(records, "emit")
-    # A request that finishes on its first token is finished in the
-    # seat's tail, not in an emit.
+    # A request that finishes on its first token is finished where that
+    # token lands, not in an emit.
     assert sum(e["finished"] for e in emits) == sum(
         len(r.tokens) > 1 for r in results.values()
     )
 
 
 def test_prefill_and_decode_step_extents_are_the_engines_timestamps(served):
-    """TTFT = queue wait + prefill span, and a request's generation
-    time runs from its prefill's end to its last decode_step's end:
-    exactly, since the spans are given the engine's own clock readings."""
+    """TTFT = queue wait + what the request waited from its pop to the
+    end of its ``prefill`` span (``since_pop_s``: the span itself is
+    the wait for the token alone), and a request's generation time runs
+    from that end to its last decode_step's end: exactly, since the
+    spans are given the engine's own clock readings."""
     records, results, _ = served
     completes = {
         r["request_id"]: r for r in records
@@ -656,9 +726,10 @@ def test_prefill_and_decode_step_extents_are_the_engines_timestamps(served):
     for rid, res in results.items():
         (prefill,) = [p for p in _spans(records, "prefill")
                       if p["request_id"] == rid]
-        assert prefill["queue_wait_s"] + prefill["dur"] == pytest.approx(
-            res.ttft_s, rel=1e-9
+        assert prefill["queue_wait_s"] + prefill["since_pop_s"] == (
+            pytest.approx(res.ttft_s, rel=1e-9)
         )
+        assert prefill["dur"] <= prefill["since_pop_s"]
         chunks = [d for d in _spans(records, "decode_step")
                   if rid in d["rids"]]
         if len(res.tokens) > 1:

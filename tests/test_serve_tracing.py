@@ -248,8 +248,9 @@ def test_shed_reason_breakdown_row(model_and_params, tmp_path, capsys):
 def test_admit_spans_count_what_was_seated_shed_and_left(
         model_and_params, tmp_path):
     """``admit`` on the engine's own clock readings: what it seated,
-    what it shed and what it left waiting, and the prefill and the seat
-    it caused inside it."""
+    what it shed and what it left waiting, and the prefill dispatch and
+    the seat it caused inside it; the wait for each first token is a
+    ``prefill`` of its own, where the engine reads it back."""
     model, params = model_and_params
     t = [0.0]
     obs.enable(str(tmp_path / "obs"))
@@ -274,7 +275,15 @@ def test_admit_spans_count_what_was_seated_shed_and_left(
     assert sum(a["shed"] for a in admits) == 1
     assert admits[-1]["queue_depth"] == 0
     caused = [s["name"] for s in spans if s["parent"] == first["id"]]
-    assert caused == ["prefill", "seat", "prefill", "seat"]
+    assert caused == ["prefill.dispatch", "seat", "prefill.dispatch", "seat"]
+    # Nothing was in flight at any seat (both slots end on one step):
+    # each first token is read back inside the ``decode_step`` of the
+    # call that seated it, in seat order, in a wait of its own.
+    steps = [s["id"] for s in spans if s["name"] == "decode_step"]
+    waits = [s for s in spans if s["name"] == "prefill"]
+    assert [w["request_id"] for w in waits] == ["ok0", "ok1", "ok2"]
+    assert [w["parent"] for w in waits] == [steps[0], steps[0], steps[2]]
+    assert [w["behind"] for w in waits] == [0, 0, 0]
     # The report's serve totals count a step and a prefill once each.
     rows = obs_report.build_report(records)["breakdown"]
     assert rows["serve_engine"]["count"] == len(

@@ -23,11 +23,11 @@ since every record carries host/process tags), then prints
   with queue-wait/TTFT means per reason), when a serve run's
   ``request_complete`` events rode the stream;
 - the serve engine's phases as the tree its spans make (``engine_step``
-  > ``admit`` > ``prefill`` > ``prefill.dispatch`` /
-  ``prefill.readback``, ``seat``; ``decode_prepare``; ``decode_step`` >
-  ``decode.dispatch`` > ``decode.address``, ``decode.readback``;
-  ``emit``), each with its total and its SELF time (its duration less
-  its children's), and the queue depth ``admit`` left behind;
+  > ``admit`` > ``prefill.dispatch``, ``seat``; ``decode_prepare``;
+  ``decode_step`` > ``decode.dispatch`` > ``decode.address``,
+  ``decode.readback``; ``emit``; ``prefill`` > ``prefill.readback``),
+  each with its total and its SELF time (its duration less its
+  children's), and the queue depth ``admit`` left behind;
 - the last counters snapshot per process, if any rode the stream.
 
 ``--request <id>`` switches to per-request trace mode: the serve
@@ -105,12 +105,15 @@ def load_records(paths: Iterable[str]) -> List[dict]:
 
 
 #: The serve engine's spans, a step's order, with their depth in the
-#: tree (tpudl.serve.engine).
+#: tree (tpudl.serve.engine) as a call that seats behind a decode step
+#: in flight and then dispatches has it; such a call's ``decode_step``
+#: is open from before ``admit``, and a ``prefill`` (the wait for a
+#: first token) that precedes the landed step lies inside it.
 _SERVE_TREE = (
-    ("engine_step", 0), ("admit", 1), ("prefill", 2),
-    ("prefill.dispatch", 3), ("prefill.readback", 3), ("seat", 2),
+    ("engine_step", 0), ("admit", 1), ("prefill.dispatch", 2), ("seat", 2),
     ("decode_prepare", 1), ("decode_step", 1), ("decode.dispatch", 2),
     ("decode.address", 3), ("decode.readback", 2), ("emit", 1),
+    ("prefill", 1), ("prefill.readback", 2),
 )
 
 
@@ -475,9 +478,17 @@ def build_request_timeline(records: Iterable[dict], request_id) -> dict:
                        "depth": queued.get("depth")},
             "record": queued,
         })
+    prefill_s = None
     if prefill is not None:
+        # The engine's own span is the wait for the first token alone
+        # and says how long the request had been popped by its end
+        # (``since_pop_s``: the dispatch and what the device held
+        # before the prefill included); a prefill worker's span is the
+        # whole of it.
+        prefill_s = float(prefill.get("since_pop_s", prefill["dur"]))
         timeline.append({
-            "ts": float(prefill["ts"]), "dur": float(prefill["dur"]),
+            "ts": float(prefill["ts"]) + float(prefill["dur"]) - prefill_s,
+            "dur": prefill_s,
             "what": "prefill",
             # prefix_hit_tokens: how much of the prompt the radix
             # prefix cache served for free — the TTFT attribution
@@ -563,9 +574,27 @@ def build_request_timeline(records: Iterable[dict], request_id) -> dict:
     if complete is not None and complete.get("queue_wait_s") is not None:
         queue_wait_s = float(complete["queue_wait_s"])
     elif prefill is not None and queued is not None:
-        queue_wait_s = float(prefill["ts"]) - float(queued["ts"])
-    prefill_s = float(prefill["dur"]) if prefill is not None else None
-    decode_s = sum(float(c["dur"]) for c in decode_chunks)
+        queue_wait_s = (
+            float(prefill["ts"]) + float(prefill["dur"]) - prefill_s
+            - float(queued["ts"])
+        )
+    # A chunk whose ``decode_step`` was open while the engine still
+    # waited for this request's first token (the step dispatched from a
+    # token on the device, its span around that wait) counts from that
+    # token on: the time before it is the prefill leg's. Only against
+    # the engine's own span, which shares the chunks' clock.
+    first_at = (
+        float(prefill["ts"]) + float(prefill["dur"])
+        if prefill is not None and "since_pop_s" in prefill else None
+    )
+    decode_s = sum(
+        float(c["dur"]) if first_at is None else max(
+            0.0,
+            float(c["ts"]) + float(c["dur"])
+            - max(float(c["ts"]), first_at),
+        )
+        for c in decode_chunks
+    )
     first_chunk_s = (
         float(decode_chunks[0]["dur"]) if decode_chunks else None
     )

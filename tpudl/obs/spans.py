@@ -467,20 +467,30 @@ def _span_key(record: dict, span_id) -> tuple:
 
 
 def without_same_category_children(spans: Iterable[dict]) -> list:
-    """The spans with no parent of their own category: a phase that is
-    split into children (``decode_step`` into ``decode.dispatch`` and
-    ``decode.readback``) counts once in a sum, a count or a histogram
-    over its category. Records without ids (older files) all pass."""
+    """The spans with no span of their own category around them: a
+    phase that is split into children (``decode_step`` into
+    ``decode.dispatch`` and ``decode.readback``) counts once in a sum,
+    a count or a histogram over its category, and so does one whose
+    part lies under a phase of another category (the serve engine's
+    ``admit`` inside the ``decode_step`` of a call that lands a step in
+    flight, inside its ``engine_step``). Records without ids (older
+    files) all pass."""
     spans = list(spans)
-    cat_of = {
-        _span_key(s, s["id"]): s.get("cat")
-        for s in spans if s.get("id") is not None
+    by_key = {
+        _span_key(s, s["id"]): s for s in spans if s.get("id") is not None
     }
-    return [
-        s for s in spans
-        if s.get("parent") is None
-        or cat_of.get(_span_key(s, s["parent"])) != s.get("cat")
-    ]
+
+    def enclosed(s: dict) -> bool:
+        around = s
+        while around.get("parent") is not None:
+            around = by_key.get(_span_key(s, around["parent"]))
+            if around is None:
+                return False
+            if around.get("cat") == s.get("cat"):
+                return True
+        return False
+
+    return [s for s in spans if not enclosed(s)]
 
 
 def self_seconds(spans: Iterable[dict]) -> list:

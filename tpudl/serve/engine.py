@@ -34,8 +34,8 @@ Admission is ``fits_tokens``: are enough free pages left to reserve the
 request's worst case up front.
 
 Two hooks the multi-replica router (tpudl.serve.router) builds on:
-``on_token`` (called per (request_id, token) as it is selected — the
-streaming feed) and ``prefill_inbox`` (externally prefilled requests:
+``on_token`` (called per (request_id, token) as the host reads it
+back — the streaming feed) and ``prefill_inbox`` (externally prefilled requests:
 a dedicated prefill replica runs the batch-1 program and hands the row
 cache over; this engine only seats and decodes — prefill/decode
 disaggregation over the same mid-stream insertion contract).
@@ -70,12 +70,17 @@ from tpudl.serve.queue import CAT_SERVE_REQUEST, AdmissionQueue, _Entry
 
 #: Span categories (their own rows in the obs report breakdown table).
 #: One ``engine_step`` span encloses a step's ``admit`` (which encloses
-#: the ``prefill`` and ``seat`` spans it causes), ``decode_prepare``,
-#: ``decode_step`` and ``emit``. ``prefill`` encloses
-#: ``prefill.dispatch`` and ``prefill.readback``, ``decode_step``
+#: the ``prefill.dispatch`` and ``seat`` spans it causes),
+#: ``decode_prepare``, ``decode_step``, ``emit`` and a ``prefill``
+#: (around ``prefill.readback``) for every first token the step reads
+#: back: the wait for it, wherever the engine waits. ``decode_step``
 #: encloses ``decode.dispatch`` (around ``decode.address``) and
-#: ``decode.readback``: children of their parent's category, so that a
-#: sum over ``serve_prefill`` or ``serve_decode`` counts each once.
+#: ``decode.readback``; in a call that lands a step already in flight
+#: it is opened first and encloses ``admit`` and ``decode_prepare``
+#: too, so that the landing step's device time lies inside it. A child
+#: of its parent's category is counted once in a sum over that
+#: category: ``serve_prefill`` is the waits for first tokens
+#: (``prefill.dispatch`` is host work of ``admit``, ``serve_engine``).
 CAT_SERVE_ENGINE = "serve_engine"
 CAT_SERVE_PREFILL = "serve_prefill"
 CAT_SERVE_SEAT = "serve_seat"
@@ -142,26 +147,38 @@ def _selection_sharding(params):
     return sharding
 
 
-def first_token(logits, request, also=None):
-    """Select a request's FIRST token from its batch-1 prefill logits
-    (step 0 of its per-request sampling stream) — shared by the
-    engine's local seat path and the router's dedicated prefill
-    workers, so disaggregated serving draws identical tokens. ``also``
-    (device arrays the same program returned) is read back in the same
-    transfer: ``(token, also_on_host)``."""
+def select_first(logits, request):
+    """A request's FIRST token selected from its batch-1 prefill logits
+    (step 0 of its per-request sampling stream), int32 [1] ON THE
+    DEVICE: nothing is read back. Shared by the engine's seat, which
+    leaves it there for the next decode step, and ``first_token``."""
     if request.temperature > 0:
-        sel = _select_tokens(
+        return _select_tokens(
             logits,
             np.float32([request.temperature]),
             np.uint32([request.seed]),
             np.int32([0]),
         )
-    else:
-        sel = _select_greedy(logits)
-    if also is None:
-        return int(jax.device_get(sel)[0])
-    sel, also = jax.device_get((sel, also))
-    return int(sel[0]), also
+    return _select_greedy(logits)
+
+
+def first_token(logits, request):
+    """``select_first`` read back at once: the router's dedicated
+    prefill workers hand a host token over with the row, so
+    disaggregated serving draws the tokens the engine's own seat
+    draws. The engine itself never calls this (``Engine._seat``)."""
+    return int(jax.device_get(select_first(logits, request))[0])
+
+
+def tpudl_first_token(tokens, slot, first):
+    """A decode step's token vector (int32 [slots]) with ``slot``'s
+    entry taken from a prefill's selection (int32 [1]) as it lies on
+    the device. ``slot`` is traced: one program whichever slot and
+    however many seats a call makes."""
+    return jax.lax.dynamic_update_slice(tokens, first, (slot,))
+
+
+_set_first = jax.jit(tpudl_first_token)
 
 
 def record_expert_load(counts=None, chose=None, hyper=None, loop=None) -> dict:
@@ -284,29 +301,37 @@ class _Prefilled:
 
 
 class _Slot:
-    """Host-side state of one occupied decode slot."""
+    """Host-side state of one occupied decode slot. ``tokens`` is empty
+    and ``first`` set while the slot's first token is still on the
+    device (``_First``): the slot is seated and busy, its times
+    ``t_first`` / ``t_last`` are not known yet."""
 
     __slots__ = (
-        "entry", "request", "tokens", "position", "steps",
+        "entry", "request", "tokens", "first", "position", "steps",
         "t_seated", "t_first", "t_last", "gap_origin",
         "prefix_hit", "spec_proposed", "spec_accepted",
         "adapter_reloads", "migrations", "kv_base",
     )
 
-    def __init__(self, entry: _Entry, first_token: int, prompt_len: int,
-                 seated: float, now: float, kv_base: int = 0):
+    def __init__(self, entry: _Entry, prompt_len: int, seated: float,
+                 kv_base: int = 0):
         self.entry = entry
         self.request: Request = entry.request
-        self.tokens: List[int] = [first_token]
+        self.tokens: List[int] = []
+        self.first: Optional["_First"] = None
         self.position = prompt_len  # next absolute RoPE position
-        self.steps = 1  # tokens drawn so far (the sampling fold_in index)
+        # Tokens drawn so far (the sampling fold_in index): the first
+        # is drawn at the seat, landed or not.
+        self.steps = 1
         # Cache rows the slot held with its first token alone: with n
         # tokens it holds ``kv_base + n - 1``, whatever the cache's own
         # ``lens`` says while a step is in flight.
         self.kv_base = kv_base
         self.t_seated = seated  # pop time: queue wait ends HERE
-        self.t_first = now  # first token out: TTFT ends here (incl. prefill)
-        self.t_last = now
+        # First token out: TTFT ends here (incl. prefill), where the
+        # host has it (``Engine._first_landed``).
+        self.t_first: Optional[float] = None
+        self.t_last: Optional[float] = None
         # Migrated slots: the SOURCE's last-token time, consumed when
         # the first post-migration token lands (the failover token-gap
         # histogram — how long the client's stream actually stalled).
@@ -322,15 +347,37 @@ class _Slot:
         self.migrations = 0
 
 
+class _First:
+    """A request's first token where its prefill's selection left it:
+    on the device (``sel``, int32 [1]), beside what the prefill program
+    returned after the logits and the row (``counts``). It is queued
+    with the decode steps the device holds unread, in dispatch order
+    (``Engine._unread``), feeds the slot's first decode step from the
+    device, and is read back by ``Engine._land_first``. ``attrs``: what
+    the ``prefill`` span around that read-back will say of the prefill
+    (made at its dispatch; None where nothing records)."""
+
+    __slots__ = ("slot", "state", "sel", "counts", "attrs")
+
+    def __init__(self, sel, counts, attrs):
+        self.slot: Optional[int] = None  # both set by ``_install``
+        self.state: Optional[_Slot] = None
+        self.sel = sel
+        self.counts = counts
+        self.attrs = attrs
+
+
 class _Plan:
     """One decode step's inputs, made before its dispatch. ``rows[i]``
     is the ``_Slot`` whose row ``i`` the step computes, None for a row
     that rides idle. ``tokens``: the host's array, or with ``ahead``
     the selection of the step before as it lies on the device (int32
-    [slots] either way, what ``decode_call`` takes)."""
+    [slots] either way, what ``decode_call`` takes); ``firsts``: the
+    ``(slot, selection)`` of every row whose input is a first token
+    still on the device, set into ``tokens`` there at the dispatch."""
 
-    __slots__ = ("rows", "ahead", "tokens", "positions", "temps", "seeds",
-                 "steps", "pages_live")
+    __slots__ = ("rows", "ahead", "tokens", "firsts", "positions", "temps",
+                 "seeds", "steps", "pages_live")
 
     def __init__(self, num_slots: int, prev: Optional["_InFlight"]):
         self.rows: List[Optional[_Slot]] = [None] * num_slots
@@ -338,6 +385,7 @@ class _Plan:
         self.tokens = (
             prev.sel if self.ahead else np.zeros(num_slots, np.int32)
         )
+        self.firsts: List[tuple] = []
         self.positions = np.zeros(num_slots, np.int32)
         self.temps = np.zeros(num_slots, np.float32)
         self.seeds = np.zeros(num_slots, np.uint32)
@@ -350,9 +398,10 @@ class _InFlight:
     read back: the tokens it selects (``sel``, int32 [slots], on the
     device) and what its program returned beside the logits
     (``extras``), the slots whose rows it computes (``rows``, as
-    ``_Plan`` has them), whether its input tokens came from the device
-    (``ahead``), and what its ``decode_step`` span will say of it
-    (``attrs``, made at the dispatch; None where nothing records)."""
+    ``_Plan`` has them), whether its input tokens were the selection of
+    the step before as it lay on the device (``ahead``), and what its
+    ``decode_step`` span will say of it (``attrs``, made at the
+    dispatch; None where nothing records)."""
 
     __slots__ = ("rows", "sel", "extras", "ahead", "attrs")
 
@@ -397,8 +446,11 @@ class Engine:
     advances the world by one decode step; ``run_until_drained()`` loops
     it (the ServeSession front end drives either). With every slot
     seated the device is kept one step ahead of what ``step()`` has
-    read back (``_decode_step``): that step is ``_in_flight``, and
-    ``land()`` reads it back for whoever touches a slot from outside."""
+    read back (``_decode_step``), and a seat reads nothing back
+    (``_seat``): its first token stays on the device for the slot's
+    first decode step. What the device holds unread is ``_unread``
+    (``_in_flight``: the decode step among it), and ``land()`` reads
+    it all back for whoever touches a slot from outside."""
 
     def __init__(
         self,
@@ -476,9 +528,13 @@ class Engine:
                     "chunk decode program)"
                 )
         self._slots: List[Optional[_Slot]] = [None] * self.num_slots
-        # The decode step the device holds and the host has not read
-        # back (``_decode_step``): at most one between two ``step``s.
-        self._in_flight: Optional[_InFlight] = None
+        import collections
+
+        # What the device holds and the host has not read back, in
+        # dispatch order: decode steps (``_InFlight``) and first tokens
+        # (``_First``). Between two ``step``s at most one decode step
+        # and nothing else.
+        self._unread = collections.deque()
         self._token_sharding = _selection_sharding(params)
         self.results: Dict[Any, Result] = {}
         # The recorder of the step under way (tpudl.obs.spans), looked
@@ -486,16 +542,15 @@ class Engine:
         # step and whenever recording is off.
         self._rec = None
         self._seats = 0  # prompts seated in the step under way
-        # Streaming feed: called with (request_id, token) the moment a
-        # token is selected (prefill's first token included) — BEFORE
-        # the finish check, so a consumer sees eos arrive as a token
-        # and then the Result. ServeSession.stream() installs it.
+        # Streaming feed: called with (request_id, token) the moment
+        # the host has a token (prefill's first token included, where
+        # it lands) — BEFORE the finish check, so a consumer sees eos
+        # arrive as a token and then the Result. ServeSession.stream()
+        # installs it.
         self.on_token: Optional[Callable[[Any, int], None]] = None
         # Disaggregation inbox: _Prefilled items seated by _fill_slots
         # ahead of local queue pops (deque: appends are thread-safe, the
         # router's prefill workers feed it from their own threads).
-        import collections
-
         self.prefill_inbox = collections.deque()
         # Migration inbox: (rid, payload, lease) triples appended by the
         # router when a dying/draining replica's decode state is shipped
@@ -547,7 +602,8 @@ class Engine:
         """/healthz payload: slot occupancy + admission-queue state
         (what the serve router's readiness and autoscale signals read).
         Burning SLO objectives surface via the monitor's own health
-        source; here they only annotate the engine's view."""
+        source; here they only annotate the engine's view. A slot whose
+        first token is still on the device is busy."""
         out = {
             "healthy": True,
             "slots_busy": sum(s is not None for s in self._slots),
@@ -628,16 +684,40 @@ class Engine:
         meets a program that does not exist yet. The dry seat aims at
         the trash page and no slot is touched. A radix session seats
         through ONE left-aligned program whatever the row's length, so
-        only its prefill is made."""
+        only its prefill is made. Beside the seat, the program that
+        hands a first token to the next decode step on the device
+        (``tpudl_first_token``), over both vectors it is given: the
+        host's tokens and a step's selection, which its own result
+        stands for here (an array of the device, placed alike)."""
         for rows in lengths:
-            _, row_cache, *_ = self.prefill_call(
+            logits, row_cache, *_ = self.prefill_call(
                 self.params, *left_pad([0], rows)
             )
             if not self.prefix_share:
                 self.cache.compile_seat(row_cache, rows)
             if self.speculator is not None:
                 self.speculator.compile_seat(rows)
+            first = _select_greedy(logits)
+            tokens = self._with_firsts(
+                np.zeros(self.num_slots, np.int32), [(0, first)]
+            )
+            self._with_firsts(tokens, [(0, first)], from_device=True)
         self.prefill_lengths = tuple(sorted({*lengths, self.prompt_len}))
+
+    def _with_firsts(self, tokens, firsts, from_device: bool = False):
+        """A decode step's token vector as the device takes it: the
+        host's array put where the selection would have left it (where
+        the parameters are committed), or with ``from_device`` the
+        selection of the step before as it lies there; then every
+        ``(slot, selection)`` of ``firsts`` set into it on the device,
+        one small program a first token. Its arguments being placed
+        alike, its result is too: the decode program meets one kind of
+        vector whichever way the tokens come."""
+        if not from_device and self._token_sharding is not None:
+            tokens = jax.device_put(tokens, self._token_sharding)
+        for slot, sel in firsts:
+            tokens = _set_first(tokens, np.int32(slot), sel)
+        return tokens
 
     # -- admission / seating -------------------------------------------
 
@@ -669,31 +749,33 @@ class Engine:
 
     def _seat(self, entry: _Entry, slot: int) -> None:
         """Prefill one request and scatter it into ``slot`` of the live
-        cache; select its first token. Radix mode first walks the
-        prefix tree: matched full pages seat for free, and the batch-1
-        program is replaced by the CHUNKED suffix prefill — prefill
-        cost drops from O(prompt window) to O(unshared suffix)."""
+        cache; select its first token. NOTHING IS READ BACK: the
+        prefill, the selection and the scatter are dispatched, the
+        token stays on the device for the slot's first decode step
+        (``_First``) and the host reads it when it lands what the
+        device holds (``_land_first``). So a seat costs the host its
+        dispatches and may be made behind a decode step that is still
+        running. Radix mode first walks the prefix tree: matched full
+        pages seat for free, and the batch-1 program is replaced by the
+        CHUNKED suffix prefill — prefill cost drops from O(prompt
+        window) to O(unshared suffix)."""
         req = entry.request
         ids = np.asarray(req.input_ids, np.int32)
         n = int(ids.shape[0])
         rec = self._rec
         t0 = self.clock()
-        span = part = None
+        part = None
         if rec is not None:
-            # request_id on the prefill span is the trace link between
-            # the queued event and this request's decode chunks.
-            span = rec.begin(
-                "prefill", CAT_SERVE_PREFILL, t0, slot=slot,
-                request_id=req.request_id,
-                queue_wait_s=t0 - entry.submitted_at,
-            )
-            # Its two halves: everything up to the program's call
-            # returning, then the wait for the first token.
-            part = rec.begin("prefill.dispatch", CAT_SERVE_PREFILL, t0)
+            # Everything up to the program's call returning; the wait
+            # for the first token is ``prefill``'s, at its landing.
+            part = rec.begin("prefill.dispatch", CAT_SERVE_ENGINE, t0)
         lease = None
         hit = 0
         tenant_pinned = False
         reloads0 = 0
+        # A decode step the device still holds: the prefill queues
+        # behind it and the chip goes from one to the other.
+        behind = self._in_flight is not None
         # The prompt runs left-padded to the shortest compiled length
         # that holds it; the row, its seat and its pages follow that
         # length, not the window.
@@ -740,31 +822,29 @@ class Engine:
                     logits, row_cache, *counts = self.prefill_call(
                         self.params, padded, mask
                     )
-            if part is not None:
-                t = self.clock()
-                part.end(t)
-                part = rec.begin("prefill.readback", CAT_SERVE_PREFILL, t)
-            load = {}
-            if counts:
-                first, counts = first_token(logits, req, also=counts)
-                load = record_expert_load(*counts)
-            else:
-                first = first_token(logits, req)
+            sel = select_first(logits, req)
         except BaseException:
             if lease is not None:
                 self.cache.release_lease(lease[1])
             if tenant_pinned:
                 self.adapter_pool.release(req.tenant)
             raise
-        now = self.clock()
-        if span is not None:
-            part.end(now)
+        attrs = None
+        if part is not None:
+            part.end(self.clock())
+            # request_id on the prefill span is the trace link between
+            # the queued event and this request's decode chunks.
             # prefix_hit_tokens names how much of the prompt the radix
             # cache paid for (report.py --request's TTFT attribution).
             # rows: the length the program ran; tokens: the prompt's
             # among them (what the padding and a shared prefix leave).
-            span.end(now, prefix_hit_tokens=hit, rows=ran, tokens=n - hit,
-                     **load)
+            # behind: a decode step was in flight at the dispatch.
+            attrs = dict(
+                slot=slot, request_id=req.request_id,
+                queue_wait_s=t0 - entry.submitted_at,
+                prefix_hit_tokens=hit, rows=ran, tokens=n - hit,
+                behind=int(behind),
+            )
         reg = registry()
         if hit:
             reg.counter("serve_prefix_hit_tokens").inc(hit)
@@ -772,8 +852,10 @@ class Engine:
         reg.counter("serve_prefills").inc()
         reg.counter("serve_prefill_rows").inc(ran)
         reg.counter("serve_prefill_tokens").inc(n - hit)
-        self._install(entry, slot, row_cache, first, n, t0, now,
-                      rows, lease=lease, row_offset=row_offset,
+        if behind:
+            reg.counter("serve_prefills_behind_step").inc()
+        self._install(entry, slot, row_cache, _First(sel, counts, attrs),
+                      n, t0, None, rows, lease=lease, row_offset=row_offset,
                       tenant_pinned=self.adapter_pool is not None,
                       prefix_hit=hit,
                       adapter_reloads=(
@@ -784,23 +866,27 @@ class Engine:
     def _seat_prefilled(self, item: _Prefilled, slot: int) -> None:
         """Seat a request a DEDICATED prefill replica already prefilled
         (tpudl.serve.router disaggregation): same mid-stream insertion,
-        no local batch-1 dispatch — this engine only decodes."""
+        no local batch-1 dispatch — this engine only decodes. The
+        handoff carries the first token on the host, landed at once."""
         self._install(
             item.entry, slot, item.row_cache, item.first_token,
             item.prompt_ids_len, item.t_popped, item.t_first, item.rows,
         )
 
     def _install(self, entry: _Entry, slot: int, row_cache: Any,
-                 first: int, ids_len: int, t_popped: float,
-                 t_first: float, rows: int, lease=None,
+                 first, ids_len: int, t_popped: float,
+                 t_first: Optional[float], rows: int, lease=None,
                  row_offset: Optional[int] = None,
                  tenant_pinned: bool = False, prefix_hit: int = 0,
                  adapter_reloads: int = 0,
                  ) -> None:
         """Shared seat tail: cache insertion (page reservation+scatter,
-        or radix-shared left-aligned seat),
-        latency accounting, draft-cache seating, adapter binding, slot
-        activation. ``rows`` is the length the row was prefilled at."""
+        or radix-shared left-aligned seat), draft-cache seating,
+        adapter binding, slot activation. ``rows`` is the length the
+        row was prefilled at. ``first``: the first token where the host
+        has it already (an int, selected at ``t_first``; landed here,
+        at once), else the ``_First`` that holds it on the device, which
+        is queued behind whatever the device holds unread."""
         req = entry.request
         tenant = getattr(req, "tenant", None)
         if self.adapter_pool is not None and not tenant_pinned:
@@ -857,21 +943,91 @@ class Engine:
                 rows, rows + req.max_new_tokens,
             )
         queue_wait_ms = 1e3 * (t_popped - entry.submitted_at)
-        ttft_ms = 1e3 * (t_first - entry.submitted_at)
-        reg = registry()
-        reg.histogram("serve_queue_wait_ms").observe(queue_wait_ms)
-        reg.histogram("serve_ttft_ms").observe(ttft_ms)
+        registry().histogram("serve_queue_wait_ms").observe(queue_wait_ms)
         self._slo_observe("serve_queue_wait_ms", queue_wait_ms)
-        self._slo_observe("serve_ttft_ms", ttft_ms)
-        s = _Slot(entry, first, ids_len, t_popped, t_first,
+        s = _Slot(entry, ids_len, t_popped,
                   kv_base=int(self.cache.lens[slot]))
         s.prefix_hit = prefix_hit
         s.adapter_reloads = adapter_reloads
         self._slots[slot] = s
+        if isinstance(first, _First):
+            first.slot, first.state = slot, s
+            s.first = first
+            self._unread.append(first)
+        else:
+            self._first_landed(slot, s, first, t_first)
+
+    def _first_landed(self, slot: int, s: _Slot, token: int,
+                      now: float) -> None:
+        """The host has ``s``'s first token, selected at ``now``: TTFT
+        ends, the stream starts, and the request may be over."""
+        s.tokens.append(token)
+        s.t_first = s.t_last = now
+        ttft_ms = 1e3 * (now - s.entry.submitted_at)
+        registry().histogram("serve_ttft_ms").observe(ttft_ms)
+        self._slo_observe("serve_ttft_ms", ttft_ms)
         if self.on_token is not None:
-            self.on_token(req.request_id, first)
+            self.on_token(s.request.request_id, token)
         # A request can finish on its very first token.
-        self._maybe_finish(slot, first)
+        self._maybe_finish(slot, token)
+
+    def _land_first(self, first: _First,
+                    t: Optional[float] = None) -> Optional[float]:
+        """Read one first token back, in a transfer of its own, with
+        what its prefill returned beside it. Under a ``prefill`` span
+        (around ``prefill.readback``) that carries the prefill's
+        attributes: the wait for that prefill wherever the engine
+        waits, so that the program's device time lies inside it
+        (behind a decode step the prefill starts on the chip when that
+        step ends, which is when this wait begins). ``t``: a clock
+        reading just made, where the span begins; the reading at which
+        it ended is returned, so that waits tile. A prefill that
+        failed surfaces here: the slot is freed, with its pages, lease
+        and pin, and the error goes on."""
+        slot, s = first.slot, first.state
+        rec = self._rec
+        span = readback = None
+        if rec is not None and first.attrs is not None:
+            if t is None:
+                t = self.clock()
+            span = rec.begin("prefill", CAT_SERVE_PREFILL, t, **first.attrs)
+            readback = rec.begin("prefill.readback", CAT_SERVE_PREFILL, t)
+        s.first = None
+        try:
+            sel, counts = jax.device_get((first.sel, first.counts))
+        except BaseException:
+            self._release(slot)
+            raise
+        if readback is not None:
+            readback.end(self.clock())
+        load = record_expert_load(*counts) if counts else {}
+        now = self.clock()
+        if span is not None:
+            # since_pop_s: what the request waited from its pop to this
+            # token, the dispatch and what the device held before the
+            # prefill included (report.py --request's TTFT attribution:
+            # queue wait + this = TTFT).
+            span.end(now, since_pop_s=now - s.t_seated, **load)
+        self._first_landed(slot, s, int(sel[0]), now)
+        return now
+
+    def _land_firsts(self, t: Optional[float] = None) -> Optional[float]:
+        """Land the first tokens that precede the oldest decode step
+        the device holds, each in its own transfer, in order. ``t`` as
+        ``_land_first`` takes and returns it."""
+        unread = self._unread
+        while unread and isinstance(unread[0], _First):
+            t = self._land_first(unread.popleft(), t)
+        return t
+
+    @property
+    def _in_flight(self) -> Optional[_InFlight]:
+        """The newest decode step the device holds and the host has
+        not read back; None where it holds none."""
+        return next(
+            (u for u in reversed(self._unread) if isinstance(u, _InFlight)),
+            None,
+        )
 
     def _active(self) -> bool:
         return any(s is not None for s in self._slots)
@@ -917,14 +1073,13 @@ class Engine:
                 self._record_shed(burnt, "shed_slo")
         if not self.continuous and self._active():
             return popped, shed
-        if self._in_flight is not None:
-            # Nothing is seated behind a decode step in flight: its
-            # landing (this call's) frees the chip for the prefill and
-            # the next call seats, as a step that was never ahead would
-            # have. A prefill queued behind it would run no sooner, and
-            # the step's device time would pass under ``admit`` instead
-            # of under the ``decode_step`` that lands it.
-            return popped, shed
+        # A decode step in flight holds nothing up: a seat reads nothing
+        # back, so its prefill, selection and scatter queue behind that
+        # step on the device (ordered after it through the pool, like
+        # the step ahead) and the chip goes from one to the other while
+        # the host dispatches. The call's ``decode_step`` span is open
+        # already (``step``), so the landing step's device time lies in
+        # it and not under ``admit`` alone.
         # Migrated-in requests seat FIRST: they are mid-stream — their
         # prefill AND some decode are already paid, and every queued
         # token of delay widens the client's visible stall (the
@@ -1147,10 +1302,11 @@ class Engine:
         from the payload (the router probed AND LEASED them in the
         target's radix tree — prefix by reference, not by bytes).
         Commit-or-invisible: the slot is freed only after the payload
-        exists in full. A decode step in flight is landed first, so the
-        payload holds every token the device has computed; a request
-        that step finished is not seated any more (None) and its Result
-        is in ``results``."""
+        exists in full. What the device holds unread (a decode step in
+        flight, a first token) is landed first, so the payload holds
+        every token the device has computed; a request that this
+        finished is not seated any more (None) and its Result is in
+        ``results``."""
         self.land()
         slot = next(
             (
@@ -1242,12 +1398,7 @@ class Engine:
         )
         # Commit point: the payload exists in full — the local copy of
         # this request ends here (no double decode, no late Result).
-        self.cache.free(slot)
-        if self.speculator is not None:
-            self.speculator.free(slot)
-        if self.adapter_pool is not None:
-            self.adapter_pool.free_slot(slot)
-        self._slots[slot] = None
+        self._release(slot)
         reg = registry()
         reg.counter("serve_migrations_exported").inc()
         reg.counter("serve_migration_payload_bytes").inc(len(payload))
@@ -1272,9 +1423,9 @@ class Engine:
         never resumed. Raises ``MigrationCorruptError`` on a payload
         that fails the crc (resuming garbage is the one unforgivable
         outcome) and ``MigrationCompatError`` on a cache this engine
-        cannot seat it in. Returns the request_id. Lands a decode step
-        in flight first, as whoever touches a slot from outside
-        ``step`` does (admission, inside one, seats without)."""
+        cannot seat it in. Returns the request_id. Lands what the
+        device holds unread first, as whoever touches a slot from
+        outside ``step`` does (admission, inside one, seats without)."""
         self.land()
         return self._install_migrated(payload, slot, lease)
 
@@ -1365,11 +1516,11 @@ class Engine:
                 self.adapter_pool.release(req.tenant)
             raise
         s = _Slot(
-            entry, int(meta["tokens"][0]), int(meta["prompt_ids_len"]),
-            float(meta["t_seated"]), float(meta["t_first"]),
+            entry, int(meta["prompt_ids_len"]), float(meta["t_seated"]),
             kv_base=int(self.cache.lens[slot]) - len(meta["tokens"]) + 1,
         )
         s.tokens = [int(t) for t in meta["tokens"]]
+        s.t_first = float(meta["t_first"])
         s.position = int(meta["position"])
         s.steps = int(meta["steps"])
         s.t_last = float(meta["t_last"])
@@ -1544,12 +1695,17 @@ class Engine:
             active_s=active_s,
             **samples,
         ))
+        self._release(slot)
+
+    def _release(self, slot: int) -> None:
+        """Vacate ``slot``: its pages and radix lease go back to the
+        cache, the draft's rows to the speculator, the tenant pin to
+        the adapter pool (the adapter stays CACHED at refcount 0, the
+        evictable pool, for the next request)."""
         self.cache.free(slot)
         if self.speculator is not None:
             self.speculator.free(slot)
         if self.adapter_pool is not None:
-            # Drops the slot's tenant pin; the adapter stays CACHED at
-            # refcount 0 (the evictable pool) for the next request.
             self.adapter_pool.free_slot(slot)
         self._slots[slot] = None
 
@@ -1576,30 +1732,34 @@ class Engine:
     def _prepare(self, prev: Optional[_InFlight]) -> Optional[_Plan]:
         """The host arrays of the next decode step. With nothing in
         flight (``prev`` None) every seated slot's row, its input token
-        from the host. With ``prev`` in flight the step AHEAD of it,
-        which takes its tokens from the device, and only where the
-        engine can see that running ahead costs nobody anything: every
-        slot is seated (no arrival could be seated before that step
-        anyway), each by the request whose row ``prev`` computes (a
-        guard: admission seats nothing while a step is in flight, and
-        whoever seats from outside ``step`` lands it first; a slot
-        seated since would have its first token on the host and no row
-        in ``prev``), and nothing speculates (acceptance needs the
-        host). None where that does not hold, or no row would be left.
+        from the host, or from the device where the slot's first token
+        is still there (``plan.firsts``). With ``prev`` in flight the
+        step AHEAD of it, whose tokens are ``prev``'s selection on the
+        device, and only where the engine can see that running ahead
+        costs nobody anything: every slot is seated (no arrival could
+        be seated before that step anyway), each by the request whose
+        row ``prev`` computes or by one seated since whose first token
+        is on the device too (its entry is set there; one seated since
+        with a token on the host, a migrated or externally prefilled
+        request, has neither), and nothing speculates (acceptance needs
+        the host). None where that does not hold, or no row would be
+        left.
 
-        A slot whose last token is ``prev``'s to select (by length: the
-        host knows beforehand) rides the step ahead as an idle row: its
-        pages are handed back here, ``prev`` having been dispatched, so
-        that its table row maps the trash page as an idle slot's does
-        (whoever is given the pages next writes them in a program
-        ordered after ``prev`` on the device: the pool goes from program
-        to program). The slot itself stays seated until ``prev`` lands
-        its token. One that ends by ``eos_id`` is known one step late:
-        the step ahead then computes one row for nobody, inside the
-        slot's own reservation, and ``_land`` drops its token."""
+        A slot whose last token is on the device already (by length:
+        the host knows beforehand; ``prev``'s to select, or a first
+        token that is the only one asked for) has no row. Under a step
+        ahead its pages are handed back here, what wrote them having
+        been dispatched, so that its table row maps the trash page as
+        an idle slot's does (whoever is given the pages next writes
+        them in a program ordered after on the device: the pool goes
+        from program to program). The slot itself stays seated until
+        its token lands. One that ends by ``eos_id`` is known one step
+        late: the step dispatched meanwhile computes one row for
+        nobody, inside the slot's own reservation, and ``_land`` drops
+        its token."""
         ahead = prev is not None
         if ahead and (self.speculator is not None or any(
-            s is None or s is not row
+            s is None or (s is not row and s.first is None)
             for s, row in zip(self._slots, prev.rows)
         )):
             return None
@@ -1608,22 +1768,28 @@ class Engine:
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
-            if ahead and len(s.tokens) + 1 >= s.request.max_new_tokens:
+            # Its token in ``prev``, where it has a row there.
+            coming = int(ahead and s is prev.rows[i])
+            pending = s.first is not None
+            if len(s.tokens) + pending + coming >= s.request.max_new_tokens:
                 ending.append(i)
                 continue
             plan.rows[i] = s
-            if not ahead:
+            if pending and not coming:
+                plan.firsts.append((i, s.first.sel))
+            elif not ahead:
                 plan.tokens[i] = s.tokens[-1]
-            # ``position`` and ``steps`` move when a token lands: the
-            # step ahead is one past them.
-            plan.positions[i] = s.position + ahead
+            # ``position`` and ``steps`` move when a decode step's
+            # token lands: a row of the step ahead is one past them.
+            plan.positions[i] = s.position + coming
             plan.temps[i] = s.request.temperature
             plan.seeds[i] = s.request.seed
-            plan.steps[i] = s.steps + ahead
-        if ahead and not any(plan.rows):
+            plan.steps[i] = s.steps + coming
+        if not any(plan.rows):
             return None
-        for i in ending:
-            self.cache.free(i)
+        if ahead:
+            for i in ending:
+                self.cache.free(i)
         # Counted for the span alone, so outside what is read for time.
         if self._rec is not None:
             plan.pages_live = self.cache.pages_live()
@@ -1635,10 +1801,9 @@ class Engine:
         next dispatch's addressing counts the row this one writes; idle
         slots ride along and their output is discarded (idle rows write
         into the trash page)."""
-        tokens = plan.tokens
-        if not plan.ahead and self._token_sharding is not None:
-            # Put where the selection would have left them.
-            tokens = jax.device_put(tokens, self._token_sharding)
+        tokens = self._with_firsts(
+            plan.tokens, plan.firsts, from_device=plan.ahead
+        )
         # Tenant adapters ride the paged contract as three more
         # traced inputs.
         adapters = (
@@ -1664,8 +1829,8 @@ class Engine:
             # seated slots hold and the positions the step reads (lens
             # already counts the token it writes); whether attention
             # reads the pool in place, and the pages it then visits
-            # (``_paged_attrs``). ``ahead``: its input tokens came from
-            # the device.
+            # (``_paged_attrs``). ``ahead``: its input tokens were the
+            # selection of the step before, on the device.
             attrs = dict(
                 busy=sum(s is not None for s in rows),
                 rids=[s.request.request_id for s in rows if s is not None],
@@ -1674,71 +1839,108 @@ class Engine:
             )
         # What the program returned beside the logits is this step's
         # until the next dispatch overwrites it: kept with the step.
-        return _InFlight(rows, sel, list(self.cache.program_extras),
+        step = _InFlight(rows, sel, list(self.cache.program_extras),
                          plan.ahead, attrs)
+        self._unread.append(step)
+        return step
 
-    def _decode_step(self) -> None:
+    def _decode_step(self, span=None) -> None:
         """One LANDING: the oldest decode step the device holds is read
         back and its tokens emitted. With nothing in flight that step
-        is dispatched here first, from the host's tokens; and where
-        ``_prepare`` finds that the engine may run ahead, the step
-        after it is dispatched BEFORE the read-back and stays in flight
-        until the next call lands it, so that the read-back's wait, the
-        emit, whoever drives the engine, the next admission and the
-        next step's arrays all pass under a busy device.
+        is dispatched here first, from the host's tokens and the first
+        tokens the device still holds; and where ``_prepare`` finds
+        that the engine may run ahead, the step after it is dispatched
+        BEFORE the read-back and stays in flight until the next call
+        lands it, so that the read-back's wait, the emit, whoever
+        drives the engine, the next admission (seats and their
+        prefills included) and the next step's arrays all pass under a
+        busy device.
+
+        What the device holds unread lands in dispatch order, each
+        first token in a transfer of its own and never in one that
+        waits for a later program: the first tokens that precede the
+        step (seated by this call with nothing in flight: between
+        ``decode.dispatch`` and ``decode.readback``), the step, and,
+        once ``decode_step`` and ``emit`` are closed, the first tokens
+        that follow it (seated by this call behind the step). So no
+        prefill's device time lies in the ``decode_step`` of a call
+        that found a step in flight.
 
         The spans are the ones a step always had: ``decode_prepare``
-        (the host arrays of the call's first dispatch; a call that only
-        lands has none to make), ``decode_step`` around
-        ``decode.dispatch`` (every dispatch the call makes: of the step
-        it lands, when none was in flight, and of the step ahead, whose
-        arrays are then made inside it) and ``decode.readback`` (the
-        wait for the step that lands), then ``emit``. ``decode_step``'s
-        attributes describe the step that lands."""
+        (the host arrays of the call's first dispatch), ``decode_step``
+        around ``decode.dispatch`` (every dispatch the call makes: of
+        the step it lands, when none was in flight, and of the step
+        ahead, whose arrays are then made inside it) and
+        ``decode.readback`` (the wait for the step that lands), then
+        ``emit``. ``span``: the ``decode_step`` that ``step`` opened
+        before admission because a step was in flight, so that what is
+        left of that step on the device lies inside it;
+        ``decode_prepare`` is then its child. ``decode_step``'s
+        attributes describe the step that lands. A call whose seats
+        all asked for one token has no step to make: it lands them."""
         rec = self._rec
         prepare = None
         if rec is not None:
-            # The step's host arrays, a sibling before ``decode_step``.
+            # The step's host arrays: a sibling before ``decode_step``,
+            # or its child where that is open already.
             prepare = rec.begin(
                 "decode_prepare", CAT_SERVE_ENGINE, self.clock()
             )
-        land, self._in_flight = self._in_flight, None
-        plan = self._prepare(land)
+        prev = self._in_flight
+        plan = self._prepare(prev)
         t0 = self.clock()
-        span = dispatch = readback = None
+        dispatch = readback = None
         if rec is not None:
             prepare.end(t0, slots=self.num_slots)
-            span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
+        if prev is None and plan is None:
+            self._land_firsts()
+            return
+        if rec is not None:
+            if span is None:
+                span = rec.begin("decode_step", CAT_SERVE_DECODE, t0)
             dispatch = rec.begin("decode.dispatch", CAT_SERVE_DECODE, t0)
-        if land is None:
-            land = self._dispatch(plan)
-            plan = self._prepare(land)
+        if prev is None:
+            plan = self._prepare(self._dispatch(plan))
         if plan is not None:
-            self._in_flight = self._dispatch(plan)
+            self._dispatch(plan)
+        t = None
         if dispatch is not None:
             # Every dispatch of the call has returned; what is left of
             # decode_step is the wait for the device and the copy back.
             t = self.clock()
             dispatch.end(t)
+        t = self._land_firsts(t)
+        if dispatch is not None:
             readback = rec.begin("decode.readback", CAT_SERVE_DECODE, t)
-        self._land(land, span, readback)
+        # The head of the queue is now the oldest decode step.
+        t = self._land(self._unread.popleft(), span, readback)
+        self._land_firsts(t)
 
     def land(self) -> None:
-        """Read back and emit the decode step in flight, if there is
-        one. ``step`` lands it by itself; whoever touches a slot from
-        outside ``step`` (an export, an install, a drain) calls this
-        first, so that no token the device has computed is lost."""
-        step, self._in_flight = self._in_flight, None
-        if step is not None:
-            self._land(step)
+        """Read back and emit everything the device holds unread, in
+        dispatch order: first tokens and the decode step in flight, if
+        there are any. ``step`` lands them by itself; whoever touches a
+        slot from outside ``step`` (an export, an install, a drain)
+        calls this first, so that no token the device has computed is
+        lost and no slot is met without its first token."""
+        unread = self._unread
+        while unread:
+            item = unread.popleft()
+            if isinstance(item, _First):
+                self._land_first(item)
+            else:
+                self._land(item)
 
-    def _land(self, step: _InFlight, span=None, readback=None) -> None:
+    def _land(self, step: _InFlight, span=None,
+              readback=None) -> Optional[float]:
         """Read ``step``'s tokens back and emit them, in order: each
         goes to the slot whose row computed it if that slot still holds
         the same request (one that ended meanwhile, by ``eos_id`` a
-        step before, gets nothing, nor would whoever was seated there
-        since). ``span`` / ``readback``: the open ``decode_step`` and
-        ``decode.readback`` spans of a landing inside ``step``."""
+        step or a first token before, gets nothing, nor would whoever
+        was seated there since). ``span`` / ``readback``: the open
+        ``decode_step`` and ``decode.readback`` spans of a landing
+        inside ``step``, which returns the clock reading that ended its
+        ``emit``."""
         # Explicit readback (jax.device_get, not an implicit
         # np.asarray): the per-step token sync is the ONE intended
         # d2h in the decode steady state, and the dispatch-hygiene
@@ -1784,8 +1986,11 @@ class Engine:
                 self.on_token(s.request.request_id, tok)
             self._maybe_finish(i, tok)
             finished += self._slots[i] is not s
-        if emit is not None:
-            emit.end(self.clock(), finished=finished)
+        if emit is None:
+            return None
+        t = self.clock()
+        emit.end(t, finished=finished)
+        return t
 
     def _spec_step(self) -> None:
         """One speculative window: k draft dispatches propose, ONE
@@ -1937,14 +2142,14 @@ class Engine:
 
     def step(self) -> bool:
         """Seat what fits, land one decode step (``_decode_step``; a
-        speculative window when a speculator is attached). False when
-        fully drained (no active slots, no step in flight and nothing
-        seatable queued)."""
+        speculative window when a speculator is attached) and the first
+        tokens around it. False when fully drained (no active slots,
+        nothing the device holds unread and nothing seatable queued)."""
         # One look for the recorder a step; what the step calls reads
         # ``self._rec``. With none, nothing below reads a clock or
         # allocates for tracing.
         rec = self._rec = active_recorder()
-        span = None
+        span = decode = None
         if rec is not None:
             span = rec.begin("engine_step", CAT_SERVE_ENGINE, self.clock())
             self._seats = 0
@@ -1955,18 +2160,28 @@ class Engine:
                 # like a real engine fault), a freeze hook sleeps here
                 # holding the whole loop (the stale-heartbeat path).
                 hook(self.num_decode_steps)
+            if rec is not None and self._unread:
+                # A decode step is in flight and this call lands it:
+                # its ``decode_step`` opens before the admission, whose
+                # dispatches queue behind that step on the device.
+                decode = rec.begin(
+                    "decode_step", CAT_SERVE_DECODE, self.clock()
+                )
             self._fill_slots()
-            if self._in_flight is None and not self._active():
+            if not self._unread and not self._active():
                 # Nothing seated: the queue is empty or held only
                 # expired entries (shed during the fill's pop).
                 self._record_shed(
                     self.queue.drain_expired(), "shed_timeout"
                 )
                 return False
-            if self.speculator is not None:
-                self._spec_step()
+            if self.speculator is None:
+                self._decode_step(decode)
             else:
-                self._decode_step()
+                # Acceptance needs every slot's last token on the host.
+                self._land_firsts()
+                if self._active():
+                    self._spec_step()
             return True
         finally:
             if span is not None:
