@@ -1,15 +1,28 @@
 """Time the two dispatch forms of ``tpudl.ops.moe.DroplessMoE`` on the
 chip, one layer at a time, at the row counts a serving program traces
-it with (PERF.md records the table; ISSUE 30: 256 experts of width 512
-over a hidden size of 2,048, 8 a token, at 64 decode rows and at a
-4,096-row prefill).
+it with, and the sorted form's three grouped matmuls ALONE on sorted
+rows of the same shape, once through ``jax.lax.ragged_dot`` and once
+through the kernel of ``tpudl.ops.grouped_matmul`` called directly.
+PERF.md records the tables: ISSUE 30, 256 experts of width 512 over a
+hidden size of 2,048, 8 a token (the defaults; Laguna's shape), at 64
+decode rows and at a 4,096-row prefill; ISSUE 42, that shape and
+xing4's at the two prefill lengths:
 
     chiprun -- python scripts/moe_dispatch_times.py [--rows 64 512 4096]
+    chiprun -- python scripts/moe_dispatch_times.py --experts 64 \
+        --per-token 4 --hidden 3584 --width 1024 --rows 2048 4096
+    chiprun -- python scripts/moe_dispatch_times.py --experts 256 \
+        --per-token 8 --hidden 2048 --width 512 --rows 2048 4096
 
-Prints one JSON line a (rows, form): milliseconds a call, median of
-``--repeats`` timed calls after a warm-up, the call blocked on. A form
-that does not fit the chip at a row count says so and goes on. Refuses
-to run without a TPU: a time from a CPU is not a device time.
+Prints one JSON line a (rows, form) and a (rows, grouped matmul):
+milliseconds a call, median of ``--repeats`` timed calls after a
+warm-up, the call blocked on. The layer takes the kernel or
+``ragged_dot`` by its own rule (``grouped_kernel`` in the line says
+which); the matmuls alone are gate and up over the sorted rows, ``silu
+(gate) * up``, and down with a float32 result, over the groups a
+top-k of random scores gives. A form that does not fit the chip at a
+row count says so and goes on. Refuses to run without a TPU: a time
+from a CPU is not a device time.
 """
 
 from __future__ import annotations
@@ -22,6 +35,26 @@ import sys
 import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+
+
+def _timed(call, repeats):
+    """``call()`` once to compile, then timed: median and least ms."""
+    call().block_until_ready()
+    times = []
+    for _ in range(repeats):
+        t = time.perf_counter()
+        call().block_until_ready()
+        times.append(1e3 * (time.perf_counter() - t))
+    return {"ms": statistics.median(times), "ms_min": min(times)}
+
+
+def _report(line, call, repeats, note=lambda: {}):
+    try:
+        line.update(_timed(call, repeats))
+    except Exception as e:  # does not fit, or does not compile
+        line["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+    line.update(note())
+    print(json.dumps(line), flush=True)
 
 
 def main(argv=None) -> int:
@@ -37,11 +70,14 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from tpudl.ops.moe import DroplessMoE
+    from tpudl.obs import registry
+    from tpudl.ops.grouped_matmul import grouped_matmul
+    from tpudl.ops.moe import SORTED_DISPATCH_ROWS, DroplessMoE
 
     device = jax.devices()[0]
     if device.platform != "tpu":
         sys.exit(f"moe_dispatch_times: needs a TPU, JAX found {device}")
+    took_kernel = registry().counter("serve_moe_grouped_kernel")
     for rows in args.rows:
         x = jax.random.normal(
             jax.random.key(rows), (1, rows, args.hidden), jnp.bfloat16
@@ -67,18 +103,46 @@ def main(argv=None) -> int:
                 {"params": p}, x, real, mutable=["moe_stats"]
             )[0])
             line = {"rows": rows, "form": form, "device": device.device_kind}
-            try:
-                call(params, x).block_until_ready()
-                times = []
-                for _ in range(args.repeats):
-                    t = time.perf_counter()
-                    call(params, x).block_until_ready()
-                    times.append(1e3 * (time.perf_counter() - t))
-                line["ms"] = statistics.median(times)
-                line["ms_min"] = min(times)
-            except Exception as e:  # does not fit, or does not compile
-                line["error"] = f"{type(e).__name__}: {str(e)[:200]}"
-            print(json.dumps(line), flush=True)
+            before = took_kernel.value
+            _report(
+                line, lambda: call(params, x), args.repeats,
+                lambda: {"grouped_kernel": took_kernel.value > before},
+            )
+        if rows <= SORTED_DISPATCH_ROWS:
+            continue  # no served program sorts so few rows
+        # The three grouped matmuls alone, on rows that lie sorted.
+        keys = jax.random.split(jax.random.key(rows + 1), 3)
+        _, chosen = jax.lax.top_k(
+            jax.random.normal(keys[0], (rows, args.experts)), args.per_token
+        )
+        sizes = jnp.zeros((args.experts,), jnp.int32).at[
+            chosen.reshape(-1)
+        ].add(1)
+        sorted_rows = jax.random.normal(
+            keys[1], (rows * args.per_token, args.hidden), jnp.bfloat16
+        )
+        experts = params["gate_proj"], params["up_proj"], params["down_proj"]
+        wg, wu, wd = (e["kernel"] for e in experts)
+        for name, grouped in (
+            ("ragged_dot", jax.lax.ragged_dot), ("kernel", grouped_matmul)
+        ):
+            def three(lhs, wg, wu, wd, sizes, grouped=grouped):
+                act = jax.nn.silu(grouped(lhs, wg, sizes)) * grouped(
+                    lhs, wu, sizes
+                )
+                return grouped(
+                    act, wd, sizes, preferred_element_type=jnp.float32
+                )
+
+            call = jax.jit(three)
+            line = {
+                "rows": rows, "assignments": rows * args.per_token,
+                "grouped_matmuls": name, "device": device.device_kind,
+            }
+            _report(
+                line, lambda: call(sorted_rows, wg, wu, wd, sizes),
+                args.repeats,
+            )
     return 0
 
 

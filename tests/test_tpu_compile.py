@@ -186,6 +186,21 @@ def _paged_attention(chunk):
     )
 
 
+def _grouped_matmul(experts, per_token, k, n, result, rows=4096):
+    """One grouped matmul of a sorted expert layer over a 4,096-row
+    prefill's assignments: gate / up (``k`` the hidden size, a bfloat16
+    result) or down (``k`` the experts' width, a float32 result)."""
+    from tpudl.ops.grouped_matmul import grouped_matmul
+
+    fn = lambda lhs, rhs, sizes: grouped_matmul(  # noqa: E731
+        lhs, rhs, sizes, result, interpret=False
+    )
+    return fn, (
+        _s((rows * per_token, k), bf16), _s((experts, k, n), bf16),
+        _s((experts,), i32),
+    )
+
+
 CASES = {
     # BERT-base, b256 s128
     "bert/layer_norm+residual": _layer_norm_residual,
@@ -205,6 +220,16 @@ CASES = {
     # Mistral-7B-v0.3, the serving cells' pool
     "mistral/paged_attention-decode": lambda: _paged_attention(1),
     "mistral/paged_attention-verify": lambda: _paged_attention(3),
+    # The sorted experts of xing4 (64 of [3584, 1024], 4 a token) and
+    # of Laguna (256 of [2048, 512], 8 a token)
+    "xing4/moe_grouped_matmul-gate": lambda: _grouped_matmul(
+        64, 4, 3584, 1024, bf16),
+    "xing4/moe_grouped_matmul-down": lambda: _grouped_matmul(
+        64, 4, 1024, 3584, f32),
+    "laguna/moe_grouped_matmul-gate": lambda: _grouped_matmul(
+        256, 8, 2048, 512, bf16),
+    "laguna/moe_grouped_matmul-down": lambda: _grouped_matmul(
+        256, 8, 512, 2048, f32),
 }
 
 
@@ -569,6 +594,29 @@ def test_latent_decode_program_reads_the_pool_in_place_on_v5e(
 # [256, 4096, 512] expert tensors 2.1 GB).
 
 
+def _on_one_chip(monkeypatch):
+    """The sorted experts' rule (tpudl.ops.grouped_matmul) answered as
+    the chip machine answers it: a TPU backend of one device."""
+    import tpudl.ops.grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(gm, "one_device", lambda: True)
+
+
+def _grouped_kernel_calls(text: str) -> int:
+    """The calls of the grouped-matmul kernel in a compiled program's
+    text, each inside the scope ``experts``; no ``ragged-dot`` is left
+    beside them."""
+    calls = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and "moe_grouped_matmul" in line
+    ]
+    assert all("/experts/" in line for line in calls)
+    assert "ragged-dot" not in text
+    return len(calls)
+
+
 def _window_moe_session():
     import json
 
@@ -612,6 +660,7 @@ def test_window_moe_program_compiles_for_v5e(
         pytest.skip("this installation cannot describe a v5e topology")
     for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
         monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    _on_one_chip(monkeypatch)
     on_chip = SingleDeviceSharding(device)
     sess, model, params, session = _window_moe_session()
     slots, page = sess["num_slots"], sess["page_size"]
@@ -623,6 +672,9 @@ def test_window_moe_program_compiles_for_v5e(
         memory = compiled.memory_analysis()
         assert memory.temp_size_in_bytes < 1.5e9
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+        # The rule has no shape threshold: the three grouped matmuls
+        # of the four expert layers are the kernel, as in xing4's.
+        assert _grouped_kernel_calls(compiled.as_text()) == 3 * 4
         return
     cache = session.engine.cache
     assert cache.ring_pages == 33
@@ -812,7 +864,7 @@ def _hyper_mla_moe_session():
     return sess, model, params, session
 
 
-@pytest.mark.parametrize("name", ["decode", "prefill"])
+@pytest.mark.parametrize("name", ["decode", "prefill", "prefill_4096"])
 def test_hyper_mla_moe_program_compiles_for_v5e(
     name, monkeypatch, no_compile_cache
 ):
@@ -828,6 +880,7 @@ def test_hyper_mla_moe_program_compiles_for_v5e(
         pytest.skip("this installation cannot describe a v5e topology")
     for module in (tpudl.ops.attention, tpudl.ops.paged_attention):
         monkeypatch.setattr(module, "is_tpu_backend", lambda: True)
+    _on_one_chip(monkeypatch)
     on_chip = SingleDeviceSharding(device)
     sess, model, params, session = _hyper_mla_moe_session()
     slots, page = sess["num_slots"], sess["page_size"]
@@ -836,20 +889,27 @@ def test_hyper_mla_moe_program_compiles_for_v5e(
         for leaf in jax.tree.leaves(params)
     )
     assert 9.59e9 < weights < 9.61e9
-    if name == "prefill":
-        ids = _s((1, 2048), i32, sharding=on_chip)
+    if name.startswith("prefill"):
+        rows = 4096 if name == "prefill_4096" else 2048
+        ids = _s((1, rows), i32, sharding=on_chip)
         compiled = jax.jit(prefill_fn(model)).lower(
             _placed(params, on_chip), ids, ids
         ).compile()
         memory = compiled.memory_analysis()
         # Beside the pools (1.93 GB) on a chip of 16 GB.
+        assert memory.temp_size_in_bytes < 1.5e9
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 11e9
         text = compiled.as_text()
         # No score matrix of the whole prompt, at any precision: a
         # block of 256 queries meets the keys up to its own end.
-        assert ",2048,2048]" not in text
-        for keys in range(256, 2049, 256):
+        # (At 4,096 rows a weight has that shape: the heads name it.)
+        assert not re.search(rf"32,(?:1,)?{rows},{rows}\]", text)
+        assert rows == 4096 or ",2048,2048]" not in text
+        for keys in range(256, rows + 1, 256):
             assert f"f32[1,32,1,256,{keys}]" in text
+        # The three grouped matmuls of the five expert layers are the
+        # kernel, at either prefill length.
+        assert _grouped_kernel_calls(text) == 3 * 5
         return
     cache = session.engine.cache
     table_pages = sess["max_seq_len"] // page

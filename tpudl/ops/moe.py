@@ -36,6 +36,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpudl.ops.grouped_matmul import grouped_kernel_ok, grouped_matmul
 from tpudl.parallel.sharding import constrain
 
 P = jax.sharding.PartitionSpec
@@ -256,11 +257,15 @@ class DroplessMoE(nn.Module):
       grow with rows x held experts. An identity id has no column.
     - ``"sorted"``: the ``tokens x experts_per_token`` assignments are
       sorted by expert, the rows gathered in that order, and the three
-      projections run as ragged grouped matmuls over the sorted groups
-      (``jax.lax.ragged_dot``): each row meets only the experts it
-      chose. A choice of an expert held elsewhere, or of an identity
-      expert, sorts behind the last group and is left out. The results
-      go back to token order and are summed by token in float32.
+      projections run as ragged grouped matmuls over the sorted groups:
+      each row meets only the experts it chose. On one TPU device, for
+      unquantized bfloat16 experts of whole-lane widths, they are the
+      Pallas kernel of tpudl.ops.grouped_matmul (``grouped_kernel_ok``
+      decides from what the traced program can observe; counter
+      ``serve_moe_grouped_kernel``), else ``jax.lax.ragged_dot``: the
+      same arithmetic. A choice of an expert held elsewhere, or of an
+      identity expert, sorts behind the last group and is left out. The
+      results go back to token order and are summed by token in float32.
 
     The dense form does ``rows`` operations for every byte of weights
     it streams, so it costs one pass of the weights up to about 240
@@ -400,8 +405,12 @@ class DroplessMoE(nn.Module):
                         1
                     )[:count]
                     rows = tokens[order // k]  # [T * k, m]
-                    gate = jax.lax.ragged_dot(rows, wg, sizes)
-                    up = jax.lax.ragged_dot(rows, wu, sizes)
+                    grouped = jax.lax.ragged_dot
+                    if grouped_kernel_ok(rows, (wg, wu, wd), (sg, su, sd)):
+                        grouped = grouped_matmul
+                        registry().counter("serve_moe_grouped_kernel").inc()
+                    gate = grouped(rows, wg, sizes)
+                    up = grouped(rows, wu, sizes)
                     weight = gates.reshape(-1)[order, None]  # [T * k, 1]
                 if sg is not None:
                     gate = gate * sg.astype(gate.dtype)
@@ -414,7 +423,7 @@ class DroplessMoE(nn.Module):
                         preferred_element_type=jnp.float32,
                     )
                 else:
-                    out = jax.lax.ragged_dot(
+                    out = grouped(
                         act, wd, sizes, preferred_element_type=jnp.float32
                     )
                     # Rows past the groups are no expert's: left out.
