@@ -210,7 +210,7 @@ def main():
     logger = MetricLogger(args.log_dir) if args.log_dir else None
     rng = jax.random.key(cfg.seed + 1)
     # Warmup fit absorbs compile so the throughput print is steady-state
-    # (the repo-wide timing doctrine — bench.py). islice hands fit exactly
+    # (the repo-wide timing doctrine). islice hands fit exactly
     # `warmup` items: fit's own num_steps break would pull (and discard)
     # one extra batch from the shared generator.
     state, _, _ = fit(step, state, itertools.islice(batches, warmup), rng)

@@ -24,34 +24,6 @@ if [[ "${1:-}" == "--lint-only" ]]; then
     exit 0
 fi
 
-if [[ "${1:-}" == "--precision" ]]; then
-    # Mixed-precision training smoke: tiny fixed-seed bf16-vs-f32 (and
-    # fp8) parity run — the loss-parity gates assert inside the sweep.
-    echo "== precision smoke (bf16/fp8 train-step loss parity vs f32)"
-    JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.train_precision --smoke
-    exit 0
-fi
-
-echo "== precision smoke (bf16 train-step loss parity vs f32)"
-JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.train_precision \
-    --smoke --cells f32,bf16 > /dev/null
-
-echo "== multi-tenant smoke (adapter pool + segmented-LoRA batched decode)"
-JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.serve_load \
-    --tenants --tenants-adapters 8 --requests 4 > /dev/null
-
-echo "== request-log smoke (durable JSONL round-trip + per-tenant token reconciliation)"
-JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.serve_load \
-    --requestlog --requests 4 > /dev/null
-
-echo "== flywheel smoke (samples on -> one LoRA refresh -> safe hot-swap asserted)"
-JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.serve_load \
-    --flywheel --requests 8 > /dev/null
-
-echo "== fleet smoke (mesh replicas + reshard-restore + chip mover end-to-end)"
-JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m benchmarks.fleet_mesh \
-    --smoke --json > /dev/null
-
 echo "== chaos smoke (serving fault injection: migration, failover, drains)"
 JAX_PLATFORMS=${JAX_PLATFORMS:-cpu} python -m pytest tests/ -q -m 'chaos and not slow' \
     -p no:cacheprovider

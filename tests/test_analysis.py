@@ -315,7 +315,7 @@ def test_env_write_and_non_tpudl_keys_pass():
         """
         import os
         def ok():
-            os.environ["TPUDL_NORM_BLOCK_ROWS"] = "64"   # a WRITE: pins
+            os.environ["TPUDL_SERVE_PAGE_SIZE"] = "64"   # a WRITE: pins
             flags = os.environ.get("XLA_FLAGS", "")
             return flags
         """
@@ -541,10 +541,10 @@ def test_serving_decode_steady_state_is_dispatch_clean():
     neither recompiles nor implicitly transfers (beyond the per-step
     h2d control arrays, which are by design — every intended readback
     in the engine is an explicit jax.device_get)."""
-    from benchmarks.serve_load import build_session, warmup_session
+    from tests.serve_helpers import build_session, warmup_session
     from tpudl.serve import Request
 
-    session, _, _ = build_session(num_slots=2)
+    session = build_session(num_slots=2)
     warmup_session(session)
     steps0 = session.engine.num_decode_steps
     # 52 new tokens = 1 from prefill + 51 decode steps: the audited

@@ -8,11 +8,12 @@ actions, and min/max bounds hold. The drain contract runs against a
 REAL two-replica router: removing a replica that owns in-flight work
 must deliver every Result (generate()-parity intact) before the
 replica disappears. The end-to-end acceptance (overload -> fleet
-burn -> scale-up -> recovery with zero shed_slo -> idle drain) rides
-benchmarks/serve_load.run_autoscale_recovery with test-sized load."""
+burn -> scale-up -> recovery with zero shed_slo -> idle drain) runs
+last, at test-sized load, with sessions from tests/serve_helpers.py."""
 
 import time
 import types
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,16 @@ import numpy as np
 import pytest
 
 import tpudl.obs as obs
+from tests.serve_helpers import (
+    build_programs,
+    make_requests,
+    session_from_programs,
+    warmup_session,
+)
 from tpudl.obs import counters as obs_counters
 from tpudl.obs import exporter as obs_exporter
+from tpudl.obs.fleet import FleetMonitor
+from tpudl.obs.slo import Objective, SloMonitor
 from tpudl.models.generate import generate
 from tpudl.models.llama import LLAMA_TINY, LlamaForCausalLM
 from tpudl.serve import (
@@ -389,30 +398,156 @@ def test_remove_replica_timeout_restores_service(model_and_params):
 
 # ---------------------------------------------------------------------------
 # End-to-end acceptance: overload -> burn -> scale-up -> recovery ->
-# idle drain (rides the benchmark scenario at test-sized load)
+# idle drain
 # ---------------------------------------------------------------------------
 
 
-def test_autoscale_acceptance_end_to_end(tmp_path):
-    from benchmarks.serve_load import run_autoscale_recovery
+def _slowed(call, seconds):
+    """The scenario's load shaper: a compiled call that then sleeps.
+    A CPU engine over a tiny model cannot be overloaded by a paced
+    arrival stream otherwise, and the sleep releases the GIL, so the
+    replica threads overlap as separate meshes would. It shapes load:
+    no time it causes is reported or compared with anything."""
 
+    def wrapped(*args):
+        out = call(*args)
+        jax.block_until_ready(out)
+        time.sleep(seconds)
+        return out
+
+    return wrapped
+
+
+def _submit_paced(router, requests, rate, rng, tick=lambda: None):
+    """Open-loop arrivals at ``rate`` requests/s (exponential gaps)."""
+    arrivals = np.cumsum(rng.exponential(1.0 / rate, size=len(requests)))
+    t0 = time.perf_counter()
+    for request, due in zip(requests, arrivals):
+        lag = due - (time.perf_counter() - t0)
+        if lag > 0:
+            time.sleep(lag)
+        router.submit(request)
+        tick()
+
+
+def test_autoscale_acceptance_end_to_end(tmp_path):
+    """2x-capacity open-loop overload on a 2-replica fleet with
+    per-replica TTFT monitors and a FleetMonitor over the process's
+    live telemetry -> the burn sustains -> the Autoscaler adds a
+    replica (spawned over the SAME compiled programs: scale-up costs
+    no compilation) -> the burn clears, and traffic after it sees ZERO
+    shed_slo -> sustained idle drains the fleet back to 2 with every
+    Result delivered and generate() parity intact."""
     obs.enable(str(tmp_path / "obs"))  # the fleet trace rides along
-    out = run_autoscale_recovery(
-        num_replicas=2,
-        max_replicas=3,
-        offered_rate=250.0,
-        n_requests=90,
-        recovery_rate=50.0,
-        n_recovery_requests=20,
-        sim_step_ms=4.0,
-        check=True,  # every acceptance assert lives in the scenario
-    )
-    assert out["scale_ups"] == 1 and out["scale_downs"] == 1
-    assert out["replicas_final"] == 2
-    assert out["fleet_burned"] is True
-    assert out["autoscale_recovery_s"] is not None
-    assert out["post_scale_up"]["finish_reasons"].get("shed_slo", 0) == 0
-    assert out["parity_ok"] is True
+    n_overload, n_after = 90, 20
+    programs = build_programs(num_slots=4)
+    warmup_session(session_from_programs(programs))
+    monitors = []
+
+    def make_replica(name):
+        # Alert on 0.6 x a 300 ms objective (the tighter internal bar:
+        # burn detection needs violations to fire).
+        monitor = SloMonitor([Objective(
+            name=f"ttft_{name}", metric="serve_ttft_ms", threshold=180.0,
+            quantile=0.95, window_s=4.0, fast_window_s=0.5, min_count=3,
+        )])
+        monitors.append(monitor)
+        # Deep queues: capacity sheds must not be the relief valve.
+        session = session_from_programs(
+            programs, slo=monitor, queue_capacity=4 * n_overload
+        )
+        engine = session.engine
+        engine.prefill_call = _slowed(engine.prefill_call, 0.004)
+        engine.decode_call = _slowed(engine.decode_call, 0.004)
+        return Replica(name, session)
+
+    exporter = obs_exporter.ObsExporter(port=0).start()
+    fleet = FleetMonitor({"serving": exporter.snapshot}, scrape_interval_s=0.1)
+    rng = np.random.default_rng(0)
+    results = {}
+    try:
+        with Router([make_replica(f"r{i}") for i in range(2)]) as router:
+            scaler = Autoscaler(
+                router, make_replica,
+                AutoscaleConfig(
+                    min_replicas=2, max_replicas=3, up_sustain_s=0.2,
+                    down_sustain_s=0.5, cooldown_s=1.0, idle_busy_frac=0.05,
+                ),
+                fleet=fleet,
+            )
+            # -- phase 1: overload. The control loop ticks THROUGHOUT:
+            # per arrival while submitting, then per poll while the
+            # backlog drains — the burn peaks during the drain, which
+            # is exactly when the scale-up must fire.
+            fleet_burned = set()
+
+            def tick():
+                scaler.evaluate()
+                if not fleet_burned:
+                    # The fleet plane's own confirmation of the burn.
+                    fleet_burned.update(fleet.burning_sources())
+
+            _submit_paced(
+                router,
+                make_requests(n_overload, seed=0, best_effort_every=3),
+                250.0, rng, tick,
+            )
+            deadline = time.perf_counter() + 600.0
+            while len(results) < n_overload:
+                assert time.perf_counter() < deadline
+                results.update(router.poll())
+                tick()
+                time.sleep(0.002)
+            assert scaler.num_scale_ups == 1, (
+                f"overload never triggered a scale-up ({scaler.history})"
+            )
+            assert fleet_burned
+            # -- the burn clears after the scale-up.
+            deadline = time.perf_counter() + 30.0
+            while any(m.burning_names() for m in monitors):
+                assert time.perf_counter() < deadline, (
+                    "the SLO burn never cleared after scale-up"
+                )
+                time.sleep(0.02)
+            # -- phase 2: traffic after the scale-up is not shed (a rate
+            # the three replicas hold even where five other pytest
+            # workers share the machine's cores).
+            _submit_paced(
+                router,
+                make_requests(
+                    n_after, seed=1, best_effort_every=3, tag="p2-"
+                ),
+                20.0, rng,
+            )
+            after = router.collect(timeout_s=600.0)
+            results.update(after)
+            reasons = Counter(r.finish_reason for r in after.values())
+            assert reasons["shed_slo"] == 0, (
+                f"post-scale-up traffic still shed on SLO burn "
+                f"({dict(reasons)}): the added replica did not relieve "
+                f"the overload"
+            )
+            # -- phase 3: sustained idle -> drain-then-remove.
+            deadline = time.perf_counter() + 60.0
+            while scaler.num_scale_downs < scaler.num_scale_ups:
+                assert time.perf_counter() < deadline, (
+                    "sustained idle never drained the scaled-up replica"
+                )
+                scaler.evaluate()
+                time.sleep(0.05)
+            assert (scaler.num_scale_ups, scaler.num_scale_downs) == (1, 1)
+            assert router.load_report()["active_replicas"] == 2
+            assert len(results) == n_overload + n_after, (
+                "a drain lost in-flight work"
+            )
+            # -- the shrunk fleet still serves generate()'s tokens.
+            parity = make_requests(4, seed=2, tag="parity-")
+            _assert_generate_parity(
+                programs["model"], programs["params"], parity,
+                router.serve(parity, timeout_s=600.0),
+            )
+    finally:
+        exporter.close()
     # The recorded stream stitches into a fleet report that shows the
     # membership churn.
     from tpudl.obs import report as obs_report

@@ -19,9 +19,8 @@ Correctness bars, all on the fake 8-device CPU host
   corrupted draft would change which proposals are made and therefore
   which uniforms are consumed);
 - the chip mover's hysteresis tick moves devices training -> serving
-  -> training with sustain windows and cooldown honored (fake clock;
-  the end-to-end scenario with a real trainer and router runs in
-  ``benchmarks.fleet_mesh`` / the ci_check fleet smoke stage).
+  -> training with sustain windows and cooldown honored (fake clock),
+  and the same two moves end to end with a real trainer and router.
 """
 
 import dataclasses
@@ -67,7 +66,13 @@ from tpudl.models.resnet import ResNetTiny
 from tpudl.obs import registry
 from tpudl.parallel.sharding import FSDP_RULES
 from tpudl.runtime.mesh import MeshSpec
-from tpudl.serve import MigrationCompatError, Request, Router, ServeSession
+from tpudl.serve import (
+    MigrationCompatError,
+    Replica,
+    Request,
+    Router,
+    ServeSession,
+)
 from tpudl.serve.cache import PagedKVCache
 from tpudl.train import create_train_state, make_classification_train_step
 
@@ -306,6 +311,92 @@ def test_chipmover_config_rejects_full_loan():
     with pytest.raises(ValueError, match="serve_share"):
         ChipMoverConfig(burn_sustain_s=1, clear_sustain_s=1,
                         cooldown_s=0, serve_share=1.0)
+
+
+def test_chipmover_end_to_end_real_trainer_and_router(
+    model_and_params, monkeypatch
+):
+    """Both moves with nothing faked but the burn signal: sustained
+    burn -> the training cohort is preempted (the SIGTERM protocol)
+    and reshard-restored on fewer devices -> a borrowed MeshReplica
+    serves on the freed ones -> burn clear -> the borrowed replica
+    drains and training grows back. No request of any wave is
+    dropped or failed, and the trainer went through both restarts."""
+    from tpudl.data import synthetic_classification_batches
+
+    # The grace window's watchdog is os._exit: on a loaded machine the
+    # cohort may still be compiling its step when the signal lands, and
+    # the default 15 s would take this pytest worker down with it.
+    monkeypatch.setenv("TPUDL_FT_GRACE_S", "900")
+    model, params = model_and_params
+
+    def make_batches():
+        return synthetic_classification_batches(
+            8, image_shape=(16, 16, 3), num_classes=4,
+            num_batches=2000, seed=7,
+        )
+
+    def spawn_replica(name, devices):
+        return MeshReplica(
+            name, model=model, params=params, prompt_len=PROMPT_LEN,
+            devices=devices, session_kwargs={"num_slots": 2},
+        )
+
+    burn = {"on": False}
+    results = {}
+    with tempfile.TemporaryDirectory() as ckpt_dir:
+        trainer = ElasticTrainer(
+            _resnet_state, make_classification_train_step(), make_batches,
+            AsyncCheckpointManager(ckpt_dir), jax.devices(),
+            total_steps=100_000, checkpoint_every=25,
+        )
+        r0 = Replica("r0", ServeSession.from_model(
+            model, params, PROMPT_LEN, num_slots=2
+        ))
+        try:
+            with Router([r0]) as router:
+                mover = ChipMover(
+                    router, trainer.start(), spawn_replica,
+                    ChipMoverConfig(
+                        burn_sustain_s=0.1, clear_sustain_s=0.1,
+                        cooldown_s=0.0,
+                    ),
+                    burn_fn=lambda: burn["on"],
+                )
+
+                def tick_until(state):
+                    deadline = time.monotonic() + 600.0
+                    while mover.state != state:
+                        mover.evaluate()
+                        assert time.monotonic() < deadline, (
+                            f"chip mover never reached {state!r}"
+                        )
+                        time.sleep(0.02)
+
+                def wave(tag):
+                    results.update(router.serve(
+                        _greedy_requests(2, seed=2, tag=tag),
+                        timeout_s=600.0,
+                    ))
+
+                wave("full")
+                burn["on"] = True
+                tick_until("borrowed")
+                assert len(router.replicas) == 2
+                wave("lent")
+                burn["on"] = False
+                tick_until("training_full")
+                assert len(router.replicas) == 1
+                wave("back")
+        finally:
+            trainer.close()
+    assert len(results) == 6
+    assert all(r.ok for r in results.values()), {
+        k: r.finish_reason for k, r in results.items()
+    }
+    assert mover.moves == 2
+    assert trainer.restarts >= 2
+    assert len(set(trainer.mesh_shapes)) == 2  # shrunk, then grown back
 
 
 # ---------------------------------------------------------------------------

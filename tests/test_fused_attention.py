@@ -3,8 +3,7 @@
 CPU tier (interpret mode): exact-shape parity for every masking mode at
 dropout 0 — the PRNG-backed dropout paths are TPU-only (interpret mode
 has no PRNG emulation; asserted here) and get their statistical checks
-on the real chip via benchmarks/bert_attn_seq128.py and the TPU
-subprocess check in scripts/tpu_dropout_check.py.
+on the real chip via scripts/tpu_dropout_check.py.
 """
 
 import jax

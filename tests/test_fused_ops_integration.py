@@ -267,7 +267,7 @@ def test_bert_param_tree_identical_across_modes():
 
 
 def test_block_size_overrides_preserve_parity():
-    """The --sweep-blocks knobs (norms.BLOCK_ROWS_OVERRIDE /
+    """The block-size seams (norms.BLOCK_ROWS_OVERRIDE /
     cross_entropy.VOCAB_BLOCK_OVERRIDE) change only the kernel grid:
     fused outputs at a non-default block size still match the
     composite references (interpret mode on CPU)."""
@@ -306,16 +306,3 @@ def test_block_size_overrides_preserve_parity():
             layer_norm(x, scale, bias, impl="fused")
     finally:
         norms_mod.BLOCK_ROWS_OVERRIDE = None
-
-
-def test_fused_epilogue_sweep_blocks_smoke():
-    """benchmarks/fused_epilogue.py --sweep-blocks finds a best block
-    per family at smoke shapes (CPU interpret mode) and restores the
-    heuristic (override None) afterwards."""
-    from benchmarks.fused_epilogue import main as bench_main
-    from tpudl.ops import cross_entropy as ce_mod
-    from tpudl.ops import norms as norms_mod
-
-    bench_main(["--sweep-blocks", "--smoke"])
-    assert norms_mod.BLOCK_ROWS_OVERRIDE is None
-    assert ce_mod.VOCAB_BLOCK_OVERRIDE is None

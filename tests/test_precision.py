@@ -51,7 +51,7 @@ _CFG = dict(
     dtype=jnp.float32, hidden_dropout=0.0, attention_dropout=0.0,
 )
 
-#: The benchmark's documented bands (benchmarks/train_precision.py).
+#: Loss-parity bands against the f32 run (bf16 / fp8 train steps).
 BF16_BAND = 0.03
 FP8_BAND = 0.08
 
@@ -498,8 +498,7 @@ def test_state_payloads_carry_precision(runs):
 
 def test_moment_rules_bitwise_match_optax_mu_dtype():
     """apply_moment_rules is numerically optax's mu_dtype: same stored
-    dtypes, same values, bit for bit — benchmarks/bert_mu_dtype.py's
-    drift gate."""
+    dtypes, same values, bit for bit."""
     params = {
         "a": {"kernel": jnp.ones((4, 3)) * 0.1, "bias": jnp.zeros((3,))},
         "b": {"kernel": jnp.ones((3, 2)) * 0.2},

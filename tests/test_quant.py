@@ -38,8 +38,8 @@ CFG = LLAMA_TINY(dtype=jnp.float32, max_seq_len=96)
 PROMPT_LEN = 8
 SLOTS = 4
 
-#: Grid tolerances (benchmarks/parity_grid.py CELL_ATOL): near-tie
-#: argmax flips only; a wide-margin divergence is a cache/matmul bug.
+#: Grid tolerances: near-tie argmax flips only; a wide-margin
+#: divergence is a cache/matmul bug.
 INT8_ATOL = 0.06
 KV8_ATOL = 0.10
 

@@ -15,6 +15,7 @@ import json
 import os
 import threading
 import zlib
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -24,6 +25,11 @@ from tpudl.ft.data import resumable_request_log
 from tpudl.obs import counters as obs_counters
 from tpudl.obs import metering, requestlog
 from tpudl.obs import report as obs_report
+from tests.serve_helpers import (
+    PROMPT_LEN,
+    build_tenant_session,
+    make_adapters,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -566,26 +572,54 @@ def test_publish_numerics_telemetry():
 # ---------------------------------------------------------------------------
 
 
-def test_end_to_end_multitenant_reconciliation(tmp_path):
-    """The acceptance bar: a multi-tenant serve across a forced
-    rotation boundary leaves one record per Result, zero drops, and
-    per-tenant token sums from the READER equal to the live Results —
-    and the live meter agrees."""
-    from benchmarks.serve_load import run_requestlog_roundtrip
+def _tenant_requests(tenants, per_tenant, seed):
+    """Ragged multi-tenant mix, interleaved round-robin (the
+    heterogeneous batch shape: adjacent slots belong to different
+    tenants)."""
+    from tpudl.serve import Request
 
-    out = run_requestlog_roundtrip(
-        log_dir=str(tmp_path), n_tenants=2, per_tenant=3,
-        num_slots=2, segment_bytes=1024,
-    )
-    assert out["reconciled"]
-    assert out["dropped"] == 0
-    assert out["segments"] >= 2
+    rng = np.random.default_rng(seed)
+    return [
+        Request(
+            request_id=f"rlog-{tenant}-{i}",
+            input_ids=rng.integers(
+                1, 512, size=int(rng.integers(2, PROMPT_LEN + 1))
+            ).tolist(),
+            max_new_tokens=int(rng.integers(6, 13)),
+            tenant=tenant,
+        )
+        for i in range(per_tenant)
+        for tenant in tenants
+    ]
+
+
+def test_end_to_end_multitenant_reconciliation(tmp_path):
+    """The acceptance bar: a multi-tenant serve with the log on, its
+    segment size forced small so that the run CROSSES a rotation
+    boundary, leaves one record per Result, zero drops, and per-tenant
+    token sums from the READER equal to the live Results — and the
+    live meter agrees. The flywheel ingest and every per-tenant bill
+    stand on this reconciliation."""
+    adapters = make_adapters(2, rank=2, seed=0)
+    session = build_tenant_session(adapters, num_slots=2)
+    reqs = _tenant_requests(list(adapters), per_tenant=3, seed=1)
+    writer = requestlog.enable(str(tmp_path), segment_bytes=1024)
+    try:
+        results = session.serve(reqs)
+    finally:
+        requestlog.disable()  # commits the open segment
+    assert writer.dropped == 0
+    assert len(requestlog.list_segments(str(tmp_path))) >= 2
     records = [
         r for r in requestlog.read_request_log(str(tmp_path))
         if str(r["request_id"]).startswith("rlog-")
     ]
-    assert len(records) == out["requests"]
+    assert len(records) == len(reqs)
+    expected, got = Counter(), Counter()
+    for req in reqs:
+        expected[req.tenant] += len(results[req.request_id].tokens)
     for r in records:
+        got[r["tenant"]] += r["tokens_out"]
         assert r["v"] == requestlog.SCHEMA_VERSION
         assert r["site"] == "engine"
         assert r["finish_reason"] in ("eos", "length")
@@ -593,19 +627,19 @@ def test_end_to_end_multitenant_reconciliation(tmp_path):
         assert r["tokens_out"] > 0
         assert r["active_s"] >= 0.0
         assert r["kv_page_seconds"] >= 0.0
+    assert got == expected
     snap = metering.meter().tenants()
-    for tenant, want in out["per_tenant_tokens"].items():
+    for tenant, want in got.items():
         assert snap[tenant]["tokens_out"] >= want
 
 
 def test_router_load_report_tenants_and_quota_gauge():
     """Router.load_report() carries the per-tenant quota-utilization
     section and feeds the metering gauge."""
-    from benchmarks.serve_load import build_tenant_session, make_adapters
     from tpudl.serve import Replica, Router
 
     adapters = make_adapters(2, rank=2, seed=0)
-    session, _, _ = build_tenant_session(adapters, num_slots=2)
+    session = build_tenant_session(adapters, num_slots=2)
     names = sorted(adapters)
     router = Router(
         [Replica("r0", session)],

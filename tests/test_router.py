@@ -10,6 +10,7 @@ the per-replica obs gauges publish what the router scraped.
 """
 
 import time
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ import pytest
 
 from tpudl.models.generate import generate
 from tpudl.models.llama import LLAMA_TINY, LlamaForCausalLM
+from tpudl.obs.slo import Objective, SloMonitor
 from tpudl.serve import (
     PrefillWorker,
     Replica,
@@ -213,6 +215,50 @@ def test_router_slo_burn_sheds_best_effort_only(model_and_params):
         assert registry().gauge("serve_router_autoscale_hint").value == 1
         router._burning["r0"] = frozenset()
         assert router._autoscale_hint() == 0
+
+
+def test_router_overload_sheds_by_slo_burn_not_capacity(model_and_params):
+    """Overload through the router against per-replica TTFT monitors,
+    on an injected clock that moves 10 virtual ms every time anything
+    reads it (so a request's wait is a count of the fleet's work, not
+    of this machine's speed): 40 requests land at once on 2 x 2 slots
+    with queues deep enough to hold them all, the waiting ones blow
+    the 100 ms objective, the engines hand their queues back as
+    shed_slo and the router sheds best-effort work at the door while
+    it burns. Queue overflow is never the relief valve."""
+    model, params = model_and_params
+    t = [0.0]
+
+    def clock():
+        t[0] += 0.01
+        return t[0]
+
+    replicas = [
+        Replica(f"r{i}", _session(
+            model, params, clock=clock, queue_capacity=160,
+            slo=SloMonitor([Objective(
+                name=f"ttft_r{i}", metric="serve_ttft_ms", threshold=100.0,
+                quantile=0.95, window_s=1e6, fast_window_s=1e6, min_count=3,
+            )], clock=clock),
+        ))
+        for i in range(2)
+    ]
+    burst = _greedy_requests(40, seed=11)
+    with Router(replicas) as router:
+        for request in burst:
+            router.submit(request)
+        first = router.collect(timeout_s=300.0)
+        # The engines' burn reached the router: best-effort work now
+        # sheds at the door, before it is placed.
+        assert router.burning
+        router.submit(Request("be", [1, 2], max_new_tokens=2, priority=1))
+        door = router.poll()["be"]
+    reasons = Counter(r.finish_reason for r in first.values())
+    assert sum(reasons.values()) == len(burst)
+    assert reasons["length"] >= 4  # every slot served its seat
+    assert reasons["shed_slo"] > 0
+    assert set(reasons) == {"length", "shed_slo"}  # no shed_capacity
+    assert (door.finish_reason, door.tokens) == ("shed_slo", [])
 
 
 def test_router_disaggregated_prefill_parity(model_and_params):

@@ -6,8 +6,8 @@ latency" (PAPER.md §0); every PR 8-11 review round hand-found the same
 silent regressions in the hot loops: a shape that quietly recompiles
 per step, an eager readback that serializes the dispatch pipeline, a
 donated buffer that silently copies. These context managers make those
-audits reusable — in tests, in benchmarks (serve_load wraps its timed
-steady state in both), and ad hoc around any suspect loop:
+audits reusable — in tests, in the benchmark (perfbench wraps its timed
+window in a RecompileWatcher), and ad hoc around any suspect loop:
 
     with assert_no_recompiles():
         for _ in range(50):
@@ -95,7 +95,7 @@ def compile_seconds() -> float:
 
 class RecompileWatcher:
     """Counts backend compiles inside a ``with`` region without
-    asserting — the benchmark form (serve_load banks the count)."""
+    asserting — the benchmark form (perfbench reports the count)."""
 
     def __init__(self, label: str = ""):
         self.label = label

@@ -60,7 +60,7 @@ CONCURRENCY_TARGETS = (
 )
 
 #: Trees the registry/metric rules scan.
-REGISTRY_TARGETS = ("tpudl", "benchmarks", "scripts", "bench.py")
+REGISTRY_TARGETS = ("tpudl", "scripts")
 
 
 def _iter_py_files(root: str, targets: Sequence[str]) -> List[str]:

@@ -131,23 +131,11 @@ _declare("TPUDL_OVERLAP_BUCKET_MB", "float", None,
          "disables bucketing; unset = auto (4 MiB buckets on "
          "multi-shard meshes).",
          "tpudl.parallel.overlap")
-_declare("TPUDL_NORM_BLOCK_ROWS", "int", None,
-         "Row-block override for the fused norm/MLP-epilogue Pallas "
-         "kernels (benchmarks/fused_epilogue.py --sweep-blocks prints "
-         "the winning pin).",
-         "tpudl.ops.norms")
-_declare("TPUDL_CE_VOCAB_BLOCK", "int", None,
-         "Vocab-block override for the streaming cross-entropy kernel "
-         "(must divide the padded vocab; the sweep keeps the "
-         "divisibility walk).",
-         "tpudl.ops.cross_entropy")
 
 # --- training precision --------------------------------------------------
 _declare("TPUDL_TRAIN_PRECISION", "str", None,
-         "Mixed-precision training policy preset (f32 | bf16 | fp8): "
-         "narrows benchmarks/train_precision.py's default cell sweep "
-         "to f32 + that cell (via policy_from_env); unset = full "
-         "sweep / no policy.",
+         "Mixed-precision training policy preset (f32 | bf16 | fp8), "
+         "resolved by policy_from_env; unset = no policy.",
          "tpudl.train.precision")
 _declare("TPUDL_FP8_AMAX_WINDOW", "int", 16,
          "fp8 delayed-scaling amax-history ring length per tensor "

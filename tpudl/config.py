@@ -26,10 +26,9 @@ class OptimConfig:
     b1: float = 0.9
     b2: float = 0.999
     #: AdamW first-moment dtype. bf16 halves that state's HBM footprint
-    #: and traffic (+2.6% measured on the BERT bench step,
-    #: benchmarks/bert_mu_dtype.py); the second moment stays f32 for
-    #: numerical range. Default f32 so existing checkpoints restore
-    #: unchanged — opt in per config.
+    #: and traffic; the second moment stays f32 for numerical range.
+    #: Default f32 so existing checkpoints restore unchanged — opt in
+    #: per config.
     mu_dtype: str = "float32"  # float32 | bfloat16
     grad_clip_norm: Optional[float] = 1.0
     schedule: str = "cosine"  # cosine | constant | linear

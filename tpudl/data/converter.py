@@ -419,9 +419,7 @@ def make_converter(source: str | Sequence[str]) -> Converter:
 
 # ---------------------------------------------------------------------------
 # Device prefetch (tpudl.data.prefetch — re-exported for the historical
-# import path; the old single-worker implementation serialized host batch
-# assembly and device_put on one thread and lives on only as the
-# benchmarks/input_pipeline.py comparison baseline).
+# import path).
 # ---------------------------------------------------------------------------
 
 from tpudl.data.prefetch import (  # noqa: E402,F401

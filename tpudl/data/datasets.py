@@ -62,10 +62,9 @@ def materialize_cifar10_like(
 
     ``row_group_size`` bounds rows per Parquet row group. 256 (vs the old
     one-group-per-file layout) is the converter's streaming/parallelism
-    granularity: the reader-thread pool overlaps group decode, measured
-    20.7k -> 120k images/sec on the benchmarks/input_pipeline.py read
-    path (one 6 MB group per file decodes single-threaded AND pays
-    superlinear combine/reshape cost)."""
+    granularity: the reader-thread pool overlaps group decode (one 6 MB
+    group per file decodes single-threaded AND pays superlinear
+    combine/reshape cost)."""
     rng = np.random.default_rng(seed)
     labels = rng.integers(0, num_classes, size=(num_rows,))
     images = _class_pattern_images(rng, labels, 32, 4, num_classes)

@@ -24,10 +24,9 @@ import numpy as np
 @dataclasses.dataclass(frozen=True)
 class LatencyStats:
     """Percentile summary of one timing series (milliseconds) — the ONE
-    definition of "p50/p95/p99/max" every benchmark consumes
-    (``latency_benchmark`` below, ``benchmarks/serve_load.py``,
-    ``benchmarks/parity_grid.py``) instead of each hand-rolling its own
-    np.percentile calls. Only post-warmup samples should ever enter:
+    definition of "p50/p95/p99/max" (``latency_benchmark`` below)
+    instead of each caller hand-rolling its own np.percentile calls.
+    Only post-warmup samples should ever enter:
     serving SLOs are quoted at tail percentiles, and a mean/min pair
     hides exactly the outliers that matter."""
 
@@ -75,9 +74,7 @@ class LatencyStats:
         }
 
     def percentiles(self, digits: int = 3) -> dict:
-        """The serving-benchmark tail summary ({p50,p95,p99}_ms,
-        rounded) — benchmarks/serve_load.py's per-request TTFT/TPOT
-        rendering."""
+        """The serving tail summary ({p50,p95,p99}_ms, rounded)."""
         return {
             "p50_ms": round(self.p50_ms, digits),
             "p95_ms": round(self.p95_ms, digits),

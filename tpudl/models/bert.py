@@ -292,9 +292,9 @@ def _remat_policy(name: Optional[str]):
 
 
 def remat_options(cli_name: str) -> dict:
-    """CLI remat mode name -> BertConfig kwargs — the ONE mapping shared
-    by the training driver (notebooks/nlp/train_sst2.py --remat) and the
-    benchmark (benchmarks/bert_large_single_chip.py)."""
+    """CLI remat mode name -> BertConfig kwargs — the ONE mapping the
+    training driver (notebooks/nlp/train_sst2.py --remat) and any other
+    caller that names a remat mode share."""
     opts = {
         "none": {"remat": False},
         "layer": {"remat": "layer"},

@@ -204,8 +204,8 @@ def attend(
             dropout_rng=dropout_rng, dropout_exact=dropout_exact,
         )
     if implementation == "fused":
-        # Three regimes (measured, benchmarks/bert_attn_seq128.py +
-        # BASELINE.md): at short S, XLA's batched matmuls are unbeatable
+        # Three regimes (measured in the early rounds, BASELINE.md):
+        # at short S, XLA's batched matmuls are unbeatable
         # and only softmax+dropout is worth fusing (hybrid); at mid S the
         # whole-attention kernel wins (S=256/512: 4.1/4.3 ms vs einsum's
         # 5.0/5.5 fwd+bwd); past MAX_SEQ its one-pass backward blows VMEM

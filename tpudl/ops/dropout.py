@@ -2,17 +2,17 @@
 
 jax.random.bernoulli generates 32 random bits per element and converts
 them to floats before comparing — for attention-probability dropout on
-the configs[1] headline step that random-bit traffic alone is ~6 ms/step
-(rng-bit-generator in the profile), and the whole bernoulli dropout chain
-costs 21 ms/step (benchmarks/bert_attn_seq128.py: 45.5% -> 50.4% MFU
-with attention dropout off).
+the configs[1] headline step that random-bit traffic alone showed as
+the rng-bit-generator in the profile, and the whole bernoulli dropout
+chain cost about a tenth of the step (early rounds, BASELINE.md; the
+BERT cell's mask-generation metrics in PERF.md are today's reading).
 
 A keep/drop decision needs nowhere near 32 bits of entropy: this module
 draws uint8 bits from the same (hardware-RBG-backed) generator and
 compares against ``round(rate * 256)`` — a quarter of the random-bit
 traffic and an integer compare instead of a float convert+compare.
-Measured: 195.3 -> 180.8 ms/step on the headline BERT fine-tune when
-attention dropout uses this path.
+The BERT cell of the benchmark runs this path (PERF.md, "Where the
+time goes").
 
 The cost: the effective drop rate quantizes to multiples of 1/256
 (rate 0.1 becomes 26/256 ~ 0.1016). Dropout rates are loose

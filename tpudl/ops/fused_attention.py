@@ -3,9 +3,9 @@
 Why this exists next to tpudl.ops.flash_attention: the flash kernel's
 streaming design (kv tiles + online softmax + 3-kernel backward with
 saved logsumexp) wins when S is large, but at the configs[1] headline
-shape (BERT fine-tune, seq 128) it LOSES to XLA's einsum attention —
-measured 257 vs 174 ms/step at batch 256 (benchmarks/bert_attn_seq128.py,
-2026-07-30). At short S the whole [S, S] score tile fits in registers, so
+shape (BERT fine-tune, seq 128) it LOSES to XLA's einsum attention
+(measured in the early rounds, BASELINE.md; no ledger row times it).
+At short S the whole [S, S] score tile fits in registers, so
 the right kernel shape is different:
 
 - one grid cell owns a (batch row, head group): q/k/v arrive as natural

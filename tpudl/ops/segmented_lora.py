@@ -32,8 +32,8 @@ rule): ``"reference"`` is the XLA composite — gather the pages with a
 take, contract with two f32 einsums — and the parity baseline;
 ``"fused"`` is the Pallas kernel (compiled on TPU, interpret mode
 elsewhere — the CPU test mode); ``"auto"`` picks fused on TPU. The two
-differ only in f32 reduction order; benchmarks/parity_grid.py's
-``lora`` cell gates them (and the sequential one-adapter-at-a-time
+differ only in f32 reduction order; tests/test_tenant_lora.py holds
+them (and the sequential one-adapter-at-a-time
 merged reference) at EXACT token parity for f32 pools and
 teacher-forced logit-margin parity for int8 pools. Inference-only: no
 custom VJP (adapters train per-tenant offline; serving only reads

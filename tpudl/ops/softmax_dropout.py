@@ -1,6 +1,6 @@
 """Pallas TPU fused masked-softmax + attention dropout over logits.
 
-The seq-128 lesson (benchmarks/bert_attn_seq128.py, 2026-07-30): XLA's
+The seq-128 lesson (early rounds, BASELINE.md, 2026-07-30): XLA's
 batched [B, H, S, S] attention matmuls are effectively unbeatable at
 short sequence — a whole-attention Pallas kernel spends its time filling
 and draining the MXU on 128x64x128 dots (tpudl.ops.fused_attention is at

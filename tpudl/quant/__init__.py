@@ -25,8 +25,8 @@ package quantizes a TRAINED param tree for serving:
 End to end: ``ServeSession.from_model(..., weight_dtype="int8")``
 serves the quantized tree (composing with the paged int8 KV cache),
 ``tpudl.export.decode`` exports the quantized decoder through the
-existing StableHLO path, and ``benchmarks/parity_grid.py`` gates every
-precision x backend cell with ``assert_serving_parity``.
+existing StableHLO path, and ``tests/test_quant.py`` holds every
+precision x backend cell to ``assert_serving_parity``.
 """
 
 from tpudl.quant.dense import (  # noqa: F401

@@ -30,7 +30,7 @@ driver's loop, the test idiom — deterministic with an injected clock);
 ``start()`` runs the same tick on a background thread for operators.
 The replica factory (``spawn``) is the deployment seam: in-process it
 builds a Replica over shared compiled programs
-(benchmarks/serve_load.py), on a real pod it would boot a mesh.
+(tests/test_autoscale.py), on a real pod it would boot a mesh.
 """
 
 from __future__ import annotations

@@ -788,8 +788,8 @@ class Router:
         #: ``max_inflight_tokens`` caps the tenant's outstanding token
         #: budget — past it, its requests shed as ``shed_quota`` at the
         #: door, so one tenant's overload cannot queue out everyone
-        #: else (the isolation bar benchmarks/serve_load.py --tenants
-        #: asserts). ``tenant_quota_tokens`` (or
+        #: else (the isolation bar tests/test_tenant_lora.py
+        #: holds). ``tenant_quota_tokens`` (or
         #: ``TPUDL_SERVE_TENANT_QUOTA_TOKENS``) is the default quota
         #: for tenants without an explicit class; None = unlimited.
         self.tenant_classes: Dict[Any, dict] = dict(tenant_classes or {})

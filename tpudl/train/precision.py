@@ -61,8 +61,8 @@ DEFAULT_CAST_RULES: Rules = (
     (r".*", None),
 )
 
-#: Rule-selected bf16 first moments (the benchmarks/bert_mu_dtype.py
-#: memory win): every AdamW mu leaf stores bf16; the second moment
+#: Rule-selected bf16 first moments (half that state's bytes):
+#: every AdamW mu leaf stores bf16; the second moment
 #: always stays f32 for range (the OptimConfig.mu_dtype contract).
 BF16_MOMENT_RULES: Rules = ((r".*", "bfloat16"),)
 
@@ -131,9 +131,8 @@ class PrecisionPolicy:
         a flax module promotes its inputs AND params to its own
         ``dtype`` at apply time, so a cast applied outside the module
         cannot lower (or keep) the in-module compute precision — only
-        the seam can. ``run_cell`` in benchmarks/train_precision.py
-        and the policy tests build their models through this (and
-        tests/test_precision.py pins the traced dot dtypes via
+        the seam can. The policy tests build their models through this
+        (and tests/test_precision.py pins the traced dot dtypes via
         jaxpr, so a policy whose compute dtype silently stops landing
         fails loudly)."""
         if not hasattr(cfg, "dtype"):
@@ -403,9 +402,8 @@ def apply_moment_rules(
     policy's rule-selected dtypes (mu trees mirror the param tree, so
     the same ``kernel$``-style regexes address them). Numerically
     identical to optax's global ``mu_dtype``: moments promote to f32
-    inside the update and re-cast on the way back to storage —
-    benchmarks/bert_mu_dtype.py now routes through this instead of
-    hand-wiring the cast, so the two paths cannot drift."""
+    inside the update and re-cast on the way back to storage
+    (tests/test_precision.py holds the two bit for bit)."""
     if pol is None or not pol.moment_rules:
         return tx
 
