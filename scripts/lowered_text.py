@@ -71,6 +71,11 @@ def decoder_programs(root: str, param_dtype=None):
             # whose five ARE its kinds (a list of one kind is no list).
             if key in cfg and len(set(cfg.get("layer_types", ()))) < 2:
                 cfg[key] = max(2, cfg.get("first_k_dense_replace", 0) + 1)
+                # Lists that name every layer are cut with the depth (an
+                # indexer layer and one that shares its choice stay).
+                for listed in ("indexer_types", "mlp_layer_types"):
+                    if listed in cfg:
+                        cfg[listed] = cfg[listed][:cfg[key]]
         sess = cfg["session"]
         if cfg["family"] == "decoder_serve":
             model_cfg = LlamaConfig(

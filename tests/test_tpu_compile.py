@@ -837,15 +837,20 @@ def test_shortcut_moe_program_compiles_for_v5e(
 # length) attends in blocks and fits beside them.
 
 
-def _hyper_mla_moe_session():
+def _family_session(config: str, name: str):
+    """A session of ``perfbench/configs/<config>.json`` at its cell's
+    size over shapes alone, through the family ``<name>_serve`` and the
+    weights its reference ``<name>`` makes: ``(session shapes, model,
+    params, session)``."""
+    import importlib
     import json
 
-    from perfbench.families import hyper_mla_moe_serve as family
-    from perfbench.reference import hyper_mla_moe as ref
     from tpudl.models.llama import LlamaForCausalLM
     from tpudl.serve import ServeSession
 
-    with open(REPO / "perfbench/configs/xing4-29b-a4b-l6.json") as f:
+    family = importlib.import_module(f"perfbench.families.{name}_serve")
+    ref = importlib.import_module(f"perfbench.reference.{name}")
+    with open(REPO / f"perfbench/configs/{config}.json") as f:
         cfg = json.load(f)
     sess = cfg["session"]
     model = LlamaForCausalLM(
@@ -862,6 +867,10 @@ def _hyper_mla_moe_session():
         num_pages=sess["max_seq_len"] // sess["page_size"] + 1,
     )
     return sess, model, params, session
+
+
+def _hyper_mla_moe_session():
+    return _family_session("xing4-29b-a4b-l6", "hyper_mla_moe")
 
 
 @pytest.mark.parametrize("name", ["decode", "prefill", "prefill_4096"])

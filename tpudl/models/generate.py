@@ -42,10 +42,10 @@ MOE_STATS = "moe_stats"
 def _apply_cached(model, variables, *args, **kwargs):
     """``model.apply`` with the cache mutable: ``(output, cache, *stats)``:
     one array ``[layers, ...]`` a statistic the layers sow, at fixed places
-    (MOE_STAT_NAMES, HYPER_STAT_NAME, then a looped stack's LOOP_STAT_NAME,
-    ``[1, passes + 1]``), None where a later one is sown and this one is
-    not; none sown adds nothing. Kernels may be held turned (models.turned)."""
-    from tpudl.models.llama import LOOP_STAT_NAME
+    (MOE_STAT_NAMES, HYPER_STAT_NAME, a looped stack's LOOP_STAT_NAME ``[1,
+    passes + 1]``, then the indexers' SPARSE_STAT_NAME), None where a later one
+    is sown and this one is not; none sown adds nothing. Kernels may be turned."""
+    from tpudl.models.llama import LOOP_STAT_NAME, SPARSE_STAT_NAME
     out, mutated = model.apply(
         {**variables, "params": as_declared(variables["params"])},
         *args, mutable=["cache", MOE_STATS], **kwargs
@@ -60,7 +60,7 @@ def _apply_cached(model, variables, *args, **kwargs):
     stacks = [[
         leaf for path, leaf in sorted(stats, key=layer)
         if path[-2].key == name
-    ] for name in (*MOE_STAT_NAMES, HYPER_STAT_NAME, LOOP_STAT_NAME)]
+    ] for name in (*MOE_STAT_NAMES, HYPER_STAT_NAME, LOOP_STAT_NAME, SPARSE_STAT_NAME)]
     while stacks and not stacks[-1]:
         stacks.pop()
     stacks = [jnp.stack(leaves) if leaves else None for leaves in stacks]

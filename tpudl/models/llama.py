@@ -172,8 +172,7 @@ class LlamaConfig:
                     "attention keeps every layer alike"
                 )
         _check_block(self)
-    # tpudl.ops.norms / mlp_fused: False = composite; True = Pallas on
-    # TPU; "force" = Pallas everywhere.
+    # tpudl.ops.norms / mlp_fused: True = Pallas on TPU; "force" = always.
     fused_ops: Any = False
     # tpudl.quant: "int8" / "fp8_e4m3" = the projections become
     # QuantDense over the quantize_tree output (from_model(weight_dtype=)).
@@ -187,22 +186,27 @@ class LlamaConfig:
     moe_experts: int = 0
     moe_k: int = 2
     moe_capacity_factor: float = 1.25
-    # What each layer is made of. ``attention``: "gqa" (the block
-    # above) or "mla", latent attention: keys and values up-project ONE
-    # normed latent of ``kv_lora_rank`` values a position plus one roped
-    # key of ``qk_rope_head_dim`` shared by all heads, and that pair is
-    # all the cache keeps (LatentAttention).
+    # ``attention``: "gqa" (the block above) or "mla", latent attention:
+    # ONE normed latent of ``kv_lora_rank`` values a position plus one
+    # roped key shared by all heads are all the cache keeps.
     attention: str = "gqa"
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_scaling: Optional[RopeScaling] = None
-    # Dropless routed experts (tpudl.ops.moe.DroplessMoE) in every
-    # layer from ``first_k_dense`` on; the layers before keep the dense
-    # SwiGLU of ``intermediate_size``. ``num_experts``: the router's
-    # width; ``experts_held = (first, count)``: the experts whose
-    # weights this program holds (None: all), one expert-parallel share.
+    # Learned sparse attention over the latent cache (``_check_sparse``):
+    # a query attends the ``index_topk`` positions an indexer of
+    # ``index_n_heads`` x ``index_head_dim`` scores highest; a layer's
+    # ``indexer_types`` entry: "full" (its own indexer, a second cache
+    # leaf of its keys) or "shared" (the last "full" layer's choice).
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Optional[Tuple[str, ...]] = None
+    # Dropless routed experts (tpudl.ops.moe.DroplessMoE) from layer
+    # ``first_k_dense`` on, a dense SwiGLU before. ``num_experts``: the
+    # router's width; ``experts_held = (first, count)``: those held here.
     num_experts: int = 0
     experts_per_token: int = 0
     moe_intermediate_size: int = 0
@@ -210,16 +214,14 @@ class LlamaConfig:
     routed_scaling_factor: float = 1.0
     first_k_dense: int = 0
     experts_held: Optional[Tuple[int, int]] = None
-    # Layers that differ in their attention. ``layer_types``: each
-    # layer's kind ("full_attention": the whole context;
-    # "sliding_attention": the last ``sliding_window`` positions, the
-    # query's own counted), ``num_heads_per_layer`` its query heads
-    # (``num_kv_heads`` is shared), ``head_size`` the head's width where
-    # it is not ``hidden_size // num_heads``. A full layer rotates the
-    # first ``partial_rotary_factor`` of the head with ``rope_theta`` /
-    # ``rope_scaling``, a sliding layer the whole head, plainly, with
-    # ``sliding_rope_theta``. ``attention_gate``: a sigmoid gate a head,
-    # from the layer's normed input, before ``o_proj`` (``layer_spec``).
+    # Layers that differ in their attention (``layer_spec``).
+    # ``layer_types``: "full_attention" (the whole context) or
+    # "sliding_attention" (the last ``sliding_window`` positions, the
+    # query's own counted); ``num_heads_per_layer``: its query heads;
+    # ``head_size``: the head's width where it is not ``hidden_size //
+    # num_heads``. A full layer rotates the first ``partial_rotary_factor``
+    # of the head, a sliding layer all of it with ``sliding_rope_theta``.
+    # ``attention_gate``: a sigmoid gate a head before ``o_proj``.
     head_size: int = 0
     layer_types: Optional[Tuple[str, ...]] = None
     num_heads_per_layer: Optional[Tuple[int, ...]] = None
@@ -230,9 +232,8 @@ class LlamaConfig:
     # ``block``: "llama" or "shortcut" (ShortcutBlock: two latent
     # attentions and two dense FFNs around one expert branch).
     # ``q_lora_rank`` > 0: the latent query is low-rank (q_a_proj,
-    # RMSNorm, q_b_proj); ``mla_scale_q`` / ``_kv``: constants on the
-    # normed low-rank query and latent. ``router_scoring``,
-    # ``router_renormalize``, ``zero_experts``: DroplessMoE's.
+    # RMSNorm, q_b_proj); ``mla_scale_q`` / ``_kv``: constants on it and
+    # on the latent. ``router_*``, ``zero_experts``: DroplessMoE's.
     block: str = "llama"
     q_lora_rank: int = 0
     mla_scale_q: float = 1.0
@@ -240,9 +241,8 @@ class LlamaConfig:
     router_scoring: str = "sigmoid"
     router_renormalize: bool = True
     zero_experts: int = 0
-    # ``hyper_streams`` n > 0: n residual vectors a token, mixed around
-    # every sublayer by hyper-connections (HyperBlock). ``sandwich_norm``:
-    # a norm on each sublayer's OUTPUT too, before the add (SandwichBlock).
+    # ``hyper_streams`` n > 0: n residual vectors a token (HyperBlock).
+    # ``sandwich_norm``: a norm on each sublayer's OUTPUT too (SandwichBlock).
     hyper_streams: int = 0
     hyper_sinkhorn_iters: int = 20
     hyper_eps: float = 1e-6
@@ -488,14 +488,12 @@ PREFILL_SCORE_BYTES = 256 << 20
 PREFILL_BLOCK = 256
 
 
-def _blocked_attention(q, k, v, valid, window: int, block: int, scale=None):
-    """Causal grouped-query attention of a chunk over itself, a block
-    of queries at a time, so that no [H, S, S] tensor exists. q: [B, S,
-    H, D]; k, v: [B, S, Hkv, D] in slot order; valid: [B, S] bool (a
-    left-padded prompt's real slots). A block of queries meets the
-    keys up to its own last slot: all of them on a layer that keeps the
-    context, and on a layer with a ``window`` only the band, from the
-    block start that holds its first query's oldest visible key."""
+def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None):
+    """Causal grouped-query attention of a chunk over itself, a block of
+    queries at a time, so that no [H, S, S] tensor exists. q: [B, S, H, D];
+    k, v: [B, S, Hkv, D] in slot order; valid: [B, S] bool (the real slots).
+    A block meets the keys up to its own last slot (a layer with a ``window``
+    only the band); ``chosen`` [B, S, S] bool: an indexer's choice of them."""
     s = q.shape[1]
     out = []
     for at in range(0, s, block):
@@ -504,6 +502,8 @@ def _blocked_attention(q, k, v, valid, window: int, block: int, scale=None):
         q_slot = jnp.arange(at, end)[:, None]
         kv_slot = jnp.arange(low, end)[None, :]
         mask = (kv_slot <= q_slot)[None] & valid[:, None, low:end]
+        if chosen is not None:
+            mask = mask & chosen[:, at:end, low:end]
         if window:
             mask = mask & (q_slot - kv_slot < window)[None]
         out.append(_gqa_decode_attention(
@@ -736,28 +736,27 @@ class LatentAttention(nn.Module):
     the constant ``mla_scale_kv``: the scale is IN the row) and the
     roped key ``k_r`` (``qk_rope_head_dim``) every head shares. No
     value pool, no head axis: the cache declares the one leaf ``kv``
-    (dense rows) / ``pages_kv`` (page pool); tpudl.serve.cache builds,
-    seats, gathers and migrates whatever leaves a layer declares. The
-    query is one matrix ``q_proj``, or with ``q_lora_rank`` low-rank:
-    ``q = W_qb (RMSNorm(W_qa x) * mla_scale_q)`` (``_latent_query``).
+    (dense rows) / ``pages_kv`` (page pool); tpudl.serve.cache pools
+    whatever leaves a layer declares. The query: ``_latent_query``.
 
     Two forms of the same attention, which must agree. Prefill and
-    training up-project: ``[k_nope_h | v_h] = c W_kv_b``, keys
-    ``qk_nope_head_dim + qk_rope_head_dim`` wide. Paged decode absorbs
-    ``W_kv_b`` into the query and the output and attends the cached
-    rows as they are, one head for all query heads: ``score =
-    (W_kv_b^K q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``;
-    in place through the latent kernel of tpudl.ops.paged_attention
-    where the program observes that it can, else over every slot's
-    table gathered dense. The pool may be held folded
-    (tpudl.models.paged.page_fold): all three address it as held."""
+    training up-project: ``[k_nope_h | v_h] = c W_kv_b``. Paged decode
+    absorbs ``W_kv_b`` into the query and the output and attends the
+    cached rows as they are, one head for all query heads: ``score =
+    (W_kv_b^K q_nope)·c + q_rope·k_r``, ``ctx_h = (Σ p c) W_kv_b^V``; in
+    place through the latent kernel where it can, else gathered dense.
+
+    With ``cfg.index_topk`` (learned sparse attention, below the stack)
+    both forms attend a CHOICE of the cached positions: ``layer``'s
+    indexer makes it ("full") or ``choice`` hands the last one in
+    ("shared"), and the call returns ``(output, choice)``."""
 
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(
         self, hidden, positions, kv_mask=None, decode: bool = False,
-        paged=None, adapters=None,
+        paged=None, adapters=None, layer: int = 0, choice=None,
     ):
         cfg = self.cfg
         if adapters is not None:
@@ -770,7 +769,7 @@ class LatentAttention(nn.Module):
         scale = (dn + dr) ** -0.5
         if cfg.rope_scaling is not None:
             scale *= cfg.rope_scaling.attention_scale
-        q = _latent_query(cfg, hidden).reshape(B, S, H, dn + dr)
+        q, low = _latent_query(cfg, hidden)
         q_nope = q[..., :dn]
         q_rope = rope(q[..., dn:], positions, cfg.rope_theta, cfg.rope_scaling)
         with jax.named_scope("kv_down"):
@@ -787,6 +786,9 @@ class LatentAttention(nn.Module):
         kv_b = self.param(
             "kv_b_proj", nn.initializers.normal(0.02), (r, H * (dn + dv))
         ).astype(cfg.dtype).reshape(r, H, dn + dv)
+        # A "full" layer's indexer: its queries, keys and head weights.
+        index = _index_of(self, layer, low, hidden, positions)
+        real = None if index is None else real_tokens(hidden, kv_mask, paged)
 
         if decode and paged is not None:
             from tpudl.models.paged import paged_write
@@ -802,22 +804,19 @@ class LatentAttention(nn.Module):
             if sc is not None:
                 sc.value = scales
             # Then the absorbed attention reads the pool: in place where
-            # it can, through the dense gather where it cannot (an int8
-            # pool, a pool on a mesh, several tokens a slot, a CPU run).
-            # The program chooses by what it observes and records the
-            # choice (PagedView.took).
+            # it can, else gathered (PagedView.took records the choice).
+            choice = _paged_choice(self, index, choice, paged, real)
             with jax.named_scope("mla_core"):
                 query = _absorbed_query(q_nope, q_rope, kv_b, dn)
             u = paged_latent_attention(
-                query, pool.value, paged, rank=r, scale=scale, scales=scales
+                query, pool.value, paged, rank=r, scale=scale, scales=scales, chosen=choice
             )
             with jax.named_scope("mla_core"):
                 ctx = jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
         elif decode:
-            # The dense row cache of a prefill (and of the chunked
-            # suffix prefill, which is handed the prefix's rows). A
-            # cache made HERE starts empty, so the chunk is all there
-            # is to attend to; one that was handed in is attended whole.
+            # The dense row cache of a prefill (and of the chunked suffix
+            # prefill, which is handed the prefix's rows). A cache made HERE
+            # starts empty: the chunk is all there is to attend to.
             fresh = not self.has_variable("cache", "kv")
             ckv = self.variable(
                 "cache", "kv", jnp.zeros, (B, cfg.max_seq_len, r + dr),
@@ -849,18 +848,19 @@ class LatentAttention(nn.Module):
                 q_slot = q_slot + start
             kv_slot = jnp.arange(rows.shape[1])[None, None, None, :]
             mask = (kv_slot <= q_slot) & valid[:, None, None, :]
-            ctx = _mla_prefill(
-                q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh)
+            choice = _dense_choice(self, index, choice, mask, real, start, fresh)
+            ctx = _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale,
+                               valid, fresh, choice)
         else:
             slot = jnp.arange(S)
             mask = (slot[None, :] <= slot[:, None])[None, None]
             if kv_mask is not None:
                 mask = mask & kv_mask.astype(jnp.bool_)[:, None, None, :]
-            ctx = _mla_up_projected(
-                q_nope, q_rope, latent, kv_b, dn, mask, scale)
-        return _proj(cfg, cfg.hidden_size, "o_proj")(
-            ctx.reshape(B, S, H * dv)
-        )
+            choice = _dense_choice(self, index, choice, mask, real)
+            ctx = _mla_prefill(q_nope, q_rope, latent, kv_b, dn, mask, scale,
+                               None, False, choice)
+        out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx.reshape(B, S, H * dv))
+        return (out, choice) if cfg.index_topk else out
 
 
 def _masked_softmax(logits, mask, dtype):
@@ -958,14 +958,14 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(
         self, hidden, positions, kv_mask=None, decode: bool = False,
-        paged=None, adapters=None,
+        paged=None, adapters=None, choice=None,
     ):
         from tpudl.models.lora import adapter_delta
-
-        cfg = self.cfg
         from tpudl.ops.norms import fused_ops_impl
-
+        cfg = self.cfg
         impl = fused_ops_impl(cfg.fused_ops)
+        # With an indexer a layer takes the last choice of rows, hands its own on.
+        sparse = {"layer": self.layer, "choice": choice} if cfg.index_topk else {}
         if cfg.attention == "mla":
             attention = LatentAttention(cfg, name="attention")
         else:
@@ -976,11 +976,11 @@ class LlamaBlock(nn.Module):
             kv_mask,
             decode,
             paged,
-            adapters,
+            adapters, **sparse,
         )
-        # The attention residual add rides inside the post-attention
-        # norm kernel; the summed value comes back as the carried
-        # residual (one activation pass instead of add + norm).
+        attn, choice = attn if cfg.index_topk else (attn, None)
+        # The attention residual add rides inside the post-attention norm
+        # kernel; the summed value comes back as the carried residual.
         x, hidden = RMSNorm(
             cfg.rms_norm_eps, impl, name="post_attention_norm"
         )(attn, residual=hidden)
@@ -1039,8 +1039,8 @@ class LlamaBlock(nn.Module):
                 act = swiglu(gate, up, impl=impl)
                 down = _proj(cfg, cfg.hidden_size, "down_proj")(act)
                 down = down + adapter_delta(adapters, "down_proj", act)
-        hidden = hidden + down
-        return constrain(hidden, ("dp", "fsdp"), "sp", "tp")
+        hidden = constrain(hidden + down, ("dp", "fsdp"), "sp", "tp")
+        return (hidden, choice) if cfg.index_topk else hidden
 
 
 class LlamaModel(nn.Module):
@@ -1068,13 +1068,13 @@ class LlamaModel(nn.Module):
             ).astype(jnp.int32)
         with jax.named_scope("embeddings"):
             x = nn.Embed(
-                cfg.vocab_size,
-                cfg.hidden_size,
+                cfg.vocab_size, cfg.hidden_size, name="embed_tokens",
                 embedding_init=nn.initializers.normal(0.02),
-                name="embed_tokens",
             )(input_ids).astype(cfg.dtype)
         x = _enter_stream(cfg, constrain(x, ("dp", "fsdp"), "sp", "tp"))
         block = _block_of(cfg)
+        if cfg.index_topk:  # layers that hand on an indexer's choice
+            return _sparse_stack(self, block, x, positions, kv_mask, decode, paged, adapters)
         if cfg.loop_passes > 1:
             return _loop(self, block, x, positions, kv_mask, decode, paged, adapters)
         if cfg.remat and not decode:  # (adapter views are decode-only)
@@ -1155,6 +1155,7 @@ def _check_block(cfg: LlamaConfig) -> None:
     """``__post_init__``: the block, the low-rank query, the router."""
     _check_stream(cfg)
     _check_loop(cfg)
+    _check_sparse(cfg)
     if cfg.block not in _BLOCKS:
         raise ValueError(
             f"block must be one of {sorted(_BLOCKS)}, got {cfg.block!r}"
@@ -1166,8 +1167,7 @@ def _check_block(cfg: LlamaConfig) -> None:
     if cfg.q_lora_rank < 0 or cfg.zero_experts < 0:
         raise ValueError(
             f"q_lora_rank and zero_experts must be >= 0, got "
-            f"{cfg.q_lora_rank} and {cfg.zero_experts}"
-        )
+            f"{cfg.q_lora_rank} and {cfg.zero_experts}")
     if cfg.zero_experts and not cfg.num_experts:
         raise ValueError(
             "zero_experts are ids past num_experts of a routed-expert "
@@ -1191,17 +1191,17 @@ def _scaled(scale: float, x):
 
 
 def _latent_query(cfg: LlamaConfig, hidden):
-    """``LatentAttention``'s query, [B, S, H * (dn + dr)]: one matrix
-    ``q_proj``, or with ``q_lora_rank`` the low-rank ``W_qb
-    (RMSNorm(W_qa x) * mla_scale_q)``."""
-    width = cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    """``LatentAttention``'s query [B, S, H, dn + dr]: one matrix ``q_proj``,
+    or with ``q_lora_rank`` ``W_qb (RMSNorm(W_qa x) * mla_scale_q)``; and
+    that normed low-rank input, which an indexer reads too (None without)."""
+    heads = (*hidden.shape[:2], cfg.num_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     if not cfg.q_lora_rank:
-        return _proj(cfg, width, "q_proj")(hidden)
+        return _proj(cfg, heads[2] * heads[3], "q_proj")(hidden).reshape(heads), None
     with jax.named_scope("q_down"):
         low = RMSNorm(cfg.rms_norm_eps, name="q_norm")(
-            _proj(cfg, cfg.q_lora_rank, "q_a_proj")(hidden)
-        )
-    return _proj(cfg, width, "q_b_proj")(_scaled(cfg.mla_scale_q, low))
+            _proj(cfg, cfg.q_lora_rank, "q_a_proj")(hidden))
+    q = _proj(cfg, heads[2] * heads[3], "q_b_proj")(_scaled(cfg.mla_scale_q, low))
+    return q.reshape(heads), low
 
 
 def _latent_norm(cfg: LlamaConfig, down):
@@ -1626,7 +1626,9 @@ def _loop(model, block, x, positions, kv_mask, decode, paged, adapters):
     return x
 
 
-def _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh):
+def _mla_prefill(
+    q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh, choice=None
+):
     """``_mla_up_projected`` for a chunk written into a row cache.
     Where the chunk starts the cache (``fresh``: its own rows are all
     there is to attend to; ``valid`` [B, S] marks the real ones) and
@@ -1635,9 +1637,13 @@ def _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh):
     the grouped-query routine, with one KV head a query head: keys
     ``[k_nope_h | k_r]`` (the roped key repeated to every head),
     values ``v_h``. Chosen from the static shapes of the program being
-    traced: 512 rows at 64 heads are 67 MB and keep the one pass."""
+    traced: 512 rows at 64 heads are 67 MB and keep the one pass.
+    ``choice`` [B, S, T] bool: an indexer's choice of the rows, for
+    each query (learned sparse attention, below): a mask more."""
     b, s, h, _ = q_nope.shape
     if not fresh or 4 * b * h * s * s <= PREFILL_SCORE_BYTES:
+        if choice is not None:
+            mask = mask & choice[:, None]
         return _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale)
     r = kv_b.shape[0]
     with jax.named_scope("mla_core"):
@@ -1648,8 +1654,295 @@ def _mla_prefill(q_nope, q_rope, rows, kv_b, dn, mask, scale, valid, fresh):
         return _blocked_attention(
             jnp.concatenate([q_nope, q_rope], axis=-1),
             jnp.concatenate([up[..., :dn], k_rope.astype(up.dtype)], axis=-1),
-            up[..., dn:], valid, 0, PREFILL_BLOCK, scale,
+            up[..., dn:], valid, 0, PREFILL_BLOCK, scale, choice,
         )
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention over the latent cache (``model_type``
+# ``glm_moe_dsa``; DeepSeek-V3.2's form): a small indexer scores every
+# cached position for a query, and the latent attention above attends
+# the ``index_topk`` best of them only. Most layers have no indexer and
+# reuse the choice of the last layer that has one.
+# ---------------------------------------------------------------------------
+
+#: The statistic a layer with an indexer sows into the serving
+#: statistics' collection (tpudl.models.generate), int32 [2] over the
+#: call's real queries: the positions they attended, and the positions
+#: they could see.
+SPARSE_STAT_NAME = "sparse_rows"
+#: ``k_norm`` is a LayerNorm with bias, at this epsilon.
+INDEX_NORM_EPS = 1e-6
+
+
+def _check_sparse(cfg: LlamaConfig) -> None:
+    """``_check_block``'s checks of ``index_topk`` and the indexer's
+    keys: what they are, and what they are not wired to."""
+    if cfg.index_topk < 0:
+        raise ValueError(
+            f"index_topk must be >= 0 (0: every cached position is "
+            f"attended), got {cfg.index_topk}"
+        )
+    if not cfg.index_topk:
+        if cfg.indexer_types or cfg.index_n_heads or cfg.index_head_dim:
+            raise ValueError(
+                "indexer_types, index_n_heads and index_head_dim describe "
+                "the indexer of learned sparse attention: set index_topk"
+            )
+        return
+    if cfg.attention != "mla" or not cfg.q_lora_rank:
+        raise ValueError(
+            "index_topk (learned sparse attention) chooses rows of the "
+            "LATENT cache, with an indexer that reads the low-rank "
+            "query: it needs attention='mla' and q_lora_rank > 0"
+        )
+    if cfg.index_n_heads < 1 or cfg.index_head_dim < cfg.qk_rope_head_dim:
+        raise ValueError(
+            f"the indexer needs index_n_heads >= 1 and an index_head_dim "
+            f"that holds the {cfg.qk_rope_head_dim} roped values, got "
+            f"{cfg.index_n_heads} x {cfg.index_head_dim}"
+        )
+    types = cfg.indexer_types
+    if (
+        types is None or len(types) != cfg.num_layers
+        or not set(types) <= {"full", "shared"} or types[0] != "full"
+    ):
+        raise ValueError(
+            f"indexer_types names each of the {cfg.num_layers} layers "
+            f"'full' (its own indexer) or 'shared' (the last 'full' "
+            f"layer's choice), the first one 'full': got {types}"
+        )
+    for what, asked, why in (
+        ("block='shortcut'", cfg.block != "llama",
+         "the double layer's two attentions would each need a choice, "
+         "and which of them a later 'shared' layer reuses is not defined"),
+        ("hyper_streams", cfg.hyper_streams,
+         "HyperBlock hands on the residual stream alone, not a choice "
+         "of cached positions beside it"),
+        ("loop_passes > 1", cfg.loop_passes > 1,
+         "a pass would need the choice made by the same layer in the "
+         "pass before, and a pool of indexer keys a (pass, layer)"),
+        ("lora_rank", cfg.lora_rank,
+         "latent attention takes no adapter view, and no adapter "
+         "addresses the indexer's projections"),
+        ("moe_experts, fp8_train or remat",
+         cfg.moe_experts or cfg.fp8_train or cfg.remat,
+         "the training tiers run no indexer, and a rematerialised block "
+         "would trace the choice it hands on twice"),
+    ):
+        if asked:
+            raise ValueError(
+                f"{what} is not wired to learned sparse attention "
+                f"(index_topk): {why}"
+            )
+
+
+class Indexer(nn.Module):
+    """A "full" layer's indexer: for each position ``index_n_heads``
+    queries from the normed low-rank query ``low``, ONE key (LayerNorm
+    with bias) and a weight a head from the layer's normed input, the
+    first ``qk_rope_head_dim`` values of queries and key roped. The
+    key is what the layer caches beside its latent row. The score of
+    key ``s`` for query ``t`` (``index_scores``):
+
+        I[t, s] = sum_j w_j[t] relu(q_j[t] . k[s])
+
+    with ``index_head_dim ** -0.5 * index_n_heads ** -0.5`` folded
+    into ``w`` (float32). -> ``(q [B, S, Hi, Di], k [B, S, Di],
+    w [B, S, Hi])``. Scope: ``dsa_index``."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, low, hidden, positions):
+        cfg = self.cfg
+        B, S, _ = hidden.shape
+        heads, width = cfg.index_n_heads, cfg.index_head_dim
+
+        def roped(x):
+            return rope(x, positions, cfg.rope_theta, cfg.rope_scaling,
+                        cfg.qk_rope_head_dim)
+
+        q = _proj(cfg, heads * width, "q_proj")(low)
+        key = nn.LayerNorm(
+            epsilon=INDEX_NORM_EPS, dtype=cfg.dtype, name="k_norm"
+        )(_proj(cfg, width, "k_proj")(hidden))
+        w = nn.Dense(
+            heads, use_bias=False, dtype=cfg.dtype,
+            kernel_init=nn.initializers.normal(0.02), name="weights_proj",
+        )(hidden)
+        return (
+            roped(q.reshape(B, S, heads, width)),
+            roped(key[:, :, None])[:, :, 0],
+            w.astype(jnp.float32) * (heads * width) ** -0.5,
+        )
+
+
+def index_scores(q, w, keys):
+    """``I[b, t, s]`` float32 of the indexer's queries q [B, S, Hi, Di]
+    and head weights w [B, S, Hi] against keys [B, T, Di]."""
+    dots = jnp.einsum(
+        "bshd,btd->bsht", q, keys, preferred_element_type=jnp.float32
+    )
+    return jnp.einsum("bsht,bsh->bst", jax.nn.relu(dots), w)
+
+
+def top_rows(scores, k: int):
+    """bool like ``scores`` [..., T] float32: each row's ``k`` largest,
+    a tie going to the lower position: what ``jax.lax.top_k`` picks, as
+    a mask, without its sort. The k-th largest score is found EXACTLY
+    by bisection over the 32 bits of a key that orders as the floats do
+    (32 counts of a compare, each one pass over the row, against a sort
+    of every row); scores above it are in, and of those equal to it the
+    first ones, as many as there is room for."""
+    bits = jax.lax.bitcast_convert_type(scores + 0.0, jnp.uint32)  # no -0.0
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+
+    def keep_bit(i, kth):
+        higher = kth | (top >> i)
+        fits = jnp.sum(keys >= higher, axis=-1, keepdims=True) >= k
+        return jnp.where(fits, higher, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, keep_bit, jnp.zeros((*scores.shape[:-1], 1), jnp.uint32)
+    )
+    above, ties = keys > kth, keys == kth
+    room = k - above.sum(-1, keepdims=True)
+    return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+
+
+def choose_rows(q, w, keys, mask, k: int, causal: bool):
+    """The indexer's choice for a dense pass, bool [B, S, T]: of the
+    positions ``mask`` [B, S, T] lets each query see, the ``k`` it
+    scores highest (all of them where there are no more). A block of
+    ``PREFILL_BLOCK`` queries at a time, so that one block's scores
+    [Hi, block, T] are all that is held; ``causal``: query ``i`` sees
+    no key past ``i``, so a block is scored against the keys up to its
+    own last and one that sees ``k`` keys or fewer is not scored."""
+    B, S, T = q.shape[0], q.shape[1], keys.shape[1]
+    mask = jnp.broadcast_to(mask, (B, S, T))
+    if T <= k:
+        return mask
+    out = []
+    for at in range(0, S, PREFILL_BLOCK):
+        end = min(at + PREFILL_BLOCK, S)
+        seen = min(end, T) if causal else T
+        if seen <= k:
+            out.append(mask[:, at:end])
+            continue
+        with jax.named_scope("dsa_index"):
+            scores = jnp.where(
+                mask[:, at:end, :seen],
+                index_scores(q[:, at:end], w[:, at:end], keys[:, :seen]),
+                -jnp.inf,
+            )
+        with jax.named_scope("dsa_select"):
+            chosen = top_rows(scores, k) & mask[:, at:end, :seen]
+            out.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, T - seen))))
+    return jnp.concatenate(out, axis=1)
+
+
+def _index_of(module, layer: int, low, hidden, positions):
+    """``LatentAttention``'s indexer outputs on a "full" layer (the
+    module ``indexer``), None on a "shared" one and without
+    ``index_topk``."""
+    cfg = module.cfg
+    if not cfg.index_topk or cfg.indexer_types[layer] != "full":
+        return None
+    with jax.named_scope("dsa_index"):
+        return Indexer(cfg, name="indexer")(low, hidden, positions)
+
+
+def _sow_choice(module, choice, chosen, live, real):
+    """A "full" layer's record of its choice: ``SPARSE_STAT_NAME``
+    (chosen and live [B, S], summed over the real queries) beside the
+    other serving statistics, and the choice itself for a caller that
+    asks for ``intermediates`` (a test; a no-op in every program)."""
+    real = real.astype(jnp.int32)
+    module.sow("moe_stats", SPARSE_STAT_NAME, jnp.stack([
+        jnp.sum(chosen.astype(jnp.int32) * real),
+        jnp.sum(live.astype(jnp.int32) * real),
+    ]))
+    module.sow("intermediates", "index_choice", choice)
+
+
+def _dense_choice(module, index, choice, mask, real, start=None, fresh=True):
+    """The choice a dense pass of ``LatentAttention`` attends under,
+    bool [B, S, T]: a "shared" layer's (``index`` None) is the one
+    handed in; a "full" layer makes its own (``choose_rows``) from
+    ``mask`` [B, 1, S, T], what each query may see. Into a row cache
+    (``start``: where the chunk is written) the layer declares a SECOND
+    row leaf, ``index_k``, its indexer's keys: the page manager pools
+    it under the same table as the latent rows."""
+    if index is None:
+        return choice
+    cfg = module.cfg
+    q, keys, w = index
+    if start is not None:
+        cached = module.variable(
+            "cache", "index_k", jnp.zeros,
+            (keys.shape[0], cfg.max_seq_len, keys.shape[-1]), keys.dtype,
+        )
+        cached.value = jax.lax.dynamic_update_slice(
+            cached.value, keys, (0, start, 0)
+        )
+        if not fresh:
+            keys = cached.value
+    choice = choose_rows(
+        q, w, keys, mask[:, 0], cfg.index_topk, causal=fresh
+    )
+    _sow_choice(module, choice, choice.sum(-1), mask[:, 0].sum(-1), real)
+    return choice
+
+
+def _paged_choice(module, index, choice, paged, real):
+    """The choice a paged decode step attends under, int32 [B, S, k]
+    logical positions of each slot (None: the cache holds no more than
+    ``k`` a slot, every live one is attended): a "shared" layer's is
+    the one handed in; a "full" layer scatters this step's indexer key
+    into its second pool, ``pages_index_k``, and chooses among the
+    slot's live keys (tpudl.ops.paged_attention.paged_index_choice)."""
+    if index is None:
+        return choice
+    from tpudl.models.paged import paged_write
+    from tpudl.ops.paged_attention import paged_index_choice
+
+    q, keys, w = index
+    pool = module.variable("cache", "pages_index_k", _paged_cache_missing)
+    sc = None
+    if paged.quantized:
+        sc = module.variable("cache", "scale_index_k", _paged_cache_missing)
+    scales = sc.value if sc is not None else None
+    pool.value, scales = paged_write(pool.value, scales, keys, paged)
+    if sc is not None:
+        sc.value = scales
+    k = module.cfg.index_topk
+    choice, live = paged_index_choice(q, w, pool.value, paged, k, scales)
+    _sow_choice(module, choice, jnp.minimum(live, k), live, real)
+    return choice
+
+
+def _sparse_stack(model, block, x, positions, kv_mask, decode, paged, adapters):
+    """``LlamaModel``'s stack for ``index_topk`` > 0, called inside its
+    ``__call__``: every layer is handed the last choice of cached
+    positions and hands on its own ("full") or the same ("shared")."""
+    from tpudl.ops.norms import fused_ops_impl
+
+    cfg = model.cfg
+    if adapters is not None:
+        raise ValueError(
+            "per-tenant adapters are not wired to learned sparse "
+            "attention (index_topk)"
+        )
+    choice = None
+    for i in range(cfg.num_layers):
+        x, choice = block(cfg, cfg.mlp_kind(i), i, name=f"layer_{i}")(
+            x, positions, kv_mask, decode, paged, None, choice
+        )
+    return RMSNorm(
+        cfg.rms_norm_eps, fused_ops_impl(cfg.fused_ops), name="final_norm"
+    )(x)
 
 
 # ---------------------------------------------------------------------------

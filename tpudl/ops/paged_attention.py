@@ -575,19 +575,19 @@ def _fused_latent(
 
 def paged_latent_attention(
     query, pages, view, *, rank: int, scale: float,
-    scales=None,
+    scales=None, chosen=None,
     impl: str = "auto", interpret: Optional[bool] = None,
 ):
     """Absorbed latent attention of ``query`` [B, S, H, C] over each
     slot's logical positions ``[start, lens + j]`` of the ONE headless
     pool ``pages`` (as held), ``paged_write`` having put this step's
-    rows there: the score of a position is ``query . row`` over all
-    ``C`` values, its value the row's first ``rank``. Returns
-    [B, S, H, rank] in ``query.dtype``: ``u``, which the caller
-    projects through ``W_kv_b^V``. ``view`` records which path the layer
-    took. ``"auto"`` takes the kernel on a TPU where
-    ``latent_in_place_ok``; int8 pools (``scales``), a pool on a mesh,
-    a chunk of more than one token and every CPU run gather."""
+    rows there: a position's score is ``query . row``, its value the
+    row's first ``rank``. Returns ``u`` [B, S, H, rank]. ``view``
+    records the path taken. ``"auto"``: the kernel on a TPU where
+    ``latent_in_place_ok``, else the gather. ``chosen`` [B, S, k]: of
+    those positions, the ones an indexer chose (``chosen_latent_rows``)."""
+    if chosen is not None:
+        return chosen_latent_rows(query, pages, view, rank, scale, chosen, scales)
     if impl == "auto":
         impl = (
             "fused"
@@ -614,3 +614,82 @@ def paged_latent_attention(
         page_size=view.page_size, rank=rank, scale=float(scale),
         interpret=interpret,
     )
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention (tpudl.models.llama, below its stack): an
+# indexer chooses ``k`` of a slot's cached positions, and the latent
+# attention reads those ROWS (token-granular, not page-granular). Scopes
+# inside a layer's ``attention``: ``dsa_index`` (the scores), ``dsa_select``
+# (the top-k) and, inside ``mla_core``, ``dsa_gather`` (the rows' read).
+# ---------------------------------------------------------------------------
+
+def paged_index_choice(q, w, pages, view, k: int, scales=None):
+    """An indexer's choice at a paged decode step. q [B, S, Hi, Di] and
+    w [B, S, Hi] (tpudl.models.llama.Indexer) score every logical
+    position of each slot's table in the pool of indexer keys ``pages``
+    ([NP, ps, Di], this step's keys written); of the positions a query
+    may see, ``[start, lens + j]``, the ``k`` best are chosen, exactly
+    (``jax.lax.top_k``: a tie goes to the lower position). ->
+    ``(chosen, live)``: int32 [B, S, k] logical positions, in no order,
+    padded with positions the query may NOT see where it sees fewer
+    than ``k`` (the attention masks by position), and how many it sees,
+    int32 [B, S]. ``chosen`` is None where a table holds no more than
+    ``k`` positions: every live one is attended, by the path that was
+    there."""
+    from tpudl.models.llama import index_scores
+    from tpudl.models.paged import paged_attend_mask, paged_gather
+
+    mask = paged_attend_mask(view, chunk=q.shape[1])[:, 0]
+    live = mask.sum(-1, dtype=jnp.int32)
+    if view.logical_len <= k:
+        return None, live
+    with jax.named_scope("dsa_index"):
+        keys = paged_gather(pages, scales, view, q.dtype)
+        # A pool of another width than whole lanes is held folded.
+        keys = keys.reshape(keys.shape[0], view.logical_len, -1)
+        scores = jnp.where(mask, index_scores(q, w, keys), -jnp.inf)
+    with jax.named_scope("dsa_select"):
+        return jax.lax.top_k(scores, k)[1].astype(jnp.int32), live
+
+
+def chosen_latent_rows(query, pages, view, rank, scale, chosen, scales=None):
+    """``paged_latent_attention`` over the ``chosen`` [B, S, k] logical
+    positions of each slot alone: their rows are gathered out of the
+    pool through the page table (a held row each; of a folded pool the
+    row that holds the position, whose other lane blocks are masked),
+    dequantised where the pool is int8, and attended as
+    ``attend_latent_rows`` attends a gathered table, every (slot,
+    query) a batch row of its own. A chosen position the query may not
+    see (``paged_index_choice``'s padding) is masked. Scope:
+    ``mla_core`` > ``dsa_gather``."""
+    from tpudl.models.llama import attend_latent_rows
+    from tpudl.models.paged import held_fold
+
+    view.took.append(False)
+    b, s, h, c = query.shape
+    k, ps = chosen.shape[-1], view.page_size
+    fold = held_fold(pages, ps)
+    with jax.named_scope(LATENT_SCOPE):
+        with jax.named_scope("dsa_gather"):
+            page = jnp.take_along_axis(
+                view.page_table, (chosen // ps).reshape(b, s * k), axis=1
+            ).reshape(b, s, k)
+            off = chosen % ps
+            rows = pages[page, off // fold]
+            if scales is not None:
+                rows = rows.astype(jnp.float32) * scales[page, off][..., None]
+            rows = rows.astype(query.dtype).reshape(b * s, k, -1)
+        upper = view.lens[:, None] + jnp.arange(s, dtype=view.lens.dtype)
+        seen = (chosen >= view.start[:, None, None]) & (
+            chosen <= upper[:, :, None]
+        )
+        block = jnp.arange(fold, dtype=off.dtype)[None, :, None]
+        mask = (
+            seen.reshape(b * s, 1, k)
+            & (off.reshape(b * s, 1, k) % fold == block)
+        )[:, :, None]
+        u = attend_latent_rows(
+            query.reshape(b * s, 1, h, c), rows, mask, scale, rank
+        )
+        return u.reshape(b, s, h, rank)

@@ -74,8 +74,15 @@ LLAMA_QUANT_PATTERNS = (
 #: (``router/kernel``, which no pattern above names); a looped stack's
 #: exit gate (``early_exit_gate``, float32: its sigmoid is the exit
 #: distribution). The sandwich form's four norms a layer are ``scale``
-#: leaves, which no pattern names.
-LLAMA_KEEP_PATTERNS = (r"hyper_\w+/phi$", r"early_exit_gate/")
+#: leaves, which no pattern names. Of a learned-sparse-attention
+#: indexer (``attention/indexer``, tpudl.models.llama.Indexer) the query
+#: and key matrices quantize with the attention's (``q_proj``,
+#: ``k_proj``: the int8 control's choice is then int8's); its head
+#: weights ``weights_proj`` (``[hidden, heads]``: they weigh every
+#: score of a choice that is exact) and its LayerNorm are kept.
+LLAMA_KEEP_PATTERNS = (
+    r"hyper_\w+/phi$", r"early_exit_gate/", r"indexer/(weights_proj|k_norm)/",
+)
 
 #: Which BERT leaves quantize: encoder attention + MLP projections.
 #: The pooler/classifier head and embeddings keep full precision.

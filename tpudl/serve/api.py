@@ -492,6 +492,22 @@ class ServeSession:
                         f"{what} is not wired to a stack run {passes} "
                         f"times a token (loop_passes): {why}"
                     )
+        topk = getattr(cfg, "index_topk", 0)
+        registry().gauge("serve_index_topk").set(topk)
+        registry().gauge("serve_index_pools").set(
+            cfg.indexer_types.count("full") if topk else 0
+        )
+        if topk and mesh is not None:
+            # Learned sparse attention is served from the paged pool, a
+            # second leaf (the indexer's keys) on the layers that have
+            # an indexer, with its int8 store, prefix sharing and
+            # migration (adapters and spec_k: latent attention's, below).
+            raise ValueError(
+                "a mesh-committed session is not wired to learned sparse "
+                "attention (index_topk): no sharding rule places the "
+                "indexer's headless key pool, and the gather of chosen "
+                "rows has run on no mesh"
+            )
         if getattr(cfg, "block", "llama") == "shortcut":
             # The shortcut double layer (two latent attentions and two
             # dense FFNs around one expert branch) is served from the

@@ -181,7 +181,9 @@ def tpudl_first_token(tokens, slot, first):
 _set_first = jax.jit(tpudl_first_token)
 
 
-def record_expert_load(counts=None, chose=None, hyper=None, loop=None) -> dict:
+def record_expert_load(
+    counts=None, chose=None, hyper=None, loop=None, sparse=None
+) -> dict:
     """The statistics a model's layers sowed in one prefill or decode
     step, on the host, in the places the serving contracts return them
     (tpudl.models.generate): counted into the registry and returned as
@@ -222,8 +224,27 @@ def record_expert_load(counts=None, chose=None, hyper=None, loop=None) -> dict:
     every token ran; ``loop_exit_pdf``: the mean exit distribution over
     the real tokens, a number a pass; its last entry (the mass that no
     earlier pass's gate let go) is observed into the histogram
-    ``serve_loop_exit_last_pass_mass``."""
+    ``serve_loop_exit_last_pass_mass``.
+
+    ``sparse`` (learned sparse attention): int [layers with an indexer,
+    2], the positions the call's real queries attended and the
+    positions they could see (tpudl.models.llama.SPARSE_STAT_NAME),
+    alike in every such layer. ``sparse_rows_chosen`` /
+    ``sparse_rows_live``: one layer's pair; ``index_layers``: how many
+    layers chose; chosen over live is observed into the histogram
+    ``serve_sparse_chosen_share`` (1.0: nothing was skipped)."""
     attrs = {}
+    if sparse is not None:
+        sparse = np.asarray(sparse)
+        chosen, live = int(sparse[0, 0]), int(sparse[0, 1])
+        if live:
+            registry().histogram("serve_sparse_chosen_share").observe(
+                chosen / live
+            )
+        attrs.update(
+            sparse_rows_chosen=chosen, sparse_rows_live=live,
+            index_layers=len(sparse),
+        )
     if loop is not None:
         loop = np.asarray(loop, np.float64)[0]
         pdf = (loop[:-1] / loop[-1] if loop[-1] else loop[:-1]).tolist()
