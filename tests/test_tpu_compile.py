@@ -17,6 +17,7 @@ import functools
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -186,18 +187,39 @@ def _paged_attention(chunk):
     )
 
 
-def _grouped_matmul(experts, per_token, k, n, result, rows=4096):
+def _grouped_matmul(
+    experts, per_token, k, n, result, rows=4096, by_index=False
+):
     """One grouped matmul of a sorted expert layer over a 4,096-row
     prefill's assignments: gate / up (``k`` the hidden size, a bfloat16
-    result) or down (``k`` the experts' width, a float32 result)."""
+    result) or down (``k`` the experts' width, a float32 result), the
+    down call's rows put at their assignments' places where
+    ``by_index`` (PR 45: ``rows_to`` scalar-prefetched, one copy a
+    row)."""
     from tpudl.ops.grouped_matmul import grouped_matmul
 
+    specs = (
+        _s((rows * per_token, k), bf16), _s((experts, k, n), bf16),
+        _s((experts,), i32),
+    )
+    if by_index:
+        fn = lambda lhs, rhs, sizes, places: grouped_matmul(  # noqa: E731
+            lhs, rhs, sizes, result, rows_to=places, interpret=False
+        )
+        return fn, specs + (_s((rows * per_token,), i32),)
     fn = lambda lhs, rhs, sizes: grouped_matmul(  # noqa: E731
         lhs, rhs, sizes, result, interpret=False
     )
+    return fn, specs
+
+
+def _sum_choices(per_token, n, rows=4096):
+    """The token's sum over the indexed down call's result."""
+    from tpudl.ops.grouped_matmul import sum_choices
+
+    fn = lambda out, held: sum_choices(out, held, interpret=False)  # noqa: E731
     return fn, (
-        _s((rows * per_token, k), bf16), _s((experts, k, n), bf16),
-        _s((experts,), i32),
+        _s((rows * per_token, 1, n), f32), _s((rows, per_token), jnp.bool_),
     )
 
 
@@ -230,6 +252,20 @@ CASES = {
         256, 8, 2048, 512, bf16),
     "laguna/moe_grouped_matmul-down": lambda: _grouped_matmul(
         256, 8, 512, 2048, f32),
+    # ... and the down call whose rows go back to assignment order by
+    # the kernel's own copies, at the three served shapes: GLM's share
+    # (16 experts of [2048, 6144] held, 8 a token, 8,192 rows: a
+    # float32 [65536, 1, 6144] result walked in three column tiles)
+    "glm/moe_grouped_matmul-down-by-index": lambda: _grouped_matmul(
+        16, 8, 2048, 6144, f32, rows=8192, by_index=True),
+    "xing4/moe_grouped_matmul-down-by-index": lambda: _grouped_matmul(
+        64, 4, 1024, 3584, f32, by_index=True),
+    "laguna/moe_grouped_matmul-down-by-index": lambda: _grouped_matmul(
+        256, 8, 512, 2048, f32, by_index=True),
+    # ... whose rows the second kernel sums a token
+    "glm/moe_sum_choices": lambda: _sum_choices(8, 6144, rows=8192),
+    "xing4/moe_sum_choices": lambda: _sum_choices(4, 3584),
+    "laguna/moe_sum_choices": lambda: _sum_choices(8, 2048),
 }
 
 
@@ -606,15 +642,21 @@ def _on_one_chip(monkeypatch):
 def _grouped_kernel_calls(text: str) -> int:
     """The calls of the grouped-matmul kernel in a compiled program's
     text, each inside the scope ``experts``; no ``ragged-dot`` is left
-    beside them."""
-    calls = [
-        line for line in text.splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line
-        and "moe_grouped_matmul" in line
-    ]
-    assert all("/experts/" in line for line in calls)
+    beside them, and a layer's three are followed by ONE call of the
+    kernel that sums a token's rows (PR 45: the down call's rows go
+    back by index)."""
+    def calls(kernel):
+        found = [
+            line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and re.match(rf"\s*(ROOT )?%{kernel}[. ]", line)
+        ]
+        assert all("/experts/" in line for line in found)
+        return len(found)
+
     assert "ragged-dot" not in text
-    return len(calls)
+    assert 3 * calls("moe_sum_choices") == calls("moe_grouped_matmul")
+    return calls("moe_grouped_matmul")
 
 
 def _window_moe_session():

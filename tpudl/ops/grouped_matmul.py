@@ -27,6 +27,26 @@ the row tiles:
   tile's product.) An empty group costs nothing, rows behind the last
   group are never visited and their output is never written.
 
+The rows leave sorted order in the kernel too (``rows_to``, PR 45). The
+layer sums a token's ``k`` results, so each sorted row's result has to
+go back to its assignment's place ``token * k + choice``, and outside
+the kernel that was a float32 gather of every row, held or not. Given
+the sort's index the down projection's call leaves its result in HBM,
+one row a LEADING index (``[m, 1, n]``: a row is contiguous there, as
+it is in the ``[2, tm, 1, tn]`` VMEM scratch a visit's product is laid
+into; a one-row slice of an ``(8, 128)``-tiled block is not a copy the
+compiler takes), and a visit copies each row it owns to
+``out[rows_to[row]]``, its column tile's slice: the copies of a visit
+start together and are waited for two visits later, so the next
+product overlaps them. What moves grows with the rows the groups
+cover: a row behind the last group (an assignment of an expert held
+elsewhere) is never written, and ``sum_choices``, a second small
+kernel, selects its place away while it sums a token's ``k`` rows in
+one pass, in the order the layer's sum has always had on the chip. The
+layer asks for both wherever it takes the kernel: on the chip it leads
+XLA's gather at every served shape, Laguna's 1.4 us visits included
+(PERF.md, PR 45).
+
 bfloat16 operands, float32 accumulation, the result rounded once to the
 type asked for: ``ragged_dot``'s arithmetic. ``grouped_kernel_ok`` says
 where the layer takes the kernel, from what a traced program can
@@ -58,6 +78,8 @@ ROW_TILE = 128
 #: two): a wider matrix is walked in column tiles.
 MATRIX_BLOCK_BYTES = 8 << 20
 LANES = 128
+#: Arrays of the walk (``_walk``), scalar-prefetched ahead of all else.
+WALK = 8
 
 
 def one_device() -> bool:
@@ -134,20 +156,10 @@ def _walk(sizes, row_tiles: int, tm: int):
     )
 
 
-def _kernel(
-    group_of, tile_of, starts, ends, first_visit, following, buffer_of,
-    total,  # scalar prefetch
-    lhs_ref, rhs_hbm,
-    out_ref,
-    matrix, sem,
-    *, tm: int, tn: int,
-):
-    """One visit: ``lhs_ref`` ``[tm, k]`` the visit's row tile,
-    ``rhs_hbm`` ``[groups, k, n]`` in HBM, ``out_ref`` ``[tm, tn]`` the
-    tile's output block (resident while consecutive visits name it),
-    ``matrix`` ``[2, k, tn]``, ``sem`` one DMA semaphore a buffer."""
-    column = pl.program_id(0)
-    visit = pl.program_id(1)
+def _product(walk, column, visit, lhs_ref, rhs_hbm, matrix, sem, tn: int):
+    """The visit's ``[tm, tn]`` float32 product, the group's matrix
+    streamed as the header says. Called inside ``visit < total``."""
+    group_of, _, _, _, first_visit, following, buffer_of, _ = walk
     group = group_of[visit]
     buffer = buffer_of[group]
 
@@ -157,31 +169,123 @@ def _kernel(
             matrix.at[buf], sem.at[buf],
         )
 
+    @pl.when(visit == first_visit[group])
+    def _():
+        # Only the walk's first group was not put in flight by the
+        # group before it.
+        @pl.when(visit == 0)
+        def _():
+            stream(group, buffer).start()
+
+        stream(group, buffer).wait()
+        nxt = following[group]
+
+        @pl.when(nxt >= 0)
+        def _():
+            stream(nxt, 1 - buffer).start()
+
+    return jnp.dot(
+        lhs_ref[...], matrix[buffer], preferred_element_type=jnp.float32
+    )
+
+
+def _own_rows(walk, visit, tm: int):
+    """The sorted rows ``[lo, hi)`` of the visit's tile that are its
+    group's."""
+    group_of, tile_of, starts, ends = walk[:4]
+    group, first = group_of[visit], tile_of[visit] * tm
+    return (
+        jnp.maximum(starts[group], first),
+        jnp.minimum(ends[group], first + tm),
+    )
+
+
+def _kernel(*refs, tm: int, tn: int):
+    """One visit: ``lhs_ref`` ``[tm, k]`` the visit's row tile,
+    ``rhs_hbm`` ``[groups, k, n]`` in HBM, ``out_ref`` ``[tm, tn]`` the
+    tile's output block (resident while consecutive visits name it),
+    ``matrix`` ``[2, k, tn]``, ``sem`` one DMA semaphore a buffer."""
+    walk, (lhs_ref, rhs_hbm, out_ref, matrix, sem) = refs[:WALK], refs[WALK:]
+    _, tile_of, *_, total = walk
+    column, visit = pl.program_id(0), pl.program_id(1)
+
     @pl.when(visit < total[0])
     def _():
-        @pl.when(visit == first_visit[group])
-        def _():
-            # Only the walk's first group was not put in flight by the
-            # group before it.
-            @pl.when(visit == 0)
-            def _():
-                stream(group, buffer).start()
-
-            stream(group, buffer).wait()
-            nxt = following[group]
-
-            @pl.when(nxt >= 0)
-            def _():
-                stream(nxt, 1 - buffer).start()
-
-        product = jnp.dot(
-            lhs_ref[...], matrix[buffer], preferred_element_type=jnp.float32
+        product = _product(
+            walk, column, visit, lhs_ref, rhs_hbm, matrix, sem, tn
         ).astype(out_ref.dtype)
+        lo, hi = _own_rows(walk, visit, tm)
         row = tile_of[visit] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, tn), 0
         )
-        own = (row >= starts[group]) & (row < ends[group])
-        out_ref[...] = jnp.where(own, product, out_ref[...])
+        out_ref[...] = jnp.where(
+            (row >= lo) & (row < hi), product, out_ref[...]
+        )
+
+
+def _kernel_by_index(*refs, tm: int, tn: int):
+    """One visit whose rows go to the places ``rows_to`` names:
+    ``rows_to`` ``[rows]`` int32 in SMEM (scalar-prefetched behind the
+    walk), ``out_hbm`` ``[m, 1, n]`` in HBM, ``result`` ``[2, tm, 1,
+    tn]`` the products of this visit and the last, a row a leading
+    index, ``row_sem`` one DMA semaphore a result block. A row the
+    visit owns is copied from its result block to
+    ``out_hbm[rows_to[row], 0, column tile]``; the copies
+    of a visit start together and are waited for two visits later,
+    before their block is written again (and at the walk's end), so the
+    next visit's product overlaps them."""
+    walk, refs = refs[:WALK], refs[WALK:]
+    rows_to, lhs_ref, rhs_hbm, out_hbm, matrix, sem, result, row_sem = refs
+    _, tile_of, *_, total = walk
+    column, visit = pl.program_id(0), pl.program_id(1)
+    total = total[0]
+    columns = pl.ds(pl.multiple_of(column * tn, LANES), tn)
+
+    def row_copy(slot, at, to):
+        return pltpu.make_async_copy(
+            result.at[slot, at], out_hbm.at[to, :, columns], row_sem.at[slot],
+        )
+
+    def land(of):
+        """Wait for the row copies that visit ``of`` started."""
+        lo, hi = _own_rows(walk, of, tm)
+
+        def wait(_, c):
+            # Every row copy signals the same count: any descriptor of
+            # the block's semaphore waits for one of them.
+            row_copy(of % 2, 0, 0).wait()
+            return c
+
+        jax.lax.fori_loop(lo, hi, wait, 0)
+
+    @pl.when(visit < total)
+    def _():
+        slot = visit % 2
+        product = _product(
+            walk, column, visit, lhs_ref, rhs_hbm, matrix, sem, tn
+        )
+
+        @pl.when(visit >= 2)
+        def _():
+            land(visit - 2)
+
+        result[slot] = product.astype(result.dtype).reshape(tm, 1, tn)
+        lo, hi = _own_rows(walk, visit, tm)
+        first = tile_of[visit] * tm
+
+        def start(row, c):
+            row_copy(slot, row - first, rows_to[row]).start()
+            return c
+
+        jax.lax.fori_loop(lo, hi, start, 0)
+
+        @pl.when(visit == total - 1)
+        def _():
+            @pl.when(visit >= 1)
+            def _():
+                land(visit - 1)
+
+            land(visit)
 
 
 @functools.partial(
@@ -189,7 +293,7 @@ def _kernel(
     static_argnames=("preferred_element_type", "row_tile", "interpret"),
 )
 def grouped_matmul(
-    lhs, rhs, group_sizes, preferred_element_type=None, *,
+    lhs, rhs, group_sizes, preferred_element_type=None, *, rows_to=None,
     row_tile: Optional[int] = None, interpret: Optional[bool] = None,
 ):
     """``jax.lax.ragged_dot(lhs, rhs, group_sizes)`` through the kernel:
@@ -198,10 +302,13 @@ def grouped_matmul(
     ``[m, n]`` in ``preferred_element_type`` (default: ``lhs``'s type);
     the rows behind the last group are NOT written and hold whatever
     the buffer held (``ragged_dot`` leaves zeros there: the caller
-    leaves those rows out either way). Jitted on its own so that the
-    layers of a program, and gate and up of a layer, share one lowered
-    function (as the paged kernels do); compiled on a TPU, interpret
-    mode elsewhere (the CPU test mode)."""
+    leaves those rows out either way). With ``rows_to`` (int ``[m]``,
+    no place named twice) what is returned is ``[m, 1, n]`` and sorted
+    row ``r``'s result is its row ``rows_to[r]``; the places of the
+    rows behind the last group are the ones not written. Jitted on its own
+    so that the layers of a program, and gate and up of a layer, share
+    one lowered function (as the paged kernels do); compiled on a TPU,
+    interpret mode elsewhere (the CPU test mode)."""
     m, k = lhs.shape
     groups, _, n = rhs.shape
     out_dtype = jnp.dtype(preferred_element_type or lhs.dtype)
@@ -212,31 +319,45 @@ def grouped_matmul(
         lhs = jnp.pad(lhs, ((0, rows - m), (0, 0)))
     tn = _column_tile(k, n, rhs.dtype.itemsize)
     walk = _walk(group_sizes, rows // tm, tm)
+    by_index = rows_to is not None
+    prefetch = walk + ((rows_to.astype(jnp.int32),) if by_index else ())
     resident = (
         2 * k * tn * rhs.dtype.itemsize
         + 2 * tm * k * lhs.dtype.itemsize
         + 2 * tm * tn * out_dtype.itemsize
         + 2 * tm * tn * 4  # the product before it is rounded, the mask
     )
+    scratch = [
+        pltpu.VMEM((2, k, tn), rhs.dtype), pltpu.SemaphoreType.DMA((2,)),
+    ]
+    if by_index:
+        # The result stays in HBM and takes its rows by copy, from two
+        # blocks of products in VMEM.
+        out_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch += [
+            pltpu.VMEM((2, tm, 1, tn), out_dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    else:
+        out_spec = pl.BlockSpec((tm, tn), lambda j, v, g, tile, *_: (tile[v], j))
     with jax.named_scope(NAME):
         out = pl.pallas_call(
-            functools.partial(_kernel, tm=tm, tn=tn),
+            functools.partial(
+                _kernel_by_index if by_index else _kernel, tm=tm, tn=tn
+            ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(walk),
+                num_scalar_prefetch=len(prefetch),
                 grid=(n // tn, rows // tm + groups - 1),
                 in_specs=[
                     pl.BlockSpec((tm, k), lambda j, v, g, tile, *_: (tile[v], 0)),
                     pl.BlockSpec(memory_space=pl.ANY),
                 ],
-                out_specs=pl.BlockSpec(
-                    (tm, tn), lambda j, v, g, tile, *_: (tile[v], j)
-                ),
-                scratch_shapes=[
-                    pltpu.VMEM((2, k, tn), rhs.dtype),
-                    pltpu.SemaphoreType.DMA((2,)),
-                ],
+                out_specs=out_spec,
+                scratch_shapes=scratch,
             ),
-            out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype),
+            out_shape=jax.ShapeDtypeStruct(
+                (m, 1, n) if by_index else (rows, n), out_dtype
+            ),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=resident + (16 << 20),
@@ -251,5 +372,97 @@ def grouped_matmul(
             ),
             interpret=interpret,
             name=NAME,
-        )(*walk, lhs, rhs)
-    return out[:m] if rows != m else out
+        )(*prefetch, lhs, rhs)
+    if by_index or rows == m:
+        return out
+    return out[:m]
+
+
+#: Tokens of a step of ``sum_choices``: with 8 choices of 6,144 columns
+#: a 3 MB block of rows, double-buffered.
+SUM_TOKENS = 16
+SUM_NAME = "moe_sum_choices"
+
+
+def _sum_kernel(held, rows_ref, out_ref, *, k: int, tokens: int):
+    """One step: ``rows_ref`` ``[tokens * k, 1, n]`` the rows of
+    ``tokens`` tokens as the indexed call left them, ``held`` int32 in
+    SMEM, one an assignment; ``out_ref`` ``[tokens, 1, n]``."""
+    first = pl.program_id(0) * tokens * k
+    zero = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+
+    def one_token(t, carry):
+        # A select, not a product: a row nobody wrote may hold NaN.
+        terms = [
+            jax.lax.select(
+                held[first + t * k + c] != 0,
+                rows_ref[t * k + c], zero,
+            )
+            for c in range(k)
+        ]
+        n = k
+        while n > 1 and n % 2 == 0:
+            n //= 2
+            terms = [terms[i] + terms[i + n] for i in range(n)]
+        out_ref[t] = functools.reduce(jnp.add, terms)
+        return carry
+
+    # A loop, not ``tokens`` copies of its body: a traced program pays
+    # for every operation of a kernel's body on the host, at set-up.
+    jax.lax.fori_loop(0, tokens, one_token, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def sum_choices(rows, held, *, interpret: Optional[bool] = None):
+    """A token's ``k`` rows summed in float32: ``rows`` ``[T * k, 1,
+    n]`` as ``grouped_matmul(..., rows_to=)`` returns them, ``held``
+    bool ``[T, k]`` the assignments that have a row (the others' places
+    were never written and are selected away). Returns ``[T, n]``
+    float32.
+
+    The order is the one the chip's own reduce takes over the ``k``
+    sublanes of a ``[T, k, n]`` tile, which is what summed these rows
+    while XLA gathered them (read from the compiler's LLO: ``vrot.slane
+    4, vadd, vrot.slane 2, vadd, vrot.slane 1, vadd``): halves folded
+    onto each other, ``((c0 + c4) + (c2 + c6)) + ((c1 + c5) + (c3 +
+    c7))`` at ``k`` = 8, ``(c0 + c2) + (c1 + c3)`` at 4. XLA's reduce
+    over a LEADING axis, which ``k`` is here, runs another order, and
+    its slices of one operand do not fuse; a kernel of one pass keeps
+    the layer's bits and reads each row once."""
+    places, _, n = rows.shape
+    tokens, k = held.shape
+    _, interpret = resolve_impl("fused", interpret)
+    steps = pl.cdiv(tokens, SUM_TOKENS)
+    held = jnp.pad(
+        held.reshape(-1).astype(jnp.int32),
+        (0, steps * SUM_TOKENS * k - places),
+    )
+    with jax.named_scope(SUM_NAME):
+        out = pl.pallas_call(
+            functools.partial(_sum_kernel, k=k, tokens=SUM_TOKENS),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(steps,),
+                in_specs=[pl.BlockSpec(
+                    (SUM_TOKENS * k, 1, n), lambda i, held: (i, 0, 0)
+                )],
+                out_specs=pl.BlockSpec(
+                    (SUM_TOKENS, 1, n), lambda i, held: (i, 0, 0)
+                ),
+            ),
+            out_shape=jax.ShapeDtypeStruct((tokens, 1, n), rows.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=(
+                    2 * SUM_TOKENS * (k + 1) * n * rows.dtype.itemsize
+                    + (16 << 20)
+                ),
+            ),
+            cost_estimate=pl.CostEstimate(
+                flops=places * n, transcendentals=0,
+                bytes_accessed=(places + tokens) * n * rows.dtype.itemsize,
+            ),
+            interpret=interpret,
+            name=SUM_NAME,
+        )(held, rows)
+    return out.reshape(tokens, n)

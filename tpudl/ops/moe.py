@@ -36,7 +36,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tpudl.ops.grouped_matmul import grouped_kernel_ok, grouped_matmul
+from tpudl.ops.grouped_matmul import (
+    grouped_kernel_ok,
+    grouped_matmul,
+    sum_choices,
+)
 from tpudl.parallel.sharding import constrain
 
 P = jax.sharding.PartitionSpec
@@ -265,7 +269,17 @@ class DroplessMoE(nn.Module):
       ``serve_moe_grouped_kernel``), else ``jax.lax.ragged_dot``: the
       same arithmetic. A choice of an expert held elsewhere, or of an
       identity expert, sorts behind the last group and is left out. The
-      results go back to token order and are summed by token in float32.
+      results go back to token order INSIDE the down projection's
+      kernel call, which copies each row a group covers to its
+      assignment's place ``token * k + choice`` of a float32
+      ``[T * k, 1, m]`` result wherever the kernel is taken (counter
+      ``serve_moe_rows_by_index``), else through the sort's inverse (a
+      scatter of an iota) and one gather; a token's ``k`` rows are then
+      summed in float32 under a select by the ``[T, k]`` ``held``
+      (``sum_choices``, in the order the chip's reduce has always
+      taken), and rounded once. A row held elsewhere is never written
+      and never selected: no ``[T * k, m]`` tensor is zeroed, and with
+      the kernel none is gathered.
 
     The dense form does ``rows`` operations for every byte of weights
     it streams, so it costs one pass of the weights up to about 240
@@ -423,16 +437,30 @@ class DroplessMoE(nn.Module):
                         preferred_element_type=jnp.float32,
                     )
                 else:
-                    out = grouped(
-                        act, wd, sizes, preferred_element_type=jnp.float32
-                    )
-                    # Rows past the groups are no expert's: left out.
-                    out = jnp.where(
-                        (key[order] < count)[:, None], out, 0.0
-                    )
-                    routed = out[jnp.argsort(order)].reshape(
-                        b * s, k, m
-                    ).sum(axis=1)
+                    if grouped is grouped_matmul:
+                        # The kernel puts a sorted row's result at its
+                        # assignment's place, [T * k, 1, m]; a place
+                        # nobody wrote is selected away in the sum.
+                        registry().counter("serve_moe_rows_by_index").inc()
+                        out = grouped_matmul(
+                            act, wd, sizes, jnp.float32, rows_to=order
+                        )
+                        routed = sum_choices(out, held)
+                    else:
+                        # Back to assignment order through the sort's
+                        # inverse, a scatter of an iota; ``ragged_dot``
+                        # left zeros behind the last group, and the
+                        # select keeps the sum to the rows held.
+                        inv = jnp.zeros_like(order).at[order].set(
+                            jnp.arange(order.shape[0], dtype=order.dtype),
+                            unique_indices=True,
+                        )
+                        out = jax.lax.ragged_dot(
+                            act, wd, sizes, preferred_element_type=jnp.float32
+                        )[inv].reshape(b * s, k, m)
+                        routed = jnp.where(held[..., None], out, 0.0).sum(
+                            axis=1
+                        )
                 if sd is not None:
                     routed = routed * sd
                 routed = routed.astype(self.dtype)
