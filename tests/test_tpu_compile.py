@@ -223,6 +223,23 @@ def _sum_choices(per_token, n, rows=4096):
     )
 
 
+def _prefill_attention(rows, heads, dk, dv, choice):
+    import importlib
+
+    fa = importlib.import_module("tpudl.ops.flash_attention")
+
+    def fn(q, k, v, valid, chosen=None):
+        return fa.prefill_attention(
+            q, k, v, valid, 0.0625, chosen, interpret=False
+        )
+
+    return fn, (
+        _s((1, rows, heads, dk), bf16), _s((1, rows, heads, dk), bf16),
+        _s((1, rows, heads, dv), bf16), _s((1, rows), jnp.bool_),
+        *([_s((1, rows, rows), jnp.bool_)] if choice else []),
+    )
+
+
 CASES = {
     # BERT-base, b256 s128
     "bert/layer_norm+residual": _layer_norm_residual,
@@ -266,6 +283,14 @@ CASES = {
     "glm/moe_sum_choices": lambda: _sum_choices(8, 6144, rows=8192),
     "xing4/moe_sum_choices": lambda: _sum_choices(4, 3584),
     "laguna/moe_sum_choices": lambda: _sum_choices(8, 2048),
+    # The long latent prefill's attention, one call a layer (PR 46):
+    # GLM's 8,192 rows x 64 heads, keys 192 + 64 and values 256, under
+    # the indexer's choice; xing4's 4,096 x 32, keys 128 + 64 padded to
+    # 256 and values 128. Lowers, and its tiles fit VMEM.
+    "glm/prefill_attention": lambda: _prefill_attention(
+        8192, 64, 256, 256, True),
+    "xing4/prefill_attention": lambda: _prefill_attention(
+        4096, 32, 256, 128, False),
 }
 
 
@@ -639,6 +664,18 @@ def _on_one_chip(monkeypatch):
     monkeypatch.setattr(gm, "one_device", lambda: True)
 
 
+def _prefill_kernel_calls(text: str) -> int:
+    """The calls of the prefill attention kernel in a compiled
+    program's text, each inside the scope ``mla_core``."""
+    found = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and re.match(r"\s*(ROOT )?%prefill_attention[. ]", line)
+    ]
+    assert all("/mla_core/" in line for line in found)
+    return len(found)
+
+
 def _grouped_kernel_calls(text: str) -> int:
     """The calls of the grouped-matmul kernel in a compiled program's
     text, each inside the scope ``experts``; no ``ragged-dot`` is left
@@ -951,13 +988,15 @@ def test_hyper_mla_moe_program_compiles_for_v5e(
         assert memory.temp_size_in_bytes < 1.5e9
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 11e9
         text = compiled.as_text()
-        # No score matrix of the whole prompt, at any precision: a
-        # block of 256 queries meets the keys up to its own end.
-        # (At 4,096 rows a weight has that shape: the heads name it.)
+        # No score matrix of the whole prompt, at any precision, and
+        # since PR 46 none of a block of 256 queries either: a layer's
+        # attention is ONE call of the prefill kernel, its scores in
+        # VMEM. (At 4,096 rows a weight has the first shape: the heads
+        # name it; the int8 [1, rows, rows] the kernel streams is the
+        # mask, one for all heads.)
         assert not re.search(rf"32,(?:1,)?{rows},{rows}\]", text)
-        assert rows == 4096 or ",2048,2048]" not in text
-        for keys in range(256, rows + 1, 256):
-            assert f"f32[1,32,1,256,{keys}]" in text
+        assert "f32[1,32,1,256," not in text
+        assert _prefill_kernel_calls(text) == 6
         # The three grouped matmuls of the five expert layers are the
         # kernel, at either prefill length.
         assert _grouped_kernel_calls(text) == 3 * 5

@@ -15,6 +15,7 @@ from tests.test_tpu_compile import (  # noqa: F401  (the fixture)
     _family_session,
     _grouped_kernel_calls,
     _on_one_chip,
+    _prefill_kernel_calls,
     _placed,
     _s,
     _v5e_device,
@@ -32,7 +33,8 @@ from tests.test_tpu_compile import (  # noqa: F401  (the fixture)
 # (the gather of rows, not the in-place kernel, which reads every live
 # block), takes the eight pools donated and copies none; 9.40 GB of
 # weights, 0.75 GB of pools and a step's temporaries fit the chip. The
-# batch-1 prefill at 8,192 rows attends in blocks under the choice.
+# batch-1 prefill at 8,192 rows attends under the choice, a kernel call a
+# layer (PR 46).
 
 
 @pytest.mark.parametrize("name", ["decode", "prefill_8192"])
@@ -74,9 +76,13 @@ def test_sparse_mla_moe_program_compiles_for_v5e(
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5e9
         text = compiled.as_text()
         # No score matrix of the whole prompt, the attention's or the
-        # indexer's: a block of 256 queries meets the keys up to its
-        # own end.
+        # indexer's (a block of 256 of the indexer's queries meets the
+        # keys up to its own end), and since PR 46 none of a block of
+        # the attention's queries: a layer's attention under the
+        # choice is ONE call of the prefill kernel.
         assert not re.search(rf"(?:64|32),(?:1,)?{rows},{rows}\]", text)
+        assert "f32[1,64,256," not in text and "f32[1,64,1,256," not in text
+        assert _prefill_kernel_calls(text) == 6
         assert _grouped_kernel_calls(text) == 3 * 5
         return
     cache = session.engine.cache

@@ -77,23 +77,23 @@ def prefill_fn(model):
     (params, input_ids, attention_mask) -> (last_logits, cache). One
     definition serves both the live loop below and the serving export
     (tpudl.export.decode) — they cannot diverge. A model with routed
-    experts returns a third value, its tokens per held expert (and,
-    with identity experts, a fourth: ``_apply_cached``); so do the
-    paged decode, chunked prefill and verify contracts below."""
+    experts returns further values (``_apply_cached``), as the contracts
+    below do. Once traced at a length, ``attention_in_kernel[rows]`` is
+    the number of layers whose attention the prefill kernel took."""
 
     def tpudl_prefill(params, input_ids, attention_mask):
         positions = jnp.maximum(
             jnp.cumsum(attention_mask, axis=-1) - 1, 0
         ).astype(jnp.int32)
-        # Only the last position's logits leave this program. Where the
-        # whole window's would be a temporary past the bound a long
-        # prefill keeps its scores under (a 4,096-token window over a
-        # 100k vocabulary: 1.6 GB and 1.7 TFLOP), the head is asked for
-        # that one row; shorter windows keep the program they had.
+        # Only the last position's logits leave this program: where the
+        # window's would pass the bound a long prefill keeps its scores
+        # under (4,096 rows x a 100k vocabulary: 1.6 GB), ask for that row.
         from tpudl.models.llama import PREFILL_SCORE_BYTES
-
+        from tpudl.ops.flash_attention import note_prefill
+        from tpudl.ops.flash_attention import prefill_kernel_calls
         vocab = getattr(getattr(model, "cfg", None), "vocab_size", 0)
         one_row = 4 * input_ids.shape[1] * vocab > PREFILL_SCORE_BYTES
+        before = prefill_kernel_calls()
         logits, *rest = _apply_cached(
             model,
             {"params": params},
@@ -103,8 +103,8 @@ def prefill_fn(model):
             positions=positions,
             last_only=one_row,
         )
+        note_prefill(tpudl_prefill, input_ids.shape[1], before)
         return (logits[:, -1, :], *rest)
-
     return tpudl_prefill
 
 

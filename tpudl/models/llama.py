@@ -476,24 +476,24 @@ def _paged_cache_missing():
     )
 
 
-#: A prefill that starts a cache attends its own chunk. Where the
-#: chunk's scores against the whole row cache ([B, H, S, max_seq_len]
-#: float32) would pass this many bytes, the chunk is attended in blocks
-#: of ``PREFILL_BLOCK`` queries instead, each against the keys it can
-#: see. Chosen from the static shapes of the program being traced; a
-#: 512-token window over a 1,024-position cache at 32 heads is 67 MB
-#: and keeps the one dense pass, a 4,096-token window at 64 heads would
-#: be 5.4 GB.
+#: A prefill that starts a cache attends its own chunk. Where its scores
+#: against the whole row cache ([B, H, S, max_seq_len] float32) would pass
+#: this many bytes (static shapes: 512 rows over 1,024 positions at 32
+#: heads are 67 MB and keep the one dense pass, 4,096 rows at 64 heads
+#: would be 5.4 GB), it is attended ``PREFILL_BLOCK`` queries at a time.
 PREFILL_SCORE_BYTES = 256 << 20
 PREFILL_BLOCK = 256
 
 
 def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None):
-    """Causal grouped-query attention of a chunk over itself, a block of
-    queries at a time, so that no [H, S, S] tensor exists. q: [B, S, H, D];
-    k, v: [B, S, Hkv, D] in slot order; valid: [B, S] bool (the real slots).
-    A block meets the keys up to its own last slot (a layer with a ``window``
-    only the band); ``chosen`` [B, S, S] bool: an indexer's choice of them."""
+    """Causal grouped-query attention of a chunk over itself with no [H, S, S]
+    tensor: one Pallas call where ``prefill_kernel_ok`` (ops.flash_attention),
+    else a block of queries at a time against the keys up to its last slot (a
+    ``window`` layer: the band). q: [B, S, H, D]; k, v: [B, S, Hkv, D] in slot
+    order; valid: [B, S] bool; ``chosen`` [B, S, S] bool: an indexer's choice."""
+    from tpudl.ops.flash_attention import prefill_attention, prefill_kernel_ok
+    if prefill_kernel_ok(q, k, v, window):
+        return prefill_attention(q, k, v, valid, scale, chosen)
     s = q.shape[1]
     out = []
     for at in range(0, s, block):
@@ -1645,8 +1645,21 @@ def _mla_prefill(
         if choice is not None:
             mask = mask & choice[:, None]
         return _mla_up_projected(q_nope, q_rope, rows, kv_b, dn, mask, scale)
-    r = kv_b.shape[0]
+    from tpudl.models.paged import LANES
+    from tpudl.ops.flash_attention import prefill_kernel_ok
+    from tpudl.ops.pallas_utils import round_up
+
+    r, dr, dv = kv_b.shape[0], q_rope.shape[-1], kv_b.shape[-1] - dn
     with jax.named_scope("mla_core"):
+        # The kernel takes a head as a column block of whole lanes.
+        wide = round_up(dn + dr, LANES)
+        like = jax.ShapeDtypeStruct((b, s, h, wide), rows.dtype)
+        value = jax.ShapeDtypeStruct((b, s, h, dv), rows.dtype)
+        if prefill_kernel_ok(like, like, value, 0):
+            return _blocked_attention(
+                *_kernel_operands(q_nope, q_rope, rows, kv_b, dn, wide),
+                valid, 0, PREFILL_BLOCK, scale, choice,
+            )
         up = jnp.einsum("btr,rhd->bthd", rows[..., :r], kv_b)
         k_rope = jnp.broadcast_to(
             rows[:, :, None, r:], (b, s, h, rows.shape[-1] - r)
@@ -1656,6 +1669,36 @@ def _mla_prefill(
             jnp.concatenate([up[..., :dn], k_rope.astype(up.dtype)], axis=-1),
             up[..., dn:], valid, 0, PREFILL_BLOCK, scale, choice,
         )
+
+
+def _kernel_operands(q_nope, q_rope, rows, kv_b, dn, wide):
+    """``_mla_prefill``'s query, key and value as the prefill kernel
+    reads them: ``[B, S, H, wide]``, ``[B, S, H, wide]``, ``[B, S, H,
+    dv]``, each the result of ONE plain matmul or concatenate whose
+    minor axes are ``H x D`` (the chip then writes it row by row, as the
+    kernel's blocks read it; the einsum over ``[r, H, D]`` writes the
+    POSITIONS minor and a transposing copy of every operand follows).
+    The key is ``rows @ W``: a head's columns are its ``W_kv_b^K`` over
+    the latent values, the identity over the roped ones (every head's
+    roped key is the row's own: copied exactly, the sums of the latent
+    part are the einsum's), and zeros up to ``wide``, whole lanes (the
+    query's columns there are zeros too)."""
+    b, s, h, _ = q_nope.shape
+    r, dr, dv = kv_b.shape[0], q_rope.shape[-1], kv_b.shape[-1] - dn
+    w = jnp.zeros((r + dr, h, wide), kv_b.dtype)
+    w = w.at[:r, :, :dn].set(kv_b[..., :dn])
+    w = w.at[r:, :, dn:dn + dr].set(jnp.eye(dr, dtype=kv_b.dtype)[:, None])
+    key = rows.astype(kv_b.dtype) @ w.reshape(r + dr, h * wide)
+    value = rows[..., :r].astype(kv_b.dtype) @ (
+        kv_b[..., dn:].reshape(r, h * dv)
+    )
+    query = [q_nope, q_rope]
+    if wide != dn + dr:
+        query.append(jnp.zeros((b, s, h, wide - dn - dr), q_nope.dtype))
+    return (
+        jnp.concatenate(query, axis=-1), key.reshape(b, s, h, wide),
+        value.reshape(b, s, h, dv),
+    )
 
 
 # ---------------------------------------------------------------------------

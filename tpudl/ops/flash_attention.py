@@ -40,6 +40,7 @@ verification lives in scripts/tpu_dropout_check.py.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -640,3 +641,269 @@ def flash_attention(
         q, k, v, kvmask, seed, causal, scale, block_q, block_k, interpret,
         has_mask, float(dropout_rate),
     )
+
+
+# ---------------------------------------------------------------------------
+# The long prefill's attention: a fresh chunk over itself, forward only.
+# A sibling of ``_fwd`` with its own ``pallas_call``: the serving models
+# hold a position's heads side by side ([B, S, H x D]: a head is a column
+# block, and what the kernel writes is what ``o_proj`` reads), keys and
+# values of different widths, and a choice of keys a query; ``_fwd``
+# wants [B, H, S, D] (a transpose either side of the call), one width
+# and a key-validity row only.
+# ---------------------------------------------------------------------------
+
+#: The call's name: its row of a trace's breakdown.
+PREFILL_NAME = "prefill_attention"
+#: The tile of scores a grid step holds in VMEM, queries x keys. Swept
+#: on the chip at 8,192 rows x 64 heads x 256 / 256 (PERF.md, PR 46).
+PREFILL_BLOCK_Q = 1024
+PREFILL_BLOCK_K = 1024
+#: Where the running maximum starts: far above ``MASK_VALUE``, so that a
+#: masked score's weight is exp(MASK_VALUE - m) = 0 whatever the row has
+#: seen (a row with no key allowed sums to 0 and is written as zeros),
+#: and far below every real score.
+_MAX_FLOOR = -1e30
+
+_prefill_calls = 0
+
+
+def prefill_kernel_calls() -> int:
+    """``prefill_attention`` calls traced so far in this process."""
+    return _prefill_calls
+
+
+def note_prefill(program, rows: int, before: int) -> None:
+    """A prefill contract's note of itself (tpudl.models.generate
+    .prefill_fn), written while it is traced at ``rows``: how many of
+    its layers' attentions were the kernel's since the count read
+    ``before``, as ``program.attention_in_kernel[rows]``."""
+    note = program.__dict__.setdefault("attention_in_kernel", {})
+    note[rows] = _prefill_calls - before
+
+
+def prefill_kernel_ok(q, k, v, window) -> bool:
+    """Whether ``prefill_attention`` can stand in for the XLA blocks of
+    ``tpudl.models.llama._blocked_attention``, from what the program
+    can observe while it is traced: one TPU device (no mesh to
+    partition a kernel over), bfloat16, one KV head a query head (the
+    up-projected latent form; grouped heads against one K tile would be
+    another grid), no window (a band likewise), and widths of whole
+    128-value lanes, so that a head is a column block of [B, S, H x D]."""
+    from tpudl.ops.attention import is_tpu_backend
+    from tpudl.ops.grouped_matmul import one_device
+
+    return (
+        is_tpu_backend()
+        and one_device()
+        and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+        and k.shape[2] == v.shape[2] == q.shape[2]
+        and not window
+        and k.shape[-1] == q.shape[-1]
+        and q.shape[-1] % 128 == 0
+        and v.shape[-1] % 128 == 0
+    )
+
+
+def _lower_tiles(rows: int, bq: int, bk: int):
+    """The (query tile, key tile) pairs at or below the diagonal, a
+    query tile's keys in order: the kernel's walk. Tiles above it are
+    neither computed nor fetched."""
+    import numpy as np
+
+    pairs = [
+        (i, j)
+        for i in range(rows // bq)
+        for j in range(((i + 1) * bq - 1) // bk + 1)
+    ]
+    qi, kj = np.asarray(pairs, np.int32).T
+    return jnp.asarray(qi), jnp.asarray(kj)
+
+
+def _prefill_kernel(
+    qi_ref, kj_ref, first_ref,  # scalar prefetch: the walk, the padding
+    qt_ref, k_ref, v_ref, keep_ref,
+    o_ref,
+    q_scr, m_scr, l_scr, acc_scr,
+    *, scale: float, block_q: int, block_k: int,
+):
+    """One head's (block_q, block_k) tile of scores a step, under a
+    running maximum and denominator; everything between the two matrix
+    products is float32 and stays in VMEM. The query tile arrives with
+    its positions minor (``[Dk, block_q]``) and is turned once, at the
+    first of its key tiles. A key tile that lies wholly in the row's
+    left padding (before tile ``first``) is a step that does nothing."""
+    step = pl.program_id(2)
+    qi, kj = qi_ref[step], kj_ref[step]
+
+    # A power of two scales the query exactly, once a tile; any other
+    # scale multiplies the float32 scores.
+    exact = math.frexp(scale)[0] == 0.5
+
+    @pl.when(kj == 0)
+    def _init():
+        q = qt_ref[0].T
+        q_scr[...] = q * jnp.asarray(scale, q.dtype) if exact else q
+        m_scr[...] = jnp.full_like(m_scr, _MAX_FLOOR)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kj >= first_ref[pl.program_id(0)])
+    def _accumulate():
+        s = jax.lax.dot_general(
+            q_scr[...], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if not exact:
+            s = s * scale
+        s = jnp.where(keep_ref[0] != 0, s, MASK_VALUE)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(kj == ((qi + 1) * block_q - 1) // block_k)
+    def _finalize():
+        l = l_scr[:, :1]
+        o_ref[0] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)).astype(
+            o_ref.dtype
+        )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("heads", "scale", "block_q", "block_k", "interpret")
+)
+def _prefill_call(
+    qt, k, v, keep, first, *, heads, scale, block_q, block_k, interpret
+):
+    """The kernel's call, jitted on its own so that the layers of a
+    prefill program share one traced and lowered function (as the paged
+    kernels do). qt: [B, H x Dk, S]; k: [B, S, H x Dk]; v: [B, S, H x
+    Dv]; keep: int8 [B, S, S], nonzero where a query attends a key;
+    first: int32 [B], the key tiles that hold nothing but a row's left
+    padding (never computed, and the one fetched in their place is the
+    first that counts); S a whole number of both blocks."""
+    b, s, _ = k.shape
+    dk, dv = k.shape[-1] // heads, v.shape[-1] // heads
+    bq, bk = block_q, block_k
+    walk = _lower_tiles(s, bq, bk)
+    steps = int(walk[0].shape[0])
+
+    # Index maps over (batch, head, step of the walk): a head is the
+    # column block ``h``; a key tile before the row's first real one is
+    # asked for as that one (asked for again, it is not fetched again).
+    def query(b, h, t, qi, kj, first):
+        return b, h, qi[t]
+
+    def keys(b, h, t, qi, kj, first):
+        return b, jnp.maximum(kj[t], first[b]), h
+
+    def mask(b, h, t, qi, kj, first):
+        return b, qi[t], jnp.maximum(kj[t], first[b])
+
+    def result(b, h, t, qi, kj, first):
+        return b, qi[t], h
+
+    item = k.dtype.itemsize
+    tiles = item * bk * (dk + dv) + bq * bk  # a step's keys, values, mask
+    resident = (
+        2 * (tiles + item * bq * (dk + dv))  # double buffers
+        + bq * (item * dk + 4 * dv + 1024)   # scratch
+        + 4 * 4 * bq * bk                    # scores and weights, float32
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _prefill_kernel, scale=scale, block_q=bq, block_k=bk
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, heads, steps),
+            in_specs=[
+                pl.BlockSpec((1, dk, bq), query),
+                pl.BlockSpec((1, bk, dk), keys),
+                pl.BlockSpec((1, bk, dv), keys),
+                pl.BlockSpec((1, bq, bk), mask),
+            ],
+            out_specs=pl.BlockSpec((1, bq, dv), result),
+            scratch_shapes=[
+                pltpu.VMEM((bq, dk), qt.dtype),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s, heads * dv), qt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=resident + (16 << 20),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * heads * steps * bq * bk * (dk + dv),
+            transcendentals=b * heads * steps * bq * bk,
+            bytes_accessed=(
+                item * (qt.size + v.size) + b * heads * steps * tiles
+            ),
+        ),
+        interpret=interpret,
+        name=PREFILL_NAME,
+    )(*walk, first, qt, k, v, keep)
+
+
+def prefill_attention(
+    q, k, v, valid, scale=None, chosen=None,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+):
+    """Causal attention of a chunk over itself, one KV head a query
+    head, as ONE kernel call: no [H, S, S] and no [H, block, S] tensor
+    exists outside VMEM. q, k: [B, S, H, Dk]; v: [B, S, H, Dv] (the
+    widths may differ; each a whole number of 128-value lanes); valid:
+    [B, S] bool, the real slots (a left-padded row's first keys are
+    not); ``scale``: the softmax's, Dk ** -0.5 unless given; ``chosen``
+    [B, S, S] bool: an indexer's choice of keys a query. -> [B, S, H,
+    Dv]. Scores are float32 from the operands' own dtype, the weights
+    are rounded to the values' dtype for the second product; a query
+    with no key allowed gets zeros. Every causal tile that holds a real
+    slot is computed: a choice is a mask, no tile is skipped for it; a
+    key tile of nothing but left padding is."""
+    global _prefill_calls
+
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    if interpret is None:
+        interpret = _interpret_default()
+    bq = block_q or _fit_block(s, PREFILL_BLOCK_Q)
+    bk = block_k or _fit_block(s, PREFILL_BLOCK_K)
+    rows = _round_up(s, max(bq, bk))
+    slot = jnp.arange(s)
+    keep = (slot[None, :] <= slot[:, None])[None] & valid[:, None, :]
+    if chosen is not None:
+        keep = keep & chosen
+    keep = keep.astype(jnp.int8)
+    # The query with its POSITIONS minor: how XLA writes a latent
+    # model's query on the chip (its roped halves are narrower than a
+    # lane), so this transpose is none there, where [B, S, H x Dk] asked
+    # for a transposing copy of the whole query a layer (PERF.md, PR 46).
+    # The kernel turns a tile once. Keys and values come from matmuls
+    # the caller makes ([B, S, H x D], ``llama._kernel_operands``).
+    qt = q.transpose(0, 2, 3, 1).reshape(b, h * dk, s)
+    k, v = k.reshape(b, s, -1), v.reshape(b, s, -1)
+    if rows != s:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, rows - s)))
+        k, v = (jnp.pad(x, ((0, 0), (0, rows - s), (0, 0))) for x in (k, v))
+        keep = jnp.pad(keep, ((0, 0), (0, rows - s), (0, rows - s)))
+    _prefill_calls += 1
+    # Key tiles before a row's first real slot hold nothing to attend.
+    first = (jnp.argmax(valid, axis=1) // bk).astype(jnp.int32)
+    out = _prefill_call(
+        qt, k, v, keep, first, heads=h, scale=float(scale or dk ** -0.5),
+        block_q=bq, block_k=bk, interpret=bool(interpret),
+    )
+    return out[:, :s].reshape(b, s, h, dv)

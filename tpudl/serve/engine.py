@@ -725,6 +725,14 @@ class Engine:
             self._with_firsts(tokens, [(0, first)], from_device=True)
         self.prefill_lengths = tuple(sorted({*lengths, self.prompt_len}))
 
+    def _kernel_layers(self, rows: int) -> int:
+        """Layers of the prefill program at ``rows`` whose attention is
+        ONE kernel call (tpudl.ops.flash_attention.prefill_attention):
+        what the program noted of itself while it was traced; 0 for a
+        length not traced yet, an artifact, the XLA blocks."""
+        program = getattr(self.prefill_call, "__wrapped__", self.prefill_call)
+        return getattr(program, "attention_in_kernel", {}).get(rows, 0)
+
     def _with_firsts(self, tokens, firsts, from_device: bool = False):
         """A decode step's token vector as the device takes it: the
         host's array put where the selection would have left it (where
@@ -860,11 +868,14 @@ class Engine:
             # rows: the length the program ran; tokens: the prompt's
             # among them (what the padding and a shared prefix leave).
             # behind: a decode step was in flight at the dispatch.
+            # attention_in_kernel: the program's attention at that
+            # length was the prefill kernel's (its own note, traced).
             attrs = dict(
                 slot=slot, request_id=req.request_id,
                 queue_wait_s=t0 - entry.submitted_at,
                 prefix_hit_tokens=hit, rows=ran, tokens=n - hit,
                 behind=int(behind),
+                attention_in_kernel=int(self._kernel_layers(ran) > 0),
             )
         reg = registry()
         if hit:
@@ -1204,6 +1215,11 @@ class Engine:
         # in place (0 until its first dispatch traced it).
         reg.gauge("serve_paged_attention_in_place").set(
             self.cache.in_place_layers
+        )
+        # Layers of the longest prefill program whose attention is the
+        # prefill kernel's (0 where it runs the XLA blocks, or dense).
+        reg.gauge("serve_prefill_attention_in_kernel").set(
+            self._kernel_layers(self.prompt_len)
         )
         # Pool leaves held folded (one a latent layer; a k / v pool
         # never is).
