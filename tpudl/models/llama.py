@@ -94,8 +94,9 @@ class AttentionSpec:
     """One layer's grouped-query attention (``LlamaConfig.layer_spec``):
     ``scope`` "full_attention" or "window_attention", its query heads,
     its RoPE (``rotary_dim`` leading values of the head rotate, the
-    rest pass), ``window`` (0 = the whole context) and whether its
-    output is gated by head."""
+    rest pass), ``window`` (0 = the whole context), whether its
+    output is gated by head, its KV heads and whether its softmax has
+    a learned sink a query head."""
 
     scope: str
     num_heads: int
@@ -104,6 +105,8 @@ class AttentionSpec:
     rotary_dim: int
     window: int
     gate: bool
+    num_kv_heads: int
+    sink: bool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,10 +251,27 @@ class LlamaConfig:
     hyper_eps: float = 1e-6
     hyper_clamp: float = 30.0
     sandwich_norm: bool = False
+    # More that differs by layer kind (``layer_spec``; ``_check_kinds``):
+    # a sliding layer's KV heads (0: ``num_kv_heads``) and the share of
+    # its head that rotates; a learned sink a query head in the softmax
+    # of each kind. Grouped-query values ``value_head_size`` wide where
+    # that is not the head's width (``v_head_dim`` is the latent
+    # attention's), times the constant ``attention_value_scale``.
+    value_head_size: int = 0
+    sliding_num_kv_heads: int = 0
+    sliding_partial_rotary_factor: float = 1.0
+    full_attention_sink: bool = False
+    sliding_attention_sink: bool = False
+    attention_value_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def value_dim(self) -> int:
+        """A grouped-query head's value width."""
+        return self.value_head_size or self.head_dim
 
     def layer_spec(self, layer: int) -> "AttentionSpec":
         """What ``layer``'s grouped-query attention is made of."""
@@ -266,12 +286,16 @@ class LlamaConfig:
         if kind == "sliding_attention":
             return AttentionSpec(
                 "window_attention", heads, self.sliding_rope_theta, None,
-                self.head_dim, self.sliding_window, self.attention_gate,
+                int(self.head_dim * self.sliding_partial_rotary_factor),
+                self.sliding_window, self.attention_gate,
+                self.sliding_num_kv_heads or self.num_kv_heads,
+                self.sliding_attention_sink,
             )
         return AttentionSpec(
             "full_attention", heads, self.rope_theta, self.rope_scaling,
             int(self.head_dim * self.partial_rotary_factor), 0,
-            self.attention_gate,
+            self.attention_gate, self.num_kv_heads,
+            self.full_attention_sink,
         )
 
     @property
@@ -449,11 +473,13 @@ def rope(
     return out.astype(x.dtype)
 
 
-def _gqa_decode_attention(q, k, v, mask, scale=None):
+def _gqa_decode_attention(q, k, v, mask, scale=None, sink=None):
     """Decode-path attention with query heads grouped over shared KV
     heads. q: [B, S, H, D]; k: [B, T, Hkv, D]; v: [B, T, Hkv, Dv]; mask:
     [B, 1, S, T] (True = attend); ``scale``: the softmax's, D ** -0.5
-    unless given. f32 logits/softmax like ops.attention's."""
+    unless given; ``sink`` [H] float32: a learned score a query head
+    that takes its share of the softmax and adds no value (one more
+    term of the denominator). f32 logits/softmax like ops.attention's."""
     from tpudl.ops.attention import MASK_VALUE
 
     b, s, h, d = q.shape
@@ -463,7 +489,16 @@ def _gqa_decode_attention(q, k, v, mask, scale=None):
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * (scale or d ** -0.5)
     logits = logits.astype(jnp.float32)
     logits = jnp.where(mask[:, :, None, :, :], logits, MASK_VALUE)
-    weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    if sink is None:
+        weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    else:
+        with jax.named_scope("attention_sink"):
+            bias = sink.astype(jnp.float32).reshape(1, hkv, g, 1, 1)
+            top = jnp.maximum(logits.max(-1, keepdims=True), bias)
+            weights = jnp.exp(logits - top)
+            weights = (weights / (
+                weights.sum(-1, keepdims=True) + jnp.exp(bias - top)
+            )).astype(v.dtype)
     ctx = jnp.einsum("bhgqk,bkhd->bqhgd", weights, v)
     return ctx.reshape(b, s, h, v.shape[-1])
 
@@ -485,14 +520,16 @@ PREFILL_SCORE_BYTES = 256 << 20
 PREFILL_BLOCK = 256
 
 
-def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None):
+def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None,
+                       sink=None):
     """Causal grouped-query attention of a chunk over itself with no [H, S, S]
     tensor: one Pallas call where ``prefill_kernel_ok`` (ops.flash_attention),
     else a block of queries at a time against the keys up to its last slot (a
     ``window`` layer: the band). q: [B, S, H, D]; k, v: [B, S, Hkv, D] in slot
-    order; valid: [B, S] bool; ``chosen`` [B, S, S] bool: an indexer's choice."""
+    order; valid: [B, S] bool; ``chosen`` [B, S, S] bool: an indexer's choice;
+    ``sink`` [H]: ``_gqa_decode_attention``'s, which the kernel does not take."""
     from tpudl.ops.flash_attention import prefill_attention, prefill_kernel_ok
-    if prefill_kernel_ok(q, k, v, window):
+    if sink is None and prefill_kernel_ok(q, k, v, window):
         return prefill_attention(q, k, v, valid, scale, chosen)
     s = q.shape[1]
     out = []
@@ -507,7 +544,8 @@ def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None):
         if window:
             mask = mask & (q_slot - kv_slot < window)[None]
         out.append(_gqa_decode_attention(
-            q[:, at:end], k[:, low:end], v[:, low:end], mask[:, None], scale
+            q[:, at:end], k[:, low:end], v[:, low:end], mask[:, None], scale,
+            sink,
         ))
     return jnp.concatenate(out, axis=1)
 
@@ -541,26 +579,36 @@ class LlamaAttention(nn.Module):
         spec = cfg.layer_spec(self.layer)
         B, S, _ = hidden.shape
         hd, H, window = cfg.head_dim, spec.num_heads, spec.window
+        # A layer kind has its own KV heads; values may be another
+        # width than keys (the head's width on both counts unless the
+        # configuration says otherwise).
+        Hkv, vd = spec.num_kv_heads, cfg.value_dim
         # Pass ``t`` of a looped stack keeps cache leaves of its own.
         of_pass, last_pass = _pass_leaves(cfg, t)
         # Multi-tenant adapters (tpudl.models.lora.AdapterView): a slot's
         # LoRA delta rides AFTER the shared base projection, one dispatch.
         q = _proj(cfg, H * hd, "q_proj")(hidden)
         q = q + adapter_delta(adapters, "q_proj", hidden)
-        k = _proj(cfg, cfg.num_kv_heads * hd, "k_proj")(hidden)
+        k = _proj(cfg, Hkv * hd, "k_proj")(hidden)
         k = k + adapter_delta(adapters, "k_proj", hidden)
-        v = _proj(cfg, cfg.num_kv_heads * hd, "v_proj")(hidden)
+        v = _proj(cfg, Hkv * vd, "v_proj")(hidden)
         v = v + adapter_delta(adapters, "v_proj", hidden)
         q = q.reshape(B, S, H, hd)
-        k = k.reshape(B, S, cfg.num_kv_heads, hd)
-        v = v.reshape(B, S, cfg.num_kv_heads, hd)
+        k = k.reshape(B, S, Hkv, hd)
+        v = _scaled(cfg.attention_value_scale, v.reshape(B, S, Hkv, vd))
         q = rope(q, positions, spec.rope_theta, spec.rope_scaling,
                  spec.rotary_dim)
         k = rope(k, positions, spec.rope_theta, spec.rope_scaling,
                  spec.rotary_dim)
+        # A learned score a query head that every softmax of the layer
+        # counts and no value follows (float32, like a router's bias).
+        sink = (
+            self.param("sink", nn.initializers.zeros, (H,), jnp.float32)
+            if spec.sink else None
+        )
 
         def project_out(ctx):
-            """[B, S, H, hd] -> the layer's output: the gate by head
+            """[B, S, H, vd] -> the layer's output: the gate by head
             where the layer has one, then ``o_proj``."""
             if spec.gate:
                 with jax.named_scope("gate"):
@@ -570,7 +618,7 @@ class LlamaAttention(nn.Module):
                         name="g_proj",
                     )(hidden).astype(jnp.float32))
                     ctx = (ctx * gate[..., None]).astype(ctx.dtype)
-            ctx = ctx.reshape(B, S, H * hd)
+            ctx = ctx.reshape(B, S, H * vd)
             out = _proj(cfg, cfg.hidden_size, "o_proj")(ctx)
             return out + adapter_delta(adapters, "o_proj", ctx)
 
@@ -626,6 +674,7 @@ class LlamaAttention(nn.Module):
                 q, pk.value, pv.value, paged,
                 scale_k=sk.value if sk is not None else None,
                 scale_v=sv.value if sv is not None else None,
+                sink=sink,
             )
             return project_out(ctx)
 
@@ -638,11 +687,11 @@ class LlamaAttention(nn.Module):
             fresh = not self.has_variable("cache", of_pass("k"))
             ck = self.variable(
                 "cache", of_pass("k"),
-                jnp.zeros, (B, cfg.max_seq_len, cfg.num_kv_heads, hd), k.dtype,
+                jnp.zeros, (B, cfg.max_seq_len, Hkv, hd), k.dtype,
             )
             cv = self.variable(
                 "cache", of_pass("v"),
-                jnp.zeros, (B, cfg.max_seq_len, cfg.num_kv_heads, hd), v.dtype,
+                jnp.zeros, (B, cfg.max_seq_len, Hkv, vd), v.dtype,
             )
             # Per-slot validity: padded prompt slots hold garbage k/v and
             # must never be attended. Written alongside k/v from the
@@ -685,7 +734,7 @@ class LlamaAttention(nn.Module):
                 # A long prompt into an empty cache: the chunk is all
                 # there is to attend to, and it is attended in blocks.
                 return project_out(_blocked_attention(
-                    q, k, v, chunk_valid, window, PREFILL_BLOCK
+                    q, k, v, chunk_valid, window, PREFILL_BLOCK, sink=sink
                 ))
             k, v = ck.value, cv.value
             # Attend to slots that are (a) causally prior in WRITE order —
@@ -700,20 +749,23 @@ class LlamaAttention(nn.Module):
             # Grouped-query attention against the UNEXPANDED cache — never
             # materialize [B, max_seq, H, D] (the 4x KV blowup per decode
             # step that GQA exists to avoid).
-            return project_out(_gqa_decode_attention(q, k, v, mask))
+            return project_out(
+                _gqa_decode_attention(q, k, v, mask, sink=sink)
+            )
 
-        if window:
-            # Training / scoring with a window: the band under an
-            # explicit mask, in blocks.
+        if window or sink is not None:
+            # Training / scoring with a window (the band) or a sink (a
+            # term no ``attend`` implementation has): under an explicit
+            # mask, in blocks.
             valid = (
                 jnp.ones((B, S), jnp.bool_) if kv_mask is None
                 else kv_mask.astype(jnp.bool_)
             )
             return project_out(_blocked_attention(
-                q, k, v, valid, window, PREFILL_BLOCK
+                q, k, v, valid, window, PREFILL_BLOCK, sink=sink
             ))
-        if cfg.num_kv_heads != H:  # GQA: expand kv heads
-            reps = H // cfg.num_kv_heads
+        if Hkv != H:  # GQA: expand kv heads
+            reps = H // Hkv
             k = jnp.repeat(k, reps, axis=2)
             v = jnp.repeat(v, reps, axis=2)
         q = constrain(q, ("dp", "fsdp"), "sp", "tp", None)
@@ -1156,6 +1208,7 @@ def _check_block(cfg: LlamaConfig) -> None:
     _check_stream(cfg)
     _check_loop(cfg)
     _check_sparse(cfg)
+    _check_kinds(cfg)
     if cfg.block not in _BLOCKS:
         raise ValueError(
             f"block must be one of {sorted(_BLOCKS)}, got {cfg.block!r}"
@@ -1404,6 +1457,76 @@ class HyperBlock(nn.Module):
 
 #: ``LlamaConfig.block`` -> the module a layer is.
 _BLOCKS = {"llama": LlamaBlock, "shortcut": ShortcutBlock}
+
+
+def _check_kinds(cfg: LlamaConfig) -> None:
+    """``_check_block``'s checks of what a grouped-query layer kind may
+    have of its own (KV heads, rotary share, a sink in the softmax) and
+    of a value width and scale: what they are, and what they are not
+    wired to."""
+    sliding = (
+        cfg.sliding_num_kv_heads or cfg.sliding_partial_rotary_factor != 1.0
+        or cfg.sliding_attention_sink
+    )
+    sink = cfg.full_attention_sink or cfg.sliding_attention_sink
+    value = cfg.attention_value_scale != 1.0 or cfg.value_head_size
+    if not (sliding or sink or value):
+        return
+    if cfg.sliding_num_kv_heads < 0 or not (
+        0.0 < cfg.sliding_partial_rotary_factor <= 1.0
+    ):
+        raise ValueError(
+            f"sliding_num_kv_heads must be >= 0 (0: num_kv_heads) and "
+            f"sliding_partial_rotary_factor in (0, 1], got "
+            f"{cfg.sliding_num_kv_heads} and "
+            f"{cfg.sliding_partial_rotary_factor}"
+        )
+    if sliding and "sliding_attention" not in (cfg.layer_types or ()):
+        raise ValueError(
+            "sliding_num_kv_heads, sliding_partial_rotary_factor and "
+            "sliding_attention_sink describe 'sliding_attention' layers: "
+            "layer_types names none"
+        )
+    if cfg.attention == "mla":
+        raise ValueError(
+            "a softmax sink, a value scale and KV heads by layer kind are "
+            "the grouped-query block's: latent attention keeps ONE "
+            "headless row a position, its value width is the up-"
+            "projection's (v_head_dim, not value_head_size), and neither "
+            "of its forms has a sink term"
+        )
+    if cfg.block != "llama":
+        raise ValueError(
+            f"block={cfg.block!r} is built of latent attentions: a sink, "
+            f"a value width or scale and KV heads by layer kind are "
+            f"LlamaAttention's"
+        )
+    if cfg.hyper_streams:
+        raise ValueError(
+            "hyper_streams is not wired to a sink, a value width or scale "
+            "or KV heads by layer kind: HyperBlock has been built and "
+            "checked without them"
+        )
+    if cfg.loop_passes > 1 or cfg.sandwich_norm:
+        raise ValueError(
+            "a looped (sandwich-normed) stack keeps a k / v pool pair a "
+            "(pass, layer) of ONE shape under one table: a sink, a value "
+            "width or scale and KV heads by layer kind are not wired to it"
+        )
+    if cfg.index_topk:
+        raise ValueError(
+            "an indexer's choice is attended by the latent attention: a "
+            "sink, a value width or scale and KV heads by layer kind are "
+            "the grouped-query block's"
+        )
+    heads = set(cfg.num_heads_per_layer or (cfg.num_heads,))
+    for name, kv in (("num_kv_heads", cfg.num_kv_heads),
+                     ("sliding_num_kv_heads", cfg.sliding_num_kv_heads)):
+        if kv and any(h % kv for h in heads):
+            raise ValueError(
+                f"{name} {kv} must divide a layer's query heads "
+                f"{sorted(heads)}"
+            )
 
 
 def _check_stream(cfg: LlamaConfig) -> None:

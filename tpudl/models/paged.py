@@ -20,9 +20,12 @@ both:
   inside the decode gather (one fused multiply on the gathered view).
 
 A pool leaf is HELD in the shape the chip lays out major-to-minor
-with a page contiguous (``page_fold``, the one place that knows): a
-row with a head axis, or whose width is a whole number of 128-value
-lanes, is held as ``[num_pages, page_size, ...]``; a headless row of
+with a page contiguous (``page_fold`` and ``heads_in_lanes``, the one
+place that knows): a row with a head axis, or whose width is a whole
+number of 128-value lanes, is held as ``[num_pages, page_size, ...]``
+(a layer with a head width that is NOT whole lanes, keys 192 wide,
+holds its heads merged into the lanes, ``[num_pages, page_size, Hkv *
+D]``, so that no head's row is padded); a headless row of
 another width C (a latent layer's 576) is held FOLDED,
 ``[num_pages, page_size / f, f * C]``: position ``t`` of a page lies
 in row ``t // f``, lanes ``(t % f) * C ...``. The bytes are the same
@@ -233,6 +236,33 @@ def page_fold(page_size: int, tail, dtype) -> int:
     return min(whole_tiles or folds or [1])
 
 
+def heads_in_lanes(tails, dtype) -> bool:
+    """Whether a LAYER's rows with a head axis are held with the heads
+    merged into the lane axis, ``[NP, ps, Hkv * D]``: the second rule
+    of the pool's shape, from what the cache can observe when it
+    builds a layer's leaves (every row leaf's trailing shape, the
+    stored dtype).
+
+    The chip pads the minor axis of a buffer to whole 128-value lanes:
+    a leaf ``[NP, ps, Hkv, 192]`` would take a third more bytes in HBM
+    and in every DMA of a page than its rows have. Where one of a
+    layer's head widths is not whole lanes and every ``Hkv * D`` of it
+    is, all its leaves are held merged (k and v alike: one kernel
+    reads both, tpudl.ops.paged_attention): position ``t`` of a page
+    in row ``t``, head ``h`` in lanes ``h * D ...``; the bytes are the
+    declared row-major bytes. False = held as declared: every head
+    width of whole lanes (the pools that were there, bit for bit), a
+    headless row (``page_fold``'s), a merged width that is not whole
+    lanes either, and an int8 pool, whose dequant scales are one a
+    head."""
+    tails = [tuple(int(d) for d in tail) for tail in tails]
+    if jnp.dtype(dtype) == jnp.int8 or any(len(t) != 2 for t in tails):
+        return False
+    return any(t[1] % LANES for t in tails) and not any(
+        (t[0] * t[1]) % LANES for t in tails
+    )
+
+
 def held_fold(pages, page_size: int) -> int:
     """The fold a pool leaf is held in, read off its shape."""
     return page_size // int(pages.shape[1])
@@ -259,7 +289,9 @@ def paged_write(
     (the freshly projected + RoPE'd k or v; [B, Hkv, D] is accepted as
     the S=1 single-token form). A pool with no head axis ([NP, ps, C],
     scales [NP, ps]: a latent cache's one row a position) takes
-    ``value`` [B, S, C] the same way; held folded ([NP, ps / f, f * C],
+    ``value`` [B, S, C] the same way, and ``value`` [B, S, Hkv, D]
+    where the heads are held merged into its lanes (C = Hkv x D,
+    ``heads_in_lanes``); held folded ([NP, ps / f, f * C],
     ``page_fold``) the row lands in held row ``off // f``, lanes
     ``(off % f) * C ...``: the pool is never reshaped.
     Token j of slot b lands at physical
@@ -272,6 +304,10 @@ def paged_write(
     a clamped write would corrupt KEPT rows of the same slot."""
     if value.ndim == pages.ndim - 1:
         value = value[:, None]
+    if value.ndim == pages.ndim + 1:
+        # Held with the heads merged into the lanes (``heads_in_lanes``):
+        # the chunk's rows take that form, the pool is never reshaped.
+        value = value.reshape(*value.shape[:2], -1)
     s = value.shape[1]
     ps = view.page_size
     p = view.page_table.shape[1]
@@ -323,8 +359,9 @@ def paged_gather(
     gather path; tpudl.ops.paged_attention reads a pool in place where
     it can, and then this is not called).
 
-    Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis)
-    in ``compute_dtype`` where L = pages_per_slot x page_size; from a
+    Returns [B, L, Hkv, D] ([B, L, C] from a pool with no head axis,
+    or whose heads are held merged into its lanes: the caller splits
+    them) in ``compute_dtype`` where L = pages_per_slot x page_size; from a
     pool held folded, the HELD rows [B, L / f, f * C] (logical position
     ``t`` in row ``t // f``, lanes ``(t % f) * C ...``), because
     splitting the lanes of a view this size is a copy of it on the

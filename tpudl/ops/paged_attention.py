@@ -68,11 +68,20 @@ PAGES_PER_BLOCK = 8
 SCOPE = "paged_attention"
 
 
-def in_place_ok(q, pages, view) -> bool:
-    """Whether the kernel can serve this layer, from what the program
+def in_place_ok(q, pages, view, pages_v=None, sink=None) -> bool:
+    """Whether a kernel can serve this layer, from what the program
     can observe at trace time: the view's static facts and the shapes.
-    ``pages`` is the k pool (v is its twin)."""
-    if view.quantized or view.sharded or pages.ndim != 4:
+    ``pages`` is the k pool. A pool pair held as declared, ``[NP, ps,
+    Hkv, D]``, is ``_kernel``'s (v is k's twin, no sink); one held with
+    the heads merged into the lanes is ``_merged_kernel``'s
+    (``merged_in_place_ok``)."""
+    if view.quantized or view.sharded:
+        return False
+    if pages.ndim == 3:
+        return pages_v is not None and merged_in_place_ok(q, pages, pages_v)
+    if pages.ndim != 4 or sink is not None or (
+        pages_v is not None and pages_v.shape != pages.shape
+    ):
         return False
     _, ps, hkv, d = pages.shape
     # A page is a whole number of the dtype's (sublane x 128) tiles.
@@ -85,7 +94,8 @@ def in_place_ok(q, pages, view) -> bool:
     )
 
 
-def paged_attention_ref(q, pages_k, pages_v, view, scale_k=None, scale_v=None):
+def paged_attention_ref(q, pages_k, pages_v, view, scale_k=None, scale_v=None,
+                        sink=None):
     """Today's path: every slot's logical view gathered dense out of
     both pools, then grouped-query attention under the mask."""
     from tpudl.models.llama import _gqa_decode_attention
@@ -93,8 +103,13 @@ def paged_attention_ref(q, pages_k, pages_v, view, scale_k=None, scale_v=None):
 
     kf = paged_gather(pages_k, scale_k, view, q.dtype)
     vf = paged_gather(pages_v, scale_v, view, q.dtype)
+    if kf.ndim == 3:
+        # Heads held merged into the lanes: a key head is a query's width.
+        hkv = kf.shape[-1] // q.shape[-1]
+        kf = kf.reshape(*kf.shape[:2], hkv, -1)
+        vf = vf.reshape(*vf.shape[:2], hkv, -1)
     return _gqa_decode_attention(
-        q, kf, vf, paged_attend_mask(view, chunk=q.shape[1])
+        q, kf, vf, paged_attend_mask(view, chunk=q.shape[1]), sink=sink
     )
 
 
@@ -261,16 +276,27 @@ def _fused(q, pages_k, pages_v, page_table, start, lens, *, interpret: bool):
         return out[:, : s * h].reshape(b, s, h, d)
 
 
-def paged_attention_fused(q, pages_k, pages_v, view, interpret: bool):
+def paged_attention_fused(q, pages_k, pages_v, view, interpret: bool,
+                          sink=None):
     """The Pallas path. ``q`` [B, S, H, D]; ``pages_k`` / ``pages_v``
-    [NP, ps, Hkv, D]; returns [B, S, H, D] in ``q.dtype``."""
-    if not in_place_ok(q, pages_k, view):
+    [NP, ps, Hkv, D], or [NP, ps, Hkv x D] and [NP, ps, Hkv x Dv] with
+    the heads merged into the lanes; returns [B, S, H, Dv] in
+    ``q.dtype``."""
+    if not in_place_ok(q, pages_k, view, pages_v, sink):
         raise ValueError(
-            "the paged-attention kernel reads an unquantized k / v pool "
-            "pair [NP, ps, Hkv, D] on one device with D a multiple of "
-            f"128; got q {q.shape}, pool {pages_k.shape} "
-            f"{pages_k.dtype}, quantized={view.quantized}, "
+            "the paged-attention kernels read an unquantized k / v pool "
+            "pair on one device: [NP, ps, Hkv, D] twice with D a "
+            "multiple of 128 and no sink, or [NP, ps, Hkv x D] beside "
+            "[NP, ps, Hkv x Dv] with both merged widths and Dv "
+            f"multiples of 128; got q {q.shape}, pools {pages_k.shape} "
+            f"{pages_k.dtype} and {pages_v.shape}, sink "
+            f"{sink is not None}, quantized={view.quantized}, "
             f"sharded={view.sharded}"
+        )
+    if pages_k.ndim == 3:
+        return _fused_merged(
+            q, pages_k, pages_v, view.page_table, view.start, view.lens,
+            sink, interpret=interpret,
         )
     return _fused(
         q, pages_k, pages_v, view.page_table, view.start, view.lens,
@@ -280,25 +306,283 @@ def paged_attention_fused(q, pages_k, pages_v, view, interpret: bool):
 
 def paged_attention(
     q, pages_k, pages_v, view, *,
-    scale_k=None, scale_v=None,
+    scale_k=None, scale_v=None, sink=None,
     impl: str = "auto", interpret: Optional[bool] = None,
 ):
     """Attention of ``q`` [B, S, H, D] over each slot's logical
     positions ``[start, lens + j]`` (query ``j`` of the chunk) of the
     paged pools, ``paged_write`` having put this step's rows there.
-    Returns [B, S, H, D]. ``view`` records which path the layer took
-    (``PagedView.took``). See the module docstring for the seam."""
+    Returns [B, S, H, Dv]. ``sink`` [H] float32: a learned score a
+    query head in the softmax, which no value follows. ``view`` records
+    which path the layer took (``PagedView.took``). See the module
+    docstring for the seam."""
     if impl == "auto":
         impl = (
             "fused"
-            if is_tpu_backend() and in_place_ok(q, pages_k, view)
+            if is_tpu_backend()
+            and in_place_ok(q, pages_k, view, pages_v, sink)
             else "reference"
         )
     fused, interpret = resolve_impl(impl, interpret)
     view.took.append(fused)
     if fused:
-        return paged_attention_fused(q, pages_k, pages_v, view, interpret)
-    return paged_attention_ref(q, pages_k, pages_v, view, scale_k, scale_v)
+        return paged_attention_fused(
+            q, pages_k, pages_v, view, interpret, sink
+        )
+    return paged_attention_ref(
+        q, pages_k, pages_v, view, scale_k, scale_v, sink
+    )
+
+
+# ---------------------------------------------------------------------------
+# A k / v pool pair held with the heads MERGED into the lanes
+# (tpudl.models.paged.heads_in_lanes: keys 192 wide beside values 128
+# wide, KV heads that differ by layer kind), read the same way. A second
+# entry with its own ``pallas_call`` and the first one's page walk (``span``,
+# ``each_page``: a slot's live pages, double-buffered, the next slot's first
+# block in flight): the rows are other rows, so the arithmetic is another,
+# and ``_kernel`` above is left as it is, line for line, for the pools that
+# reach it today. Not one kernel with branches: there a page is ``ps x Hkv``
+# rows of ONE head's width and a query keeps its own head's COLUMNS of the
+# scores; here a page is ``ps`` rows of all heads' widths and a query keeps
+# its own head's LANES.
+# ---------------------------------------------------------------------------
+
+#: Pages fetched into one VMEM block of the merged kernel: a window
+#: layer's whole ring (9 pages of 16 for a window of 128) is ONE block,
+#: and a page here is all heads of 16 positions (24-48 KB a pool).
+MERGED_PAGES_PER_BLOCK = 16
+
+
+def merged_in_place_ok(q, pages_k, pages_v) -> bool:
+    """``in_place_ok`` for a pool pair held ``[NP, ps, Hkv x D]`` /
+    ``[NP, ps, Hkv x Dv]``: the key row is a whole number of query
+    widths (that number is Hkv), both merged widths and a value head
+    are whole lanes, the query heads group over the KV heads, and a
+    page is whole tiles of the dtype."""
+    from tpudl.models.paged import LANES
+
+    if pages_k.ndim != 3 or pages_v.ndim != 3:
+        return False
+    (_, ps, wk), (_, ps_v, wv) = pages_k.shape, pages_v.shape
+    d = q.shape[-1]
+    if wk % d or ps_v != ps or pages_v.dtype != pages_k.dtype:
+        return False
+    hkv = wk // d
+    sublanes = 8 * (4 // jnp.dtype(pages_k.dtype).itemsize)
+    return (
+        wv % hkv == 0
+        and (wv // hkv) % LANES == 0
+        and wk % LANES == 0
+        and q.shape[2] % hkv == 0
+        and ps % sublanes == 0
+    )
+
+
+def _merged_kernel(
+    table_ref, start_ref, lens_ref,  # scalar prefetch
+    *refs,
+    page_size: int, heads: int, kv_heads: int, chunk: int, ppb: int,
+    scale: float, has_sink: bool,
+):
+    """One slot a grid step, an inner loop over the slot's blocks of
+    pages; the first block of the next slot is in flight while this
+    slot's last is attended (``_latent_kernel``'s frame, ``_kernel``'s
+    walk of two pools).
+
+    ``q_ref`` [1, R, Hkv x D], row ``r = s * H + h``: head ``h``'s
+    query in the lanes of ITS KV head and zeros elsewhere, so ONE
+    matmul against a block's rows ``[ppb * ps, Hkv x D]`` scores every
+    query row against every position, [R, ppb * ps], with no column of
+    another head to mask. ``sink_ref`` [R, 1] float32 (``has_sink``):
+    in a running softmax a sink is only the state a row STARTS from
+    (running maximum the sink, running sum 1, accumulator 0, in place
+    of -inf, 0, 0): no pass of its own. ``p . v`` against the block's
+    value rows [ppb * ps, Hkv x Dv] accumulates every KV head's values
+    for every row; a row keeps its own head's lanes once, at the end.
+    ``o_ref`` [1, R, Dv]; ``kbuf`` / ``vbuf`` [2, ppb, ps, width]: a
+    page is a LEADING index of the buffer; ``sem`` one DMA semaphore a
+    buffer and pool; ``turn`` (SMEM) the buffer the next block lands
+    in."""
+    if has_sink:
+        q_ref, sink_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, turn = refs
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, turn = refs
+    b = pl.program_id(0)
+    num_slots = pl.num_programs(0)
+    pages = table_ref.shape[1]
+    rows = q_ref.shape[1]
+    dv = o_ref.shape[2]
+    cols = ppb * page_size
+    group = heads // kv_heads
+
+    def span(slot):
+        """First and last logical page ``slot`` attends."""
+        lo = start_ref[slot] // page_size
+        hi = jnp.minimum(
+            (lens_ref[slot] + chunk - 1) // page_size, pages - 1
+        )
+        return lo, jnp.maximum(hi, lo)
+
+    def each_page(slot, j, which, act):
+        """``act`` on the copies of block ``j`` of ``slot`` into buffer
+        ``which``: one a live page and pool. Start and wait walk the
+        same pages (a loop, not unrolled copies: set-up time is
+        judged)."""
+        lo, hi = span(slot)
+        first = lo + j * ppb
+
+        def page(i, _):
+            phys = table_ref[slot, first + i]
+            for hbm, vmem, pool in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                act(pltpu.make_async_copy(
+                    hbm.at[phys], vmem.at[which, i], sem.at[which, pool]
+                ))
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(hi - first + 1, ppb), page, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # A page that was not fetched holds what the buffer held
+        # before: its keys are masked whatever they are, its values
+        # meet a weight of exactly 0, which only a finite value
+        # survives.
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        turn[0] = 0
+        each_page(0, 0, 0, lambda copy: copy.start())
+
+    lo, hi = span(b)
+    blocks = (hi - lo) // ppb + 1
+    q = q_ref[0]
+    first = start_ref[b]
+    # Row r is query s = r // H; column c is position c of the block.
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    row_s = jnp.minimum(row // heads, chunk - 1)
+    # A verify window may overshoot a nearly full slot: positions past
+    # the table's capacity do not exist.
+    upper = jnp.minimum(lens_ref[b] + row_s, pages * page_size - 1)
+
+    def block_body(j, carry):
+        m, l, acc, which = carry
+        last = j + 1 >= blocks
+        nb = jnp.where(last, b + 1, b)
+        nj = jnp.where(last, 0, j + 1)
+
+        @pl.when(nb < num_slots)
+        def _():
+            each_page(nb, nj, 1 - which, lambda copy: copy.start())
+
+        each_page(b, j, which, lambda copy: copy.wait())
+        k = kbuf[which].reshape(cols, kbuf.shape[-1])
+        v = vbuf[which].reshape(cols, vbuf.shape[-1])
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        pos = (lo + j * ppb) * page_size + col
+        s = jnp.where((pos >= first) & (pos <= upper), s, MASK_VALUE)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        return m_new, l, acc, 1 - which
+
+    if has_sink:
+        m0 = sink_ref[...]
+        l0 = jnp.ones((rows, 1), jnp.float32)
+    else:
+        m0 = jnp.full((rows, 1), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((rows, 1), jnp.float32)
+    acc0 = jnp.zeros((rows, kv_heads * dv), jnp.float32)
+    _, l, acc, which = jax.lax.fori_loop(
+        0, blocks, block_body, (m0, l0, acc0, turn[0])
+    )
+    turn[0] = which
+    # Row r is query head r % H, whose values are KV head (r % H) // g's.
+    own = (
+        jax.lax.broadcasted_iota(jnp.int32, (rows, dv), 0) % heads
+    ) // group
+    out = jnp.zeros((rows, dv), jnp.float32)
+    for h in range(kv_heads):
+        out = jnp.where(own == h, acc[:, h * dv:(h + 1) * dv], out)
+    o_ref[0] = (out / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fused_merged(
+    q, pages_k, pages_v, page_table, start, lens, sink, *, interpret: bool,
+):
+    """The merged kernel's call, jitted on its own like ``_fused``:
+    the layers of one kind share one lowering, and the scope is named
+    inside so the shared function carries it."""
+    b, s, h, d = q.shape
+    _, ps, wk = pages_k.shape
+    wv = pages_v.shape[-1]
+    hkv = wk // d
+    dv = wv // hkv
+    rows = round_up(s * h, 16)
+    ppb = min(MERGED_PAGES_PER_BLOCK, int(page_table.shape[1]))
+    with jax.named_scope(SCOPE):
+        # Head h's query in the lanes of its KV head, zeros elsewhere.
+        own = (
+            jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :]
+        )
+        q3 = jnp.where(
+            own[None, None, :, :, None],
+            q.astype(pages_k.dtype)[:, :, :, None, :], 0,
+        ).reshape(b, s * h, wk)
+        if rows != s * h:
+            q3 = jnp.pad(q3, ((0, 0), (0, rows - s * h), (0, 0)))
+        operands = [q3]
+        in_specs = [pl.BlockSpec((1, rows, wk), lambda i, *_: (i, 0, 0))]
+        if sink is not None:
+            column = jnp.tile(sink.astype(jnp.float32), s)
+            operands.append(
+                jnp.pad(column, (0, rows - s * h)).reshape(rows, 1)
+            )
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+        out = pl.pallas_call(
+            functools.partial(
+                _merged_kernel, page_size=ps, heads=h, kv_heads=hkv,
+                chunk=s, ppb=ppb, scale=d ** -0.5,
+                has_sink=sink is not None,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(b,),
+                in_specs=in_specs + [in_hbm, in_hbm],
+                out_specs=pl.BlockSpec(
+                    (1, rows, dv), lambda i, *_: (i, 0, 0)
+                ),
+                scratch_shapes=[
+                    pltpu.VMEM((2, ppb, ps, wk), pages_k.dtype),
+                    pltpu.VMEM((2, ppb, ps, wv), pages_v.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.SMEM((1,), jnp.int32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, rows, dv), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+            name="merged_paged_attention",
+        )(
+            page_table.astype(jnp.int32),
+            start.astype(jnp.int32),
+            lens.astype(jnp.int32),
+            *operands,
+            pages_k,
+            pages_v,
+        )
+        return out[:, : s * h].reshape(b, s, h, dv)
 
 
 # ---------------------------------------------------------------------------

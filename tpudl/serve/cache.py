@@ -429,7 +429,11 @@ class PagedKVCache:
     layout on the chip is major-to-minor with a page contiguous, so
     that no program re-lays the pool on the way in or out. A row with
     a head axis, or one that is whole 128-value lanes wide, is held as
-    declared (``[num_pages, page_size, Hkv, D]``); a headless row of
+    declared (``[num_pages, page_size, Hkv, D]``; a layer one of whose
+    head widths is not whole lanes, keys 192 wide beside values 128
+    wide, holds both leaves with the heads merged into the lanes,
+    ``[num_pages, page_size, Hkv * D]``, so that no head's row is
+    padded to 256: tpudl.models.paged.heads_in_lanes); a headless row of
     another width C (the latent 576) is held FOLDED, ``f`` positions
     to a held row: ``[num_pages, page_size / f, f * C]``, position
     ``t`` of a page in row ``t // f``, lanes ``(t % f) * C ...``
@@ -577,16 +581,29 @@ class PagedKVCache:
             -(-self.window // self.page_size) + 1 if self.window else 0
         )
         self.num_ring_pages = self.num_slots * self.ring_pages + 1
+        # Bytes ONE position takes over all the full-context layers'
+        # pools, and over all the window layers' (``bytes_live``).
+        self.row_bytes = [0, 0]
 
         def to_pool(attn: dict) -> dict:
-            from tpudl.models.paged import page_fold
+            from tpudl.models.paged import heads_in_lanes, page_fold
 
             pages = self.num_ring_pages if _window_of(attn) else self.num_pages
             page = (pages, self.page_size)
             pool = {}
-            for name in _row_names(attn):
+            names = _row_names(attn)
+            # A layer with a head width that is not whole lanes holds
+            # its heads merged into the lanes, k and v alike, so that
+            # the chip pads no head's row.
+            merged = bool(names) and heads_in_lanes(
+                [attn[n].shape[2:] for n in names],
+                jnp.int8 if self.quantized else attn[names[0]].dtype,
+            )
+            for name in names:
                 row = attn[name]
                 tail = tuple(int(d) for d in row.shape[2:])
+                if merged:
+                    tail = (tail[0] * tail[1],)
                 dtype = jnp.int8 if self.quantized else row.dtype
                 # Held in the shape the chip lays out with a page
                 # contiguous: a headless row that is not whole lanes
@@ -604,6 +621,9 @@ class PagedKVCache:
                     pool[f"scale_{name}"] = jnp.zeros(
                         page + tail[:-1], jnp.float32
                     )
+            self.row_bytes[bool(_window_of(attn))] += sum(
+                leaf.nbytes for leaf in pool.values()
+            ) // (pages * self.page_size)
             return pool
 
         self.cache = _map_attn_caches(template, to_pool)
@@ -700,6 +720,9 @@ class PagedKVCache:
         obj.page_table = np.zeros(
             (obj.num_slots, obj.pages_per_slot), np.int32
         )
+        obj.row_bytes = [sum(
+            leaf.nbytes for leaf in jax.tree.leaves(obj.cache)
+        ) // (obj.num_pages * obj.page_size), 0]
         # An exported artifact has one table: no window group.
         obj.window = obj.ring_pages = 0
         obj.num_ring_pages = 1
@@ -761,6 +784,19 @@ class PagedKVCache:
         if not self.window:
             return 0
         return int(np.minimum(self.lens - self.start, self.window).sum())
+
+    @property
+    def bytes_live(self) -> int:
+        """Bytes of cache rows a decode step's attention reads over
+        both groups: ``tokens_live`` positions of every full-context
+        layer's rows and ``tokens_live_window`` of every window
+        layer's, each at its own pools' row bytes. Layers whose rows
+        differ (KV heads or widths by kind) make positions a poor
+        count of what a step reads; this is the count."""
+        return (
+            self.tokens_live * self.row_bytes[0]
+            + self.tokens_live_window * self.row_bytes[1]
+        )
 
     def _seated(self, slot: int, pages: int) -> None:
         """``slot`` was just seated on ``pages`` pages, with its
@@ -1166,7 +1202,11 @@ class PagedKVCache:
                             rows,
                             [(0, seq - span)] + [(0, 0)] * (rows.ndim - 1),
                         )
-                    out[kv] = rows[None].astype(tmpl[kv].dtype)
+                    # (A row whose heads the pool holds merged into its
+                    # lanes takes its declared form here.)
+                    out[kv] = rows.reshape(tmpl[kv].shape).astype(
+                        tmpl[kv].dtype
+                    )
                 out["valid"] = (jnp.arange(seq) < m_tok)[None, :]
                 out["index"] = jnp.asarray(m_tok, tmpl["index"].dtype)
                 return out

@@ -1203,6 +1203,7 @@ class Engine:
         )
         reg.gauge("serve_kv_pages_reserved").set(self.cache.pages_reserved)
         reg.gauge("serve_kv_tokens_live").set(self.cache.tokens_live)
+        reg.gauge("serve_kv_bytes_live").set(self.cache.bytes_live)
         if self.cache.window:
             # The window layers' rings, beside the full-context group.
             reg.gauge("serve_kv_pages_reserved_window").set(
@@ -1232,7 +1233,8 @@ class Engine:
 
     def _paged_attrs(self, pages_live: int) -> dict:
         """What a ``decode_step`` span says of the paged cache: the
-        pages the seated slots hold, the positions the step read,
+        pages the seated slots hold, the positions the step read and
+        their bytes over both groups (``PagedKVCache.bytes_live``),
         whether its attention read the pool in place (the program's
         own note, ``PagedKVCache.in_place_layers``) and the pages an
         in-place step visits, counted on the host before dispatch. (How
@@ -1241,6 +1243,7 @@ class Engine:
         cache = self.cache
         attrs = {"pages_reserved": cache.pages_reserved,
                  "tokens_live": cache.tokens_live,
+                 "kv_bytes_live": cache.bytes_live,
                  "kv_in_place": int(cache.in_place_layers > 0),
                  "pages_live": pages_live}
         if cache.window:

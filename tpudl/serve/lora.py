@@ -63,11 +63,12 @@ def _site_shapes(cfg) -> Dict[str, Tuple[int, int]]:
     projections, so only the attention sites exist there."""
     h = cfg.hidden_size
     hd = cfg.head_dim
+    vd = getattr(cfg, "value_dim", hd)  # values may be another width
     sites = {
         "q_proj": (h, cfg.num_heads * hd),
         "k_proj": (h, cfg.num_kv_heads * hd),
-        "v_proj": (h, cfg.num_kv_heads * hd),
-        "o_proj": (cfg.num_heads * hd, h),
+        "v_proj": (h, cfg.num_kv_heads * vd),
+        "o_proj": (cfg.num_heads * vd, h),
     }
     if getattr(cfg, "moe_experts", 0) == 0:
         sites.update({
