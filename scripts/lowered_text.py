@@ -99,9 +99,19 @@ def decoder_programs(root: str, param_dtype=None):
         ids = jax.ShapeDtypeStruct(
             (1, sess["prompt_window"]), jnp.int32, sharding=chip)
         yield name, "prefill", prefill, (params, ids, ids)
+        # The cache template as ``ServeSession.from_model`` derives it:
+        # one prefilled row, traced at the batch-1 shape the window's
+        # prompts run at, at every slot (a batch of ``slots`` rows is
+        # another program: past ``PREFILL_SCORE_BYTES`` where no
+        # session's is, so it would trace a kernel that no cell runs).
         slots = sess["num_slots"]
-        ids = jax.ShapeDtypeStruct((slots, sess["prompt_window"]), jnp.int32)
-        _, template, *_ = jax.eval_shape(prefill, params, ids, ids)
+        _, row, *_ = jax.eval_shape(prefill, params, ids, ids)
+        template = jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                (slots, *leaf.shape[1:]), leaf.dtype
+            ) if leaf.ndim >= 2 else leaf,
+            row,
+        )
 
         def pools_and_addressing():
             cache = PagedKVCache(template, page_size=sess["page_size"])

@@ -223,10 +223,11 @@ def _sum_choices(per_token, n, rows=4096):
     )
 
 
-def _prefill_attention(rows, heads, dk, dv, choice):
+def _prefill_attention(rows, heads, dk, dv, choice, kv_heads=None):
     import importlib
 
     fa = importlib.import_module("tpudl.ops.flash_attention")
+    kv_heads = kv_heads or heads
 
     def fn(q, k, v, valid, chosen=None):
         return fa.prefill_attention(
@@ -234,8 +235,8 @@ def _prefill_attention(rows, heads, dk, dv, choice):
         )
 
     return fn, (
-        _s((1, rows, heads, dk), bf16), _s((1, rows, heads, dk), bf16),
-        _s((1, rows, heads, dv), bf16), _s((1, rows), jnp.bool_),
+        _s((1, rows, heads, dk), bf16), _s((1, rows, kv_heads, dk), bf16),
+        _s((1, rows, kv_heads, dv), bf16), _s((1, rows), jnp.bool_),
         *([_s((1, rows, rows), jnp.bool_)] if choice else []),
     )
 
@@ -291,6 +292,14 @@ CASES = {
         8192, 64, 256, 256, True),
     "xing4/prefill_attention": lambda: _prefill_attention(
         4096, 32, 256, 128, False),
+    # ... and the full layers of the grouped-query prefills at their
+    # cells' longer length (PR 48), a group's heads a grid step: MiMo's
+    # (64 query heads on 4 KV heads, keys 192 beside values 128) and
+    # Laguna's (48 on 8). Their sliding layers keep the XLA blocks.
+    "mimo/prefill_attention": lambda: _prefill_attention(
+        16384, 64, 192, 128, False, kv_heads=4),
+    "laguna/prefill_attention": lambda: _prefill_attention(
+        4096, 48, 128, 128, False, kv_heads=8),
 }
 
 
@@ -664,16 +673,48 @@ def _on_one_chip(monkeypatch):
     monkeypatch.setattr(gm, "one_device", lambda: True)
 
 
-def _prefill_kernel_calls(text: str) -> int:
+def _prefill_kernel_calls(text: str, scopes=("mla_core",)) -> int:
     """The calls of the prefill attention kernel in a compiled
-    program's text, each inside the scope ``mla_core``."""
+    program's text, each inside one of the scopes ``scopes``."""
     found = [
         line for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
         and re.match(r"\s*(ROOT )?%prefill_attention[. ]", line)
     ]
-    assert all("/mla_core/" in line for line in found)
+    assert all(any(f"/{s}/" in line for s in scopes) for line in found)
     return len(found)
+
+
+def _copies_into_prefill_kernel(text: str, elements: int) -> list:
+    """The operands of a compiled program's prefill-kernel calls that a
+    copy or a transpose of at least ``elements`` values wrote (looked
+    up through bitcasts and moves between memories): what XLA puts in
+    front of a kernel whose operand it holds the other way round (PR
+    46: three a layer, 3.7 ms each at GLM's shape)."""
+    import math
+
+    made = {}
+    for line in text.splitlines():
+        hit = re.match(
+            r"\s*(?:ROOT )?%(\S+) = (?:\w+\[([\d,]*)\]\S* )?([\w-]+)\((.*)", line)
+        if hit:
+            made[hit[1]] = (hit[2], hit[3], hit[4])
+    found = []
+    for name, (_, op, rest) in made.items():
+        if op != "custom-call" or not name.startswith("prefill_attention"):
+            continue
+        for operand in re.findall(r"%([\w.-]+)", rest.split(")")[0]):
+            while made.get(operand, ("", "", ""))[1] in (
+                    "bitcast", "copy-done", "copy-start"):
+                operand = re.match(r"%([\w.-]+)", made[operand][2])[1]
+            shape, op, _ = made.get(operand, ("", "", ""))
+            size = math.prod(map(int, shape.split(","))) if shape else 0
+            turned = op in ("copy", "transpose") or re.match(
+                r"(copy|transpose)", operand)
+            if turned and size >= elements:
+                found.append(f"{name} <- %{operand} [{shape}] {op}")
+    assert any(n.startswith("prefill_attention") for n in made)
+    return found
 
 
 def _grouped_kernel_calls(text: str) -> int:
@@ -753,7 +794,18 @@ def test_window_moe_program_compiles_for_v5e(
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
         # The rule has no shape threshold: the three grouped matmuls
         # of the four expert layers are the kernel, as in xing4's.
-        assert _grouped_kernel_calls(compiled.as_text()) == 3 * 4
+        text = compiled.as_text()
+        assert _grouped_kernel_calls(text) == 3 * 4
+        # Since PR 48 the attention of the two FULL layers is the
+        # prefill kernel (48 query heads on 8 KV heads, a group's heads
+        # sharing each key tile); the three sliding layers keep the XLA
+        # blocks (the rule excludes a window: PERF.md §6, PR 48). No
+        # [1, rows, rows] mask is built, and XLA feeds the kernel no
+        # copy as large as its query (4,096 x 48 x 128).
+        rows = sess["prompt_window"]
+        assert _prefill_kernel_calls(text, ("full_attention",)) == 2
+        assert not re.search(rf"(?:s8|pred)\[1,{rows},{rows}\]", text)
+        assert not _copies_into_prefill_kernel(text, rows * 48 * 128)
         return
     cache = session.engine.cache
     assert cache.ring_pages == 33
@@ -992,9 +1044,10 @@ def test_hyper_mla_moe_program_compiles_for_v5e(
         # since PR 46 none of a block of 256 queries either: a layer's
         # attention is ONE call of the prefill kernel, its scores in
         # VMEM. (At 4,096 rows a weight has the first shape: the heads
-        # name it; the int8 [1, rows, rows] the kernel streams is the
-        # mask, one for all heads.)
+        # name it.) Since PR 48 no [1, rows, rows] mask is built
+        # either: no indexer chose, so the kernel makes its own.
         assert not re.search(rf"32,(?:1,)?{rows},{rows}\]", text)
+        assert not re.search(rf"(?:s8|pred)\[1,{rows},{rows}\]", text)
         assert "f32[1,32,1,256," not in text
         assert _prefill_kernel_calls(text) == 6
         # The three grouped matmuls of the five expert layers are the

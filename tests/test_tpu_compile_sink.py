@@ -14,7 +14,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tests.test_tpu_compile import (  # noqa: F401  (the fixture)
+    REPO,
+    _copies_into_prefill_kernel,
     _family_session,
+    _prefill_kernel_calls,
     _on_one_chip,
     _placed,
     _s,
@@ -125,6 +128,49 @@ def test_sink_window_moe_decode_compiles_for_v5e(monkeypatch, no_compile_cache):
     assert f"bf16[{slots},{sess['max_seq_len']}," not in text
     assert f"bf16[{slots},{cache.ring_pages * page}," not in text
     assert ",4,256]" not in text and ",8,256]" not in text
+
+
+# The 8,192-row prefill of a full layer and a sliding one (the first
+# two of the published pattern: a dense FFN, then the experts) since PR
+# 48: the full layer's attention is ONE call of the prefill kernel (64
+# query heads on 4 KV heads, keys 192 beside values 128); the sliding
+# layer keeps the XLA blocks (the rule excludes a window). No block of
+# 256 queries' scores of the full layer and no [1, rows, rows] mask is
+# left in the program, the query reaches the kernel as ``q_proj`` and
+# the rotation write it (positions minor) and nothing as large as the
+# kernel's result is copied or turned on its way in (the keys,
+# zero-padded to 256, and the values, a sixteenth of that, are).
+
+
+def test_sink_window_moe_prefill_attends_in_the_kernel_on_v5e(
+    monkeypatch, no_compile_cache
+):
+    import json
+    import re
+
+    from perfbench.families import sink_window_moe_serve as family
+    from tpudl.models.generate import prefill_fn
+    from tpudl.models.llama import LlamaForCausalLM
+
+    on_chip = _kernels_taken(monkeypatch)
+    with open(REPO / "perfbench/configs/mimo-v2-flash-l7-e16.json") as f:
+        cfg = json.load(f)
+    cfg["num_hidden_layers"] = 2
+    rows = cfg["session"]["prompt_window"] // 2
+    assert rows == 8192
+    model = LlamaForCausalLM(family.model_config(
+        cfg, cfg["session"]["max_seq_len"], jnp.bfloat16))
+    params = jax.eval_shape(
+        model.init, jax.random.key(0), _s((1, 8), i32))["params"]
+    ids = _s((1, rows), i32, sharding=on_chip)
+    program = prefill_fn(model)
+    text = jax.jit(program).lower(
+        _placed(params, on_chip), ids, ids).compile().as_text()
+    assert program.attention_in_kernel == {rows: 1}
+    assert _prefill_kernel_calls(text, ("full_attention",)) == 1
+    assert not re.search(rf"(?:s8|pred)\[1,{rows},{rows}\]", text)
+    assert ",16,256," not in text  # the full layer's blocks: 4 x 16 heads
+    assert not _copies_into_prefill_kernel(text, rows * 64 * 128)
 
 
 # The configurations whose decode programs read a k / v pool pair held

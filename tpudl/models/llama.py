@@ -521,15 +521,15 @@ PREFILL_BLOCK = 256
 
 
 def _blocked_attention(q, k, v, valid, window, block, scale=None, chosen=None,
-                       sink=None):
+                       sink=None, forward_only=False):
     """Causal grouped-query attention of a chunk over itself with no [H, S, S]
-    tensor: one Pallas call where ``prefill_kernel_ok`` (ops.flash_attention),
-    else a block of queries at a time against the keys up to its last slot (a
-    ``window`` layer: the band). q: [B, S, H, D]; k, v: [B, S, Hkv, D] in slot
-    order; valid: [B, S] bool; ``chosen`` [B, S, S] bool: an indexer's choice;
-    ``sink`` [H]: ``_gqa_decode_attention``'s, which the kernel does not take."""
+    tensor: a block of queries at a time against the keys up to its last slot
+    (a ``window`` layer: the band), or for a serving prefill (``forward_only``:
+    the kernel has no backward pass, and takes no ``sink`` [H]) one Pallas call
+    where ``prefill_kernel_ok`` (ops.flash_attention). q: [B, S, H, D]; k, v:
+    [B, S, Hkv, D] in slot order; valid: [B, S] bool; ``chosen`` [B, S, S] bool."""
     from tpudl.ops.flash_attention import prefill_attention, prefill_kernel_ok
-    if sink is None and prefill_kernel_ok(q, k, v, window):
+    if forward_only and sink is None and prefill_kernel_ok(q, k, v, window):
         return prefill_attention(q, k, v, valid, scale, chosen)
     s = q.shape[1]
     out = []
@@ -734,8 +734,8 @@ class LlamaAttention(nn.Module):
                 # A long prompt into an empty cache: the chunk is all
                 # there is to attend to, and it is attended in blocks.
                 return project_out(_blocked_attention(
-                    q, k, v, chunk_valid, window, PREFILL_BLOCK, sink=sink
-                ))
+                    q, k, v, chunk_valid, window, PREFILL_BLOCK, sink=sink,
+                    forward_only=True))
             k, v = ck.value, cv.value
             # Attend to slots that are (a) causally prior in WRITE order —
             # slots fill in token order, so slot order IS causal order
@@ -755,8 +755,8 @@ class LlamaAttention(nn.Module):
 
         if window or sink is not None:
             # Training / scoring with a window (the band) or a sink (a
-            # term no ``attend`` implementation has): under an explicit
-            # mask, in blocks.
+            # term no ``attend`` implementation has): under an explicit mask,
+            # in XLA's blocks, which differentiate (not ``forward_only``).
             valid = (
                 jnp.ones((B, S), jnp.bool_) if kv_mask is None
                 else kv_mask.astype(jnp.bool_)
@@ -1781,7 +1781,7 @@ def _mla_prefill(
         if prefill_kernel_ok(like, like, value, 0):
             return _blocked_attention(
                 *_kernel_operands(q_nope, q_rope, rows, kv_b, dn, wide),
-                valid, 0, PREFILL_BLOCK, scale, choice,
+                valid, 0, PREFILL_BLOCK, scale, choice, forward_only=True,
             )
         up = jnp.einsum("btr,rhd->bthd", rows[..., :r], kv_b)
         k_rope = jnp.broadcast_to(

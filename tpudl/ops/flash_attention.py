@@ -648,15 +648,16 @@ def flash_attention(
 # A sibling of ``_fwd`` with its own ``pallas_call``: the serving models
 # hold a position's heads side by side ([B, S, H x D]: a head is a column
 # block, and what the kernel writes is what ``o_proj`` reads), keys and
-# values of different widths, and a choice of keys a query; ``_fwd``
-# wants [B, H, S, D] (a transpose either side of the call), one width
-# and a key-validity row only.
+# values of different widths, a group of query heads a KV head, and a
+# choice of keys a query; ``_fwd`` wants [B, H, S, D] (a transpose either
+# side of the call), one width and a key-validity row only.
 # ---------------------------------------------------------------------------
 
 #: The call's name: its row of a trace's breakdown.
 PREFILL_NAME = "prefill_attention"
 #: The tile of scores a grid step holds in VMEM, queries x keys. Swept
-#: on the chip at 8,192 rows x 64 heads x 256 / 256 (PERF.md, PR 46).
+#: on the chip at 8,192 rows x 64 heads x 256 / 256 (PERF.md, PR 46) and
+#: at the grouped-query shapes (PR 48).
 PREFILL_BLOCK_Q = 1024
 PREFILL_BLOCK_K = 1024
 #: Where the running maximum starts: far above ``MASK_VALUE``, so that a
@@ -686,10 +687,17 @@ def prefill_kernel_ok(q, k, v, window) -> bool:
     """Whether ``prefill_attention`` can stand in for the XLA blocks of
     ``tpudl.models.llama._blocked_attention``, from what the program
     can observe while it is traced: one TPU device (no mesh to
-    partition a kernel over), bfloat16, one KV head a query head (the
-    up-projected latent form; grouped heads against one K tile would be
-    another grid), no window (a band likewise), and widths of whole
-    128-value lanes, so that a head is a column block of [B, S, H x D]."""
+    partition a kernel over), bfloat16, query heads a whole number of
+    groups over the KV heads (one a head in the up-projected latent
+    form, 6 to 16 in the grouped-query models), values of whole
+    128-value lanes (a head is a column block of [B, S, H x Dv]), keys
+    of whole 16-row sublane tiles (the query arrives positions minor,
+    and the keys are zero-padded to whole lanes), and no ``window``: a
+    band would be another walk, and the one tried, this body over key
+    tiles about as wide as the window, did not lead the blocks at a
+    window of 128 or 512 (PERF.md §6, PR 48). Whatever the rule is
+    asked, the kernel has no backward pass and takes no sink: only a
+    serving prefill of a layer without one offers it."""
     from tpudl.ops.attention import is_tpu_backend
     from tpudl.ops.grouped_matmul import one_device
 
@@ -697,10 +705,11 @@ def prefill_kernel_ok(q, k, v, window) -> bool:
         is_tpu_backend()
         and one_device()
         and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-        and k.shape[2] == v.shape[2] == q.shape[2]
+        and k.shape[2] == v.shape[2]
+        and q.shape[2] % k.shape[2] == 0
         and not window
         and k.shape[-1] == q.shape[-1]
-        and q.shape[-1] % 128 == 0
+        and q.shape[-1] % 16 == 0
         and v.shape[-1] % 128 == 0
     )
 
@@ -722,19 +731,36 @@ def _lower_tiles(rows: int, bq: int, bk: int):
 
 def _prefill_kernel(
     qi_ref, kj_ref, first_ref,  # scalar prefetch: the walk, the padding
-    qt_ref, k_ref, v_ref, keep_ref,
-    o_ref,
-    q_scr, m_scr, l_scr, acc_scr,
-    *, scale: float, block_q: int, block_k: int,
+    *refs,
+    scale: float, block_q: int, block_k: int, key_width: int, has_keep: bool,
 ):
-    """One head's (block_q, block_k) tile of scores a step, under a
-    running maximum and denominator; everything between the two matrix
-    products is float32 and stays in VMEM. The query tile arrives with
-    its positions minor (``[Dk, block_q]``) and is turned once, at the
-    first of its key tiles. A key tile that lies wholly in the row's
-    left padding (before tile ``first``) is a step that does nothing."""
-    step = pl.program_id(2)
+    """The query heads of one KV head (``group``: the scratch's leading
+    size) against ONE (block_k) tile of its keys and values a step, a
+    head's (block_q, block_k) tile of scores at a time under a running
+    maximum and denominator; everything between the two matrix products
+    is float32 and stays in VMEM. A query tile arrives with its
+    positions minor (``[group x key_width, block_q]``) and is turned
+    once, at the first of its key tiles (zeros up to the keys' padded
+    width). What a query may attend is either the operand ``keep`` (an
+    indexer's choice, with the causal rule and the validity in it) or
+    made here, from the tiles' positions and the validity row, and only
+    in a tile that an edge crosses: the diagonal, or left padding
+    (``clean``, a fourth prefetched scalar a row: the first position
+    from which every slot is real). A key tile wholly in the row's left
+    padding (before tile ``first``), and every step of a query tile
+    that ends before it, does nothing."""
+    if has_keep:
+        qt_ref, k_ref, v_ref, keep_ref, o_ref, *scratch = refs
+    else:
+        clean_ref, qt_ref, k_ref, v_ref, real_ref, o_ref, *scratch = refs
+    q_scr, m_scr, l_scr, acc_scr = scratch
+
+    bq, bk, dk = block_q, block_k, key_width
+    group, dv = acc_scr.shape[0], acc_scr.shape[-1]
+    row, step = pl.program_id(0), pl.program_id(2)
     qi, kj = qi_ref[step], kj_ref[step]
+    q0, k0 = qi * bq, kj * bk
+    first = first_ref[row]
 
     # A power of two scales the query exactly, once a tile; any other
     # scale multiplies the float32 scores.
@@ -742,101 +768,159 @@ def _prefill_kernel(
 
     @pl.when(kj == 0)
     def _init():
-        q = qt_ref[0].T
-        q_scr[...] = q * jnp.asarray(scale, q.dtype) if exact else q
+        for i in range(group):
+            q = qt_ref[0, i * dk:(i + 1) * dk, :]
+            if q_scr.shape[-1] != dk:
+                q = jnp.concatenate(
+                    [q, jnp.zeros((q_scr.shape[-1] - dk, bq), q.dtype)], 0
+                )
+            q = q.T
+            q_scr[i] = q * jnp.asarray(scale, q.dtype) if exact else q
         m_scr[...] = jnp.full_like(m_scr, _MAX_FLOOR)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(kj >= first_ref[pl.program_id(0)])
-    def _accumulate():
-        s = jax.lax.dot_general(
-            q_scr[...], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if not exact:
-            s = s * scale
-        s = jnp.where(keep_ref[0] != 0, s, MASK_VALUE)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    def accumulate(allowed):
+        """The step's work; ``allowed`` [block_q, block_k] bool, the
+        same for every head, or None: every pair counts."""
+        def head(i, carry):
+            s = jax.lax.dot_general(
+                q_scr[i], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if not exact:
+                s = s * scale
+            if allowed is not None:
+                s = jnp.where(allowed, s, MASK_VALUE)
+            m_prev = m_scr[i][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_scr[i][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[i] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[i] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return carry
 
-    @pl.when(kj == ((qi + 1) * block_q - 1) // block_k)
+        if group == 1:  # the latent form: no loop around its one head
+            head(0, 0)
+        else:
+            jax.lax.fori_loop(0, group, head, 0)
+
+    # Something real on both sides: a key tile at or after the row's
+    # first real one, queries that reach it.
+    some = (kj >= first) & (q0 + bq > first * bk)
+    if has_keep:
+        @pl.when(some)
+        def _chosen():
+            accumulate(keep_ref[0] != 0)
+    else:
+        edge = (k0 + bk - 1 > q0) | (k0 < clean_ref[row])
+
+        @pl.when(some & edge)
+        def _edge():
+            behind = (
+                jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+                - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+                + (q0 - k0)
+            )  # how far a key lies behind its query
+            accumulate((behind >= 0) & (real_ref[0] != 0))
+
+        @pl.when(some & jnp.logical_not(edge))
+        def _inside():
+            accumulate(None)
+
+    @pl.when(kj == ((qi + 1) * bq - 1) // bk)
     def _finalize():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)).astype(
-            o_ref.dtype
-        )
+        for i in range(group):
+            l = l_scr[i][:, :1]
+            o_ref[0, :, i * dv:(i + 1) * dv] = (
+                acc_scr[i] / jnp.where(l > 0.0, l, 1.0)
+            ).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("heads", "scale", "block_q", "block_k", "interpret")
 )
 def _prefill_call(
-    qt, k, v, keep, first, *, heads, scale, block_q, block_k, interpret
+    qt, k, v, keep, first, real, clean, *, heads, scale, block_q, block_k,
+    interpret,
 ):
     """The kernel's call, jitted on its own so that the layers of a
     prefill program share one traced and lowered function (as the paged
-    kernels do). qt: [B, H x Dk, S]; k: [B, S, H x Dk]; v: [B, S, H x
-    Dv]; keep: int8 [B, S, S], nonzero where a query attends a key;
-    first: int32 [B], the key tiles that hold nothing but a row's left
-    padding (never computed, and the one fetched in their place is the
-    first that counts); S a whole number of both blocks."""
-    b, s, _ = k.shape
-    dk, dv = k.shape[-1] // heads, v.shape[-1] // heads
+    kernels do). qt: [B, H x Dk, S]; k: [B, S, Hkv x Dkp] (a head's
+    keys zero-padded to whole lanes); v: [B, S, Hkv x Dv]; ``keep``:
+    int8 [B, S, S], nonzero where a query attends a key, or None, and
+    then ``real`` int32 [B, 1, S], nonzero at a real slot, with
+    ``clean`` int32 [B], the first slot from which every slot is real;
+    ``first``: int32 [B], the key tiles that hold nothing but a row's
+    left padding (never computed, and the one fetched in their place is
+    the first that counts); S a whole number of both blocks."""
+    b, s, _ = v.shape
+    dk = qt.shape[1] // heads
+    kv_heads = k.shape[-1] // _round_up(dk, 128)
+    group = heads // kv_heads
+    dkp, dv = k.shape[-1] // kv_heads, v.shape[-1] // kv_heads
     bq, bk = block_q, block_k
     walk = _lower_tiles(s, bq, bk)
     steps = int(walk[0].shape[0])
 
-    # Index maps over (batch, head, step of the walk): a head is the
-    # column block ``h``; a key tile before the row's first real one is
-    # asked for as that one (asked for again, it is not fetched again).
-    def query(b, h, t, qi, kj, first):
+    # Index maps over (batch, KV head, step of the walk): a KV head's
+    # keys, values and its group's queries and results are column
+    # blocks; a key tile before the row's first real one is asked for
+    # as that one (asked for again, it is not fetched again).
+    def query(b, h, t, qi, kj, first, *_):
         return b, h, qi[t]
 
-    def keys(b, h, t, qi, kj, first):
+    def keys(b, h, t, qi, kj, first, *_):
         return b, jnp.maximum(kj[t], first[b]), h
 
-    def mask(b, h, t, qi, kj, first):
+    def mask(b, h, t, qi, kj, first, *_):
         return b, qi[t], jnp.maximum(kj[t], first[b])
 
-    def result(b, h, t, qi, kj, first):
+    def slots(b, h, t, qi, kj, first, *_):
+        return b, 0, jnp.maximum(kj[t], first[b])
+
+    def result(b, h, t, qi, kj, first, *_):
         return b, qi[t], h
 
+    if keep is not None:
+        prefetch, allowed = [*walk, first], keep
+        allowed_spec = pl.BlockSpec((1, bq, bk), mask)
+    else:
+        prefetch, allowed = [*walk, first, clean], real
+        allowed_spec = pl.BlockSpec((1, 1, bk), slots)
+
     item = k.dtype.itemsize
-    tiles = item * bk * (dk + dv) + bq * bk  # a step's keys, values, mask
+    tiles = item * bk * (dkp + dv) + (bq * bk if keep is not None else 4 * bk)
     resident = (
-        2 * (tiles + item * bq * (dk + dv))  # double buffers
-        + bq * (item * dk + 4 * dv + 1024)   # scratch
+        2 * (tiles + item * group * bq * (dk + dv))   # double buffers
+        + group * bq * (item * dkp + 4 * dv + 1024)   # scratch
         + 4 * 4 * bq * bk                    # scores and weights, float32
     )
     return pl.pallas_call(
         functools.partial(
-            _prefill_kernel, scale=scale, block_q=bq, block_k=bk
+            _prefill_kernel, scale=scale, block_q=bq, block_k=bk,
+            key_width=dk, has_keep=keep is not None,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b, heads, steps),
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, kv_heads, steps),
             in_specs=[
-                pl.BlockSpec((1, dk, bq), query),
-                pl.BlockSpec((1, bk, dk), keys),
+                pl.BlockSpec((1, group * dk, bq), query),
+                pl.BlockSpec((1, bk, dkp), keys),
                 pl.BlockSpec((1, bk, dv), keys),
-                pl.BlockSpec((1, bq, bk), mask),
+                allowed_spec,
             ],
-            out_specs=pl.BlockSpec((1, bq, dv), result),
+            out_specs=pl.BlockSpec((1, bq, group * dv), result),
             scratch_shapes=[
-                pltpu.VMEM((bq, dk), qt.dtype),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, dv), jnp.float32),
+                pltpu.VMEM((group, bq, dkp), qt.dtype),
+                pltpu.VMEM((group, bq, 128), jnp.float32),
+                pltpu.VMEM((group, bq, 128), jnp.float32),
+                pltpu.VMEM((group, bq, dv), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, s, heads * dv), qt.dtype),
@@ -845,15 +929,16 @@ def _prefill_call(
             vmem_limit_bytes=resident + (16 << 20),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * heads * steps * bq * bk * (dk + dv),
+            flops=2 * b * heads * steps * bq * bk * (dkp + dv),
             transcendentals=b * heads * steps * bq * bk,
             bytes_accessed=(
-                item * (qt.size + v.size) + b * heads * steps * tiles
+                item * (qt.size + b * s * heads * dv)
+                + b * kv_heads * steps * tiles
             ),
         ),
         interpret=interpret,
         name=PREFILL_NAME,
-    )(*walk, first, qt, k, v, keep)
+    )(*prefetch, qt, k, v, allowed)
 
 
 def prefill_attention(
@@ -861,18 +946,22 @@ def prefill_attention(
     block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
-    """Causal attention of a chunk over itself, one KV head a query
-    head, as ONE kernel call: no [H, S, S] and no [H, block, S] tensor
-    exists outside VMEM. q, k: [B, S, H, Dk]; v: [B, S, H, Dv] (the
-    widths may differ; each a whole number of 128-value lanes); valid:
-    [B, S] bool, the real slots (a left-padded row's first keys are
-    not); ``scale``: the softmax's, Dk ** -0.5 unless given; ``chosen``
-    [B, S, S] bool: an indexer's choice of keys a query. -> [B, S, H,
-    Dv]. Scores are float32 from the operands' own dtype, the weights
-    are rounded to the values' dtype for the second product; a query
-    with no key allowed gets zeros. Every causal tile that holds a real
-    slot is computed: a choice is a mask, no tile is skipped for it; a
-    key tile of nothing but left padding is."""
+    """Causal attention of a chunk over itself as ONE kernel call: no
+    [H, S, S] and no [H, block, S] tensor exists outside VMEM. q: [B,
+    S, H, Dk]; k: [B, S, Hkv, Dk]; v: [B, S, Hkv, Dv] (H a whole number
+    of groups over Hkv, a group's heads a grid step against each tile
+    of their KV head; the widths may differ: Dv a whole number of
+    128-value lanes, Dk of 16-row tiles); valid: [B, S] bool, the real
+    slots (a left-padded row's first keys are not); ``scale``: the
+    softmax's, Dk ** -0.5 unless given; ``chosen`` [B, S, S] bool: an
+    indexer's choice of keys a query. -> [B, S, H, Dv]. Scores are
+    float32 from the operands' own dtype, the weights are rounded to
+    the values' dtype for the second product; a query with no key
+    allowed gets zeros. Tiles above the diagonal or of nothing but left
+    padding (keys or queries) are neither computed nor fetched; a
+    choice is a mask operand, no tile is skipped for it; without one no
+    [B, S, S] mask exists: the kernel makes it, in the tiles that need
+    one."""
     global _prefill_calls
 
     b, s, h, dk = q.shape
@@ -882,28 +971,43 @@ def prefill_attention(
     bq = block_q or _fit_block(s, PREFILL_BLOCK_Q)
     bk = block_k or _fit_block(s, PREFILL_BLOCK_K)
     rows = _round_up(s, max(bq, bk))
-    slot = jnp.arange(s)
-    keep = (slot[None, :] <= slot[:, None])[None] & valid[:, None, :]
+    keep = real = clean = None
     if chosen is not None:
-        keep = keep & chosen
-    keep = keep.astype(jnp.int8)
-    # The query with its POSITIONS minor: how XLA writes a latent
+        slot = jnp.arange(s)
+        keep = (slot[None, :] <= slot[:, None])[None] & valid[:, None, :]
+        keep = (keep & chosen).astype(jnp.int8)
+    else:
+        # No [B, S, S] mask: the kernel makes it from positions, the
+        # validity row and the first slot from which every slot is real
+        # (tiles from there on need no look at the row).
+        real = valid.astype(jnp.int32)[:, None, :]
+        clean = jnp.where(valid, 0, jnp.arange(1, s + 1, dtype=jnp.int32))
+        clean = clean.max(axis=1)
+    # The query with its POSITIONS minor: how XLA writes a serving
     # model's query on the chip (its roped halves are narrower than a
     # lane), so this transpose is none there, where [B, S, H x Dk] asked
     # for a transposing copy of the whole query a layer (PERF.md, PR 46).
     # The kernel turns a tile once. Keys and values come from matmuls
-    # the caller makes ([B, S, H x D], ``llama._kernel_operands``).
+    # the caller makes ([B, S, H x D], ``llama._kernel_operands``); a
+    # key of part lanes is zero-padded to whole ones (the kernel pads
+    # its query tile the same way).
     qt = q.transpose(0, 2, 3, 1).reshape(b, h * dk, s)
+    if dk % 128:
+        k = jnp.pad(k, ((0, 0),) * 3 + ((0, _round_up(dk, 128) - dk),))
     k, v = k.reshape(b, s, -1), v.reshape(b, s, -1)
     if rows != s:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, rows - s)))
         k, v = (jnp.pad(x, ((0, 0), (0, rows - s), (0, 0))) for x in (k, v))
-        keep = jnp.pad(keep, ((0, 0), (0, rows - s), (0, rows - s)))
+        if keep is not None:
+            keep = jnp.pad(keep, ((0, 0), (0, rows - s), (0, rows - s)))
+        else:
+            real = jnp.pad(real, ((0, 0), (0, 0), (0, rows - s)))
     _prefill_calls += 1
     # Key tiles before a row's first real slot hold nothing to attend.
     first = (jnp.argmax(valid, axis=1) // bk).astype(jnp.int32)
     out = _prefill_call(
-        qt, k, v, keep, first, heads=h, scale=float(scale or dk ** -0.5),
-        block_q=bq, block_k=bk, interpret=bool(interpret),
+        qt, k, v, keep, first, real, clean, heads=h,
+        scale=float(scale or dk ** -0.5), block_q=bq, block_k=bk,
+        interpret=bool(interpret),
     )
     return out[:, :s].reshape(b, s, h, dv)
