@@ -96,6 +96,19 @@ def pytest_terminal_summary(terminalreporter):
 
 
 @pytest.fixture(autouse=True)
+def _startup_timeline_of_its_own():
+    """Every test begins with an empty start-up recorder: a recorder
+    that a test turns on is handed what THAT test's start-up recorded
+    (tpudl.obs.spans.enable), not what the tests before it in the
+    process left behind."""
+    from tpudl.obs import spans
+
+    if spans._startup is not None:
+        spans._startup.drain()
+    yield
+
+
+@pytest.fixture(autouse=True)
 def _chaos_env_guard(request):
     """Chaos-marked tests drive env-gated fault injectors
     (TPUDL_SERVE_CHAOS_*): snapshot and restore those knobs around each
@@ -128,3 +141,23 @@ def mesh8():
 @pytest.fixture
 def rng_np():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def compile_cache_config():
+    """conftest turns the persistent cache off for the hermetic run; a
+    cache test turns it on for itself and leaves the session as it
+    found it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = (
+        "jax_enable_compilation_cache",
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    saved = {name: getattr(jax.config, name) for name in names}
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()  # un-latch: later tests stay uncached

@@ -98,8 +98,11 @@ def _ahead():
 
 
 def _spans(records, name=None):
+    """The spans called ``name``; with no name, the hot path's (the
+    records a process makes of how it began, ISSUE 49, left out)."""
     return [r for r in records if r.get("kind") == "span"
-            and (name is None or r["name"] == name)]
+            and (r["name"] == name if name is not None else
+                 not r["name"].startswith(("startup.", "program.", "kernel.")))]
 
 
 # ---------------------------------------------------------------------------

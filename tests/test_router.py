@@ -389,7 +389,11 @@ def test_replica_scrape_over_real_http_healthz(model_and_params):
     try:
         with obs_exporter.ObsExporter(port=0) as ex:
             url = f"http://127.0.0.1:{ex.port}/healthz"
-            replica = Replica("r0", session, health_url=url)
+            # (The contract is the payload, not its latency: beside five
+            # other workers the default 1 s scrape has timed out.)
+            replica = Replica(
+                "r0", session, health_url=url, scrape_timeout_s=30.0
+            )
             with Router([replica], scrape_interval_s=0.0) as router:
                 requests = _greedy_requests(2, seed=7)
                 results = router.serve(requests, timeout_s=300.0)

@@ -17,11 +17,16 @@ window in a RecompileWatcher), and ad hoc around any suspect loop:
         run_decode_steady_state()
 
 **Recompiles** are counted via the ``jax.monitoring`` backend-compile
-event — the same channel the persistent compile cache's hit counters
-ride (tpudl.runtime.compile_cache). One module-level listener feeds a
-process-global counter; watchers snapshot it, so nesting and
-concurrent use are safe and no listener is ever unregistered (jax only
-offers clear-all).
+event. ONE registration (``install_listeners``, made at ``tpudl.runtime``
+import and again, idempotently, by every reader below) keeps what JAX
+says of each program it builds: process-global totals that watchers
+snapshot (so nesting and concurrent use are safe and no listener is
+ever unregistered; jax only offers clear-all), the persistent compile
+cache's hit and miss counters, and one span record a program and stage
+(``program.trace`` / ``program.lower`` / ``program.compile``), written
+to the active span recorder or else to the start-up recorder
+(``tpudl.obs.spans.startup_recorder``), so that a set-up nobody was
+watching can still be read by program afterwards.
 
 **Host transfers** use ``jax.transfer_guard`` in ``disallow`` mode,
 which blocks IMPLICIT transfers only: explicit ``jax.device_put`` /
@@ -40,29 +45,111 @@ import contextlib
 import threading
 from typing import Iterable, Optional
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _TRANSFER_KINDS = ("h2d", "d2h", "d2d")
 
 _compiles = 0
 _compile_seconds = 0.0
+_STAGE = {
+    _TRACE_EVENT: "program.trace",
+    _LOWER_EVENT: "program.lower",
+    _COMPILE_EVENT: "program.compile",
+}
 _compiles_mu = threading.Lock()
 _listener_installed = False
 _install_mu = threading.Lock()
+
+
+class _Building(threading.local):
+    """What the calling thread is in the middle of: the stages JAX has
+    begun and not ended, innermost last (a span, or None for a stage
+    that is part of the one around it), and what the compile cache has
+    said since the last ``program.compile``."""
+
+    def __init__(self):
+        self.stages = []
+        self.cache = {}
+
+
+_building = _Building()
 
 
 class DispatchHygieneError(AssertionError):
     """A hot loop recompiled or implicitly transferred after warmup."""
 
 
+def _program(fun_name: str) -> str:
+    """JAX names a trace by the function (``tpudl_prefill``) and the
+    lowering and the compile by the module (``jit(tpudl_prefill)``):
+    one name for the three."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _on_stage_begin(event: str, value, fun_name: str = "", **kwargs) -> None:
+    # JAX announces a stage as it begins (a scalar, the start time) and
+    # times it as it ends (the duration, below), in a ``with`` of its
+    # own: the two come in pairs, innermost first, whatever is raised
+    # in between. The span is OPEN in between, so that what the stage
+    # holds (a ``kernel.trace``) is its child and not its sibling.
+    if event not in _STAGE:
+        return
+    stages = _building.stages
+    if event != _COMPILE_EVENT and any(stages):
+        # A jitted function traced inside another's trace (every
+        # ``jnp`` function is one) or inside a lowering: its seconds
+        # are the outer program's, whose span holds them.
+        stages.append(None)
+        return
+    from tpudl.obs import spans as obs_spans
+
+    stages.append(obs_spans.startup_recorder().begin(
+        _STAGE[event], obs_spans.CAT_COMPILE, program=_program(fun_name)
+    ))
+
+
 def _on_duration_event(event: str, duration: float, **kwargs) -> None:
     global _compiles, _compile_seconds
+    if event == _CACHE_READ_EVENT:
+        _building.cache["cache_read_s"] = duration
+        return
+    if event not in _STAGE:
+        return
+    attrs = {}
     if event == _COMPILE_EVENT:
         with _compiles_mu:
             _compiles += 1
             _compile_seconds += duration
+        attrs, _building.cache = _building.cache, {}
+    stages = _building.stages
+    span = stages.pop() if stages else None
+    if span is not None:
+        span.end_lasting(duration, **attrs)  # JAX's own seconds
 
 
-def _ensure_listener() -> None:
+def _on_cache_event(event: str, **kwargs) -> None:
+    if event not in (_HIT_EVENT, _MISS_EVENT):
+        return
+    from tpudl.obs import counters as obs_counters
+    from tpudl.obs import spans as obs_spans
+
+    hit = event == _HIT_EVENT
+    _building.cache["cache_hit"] = int(hit)
+    name = "compile_cache_hits" if hit else "compile_cache_misses"
+    obs_counters.registry().counter(name).inc()
+    rec = obs_spans.active_recorder()
+    if rec is not None:
+        rec.event(name[:-1], "compile")
+
+
+def install_listeners() -> None:
+    """The one hook-up to ``jax.monitoring``. Idempotent."""
     global _listener_installed
     if _listener_installed:
         return
@@ -71,16 +158,18 @@ def _ensure_listener() -> None:
             return
         import jax.monitoring
 
+        jax.monitoring.register_scalar_listener(_on_stage_begin)
         jax.monitoring.register_event_duration_secs_listener(
             _on_duration_event
         )
+        jax.monitoring.register_event_listener(_on_cache_event)
         _listener_installed = True
 
 
 def compile_count() -> int:
     """Backend compiles observed process-wide since the listener
     installed (monotonic; diff two reads to bracket a region)."""
-    _ensure_listener()
+    install_listeners()
     with _compiles_mu:
         return _compiles
 
@@ -88,7 +177,7 @@ def compile_count() -> int:
 def compile_seconds() -> float:
     """Seconds spent in backend compiles since the listener installed
     (monotonic, like :func:`compile_count`)."""
-    _ensure_listener()
+    install_listeners()
     with _compiles_mu:
         return _compile_seconds
 
@@ -109,7 +198,6 @@ class RecompileWatcher:
         return compile_count() - self._start
 
     def __enter__(self) -> "RecompileWatcher":
-        _ensure_listener()
         self._start = compile_count()
         return self
 

@@ -78,8 +78,8 @@ def prefill_fn(model):
     definition serves both the live loop below and the serving export
     (tpudl.export.decode) — they cannot diverge. A model with routed
     experts returns further values (``_apply_cached``), as the contracts
-    below do. Once traced at a length, ``attention_in_kernel[rows]`` is
-    the number of layers whose attention the prefill kernel took."""
+    below do. Once traced at a length, ``attention_in_kernel[rows]`` of its
+    ``attention_layers[rows]`` attentions are the prefill kernel's."""
 
     def tpudl_prefill(params, input_ids, attention_mask):
         positions = jnp.maximum(
@@ -89,11 +89,11 @@ def prefill_fn(model):
         # window's would pass the bound a long prefill keeps its scores
         # under (4,096 rows x a 100k vocabulary: 1.6 GB), ask for that row.
         from tpudl.models.llama import PREFILL_SCORE_BYTES
-        from tpudl.ops.flash_attention import note_prefill
+        from tpudl.ops.flash_attention import note_prefill, prefill_attentions
         from tpudl.ops.flash_attention import prefill_kernel_calls
         vocab = getattr(getattr(model, "cfg", None), "vocab_size", 0)
         one_row = 4 * input_ids.shape[1] * vocab > PREFILL_SCORE_BYTES
-        before = prefill_kernel_calls()
+        before, attentions = prefill_kernel_calls(), prefill_attentions()
         logits, *rest = _apply_cached(
             model,
             {"params": params},
@@ -103,7 +103,7 @@ def prefill_fn(model):
             positions=positions,
             last_only=one_row,
         )
-        note_prefill(tpudl_prefill, input_ids.shape[1], before)
+        note_prefill(tpudl_prefill, input_ids.shape[1], before, attentions)
         return (logits[:, -1, :], *rest)
     return tpudl_prefill
 

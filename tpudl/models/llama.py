@@ -681,9 +681,9 @@ class LlamaAttention(nn.Module):
         if decode:
             # KV cache (flax decode idiom): static [B, max_seq, Hkv, D]
             # buffers updated in place at the current index — the
-            # autoregressive serving path (the reference repo's entire
-            # substance is inference benchmarking; this is its decoder
+            # autoregressive serving path (the reference repo's decoder
             # analog). Shapes stay static so the step jits once.
+            _count_prefill_attention()
             fresh = not self.has_variable("cache", of_pass("k"))
             ck = self.variable(
                 "cache", of_pass("k"),
@@ -866,9 +866,9 @@ class LatentAttention(nn.Module):
             with jax.named_scope("mla_core"):
                 ctx = jnp.einsum("bshr,rhd->bshd", u, kv_b[..., dn:])
         elif decode:
-            # The dense row cache of a prefill (and of the chunked suffix
-            # prefill, which is handed the prefix's rows). A cache made HERE
-            # starts empty: the chunk is all there is to attend to.
+            # The dense row cache of a prefill (a chunked suffix prefill is handed
+            # the prefix's rows). Made HERE it starts empty: the chunk is all.
+            _count_prefill_attention()
             fresh = not self.has_variable("cache", "kv")
             ckv = self.variable(
                 "cache", "kv", jnp.zeros, (B, cfg.max_seq_len, r + dr),
@@ -2266,3 +2266,14 @@ def build_llama(name: str, num_classes: int, dtype=jnp.bfloat16, **kwargs):
         kwargs.setdefault("moe_experts", 8)
     cfg = LLAMA_SIZES[base](num_labels=num_classes, dtype=dtype, **kwargs)
     return LlamaForSequenceClassification(cfg)
+
+
+def _count_prefill_attention():
+    """An attention over a dense row cache is being traced: the prefill
+    contract counts its layers by these (tpudl.models.generate
+    .prefill_fn). Down here, and one line a site, so that no line above
+    moves: a serving program's text holds the line numbers of its
+    kernel's call chain through this file."""
+    from tpudl.ops.flash_attention import count_prefill_attention
+
+    count_prefill_attention()

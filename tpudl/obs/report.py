@@ -28,6 +28,11 @@ since every record carries host/process tags), then prints
   ``decode.readback``; ``emit``; ``prefill`` > ``prefill.readback``),
   each with its total and its SELF time (its duration less its
   children's), and the queue depth ``admit`` left behind;
+- a start-up table, when the stream holds the ``startup.*`` phases and
+  ``program.*`` records every process makes of how it began
+  (``tpudl.obs.spans.startup_recorder``): the phases by self time, the
+  programs by their trace / lower / compile-or-load seconds with the
+  compile cache's hits, the Pallas kernels by trace seconds;
 - the last counters snapshot per process, if any rode the stream.
 
 ``--request <id>`` switches to per-request trace mode: the serve
@@ -60,6 +65,7 @@ from tpudl.obs.spans import (
     CAT_EVAL,
     CAT_METRIC_WAIT,
     CAT_RECOVERY,
+    CAT_STARTUP,
     CAT_STEP,
     chrome_trace_events,
     read_jsonl,
@@ -67,10 +73,14 @@ from tpudl.obs.spans import (
     without_same_category_children,
 )
 
-#: Table row order: the lifecycle order of one step; the overlapped
-#: background-write row and recovery last (present only when nonzero).
-_TABLE_CATS = (CAT_DATA_WAIT, CAT_STEP, CAT_EVAL, CAT_COMPILE,
+#: Table row order: start-up, then the lifecycle order of one step; the
+#: overlapped background-write row and recovery last (present only when
+#: nonzero).
+_TABLE_CATS = (CAT_STARTUP, CAT_DATA_WAIT, CAT_STEP, CAT_EVAL, CAT_COMPILE,
                CAT_METRIC_WAIT, CAT_CHECKPOINT, CAT_CKPT_BG, CAT_RECOVERY)
+#: The stages JAX times of every program it builds, as
+#: tpudl.analysis.dispatch records them (``program.<stage>``).
+_PROGRAM_STAGES = ("trace", "lower", "compile")
 
 
 def load_records(paths: Iterable[str]) -> List[dict]:
@@ -224,7 +234,103 @@ def build_report(
         "straggler_factor": straggler_factor,
         "serve_requests": serve_request_breakdown(records),
         "serve_phases": serve_phase_breakdown(records),
+        "startup": startup_breakdown(records),
         "counters": counters,
+    }
+
+
+#: The names of the timeline a process records of how it began.
+_STARTUP_NAMES = ("startup.", "program.", "kernel.")
+#: What every span record holds; the rest are a site's own attributes.
+_SPAN_KEYS = frozenset((
+    "kind", "name", "cat", "ts", "dur", "id", "parent", "host", "process",
+    "pid", "tid",
+))
+
+
+def _covered_seconds(spans: Iterable[dict], lo: float, hi: float) -> float:
+    """Seconds of ``[lo, hi]`` that the spans' intervals cover, each
+    second once."""
+    covered, end = 0.0, lo
+    for ts, dur in sorted((float(s["ts"]), float(s["dur"])) for s in spans):
+        start, stop = max(ts, end), min(ts + dur, hi)
+        if stop > start:
+            covered += stop - start
+            end = stop
+    return covered
+
+
+def startup_breakdown(records: Iterable[dict]) -> dict:
+    """How the process began, from the records its start-up sites make
+    recorder or not (tpudl.obs.spans): ``phases`` (one row a
+    ``startup.*`` record, in the order they began: ``at_s`` since the
+    first of its process, total and SELF seconds, and the site's own
+    attributes: a pool's bytes and pages, a dry run's rows and kernel
+    layers), ``programs`` (``program.trace`` / ``.lower`` / ``.compile``
+    by program: seconds a stage, executables built, how many the
+    compile cache held and the seconds it took to read them, largest
+    total first) and ``kernels`` (``kernel.trace`` by kernel). A
+    phase's self time is its duration less what the start-up records of
+    its thread cover INSIDE it (a phase recorded after the fact, a
+    session's first requests, has no children by id). Empty where the
+    stream holds none."""
+    spans = [r for r in records if r.get("kind") == "span"
+             and str(r.get("name", "")).startswith(_STARTUP_NAMES)]
+    began: Dict[tuple, float] = {}
+    for s in spans:
+        key = goodput_mod.process_key(s)
+        began[key] = min(began.get(key, float(s["ts"])), float(s["ts"]))
+    phases: List[dict] = []
+    kernels: Dict[str, dict] = {}
+    programs: Dict[str, dict] = {}
+    for s in sorted(spans, key=lambda s: (str(goodput_mod.process_key(s)), s["ts"])):
+        name, ts, dur = s["name"], float(s["ts"]), float(s["dur"])
+        if name.startswith("startup."):
+            inside = [
+                o for o in spans
+                if o is not s and o.get("tid") == s.get("tid")
+                and goodput_mod.process_key(o) == goodput_mod.process_key(s)
+                and float(o["ts"]) >= ts
+                and float(o["ts"]) + float(o["dur"]) <= ts + dur
+            ]
+            phases.append({
+                "name": name,
+                "at_s": ts - began[goodput_mod.process_key(s)],
+                "total_s": dur,
+                "self_s": dur - _covered_seconds(inside, ts, ts + dur),
+                "attrs": {k: v for k, v in s.items() if k not in _SPAN_KEYS},
+            })
+        elif name == "kernel.trace":
+            row = kernels.setdefault(
+                str(s.get("kernel")), {"count": 0, "trace_s": 0.0}
+            )
+            row["count"] += 1
+            row["trace_s"] += dur
+        else:
+            stage = name[len("program."):]
+            if stage not in _PROGRAM_STAGES:
+                continue
+            row = programs.setdefault(str(s.get("program")), {
+                **{f"{st}_s": 0.0 for st in _PROGRAM_STAGES},
+                "built": 0, "cache_hits": 0, "cache_read_s": 0.0,
+            })
+            row[f"{stage}_s"] += dur
+            if stage == "compile":
+                row["built"] += 1
+                row["cache_hits"] += int(s.get("cache_hit", 0))
+                row["cache_read_s"] += float(s.get("cache_read_s", 0.0))
+    if not (phases or kernels or programs):
+        return {}
+
+    def by(rows: dict, seconds) -> dict:
+        return dict(sorted(rows.items(), key=lambda kv: -seconds(kv[1])))
+
+    return {
+        "phases": phases,
+        "programs": by(programs, lambda r: sum(
+            r[f"{st}_s"] for st in _PROGRAM_STAGES
+        )),
+        "kernels": by(kernels, lambda r: r["trace_s"]),
     }
 
 
@@ -1037,6 +1143,37 @@ def format_report(report: dict) -> str:
                 f"{r['total_s']:8.2f} {r['self_s']:8.2f} "
                 f"{r['mean_ms']:9.2f}{depth}"
             )
+
+    startup = report.get("startup")
+    if startup:
+        lines += [
+            "",
+            f"{'start-up phase':28} {'at_s':>8} {'total_s':>8} "
+            f"{'self_s':>8}",
+        ]
+        for r in startup["phases"]:
+            attrs = " ".join(f"{k}={v}" for k, v in r["attrs"].items())
+            lines.append(
+                f"{r['name']:28} {r['at_s']:8.2f} {r['total_s']:8.2f} "
+                f"{r['self_s']:8.2f}  {attrs}".rstrip()
+            )
+        lines += [
+            "",
+            f"{'program':28} {'built':>6} {'trace_s':>8} {'lower_s':>8} "
+            f"{'compile_s':>9} {'cache_hits':>10} {'cache_read_s':>12}",
+        ]
+        for name, r in startup["programs"].items():
+            lines.append(
+                f"{name:28} {r['built']:6d} {r['trace_s']:8.2f} "
+                f"{r['lower_s']:8.2f} {r['compile_s']:9.2f} "
+                f"{r['cache_hits']:10d} {r['cache_read_s']:12.2f}"
+            )
+        if startup["kernels"]:
+            lines += ["", f"{'kernel':28} {'count':>6} {'trace_s':>8}"]
+            for name, r in startup["kernels"].items():
+                lines.append(
+                    f"{name:28} {r['count']:6d} {r['trace_s']:8.2f}"
+                )
 
     for key, snap in report["counters"].items():
         cs = snap.get("counters", {})

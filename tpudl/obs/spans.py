@@ -50,11 +50,29 @@ Design constraints, all load-bearing:
 Activation mirrors the profiler hook: set ``TPUDL_OBS_DIR=/path`` (or
 call ``enable(path)``) and every instrumented layer writes into
 ``spans-<host>-p<process>-<pid>.jsonl`` under it.
+
+**Start-up is recorded before anyone asks.** The sites that run once a
+process (a session's construction and first requests, a train state's
+initialisation and first step, a Pallas kernel's trace, and the
+``program.*`` spans ``tpudl.analysis.dispatch`` opens around every
+stage of every program JAX builds) write through
+``startup_recorder()``: the active recorder, else ONE bounded in-memory
+recorder (``STARTUP_RECORDS`` records, counter
+``startup_records_dropped`` beyond). ``enable()`` hands what that one
+holds to the new recorder, ``id`` / ``parent`` / ``ts`` as they were,
+and empties it, so a recorder turned on after set-up still has set-up's
+timeline. Only those sites use it: the hot paths keep their
+``active_recorder()`` guard and record nothing while off. A phase that
+lies around the hot path (``startup.first_requests``,
+``startup.first_step``) is recorded AFTER the fact, from a reading of
+the clock, as a ``CAT_ENCLOSING`` span: a recorder's tree of a first
+call is then that of any other call, and no second is counted twice.
 """
 
 from __future__ import annotations
 
 import atexit
+import functools
 import itertools
 import json
 import os
@@ -87,16 +105,25 @@ CAT_RECOVERY = "recovery"
 #: steps by design, so the classifier reports them but never charges
 #: them against the run's wall-clock budget.
 CAT_CKPT_BG = "ckpt_bg"
-#: Enclosing lifetime spans (a distributor worker's whole run): they
-#: OVERLAP the categorized spans inside them, so the goodput classifier
-#: uses them only to extend the run window, never as accounted time.
+#: Enclosing lifetime spans (a distributor worker's whole run; a
+#: session's first requests and a step's first call, which are recorded
+#: after the fact around the hot path's own spans): they OVERLAP the
+#: categorized spans inside them, so the goodput classifier uses them
+#: only to extend the run window, never as accounted time.
 CAT_ENCLOSING = "worker"
+#: Phases a process runs once, before its steady state: a serving
+#: session's construction, a train state's initialisation, a kernel's
+#: trace. Recorded whether or not a recorder is on
+#: (``startup_recorder``).
+CAT_STARTUP = "startup"
 
 
 #: Records a file-backed recorder holds before it writes them out.
 BLOCK_RECORDS = 1024
 #: Prefix of the profiler annotations that mirror the spans.
 ANNOTATION_PREFIX = "tpudl."
+#: Records the start-up recorder holds while no recorder is active.
+STARTUP_RECORDS = 512
 
 # Span ids are unique in the process, whichever recorder hands them out
 # (itertools.count.__next__ is atomic under the GIL).
@@ -166,6 +193,19 @@ class _Span:
             self._name, self._cat, self.t0, dur, self._attrs,
             self.id, self.parent,
         )
+
+    def end_lasting(self, dur: float, **attrs) -> dict:
+        """Close the span NOW as one that lasted ``dur`` seconds by
+        another's measure (JAX's own, of a stage it timed): it began
+        ``dur`` ago, so it ends inside whatever is open around it."""
+        now = self._rec.clock()
+        self.t0 = now - dur
+        return self.end(now, **attrs)
+
+    def note(self, **attrs) -> None:
+        """Attributes known only once the work is under way, for a span
+        that a ``with`` block will close."""
+        self._attrs = {**self._attrs, **attrs}
 
     def cancel(self) -> None:
         """Close the span without a record: what it waited for did not
@@ -389,6 +429,31 @@ class SpanRecorder:
         self.close()
 
 
+class StartupRecorder(SpanRecorder):
+    """The in-memory recorder start-up sites write to while no recorder
+    is active: at most ``STARTUP_RECORDS`` records, the rest counted
+    (``startup_records_dropped``) and dropped, so that a process nobody
+    observes holds a bounded timeline of how it began."""
+
+    def __init__(self):
+        super().__init__(None)
+
+    def _emit(self, rec: dict) -> None:
+        with self._lock:
+            if len(self._records) < STARTUP_RECORDS:
+                self._records.append(rec)
+                return
+        from tpudl.obs.counters import registry
+
+        registry().counter("startup_records_dropped").inc()
+
+    def drain(self) -> list:
+        """What is held, which is then held no more."""
+        with self._lock:
+            records, self._records = self._records, []
+        return records
+
+
 def chrome_trace_events(records: Iterable[dict]) -> list:
     """tpudl span/event records -> Chrome trace-event list.
 
@@ -519,6 +584,9 @@ def self_seconds(spans: Iterable[dict]) -> list:
 # ---------------------------------------------------------------------------
 
 _active: Optional[SpanRecorder] = None
+# Where start-up sites record while ``_active`` is None; made at its
+# first use (its host and process tags are read then).
+_startup: Optional[StartupRecorder] = None
 _atexit_registered = False
 # TPUDL_OBS_DIR has been looked up and was not set: while this holds,
 # ``active_recorder()`` is one global read.
@@ -552,6 +620,11 @@ def enable(
         path if path.endswith(".jsonl") else default_span_path(path)
     )
     _active = SpanRecorder(file_path, clock=clock, process=process)
+    if _startup is not None:
+        # Start-up as it was recorded before anyone asked: both clocks
+        # are time.monotonic, so ``ts`` stands as it is.
+        for record in _startup.drain():
+            _active.ingest(record)
     if not _atexit_registered:
         atexit.register(disable)
         _atexit_registered = True
@@ -595,3 +668,41 @@ def span(name: str, cat: str = CAT_STEP, **attrs):
     if rec is None:
         return _NULL_SPAN
     return rec.span(name, cat, **attrs)
+
+
+def startup_recorder() -> SpanRecorder:
+    """Where a START-UP site records: the active recorder, else the
+    bounded in-memory one that ``enable()`` later hands over. For the
+    sites that run once a process and never in a steady state (module
+    docstring); a hot path asks ``active_recorder()``."""
+    global _startup
+    rec = active_recorder()
+    if rec is not None:
+        return rec
+    if _startup is None:
+        _startup = StartupRecorder()
+    return _startup
+
+
+def startup_span(name: str, **attrs) -> _Span:
+    """A start-up phase as a context manager: ``with
+    startup_span("startup.pools") as phase: ...; phase.note(pages=n)``."""
+    return startup_recorder().span(name, CAT_STARTUP, **attrs)
+
+
+def startup_phase(name: str, result_attrs: Optional[Callable] = None):
+    """Decorator: every call of the function is the start-up phase
+    ``name``; ``result_attrs(result)`` gives the span its attributes."""
+
+    def decorate(fn):
+        @functools.wraps(fn)
+        def phase(*args, **kwargs):
+            with startup_span(name) as span:
+                result = fn(*args, **kwargs)
+                if result_attrs is not None:
+                    span.note(**result_attrs(result))
+                return result
+
+        return phase
+
+    return decorate

@@ -49,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE
+from tpudl.ops.pallas_utils import kernel_trace
 
 #: Default tile sizes; VPU/MXU-aligned (multiples of the f32 (8,128) tile).
 #: Swept on TPU v5 lite at seq 4096 (2026-07-30): large kv tiles keep the
@@ -667,6 +668,7 @@ PREFILL_BLOCK_K = 1024
 _MAX_FLOOR = -1e30
 
 _prefill_calls = 0
+_prefill_attentions = 0
 
 
 def prefill_kernel_calls() -> int:
@@ -674,13 +676,30 @@ def prefill_kernel_calls() -> int:
     return _prefill_calls
 
 
-def note_prefill(program, rows: int, before: int) -> None:
+def prefill_attentions() -> int:
+    """Attentions over a dense row cache (a prefill's layers, whichever
+    form each then took) traced so far in this process."""
+    return _prefill_attentions
+
+
+def count_prefill_attention() -> None:
+    """An attention layer says that it is being traced over a dense row
+    cache (tpudl.models.llama, both kinds of layer)."""
+    global _prefill_attentions
+    _prefill_attentions += 1
+
+
+def note_prefill(program, rows: int, before: int, attentions: int) -> None:
     """A prefill contract's note of itself (tpudl.models.generate
     .prefill_fn), written while it is traced at ``rows``: how many of
     its layers' attentions were the kernel's since the count read
-    ``before``, as ``program.attention_in_kernel[rows]``."""
+    ``before``, as ``program.attention_in_kernel[rows]``, of how many
+    attentions it made since that count read ``attentions``, as
+    ``program.attention_layers[rows]``."""
     note = program.__dict__.setdefault("attention_in_kernel", {})
     note[rows] = _prefill_calls - before
+    note = program.__dict__.setdefault("attention_layers", {})
+    note[rows] = _prefill_attentions - attentions
 
 
 def prefill_kernel_ok(q, k, v, window) -> bool:
@@ -901,44 +920,45 @@ def _prefill_call(
         + group * bq * (item * dkp + 4 * dv + 1024)   # scratch
         + 4 * 4 * bq * bk                    # scores and weights, float32
     )
-    return pl.pallas_call(
-        functools.partial(
-            _prefill_kernel, scale=scale, block_q=bq, block_k=bk,
-            key_width=dk, has_keep=keep is not None,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(b, kv_heads, steps),
-            in_specs=[
-                pl.BlockSpec((1, group * dk, bq), query),
-                pl.BlockSpec((1, bk, dkp), keys),
-                pl.BlockSpec((1, bk, dv), keys),
-                allowed_spec,
-            ],
-            out_specs=pl.BlockSpec((1, bq, group * dv), result),
-            scratch_shapes=[
-                pltpu.VMEM((group, bq, dkp), qt.dtype),
-                pltpu.VMEM((group, bq, 128), jnp.float32),
-                pltpu.VMEM((group, bq, 128), jnp.float32),
-                pltpu.VMEM((group, bq, dv), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, s, heads * dv), qt.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=resident + (16 << 20),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * b * heads * steps * bq * bk * (dkp + dv),
-            transcendentals=b * heads * steps * bq * bk,
-            bytes_accessed=(
-                item * (qt.size + b * s * heads * dv)
-                + b * kv_heads * steps * tiles
+    with kernel_trace(PREFILL_NAME):
+        return pl.pallas_call(
+            functools.partial(
+                _prefill_kernel, scale=scale, block_q=bq, block_k=bk,
+                key_width=dk, has_keep=keep is not None,
             ),
-        ),
-        interpret=interpret,
-        name=PREFILL_NAME,
-    )(*prefetch, qt, k, v, allowed)
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(b, kv_heads, steps),
+                in_specs=[
+                    pl.BlockSpec((1, group * dk, bq), query),
+                    pl.BlockSpec((1, bk, dkp), keys),
+                    pl.BlockSpec((1, bk, dv), keys),
+                    allowed_spec,
+                ],
+                out_specs=pl.BlockSpec((1, bq, group * dv), result),
+                scratch_shapes=[
+                    pltpu.VMEM((group, bq, dkp), qt.dtype),
+                    pltpu.VMEM((group, bq, 128), jnp.float32),
+                    pltpu.VMEM((group, bq, 128), jnp.float32),
+                    pltpu.VMEM((group, bq, dv), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, s, heads * dv), qt.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=resident + (16 << 20),
+            ),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * b * heads * steps * bq * bk * (dkp + dv),
+                transcendentals=b * heads * steps * bq * bk,
+                bytes_accessed=(
+                    item * (qt.size + b * s * heads * dv)
+                    + b * kv_heads * steps * tiles
+                ),
+            ),
+            interpret=interpret,
+            name=PREFILL_NAME,
+        )(*prefetch, qt, k, v, allowed)
 
 
 def prefill_attention(

@@ -65,7 +65,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import is_tpu_backend
 from tpudl.ops.norms import resolve_impl
-from tpudl.ops.pallas_utils import round_up
+from tpudl.ops.pallas_utils import kernel_trace, round_up
 
 #: The kernel's name: its row of a device trace's breakdown.
 NAME = "moe_grouped_matmul"
@@ -340,7 +340,7 @@ def grouped_matmul(
         ]
     else:
         out_spec = pl.BlockSpec((tm, tn), lambda j, v, g, tile, *_: (tile[v], j))
-    with jax.named_scope(NAME):
+    with jax.named_scope(NAME), kernel_trace(NAME):
         out = pl.pallas_call(
             functools.partial(
                 _kernel_by_index if by_index else _kernel, tm=tm, tn=tn
@@ -437,7 +437,7 @@ def sum_choices(rows, held, *, interpret: Optional[bool] = None):
         held.reshape(-1).astype(jnp.int32),
         (0, steps * SUM_TOKENS * k - places),
     )
-    with jax.named_scope(SUM_NAME):
+    with jax.named_scope(SUM_NAME), kernel_trace(SUM_NAME):
         out = pl.pallas_call(
             functools.partial(_sum_kernel, k=k, tokens=SUM_TOKENS),
             grid_spec=pltpu.PrefetchScalarGridSpec(

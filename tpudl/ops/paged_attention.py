@@ -55,7 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.ops.attention import MASK_VALUE, is_tpu_backend
 from tpudl.ops.norms import resolve_impl
-from tpudl.ops.pallas_utils import round_up
+from tpudl.ops.pallas_utils import kernel_trace, round_up
 
 #: Pages fetched into one VMEM block. A page of Mistral's pool is
 #: 32 KB a pool: per-page compute is too small to hide a DMA, so a
@@ -242,7 +242,7 @@ def _fused(q, pages_k, pages_v, page_table, start, lens, *, interpret: bool):
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     block = (2, ppb * ps * hkv, d)
-    with jax.named_scope(SCOPE):
+    with jax.named_scope(SCOPE), kernel_trace("paged_attention"):
         out = pl.pallas_call(
             functools.partial(
                 _kernel, page_size=ps, heads=h, kv_heads=hkv, chunk=s,
@@ -528,7 +528,7 @@ def _fused_merged(
     dv = wv // hkv
     rows = round_up(s * h, 16)
     ppb = min(MERGED_PAGES_PER_BLOCK, int(page_table.shape[1]))
-    with jax.named_scope(SCOPE):
+    with jax.named_scope(SCOPE), kernel_trace("merged_paged_attention"):
         # Head h's query in the lanes of its KV head, zeros elsewhere.
         own = (
             jnp.arange(h)[:, None] // (h // hkv) == jnp.arange(hkv)[None, :]
@@ -813,7 +813,7 @@ def _fused_latent(
     _, held, width = pages.shape
     hp = round_up(h, 16)
     ppb = min(LATENT_PAGES_PER_BLOCK, int(page_table.shape[1]))
-    with jax.named_scope(LATENT_SCOPE):
+    with jax.named_scope(LATENT_SCOPE), kernel_trace("latent_paged_attention"):
         q = jnp.pad(
             query.reshape(b, h, c).astype(pages.dtype),
             ((0, 0), (0, hp - h), (0, round_up(c, LANES) - c)),

@@ -13,9 +13,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudl.obs.spans import startup_span
+
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def kernel_trace(kernel: str):
+    """Around a serving kernel's ``pl.pallas_call(...)(...)``: that
+    call traces the kernel's Python body, on the host, in every process
+    and whatever the compile cache holds, and the start-up span
+    ``kernel.trace`` (attr ``kernel``) says for how long. It runs while
+    a program is being traced, never in a compiled step."""
+    return startup_span("kernel.trace", kernel=kernel)
 
 
 def kv_valid(kvm_ref, shape):

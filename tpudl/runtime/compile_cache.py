@@ -18,11 +18,14 @@ zeroes the min-compile-time / min-entry-size gates so every executable
 is eligible — the repo's test-sized programs compile in milliseconds
 and would otherwise never be cached.
 
-Observability: a ``jax.monitoring`` listener turns the cache's hit/miss
-events into ``compile_cache_hits`` / ``compile_cache_misses`` counters
-and — when a span recorder is active — a ``compile_cache_hit`` event in
-the span stream, so a report shows whether a run's compiles were served
-from disk.
+Observability: the ``jax.monitoring`` hook-up of
+``tpudl.analysis.dispatch`` (installed here, at import) turns the
+cache's hit/miss events into ``compile_cache_hits`` /
+``compile_cache_misses`` counters, a ``compile_cache_hit`` event in the
+span stream when a span recorder is active, and the ``cache_hit`` /
+``cache_read_s`` attributes of each program's ``program.compile``
+record, so a report shows whether a run's compiles were served from
+disk and how long the reads took.
 """
 
 from __future__ import annotations
@@ -37,32 +40,12 @@ JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_CACHE_DIR = (
     pathlib.Path(__file__).resolve().parents[1] / ".compile_cache"
 )
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-_listener_installed = False
-
-
-def _on_monitoring_event(event: str, **kwargs) -> None:
-    if event not in (_HIT_EVENT, _MISS_EVENT):
-        return
-    from tpudl.obs import counters as obs_counters
-    from tpudl.obs import spans as obs_spans
-
-    name = (
-        "compile_cache_hits" if event == _HIT_EVENT
-        else "compile_cache_misses"
-    )
-    obs_counters.registry().counter(name).inc()
-    rec = obs_spans.active_recorder()
-    if rec is not None:
-        rec.event(name[:-1], "compile")
 
 
 def enable_compile_cache() -> str:
     """Point the persistent compilation cache at its directory (see the
     module docstring for which) and return that directory. Idempotent;
-    the monitoring listener installs once per process."""
-    global _listener_installed
+    the monitoring listeners install once per process."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -77,9 +60,7 @@ def enable_compile_cache() -> str:
     # process; without the reset, enabling after any jit has run is a
     # silent no-op.
     compilation_cache.reset_cache()
-    if not _listener_installed:
-        import jax.monitoring
+    from tpudl.analysis.dispatch import install_listeners
 
-        jax.monitoring.register_event_listener(_on_monitoring_event)
-        _listener_installed = True
+    install_listeners()
     return jax.config.jax_compilation_cache_dir

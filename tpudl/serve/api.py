@@ -55,7 +55,13 @@ import numpy as np
 from tpudl.analysis.registry import env_flag, env_int, env_str
 from tpudl.obs import registry
 from tpudl.obs import requestlog
-from tpudl.obs.spans import active_recorder
+from tpudl.obs.spans import (
+    CAT_ENCLOSING,
+    active_recorder,
+    startup_phase,
+    startup_recorder,
+    startup_span,
+)
 from tpudl.serve.cache import (
     PagedKVCache,
     _is_attn_cache,
@@ -281,10 +287,19 @@ class ServeSession:
         #: frame runs no ``finally``, so only this reference can
         #: reclaim the engine's token feed).
         self._stream_gen = None
+        #: No call of ``serve`` / ``stream`` has been made yet: the
+        #: first one builds the decode program, and is recorded whole
+        #: as ``startup.first_requests``.
+        self._unserved = True
 
     # -- constructors --------------------------------------------------
 
     @classmethod
+    @startup_phase("startup.from_model", lambda session: dict(
+        slots=session.engine.num_slots,
+        prompt_len=session.engine.prompt_len,
+        lengths=list(session.engine.prefill_lengths),
+    ))
     def from_model(
         cls,
         model,
@@ -411,7 +426,8 @@ class ServeSession:
         if weight_dtype is not None:
             from tpudl.quant import quantize_model
 
-            model, params = quantize_model(model, params, weight_dtype)
+            with startup_span("startup.quantize"):
+                model, params = quantize_model(model, params, weight_dtype)
         num_slots = (
             num_slots
             if num_slots is not None
@@ -584,7 +600,10 @@ class ServeSession:
         # batch axis; the write index and a window layer's marker
         # (``[window]``) have none.
         row_ids = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
-        _, row, *_ = jax.eval_shape(prefill_call, serving, row_ids, row_ids)
+        with startup_span("startup.cache_template"):
+            _, row, *_ = jax.eval_shape(
+                prefill_call, serving, row_ids, row_ids
+            )
         cache_template = jax.tree.map(
             lambda leaf: jax.ShapeDtypeStruct(
                 (num_slots, *leaf.shape[1:]), leaf.dtype
@@ -908,11 +927,38 @@ class ServeSession:
             rec.counters(registry().snapshot())
         return out
 
+    def _first_requests_began(self) -> Optional[float]:
+        """The start-up clock's reading where this call of ``serve`` /
+        ``stream`` is the session's first, else None (asked once a
+        call)."""
+        if not self._unserved:
+            return None
+        self._unserved = False
+        return startup_recorder().clock()
+
+    @staticmethod
+    def _first_requests_made(began: float) -> None:
+        """The session's first requests, whole (the decode program is
+        built on the way), as ``startup.first_requests``: recorded after
+        the fact, so that a recorder's tree of the hot path is the same
+        on a first call as on any other, and as an ENCLOSING span, since
+        it lies around the steps and programs of that call on the same
+        clock and would count their seconds a second time."""
+        rec = startup_recorder()
+        rec.record(
+            "startup.first_requests", CAT_ENCLOSING, began,
+            rec.clock() - began,
+        )
+
     def serve(self, requests: Sequence[Request]) -> Dict[Any, Result]:
         """submit() them all, collect() once — the closed-loop shape."""
+        began = self._first_requests_began()
         for request in requests:
             self.submit(request)
-        return self.collect()
+        out = self.collect()
+        if began is not None:
+            self._first_requests_made(began)
+        return out
 
     def stream(
         self,
@@ -939,6 +985,7 @@ class ServeSession:
             raise ValueError(
                 f"chunk_tokens must be >= 1, got {chunk_tokens}"
             )
+        began = self._first_requests_began()
         if self.engine.on_token is not None:
             prior = self._stream_gen() if self._stream_gen else None
             if prior is None or prior.gi_frame is None:
@@ -969,12 +1016,13 @@ class ServeSession:
         except BaseException:
             self.engine.on_token = None
             raise
-        gen = self._stream_chunks(buf, chunk_tokens, sink)
+        gen = self._stream_chunks(buf, chunk_tokens, sink, began)
         self._stream_gen = weakref.ref(gen)
         return gen
 
     def _stream_chunks(
-        self, buf: Dict[Any, List[int]], chunk_tokens: int, sink
+        self, buf: Dict[Any, List[int]], chunk_tokens: int, sink,
+        began: Optional[float] = None,
     ):
         """The lazy half of ``stream()`` (which owns validation and
         submission): step the engine and yield chunks until every
@@ -1009,6 +1057,9 @@ class ServeSession:
         finally:
             if self.engine.on_token is sink:
                 self.engine.on_token = None
+            if began is not None:
+                # From the call of ``stream`` to the last chunk.
+                self._first_requests_made(began)
         rec = active_recorder()
         if rec is not None:
             rec.counters(registry().snapshot())

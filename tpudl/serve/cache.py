@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudl.obs import registry
+from tpudl.obs.spans import startup_span
 
 
 def _is_valid_leaf(leaf) -> bool:
@@ -626,7 +627,9 @@ class PagedKVCache:
             ) // (pages * self.page_size)
             return pool
 
-        self.cache = _map_attn_caches(template, to_pool)
+        with startup_span("startup.pools") as phase:
+            self.cache = _map_attn_caches(template, to_pool)
+            phase.note(**self._pool_facts())
         # Host-owned addressing: page 0 is the trash page, never
         # allocated; unmapped table entries point at it.
         self._free: list = list(range(1, self.num_pages))
@@ -1585,10 +1588,21 @@ class PagedKVCache:
                 return NamedSharding(mesh, PartitionSpec(None, None, "tp"))
             return NamedSharding(mesh, PartitionSpec())
 
-        self.cache = jax.device_put(
-            self.cache, jax.tree.map(place, self.cache)
-        )
+        with startup_span("startup.pools", **self._pool_facts()):
+            self.cache = jax.device_put(
+                self.cache, jax.tree.map(place, self.cache)
+            )
         self.sharded = True
+
+    def _pool_facts(self) -> dict:
+        """What a ``startup.pools`` span says of the pools it made or
+        placed."""
+        leaves = jax.tree.leaves(self.cache)
+        return {
+            "leaves": len(leaves),
+            "bytes": sum(leaf.nbytes for leaf in leaves),
+            "pages": self.num_pages,
+        }
 
     def _replace_pool(self, program, *args) -> None:
         """``program(pool, *args) -> pool``: a seat or an import."""

@@ -59,7 +59,7 @@ import numpy as np
 
 from tpudl.obs import registry
 from tpudl.obs import requestlog
-from tpudl.obs.spans import active_recorder
+from tpudl.obs.spans import active_recorder, startup_span
 from tpudl.serve.api import Request, Result, left_pad
 from tpudl.serve.cache import (
     MigrationCompatError,
@@ -709,20 +709,29 @@ class Engine:
         hands a first token to the next decode step on the device
         (``tpudl_first_token``), over both vectors it is given: the
         host's tokens and a step's selection, which its own result
-        stands for here (an array of the device, placed alike)."""
-        for rows in lengths:
-            logits, row_cache, *_ = self.prefill_call(
-                self.params, *left_pad([0], rows)
-            )
-            if not self.prefix_share:
-                self.cache.compile_seat(row_cache, rows)
-            if self.speculator is not None:
-                self.speculator.compile_seat(rows)
-            first = _select_greedy(logits)
-            tokens = self._with_firsts(
-                np.zeros(self.num_slots, np.int32), [(0, first)]
-            )
-            self._with_firsts(tokens, [(0, first)], from_device=True)
+        stands for here (an array of the device, placed alike).
+        Recorded as ``startup.prefill_lengths`` around one
+        ``startup.prefill_dry_run`` a length, which nothing of blocks
+        on the device: a dry run's device time shows in whatever reads
+        back first afterwards."""
+        with startup_span("startup.prefill_lengths"):
+            for rows in lengths:
+                with startup_span(
+                    "startup.prefill_dry_run", rows=rows
+                ) as dry_run:
+                    logits, row_cache, *_ = self.prefill_call(
+                        self.params, *left_pad([0], rows)
+                    )
+                    if not self.prefix_share:
+                        self.cache.compile_seat(row_cache, rows)
+                    if self.speculator is not None:
+                        self.speculator.compile_seat(rows)
+                    first = _select_greedy(logits)
+                    tokens = self._with_firsts(
+                        np.zeros(self.num_slots, np.int32), [(0, first)]
+                    )
+                    self._with_firsts(tokens, [(0, first)], from_device=True)
+                    dry_run.note(kernel_layers=self._kernel_layers(rows))
         self.prefill_lengths = tuple(sorted({*lengths, self.prompt_len}))
 
     def _kernel_layers(self, rows: int) -> int:
@@ -730,8 +739,13 @@ class Engine:
         ONE kernel call (tpudl.ops.flash_attention.prefill_attention):
         what the program noted of itself while it was traced; 0 for a
         length not traced yet, an artifact, the XLA blocks."""
+        return self._noted("attention_in_kernel", rows)
+
+    def _noted(self, what: str, rows: int) -> int:
+        """The prefill program's own note ``what`` of itself at
+        ``rows`` (tpudl.ops.flash_attention.note_prefill)."""
         program = getattr(self.prefill_call, "__wrapped__", self.prefill_call)
-        return getattr(program, "attention_in_kernel", {}).get(rows, 0)
+        return getattr(program, what, {}).get(rows, 0)
 
     def _with_firsts(self, tokens, firsts, from_device: bool = False):
         """A decode step's token vector as the device takes it: the
@@ -869,13 +883,17 @@ class Engine:
             # among them (what the padding and a shared prefix leave).
             # behind: a decode step was in flight at the dispatch.
             # attention_in_kernel: the program's attention at that
-            # length was the prefill kernel's (its own note, traced).
+            # length was the prefill kernel's (its own note, traced),
+            # in attention_kernel_layers of its attention_layers.
+            kernel_layers = self._kernel_layers(ran)
             attrs = dict(
                 slot=slot, request_id=req.request_id,
                 queue_wait_s=t0 - entry.submitted_at,
                 prefix_hit_tokens=hit, rows=ran, tokens=n - hit,
                 behind=int(behind),
-                attention_in_kernel=int(self._kernel_layers(ran) > 0),
+                attention_in_kernel=int(kernel_layers > 0),
+                attention_kernel_layers=kernel_layers,
+                attention_layers=self._noted("attention_layers", ran),
             )
         reg = registry()
         if hit:

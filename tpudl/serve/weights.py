@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from tpudl.models.turned import turn
+from tpudl.obs.spans import startup_phase
 
 
 def chip_of(params) -> Optional[jax.Device]:
@@ -45,10 +46,15 @@ def chip_of(params) -> Optional[jax.Device]:
     return device if device.platform == "tpu" else None
 
 
+@startup_phase(
+    "startup.weights", lambda out: {"leaves": out[1], "bytes": out[2]}
+)
 def held(params) -> Tuple[Any, int, int]:
     """``params`` as a session on a chip holds them: ``(tree, leaves,
     nbytes)``, the kernels of ``tpudl.models.turned.TURNED`` turned and
-    counted; as given, ``0, 0``, where ``chip_of`` finds no chip."""
+    counted; as given, ``0, 0``, where ``chip_of`` finds no chip.
+    Recorded as ``startup.weights`` (the count and the bytes are its
+    attributes); the turning is dispatched, not waited for."""
     if chip_of(params) is None:
         return params, 0, 0
     return turn(params)

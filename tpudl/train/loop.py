@@ -102,26 +102,36 @@ def create_train_state(
     preset name) — wraps ``tx`` with the policy's rule-selected moment
     dtypes and seeds ``TrainState.precision`` (loss scale, and the
     model's ``"fp8"`` amax collection when the policy routes matmuls
-    through fp8). None = exactly the pre-policy behavior."""
+    through fp8). None = exactly the pre-policy behavior.
+
+    Recorded as the start-up phase ``startup.init_state``; ``programs``
+    is how many programs the initialisers built on the way (flax runs
+    them op by op)."""
+    from tpudl.analysis.dispatch import compile_count
+
     if init_kwargs is None:
         init_kwargs = {"train": False}
-    variables = model.init(rng, sample_input, **init_kwargs)
-    prec_state = None
-    if precision is not None:
-        from tpudl.train import precision as precision_mod
+    with obs_spans.startup_span("startup.init_state") as phase:
+        programs = compile_count()
+        variables = model.init(rng, sample_input, **init_kwargs)
+        prec_state = None
+        if precision is not None:
+            from tpudl.train import precision as precision_mod
 
-        pol = precision_mod.resolve_policy(precision)
-        tx = precision_mod.apply_moment_rules(tx, pol)
-        prec_state = precision_mod.init_precision_state(
-            pol, variables.get("fp8")
+            pol = precision_mod.resolve_policy(precision)
+            tx = precision_mod.apply_moment_rules(tx, pol)
+            prec_state = precision_mod.init_precision_state(
+                pol, variables.get("fp8")
+            )
+        state = TrainState.create(
+            apply_fn=model.apply,
+            params=variables["params"],
+            batch_stats=variables.get("batch_stats"),
+            precision=prec_state,
+            tx=tx,
         )
-    return TrainState.create(
-        apply_fn=model.apply,
-        params=variables["params"],
-        batch_stats=variables.get("batch_stats"),
-        precision=prec_state,
-        tx=tx,
-    )
+        phase.note(programs=compile_count() - programs)
+    return state
 
 
 def cross_entropy_loss(
@@ -563,6 +573,7 @@ def pad_batch(batch: dict, to_size: int) -> dict:
     return out
 
 
+@obs_spans.startup_phase("startup.compile_step")
 def compile_step(
     step_fn: Callable,
     mesh: Mesh,
@@ -830,13 +841,31 @@ def compile_step(
             # a later new-shape recompile (e.g. evaluate's padded
             # variant) still counts as a step.
             wrapped._tpudl_compile_pending = False
+            _first_step_made()
         return out
+
+    def _first_step_made():
+        # The step's first call in a fit(): traced, compiled and
+        # dispatched (not waited for), from where fit() read the clock
+        # before its loop. ``startup.first_step``, recorded after the
+        # fact and as an ENCLOSING span: it lies around the loop's own
+        # spans and the programs' on the same clock.
+        began, wrapped._tpudl_first_step_began = (
+            wrapped._tpudl_first_step_began, None
+        )
+        if began is not None:
+            rec = obs_spans.startup_recorder()
+            rec.record(
+                "startup.first_step", obs_spans.CAT_ENCLOSING, began,
+                rec.clock() - began,
+            )
 
     wrapped.jitted = jitted  # expose for lower()/cost analysis
     wrapped.state_shardings = state_sh
     wrapped.batch_sharding = batch_sh
     wrapped._tpudl_mask_aware = getattr(step_fn, "_tpudl_mask_aware", False)
     wrapped._tpudl_compile_pending = True
+    wrapped._tpudl_first_step_began = None
     wrapped.steps_per_dispatch = steps_per_dispatch
     wrapped.precision = precision_policy
 
@@ -852,6 +881,7 @@ def compile_step(
                 out = jitted_window(state_arg, window, *rest)
             if wrapped._tpudl_window_compile_pending:
                 wrapped._tpudl_window_compile_pending = False
+                _first_step_made()
             return out
 
         wrapped.window_step = window_step
@@ -1161,6 +1191,20 @@ def fit(
 
     pending = deque()  # leftover singles from a partial window pull
     i = 0
+    # A compiled step that has not run before: its first call (trace,
+    # compile, dispatch) is the start-up phase ``startup.first_step``,
+    # recorder or not. Asked once a call of fit(), here; the step's own
+    # first-call branch records it from this reading of the clock.
+    first_step = getattr(
+        compiled_step,
+        "_tpudl_compile_pending" if K == 1
+        else "_tpudl_window_compile_pending",
+        False,
+    )
+    if first_step:
+        compiled_step._tpudl_first_step_began = (
+            obs_spans.startup_recorder().clock()
+        )
     try:
         while num_steps is None or i < num_steps:
             if ft_preemption.requested():
@@ -1363,6 +1407,10 @@ def fit(
         # Orderly exit (or unwind) is "finished", not "hung": a stopped
         # heartbeat is never stale on /healthz.
         heartbeat.stop()
+        if first_step:
+            # Where the step was never called (no batch, a preemption),
+            # a later call outside fit() is no first step of this one.
+            compiled_step._tpudl_first_step_began = None
         if profiling:
             jax.profiler.stop_trace()
         if fetcher is not None:
